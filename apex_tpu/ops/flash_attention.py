@@ -1039,8 +1039,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
         if ksc_ref is not None:
             # int8 cache: dequantize blockwise in VMEM against the
             # per-(position, head) scales — HBM only ever holds int8
-            k = k.astype(jnp.float32) * ksc_ref[0][:, None]
-            v = v.astype(jnp.float32) * vsc_ref[0][:, None]
+            k = k.astype(jnp.float32) * ksc_ref[0].T
+            v = v.astype(jnp.float32) * vsc_ref[0].T
         s = jax.lax.dot_general(q, k.astype(jnp.float32),
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -1086,14 +1086,18 @@ def _decode_pallas(q3, k3, v3, lengths_bh, ksc, vsc, *, scale, block_k):
                           memory_space=pltpu.VMEM)
     kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0),
                            memory_space=pltpu.VMEM)
-    sc_spec = pl.BlockSpec((1, block_k), lambda b, j: (b, j),
+    # scales ride (bh, 1, T): a (1, block_k) block over (bh, T) breaks
+    # Mosaic's rule that a block's last two dims are (8, 128)-multiples
+    # or the array's own; the unit middle dim makes them (1, block_k)
+    # over (1, T)
+    sc_spec = pl.BlockSpec((1, 1, block_k), lambda b, j: (b, 0, j),
                            memory_space=pltpu.VMEM)
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, kv_spec,
                 kv_spec]
     args = [lengths_bh, q3, k3, v3]
     if has_scale:
         in_specs += [sc_spec, sc_spec]
-        args += [ksc, vsc]
+        args += [ksc[:, None, :], vsc[:, None, :]]
 
     def kernel(*refs):
         refs = list(refs)
@@ -1397,19 +1401,14 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, ksc_ref,
     # the clamped index map (see the section comment)
     @pl.when(j * block_size < length)
     def _():
-        # classic decode rides a rank-3 (1, 1, d) q block — the exact
-        # pre-speculation program, kept byte-identical so non-spec
-        # engines never recompile or shift numerics; the verify path
-        # widens to a rank-4 (1, 1, q_len, d) block
-        q = (q_ref[0] if q_ref.ndim == 3
-             else q_ref[0, 0]).astype(jnp.float32)  # (q_len, d)
+        q = q_ref[0, 0].astype(jnp.float32)       # (q_len, d)
         k = k_ref[0, 0]                           # (block_size, d)
         v = v_ref[0, 0]
         if ksc_ref is not None:
             # int8 pool: dequantize blockwise in VMEM against the pooled
             # per-(position, head) scales — HBM only ever holds int8
-            k = k.astype(jnp.float32) * ksc_ref[0, 0][:, None]
-            v = v.astype(jnp.float32) * vsc_ref[0, 0][:, None]
+            k = k.astype(jnp.float32) * ksc_ref[0, 0].T
+            v = v.astype(jnp.float32) * vsc_ref[0, 0].T
         s_ = jax.lax.dot_general(q, k.astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
@@ -1433,14 +1432,9 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, ksc_ref,
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         # -inf on empty rows: the identity of the _merge_current fold
-        if o_ref.ndim == 3:
-            o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-            lse_ref[0] = jnp.where(l == 0.0, -jnp.inf,
-                                   m_ref[:] + jnp.log(safe_l))
-        else:
-            o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-            lse_ref[0, 0] = jnp.where(l == 0.0, -jnp.inf,
-                                      m_ref[:] + jnp.log(safe_l))
+        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.where(l == 0.0, -jnp.inf,
+                                  m_ref[:] + jnp.log(safe_l))
 
 
 def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
@@ -1473,32 +1467,22 @@ def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
 
 def _paged_decode_pallas(q, kp, vp, tables, lengths, ksc, vsc, *, scale,
                          mean_context):
-    # q rank-3 (S, h, d) is the classic decode step — its program is
-    # kept BYTE-identical to the pre-speculation kernel (same block
-    # ranks, same index maps) so non-spec engines are untouched; rank-4
-    # (S, h, q_len, d) is the verify path, which only widens the
-    # q/out/scratch shapes — the kernel body is per-row throughout and
-    # the KV fetch sequence (and its clamp) is q_len-independent.
-    multi = q.ndim == 4
-    if multi:
-        S, h, q_len, d = q.shape
-    else:
-        S, h, d = q.shape
-        q_len = 1
+    # q is (S, h, q_len, d): q_len == 1 is the classic decode step,
+    # q_len == k + 1 the speculative verify — ONE program shape for
+    # both. The q/out/lse blocks are rank-4 (1, 1, q_len, ·) so their
+    # last two dims equal the array's, which is what Mosaic's block rule
+    # asks of a lone query row (a rank-3 (1, 1, d) block over (S, h, d)
+    # is refused: 1 is neither h nor a multiple of 8). The kernel body
+    # is per-row throughout and the KV fetch sequence (and its clamp) is
+    # q_len-independent.
+    S, h, q_len, d = q.shape
     _nb_pool, _, block_size, _ = kp.shape
     n_blocks = tables.shape[1]
     has_scale = ksc is not None
 
-    if multi:
-        def q_map(s, hh, j, tabs, lens):
-            return (s, hh, 0, 0)
-        q_block, lse_block = (1, 1, q_len, d), (1, 1, q_len, 1)
-        out_shapes = ((S, h, q_len, d), (S, h, q_len, 1))
-    else:
-        def q_map(s, hh, j, tabs, lens):
-            return (s, hh, 0)
-        q_block, lse_block = (1, 1, d), (1, 1, 1)
-        out_shapes = ((S, h, d), (S, h, 1))
+    def q_map(s, hh, j, tabs, lens):
+        return (s, hh, 0, 0)
+    q_block, lse_block = (1, 1, q_len, d), (1, 1, q_len, 1)
 
     def kv_map(s, hh, j, tabs, lens):
         # clamp past-the-cursor steps to the slot's LAST valid block:
@@ -1511,20 +1495,18 @@ def _paged_decode_pallas(q, kp, vp, tables, lengths, ksc, vsc, *, scale,
         jj = jnp.minimum(j, nb_valid - 1)
         return (tabs[s, jj], hh, 0, 0)
 
-    def sc_map(s, hh, j, tabs, lens):
-        nb_valid = jnp.maximum(
-            (lens[s] + block_size - 1) // block_size, 1)
-        jj = jnp.minimum(j, nb_valid - 1)
-        return (tabs[s, jj], hh, 0)
 
     in_specs = [pl.BlockSpec(q_block, q_map),
                 pl.BlockSpec((1, 1, block_size, d), kv_map),
                 pl.BlockSpec((1, 1, block_size, d), kv_map)]
     args = [q, kp, vp]
     if has_scale:
-        in_specs += [pl.BlockSpec((1, 1, block_size), sc_map),
-                     pl.BlockSpec((1, 1, block_size), sc_map)]
-        args += [ksc, vsc]
+        # pooled scales ride (num_blocks, h, 1, block_size) — the unit
+        # dim keeps the block's last two dims equal to the array's (see
+        # _decode_pallas) and lets them share the K/V index map
+        sc_spec = pl.BlockSpec((1, 1, 1, block_size), kv_map)
+        in_specs += [sc_spec, sc_spec]
+        args += [ksc[:, :, None, :], vsc[:, :, None, :]]
 
     def kernel(*refs):
         refs = list(refs)
@@ -1552,8 +1534,8 @@ def _paged_decode_pallas(q, kp, vp, tables, lengths, ksc, vsc, *, scale,
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct(out_shapes[0], out_dtype),
-                   jax.ShapeDtypeStruct(out_shapes[1], jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((S, h, q_len, d), out_dtype),
+                   jax.ShapeDtypeStruct((S, h, q_len, 1), jnp.float32)),
         cost_estimate=_paged_cost(S, h, d, kp.dtype, has_scale, n_blocks,
                                   block_size, mean_context, q_len=q_len),
         interpret=_interp(),
@@ -1635,13 +1617,14 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 
     with jax.named_scope("decode_attention"):
         if use_pallas:
-            # rank-3 q emits the classic (byte-identical) decode
-            # program; rank-4 q emits the widened verify program
             out, lse = _paged_decode_pallas(
-                q, k_pool, v_pool, block_tables, lengths,
+                q if multi else q[:, :, None, :], k_pool, v_pool,
+                block_tables, lengths,
                 k_scale if quantized else None,
                 v_scale if quantized else None,
                 scale=float(softmax_scale), mean_context=mean_context)
+            if not multi:
+                out, lse = out[:, :, 0], lse[:, :, 0]
             if multi:
                 if k_new is not None:
                     out = _merge_drafts(
