@@ -48,7 +48,7 @@ import numpy as np
 
 from apex_tpu.observability import health as _health
 from apex_tpu.observability import ingraph as _metrics
-from apex_tpu.utils.vma import cast_to_vma
+from apex_tpu.utils.vma import cast_to_vma, leaf_vma
 from apex_tpu.utils.compat import axis_size as _axis_size
 
 __all__ = ["allreduce_grads", "DistributedDataParallel", "Reducer",
@@ -160,39 +160,61 @@ def _bucketed_allreduce(grads: Any, axis_name: str,
     jaxpr in tests)."""
     from apex_tpu.optimizers._flatten import (bucket_bounds, build_layout,
                                               ravel_span, unravel_parts)
-    lay = build_layout(grads, chunks=1)
-    bounds = bucket_bounds(lay, bucket_bytes)
     world = _axis_size(axis_name)
     pre = gradient_predivide_factor
-
-    if _metrics.recording():
-        _metrics.record("ddp/allreduce_bytes", float(4 * lay.total),
-                        reduce="sum")
-        _metrics.record("ddp/num_buckets", float(len(bounds)), reduce="mean")
-        _metrics.record("ddp/bucket_bytes",
-                        float(4 * max(n for _, n in bounds)), reduce="mean")
-
     if gradient_average:
         post = pre / world
     else:
         post = pre if pre != 1.0 else None
 
+    # One bucket grid per varying-axes class. A bucket is a concatenate,
+    # and a concatenate of leaves typed over different mesh axes takes
+    # the UNION: a tensor-replicated LN grad that shares a bucket with a
+    # tensor-sharded weight grad would come back typed tensor-varying,
+    # and nothing short of a collective casts that back — the step's
+    # replicated out_specs then fail the VMA check. Leaves that agree
+    # (every plain-DDP tree; any tree outside shard_map) form ONE class
+    # in flatten order, i.e. exactly the single-grid program.
+    leaves, treedef = jax.tree_util.tree_flatten(grads)
+    classes = {}
+    for i, g in enumerate(leaves):
+        classes.setdefault(leaf_vma(g), []).append(i)
+
+    synced = [None] * len(leaves)
+    total = n_buckets = widest = 0
     with jax.named_scope("apex_ddp_bucketed_allreduce"):
-        pieces = []
-        for off, n in bounds:
-            # one psum per bucket, assembled span-locally: this bucket's
-            # transfer depends only on the grads in its span, and the
-            # pre/post scales are per-bucket epilogue work the scheduler
-            # can run under the next bucket's transfer
-            b = ravel_span(grads, lay, off, n)
-            if pre != 1.0:
-                b = b / pre
-            b = jax.lax.psum(
-                cast_to_vma(b, frozenset({axis_name})), axis_name)
-            if post is not None:
-                b = b * post
-            pieces.append(b)
-    synced = unravel_parts(pieces, bounds, lay)
+        for idx in classes.values():
+            sub = [leaves[i] for i in idx]
+            lay = build_layout(sub, chunks=1)
+            bounds = bucket_bounds(lay, bucket_bytes)
+            total += lay.total
+            n_buckets += len(bounds)
+            widest = max(widest, max(n for _, n in bounds))
+            pieces = []
+            for off, n in bounds:
+                # one psum per bucket, assembled span-locally: this
+                # bucket's transfer depends only on the grads in its
+                # span, and the pre/post scales are per-bucket epilogue
+                # work the scheduler can run under the next bucket's
+                # transfer
+                b = ravel_span(sub, lay, off, n)
+                if pre != 1.0:
+                    b = b / pre
+                b = jax.lax.psum(
+                    cast_to_vma(b, frozenset({axis_name})), axis_name)
+                if post is not None:
+                    b = b * post
+                pieces.append(b)
+            for i, g in zip(idx, unravel_parts(pieces, bounds, lay)):
+                synced[i] = g
+    synced = jax.tree_util.tree_unflatten(treedef, synced)
+
+    if _metrics.recording():
+        _metrics.record("ddp/allreduce_bytes", float(4 * total),
+                        reduce="sum")
+        _metrics.record("ddp/num_buckets", float(n_buckets), reduce="mean")
+        _metrics.record("ddp/bucket_bytes", float(4 * widest),
+                        reduce="mean")
     _health.observe_replica_agreement(synced, axis_name, name="ddp_grads")
     return synced
 

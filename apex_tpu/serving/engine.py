@@ -168,8 +168,7 @@ class ServingEngine:
                                          self.top_k)
                 return cache, toks
 
-        key = jax.random.PRNGKey(rng_seed)
-        self._key, _ = jax.random.split(key)  # also warms split's compile
+        self._init_key(rng_seed)
         S = self.max_seqs
         ex_tokens = jnp.zeros((1, self.prefill_len), jnp.int32)
         ex_scalar = jnp.zeros((), jnp.int32)
@@ -263,11 +262,24 @@ class ServingEngine:
 
     # -- stepping -----------------------------------------------------------
 
+    def _init_key(self, rng_seed: int) -> None:
+        self._key, _ = jax.random.split(jax.random.PRNGKey(rng_seed))
+        # the per-dispatch key split is an AOT program like the steps:
+        # the steady-state loop dispatches ONLY compiled executables and
+        # marshals host values with numpy. An eager jnp op there
+        # (``jax.random.split``, ``jnp.asarray(x, dtype)``) is a jitted
+        # primitive that can fall off jit's C++ fast path and then
+        # reports a trace on every dispatch (docs/SERVING.md
+        # "Zero-recompile contract").
+        self._split_compiled = jax.jit(
+            lambda key: tuple(jax.random.split(key))).lower(
+                self._key).compile()
+
     def _next_key(self) -> jax.Array:
-        self._key, sub = jax.random.split(self._key)
+        self._key, sub = self._split_compiled(self._key)
         return sub
 
-    def pad_prompt(self, prompt: Sequence[int]) -> jnp.ndarray:
+    def pad_prompt(self, prompt: Sequence[int]) -> np.ndarray:
         if len(prompt) == 0:
             raise ValueError("empty prompt")
         if len(prompt) > self.prefill_len:
@@ -277,7 +289,7 @@ class ServingEngine:
                 "engine construction)")
         padded = np.zeros((1, self.prefill_len), np.int32)
         padded[0, : len(prompt)] = np.asarray(prompt, np.int32)
-        return jnp.asarray(padded)
+        return padded
 
     def prefill(self, prompt: Sequence[int], slot: int,
                 temperature: float = 0.0) -> int:
@@ -291,9 +303,9 @@ class ServingEngine:
                              f"[0, {self.max_seqs})")
         self.cache, tok = self.prefill_compiled(
             self.params, self.cache, self.pad_prompt(prompt),
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(len(prompt), jnp.int32),
-            jnp.asarray(temperature, jnp.float32), self._next_key())
+            np.asarray(slot, np.int32),
+            np.asarray(len(prompt), np.int32),
+            np.asarray(temperature, np.float32), self._next_key())
         return int(tok)
 
     def decode(self, tokens: np.ndarray, temperatures: np.ndarray,
@@ -315,12 +327,12 @@ class ServingEngine:
         if active is None:
             active = np.ones(self.max_seqs, np.bool_)
         args = (self.params, self.cache,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(temperatures, jnp.float32),
-                jnp.asarray(active, jnp.bool_), self._next_key())
+                np.asarray(tokens, np.int32),
+                np.asarray(temperatures, np.float32),
+                np.asarray(active, np.bool_), self._next_key())
         if self.quarantine:
             pvec = self._zero_poison if poison is None else \
-                jnp.asarray(poison, jnp.float32)
+                np.asarray(poison, np.float32)
             self.cache, toks, finite = self.decode_compiled(*args, pvec)
             self.last_finite = np.asarray(finite)
         else:
@@ -362,13 +374,13 @@ class ServingEngine:
         tok_mat = np.concatenate(
             [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
              drafts], axis=1)
-        args = (self.params, self.cache, jnp.asarray(tok_mat),
-                jnp.asarray(drafts),
-                jnp.asarray(temperatures, jnp.float32),
-                jnp.asarray(active, jnp.bool_), self._next_key())
+        args = (self.params, self.cache, np.asarray(tok_mat),
+                np.asarray(drafts),
+                np.asarray(temperatures, np.float32),
+                np.asarray(active, np.bool_), self._next_key())
         if self.quarantine:
             pvec = self._zero_poison if poison is None else \
-                jnp.asarray(poison, jnp.float32)
+                np.asarray(poison, np.float32)
             self.cache, toks, counts, finite = self.verify_compiled(
                 *args, pvec)
             self.last_finite = np.asarray(finite)
@@ -393,7 +405,7 @@ class ServingEngine:
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {self.max_seqs})")
         self.cache = self.release_compiled(self.cache,
-                                           jnp.asarray(slot, jnp.int32))
+                                           np.asarray(slot, np.int32))
 
     # -- hot weight swap ----------------------------------------------------
 
@@ -615,8 +627,7 @@ class PagedServingEngine(ServingEngine):
                                         tokens, temperature, block_ids,
                                         offsets, cow_src, cow_dst, rng)
 
-        key = jax.random.PRNGKey(rng_seed)
-        self._key, _ = jax.random.split(key)
+        self._init_key(rng_seed)
         S = self.max_seqs
         ex_tokens = jnp.zeros((1, self.prefill_len), jnp.int32)
         ex_row = jnp.zeros((self.prefill_blocks,), jnp.int32)
@@ -773,9 +784,9 @@ class PagedServingEngine(ServingEngine):
         if plan.prefill:
             self.cache, tok = self.prefill_compiled(
                 self.params, self.cache, self.pad_prompt(prompt),
-                jnp.asarray(np.asarray(plan.block_row, np.int32)),
-                jnp.asarray(len(prompt), jnp.int32),
-                jnp.asarray(temperature, jnp.float32), self._next_key())
+                np.asarray(plan.block_row, np.int32),
+                np.asarray(len(prompt), np.int32),
+                np.asarray(temperature, np.float32), self._next_key())
             # index the freshly written full blocks so LATER admissions
             # can share them
             self.allocator.register_prefix(slot, prompt)
@@ -816,16 +827,16 @@ class PagedServingEngine(ServingEngine):
         ok[step.failed] = False
         block_ids, offsets = self.allocator.append_targets(ok)
         args = (self.params, self.cache,
-                jnp.asarray(self.allocator.tables),
-                jnp.asarray(self.allocator.lengths),
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(temperatures, jnp.float32),
-                jnp.asarray(block_ids), jnp.asarray(offsets),
-                jnp.asarray(step.cow_src), jnp.asarray(step.cow_dst),
+                np.asarray(self.allocator.tables),
+                np.asarray(self.allocator.lengths),
+                np.asarray(tokens, np.int32),
+                np.asarray(temperatures, np.float32),
+                np.asarray(block_ids), np.asarray(offsets),
+                np.asarray(step.cow_src), np.asarray(step.cow_dst),
                 self._next_key())
         if self.quarantine:
             pvec = self._zero_poison if poison is None else \
-                jnp.asarray(poison, jnp.float32)
+                np.asarray(poison, np.float32)
             self.cache, toks, finite = self.decode_compiled(*args, pvec)
             self.last_finite = np.asarray(finite)
         else:
@@ -872,16 +883,16 @@ class PagedServingEngine(ServingEngine):
             [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
              drafts], axis=1)
         args = (self.params, self.cache,
-                jnp.asarray(self.allocator.tables),
-                jnp.asarray(self.allocator.lengths),
-                jnp.asarray(tok_mat), jnp.asarray(drafts),
-                jnp.asarray(temperatures, jnp.float32),
-                jnp.asarray(ok), jnp.asarray(block_ids),
-                jnp.asarray(offsets), jnp.asarray(step.cow_src),
-                jnp.asarray(step.cow_dst), self._next_key())
+                np.asarray(self.allocator.tables),
+                np.asarray(self.allocator.lengths),
+                np.asarray(tok_mat), np.asarray(drafts),
+                np.asarray(temperatures, np.float32),
+                np.asarray(ok), np.asarray(block_ids),
+                np.asarray(offsets), np.asarray(step.cow_src),
+                np.asarray(step.cow_dst), self._next_key())
         if self.quarantine:
             pvec = self._zero_poison if poison is None else \
-                jnp.asarray(poison, jnp.float32)
+                np.asarray(poison, np.float32)
             self.cache, toks, counts, finite = self.verify_compiled(
                 *args, pvec)
             self.last_finite = np.asarray(finite)
