@@ -80,10 +80,7 @@ def iter_eqns(jaxpr):
 
 def sub_jaxprs(value):
     """Yield every (open) jaxpr reachable from one eqn param value."""
-    try:  # the classes moved out of jax.core on the current-jax line
-        from jax.extend.core import ClosedJaxpr, Jaxpr
-    except ImportError:  # pragma: no cover - early 0.4.x
-        from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, Jaxpr):

@@ -545,7 +545,7 @@ def _selfcheck_collectives():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from apex_tpu.utils.compat import shard_map_unchecked
+    from jax import shard_map
 
     mesh = _one_axis_mesh("data")
 
@@ -557,7 +557,7 @@ def _selfcheck_collectives():
                 with jax.named_scope("optimizer_step"):
                     return sync(g)
             return sync(g)
-        return shard_map_unchecked(f, mesh=mesh, in_specs=P(),
+        return shard_map(f, mesh=mesh, in_specs=P(),
                                    out_specs=P("data"))
 
     g = jnp.arange(8.0)
@@ -593,7 +593,7 @@ def _selfcheck_shared_grad():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from apex_tpu.utils.compat import shard_map_unchecked
+    from jax import shard_map
 
     mesh = _one_axis_mesh("pipe")
 
@@ -605,7 +605,7 @@ def _selfcheck_shared_grad():
             if reduced:
                 g = jax.lax.psum(g, "pipe")
             return g
-        return shard_map_unchecked(f, mesh=mesh, in_specs=(P(), P()),
+        return shard_map(f, mesh=mesh, in_specs=(P(), P()),
                                    out_specs=P())
 
     s, x = jnp.arange(4.0), jnp.ones(4)

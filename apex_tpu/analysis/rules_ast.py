@@ -135,11 +135,9 @@ def rule_annotations(repo: str) -> Findings:
 # ast-collectives: gathers/grad-syncs stay behind their chokepoints
 # ---------------------------------------------------------------------------
 
-# the only modules allowed to touch lax.all_gather directly: the VMA shims
-# themselves and the version-compat layer
+# the only module allowed to touch lax.all_gather directly: the VMA shims
 ALLOWED_GATHER = {
     _p("apex_tpu", "utils", "vma.py"),
-    _p("apex_tpu", "utils", "compat.py"),
 }
 
 # lax.psum_scatter: the grad-sync chokepoint (reduce_scatter_grads), plus

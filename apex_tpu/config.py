@@ -71,9 +71,7 @@ class ModelConfig:
     remat: bool = False
     remat_policy: Optional[str] = None
     remat_names: Optional[Tuple[str, ...]] = None
-    # Megatron-LM sequence parallelism (gpt only; needs tp > 1, pp == 1;
-    # through GPTHybridTrainer additionally needs VMA jax — the trainer
-    # refuses on the pre-VMA 0.4.x line, see training.py)
+    # Megatron-LM sequence parallelism (gpt only; needs tp > 1, pp == 1)
     sequence_parallel: bool = False
     # ring-decomposed SP collectives overlapping their GEMMs (gpt only;
     # needs sequence_parallel — see tensor_parallel.collective_matmul)
@@ -196,10 +194,8 @@ class TrainConfig:
           only the cheap LN/gelu tier recomputed (apex_tpu/remat.py);
         - ``sequence_parallel`` + ``tp_comm_overlap`` — ring-decomposed
           TP collectives riding under their GEMMs — when the mesh can
-          carry them: ``tp > 1``, ``pp == 1`` (the SP head/stage
-          contract) and VMA jax (``GPTHybridTrainer`` refuses SP on the
-          pre-VMA 0.4.x line; the preset degrades to plain TP there
-          rather than constructing a trainer that would refuse).
+          carry them: ``tp > 1`` and ``pp == 1`` (the SP head/stage
+          contract).
 
         Donation is the trainer-call half of the preset —
         ``jit_train_step(donate=True)`` is already the default. Returns
@@ -207,7 +203,6 @@ class TrainConfig:
         SP/overlap or remat settings on the receiver are kept as-is.
         Raises for optimizers with no ZeRO variant (sgd/novograd/...).
         """
-        from apex_tpu.utils.compat import HAS_VMA
         if not _zero_enabled(self.optimizer.zero) \
                 and self.optimizer.name not in ZERO_CAPABLE_OPTIMIZERS:
             raise ValueError(
@@ -216,7 +211,7 @@ class TrainConfig:
                 f"{self.optimizer.name!r}")
         tp = self.parallel.tensor_model_parallel_size
         pp = self.parallel.pipeline_model_parallel_size
-        sp_ok = tp > 1 and pp == 1 and HAS_VMA
+        sp_ok = tp > 1 and pp == 1
         # the deprecated remat=True spelling means "full" (ModelConfig
         # docs) — a receiver that asked for full remat keeps it; only a
         # genuinely-unset policy defaults to selective

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.utils.backend import pallas_interpret
+
 __all__ = ["ln_fwd", "ln_bwd", "supports_pallas"]
 
 _VMEM_BUDGET = 8 * 1024 * 1024  # conservative half of ~16MB VMEM
@@ -197,7 +199,7 @@ def ln_fwd(x2d: jnp.ndarray, weight: Optional[jnp.ndarray],
     return pl.pallas_call(
         kernel,
         grid=(n // br,),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
         in_specs=in_specs,
         out_specs=(row_spec, stat_spec, stat_spec),
         out_shape=(
@@ -254,7 +256,7 @@ def ln_bwd(dy2d: jnp.ndarray, x2d: jnp.ndarray, mean: jnp.ndarray,
         kernel, grid=(grid_n,),
         in_specs=in_specs, out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(*args)
     if not isinstance(res, (tuple, list)):
         res = (res,)

@@ -42,23 +42,39 @@ PEAK_BF16_FLOPS = {
     "TPU v6e": 918e12,
 }
 
-# assume v5e-class when the device kind is unknown (CPU test hosts, new
-# chips the table has not learned yet) — conservative for MFU claims
+# v5e-class corners for the CPU backend ONLY: there the modeled numbers
+# are structure (regions, ratios, byte counts), never a device metric.
+# An accelerator whose kind the tables do not know is an error — a
+# utilization against an assumed peak would read as a measurement.
 DEFAULT_PEAK_FLOPS = 197e12
+
+
+def _match_kind(table, device, cpu_default):
+    """``table`` entry whose key prefixes ``device.device_kind`` (default
+    device: the first visible one); ``cpu_default`` for a CPU device,
+    ``ValueError`` for any other kind the table has not learned."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    kind = getattr(device, "device_kind", "")
+    for prefix, value in table.items():
+        if kind.startswith(prefix):
+            return value
+    if getattr(device, "platform", kind) == "cpu":
+        return cpu_default
+    raise ValueError(
+        f"no peak numbers for device kind {kind!r}: add it to "
+        "PEAK_BF16_FLOPS / DEVICE_SPECS in "
+        "apex_tpu/observability/costs.py with its spec-sheet source "
+        f"(known: {sorted(table)})")
 
 
 def peak_flops(device=None) -> float:
     """Peak dense bf16 FLOP/s of ``device`` (default: the first visible
     device), matched by ``device_kind`` prefix against
-    :data:`PEAK_BF16_FLOPS`; :data:`DEFAULT_PEAK_FLOPS` when unknown."""
-    if device is None:
-        import jax
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "")
-    for prefix, value in PEAK_BF16_FLOPS.items():
-        if kind.startswith(prefix):
-            return value
-    return DEFAULT_PEAK_FLOPS
+    :data:`PEAK_BF16_FLOPS`. A CPU device gets the labelled
+    :data:`DEFAULT_PEAK_FLOPS`; an unknown accelerator kind raises."""
+    return _match_kind(PEAK_BF16_FLOPS, device, DEFAULT_PEAK_FLOPS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,9 +100,9 @@ class DeviceSpec:
 # HBM/ICI companions to PEAK_BF16_FLOPS (public spec-sheet numbers; ICI
 # is per link per direction — the ring models in pyprof serialize hops
 # over one link, the worst-case topology). Env-overridable via
-# APEX_TPU_PEAK_FLOPS / APEX_TPU_HBM_GBPS / APEX_TPU_ICI_GBPS, the escape
-# hatch for chips the table has not learned yet (and for calibrating the
-# roofline against a measured bandwidth instead of the datasheet).
+# APEX_TPU_PEAK_FLOPS / APEX_TPU_HBM_GBPS / APEX_TPU_ICI_GBPS, for
+# calibrating the roofline against a measured bandwidth instead of the
+# datasheet.
 DEVICE_SPECS = {
     "TPU v4": DeviceSpec("TPU v4", PEAK_BF16_FLOPS["TPU v4"], 1228.0, 50.0),
     "TPU v5 lite": DeviceSpec("TPU v5e", PEAK_BF16_FLOPS["TPU v5e"],
@@ -103,29 +119,21 @@ DEVICE_SPECS = {
                           1640.0, 100.0),
 }
 
-# CPU test hosts and unknown chips: v5e-class corners, same rationale as
-# DEFAULT_PEAK_FLOPS (conservative for utilization claims; on CPU the
-# modeled milliseconds are structural, not predictive — the regions,
-# ratios and byte counts are what the tests pin down)
-DEFAULT_DEVICE_SPEC = DeviceSpec("unknown (v5e-class assumed)",
+# the CPU backend's stand-in (see DEFAULT_PEAK_FLOPS): on CPU the modeled
+# milliseconds are structural, not predictive — the regions, ratios and
+# byte counts are what the tests pin down
+DEFAULT_DEVICE_SPEC = DeviceSpec("cpu (v5e-class corners assumed)",
                                  DEFAULT_PEAK_FLOPS, 819.0, 50.0)
 
 
 def device_spec(device=None) -> DeviceSpec:
     """The :class:`DeviceSpec` of ``device`` (default: first visible
-    device), matched by ``device_kind`` prefix; falls back to
-    :data:`DEFAULT_DEVICE_SPEC`. ``APEX_TPU_PEAK_FLOPS`` (FLOP/s),
-    ``APEX_TPU_HBM_GBPS`` and ``APEX_TPU_ICI_GBPS`` (GB/s) override the
-    matched table entry field-by-field."""
-    if device is None:
-        import jax
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "")
-    spec = DEFAULT_DEVICE_SPEC
-    for prefix, value in DEVICE_SPECS.items():
-        if kind.startswith(prefix):
-            spec = value
-            break
+    device), matched by ``device_kind`` prefix; a CPU device gets
+    :data:`DEFAULT_DEVICE_SPEC`, an unknown accelerator kind raises.
+    ``APEX_TPU_PEAK_FLOPS`` (FLOP/s), ``APEX_TPU_HBM_GBPS`` and
+    ``APEX_TPU_ICI_GBPS`` (GB/s) override the matched table entry
+    field-by-field."""
+    spec = _match_kind(DEVICE_SPECS, device, DEFAULT_DEVICE_SPEC)
     overrides = {}
     for env, field in (("APEX_TPU_PEAK_FLOPS", "peak_flops"),
                        ("APEX_TPU_HBM_GBPS", "hbm_gbps"),

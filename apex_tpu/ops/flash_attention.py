@@ -54,6 +54,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.utils.backend import pallas_interpret as _interp
+
 __all__ = ["flash_attention", "mha_reference", "supports_flash",
            "dropout_keep_mask", "decode_attention", "supports_paged",
            "paged_decode_attention"]
@@ -447,9 +449,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
 # ---------------------------------------------------------------------------
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
-
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _sds(shape, dtype, like):
@@ -1376,13 +1375,15 @@ def decode_attention(q, k, v, lengths, k_new=None, v_new=None,
 #   changes the math — only the modeled bytes.
 
 def supports_paged(block_size: int, d: int) -> bool:
-    """Pallas eligibility for the paged decode kernel: lane-aligned
-    blocks on real TPUs; anything goes under interpret mode (the CPU
-    CI path — alignment is a hardware tiling constraint, not a
-    correctness one)."""
-    if _interp():
-        return block_size >= 1 and d >= 1
-    return block_size % 128 == 0 and d % 8 == 0
+    """Pallas eligibility for the paged decode kernel — the same answer
+    on every backend. Every block of the kernel spans its array's last
+    two dims whole (``(block_size, d)`` pool blocks, ``(q_len, d)``
+    query rows, ``(1, block_size)`` scale rows), which Mosaic accepts at
+    any size (``tests/test_chip_compile.py`` compiles the serving shape;
+    block sizes 2..64 x head dims 8..64 were compiled for v5e when the
+    rank-3 blocks were repaired). Small blocks are legal, not fast:
+    ``block_size % 128 == 0`` keeps the score row lane-dense."""
+    return block_size >= 1 and d >= 1
 
 
 def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, ksc_ref,
@@ -1584,8 +1585,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 
     Returns ``(b, h, d)`` in ``q.dtype``.
 
-    Falls back to a gather-then-reference XLA path (same math, priced
-    O(table span)) when the pool isn't tile-aligned for Pallas.
+    ``use_pallas=False`` selects a gather-then-reference XLA path (same
+    math, priced O(table span)) — the parity oracle, never auto-selected.
     """
     multi = q.ndim == 4
     if multi:
@@ -1610,8 +1611,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     elif use_pallas and not supports_paged(block_size, d):
         raise ValueError(
             f"use_pallas=True but block_size {block_size} / head_dim {d} "
-            "are not tile-aligned for the paged kernel; resize the pool "
-            "or let use_pallas auto-select the XLA fallback")
+            "is not a shape the paged kernel takes")
     block_tables = jnp.asarray(block_tables).astype(jnp.int32)
     lengths = jnp.asarray(lengths).astype(jnp.int32)
 

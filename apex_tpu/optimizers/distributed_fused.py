@@ -66,7 +66,7 @@ from apex_tpu.optimizers._flatten import (FlatLayout, bucket_bounds,
                                           build_layout, ravel,
                                           ravel_span, segment_ids,
                                           unravel_parts)
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 __all__ = ["DistributedFusedAdam", "DistributedFusedLAMB",
            "ZeroAdamState", "ZeroLambState"]

@@ -49,7 +49,7 @@ import numpy as np
 from apex_tpu.observability import health as _health
 from apex_tpu.observability import ingraph as _metrics
 from apex_tpu.utils.vma import cast_to_vma, leaf_vma
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 __all__ = ["allreduce_grads", "DistributedDataParallel", "Reducer",
            "grouped_psum", "reduce_scatter_grads", "DEFAULT_BUCKET_BYTES"]

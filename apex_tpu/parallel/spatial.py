@@ -20,7 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 __all__ = ["halo_exchange", "spatial_conv2d"]
 

@@ -35,7 +35,7 @@ ROADMAP's cache-capacity accounting.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +48,16 @@ from apex_tpu.serving.cache import (KVCache, PagedKVCache, BlockAllocator,
 from apex_tpu.serving.sampling import sample_tokens, verify_tokens
 
 __all__ = ["ServingEngine", "PagedServingEngine"]
+
+
+def _host(x, dtype=None) -> np.ndarray:
+    """A host value marshalled for an AOT program: an engine-OWNED numpy
+    copy. Owned, because dispatch is asynchronous and the caller (a
+    scheduler reusing its token buffer, the allocator advancing its
+    tables) mutates the source in place right after the call; numpy,
+    because ``jnp.asarray`` would dispatch an eager jitted op per
+    argument inside the steady-state loop."""
+    return np.array(x, dtype)
 
 
 class ServingEngine:
@@ -303,9 +313,9 @@ class ServingEngine:
                              f"[0, {self.max_seqs})")
         self.cache, tok = self.prefill_compiled(
             self.params, self.cache, self.pad_prompt(prompt),
-            np.asarray(slot, np.int32),
-            np.asarray(len(prompt), np.int32),
-            np.asarray(temperature, np.float32), self._next_key())
+            _host(slot, np.int32),
+            _host(len(prompt), np.int32),
+            _host(temperature, np.float32), self._next_key())
         return int(tok)
 
     def decode(self, tokens: np.ndarray, temperatures: np.ndarray,
@@ -327,12 +337,12 @@ class ServingEngine:
         if active is None:
             active = np.ones(self.max_seqs, np.bool_)
         args = (self.params, self.cache,
-                np.asarray(tokens, np.int32),
-                np.asarray(temperatures, np.float32),
-                np.asarray(active, np.bool_), self._next_key())
+                _host(tokens, np.int32),
+                _host(temperatures, np.float32),
+                _host(active, np.bool_), self._next_key())
         if self.quarantine:
             pvec = self._zero_poison if poison is None else \
-                np.asarray(poison, np.float32)
+                _host(poison, np.float32)
             self.cache, toks, finite = self.decode_compiled(*args, pvec)
             self.last_finite = np.asarray(finite)
         else:
@@ -374,13 +384,13 @@ class ServingEngine:
         tok_mat = np.concatenate(
             [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
              drafts], axis=1)
-        args = (self.params, self.cache, np.asarray(tok_mat),
-                np.asarray(drafts),
-                np.asarray(temperatures, np.float32),
-                np.asarray(active, np.bool_), self._next_key())
+        args = (self.params, self.cache, _host(tok_mat),
+                _host(drafts),
+                _host(temperatures, np.float32),
+                _host(active, np.bool_), self._next_key())
         if self.quarantine:
             pvec = self._zero_poison if poison is None else \
-                np.asarray(poison, np.float32)
+                _host(poison, np.float32)
             self.cache, toks, counts, finite = self.verify_compiled(
                 *args, pvec)
             self.last_finite = np.asarray(finite)
@@ -405,7 +415,7 @@ class ServingEngine:
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {self.max_seqs})")
         self.cache = self.release_compiled(self.cache,
-                                           np.asarray(slot, np.int32))
+                                           _host(slot, np.int32))
 
     # -- hot weight swap ----------------------------------------------------
 
@@ -457,6 +467,25 @@ class ServingEngine:
                                                    verify_findings)
             verify_findings(lint_serving_engine(self),
                             "ServingEngine.swap_params")
+
+    # -- what the compiler was handed ----------------------------------------
+
+    def attention_paths(self) -> Dict[str, str]:
+        """Which attention path each AOT program took, read off its
+        compiled text: ``"pallas"`` when the program holds a Mosaic
+        kernel (``tpu_custom_call``), ``"xla"`` when attention lowered
+        to plain XLA ops (``use_flash=False``, or a shape the
+        ``use_pallas=None`` gate sent to the reference path). Keys:
+        ``prefill``, ``decode`` and, on a speculative engine,
+        ``verify``. Meaningful on the TPU backend — on CPU the kernels
+        run interpreted, which inlines them as plain ops, so every
+        program reads ``"xla"`` there."""
+        programs = {"prefill": self.prefill_compiled,
+                    "decode": self.decode_compiled}
+        if self.verify_compiled is not None:
+            programs["verify"] = self.verify_compiled
+        return {name: "pallas" if "tpu_custom_call" in prog.as_text()
+                else "xla" for name, prog in programs.items()}
 
     # -- capacity -----------------------------------------------------------
 
@@ -516,9 +545,9 @@ class PagedServingEngine(ServingEngine):
       num_blocks: global pool size in blocks (block 0 is the reserved
         null block — allocatable capacity is ``num_blocks - 1``). Size
         with :meth:`suggest_pool_blocks`.
-      block_size: tokens per block. On TPU the paged Pallas kernel
-        wants ``block_size % 128 == 0``; any size works via the XLA
-        fallback (and under interpret mode on CPU).
+      block_size: tokens per block. The paged Pallas kernel takes any
+        size on every backend; ``block_size % 128 == 0`` keeps its
+        score rows lane-dense on TPU.
       prefix_suffix_cap: longest un-shared prompt TAIL (tokens) worth
         serving through per-token decode steps on a prefix hit; a hit
         whose tail is longer falls back to the cold full prefill
@@ -784,9 +813,9 @@ class PagedServingEngine(ServingEngine):
         if plan.prefill:
             self.cache, tok = self.prefill_compiled(
                 self.params, self.cache, self.pad_prompt(prompt),
-                np.asarray(plan.block_row, np.int32),
-                np.asarray(len(prompt), np.int32),
-                np.asarray(temperature, np.float32), self._next_key())
+                _host(plan.block_row, np.int32),
+                _host(len(prompt), np.int32),
+                _host(temperature, np.float32), self._next_key())
             # index the freshly written full blocks so LATER admissions
             # can share them
             self.allocator.register_prefix(slot, prompt)
@@ -827,16 +856,16 @@ class PagedServingEngine(ServingEngine):
         ok[step.failed] = False
         block_ids, offsets = self.allocator.append_targets(ok)
         args = (self.params, self.cache,
-                np.asarray(self.allocator.tables),
-                np.asarray(self.allocator.lengths),
-                np.asarray(tokens, np.int32),
-                np.asarray(temperatures, np.float32),
-                np.asarray(block_ids), np.asarray(offsets),
-                np.asarray(step.cow_src), np.asarray(step.cow_dst),
+                _host(self.allocator.tables),
+                _host(self.allocator.lengths),
+                _host(tokens, np.int32),
+                _host(temperatures, np.float32),
+                _host(block_ids), _host(offsets),
+                _host(step.cow_src), _host(step.cow_dst),
                 self._next_key())
         if self.quarantine:
             pvec = self._zero_poison if poison is None else \
-                np.asarray(poison, np.float32)
+                _host(poison, np.float32)
             self.cache, toks, finite = self.decode_compiled(*args, pvec)
             self.last_finite = np.asarray(finite)
         else:
@@ -883,16 +912,16 @@ class PagedServingEngine(ServingEngine):
             [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
              drafts], axis=1)
         args = (self.params, self.cache,
-                np.asarray(self.allocator.tables),
-                np.asarray(self.allocator.lengths),
-                np.asarray(tok_mat), np.asarray(drafts),
-                np.asarray(temperatures, np.float32),
-                np.asarray(ok), np.asarray(block_ids),
-                np.asarray(offsets), np.asarray(step.cow_src),
-                np.asarray(step.cow_dst), self._next_key())
+                _host(self.allocator.tables),
+                _host(self.allocator.lengths),
+                _host(tok_mat), _host(drafts),
+                _host(temperatures, np.float32),
+                _host(ok), _host(block_ids),
+                _host(offsets), _host(step.cow_src),
+                _host(step.cow_dst), self._next_key())
         if self.quarantine:
             pvec = self._zero_poison if poison is None else \
-                np.asarray(poison, np.float32)
+                _host(poison, np.float32)
             self.cache, toks, counts, finite = self.verify_compiled(
                 *args, pvec)
             self.last_finite = np.asarray(finite)

@@ -27,8 +27,6 @@ from apex_tpu.parallel.distributed import allreduce_grads
 from apex_tpu.transformer.amp import GradScaler
 from apex_tpu.transformer.pipeline_parallel import (
     forward_backward_pipelining_without_interleaving)
-from apex_tpu.utils.compat import (HAS_VMA, shard_map_unchecked,
-                                   axis_size as _compat_axis_size)
 from apex_tpu.utils.vma import cast_to_vma, scan_stable_vma
 
 __all__ = ["GPTHybridTrainer", "accumulate_gradients",
@@ -145,7 +143,7 @@ def accumulate_gradients(ddp, loss_fn, params, microbatches):
             "accumulate_gradients got an empty accumulation window "
             "(num_micro == 0); every microbatch leaf has leading dim 0")
     try:
-        _compat_axis_size(ddp.axis_name)
+        jax.lax.axis_size(ddp.axis_name)
     except Exception as e:
         # axis_size raises (NameError on most jax lines) when the name is
         # unbound; surface a trace-placement error, not a deep psum failure
@@ -226,23 +224,6 @@ class GPTHybridTrainer:
         # (StepReporter.attach_memory_budget makes the policy's HBM trade
         # measurable as mem/* gauges).
         self.remat_policy = getattr(self.model, "remat_policy", None)
-        if (getattr(self.model.cfg, "sequence_parallel", False)
-                and not HAS_VMA):
-            # The step runs under shard_map_unchecked, which relaxes
-            # check_rep on pre-VMA 0.4.x — and with neither the VMA
-            # replication rewrite nor the 0.4.x check_rep rewrite active,
-            # the SP-split computation hands tensor-replicated params
-            # (LNs, position embedding) and the SP boundary activations
-            # per-rank PARTIAL cotangents: the loss is exact but the
-            # gradients are silently wrong (the degradation class
-            # documented in utils/compat.py). Refuse loudly instead.
-            raise NotImplementedError(
-                "sequence_parallel through GPTHybridTrainer requires "
-                "VMA jax (the replication rewrite that supplies the "
-                "tensor-axis psums of replicated-param cotangents); this "
-                f"jax {jax.__version__} would train on silently wrong "
-                "LN/position-embedding grads. Use the model-level SP path "
-                "(plain shard_map, full checking) on this jax, or upgrade.")
         self.opt = cfg.build_optimizer()
         # ZeRO (OptimizerConfig.zero): DistributedFused* shards optimizer
         # state 1/dp over the data axis — its init/step run inside the
@@ -265,7 +246,7 @@ class GPTHybridTrainer:
             def init_inner(stage_stack, shared):
                 return opt.init((stage_stack, shared))
 
-            opt_state = jax.jit(shard_map_unchecked(
+            opt_state = jax.jit(jax.shard_map(
                 init_inner, mesh=self.mesh,
                 in_specs=(sspec, self.shared_specs),
                 out_specs=self._zero_state_spec()))(stage_stack, shared)
@@ -534,7 +515,7 @@ class GPTHybridTrainer:
         out_specs = (P(), sspec, shspec, ospec, lspec)
         if with_metrics:
             out_specs = out_specs + (P(),)
-        return shard_map_unchecked(
+        return jax.shard_map(
             inner, mesh=self.mesh,
             in_specs=(sspec, shspec, ospec, lspec,
                       P(None, "data"), P(None, "data")),

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from apex_tpu.ops.flash_attention import NEG_INF
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 __all__ = ["ring_attention", "ulysses_attention",
            "scatter_to_sequence_parallel_region",

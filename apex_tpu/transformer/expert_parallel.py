@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 from apex_tpu.transformer.tensor_parallel.layers import init_method_normal
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 __all__ = ["ExpertParallelMLP"]
 

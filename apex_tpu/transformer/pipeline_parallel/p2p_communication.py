@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.transformer.parallel_state import PIPE_AXIS
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 __all__ = [
     "rotate_forward", "rotate_backward",

@@ -50,8 +50,7 @@ from apex_tpu.transformer.parallel_state import PIPE_AXIS
 from apex_tpu.utils.vma import cast_to_vma
 from apex_tpu.transformer.pipeline_parallel.p2p_communication import (
     rotate_backward, rotate_forward)
-from apex_tpu.utils.compat import HAS_VMA
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 
 def _record_schedule_metrics(num_microbatches: int, ticks: int,
@@ -558,22 +557,10 @@ def _onef1b_fwd_bwd(stage_fn, loss_fn, params, microbatches, remat,
     # accumulates the replicated total. If a carry cast left the
     # accumulator pipe-varying-TYPED, psum/S restores the invariant type
     # without double counting the S identical copies.
-    #
-    # Pre-VMA jax has NO reconciliation (shard_map_unchecked, no
-    # replication rewrite): each rank holds only its own DISJOINT partial
-    # cotangent — the embedding's on global stage 0, the tied head's on
-    # the last stage, zeros between — so the partials must be summed
-    # explicitly. Without this every pipe rank Adam-steps the nominally
-    # replicated shared params with a DIFFERENT gradient and the replicas
-    # silently drift apart ~2*lr/step (caught by the elastic
-    # bitwise-resume legs: a checkpoint restore collapses replicas to
-    # shard 0, changing the training trajectory).
     def _finalize_shared(g):
         g = g * inv_scale
         if PIPE_AXIS in _leaf_vma(g):
             g = jax.lax.psum(g, PIPE_AXIS) / S
-        elif not HAS_VMA:
-            g = jax.lax.psum(g, PIPE_AXIS)
         return g
 
     shared_grads = jax.tree_util.tree_map(_finalize_shared, acc_sg)
@@ -647,16 +634,6 @@ def _pipelined_fwd_bwd(stage_fn, loss_fn, stage_params, microbatches,
         lambda p: total_loss(p) * grad_scale)(diff_params)
     grads = jax.tree_util.tree_map(
         lambda g: (g / grad_scale).astype(jnp.float32), grads)
-    if shared_params is not None and not HAS_VMA:
-        # pre-VMA jax: AD inserts no cross-stage psum for the replicated
-        # shared params (no replication rewrite under
-        # shard_map_unchecked), so each rank's shared grads are its own
-        # disjoint partial (embed on stage 0, masked head on the last) —
-        # sum them explicitly, same contract as _finalize_shared above
-        sg, shg = grads
-        shg = jax.tree_util.tree_map(
-            lambda g: jax.lax.psum(g, PIPE_AXIS), shg)
-        grads = (sg, shg)
     return loss / grad_scale, grads
 
 

@@ -68,7 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from apex_tpu.utils.vma import cast_to_vma, reconcile_cotangent
 
 __all__ = ["all_gather_matmul", "matmul_reduce_scatter"]

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
-from apex_tpu.utils.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 __all__ = [
     "copy_to_tensor_model_parallel_region",

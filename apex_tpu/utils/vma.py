@@ -15,8 +15,6 @@ from typing import Any, Callable, Iterable
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.utils.compat import HAS_VMA
-from apex_tpu.utils.compat import axis_size as _axis_size
 
 __all__ = ["cast_to_vma", "scan_stable_vma", "invariant_all_gather",
            "varying_all_gather",
@@ -25,10 +23,8 @@ __all__ = ["cast_to_vma", "scan_stable_vma", "invariant_all_gather",
 
 
 def leaf_vma(x) -> frozenset:
-    """The varying-manual-axes set of a value (empty outside shard_map,
-    and on pre-VMA jax where there is no replication typing at all)."""
-    if not HAS_VMA:
-        return frozenset()
+    """The varying-manual-axes set of a value (empty outside
+    shard_map)."""
     return getattr(jax.typeof(x), "vma", None) or frozenset()
 
 
@@ -62,8 +58,6 @@ def reconcile_cotangent(ct: jnp.ndarray, primal: jnp.ndarray) -> jnp.ndarray:
     the cotangent lacks are pvaried (type-only, value-preserving). No-op
     when the types already agree.
     """
-    if not HAS_VMA:
-        return ct
     ct_vma = leaf_vma(ct)
     p_vma = leaf_vma(primal)
     extra = tuple(sorted(ct_vma - p_vma))
@@ -76,11 +70,9 @@ def reconcile_cotangent(ct: jnp.ndarray, primal: jnp.ndarray) -> jnp.ndarray:
 
 
 def cast_to_vma(x: jnp.ndarray, vma: frozenset) -> jnp.ndarray:
-    """Upcast ``x`` to be device-varying over at least ``vma`` (idempotent;
-    a no-op on pre-VMA jax, whose shard_map has no replication types)."""
-    if not HAS_VMA:
-        return x
-    cur = getattr(jax.typeof(x), "vma", frozenset())
+    """Upcast ``x`` to be device-varying over at least ``vma``
+    (idempotent)."""
+    cur = leaf_vma(x)
     missing = tuple(a for a in vma if a not in cur)
     if missing:
         x = jax.lax.pcast(x, missing, to="varying")
@@ -137,13 +129,12 @@ def varying_all_gather(x: jnp.ndarray, axis_name: str, axis: int = 0,
     """``lax.all_gather`` with the input pre-cast device-varying — the
     library's single raw-gather chokepoint.
 
-    On VMA jax a replicated-typed value cannot feed ``all_gather`` directly
-    (the op demands a varying operand); on pre-VMA 0.4.x the cast is an
-    identity and this is a plain ``all_gather``. Every gather outside this
-    module must route here (or through :func:`invariant_all_gather`) so the
-    version shim lives in exactly one place —
-    ``scripts/check_collectives.py`` (wired into the test suite) flags raw
-    ``lax.all_gather`` call sites anywhere else.
+    A replicated-typed value cannot feed ``all_gather`` directly (the op
+    demands a varying operand). Every gather outside this module must
+    route here (or through :func:`invariant_all_gather`) so the cast
+    lives in exactly one place — ``scripts/check_collectives.py`` (wired
+    into the test suite) flags raw ``lax.all_gather`` call sites anywhere
+    else.
     """
     return jax.lax.all_gather(cast_to_vma(x, frozenset({axis_name})),
                               axis_name, axis=axis, tiled=tiled)
@@ -154,21 +145,8 @@ def invariant_all_gather(x: jnp.ndarray, axis_name: str, axis: int = 0
     """Tiled all-gather typed device-INVARIANT: every rank contributes a
     disjoint slice, so the gathered value is provably replicated and can
     cross ``P()`` out_specs / keep replicated-param AD semantics (a plain
-    ``all_gather``'s varying type cannot). Wraps the private
-    ``jax._src.lax.parallel.all_gather_invariant`` with an equivalent —
-    slower, O(world x size) traffic — public-API fallback: place the slice
-    at its offset in zeros and psum (disjoint one-hot sum). Shared by the
+    ``all_gather``'s varying type cannot). ``all_gather_invariant`` has no
+    public spelling yet, hence the ``jax._src`` import. Shared by the
     ZeRO param gather and the sequence-parallel gathers."""
-    try:
-        from jax._src.lax.parallel import all_gather_invariant
-    except ImportError:  # pragma: no cover - private symbol moved
-        size = _axis_size(axis_name)
-        rank = jax.lax.axis_index(axis_name)
-        full = list(x.shape)
-        full[axis] *= size
-        return jax.lax.psum(
-            jax.lax.dynamic_update_slice_in_dim(
-                jnp.zeros(full, x.dtype), x, rank * x.shape[axis],
-                axis=axis),
-            axis_name)
+    from jax._src.lax.parallel import all_gather_invariant
     return all_gather_invariant(x, axis_name, axis=axis, tiled=True)

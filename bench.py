@@ -677,21 +677,19 @@ def bench_gpt_fast(iters=10, warmup=2, mb=8, seq=1024, max_devices=None):
     deltas are the remat bench's job)."""
     from apex_tpu.training import GPTHybridTrainer
     from apex_tpu.transformer import parallel_state
-    from apex_tpu.utils.compat import HAS_VMA
 
     if jax.device_count() < 2:
         _emit("gpt_fast_tokens_per_sec", -1.0, "skipped", None,
               error=f"needs >= 2 devices, have {jax.device_count()}")
         return
 
-    # tp=2 only where the trainer can carry SP overlap (VMA jax) and a
-    # data axis remains; otherwise all devices go to dp. ``max_devices``
+    # tp=2 only where a data axis remains beside it; otherwise all devices go to dp. ``max_devices``
     # caps the mesh (the tier-1 smoke test runs this leg on 2 of the 8
     # virtual devices — compile cost scales with mesh width on CPU)
     n_dev = jax.device_count()
     if max_devices is not None:
         n_dev = min(n_dev, int(max_devices))
-    tp = 2 if (HAS_VMA and n_dev % 2 == 0 and n_dev >= 4) else 1
+    tp = 2 if (n_dev % 2 == 0 and n_dev >= 4) else 1
     dp, M = n_dev // tp, 1
     parallel = {"tensor_model_parallel_size": tp,
                 "pipeline_model_parallel_size": 1}
@@ -761,7 +759,7 @@ def bench_gpt_sp_overlap(iters=10, warmup=2, batch=8, seq=1024,
 
     from apex_tpu.models import GPTConfig, GPTModel
     from apex_tpu.transformer import parallel_state
-    from apex_tpu.utils.compat import shard_map_unchecked
+    from jax import shard_map
 
     if jax.device_count() < 2:
         _emit("gpt_sp_overlap_tokens_per_sec", -1.0, "skipped", None,
@@ -794,10 +792,7 @@ def bench_gpt_sp_overlap(iters=10, warmup=2, batch=8, seq=1024,
                 return new_p, jax.lax.pmean(
                     jax.lax.pmean(loss, "tensor"), "data")
 
-            # 0.4.x check_rep cannot see through jax.vjp inside the
-            # body (compat.shard_map_unchecked docstring); full
-            # checking stays on under VMA jax
-            smapped = shard_map_unchecked(step_inner, mesh=mesh,
+            smapped = shard_map(step_inner, mesh=mesh,
                                 in_specs=(specs, P()),
                                 out_specs=(specs, P()))
 
@@ -856,7 +851,7 @@ def bench_dp_accumulate_overlap(iters=10, warmup=2, K=4, layers=8,
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.parallel import DistributedDataParallel
     from apex_tpu.training import accumulate_gradients
-    from apex_tpu.utils.compat import shard_map_unchecked
+    from jax import shard_map
 
     if jax.device_count() < 2:
         _emit("dp_window_overlap_step_ms", -1.0, "skipped", None,
@@ -894,7 +889,7 @@ def bench_dp_accumulate_overlap(iters=10, warmup=2, K=4, layers=8,
                 return jax.lax.pmean(loss, "data"), new_p, new_s
             pspec = jax.tree_util.tree_map(lambda _: P(), p)
             sspec = jax.tree_util.tree_map(lambda _: P(), s)
-            return shard_map_unchecked(
+            return shard_map(
                 inner, mesh=mesh,
                 in_specs=(pspec, sspec, P(None, "data"), P(None, "data")),
                 out_specs=(P(), pspec, sspec))(p, s, xs, ys)

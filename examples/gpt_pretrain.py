@@ -69,8 +69,7 @@ def main(argv=None, on_metrics=None):
                     help="steps between async checkpoints")
     ap.add_argument("--sequence-parallel", action="store_true",
                     help="Megatron-LM sequence parallelism (tp > 1, "
-                         "pp == 1, VMA jax — the trainer refuses on the "
-                         "pre-VMA 0.4.x line)")
+                         "pp == 1)")
     ap.add_argument("--tp-comm-overlap", action="store_true",
                     help="ring-decomposed SP collectives overlapping "
                          "their GEMMs (implies --sequence-parallel; see "

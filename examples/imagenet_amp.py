@@ -18,7 +18,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from apex_tpu.amp import all_finite, get_policy, make_loss_scale

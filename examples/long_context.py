@@ -17,7 +17,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 import numpy as np
-from apex_tpu.utils.compat import shard_map_unchecked as shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.ops.flash_attention import flash_attention

@@ -11,7 +11,7 @@ on TPU one process drives all devices and "DDP" is the
 import jax
 import jax.numpy as jnp
 import numpy as np
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.optimizers import FusedAdam

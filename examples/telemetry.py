@@ -30,7 +30,7 @@ from apex_tpu.optimizers.fused_sgd import SGDState
 from apex_tpu.parallel.distributed import allreduce_grads
 from apex_tpu.transformer.pipeline_parallel.schedules import (
     forward_backward_pipelining_without_interleaving)
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 from apex_tpu.utils.timers import Timers
 
 

@@ -43,7 +43,7 @@ from apex_tpu.analysis.rules_ast import (ANNOTATIONS, METRIC_PREFIXES,
                                          rule_metric_families,
                                          rule_metrics_doc,
                                          rule_remat_names)
-from apex_tpu.utils.compat import shard_map_unchecked
+from jax import shard_map
 
 REPO = repo_root()
 
@@ -588,7 +588,7 @@ def _grad_sync_program(violation):
         return (gs, *parts)
 
     out_specs = (P(), *([P("data")] * (1 if violation == "flat" else 2)))
-    wrapped = shard_map_unchecked(
+    wrapped = shard_map(
         f, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=out_specs)
     args = (jnp.arange(_N1, dtype=jnp.float32),
             jnp.arange(_N2, dtype=jnp.float32),
@@ -785,7 +785,7 @@ def test_shared_grad_cone_is_per_output():
     def f(a, b):
         return jax.lax.psum(a, "pipe"), b * 2.0  # b never reduced
 
-    wrapped = shard_map_unchecked(f, mesh=mesh, in_specs=(P(), P()),
+    wrapped = shard_map(f, mesh=mesh, in_specs=(P(), P()),
                                   out_specs=(P(), P()))
     jaxpr = jax.make_jaxpr(wrapped)(jnp.ones(4), jnp.ones(4)).jaxpr
     assert not check_shared_grad_reduction(jaxpr, [(0, "a")], "pipe")

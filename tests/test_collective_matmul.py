@@ -19,7 +19,7 @@ from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.tensor_parallel import (
     ColumnParallelLinear, RowParallelLinear, all_gather_matmul,
     matmul_reduce_scatter)
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 @pytest.fixture(params=[2, 4])
@@ -407,36 +407,11 @@ def _trainer_cfg(sp, ov):
         opt_level="O0")
 
 
-def test_hybrid_trainer_sp_refused_on_pre_vma_jax():
-    """The trainer's step runs under shard_map_unchecked; without the VMA
-    replication rewrite the SP cotangent flow is silently wrong (partial
-    LN/position grads), so construction must refuse loudly on 0.4.x."""
-    from apex_tpu.training import GPTHybridTrainer
-    from apex_tpu.utils.compat import HAS_VMA
-
-    if HAS_VMA:
-        pytest.skip("VMA jax: SP through the trainer is supported")
-    mesh = parallel_state.initialize_model_parallel(
-        tensor_model_parallel_size=2)
-    try:
-        with pytest.raises(NotImplementedError, match="silently wrong"):
-            GPTHybridTrainer(_trainer_cfg(True, True), mesh)
-        # non-SP construction stays fine
-        GPTHybridTrainer(_trainer_cfg(False, False), mesh)
-    finally:
-        parallel_state.destroy_model_parallel()
-
-
 def test_hybrid_trainer_sp_overlap_step_and_metrics():
-    """VMA jax only: SP(+overlap) trainer parity vs the NON-SP trainer —
+    """SP(+overlap) trainer parity vs the NON-SP trainer —
     loss AND one-step updated params/first moments (losses alone would
     slip wrong gradients), plus the tp/* telemetry."""
     from apex_tpu.training import GPTHybridTrainer
-    from apex_tpu.utils.compat import HAS_VMA
-
-    if not HAS_VMA:
-        pytest.skip("pre-VMA jax: SP through the trainer is refused "
-                    "(test_hybrid_trainer_sp_refused_on_pre_vma_jax)")
 
     rng = np.random.RandomState(0)
     tokens = jnp.asarray(rng.randint(0, 64, (4, 8, 8)))

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.ops.flash_attention import mha_reference
@@ -56,7 +56,7 @@ def test_ring_attention_grads_match_reference(mesh, remat):
             return jax.lax.psum(jnp.sum(out * _shard(dy)), "context")
 
         def _shard(x):
-            from apex_tpu.utils.compat import axis_size
+            from jax.lax import axis_size
             cp = axis_size("context")
             r = jax.lax.axis_index("context")
             chunk = x.shape[2] // cp

@@ -31,7 +31,7 @@ from _jaxpr_utils import (collective_census, count_eqns, eqn_axes,
                           jaxpr_str)
 from apex_tpu.optimizers._flatten import bucket_bounds, build_layout
 from apex_tpu.parallel import DistributedDataParallel, allreduce_grads
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _mesh(n=None):

@@ -41,7 +41,6 @@ from apex_tpu.observability.costs import DeviceSpec
 from apex_tpu.parallel.distributed import DEFAULT_BUCKET_BYTES
 from apex_tpu.pyprof import bucket_wire_ms, tune_bucket_bytes
 from apex_tpu.pyprof.tune import DEFAULT_CANDIDATES
-from apex_tpu.utils.compat import HAS_VMA
 
 SPEC = DeviceSpec("test", 200e12, 800.0, 50.0)
 
@@ -89,9 +88,9 @@ def test_fastpath_preset_fields():
 
 def test_fastpath_sp_gating_follows_capability():
     fast = _cfg(tp=2, pp=1, dp=2).fastpath()
-    assert fast.model.sequence_parallel == HAS_VMA
-    assert fast.model.tp_comm_overlap == HAS_VMA
-    # pp>1 never carries SP regardless of jax line
+    assert fast.model.sequence_parallel
+    assert fast.model.tp_comm_overlap
+    # pp>1 never carries SP
     fast_pp = _cfg(tp=2, pp=2, dp=1).fastpath()
     assert not fast_pp.model.sequence_parallel
 
@@ -322,16 +321,13 @@ def _compound_jaxpr_checks(tp, dp):
 
 
 def test_fastpath_compound_jaxpr_tp2():
-    """The full compound assertion at tp=2 x dp=4: on VMA jax the preset
-    carries SP+tp_comm_overlap and the TP-layer scopes must hold zero
-    fused collectives next to the B-bucket ZeRO structure; on the
-    pre-VMA 0.4.x line the preset degrades SP off (the trainer would
-    refuse it) and the same DP/ZeRO/interleave assertions run on
-    plain-TP — either way every per-feature assertion from PRs 2/4
-    holds on ONE program. (The tp=1 shape of the same checks runs in
-    the multichip dryrun gate's fastpath leg.)"""
+    """The full compound assertion at tp=2 x dp=4: the preset carries
+    SP+tp_comm_overlap and the TP-layer scopes must hold zero fused
+    collectives next to the B-bucket ZeRO structure — every per-feature
+    assertion from PRs 2/4 holds on ONE program. (The tp=1 shape of the
+    same checks runs in the multichip dryrun gate's fastpath leg.)"""
     cfg = _compound_jaxpr_checks(tp=2, dp=4)
-    assert cfg.model.tp_comm_overlap == HAS_VMA
+    assert cfg.model.tp_comm_overlap
 
 
 # ---------------------------------------------------------------------------

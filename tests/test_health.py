@@ -14,7 +14,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import observability as obs
 from apex_tpu.observability import health, ingraph
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 # ---------------------------------------------------------------------------

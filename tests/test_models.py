@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.models import (
@@ -261,7 +261,7 @@ def test_bert_mlm_head_under_tp2():
     mesh = parallel_state.initialize_model_parallel(
         tensor_model_parallel_size=2)
     try:
-        from apex_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         cfg = BertConfig(vocab_size=64, hidden_size=32, num_layers=2,
@@ -308,7 +308,7 @@ def test_gpt_sequence_parallel_matches_tp():
     """Megatron-LM SP: sequence-sharded norms/residuals with gather/
     reduce-scatter TP boundaries must reproduce plain TP exactly (same
     params, same mesh)."""
-    from apex_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from apex_tpu.transformer import parallel_state

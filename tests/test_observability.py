@@ -17,7 +17,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import observability as obs
 from apex_tpu.observability import ingraph
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -541,6 +541,27 @@ class TestCosts:
         from apex_tpu.observability.costs import DEFAULT_PEAK_FLOPS
         assert obs.peak_flops(Fake("cpu")) == DEFAULT_PEAK_FLOPS
         assert obs.peak_flops() == DEFAULT_PEAK_FLOPS  # CPU test host
+
+    @pytest.mark.parametrize("kind,platform", [
+        ("TPU v9 hypothetical", "tpu"),   # a TPU the table has not learned
+        ("TPU v9 hypothetical", None),    # kind alone is enough to refuse
+        ("NVIDIA H100", "gpu"),
+    ])
+    def test_unknown_accelerator_kind_raises(self, kind, platform):
+        """The v5e-class default is the CPU backend's stand-in only: a
+        utilization against an ASSUMED peak would read as a measurement,
+        so an accelerator the tables do not know is an error in both
+        lookups."""
+        from apex_tpu.observability.costs import device_spec
+
+        class Fake:
+            device_kind = kind
+        if platform is not None:
+            Fake.platform = platform
+        with pytest.raises(ValueError, match="no peak numbers"):
+            obs.peak_flops(Fake())
+        with pytest.raises(ValueError, match="no peak numbers"):
+            device_spec(Fake())
 
     def test_flops_budget_from_compiled(self):
         compiled = jax.jit(lambda x: x @ x).lower(

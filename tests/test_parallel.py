@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 from _jaxpr_utils import jaxpr_str
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from apex_tpu.parallel import (
     DistributedDataParallel, Reducer, SyncBatchNorm, allreduce_grads,

@@ -31,7 +31,7 @@ from apex_tpu.observability.costs import (DEFAULT_DEVICE_SPEC, DeviceSpec,
                                           device_spec, flops_budget)
 from apex_tpu.pyprof import (DEFAULT_REGIONS, UNATTRIBUTED,
                              AttributionReport, attribute, model_program)
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

@@ -67,7 +67,7 @@ def test_named_scopes_reach_hlo():
     """The pre-annotated hot paths must show up in lowered HLO metadata —
     that is what makes a captured profile attributable (the pyprof
     annotate-step equivalent)."""
-    from apex_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from apex_tpu.parallel.distributed import allreduce_grads

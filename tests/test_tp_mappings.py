@@ -19,7 +19,7 @@ from apex_tpu.transformer.context_parallel import (
     gather_from_sequence_parallel_region,
     reduce_scatter_to_sequence_parallel_region,
     scatter_to_sequence_parallel_region)
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 @pytest.fixture
