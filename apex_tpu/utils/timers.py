@@ -10,7 +10,7 @@ TPU re-design:
 
 - ``Timer``/``Timers`` keep the reference API (start/stop/reset/elapsed,
   ``log``, ``write``) but synchronize by *fetching a value* from arrays you
-  hand to ``stop(wait_for=...)`` — on async (and tunneled) backends a
+  hand to ``stop(wait_for=...)`` — on an async backend a
   dispatch returns immediately, so the only honest fence is data
   materialization. Without ``wait_for`` the timer measures host wall time
   (dispatch cost), which is also meaningful and is what you want around

@@ -24,6 +24,7 @@ from apex_tpu.observability import perfwatch as pw
 from apex_tpu.observability.registry import MetricsRegistry
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DUMPS = os.path.join(REPO, "tests", "data")
 
 
 # ---------------------------------------------------------------------------
@@ -115,9 +116,10 @@ class TestBenchHistory:
 
     def test_importer_reads_this_repos_real_dumps(self):
         hist = pw.BenchHistory()
-        added = hist.import_bench_files(root=REPO)
-        # BENCH_r01..r05 are checked in: 4 resnet rounds + round 5's
-        # full sweep — and every imported record passes the schema
+        added = hist.import_bench_files(root=DUMPS)
+        # tests/data holds two hand-written dumps in the driver's
+        # {n, cmd, rc, tail, parsed} schema (the rounds' own dumps were
+        # removed in PR 22) — every imported record passes the schema
         assert added >= 10
         assert "resnet50_train_imgs_per_sec_per_chip" in hist.metrics()
         for rec in hist:
@@ -336,9 +338,9 @@ class TestCLI:
         assert "gpt_attention" in proc.stdout
 
     def test_bootstrap_ingests_the_checked_in_rounds(self):
-        # no --history: the CLI bootstraps in-memory from the repo's
-        # own BENCH_r*.json dumps — the acceptance path
-        proc = _run_cli("--check", "--root", REPO)
+        # no --history: the CLI bootstraps in-memory from the root's
+        # BENCH_r*.json dumps — the acceptance path
+        proc = _run_cli("--check", "--root", DUMPS)
         assert proc.returncode == 0, proc.stdout + proc.stderr
 
 
