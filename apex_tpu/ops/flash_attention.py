@@ -1608,10 +1608,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         softmax_scale = 1.0 / math.sqrt(d)
     if use_pallas is None:
         use_pallas = supports_paged(block_size, d)
-    elif use_pallas and not supports_paged(block_size, d):
-        raise ValueError(
-            f"use_pallas=True but block_size {block_size} / head_dim {d} "
-            "is not a shape the paged kernel takes")
     block_tables = jnp.asarray(block_tables).astype(jnp.int32)
     lengths = jnp.asarray(lengths).astype(jnp.int32)
 
@@ -1625,15 +1621,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                 scale=float(softmax_scale), mean_context=mean_context)
             if not multi:
                 out, lse = out[:, :, 0], lse[:, :, 0]
-            if multi:
-                if k_new is not None:
-                    out = _merge_drafts(
-                        out, lse, q, k_new, v_new,
-                        k_new if k_cast is None else k_cast,
-                        v_new if v_cast is None else v_cast,
-                        float(softmax_scale), q.dtype)
-                return out.astype(q.dtype)
-            if k_new is not None:
+            if k_new is not None and multi:
+                out = _merge_drafts(
+                    out, lse, q, k_new, v_new,
+                    k_new if k_cast is None else k_cast,
+                    v_new if v_cast is None else v_cast,
+                    float(softmax_scale), q.dtype)
+            elif k_new is not None:
                 out = _merge_current(out, lse, q, k_new, v_new,
                                      float(softmax_scale), q.dtype)
             return out.astype(q.dtype)

@@ -13,8 +13,6 @@ import os
 
 __all__ = ["default_cache_dir", "enable_compile_cache"]
 
-_ENV = "JAX_COMPILATION_CACHE_DIR"
-
 
 def default_cache_dir() -> str:
     """``<checkout>/.jax_cache`` — the checkout being the directory that
@@ -27,7 +25,7 @@ def enable_compile_cache(min_compile_secs: float = 1.0) -> str:
     """Turn the persistent compile cache on and return its directory.
     Call before the first compilation."""
     import jax
-    path = os.environ.get(_ENV)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = default_cache_dir()
         jax.config.update("jax_compilation_cache_dir", path)

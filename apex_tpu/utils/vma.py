@@ -14,7 +14,7 @@ from typing import Any, Callable, Iterable
 
 import jax
 import jax.numpy as jnp
-
+from jax._src.lax.parallel import all_gather_invariant
 
 __all__ = ["cast_to_vma", "scan_stable_vma", "invariant_all_gather",
            "varying_all_gather",
@@ -148,5 +148,4 @@ def invariant_all_gather(x: jnp.ndarray, axis_name: str, axis: int = 0
     ``all_gather``'s varying type cannot). ``all_gather_invariant`` has no
     public spelling yet, hence the ``jax._src`` import. Shared by the
     ZeRO param gather and the sequence-parallel gathers."""
-    from jax._src.lax.parallel import all_gather_invariant
     return all_gather_invariant(x, axis_name, axis=axis, tiled=True)

@@ -60,13 +60,6 @@ def for_tpu(monkeypatch):
     cc.reset_cache()
 
 
-def _compile(fn, one_chip, *shapes):
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
-    return text
-
-
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 QKV = [((B, H, S, D), BF16)] * 3
 
@@ -136,4 +129,5 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, for_tpu):
     fn, shapes = CASES[case]()
-    _compile(fn, one_chip, *shapes)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()

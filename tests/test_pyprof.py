@@ -602,6 +602,15 @@ class TestBenchWiring:
 # trainer surface
 # ---------------------------------------------------------------------------
 
+# Out of tier-1 (slow): this tp x pp x dp step is the program most exposed
+# to XLA:CPU's in-process collective runtime entering its pipe
+# collective-permute and tensor all-reduce in different orders on
+# different virtual devices, which cross-blocks at the rendezvous and
+# ABORTS the process after 40 s (the class __graft_entry__.py documents;
+# reproduced in a plain loop of this test, PR 22). Under xdist that kills
+# the worker, the test is re-run on the replacement, and a second death has
+# hung the whole run. It passes alone: pytest -m slow tests/test_pyprof.py
+@pytest.mark.slow
 def test_hybrid_trainer_attribution_report():
     """GPTHybridTrainer.attribution_report prices the trainer's own
     tp x pp x dp step: every pipeline/TP/DP region shows up and the
