@@ -57,7 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 from apex_tpu.utils.backend import pallas_interpret as _interp
 
 __all__ = ["flash_attention", "mha_reference", "supports_flash",
-           "dropout_keep_mask", "decode_attention", "supports_paged",
+           "dropout_keep_mask", "decode_attention",
            "paged_decode_attention"]
 
 NEG_INF = -1e30
@@ -1374,18 +1374,6 @@ def decode_attention(q, k, v, lengths, k_new=None, v_new=None,
 #   (``pyprof/model.py`` reads it off the ``pallas_call`` eqn). It never
 #   changes the math — only the modeled bytes.
 
-def supports_paged(block_size: int, d: int) -> bool:
-    """Pallas eligibility for the paged decode kernel — the same answer
-    on every backend. Every block of the kernel spans its array's last
-    two dims whole (``(block_size, d)`` pool blocks, ``(q_len, d)``
-    query rows, ``(1, block_size)`` scale rows), which Mosaic accepts at
-    any size (``tests/test_chip_compile.py`` compiles the serving shape;
-    block sizes 2..64 x head dims 8..64 were compiled for v5e when the
-    rank-3 blocks were repaired). Small blocks are legal, not fast:
-    ``block_size % 128 == 0`` keeps the score row lane-dense."""
-    return block_size >= 1 and d >= 1
-
-
 def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, ksc_ref,
                          vsc_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                          scale, block_size, n_blocks):
@@ -1585,8 +1573,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 
     Returns ``(b, h, d)`` in ``q.dtype``.
 
-    ``use_pallas=False`` selects a gather-then-reference XLA path (same
-    math, priced O(table span)) — the parity oracle, never auto-selected.
+    ``use_pallas=None`` means the kernel. ``use_pallas=False`` selects a
+    gather-then-reference XLA path (same math, priced O(table span)) —
+    the parity oracle, never auto-selected.
     """
     multi = q.ndim == 4
     if multi:
@@ -1607,7 +1596,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     if softmax_scale is None:
         softmax_scale = 1.0 / math.sqrt(d)
     if use_pallas is None:
-        use_pallas = supports_paged(block_size, d)
+        # no shape is refused: every block of the kernel spans its array's
+        # last two dims whole ((block_size, d) pool blocks, (q_len, d)
+        # query rows, (1, block_size) scale rows), which Mosaic accepts at
+        # any size. Small blocks are legal, not fast: block_size % 128 == 0
+        # keeps the score row lane-dense.
+        use_pallas = True
     block_tables = jnp.asarray(block_tables).astype(jnp.int32)
     lengths = jnp.asarray(lengths).astype(jnp.int32)
 

@@ -13,6 +13,9 @@ import os
 
 __all__ = ["default_cache_dir", "enable_compile_cache"]
 
+# programs that compile faster than this are not worth a cache file
+MIN_COMPILE_SECS = 1.0
+
 
 def default_cache_dir() -> str:
     """``<checkout>/.jax_cache`` — the checkout being the directory that
@@ -21,7 +24,7 @@ def default_cache_dir() -> str:
     return os.path.join(os.path.dirname(os.path.dirname(here)), ".jax_cache")
 
 
-def enable_compile_cache(min_compile_secs: float = 1.0) -> str:
+def enable_compile_cache() -> str:
     """Turn the persistent compile cache on and return its directory.
     Call before the first compilation."""
     import jax
@@ -30,5 +33,5 @@ def enable_compile_cache(min_compile_secs: float = 1.0) -> str:
         path = default_cache_dir()
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      min_compile_secs)
+                      MIN_COMPILE_SECS)
     return path

@@ -9,10 +9,10 @@ TensorBoard, ``_Timers.log``) and the deprecated pyprof pipeline
 TPU re-design:
 
 - ``Timer``/``Timers`` keep the reference API (start/stop/reset/elapsed,
-  ``log``, ``write``) but synchronize by waiting for the arrays you hand
-  to ``stop(wait_for=...)`` (:func:`device_fence`) — on an async backend
-  a dispatch returns immediately, so a timing without that wait measures
-  the enqueue. Without ``wait_for`` the timer measures host wall time
+  ``log``, ``write``) but synchronize by *fetching a value* from arrays you
+  hand to ``stop(wait_for=...)`` — on an async backend a dispatch returns
+  immediately, so the only honest fence is data materialization.
+  Without ``wait_for`` the timer measures host wall time
   (dispatch cost), which is also meaningful and is what you want around
   blocking sections.
 - pyprof's annotate->trace->attribute loop maps to ``jax.profiler``:
@@ -57,6 +57,7 @@ import time
 from typing import Any, Dict, Iterable, Optional
 
 import jax
+import numpy as np
 
 __all__ = ["Timer", "Timers", "profile_trace", "device_fence",
            "set_span_hook"]
@@ -74,13 +75,16 @@ def set_span_hook(hook) -> None:
 
 
 def device_fence(tree: Any) -> None:
-    """Block until EVERY array in ``tree`` has been computed. All leaves,
-    not one: a step's outputs finish at different times (the loss long
-    before the last parameter all-gather), and a caller that fences on
-    one leaf and dispatches the next step has two executions of one
-    multi-device program in flight — on the CPU backend their collectives
-    interleave, cross-block at the rendezvous and abort the process."""
-    jax.block_until_ready(tree)
+    """Block until the computation producing ``tree`` has finished, by
+    fetching one element of one leaf: data on the host is a fence whatever
+    the backend reports about readiness. One leaf suffices: device
+    execution is stream-ordered, so materializing any output of the last
+    queued program drains everything before it — and one fetch costs one
+    host round trip instead of one per leaf."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if hasattr(leaf, "dtype") and getattr(leaf, "size", 0):
+            np.asarray(jax.device_get(jax.numpy.ravel(leaf)[0:1]))
+            return
 
 
 class Timer:

@@ -14,8 +14,7 @@ import pytest
 from apex_tpu.models import GPTConfig, GPTModel
 from apex_tpu.observability.registry import MetricsRegistry
 from apex_tpu.ops.flash_attention import (decode_attention, mha_reference,
-                                          paged_decode_attention,
-                                          supports_paged)
+                                          paged_decode_attention)
 from apex_tpu.serving import (BlockAllocator, PagedKVCache,
                               PagedServingEngine, PoolExhausted, Rejection,
                               Request, ServingEngine, SlotScheduler,
