@@ -19,6 +19,10 @@ Composable modules, each zero-cost when unused:
   :class:`StepReporter` snapshotting registry + ``Timers`` + in-graph
   metrics each step into pluggable sinks (JSONL event log, TensorBoard
   ``add_scalar`` writers, Chrome-trace span export);
+- :mod:`~apex_tpu.observability.trace` — :func:`span`, the one span
+  function of the program's host code (``apex:`` annotations on the
+  profiler's clock; the table :data:`SPANS`), and the in-memory span
+  buffer behind the Chrome-trace export;
 - :mod:`~apex_tpu.observability.runtime` — compile/recompile counters via
   ``jax.monitoring`` listeners and a ``memory_stats()`` gauge sampler, so
   recompilation storms and HBM growth land in the same stream;
@@ -63,8 +67,8 @@ from apex_tpu.observability.registry import (  # noqa: F401
 from apex_tpu.observability.ingraph import (  # noqa: F401
     Metrics, aggregate, collecting, reap, record, recording)
 from apex_tpu.observability.trace import (  # noqa: F401
-    Span, chrome_trace_events, drain_spans, epoch_offset,
-    merge_chrome_traces, span_recording, spans_enabled)
+    SPANS, Span, chrome_trace_events, drain_spans, epoch_offset,
+    merge_chrome_traces, span, span_recording, spans_enabled)
 from apex_tpu.observability.sinks import (  # noqa: F401
     ChromeTraceSink, JSONLSink, TensorBoardSink)
 from apex_tpu.observability.report import (  # noqa: F401

@@ -10,6 +10,20 @@ nothing from the rest of the library). The
 each report and writes the standard ``traceEvents`` JSON, which loads in
 ``chrome://tracing`` / Perfetto next to a ``jax.profiler.trace`` capture —
 host-side step phases and device-side ops in the same timeline workflow.
+
+**Program spans.** :func:`span` is the one span function of the program's
+own host code (the serving scheduler and engines): it opens a
+``jax.profiler.TraceAnnotation`` named ``"apex:" + name``, so inside a
+profiler session (:func:`~apex_tpu.utils.timers.profile_trace`) the span
+lands in the profiler's ``.xplane.pb`` on plane ``/host:CPU``, line
+``python3``, ON THE CLOCK OF THE DEVICE PLANES — every idle gap of the
+device can be laid against what the host was doing. Keywords (``request_id``,
+``slot``, ``step``) arrive as stats of the event. With no session the
+annotation costs what a ``nullcontext`` costs (under half a microsecond,
+no clock read, no lock). Under :func:`span_recording` the same call also
+appends to the in-memory buffer, with the enclosing span as ``parent``:
+that view sees set-up (the profiler's window opens later) and needs no
+profiler. :data:`SPANS` is the one table of names.
 """
 
 from __future__ import annotations
@@ -17,34 +31,141 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Iterable, List, NamedTuple, Optional
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
-__all__ = ["Span", "spans_enabled", "enable_spans", "disable_spans",
-           "record_span", "drain_spans", "span_recording",
-           "chrome_trace_events", "epoch_offset", "trace_metadata",
-           "merge_chrome_traces"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "SPANS", "SPAN_PREFIX", "span", "spans_enabled",
+           "enable_spans", "disable_spans", "record_span", "drain_spans",
+           "span_recording", "chrome_trace_events", "epoch_offset",
+           "trace_metadata", "merge_chrome_traces"]
+
+SPAN_PREFIX = "apex:"
+
+# Every name span() may be given -> what the interval covers. Indentation
+# in the comments is nesting. PERF.md section 3 and docs/OBSERVABILITY.md
+# copy this table and name the metric that reads each span.
+SPANS: Dict[str, str] = {
+    "sched.submit": "SlotScheduler.submit: validation, shedding, the "
+                    "RequestRecord [request_id]",
+    "sched.step": "one SlotScheduler.step() [step: decode steps run "
+                  "before it]",
+    # inside sched.step
+    "sched.expire": "_expire_queued: the walk over queued deadlines",
+    "sched.admit": "one request past the admission checks: slot taken, "
+                   "engine.prefill, _record of its first token "
+                   "[request_id, slot, prompt_len]",
+    "sched.harvest": "from the stamp after the engine call to the last "
+                     "retirement of the step",
+    "sched.gauges": "the serve/* counters and gauges set at the end of "
+                    "a step",
+    # inside sched.admit
+    "engine.prefill": "prefill() of either engine [slot]",
+    "prefill.plan": "allocator.lookup/admit (paged), pad_prompt, the "
+                    "_host() marshalling, _next_key()",
+    "prefill.dispatch": "the call of prefill_compiled (returns before "
+                        "the device is done)",
+    "prefill.wait": "int(tok): the host blocks until the device has the "
+                    "token",
+    "prefill.index": "allocator.register_prefix (paged)",
+    # inside sched.step (and inside engine.prefill on a prefix hit)
+    "engine.decode": "decode() of either engine [active: slots stepped]",
+    "decode.plan": "prepare_step, append_targets (paged), the _host() "
+                   "marshalling, _next_key()",
+    "decode.dispatch": "the call of decode_compiled",
+    "decode.advance": "allocator.advance (paged), while the device runs",
+    "decode.wait": "np.asarray(toks), and last_finite on a quarantine "
+                   "engine: the host blocks until the device is done",
+    "engine.verify": "verify() of either engine [active]",
+    "verify.plan": "prepare_verify, verify_targets (paged), marshalling, "
+                   "_next_key()",
+    "verify.dispatch": "the call of verify_compiled",
+    "verify.wait": "np.asarray of tokens and counts (and last_finite)",
+    "verify.advance": "allocator.advance_counts (paged)",
+    # inside sched.harvest (or sched.step, or a cancel/drain)
+    "engine.release": "release_slot: allocator.release (paged) + the "
+                      "release_compiled dispatch [slot]",
+    # construction
+    "engine.build": "ServingEngine/PagedServingEngine.__init__ after the "
+                    "argument checks",
+    "compile.prefill": "trace + lower + compile of the prefill program",
+    "compile.decode": "trace + lower + compile of the decode program",
+    "compile.verify": "trace + lower + compile of the verify program",
+    "compile.release": "trace + lower + compile of the release program",
+    "engine.lint": "lint_serving_engine: the donation/aliasing self-check",
+}
 
 
 class Span(NamedTuple):
     name: str
     start: float  # perf_counter seconds
     end: float
+    # (name, start) of the enclosing span() on this thread; None at the
+    # top and for a Timer's span
+    parent: Optional[Tuple[str, float]] = None
+    ids: Optional[dict] = None  # span()'s keywords: request_id, slot, step
 
 
 _LOCK = threading.Lock()
 _SPANS: List[Span] = []
 _ENABLED = False
+_OPEN = threading.local()  # .stack: the recorded spans open on this thread
+
+
+class _RecordedSpan:
+    """span() under spans_enabled(): the annotation plus one buffer
+    entry, stamped on ``perf_counter`` like a Timer's."""
+
+    __slots__ = ("name", "ids", "start", "parent", "annotation")
+
+    def __init__(self, name: str, ids: dict):
+        if name not in SPANS:
+            raise ValueError(
+                f"span {name!r} is not in apex_tpu.observability.trace"
+                ".SPANS: add it there with a line on what it covers")
+        self.name, self.ids = name, ids
+        self.annotation = TraceAnnotation(SPAN_PREFIX + name, **ids)
+
+    def __enter__(self):
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        self.parent = stack[-1] if stack else None
+        self.annotation.__enter__()
+        self.start = time.perf_counter()
+        stack.append((self.name, self.start))
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter()
+        _OPEN.stack.pop()
+        self.annotation.__exit__(*exc)
+        record_span(self.name, self.start, end, self.parent, self.ids)
+        return False
+
+
+def span(name: str, **ids):
+    """A context manager round one interval of the program's host code:
+    ``with span("engine.decode", active=n): ...``. ``name`` is a key of
+    :data:`SPANS` (checked under :func:`spans_enabled`); ``ids`` are the
+    identifiers that tie spans together (``request_id``, ``slot``,
+    ``step``). See the module docstring for where the span lands."""
+    if _ENABLED:
+        return _RecordedSpan(name, ids)
+    return TraceAnnotation(SPAN_PREFIX + name, **ids)
 
 
 def spans_enabled() -> bool:
     return _ENABLED
 
 
-def record_span(name: str, start: float, end: float) -> None:
+def record_span(name: str, start: float, end: float,
+                parent: Optional[Tuple[str, float]] = None,
+                ids: Optional[dict] = None) -> None:
     if not _ENABLED:
         return
     with _LOCK:
-        _SPANS.append(Span(name, start, end))
+        _SPANS.append(Span(name, start, end, parent, ids))
 
 
 def _install_timer_hook(on: bool) -> None:
@@ -106,7 +227,10 @@ def epoch_offset() -> float:
 def trace_metadata() -> dict:
     """The metadata block both Chrome exporters stamp into their
     documents: the clock the ``ts`` fields are in plus the epoch offset
-    that aligns it across processes."""
+    that aligns it across processes. The profiler stamps its events on
+    the epoch too, so ``epoch_offset_s`` is also what lines a
+    :func:`span_recording` export up with the ``apex:`` spans of a
+    profiler capture of the same run."""
     return {"clock": "perf_counter", "epoch_offset_s": epoch_offset()}
 
 
@@ -170,13 +294,20 @@ def chrome_trace_events(spans, pid: int = 0, tid: int = 0,
                         step: Optional[int] = None) -> List[dict]:
     """Convert spans to Chrome-trace complete events (``ph="X"``, micro-
     second timestamps). ``step``, when given, lands in ``args`` so the
-    viewer can filter by training step."""
+    viewer can filter by training step; a :func:`span`'s ``ids`` and its
+    parent (``parent``: the name, ``parent_ts``: its start) land there
+    too."""
     events = []
     for s in spans:
         ev = {"name": s.name, "ph": "X", "cat": "apex_tpu",
               "ts": s.start * 1e6, "dur": (s.end - s.start) * 1e6,
               "pid": pid, "tid": tid}
+        args = dict(s.ids or {})
         if step is not None:
-            ev["args"] = {"step": step}
+            args.setdefault("step", step)
+        if s.parent is not None:
+            args["parent"], args["parent_ts"] = s.parent[0], s.parent[1] * 1e6
+        if args:
+            ev["args"] = args
         events.append(ev)
     return events

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.observability.costs import memory_budget
+from apex_tpu.observability.trace import span
 from apex_tpu.serving.cache import (KVCache, PagedKVCache, BlockAllocator,
                                     AdmitPlan, PoolExhausted,
                                     cache_bytes_per_slot, paged_block_bytes)
@@ -129,6 +130,13 @@ class ServingEngine:
                 f"verify window, which exceeds max_len {max_len}")
         self.last_finite: Optional[np.ndarray] = None
         self.swaps = 0
+        with span("engine.build"):
+            self._build(model, params, cache_dtype, rng_seed)
+
+    def _build(self, model, params, cache_dtype, rng_seed: int) -> None:
+        """The cache and the AOT programs (``__init__`` past its checks)."""
+        cfg = model.cfg
+        max_seqs, max_len = self.max_seqs, self.max_len
         self.cache = KVCache.create(
             cfg.num_layers, max_seqs, cfg.num_attention_heads, max_len,
             cfg.head_dim, dtype=cache_dtype)
@@ -183,20 +191,22 @@ class ServingEngine:
         ex_tokens = jnp.zeros((1, self.prefill_len), jnp.int32)
         ex_scalar = jnp.zeros((), jnp.int32)
         ex_temp = jnp.zeros((), jnp.float32)
-        self.prefill_traced = jax.jit(
-            prefill_step, donate_argnums=(1,)).trace(
-                params, self.cache, ex_tokens, ex_scalar, ex_scalar,
-                ex_temp, self._key)
-        self.prefill_compiled = self.prefill_traced.lower().compile()
+        with span("compile.prefill"):
+            self.prefill_traced = jax.jit(
+                prefill_step, donate_argnums=(1,)).trace(
+                    params, self.cache, ex_tokens, ex_scalar, ex_scalar,
+                    ex_temp, self._key)
+            self.prefill_compiled = self.prefill_traced.lower().compile()
         self._zero_poison = jnp.zeros((S,), jnp.float32)
         decode_args = (params, self.cache, jnp.zeros((S,), jnp.int32),
                        jnp.zeros((S,), jnp.float32),
                        jnp.ones((S,), jnp.bool_), self._key)
         if self.quarantine:
             decode_args += (self._zero_poison,)
-        self.decode_traced = jax.jit(
-            decode_step, donate_argnums=(1,)).trace(*decode_args)
-        self.decode_compiled = self.decode_traced.lower().compile()
+        with span("compile.decode"):
+            self.decode_traced = jax.jit(
+                decode_step, donate_argnums=(1,)).trace(*decode_args)
+            self.decode_compiled = self.decode_traced.lower().compile()
 
         self.verify_traced = None
         self.verify_compiled = None
@@ -244,9 +254,10 @@ class ServingEngine:
                            jnp.ones((S,), jnp.bool_), self._key)
             if self.quarantine:
                 verify_args += (self._zero_poison,)
-            self.verify_traced = jax.jit(
-                verify_step, donate_argnums=(1,)).trace(*verify_args)
-            self.verify_compiled = self.verify_traced.lower().compile()
+            with span("compile.verify"):
+                self.verify_traced = jax.jit(
+                    verify_step, donate_argnums=(1,)).trace(*verify_args)
+                self.verify_compiled = self.verify_traced.lower().compile()
 
         def release_step(cache, slot):
             # zero one slot's cursor so a freed slot stops paying
@@ -255,9 +266,10 @@ class ServingEngine:
                 cache.lengths, jnp.zeros((1,), jnp.int32), (slot,))
             return dataclasses.replace(cache, lengths=lengths)
 
-        self.release_compiled = jax.jit(
-            release_step, donate_argnums=(0,)).trace(
-                self.cache, ex_scalar).lower().compile()
+        with span("compile.release"):
+            self.release_compiled = jax.jit(
+                release_step, donate_argnums=(0,)).trace(
+                    self.cache, ex_scalar).lower().compile()
 
         # construction-time donation self-check (analysis rule
         # jaxpr-donation, docs/ANALYSIS.md): every cache leaf must be
@@ -267,8 +279,9 @@ class ServingEngine:
         # exact class PR 9's review caught by hand
         from apex_tpu.analysis.program import (lint_serving_engine,
                                                verify_findings)
-        verify_findings(lint_serving_engine(self),
-                        "ServingEngine construction")
+        with span("engine.lint"):
+            verify_findings(lint_serving_engine(self),
+                            "ServingEngine construction")
 
     # -- stepping -----------------------------------------------------------
 
@@ -311,12 +324,16 @@ class ServingEngine:
             # slot's in-flight sequence
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {self.max_seqs})")
-        self.cache, tok = self.prefill_compiled(
-            self.params, self.cache, self.pad_prompt(prompt),
-            _host(slot, np.int32),
-            _host(len(prompt), np.int32),
-            _host(temperature, np.float32), self._next_key())
-        return int(tok)
+        with span("engine.prefill", slot=int(slot)):
+            with span("prefill.plan"):
+                args = (self.params, self.cache, self.pad_prompt(prompt),
+                        _host(slot, np.int32),
+                        _host(len(prompt), np.int32),
+                        _host(temperature, np.float32), self._next_key())
+            with span("prefill.dispatch"):
+                self.cache, tok = self.prefill_compiled(*args)
+            with span("prefill.wait"):
+                return int(tok)
 
     def decode(self, tokens: np.ndarray, temperatures: np.ndarray,
                active: Optional[np.ndarray] = None,
@@ -336,23 +353,35 @@ class ServingEngine:
         array is refused — the fault would be silently dropped)."""
         if active is None:
             active = np.ones(self.max_seqs, np.bool_)
-        args = (self.params, self.cache,
-                _host(tokens, np.int32),
-                _host(temperatures, np.float32),
-                _host(active, np.bool_), self._next_key())
-        if self.quarantine:
-            pvec = self._zero_poison if poison is None else \
-                _host(poison, np.float32)
-            self.cache, toks, finite = self.decode_compiled(*args, pvec)
-            self.last_finite = np.asarray(finite)
-        else:
-            if poison is not None:
-                raise ValueError(
-                    "poison injection requires a quarantine engine "
-                    "(ServingEngine(..., quarantine=True)) — on a plain "
-                    "engine the fault would be silently dropped")
-            self.cache, toks = self.decode_compiled(*args)
-        return np.asarray(toks)
+        self._refuse_poison_unless_quarantine(poison)
+        with span("engine.decode", active=int(np.count_nonzero(active))):
+            with span("decode.plan"):
+                args = (self.params, self.cache,
+                        _host(tokens, np.int32),
+                        _host(temperatures, np.float32),
+                        _host(active, np.bool_), self._next_key())
+                args += self._poison_arg(poison)
+            with span("decode.dispatch"):
+                # a quarantine engine's program returns ``finite`` too
+                self.cache, toks, *finite = self.decode_compiled(*args)
+            with span("decode.wait"):
+                if finite:
+                    self.last_finite = np.asarray(finite[0])
+                return np.asarray(toks)
+
+    def _refuse_poison_unless_quarantine(self, poison) -> None:
+        if poison is not None and not self.quarantine:
+            raise ValueError(
+                "poison injection requires a quarantine engine "
+                f"({type(self).__name__}(..., quarantine=True)) — on a "
+                "plain engine the fault would be silently dropped")
+
+    def _poison_arg(self, poison) -> tuple:
+        """The quarantine programs' trailing ``poison`` argument."""
+        if not self.quarantine:
+            return ()
+        return (self._zero_poison if poison is None
+                else _host(poison, np.float32),)
 
     def verify(self, tokens: np.ndarray, drafts: np.ndarray,
                temperatures: np.ndarray,
@@ -379,29 +408,26 @@ class ServingEngine:
                 f"({type(self).__name__}(..., speculate_k=k) with k > 0)")
         if active is None:
             active = np.ones(self.max_seqs, np.bool_)
-        drafts = np.asarray(drafts, np.int32).reshape(
-            self.max_seqs, self.speculate_k)
-        tok_mat = np.concatenate(
-            [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
-             drafts], axis=1)
-        args = (self.params, self.cache, _host(tok_mat),
-                _host(drafts),
-                _host(temperatures, np.float32),
-                _host(active, np.bool_), self._next_key())
-        if self.quarantine:
-            pvec = self._zero_poison if poison is None else \
-                _host(poison, np.float32)
-            self.cache, toks, counts, finite = self.verify_compiled(
-                *args, pvec)
-            self.last_finite = np.asarray(finite)
-        else:
-            if poison is not None:
-                raise ValueError(
-                    "poison injection requires a quarantine engine "
-                    "(ServingEngine(..., quarantine=True)) — on a plain "
-                    "engine the fault would be silently dropped")
-            self.cache, toks, counts = self.verify_compiled(*args)
-        return np.asarray(toks), np.asarray(counts)
+        self._refuse_poison_unless_quarantine(poison)
+        with span("engine.verify", active=int(np.count_nonzero(active))):
+            with span("verify.plan"):
+                drafts = np.asarray(drafts, np.int32).reshape(
+                    self.max_seqs, self.speculate_k)
+                tok_mat = np.concatenate(
+                    [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
+                     drafts], axis=1)
+                args = (self.params, self.cache, _host(tok_mat),
+                        _host(drafts),
+                        _host(temperatures, np.float32),
+                        _host(active, np.bool_), self._next_key())
+                args += self._poison_arg(poison)
+            with span("verify.dispatch"):
+                self.cache, toks, counts, *finite = self.verify_compiled(
+                    *args)
+            with span("verify.wait"):
+                if finite:
+                    self.last_finite = np.asarray(finite[0])
+                return np.asarray(toks), np.asarray(counts)
 
     def release_slot(self, slot: int) -> None:
         """Zero ``slot``'s write cursor (AOT-compiled, donated like the
@@ -414,8 +440,9 @@ class ServingEngine:
         if not 0 <= int(slot) < self.max_seqs:
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {self.max_seqs})")
-        self.cache = self.release_compiled(self.cache,
-                                           _host(slot, np.int32))
+        with span("engine.release", slot=int(slot)):
+            self.cache = self.release_compiled(self.cache,
+                                               _host(slot, np.int32))
 
     # -- hot weight swap ----------------------------------------------------
 
@@ -599,6 +626,15 @@ class PagedServingEngine(ServingEngine):
         self.last_admit: Optional[AdmitPlan] = None
         self.last_failed: list = []
         self.swaps = 0
+        with span("engine.build"):
+            self._build(model, params, cache_dtype, rng_seed)
+
+    def _build(self, model, params, cache_dtype, rng_seed: int) -> None:
+        """The pool, its allocator and the AOT programs (``__init__``
+        past its checks)."""
+        cfg = model.cfg
+        max_seqs, num_blocks = self.max_seqs, self.num_blocks
+        block_size = self.block_size
         self.prefill_blocks = self.prefill_len // self.block_size
         blocks_per_slot = -(-self.max_len // self.block_size)
         self.cache = PagedKVCache.create(
@@ -662,11 +698,12 @@ class PagedServingEngine(ServingEngine):
         ex_row = jnp.zeros((self.prefill_blocks,), jnp.int32)
         ex_scalar = jnp.zeros((), jnp.int32)
         ex_temp = jnp.zeros((), jnp.float32)
-        self.prefill_traced = jax.jit(
-            prefill_step, donate_argnums=(1,)).trace(
-                params, self.cache, ex_tokens, ex_row, ex_scalar,
-                ex_temp, self._key)
-        self.prefill_compiled = self.prefill_traced.lower().compile()
+        with span("compile.prefill"):
+            self.prefill_traced = jax.jit(
+                prefill_step, donate_argnums=(1,)).trace(
+                    params, self.cache, ex_tokens, ex_row, ex_scalar,
+                    ex_temp, self._key)
+            self.prefill_compiled = self.prefill_traced.lower().compile()
         self._zero_poison = jnp.zeros((S,), jnp.float32)
         zs = jnp.zeros((S,), jnp.int32)
         decode_args = (params, self.cache,
@@ -675,9 +712,10 @@ class PagedServingEngine(ServingEngine):
                        self._key)
         if self.quarantine:
             decode_args += (self._zero_poison,)
-        self.decode_traced = jax.jit(
-            decode_step, donate_argnums=(1,)).trace(*decode_args)
-        self.decode_compiled = self.decode_traced.lower().compile()
+        with span("compile.decode"):
+            self.decode_traced = jax.jit(
+                decode_step, donate_argnums=(1,)).trace(*decode_args)
+            self.decode_compiled = self.decode_traced.lower().compile()
 
         self.verify_traced = None
         self.verify_compiled = None
@@ -738,9 +776,10 @@ class PagedServingEngine(ServingEngine):
                            self._key)
             if self.quarantine:
                 verify_args += (self._zero_poison,)
-            self.verify_traced = jax.jit(
-                verify_step, donate_argnums=(1,)).trace(*verify_args)
-            self.verify_compiled = self.verify_traced.lower().compile()
+            with span("compile.verify"):
+                self.verify_traced = jax.jit(
+                    verify_step, donate_argnums=(1,)).trace(*verify_args)
+                self.verify_compiled = self.verify_traced.lower().compile()
 
         def release_step(cache):
             # re-zero the reserved null block: every masked write
@@ -758,14 +797,16 @@ class PagedServingEngine(ServingEngine):
                     jnp.float32(_MIN_SCALE))
             return dataclasses.replace(cache, **new)
 
-        self.release_compiled = jax.jit(
-            release_step, donate_argnums=(0,)).trace(
-                self.cache).lower().compile()
+        with span("compile.release"):
+            self.release_compiled = jax.jit(
+                release_step, donate_argnums=(0,)).trace(
+                    self.cache).lower().compile()
 
         from apex_tpu.analysis.program import (lint_serving_engine,
                                                verify_findings)
-        verify_findings(lint_serving_engine(self),
-                        "PagedServingEngine construction")
+        with span("engine.lint"):
+            verify_findings(lint_serving_engine(self),
+                            "PagedServingEngine construction")
 
     # -- admission ----------------------------------------------------------
 
@@ -797,43 +838,49 @@ class PagedServingEngine(ServingEngine):
         if not 0 <= int(slot) < self.max_seqs:
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {self.max_seqs})")
-        prompt = [int(t) for t in prompt]
-        shared = self.allocator.lookup(prompt)
-        covered = min(len(shared) * self.block_size, len(prompt) - 1)
-        if shared and len(prompt) - covered > self.prefix_suffix_cap:
-            shared = []        # tail too long: cold prefill wins
-        if not shared:
-            plan = self.allocator.admit(slot, prompt,
-                                        self.prefill_blocks,
-                                        share=False)
-        else:
-            plan = self.allocator.admit(slot, prompt,
-                                        self.prefill_blocks)
-        self.last_admit = plan
-        if plan.prefill:
-            self.cache, tok = self.prefill_compiled(
-                self.params, self.cache, self.pad_prompt(prompt),
-                _host(plan.block_row, np.int32),
-                _host(len(prompt), np.int32),
-                _host(temperature, np.float32), self._next_key())
-            # index the freshly written full blocks so LATER admissions
-            # can share them
-            self.allocator.register_prefix(slot, prompt)
-            return int(tok)
-        # prefix hit: decode the un-shared tail token by token through
-        # the ordinary decode program (same compiled program — zero
-        # recompiles), other slots frozen
-        active = np.zeros(self.max_seqs, np.bool_)
-        active[slot] = True
-        tokens = np.zeros(self.max_seqs, np.int32)
-        temps = np.zeros(self.max_seqs, np.float32)
-        temps[slot] = temperature
-        tok = 0
-        for t in plan.suffix:
-            tokens[slot] = t
-            toks = self.decode(tokens, temps, active=active)
-            tok = int(toks[slot])
-        return tok
+        with span("engine.prefill", slot=int(slot)):
+            with span("prefill.plan"):
+                prompt = [int(t) for t in prompt]
+                shared = self.allocator.lookup(prompt)
+                covered = min(len(shared) * self.block_size,
+                              len(prompt) - 1)
+                if shared and len(prompt) - covered > self.prefix_suffix_cap:
+                    shared = []        # tail too long: cold prefill wins
+                plan = self.allocator.admit(slot, prompt,
+                                            self.prefill_blocks,
+                                            share=bool(shared))
+                self.last_admit = plan
+                if plan.prefill:
+                    args = (self.params, self.cache,
+                            self.pad_prompt(prompt),
+                            _host(plan.block_row, np.int32),
+                            _host(len(prompt), np.int32),
+                            _host(temperature, np.float32),
+                            self._next_key())
+            if plan.prefill:
+                with span("prefill.dispatch"):
+                    self.cache, tok = self.prefill_compiled(*args)
+                with span("prefill.index"):
+                    # index the freshly written full blocks so LATER
+                    # admissions can share them
+                    self.allocator.register_prefix(slot, prompt)
+                with span("prefill.wait"):
+                    return int(tok)
+            # prefix hit: decode the un-shared tail token by token through
+            # the ordinary decode program (same compiled program — zero
+            # recompiles), other slots frozen; each is an engine.decode
+            # span inside this one
+            active = np.zeros(self.max_seqs, np.bool_)
+            active[slot] = True
+            tokens = np.zeros(self.max_seqs, np.int32)
+            temps = np.zeros(self.max_seqs, np.float32)
+            temps[slot] = temperature
+            tok = 0
+            for t in plan.suffix:
+                tokens[slot] = t
+                toks = self.decode(tokens, temps, active=active)
+                tok = int(toks[slot])
+            return tok
 
     # -- stepping -----------------------------------------------------------
 
@@ -850,33 +897,32 @@ class PagedServingEngine(ServingEngine):
         if active is None:
             active = np.ones(self.max_seqs, np.bool_)
         active = np.asarray(active, bool)
-        step = self.allocator.prepare_step(list(np.flatnonzero(active)))
-        self.last_failed = list(step.failed)
-        ok = active.copy()
-        ok[step.failed] = False
-        block_ids, offsets = self.allocator.append_targets(ok)
-        args = (self.params, self.cache,
-                _host(self.allocator.tables),
-                _host(self.allocator.lengths),
-                _host(tokens, np.int32),
-                _host(temperatures, np.float32),
-                _host(block_ids), _host(offsets),
-                _host(step.cow_src), _host(step.cow_dst),
-                self._next_key())
-        if self.quarantine:
-            pvec = self._zero_poison if poison is None else \
-                _host(poison, np.float32)
-            self.cache, toks, finite = self.decode_compiled(*args, pvec)
-            self.last_finite = np.asarray(finite)
-        else:
-            if poison is not None:
-                raise ValueError(
-                    "poison injection requires a quarantine engine "
-                    "(PagedServingEngine(..., quarantine=True)) — on a "
-                    "plain engine the fault would be silently dropped")
-            self.cache, toks = self.decode_compiled(*args)
-        self.allocator.advance(list(np.flatnonzero(ok)))
-        return np.asarray(toks)
+        self._refuse_poison_unless_quarantine(poison)
+        with span("engine.decode", active=int(np.count_nonzero(active))):
+            with span("decode.plan"):
+                step = self.allocator.prepare_step(
+                    list(np.flatnonzero(active)))
+                self.last_failed = list(step.failed)
+                ok = active.copy()
+                ok[step.failed] = False
+                block_ids, offsets = self.allocator.append_targets(ok)
+                args = (self.params, self.cache,
+                        _host(self.allocator.tables),
+                        _host(self.allocator.lengths),
+                        _host(tokens, np.int32),
+                        _host(temperatures, np.float32),
+                        _host(block_ids), _host(offsets),
+                        _host(step.cow_src), _host(step.cow_dst),
+                        self._next_key())
+                args += self._poison_arg(poison)
+            with span("decode.dispatch"):
+                self.cache, toks, *finite = self.decode_compiled(*args)
+            with span("decode.advance"):
+                self.allocator.advance(list(np.flatnonzero(ok)))
+            with span("decode.wait"):
+                if finite:
+                    self.last_finite = np.asarray(finite[0])
+                return np.asarray(toks)
 
     def verify(self, tokens: np.ndarray, drafts: np.ndarray,
                temperatures: np.ndarray,
@@ -900,43 +946,43 @@ class PagedServingEngine(ServingEngine):
         if active is None:
             active = np.ones(self.max_seqs, np.bool_)
         active = np.asarray(active, bool)
-        step = self.allocator.prepare_verify(
-            list(np.flatnonzero(active)), Q)
-        self.last_failed = list(step.failed)
-        ok = active.copy()
-        ok[step.failed] = False
-        block_ids, offsets = self.allocator.verify_targets(ok, Q)
-        drafts = np.asarray(drafts, np.int32).reshape(
-            self.max_seqs, self.speculate_k)
-        tok_mat = np.concatenate(
-            [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
-             drafts], axis=1)
-        args = (self.params, self.cache,
-                _host(self.allocator.tables),
-                _host(self.allocator.lengths),
-                _host(tok_mat), _host(drafts),
-                _host(temperatures, np.float32),
-                _host(ok), _host(block_ids),
-                _host(offsets), _host(step.cow_src),
-                _host(step.cow_dst), self._next_key())
-        if self.quarantine:
-            pvec = self._zero_poison if poison is None else \
-                _host(poison, np.float32)
-            self.cache, toks, counts, finite = self.verify_compiled(
-                *args, pvec)
-            self.last_finite = np.asarray(finite)
-        else:
-            if poison is not None:
-                raise ValueError(
-                    "poison injection requires a quarantine engine "
-                    "(PagedServingEngine(..., quarantine=True)) — on a "
-                    "plain engine the fault would be silently dropped")
-            self.cache, toks, counts = self.verify_compiled(*args)
-        counts = np.asarray(counts)
-        okidx = np.flatnonzero(ok)
-        self.allocator.advance_counts(
-            list(okidx), [int(counts[s]) for s in okidx])
-        return np.asarray(toks), counts
+        self._refuse_poison_unless_quarantine(poison)
+        with span("engine.verify", active=int(np.count_nonzero(active))):
+            with span("verify.plan"):
+                step = self.allocator.prepare_verify(
+                    list(np.flatnonzero(active)), Q)
+                self.last_failed = list(step.failed)
+                ok = active.copy()
+                ok[step.failed] = False
+                block_ids, offsets = self.allocator.verify_targets(ok, Q)
+                drafts = np.asarray(drafts, np.int32).reshape(
+                    self.max_seqs, self.speculate_k)
+                tok_mat = np.concatenate(
+                    [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
+                     drafts], axis=1)
+                args = (self.params, self.cache,
+                        _host(self.allocator.tables),
+                        _host(self.allocator.lengths),
+                        _host(tok_mat), _host(drafts),
+                        _host(temperatures, np.float32),
+                        _host(ok), _host(block_ids),
+                        _host(offsets), _host(step.cow_src),
+                        _host(step.cow_dst), self._next_key())
+                args += self._poison_arg(poison)
+            with span("verify.dispatch"):
+                self.cache, toks, counts, *finite = self.verify_compiled(
+                    *args)
+            with span("verify.wait"):
+                # the advance needs the accepted counts, so here it
+                # cannot hide under the device's work as decode's does
+                if finite:
+                    self.last_finite = np.asarray(finite[0])
+                toks, counts = np.asarray(toks), np.asarray(counts)
+            with span("verify.advance"):
+                okidx = np.flatnonzero(ok)
+                self.allocator.advance_counts(
+                    list(okidx), [int(counts[s]) for s in okidx])
+            return toks, counts
 
     def release_slot(self, slot: int) -> None:
         """Retire ``slot``: drop its block references on the host
@@ -945,8 +991,9 @@ class PagedServingEngine(ServingEngine):
         if not 0 <= int(slot) < self.max_seqs:
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {self.max_seqs})")
-        self.allocator.release(slot)
-        self.cache = self.release_compiled(self.cache)
+        with span("engine.release", slot=int(slot)):
+            self.allocator.release(slot)
+            self.cache = self.release_compiled(self.cache)
 
     # -- capacity -----------------------------------------------------------
 

@@ -69,6 +69,7 @@ import numpy as np
 from apex_tpu.observability import get_registry
 from apex_tpu.observability.reqtrace import (LATENCY_BUCKETS_MS,
                                              RequestRecord)
+from apex_tpu.observability.trace import span
 from apex_tpu.serving.cache import PoolExhausted
 from apex_tpu.serving.resilience import Rejection
 
@@ -276,6 +277,12 @@ class SlotScheduler:
         ``draining`` during :meth:`drain`). Malformed input still
         RAISES: a load condition is the server's problem, a bad request
         is the caller's."""
+        # an id the caller left out is the next one _submit will give
+        with span("sched.submit", request_id=self._next_id
+                  if request.request_id is None else request.request_id):
+            return self._submit(request)
+
+    def _submit(self, request: Request) -> Union[int, Rejection]:
         # validate HERE, not at admission: a bad request must bounce off
         # the caller, never kill the serving loop mid-step (by then it
         # has already been popped from the queue and other admissions
@@ -543,54 +550,62 @@ class SlotScheduler:
                 self.queue.appendleft((req, rec))
                 break
             slot = self.free.pop()
-            rec.admit_t = now
-            rec.slot = slot
-            try:
-                first = self.engine.prefill(req.prompt, slot,
-                                            req.temperature)
-            except PoolExhausted:
-                # can_admit is conservative but the shared-path COW
-                # headroom can still miss by a block under extreme
-                # pressure: requeue, never error-retire (host rolled
-                # the partial allocation back)
-                self.free.append(slot)
-                self.queue.appendleft((req, rec))
-                break
-            except Exception:
-                # the popped request must not vanish: retire it as an
-                # error (host bookkeeping only — the slot never held a
-                # cursor) and surface the engine fault to the caller
-                self.free.append(slot)
-                self._retire_queued(req, rec, "error", now)
-                raise
-            # prefill() syncs on the sampled token, so this stamp is the
-            # honest first-token time (prefill-done == first-token: the
-            # admission program samples it)
-            rec.prefill_done_t = rec.first_token_t = time.perf_counter()
-            st = _Active(req, [], len(req.prompt), rec,
-                         deadline_t=deadline)
-            self.active[slot] = st
-            self._temps[slot] = req.temperature
-            self._reg.counter("serve/admitted").inc()
-            self._reg.counter("serve/prefill_tokens").inc(len(req.prompt))
-            plan = getattr(self.engine, "last_admit", None)
-            if plan is not None and not plan.prefill:
-                # a prefix-shared admission: the shared span skipped
-                # prefill entirely — serve/ttft_prefix_ms is the TTFT
-                # histogram the acceptance bar compares against the
-                # cold serve/ttft_ms population
-                self._reg.counter("serve/prefix_hits").inc()
-                self._reg.counter("serve/prefix_hit_tokens").inc(
-                    plan.shared_tokens)
-                self._reg.histogram("serve/ttft_prefix_ms",
-                                    LATENCY_BUCKETS_MS).observe(
-                    (rec.first_token_t - rec.admit_t) * 1e3)
+            with span("sched.admit", request_id=req.request_id, slot=slot,
+                      prompt_len=len(req.prompt)):
+                if not self._admit_into(slot, req, rec, now, deadline):
+                    break
             admitted += 1
-            # the prefill already sampled this request's first token —
-            # it may even complete here (max_new_tokens == 1)
-            self._record(first, st, slot, rec.first_token_t,
-                         is_tick=False)
         return admitted
+
+    def _admit_into(self, slot: int, req: Request, rec: RequestRecord,
+                    now: float, deadline: Optional[float]) -> bool:
+        """Prefill ``req`` into ``slot`` (already taken off the free
+        list) and record its first token; False when the pool ran out
+        and the request went back to the head of the queue."""
+        rec.admit_t = now
+        rec.slot = slot
+        try:
+            first = self.engine.prefill(req.prompt, slot, req.temperature)
+        except PoolExhausted:
+            # can_admit is conservative but the shared-path COW
+            # headroom can still miss by a block under extreme
+            # pressure: requeue, never error-retire (host rolled
+            # the partial allocation back)
+            self.free.append(slot)
+            self.queue.appendleft((req, rec))
+            return False
+        except Exception:
+            # the popped request must not vanish: retire it as an
+            # error (host bookkeeping only — the slot never held a
+            # cursor) and surface the engine fault to the caller
+            self.free.append(slot)
+            self._retire_queued(req, rec, "error", now)
+            raise
+        # prefill() syncs on the sampled token, so this stamp is the
+        # honest first-token time (prefill-done == first-token: the
+        # admission program samples it)
+        rec.prefill_done_t = rec.first_token_t = time.perf_counter()
+        st = _Active(req, [], len(req.prompt), rec, deadline_t=deadline)
+        self.active[slot] = st
+        self._temps[slot] = req.temperature
+        self._reg.counter("serve/admitted").inc()
+        self._reg.counter("serve/prefill_tokens").inc(len(req.prompt))
+        plan = getattr(self.engine, "last_admit", None)
+        if plan is not None and not plan.prefill:
+            # a prefix-shared admission: the shared span skipped
+            # prefill entirely — serve/ttft_prefix_ms is the TTFT
+            # histogram the acceptance bar compares against the
+            # cold serve/ttft_ms population
+            self._reg.counter("serve/prefix_hits").inc()
+            self._reg.counter("serve/prefix_hit_tokens").inc(
+                plan.shared_tokens)
+            self._reg.histogram("serve/ttft_prefix_ms",
+                                LATENCY_BUCKETS_MS).observe(
+                (rec.first_token_t - rec.admit_t) * 1e3)
+        # the prefill already sampled this request's first token —
+        # it may even complete here (max_new_tokens == 1)
+        self._record(first, st, slot, rec.first_token_t, is_tick=False)
+        return True
 
     def step(self) -> int:
         """Expire what's overdue, admit whatever fits (skipped while
@@ -602,113 +617,132 @@ class SlotScheduler:
         request ``finish_reason="error"`` (slots released, records
         stamped, completions visible) before re-raising — a dead decode
         never strands ``active`` state."""
-        if self._tok_t0 is None:
-            self._tok_t0 = time.perf_counter()
-        before = self._tok_count
-        self._expire_queued(time.perf_counter())
-        try:
-            if not self._draining:
-                self._admit()
-            if self.active:
-                # satellite of the paged PR: a slot AT capacity must
-                # retire loudly BEFORE the decode dispatch — its append
-                # would be dropped (KVCache.append writes nothing at
-                # max_len; the paged pool has no block to give), so one
-                # more step would sample a token whose KV never landed
-                now = time.perf_counter()
-                for slot in list(self.active):
-                    if self.active[slot].position >= self.engine.max_len:
-                        self._retire(slot, "capacity", now)
-            if self.active:
-                step_idx = self.steps + 1  # this decode step, 1-based
-                poison = None
-                if self.fault_plan is not None:
-                    self.fault_plan.before_decode(step_idx)
-                    pslot = self.fault_plan.poison_slot(step_idx)
-                    if pslot is not None:
-                        poison = np.zeros(self.engine.max_seqs,
-                                          np.float32)
-                        poison[pslot] = np.nan
-                mask = np.zeros(self.engine.max_seqs, np.bool_)
-                mask[list(self.active)] = True
-                counts = None
-                if self.speculate_k:
-                    nxt, counts = self.engine.verify(
-                        self._tokens, self._build_drafts(), self._temps,
-                        mask, poison=poison)
-                else:
-                    nxt = self.engine.decode(self._tokens, self._temps,
-                                             mask, poison=poison)
-                self.steps = step_idx
-                self._reg.counter("serve/decode_steps").inc()
-                finite = (self.engine.last_finite
-                          if self.engine.quarantine else None)
-                # ONE stamp for the whole grid's tick (decode() synced on
-                # the fetched tokens) — the per-transition overhead
-                # contract
-                now = time.perf_counter()
-                if counts is not None:
-                    self._reg.counter("serve/spec_steps").inc()
-                    drafted = int(mask.sum()) * self.speculate_k
-                    self._spec_drafted += drafted
-                    self._reg.counter("serve/spec_drafted").inc(drafted)
-                # snapshot: _record may retire and free slots mid-harvest
-                accepted = 0
-                for slot in list(self.active):
-                    if finite is not None and not finite[slot]:
-                        # the poison-slot quarantine: retire ONLY this
-                        # slot; its sampled token is garbage-from-NaN and
-                        # is discarded, every neighbor harvests normally
-                        self._quarantine(slot, now)
-                        continue
-                    if counts is None:
-                        self._record(int(nxt[slot]), self.active[slot],
-                                     slot, now, is_tick=True)
-                        continue
-                    # speculative harvest: the accepted prefix plus one
-                    # correction/bonus token. The engine already advanced
-                    # this slot's cursor by EXACTLY counts[slot], so a
-                    # retirement mid-harvest (eos / length / capacity)
-                    # abandons only tokens whose KV sits above the
-                    # cursor — a re-admitted slot can never read a
-                    # drafted-but-rejected entry
-                    accepted += max(0, int(counts[slot]) - 1)
-                    st = self.active[slot]
-                    for j in range(int(counts[slot])):
-                        self._record(int(nxt[slot, j]), st, slot, now,
-                                     is_tick=True)
-                        if slot not in self.active:
-                            break
-                if counts is not None:
-                    self._spec_accepted += accepted
-                    if accepted:
-                        self._reg.counter("serve/spec_accepted").inc(
-                            accepted)
-                    if self._spec_drafted:
-                        self._reg.gauge("serve/spec_accept_rate").set(
-                            self._spec_accepted / self._spec_drafted)
-                # paged engines: slots the exhausted pool could not
-                # give a write block retire loudly as "capacity" — this
-                # step's sampled token is valid (the kernel merges the
-                # current token in-flight) but its KV was dropped, so
-                # one more step would decode against a hole. On the
-                # speculative path a failed slot's window aimed at the
-                # null block and its count came back 0, so it emitted
-                # nothing this step before retiring
-                for slot in getattr(self.engine, "last_failed", ()):
-                    if slot in self.active:
-                        self._retire(slot, "capacity", now)
-                # mid-flight deadline enforcement: overdue survivors of
-                # the harvest retire now, slot released for the next
-                # admission
-                for slot in list(self.active):
-                    st = self.active[slot]
-                    if st.deadline_t is not None and now >= st.deadline_t:
-                        self._retire(slot, "expired", now)
-        except Exception:
-            self._abort_in_flight()
-            raise
-        generated = self._tok_count - before
+        with span("sched.step", step=self.steps):
+            if self._tok_t0 is None:
+                self._tok_t0 = time.perf_counter()
+            before = self._tok_count
+            with span("sched.expire"):
+                self._expire_queued(time.perf_counter())
+            try:
+                if not self._draining:
+                    self._admit()
+                if self.active:
+                    # satellite of the paged PR: a slot AT capacity must
+                    # retire loudly BEFORE the decode dispatch — its
+                    # append would be dropped (KVCache.append writes
+                    # nothing at max_len; the paged pool has no block to
+                    # give), so one more step would sample a token whose
+                    # KV never landed
+                    now = time.perf_counter()
+                    for slot in list(self.active):
+                        if self.active[slot].position >= self.engine.max_len:
+                            self._retire(slot, "capacity", now)
+                if self.active:
+                    self._decode()
+            except Exception:
+                self._abort_in_flight()
+                raise
+            generated = self._tok_count - before
+            with span("sched.gauges"):
+                self._set_gauges(generated)
+            return generated
+
+    def _decode(self) -> None:
+        """One engine step for the whole slot grid, then the harvest of
+        what it sampled."""
+        step_idx = self.steps + 1  # this decode step, 1-based
+        poison = None
+        if self.fault_plan is not None:
+            self.fault_plan.before_decode(step_idx)
+            pslot = self.fault_plan.poison_slot(step_idx)
+            if pslot is not None:
+                poison = np.zeros(self.engine.max_seqs, np.float32)
+                poison[pslot] = np.nan
+        mask = np.zeros(self.engine.max_seqs, np.bool_)
+        mask[list(self.active)] = True
+        counts = None
+        if self.speculate_k:
+            nxt, counts = self.engine.verify(
+                self._tokens, self._build_drafts(), self._temps,
+                mask, poison=poison)
+        else:
+            nxt = self.engine.decode(self._tokens, self._temps,
+                                     mask, poison=poison)
+        self.steps = step_idx
+        self._reg.counter("serve/decode_steps").inc()
+        with span("sched.harvest"):
+            self._harvest(nxt, counts, mask)
+
+    def _harvest(self, nxt: np.ndarray, counts: Optional[np.ndarray],
+                 mask: np.ndarray) -> None:
+        """Record every slot's new token(s) and retire what finished,
+        was poisoned, starved or ran past its deadline."""
+        finite = (self.engine.last_finite
+                  if self.engine.quarantine else None)
+        # ONE stamp for the whole grid's tick (decode() synced on the
+        # fetched tokens) — the per-transition overhead contract
+        now = time.perf_counter()
+        if counts is not None:
+            self._reg.counter("serve/spec_steps").inc()
+            drafted = int(mask.sum()) * self.speculate_k
+            self._spec_drafted += drafted
+            self._reg.counter("serve/spec_drafted").inc(drafted)
+        # snapshot: _record may retire and free slots mid-harvest
+        accepted = 0
+        for slot in list(self.active):
+            if finite is not None and not finite[slot]:
+                # the poison-slot quarantine: retire ONLY this
+                # slot; its sampled token is garbage-from-NaN and
+                # is discarded, every neighbor harvests normally
+                self._quarantine(slot, now)
+                continue
+            if counts is None:
+                self._record(int(nxt[slot]), self.active[slot],
+                             slot, now, is_tick=True)
+                continue
+            # speculative harvest: the accepted prefix plus one
+            # correction/bonus token. The engine already advanced
+            # this slot's cursor by EXACTLY counts[slot], so a
+            # retirement mid-harvest (eos / length / capacity)
+            # abandons only tokens whose KV sits above the
+            # cursor — a re-admitted slot can never read a
+            # drafted-but-rejected entry
+            accepted += max(0, int(counts[slot]) - 1)
+            st = self.active[slot]
+            for j in range(int(counts[slot])):
+                self._record(int(nxt[slot, j]), st, slot, now,
+                             is_tick=True)
+                if slot not in self.active:
+                    break
+        if counts is not None:
+            self._spec_accepted += accepted
+            if accepted:
+                self._reg.counter("serve/spec_accepted").inc(
+                    accepted)
+            if self._spec_drafted:
+                self._reg.gauge("serve/spec_accept_rate").set(
+                    self._spec_accepted / self._spec_drafted)
+        # paged engines: slots the exhausted pool could not
+        # give a write block retire loudly as "capacity" — this
+        # step's sampled token is valid (the kernel merges the
+        # current token in-flight) but its KV was dropped, so
+        # one more step would decode against a hole. On the
+        # speculative path a failed slot's window aimed at the
+        # null block and its count came back 0, so it emitted
+        # nothing this step before retiring
+        for slot in getattr(self.engine, "last_failed", ()):
+            if slot in self.active:
+                self._retire(slot, "capacity", now)
+        # mid-flight deadline enforcement: overdue survivors of
+        # the harvest retire now, slot released for the next
+        # admission
+        for slot in list(self.active):
+            st = self.active[slot]
+            if st.deadline_t is not None and now >= st.deadline_t:
+                self._retire(slot, "expired", now)
+
+    def _set_gauges(self, generated: int) -> None:
+        """The ``serve/*`` counters and gauges of one finished step."""
         self._reg.counter("serve/generated_tokens").inc(generated)
         self._reg.gauge("serve/queue_depth").set(len(self.queue))
         self._reg.gauge("serve/active_slots").set(len(self.active))
@@ -732,7 +766,6 @@ class SlotScheduler:
         if elapsed > 0:
             self._reg.gauge("serve/tokens_per_sec").set(
                 self._tok_count / elapsed)
-        return generated
 
     # -- resilience surface -------------------------------------------------
 

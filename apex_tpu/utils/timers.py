@@ -178,6 +178,18 @@ class Timers:
 @contextlib.contextmanager
 def profile_trace(log_dir: str, host_tracer_level: int = 2):
     """``jax.profiler.trace`` wrapper — step 2 of the annotate -> trace ->
-    attribute workflow (module docstring). View with tensorboard/xprof."""
-    with jax.profiler.trace(log_dir, create_perfetto_link=False):
+    attribute workflow (module docstring). View with tensorboard/xprof.
+
+    The capture holds the device's ops and, on plane ``/host:CPU`` on the
+    same clock, the program's own ``apex:`` spans
+    (:func:`apex_tpu.observability.trace.span`: ``apex:sched.step``,
+    ``apex:engine.decode``, ``apex:decode.wait``, ...), so a device idle
+    gap can be put down to what the host was doing. ``host_tracer_level``
+    is the profiler's own option: 0 records no annotation at all, 1 the
+    ``apex:`` spans and other user annotations, 2 (default) and 3 the
+    runtime's own finer ones too."""
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    with jax.profiler.trace(log_dir, create_perfetto_link=False,
+                            profiler_options=options):
         yield

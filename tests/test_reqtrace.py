@@ -432,7 +432,9 @@ class TestTracingZeroCost:
             return ServingEngine(model, params, max_seqs=2, max_len=16,
                                  prefill_len=4)
 
-        eng_off, eng_on = build(), build()
+        # one call site for both: the compiled text carries the line and
+        # column of every frame that led to the trace
+        eng_off, eng_on = [build() for _ in range(2)]
         reqs = [Request(prompt=[1 + i, 2], max_new_tokens=3)
                 for i in range(3)]
         sched_off = SlotScheduler(eng_off, registry=MetricsRegistry())
