@@ -650,13 +650,17 @@ class GPTModel:
         # step (see KVCache.append)
         return logits, cache.append(k_new, v_new, active)
 
-    def _paged_decode_layer(self, lp: dict, x: jnp.ndarray, layer_pool,
+    def _paged_decode_layer(self, lp: dict, x: jnp.ndarray, cache, layer,
                             block_tables: jnp.ndarray,
-                            lengths: jnp.ndarray,
+                            lengths: jnp.ndarray, block_ids: jnp.ndarray,
+                            offsets: jnp.ndarray,
                             mean_context: Optional[float]):
         """One layer of the paged decode step: like :meth:`_decode_layer`
         but the context comes through each slot's block table, so only
-        ~ceil(cursor/block_size) pool blocks are streamed per slot."""
+        ~ceil(cursor/block_size) pool blocks are streamed per slot. The
+        kernel reads layer ``layer`` of the stacked pool where it lies;
+        the layer then writes its own new K/V row there (after the read:
+        the current token reaches attention through the merge)."""
         cfg = self.cfg
         h = self._ln(lp["ln1"], x)
         with jax.named_scope("gpt_attention"):
@@ -664,15 +668,16 @@ class GPTModel:
             S = qkv.shape[0]
             qkv = qkv.reshape(S, cfg.num_attention_heads, 3 * cfg.head_dim)
             q, k_new, v_new = jnp.split(qkv, 3, axis=-1)   # (S, H, D)
-            kp, vp, ksc, vsc = layer_pool
             ctx = paged_decode_attention(
-                q, kp, vp, block_tables, lengths, k_new=k_new,
-                v_new=v_new, k_scale=ksc, v_scale=vsc,
-                mean_context=mean_context, use_pallas=cfg.use_flash)
+                q, cache.k, cache.v, layer, block_tables, lengths,
+                k_new=k_new, v_new=v_new, k_scale=cache.k_scale,
+                v_scale=cache.v_scale, mean_context=mean_context,
+                use_pallas=cfg.use_flash)
+            cache = cache.append(layer, k_new, v_new, block_ids, offsets)
             out, _ = self.proj(lp["proj"], ctx.reshape(S, 1, -1))
         x = x + out
         x = x + self._mlp(lp, self._ln(lp["ln2"], x))
-        return x, (k_new, v_new)
+        return x, cache
 
     def _paged_prefill_forward(self, params, tokens, cache, block_row,
                                prompt_len, last_logit_only=False):
@@ -738,25 +743,30 @@ class GPTModel:
                 axis=0)[:, None]
             x = (h + pos).astype(cfg.compute_dtype)
 
-        xs = (params["layers"], cache.k, cache.v)
-        if cache.quantized:
-            xs = xs + (cache.k_scale, cache.v_scale)
+        block_ids = jnp.asarray(block_ids, jnp.int32)
+        offsets = jnp.asarray(offsets, jnp.int32)
 
-        def body(x, lp_c):
-            lp, kp, vp = lp_c[:3]
-            ksc, vsc = (lp_c[3], lp_c[4]) if cache.quantized else (None,
-                                                                   None)
-            return self._paged_decode_layer(lp, x, (kp, vp, ksc, vsc),
-                                            block_tables, lengths,
-                                            mean_context)
-
-        x, (k_new, v_new) = scan_stable_vma(body, x, xs,
-                                            unroll=cfg.layer_scan_unroll)
+        x, cache = self._scan_paged_layers(
+            self._paged_decode_layer, params, x, cache, block_tables,
+            lengths, block_ids, offsets, mean_context)
         x = self._ln(params["final_ln"], x)
-        logits = self.logits(params, x)[:, 0]
-        return logits, cache.append(k_new, v_new,
-                                    jnp.asarray(block_ids, jnp.int32),
-                                    jnp.asarray(offsets, jnp.int32))
+        return self.logits(params, x)[:, 0], cache
+
+    def _scan_paged_layers(self, layer_fn, params, x, cache, *args):
+        """``layer_fn(lp, x, cache, layer, *args) -> (x, cache)`` over the
+        layer stack. The pool is CARRIED, not scanned over: a scan's xs
+        are sliced per layer, and a slice of the pool is a copy of it."""
+        cfg = self.cfg
+
+        def body(carry, layer_lp):
+            layer, lp = layer_lp
+            return layer_fn(lp, *carry, layer, *args), None
+
+        carry, _ = scan_stable_vma(
+            body, (x, cache),
+            (jnp.arange(cfg.num_layers, dtype=jnp.int32), params["layers"]),
+            unroll=cfg.layer_scan_unroll)
+        return carry
 
     # -- serving: speculative k-token verify --------------------------------
 
@@ -812,87 +822,100 @@ class GPTModel:
         x = x + self._mlp(lp, self._ln(lp["ln2"], x))
         return x, (k_new, v_new)
 
-    def _paged_verify_layer(self, lp: dict, x: jnp.ndarray, layer_pool,
+    def _paged_verify_layer(self, lp: dict, x: jnp.ndarray, cache, layer,
                             block_tables: jnp.ndarray,
-                            lengths: jnp.ndarray,
+                            lengths: jnp.ndarray, block_ids: jnp.ndarray,
+                            offsets: jnp.ndarray,
                             mean_context: Optional[float]):
         """One layer of the PAGED verify step: the bounded block-table
-        fetch of :meth:`_paged_decode_layer`, amortized over Q rows."""
+        fetch of :meth:`_paged_decode_layer`, amortized over Q rows, and
+        the layer's write of the whole window (rejected rows land above
+        the cursor, see ``PagedKVCache.append_k``)."""
         cfg = self.cfg
         h = self._ln(lp["ln1"], x)
         with jax.named_scope("gpt_attention"):
             (q, k_new, v_new), roundtrip = self._verify_qkv(lp, h)
-            kp, vp, ksc, vsc = layer_pool
-            quantized = ksc is not None
             ctx = paged_decode_attention(
-                q, kp, vp, block_tables, lengths, k_new=k_new,
-                v_new=v_new, k_scale=ksc, v_scale=vsc,
-                mean_context=mean_context, use_pallas=cfg.use_flash,
-                k_cast=roundtrip(k_new, kp.dtype, quantized),
-                v_cast=roundtrip(v_new, kp.dtype, quantized))
+                q, cache.k, cache.v, layer, block_tables, lengths,
+                k_new=k_new, v_new=v_new, k_scale=cache.k_scale,
+                v_scale=cache.v_scale, mean_context=mean_context,
+                use_pallas=cfg.use_flash,
+                k_cast=roundtrip(k_new, cache.k.dtype, cache.quantized),
+                v_cast=roundtrip(v_new, cache.k.dtype, cache.quantized))
+            cache = cache.append_k(layer, k_new, v_new, block_ids, offsets)
             S, _, Q, _ = ctx.shape
             out, _ = self.proj(lp["proj"],
                                ctx.transpose(0, 2, 1, 3).reshape(S, Q, -1))
         x = x + out
         x = x + self._mlp(lp, self._ln(lp["ln2"], x))
-        return x, (k_new, v_new)
+        return x, cache
 
     def verify_forward(self, params: dict, tokens: jnp.ndarray, kv_cache,
                        block_tables: Optional[jnp.ndarray] = None,
                        lengths: Optional[jnp.ndarray] = None,
+                       append_block_ids: Optional[jnp.ndarray] = None,
+                       append_offsets: Optional[jnp.ndarray] = None,
                        cow_src: Optional[jnp.ndarray] = None,
                        cow_dst: Optional[jnp.ndarray] = None,
                        mean_context: Optional[float] = None):
         """Speculative verify: score ``tokens (max_seqs, Q)`` — each
         slot's last accepted token plus its ``Q - 1`` drafts — in ONE
         pass over the cached prefix. Returns ``(logits (S, Q, vocab),
-        (k_new, v_new) (L, S, H, Q, D), cache)`` — the cache comes back
-        WITHOUT the window appended (for the paged pool it has only the
-        COW pairs resolved): the engine decides the accepted counts from
-        the logits first and then appends via ``append_k``, all inside
-        the same AOT program. Dense caches read ``kv_cache.lengths``;
-        the paged pool takes the host table/cursor mirrors like the
-        decode leg."""
+        new_kv, cache)``.
+
+        Dense caches read ``kv_cache.lengths`` and come back WITHOUT the
+        window appended, ``new_kv`` being ``(k_new, v_new) (L, S, H, Q,
+        D)``: the engine decides the accepted counts from the logits
+        first (they move the dense cursor) and then appends via
+        ``append_k``, all inside the same AOT program. The paged pool
+        takes the host table/cursor mirrors like the decode leg, resolves
+        its COW pairs first, and every layer writes its whole window at
+        ``append_block_ids``/``append_offsets`` ``(S, Q)`` as it goes
+        (the accepted counts move only the HOST cursor, so nothing waits
+        for them); ``new_kv`` is ``None``."""
         self._require_cacheable()
         cfg = self.cfg
         if tokens.ndim != 2:
             raise ValueError(f"verify tokens must be (max_seqs, Q), got "
                              f"{tokens.shape}")
         from apex_tpu.serving.cache import PagedKVCache
-        paged = isinstance(kv_cache, PagedKVCache)
-        if paged:
-            if block_tables is None or lengths is None:
-                raise ValueError("paged verify needs block_tables and "
-                                 "lengths")
+        if isinstance(kv_cache, PagedKVCache):
+            if block_tables is None or lengths is None \
+                    or append_block_ids is None or append_offsets is None:
+                raise ValueError("paged verify needs block_tables, lengths, "
+                                 "append_block_ids and append_offsets")
             lengths = jnp.asarray(lengths, jnp.int32)
+            block_ids = jnp.asarray(append_block_ids, jnp.int32)
+            offsets = jnp.asarray(append_offsets, jnp.int32)
             # copy-on-write FIRST — same sequencing as the decode leg
             if cow_src is not None:
                 kv_cache = kv_cache.cow_copy(
                     jnp.asarray(cow_src, jnp.int32),
                     jnp.asarray(cow_dst, jnp.int32))
+            x, kv_cache = self._scan_paged_layers(
+                self._paged_verify_layer, params,
+                self._verify_embed(params, tokens, lengths), kv_cache,
+                block_tables, lengths, block_ids, offsets, mean_context)
+            new_kv = None
         else:
             lengths = kv_cache.lengths
-        x = self._verify_embed(params, tokens, lengths)
+            x = self._verify_embed(params, tokens, lengths)
+            xs = (params["layers"], kv_cache.k, kv_cache.v)
+            if kv_cache.quantized:
+                xs = xs + (kv_cache.k_scale, kv_cache.v_scale)
 
-        xs = (params["layers"], kv_cache.k, kv_cache.v)
-        if kv_cache.quantized:
-            xs = xs + (kv_cache.k_scale, kv_cache.v_scale)
+            def body(x, lp_c):
+                lp, ck, cv = lp_c[:3]
+                ksc, vsc = (lp_c[3], lp_c[4]) if kv_cache.quantized else \
+                    (None, None)
+                return self._verify_layer(lp, x, (ck, cv, ksc, vsc),
+                                          lengths)
 
-        def body(x, lp_c):
-            lp, ck, cv = lp_c[:3]
-            ksc, vsc = (lp_c[3], lp_c[4]) if kv_cache.quantized else \
-                (None, None)
-            if paged:
-                return self._paged_verify_layer(
-                    lp, x, (ck, cv, ksc, vsc), block_tables, lengths,
-                    mean_context)
-            return self._verify_layer(lp, x, (ck, cv, ksc, vsc), lengths)
-
-        x, (k_new, v_new) = scan_stable_vma(body, x, xs,
-                                            unroll=cfg.layer_scan_unroll)
+            x, new_kv = scan_stable_vma(body, x, xs,
+                                        unroll=cfg.layer_scan_unroll)
         x = self._ln(params["final_ln"], x)
         logits = self.logits(params, x)            # (S, Q, vocab)
-        return logits, (k_new, v_new), kv_cache
+        return logits, new_kv, kv_cache
 
     def sp_grad_sync(self, grads: dict) -> dict:
         """Megatron-LM allreduces the grads of ``sequence_parallel``-marked

@@ -1349,24 +1349,48 @@ def decode_attention(q, k, v, lengths, k_new=None, v_new=None,
 # PagedAttention (Kwon et al.) brought to Pallas. The dense kernel above
 # streams a per-slot ``(max_len, d)`` stripe and only SKIPS the compute
 # past the cursor — its pipelined HBM fetches stay O(max_len). Here the
-# cache is a global block pool ``(num_blocks, h, block_size, d)`` and each
-# slot owns an int32 row of pool indices (its block table), so:
+# cache is the engine's whole block pool, taken AS IT IS STORED —
+# ``(layers, num_blocks, block_size, h * d)``, token-major, all layers
+# stacked — and each slot owns an int32 row of pool indices (its block
+# table), so:
 #
-# - the per-slot block table and cursor ride as SCALAR-PREFETCH arguments
-#   (``pltpu.PrefetchScalarGridSpec``): they are resident before the grid
-#   starts, and the K/V BlockSpec index maps read them to aim each fetch
-#   at ``table[slot, j]`` — the pool block holding that slot's j-th
-#   logical block;
+# - why that shape: a Mosaic call demands its operands row-major with the
+#   last dimension on the 128 lanes. A pool whose last dimension is d = 64
+#   half-fills a lane tile, so XLA keeps such an array with the block axis
+#   on the lanes instead (layout ``{3,4,2,1,0}``) and has to relay every
+#   layer's slice to a lane-PADDED row-major image before the kernel may
+#   read it (twice the bytes, written and read again, every layer, every
+#   step). With the heads fused into the last dimension (h * d lanes, a
+#   multiple of 128 at every published width) the resident layout IS the
+#   row-major one and nothing in the decode program slices, copies or
+#   relays the pool: the kernel takes the stacked pool and a LAYER INDEX;
+# - the layer index, the per-slot block table and the cursor ride as
+#   SCALAR-PREFETCH arguments (``pltpu.PrefetchScalarGridSpec``): they are
+#   resident before the grid starts, and the K/V BlockSpec index maps read
+#   them to aim each fetch at ``(layer, table[slot, j], 0, 0)`` — one whole
+#   ``(block_size, h * d)`` pool block, all heads, lane-dense;
 # - the fetch sequence is bounded by the cursor: past the slot's last
 #   valid block the index map CLAMPS to that block, so consecutive grid
 #   steps resolve to the SAME pool block and the Pallas pipeline elides
 #   the re-fetch (equal block index => no new DMA) — HBM traffic per slot
 #   per step is O(actual_context), not O(max_len). Compute past the
 #   cursor is skipped with the same ``@pl.when`` the dense kernel uses;
-# - the online-softmax recurrence, the int8 blockwise dequant (scales are
-#   pooled alongside the blocks), the -inf empty-row convention and the
-#   exact two-way ``_merge_current`` with the current token are the dense
-#   kernel's, unchanged — the parity tests pin all of them to
+# - all heads of a block are scored at once and no lane is ever sliced:
+#   the query comes in BLOCK-DIAGONAL, ``(h, h * d)`` with head g's row
+#   holding q[g] in lanes [g*d, (g+1)*d) and exact zeros elsewhere, so
+#   ``q_bd @ K^T`` is the ``(h, block_size)`` per-head score tile. The
+#   other heads' keys are multiplied by exact zeros, so each score — and
+#   with it m, l and the per-head ``lse`` — holds its own head's products
+#   only. ``p @ V`` is ``(h, h * d)``: row g carries head g's weights
+#   over EVERY head's values; the caller keeps the diagonal ``d``-wide
+#   pieces (a select, not a product: the cross-head pieces are masked
+#   before anything is summed);
+# - the online-softmax recurrence, the int8 blockwise dequant (the pooled
+#   per-(position, head) scales ride ``(layers, num_blocks, h,
+#   block_size)`` and scale the score tile and the weights, which is the
+#   dequantized product reassociated), the -inf empty-row convention and
+#   the exact two-way ``_merge_current`` with the current token are the
+#   dense kernel's — the parity tests pin all of them to
 #   ``mha_reference(kv_length=)``;
 # - ``mean_context`` (an expected-occupancy hint, tokens) sizes the
 #   ``pl.CostEstimate`` attached to the kernel so the pyprof roofline
@@ -1374,10 +1398,11 @@ def decode_attention(q, k, v, lengths, k_new=None, v_new=None,
 #   (``pyprof/model.py`` reads it off the ``pallas_call`` eqn). It never
 #   changes the math — only the modeled bytes.
 
-def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, ksc_ref,
-                         vsc_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                         scale, block_size, n_blocks):
-    s, j = pl.program_id(0), pl.program_id(2)
+def _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref,
+                         ksc_ref, vsc_ref, o_ref, lse_ref, acc_ref, m_ref,
+                         l_ref, *, scale, block_size, n_blocks, q_len):
+    del layer_ref, tab_ref                  # the index maps' business
+    s, j = pl.program_id(0), pl.program_id(1)
     length = len_ref[s]
 
     @pl.when(j == 0)
@@ -1390,48 +1415,54 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, ksc_ref,
     # the clamped index map (see the section comment)
     @pl.when(j * block_size < length)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)       # (q_len, d)
-        k = k_ref[0, 0]                           # (block_size, d)
-        v = v_ref[0, 0]
-        if ksc_ref is not None:
-            # int8 pool: dequantize blockwise in VMEM against the pooled
-            # per-(position, head) scales — HBM only ever holds int8
-            k = k.astype(jnp.float32) * ksc_ref[0, 0].T
-            v = v.astype(jnp.float32) * vsc_ref[0, 0].T
-        s_ = jax.lax.dot_general(q, k.astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-        col = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
-        s_ = jnp.where(col < length, s_, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=1, keepdims=True))
-        p = jnp.exp(s_ - m_new)
-        p = jnp.where(col < length, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:] = m_new
-        pv = jax.lax.dot_general(p, v.astype(jnp.float32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv
+        k = k_ref[0, 0].astype(jnp.float32)       # (block_size, h*d)
+        v = v_ref[0, 0].astype(jnp.float32)
+        live = j * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_size), 1) < length
+        # one (h, h*d) block-diagonal query tile per in-flight row: every
+        # array below is (h, ·), the same program at q_len 1 and q_len k
+        for i in range(q_len):
+            q = q_ref[0, i].astype(jnp.float32)   # (h, h*d)
+            s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32
+                                     ) * scale    # (h, block_size)
+            if ksc_ref is not None:
+                # int8 pool: q . (k_q * scale) == (q . k_q) * scale — HBM
+                # and VMEM only ever hold int8 blocks
+                s_ = s_ * ksc_ref[0, 0]
+            s_ = jnp.where(live, s_, NEG_INF)
+            m_prev = m_ref[i]
+            m_new = jnp.maximum(m_prev, jnp.max(s_, axis=1, keepdims=True))
+            p = jnp.where(live, jnp.exp(s_ - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[i] = l_ref[i] * corr + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[i] = m_new
+            if vsc_ref is not None:
+                p = p * vsc_ref[0, 0]
+            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_ref[i] = acc_ref[i] * corr + pv   # (h, h*d)
 
     @pl.when(j == n_blocks - 1)
     def _():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         # -inf on empty rows: the identity of the _merge_current fold
-        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l == 0.0, -jnp.inf,
-                                  m_ref[:] + jnp.log(safe_l))
+        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(l == 0.0, -jnp.inf,
+                               m_ref[:] + jnp.log(safe_l))
 
 
 def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
-                mean_context, q_len=1):
+                mean_context, q_len=1, q_itemsize=2):
     """``pl.CostEstimate`` for one paged decode call: the fetch-elided
     HBM bytes at ``mean_context`` tokens of ACTUAL context per slot (the
     index-map clamp makes repeated blocks free), so the pyprof roofline
-    prices what the kernel moves, not the worst-case table span.
+    prices what the kernel moves, not the worst-case table span. The
+    K/V fetches are dense ``(block_size, h * d)`` blocks; the query and
+    the output ride block-diagonal, ``h`` times their useful size, and
+    the FLOPs are those the MXU is issued for them (``h`` times the
+    algorithm's: the other heads' lanes are multiplied by zeros).
 
     ``q_len > 1`` is the speculative verify call: the MXU work and the
     q/out traffic scale by q_len, but the dominant KV stream does NOT —
@@ -1447,33 +1478,37 @@ def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
     kv_bytes = 2.0 * s * h * ctx * d * itemsize
     if quantized:
         kv_bytes += 2.0 * s * h * ctx * 4
-    io_bytes = (kv_bytes + 2.0 * s * h * q_len * d * 4
-                + s * (n_blocks_slot + 1) * 4)
-    flops = 4.0 * s * h * ctx * d * q_len  # qk^T + pv, 2 MACs each
+    io_bytes = (kv_bytes + 2.0 * s * q_len * h * h * d * q_itemsize
+                + s * q_len * h * 4 + (s * (n_blocks_slot + 1) + 1) * 4)
+    flops = 4.0 * s * q_len * h * ctx * h * d  # qk^T + pv, 2 MACs each
     return pl.CostEstimate(flops=int(flops), bytes_accessed=int(io_bytes),
                            transcendentals=int(s * h * ctx * q_len))
 
 
-def _paged_decode_pallas(q, kp, vp, tables, lengths, ksc, vsc, *, scale,
-                         mean_context):
+def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
+                         scale, mean_context):
     # q is (S, h, q_len, d): q_len == 1 is the classic decode step,
     # q_len == k + 1 the speculative verify — ONE program shape for
-    # both. The q/out/lse blocks are rank-4 (1, 1, q_len, ·) so their
-    # last two dims equal the array's, which is what Mosaic's block rule
-    # asks of a lone query row (a rank-3 (1, 1, d) block over (S, h, d)
-    # is refused: 1 is neither h nor a multiple of 8). The kernel body
-    # is per-row throughout and the KV fetch sequence (and its clamp) is
-    # q_len-independent.
+    # both. Every block spans its array's last two dims whole — the
+    # (block_size, h*d) pool blocks, the (h, h*d) query/output tiles,
+    # the (h, block_size) scale tiles, the (h, 1) lse columns — which
+    # Mosaic accepts at any size; the KV fetch sequence (and its clamp)
+    # is q_len-independent.
     S, h, q_len, d = q.shape
-    _nb_pool, _, block_size, _ = kp.shape
+    block_size = kp.shape[2]
     n_blocks = tables.shape[1]
     has_scale = ksc is not None
 
-    def q_map(s, hh, j, tabs, lens):
-        return (s, hh, 0, 0)
-    q_block, lse_block = (1, 1, q_len, d), (1, 1, q_len, 1)
+    # block-diagonal query (see the section comment): (S, q_len, h, h*d)
+    eye = jnp.eye(h, dtype=jnp.bool_)
+    q_bd = jnp.where(eye[None, None, :, :, None],
+                     jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None, :],
+                     jnp.zeros((), q.dtype)).reshape(S, q_len, h, h * d)
 
-    def kv_map(s, hh, j, tabs, lens):
+    def q_map(s, j, lay, tabs, lens):
+        return (s, 0, 0, 0)
+
+    def kv_map(s, j, lay, tabs, lens):
         # clamp past-the-cursor steps to the slot's LAST valid block:
         # equal consecutive indices elide the fetch, which is what
         # bounds HBM traffic to the actual context. An empty slot
@@ -1482,58 +1517,60 @@ def _paged_decode_pallas(q, kp, vp, tables, lengths, ksc, vsc, *, scale,
         nb_valid = jnp.maximum(
             (lens[s] + block_size - 1) // block_size, 1)
         jj = jnp.minimum(j, nb_valid - 1)
-        return (tabs[s, jj], hh, 0, 0)
+        return (lay[0], tabs[s, jj], 0, 0)
 
-
-    in_specs = [pl.BlockSpec(q_block, q_map),
-                pl.BlockSpec((1, 1, block_size, d), kv_map),
-                pl.BlockSpec((1, 1, block_size, d), kv_map)]
-    args = [q, kp, vp]
+    kv_spec = pl.BlockSpec((1, 1, block_size, h * d), kv_map)
+    in_specs = [pl.BlockSpec((1, q_len, h, h * d), q_map), kv_spec, kv_spec]
+    args = [q_bd, kp, vp]
     if has_scale:
-        # pooled scales ride (num_blocks, h, 1, block_size) — the unit
-        # dim keeps the block's last two dims equal to the array's (see
-        # _decode_pallas) and lets them share the K/V index map
-        sc_spec = pl.BlockSpec((1, 1, 1, block_size), kv_map)
+        sc_spec = pl.BlockSpec((1, 1, h, block_size), kv_map)
         in_specs += [sc_spec, sc_spec]
-        args += [ksc[:, :, None, :], vsc[:, :, None, :]]
+        args += [ksc, vsc]
 
     def kernel(*refs):
         refs = list(refs)
-        tab_ref, len_ref, q_ref, k_ref, v_ref = refs[:5]
-        nxt = 5
+        layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref = refs[:6]
+        nxt = 6
         ksc_ref = refs[nxt] if has_scale else None
         vsc_ref = refs[nxt + 1] if has_scale else None
         nxt += 2 * has_scale
         o_ref, lse_ref, acc, m, l = refs[nxt:]
-        _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref,
-                             ksc_ref, vsc_ref, o_ref, lse_ref, acc, m, l,
-                             scale=scale, block_size=block_size,
-                             n_blocks=n_blocks)
+        _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref,
+                             v_ref, ksc_ref, vsc_ref, o_ref, lse_ref, acc,
+                             m, l, scale=scale, block_size=block_size,
+                             n_blocks=n_blocks, q_len=q_len)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, h, n_blocks),
+        num_scalar_prefetch=3,
+        grid=(S, n_blocks),
         in_specs=in_specs,
-        out_specs=(pl.BlockSpec(q_block, q_map),
-                   pl.BlockSpec(lse_block, q_map)),
-        scratch_shapes=[pltpu.VMEM((q_len, d), jnp.float32),
-                        pltpu.VMEM((q_len, 1), jnp.float32),
-                        pltpu.VMEM((q_len, 1), jnp.float32)])
+        out_specs=(pl.BlockSpec((1, q_len, h, h * d), q_map),
+                   pl.BlockSpec((1, q_len, h, 1), q_map)),
+        scratch_shapes=[pltpu.VMEM((q_len, h, h * d), jnp.float32),
+                        pltpu.VMEM((q_len, h, 1), jnp.float32),
+                        pltpu.VMEM((q_len, h, 1), jnp.float32)])
     out_dtype = q.dtype if q.dtype != jnp.int8 else jnp.float32
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((S, h, q_len, d), out_dtype),
-                   jax.ShapeDtypeStruct((S, h, q_len, 1), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((S, q_len, h, h * d), out_dtype),
+                   jax.ShapeDtypeStruct((S, q_len, h, 1), jnp.float32)),
         cost_estimate=_paged_cost(S, h, d, kp.dtype, has_scale, n_blocks,
-                                  block_size, mean_context, q_len=q_len),
+                                  block_size, mean_context, q_len=q_len,
+                                  q_itemsize=q.dtype.itemsize),
         interpret=_interp(),
         name="paged_decode_attention",
-    )(tables, lengths, *args)
-    return out, lse[..., 0]
+    )(jnp.reshape(layer, (1,)), tables, lengths, *args)
+    # keep each head's own d lanes of its (h*d)-wide row: a select, so a
+    # cross-head product is dropped before anything could be summed
+    out = jnp.sum(jnp.where(eye[None, None, :, :, None],
+                            out.reshape(S, q_len, h, h, d),
+                            jnp.zeros((), out_dtype)), axis=3)
+    return (jnp.transpose(out, (0, 2, 1, 3)),
+            jnp.transpose(lse[..., 0], (0, 2, 1)))
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
+def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                            k_new=None, v_new=None, k_scale=None,
                            v_scale=None,
                            softmax_scale: Optional[float] = None,
@@ -1553,9 +1590,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     Args:
       q: ``(b, h, d)`` — one query row per sequence slot — or
         ``(b, h, q_len, d)`` for the verify path.
-      k_pool, v_pool: ``(num_blocks, h, block_size, d)`` global block
-        pools (bf16/fp32, or int8 with pooled scales). Only the blocks a
+      k_pool, v_pool: ``(layers, num_blocks, block_size, h * d)`` — the
+        engine's block pools as :class:`~apex_tpu.serving.cache.
+        PagedKVCache` stores them (bf16/fp32, or int8 with pooled
+        scales), all layers stacked. Only the blocks of ``layer`` a
         slot's table names are ever read for it.
+      layer: int32 scalar (traced inside the layer scan, or a Python
+        int) — which layer's blocks to read.
       block_tables: ``(b, n_blocks_per_slot)`` int32 — pool indices of
         each slot's logical blocks, in order. Entries past
         ``ceil(length/block_size)`` are never read (the index map clamps
@@ -1565,8 +1606,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         (the current token is NOT in the cache; pass it via ``k_new``).
       k_new, v_new: optional ``(b, h, d)`` current token, folded in with
         the exact two-way LSE merge (empty prefix reduces to ``v_new``).
-      k_scale, v_scale: ``(num_blocks, h, block_size)`` fp32 pooled
-        dequantization scales, required iff the pool dtype is int8.
+      k_scale, v_scale: ``(layers, num_blocks, h, block_size)`` fp32
+        pooled dequantization scales, required iff the pool dtype is
+        int8.
       mean_context: expected ACTUAL context per slot (tokens), used only
         to size the kernel's ``CostEstimate`` for the pyprof roofline —
         never changes the math. Default: the worst-case table span.
@@ -1583,10 +1625,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     else:
         b, h, d = q.shape
         q_len = 1
-    nb_pool, hp, block_size, dp = k_pool.shape
-    if v_pool.shape != k_pool.shape or hp != h or dp != d:
-        raise ValueError(f"pool shapes {k_pool.shape}/{v_pool.shape} do "
-                         f"not match q {q.shape}")
+    if k_pool.ndim != 4 or v_pool.shape != k_pool.shape \
+            or k_pool.shape[3] != h * d:
+        raise ValueError(f"pool shapes {k_pool.shape}/{v_pool.shape} are "
+                         f"not (layers, num_blocks, block_size, h * d) "
+                         f"for q {q.shape}")
+    block_size = k_pool.shape[2]
     if block_tables.ndim != 2 or block_tables.shape[0] != b:
         raise ValueError(f"block_tables must be (b, n_blocks_per_slot), "
                          f"got {block_tables.shape}")
@@ -1597,18 +1641,18 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         softmax_scale = 1.0 / math.sqrt(d)
     if use_pallas is None:
         # no shape is refused: every block of the kernel spans its array's
-        # last two dims whole ((block_size, d) pool blocks, (q_len, d)
-        # query rows, (1, block_size) scale rows), which Mosaic accepts at
-        # any size. Small blocks are legal, not fast: block_size % 128 == 0
-        # keeps the score row lane-dense.
+        # last two dims whole, which Mosaic accepts at any size. Small
+        # blocks are legal, not fast: h * d % 128 == 0 keeps the pool
+        # blocks lane-dense, block_size % 128 == 0 the score tile.
         use_pallas = True
+    layer = jnp.asarray(layer, jnp.int32)
     block_tables = jnp.asarray(block_tables).astype(jnp.int32)
     lengths = jnp.asarray(lengths).astype(jnp.int32)
 
     with jax.named_scope("decode_attention"):
         if use_pallas:
             out, lse = _paged_decode_pallas(
-                q if multi else q[:, :, None, :], k_pool, v_pool,
+                q if multi else q[:, :, None, :], k_pool, v_pool, layer,
                 block_tables, lengths,
                 k_scale if quantized else None,
                 v_scale if quantized else None,
@@ -1625,19 +1669,20 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                 out = _merge_current(out, lse, q, k_new, v_new,
                                      float(softmax_scale), q.dtype)
             return out.astype(q.dtype)
-        # XLA fallback: gather the table-mapped blocks into the dense
-        # layout and run the dense fallback (one masked score pass +
-        # the same merge) — identical math, O(table span) traffic
+        # XLA fallback: gather the layer's table-mapped blocks into the
+        # dense layout and run the dense fallback (one masked score pass
+        # + the same merge) — identical math, O(table span) traffic
         T = block_tables.shape[1] * block_size
+
         def gather(pool):
-            g = pool[block_tables]              # (b, nbs, h, bs, d)
-            return g.transpose(0, 2, 1, 3, 4).reshape(b, h, T, d)
+            g = pool[layer][block_tables]       # (b, nbs, bs, h*d)
+            return g.reshape(b, T, h, d).transpose(0, 2, 1, 3)
         kd = gather(k_pool)
         vd = gather(v_pool)
         ksc = vsc = None
         if quantized:
             def gather_sc(sc):
-                g = sc[block_tables]            # (b, nbs, h, bs)
+                g = sc[layer][block_tables]     # (b, nbs, h, bs)
                 return g.transpose(0, 2, 1, 3).reshape(b, h, T)
             ksc = gather_sc(k_scale)
             vsc = gather_sc(v_scale)

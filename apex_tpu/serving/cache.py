@@ -30,7 +30,7 @@ blockwise in VMEM.
 **Paged layout (v2, docs/SERVING.md "Paged serving")**: the dense
 ``(L, S, H, max_len, D)`` reservation pins max_len HBM per slot for its
 whole lifetime. :class:`PagedKVCache` replaces it with a global
-``(L, num_blocks, H, block_size, D)`` block POOL; which pool blocks a
+``(L, num_blocks, block_size, H * D)`` block POOL; which pool blocks a
 slot owns is host-side state in :class:`BlockAllocator` (per-slot int32
 block tables + cursors, refcounts, a chained prefix-hash index for
 copy-on-write prompt sharing). The device pytree holds ONLY the pool
@@ -39,6 +39,27 @@ AOT serving programs, so admission, retirement, block growth, prefix
 sharing and COW are all zero-recompile by construction. Block index 0
 is the allocator's reserved NULL block: unmapped table entries and
 masked writes land there, keeping every device program total.
+
+The pool is token-major with the heads fused into its last axis because
+that is the one layout the append, the layer scan and the decode kernel
+all take as it is (measured and compiled for PR 27; the earlier
+``(L, NB, H, block_size, D)`` pool was rewritten whole, 6 GB, twice a
+decode step):
+
+- the block axis may not become the lane axis. An array whose last
+  dimension is ``D = 64`` half-fills a 128-lane tile, so XLA keeps it
+  with ``block_size`` on the lanes (layout ``{3,4,2,1,0}``), while a
+  Mosaic kernel demands its operands row-major with the last dimension
+  padded to 128 lanes — every layer's slice was relaid for the kernel.
+  ``H * D`` is a multiple of 128 at every published width: the resident
+  layout is the kernel's;
+- a write that spans all layers relays the pool: write per layer.
+  ``pool.at[:, ids, :, offs, :].set`` is run in a layout with the layer
+  axis minor (copy there, scatter, copy back); ``pool.at[layer, ids,
+  offs, :].set`` of whole ``(H * D,)`` rows is run in place. So the
+  pool is CARRIED through the layer scan and each layer appends its own
+  row (:meth:`PagedKVCache.append`), and :meth:`PagedKVCache.cow_copy`
+  is a loop of slices, not a gather and a scatter.
 """
 
 from __future__ import annotations
@@ -354,15 +375,18 @@ NULL_BLOCK = 0
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class PagedKVCache:
-    """The paged serving cache: a global block pool (see the module
-    docstring). Leaves: ``k``, ``v`` (+ ``k_scale``/``v_scale`` when
-    quantized) — per-slot block tables and cursors are HOST state
+    """The paged serving cache: a global block pool in the one
+    token-major layout the module docstring derives. Leaves: ``k``,
+    ``v`` (+ ``k_scale``/``v_scale`` when quantized); ``num_heads``
+    rides as pytree aux data (the pool's last axis is ``H * D`` fused).
+    Per-slot block tables and cursors are HOST state
     (:class:`BlockAllocator`) threaded into the AOT programs as plain
     array arguments, never pytree leaves, so they are neither donated
     nor shape-bearing."""
 
-    k: jnp.ndarray                       # (L, NB, H, block_size, D)
-    v: jnp.ndarray                       # (L, NB, H, block_size, D)
+    k: jnp.ndarray                       # (L, NB, block_size, H * D)
+    v: jnp.ndarray                       # (L, NB, block_size, H * D)
+    num_heads: int
     k_scale: Optional[jnp.ndarray] = None  # (L, NB, H, block_size) fp32
     v_scale: Optional[jnp.ndarray] = None
 
@@ -370,15 +394,14 @@ class PagedKVCache:
 
     def tree_flatten(self):
         if self.quantized:
-            return ((self.k, self.v, self.k_scale, self.v_scale), True)
-        return ((self.k, self.v), False)
+            return ((self.k, self.v, self.k_scale, self.v_scale),
+                    self.num_heads)
+        return ((self.k, self.v), self.num_heads)
 
     @classmethod
-    def tree_unflatten(cls, quantized, leaves):
-        if quantized:
-            return cls(*leaves)
-        k, v = leaves
-        return cls(k, v)
+    def tree_unflatten(cls, num_heads, leaves):
+        k, v, *scales = leaves
+        return cls(k, v, num_heads, *scales)
 
     # -- shape/bookkeeping --------------------------------------------------
 
@@ -395,16 +418,12 @@ class PagedKVCache:
         return self.k.shape[1]
 
     @property
-    def num_heads(self) -> int:
+    def block_size(self) -> int:
         return self.k.shape[2]
 
     @property
-    def block_size(self) -> int:
-        return self.k.shape[3]
-
-    @property
     def head_dim(self) -> int:
-        return self.k.shape[4]
+        return self.k.shape[3] // self.num_heads
 
     def nbytes(self) -> int:
         """Total pool bytes (the number the paged capacity math sizes)."""
@@ -423,15 +442,16 @@ class PagedKVCache:
         if num_blocks < 2:
             raise ValueError(f"num_blocks must be >= 2 (block 0 is the "
                              f"reserved null block), got {num_blocks}")
-        shape = (num_layers, num_blocks, num_heads, block_size, head_dim)
+        shape = (num_layers, num_blocks, block_size, num_heads * head_dim)
         k = jnp.zeros(shape, dtype)
         v = jnp.zeros(shape, dtype)
         if jnp.dtype(dtype) == jnp.int8:
             # two DISTINCT scale buffers — see KVCache.create
-            return cls(k, v,
-                       jnp.full(shape[:-1], _MIN_SCALE, jnp.float32),
-                       jnp.full(shape[:-1], _MIN_SCALE, jnp.float32))
-        return cls(k, v)
+            sc = (num_layers, num_blocks, num_heads, block_size)
+            return cls(k, v, num_heads,
+                       jnp.full(sc, _MIN_SCALE, jnp.float32),
+                       jnp.full(sc, _MIN_SCALE, jnp.float32))
+        return cls(k, v, num_heads)
 
     # -- writes (device-side, inside the AOT programs) ----------------------
 
@@ -440,66 +460,54 @@ class PagedKVCache:
             return _quantize(x)
         return x.astype(self.k.dtype), None
 
-    def append(self, k_new: jnp.ndarray, v_new: jnp.ndarray,
+    def append(self, layer, k_new: jnp.ndarray, v_new: jnp.ndarray,
                block_ids: jnp.ndarray,
                offsets: jnp.ndarray) -> "PagedKVCache":
-        """Append one token per slot: ``k_new``/``v_new`` are
-        ``(L, S, H, D)``, ``block_ids``/``offsets`` ``(S,)`` int32 name
-        the pool block and in-block position each slot writes (the HOST
+        """Append one token per slot to layer ``layer`` (an int32
+        scalar, traced inside the layer scan): ``k_new``/``v_new`` are
+        ``(S, H, D)``, ``block_ids``/``offsets`` ``(S,)`` int32 name the
+        pool block and in-block position each slot writes (the HOST
         computes them from its cursor mirror; masked slots point at the
-        null block). One batched scatter per array — in-place on donated
-        buffers (asserted by the engine's donation lint)."""
+        null block). One scatter per array whose update is ``(S, H * D)``
+        whole rows of the pool's lane axis — the form XLA runs in place
+        on the resident layout (module docstring: a write that spans all
+        layers relays the pool), asserted by the engine's donation lint
+        and ``tests/test_chip_compile.py``."""
         kq, ks = self._store(k_new)
         vq, vs = self._store(v_new)
-        # two advanced indices split by slices -> update dims lead: (S, L, H, D)
-        k = self.k.at[:, block_ids, :, offsets, :].set(
-            jnp.transpose(kq, (1, 0, 2, 3)), mode="drop")
-        v = self.v.at[:, block_ids, :, offsets, :].set(
-            jnp.transpose(vq, (1, 0, 2, 3)), mode="drop")
-        new = {"k": k, "v": v}
+        n = kq.shape[0]
+        new = {"k": self.k.at[layer, block_ids, offsets, :].set(
+                   kq.reshape(n, -1), mode="drop"),
+               "v": self.v.at[layer, block_ids, offsets, :].set(
+                   vq.reshape(n, -1), mode="drop")}
         if self.quantized:
-            new["k_scale"] = self.k_scale.at[:, block_ids, :, offsets].set(
-                jnp.transpose(ks, (1, 0, 2)), mode="drop")
-            new["v_scale"] = self.v_scale.at[:, block_ids, :, offsets].set(
-                jnp.transpose(vs, (1, 0, 2)), mode="drop")
+            new["k_scale"] = self.k_scale.at[
+                layer, block_ids, :, offsets].set(ks, mode="drop")
+            new["v_scale"] = self.v_scale.at[
+                layer, block_ids, :, offsets].set(vs, mode="drop")
         return dataclasses.replace(self, **new)
 
-    def append_k(self, k_new: jnp.ndarray, v_new: jnp.ndarray,
+    def append_k(self, layer, k_new: jnp.ndarray, v_new: jnp.ndarray,
                  block_ids: jnp.ndarray,
                  offsets: jnp.ndarray) -> "PagedKVCache":
-        """Speculative verify append: up to ``K`` tokens per slot —
-        ``k_new``/``v_new`` are ``(L, S, H, K, D)`` and
+        """Speculative verify append, one layer: up to ``K`` tokens per
+        slot — ``k_new``/``v_new`` are ``(S, H, K, D)`` and
         ``block_ids``/``offsets`` ``(S, K)`` int32 name each token's
         pool block and in-block position (HOST-computed by
         :meth:`BlockAllocator.verify_targets`; the window may CROSS a
         block boundary, which is why the ids are per-token, not
         per-slot). Masked tokens — inactive slots, rows past capacity —
-        aim at the null block. One batched scatter per array, in-place
-        on donated buffers; the cursor mirror advances host-side by the
-        ACCEPTED count only (:meth:`BlockAllocator.advance_counts`), so
-        rejected rows land in slot-private blocks above the cursor."""
-        S, K = block_ids.shape
-        bid = block_ids.reshape(S * K)
-        off = offsets.reshape(S * K)
-        kq, ks = self._store(k_new)
-        vq, vs = self._store(v_new)
+        aim at the null block. Every row of the window is written; the
+        cursor mirror advances host-side by the ACCEPTED count only
+        (:meth:`BlockAllocator.advance_counts`), so rejected rows land
+        in slot-private blocks above the cursor."""
+        S, H, K, D = k_new.shape
 
-        def scatter(pool, x):
-            # (L, S, H, K, D) -> (S*K, L, H, D): the two advanced
-            # indices are split by a slice, so update dims lead
-            upd = jnp.transpose(x, (1, 3, 0, 2, 4)).reshape(
-                S * K, x.shape[0], x.shape[2], x.shape[4])
-            return pool.at[:, bid, :, off, :].set(upd, mode="drop")
+        def rows(x):                       # (S, H, K, D) -> (S*K, H, D)
+            return jnp.transpose(x, (0, 2, 1, 3)).reshape(S * K, H, D)
 
-        new = {"k": scatter(self.k, kq), "v": scatter(self.v, vq)}
-        if self.quantized:
-            def scatter_sc(pool, sc):
-                upd = jnp.transpose(sc, (1, 3, 0, 2)).reshape(
-                    S * K, sc.shape[0], sc.shape[2])
-                return pool.at[:, bid, :, off].set(upd, mode="drop")
-            new["k_scale"] = scatter_sc(self.k_scale, ks)
-            new["v_scale"] = scatter_sc(self.v_scale, vs)
-        return dataclasses.replace(self, **new)
+        return self.append(layer, rows(k_new), rows(v_new),
+                           block_ids.reshape(S * K), offsets.reshape(S * K))
 
     def write_prompt_blocks(self, k_new: jnp.ndarray, v_new: jnp.ndarray,
                             block_row: jnp.ndarray) -> "PagedKVCache":
@@ -508,7 +516,9 @@ class PagedKVCache:
         ``(P // block_size,)`` int32 names the destination pool block of
         each prompt chunk (null entries absorb the padding past the last
         real block). Positions past the true prompt length hold padding
-        garbage — the cursor masks them from every read."""
+        garbage — the cursor masks them from every read. The prompt is
+        transposed to token-major once; whole ``(block_size, H * D)``
+        blocks then land in place."""
         L, H, P, D = k_new.shape
         bs = self.block_size
         npb = P // bs
@@ -517,9 +527,9 @@ class PagedKVCache:
                              f"block_size {bs}")
 
         def scatter(pool, x):
-            # (L, H, P, D) -> (L, NPB, H, bs, D): one advanced index at
+            # (L, H, P, D) -> (L, NPB, bs, H*D): one advanced index at
             # axis 1 keeps its position, so the update leads with L
-            blocks = x.reshape(L, H, npb, bs, D).transpose(0, 2, 1, 3, 4)
+            blocks = x.transpose(0, 2, 1, 3).reshape(L, npb, bs, H * D)
             return pool.at[:, block_row].set(blocks, mode="drop")
 
         kq, ks = self._store(k_new)
@@ -538,9 +548,32 @@ class PagedKVCache:
         slot, BEFORE this step's reads and append (the caller sequences
         it first). The null no-op is ``src == dst == 0`` — block 0 onto
         itself — so a step with no pending COW runs the identical
-        program (zero-recompile across admit/COW/retire)."""
+        program (zero-recompile across admit/COW/retire).
+
+        One slot at a time, a ``(L, 1, ...)`` slice read and written
+        back: the loop form stays in place where a gather-and-scatter of
+        all pairs asks for a relaid copy of the pool. Slot order is
+        safe: a pair's ``dst`` is freshly taken, and the only block a
+        later pair may reuse as its ``dst`` is an earlier pair's
+        released ``src``, which has been read by then."""
         def copy(pool):
-            return pool.at[:, dst].set(pool[:, src], mode="drop")
+            size = (pool.shape[0], 1) + pool.shape[2:]
+            zeros = (0,) * (pool.ndim - 2)
+
+            def move(s, pool):
+                block = jax.lax.dynamic_slice(
+                    pool, (0, src[s]) + zeros, size)
+                return jax.lax.dynamic_update_slice(
+                    pool, block, (0, dst[s]) + zeros)
+
+            def one(s, pool):
+                # a null pair moves nothing: skipping it keeps a
+                # COW-free step from streaming S blocks onto themselves
+                return jax.lax.cond(src[s] != dst[s], move,
+                                    lambda s, pool: pool, s, pool)
+
+            return jax.lax.fori_loop(0, src.shape[0], one, pool)
+
         new = {"k": copy(self.k), "v": copy(self.v)}
         if self.quantized:
             new["k_scale"] = copy(self.k_scale)

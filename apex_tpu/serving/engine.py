@@ -726,14 +726,16 @@ class PagedServingEngine(ServingEngine):
                              drafts, temperature, active, block_ids,
                              offsets, cow_src, cow_dst, rng, poison=None):
                 # COW resolution happens inside verify_forward (before
-                # any read), exactly like the decode leg; the append
-                # targets every row of the window — rejected rows land
-                # in blocks above the host cursor mirror, which only
-                # ever advances by the accepted count
-                logits, (k_new, v_new), cache = model.verify_forward(
+                # any read), exactly like the decode leg, and so does
+                # the append, layer by layer: it targets every row of
+                # the window — rejected rows land in blocks above the
+                # host cursor mirror, which only ever advances by the
+                # accepted count
+                logits, _, cache = model.verify_forward(
                     params, tokens, cache, block_tables=tables,
-                    lengths=lengths, cow_src=cow_src, cow_dst=cow_dst,
-                    mean_context=mc)
+                    lengths=lengths, append_block_ids=block_ids,
+                    append_offsets=offsets, cow_src=cow_src,
+                    cow_dst=cow_dst, mean_context=mc)
                 finite = None
                 if poison is not None:
                     logits = logits + poison[:, None, None]
@@ -741,7 +743,6 @@ class PagedServingEngine(ServingEngine):
                 toks, accepted = verify_tokens(logits, drafts, rng,
                                                temperature, self.top_k)
                 counts = jnp.where(active, accepted + 1, 0)
-                cache = cache.append_k(k_new, v_new, block_ids, offsets)
                 if finite is not None:
                     return cache, toks, counts, finite
                 return cache, toks, counts

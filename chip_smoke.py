@@ -200,24 +200,38 @@ def kernel_phase():
           f"are written for 8 slots of >= 6 blocks, not {S} x {T // bs}")
     lengths = jnp.asarray(lengths, jnp.int32)
 
-    def dense_of(pool):        # (nb, H, bs, ...) -> (S, H, T, ...)
-        g = jnp.moveaxis(pool[tables], 2, 1)
-        return g.reshape(S, H, T, *pool.shape[3:])
+    def dense_of(pool):        # (nb, bs, H*D) -> (S, H, T, D)
+        return pool[tables].reshape(S, T, H, D).transpose(0, 2, 1, 3)
+
+    def dense_scale_of(scale):  # (nb, H, bs) -> (S, H, T)
+        return jnp.moveaxis(scale[tables], 2, 1).reshape(S, H, T)
 
     def quantize(x):           # per-(position, head) symmetric int8
         scale = np.maximum(np.abs(x).max(-1) / 127.0, 1e-8)
         q = np.clip(np.round(x / scale[..., None]), -127, 127)
-        return jnp.asarray(q, jnp.int8), jnp.asarray(scale, jnp.float32)
+        return (jnp.asarray(q.reshape(nb, bs, H * D), jnp.int8),
+                jnp.asarray(scale.transpose(0, 2, 1), jnp.float32))
 
-    kf, vf = (rng.randn(nb, H, bs, D).astype(np.float32) for _ in "kv")
-    pools = {"bf16": (jnp.asarray(kf, jnp.bfloat16),
-                      jnp.asarray(vf, jnp.bfloat16), None, None),
+    # the pool as PagedKVCache stores it: token-major, the heads fused
+    # into the lane axis; one layer here, stacked as the kernel takes it
+    kf, vf = (rng.randn(nb, bs, H, D).astype(np.float32) for _ in "kv")
+    pools = {"bf16": (jnp.asarray(kf.reshape(nb, bs, H * D), jnp.bfloat16),
+                      jnp.asarray(vf.reshape(nb, bs, H * D), jnp.bfloat16),
+                      None, None),
              "int8": tuple(x for pair in zip(quantize(kf), quantize(vf))
                            for x in pair)}
+
+    def paged(q, kp, vp, *rest, ksc=None, vsc=None, use_pallas):
+        return paged_decode_attention(
+            q, kp[None], vp[None], 0, *rest,
+            k_scale=None if ksc is None else ksc[None],
+            v_scale=None if vsc is None else vsc[None],
+            use_pallas=use_pallas)
+
     diffs = {}
     for kv, (kp, vp, ksc, vsc) in pools.items():
-        dense_scales = (None, None) if ksc is None else (dense_of(ksc),
-                                                         dense_of(vsc))
+        dense_scales = (None, None) if ksc is None else (
+            dense_scale_of(ksc), dense_scale_of(vsc))
         for q_len in (1, SPECULATE_K + 1):
             shape = (S, H, D) if q_len == 1 else (S, H, q_len, D)
             q, k_new, v_new = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
@@ -226,9 +240,8 @@ def kernel_phase():
                 "dense": (decode_attention,
                           (q, dense_of(kp), dense_of(vp), lengths, k_new,
                            v_new, *dense_scales)),
-                "paged": (paged_decode_attention,
-                          (q, kp, vp, tables, lengths, k_new, v_new, ksc,
-                           vsc)),
+                "paged": (functools.partial(paged, ksc=ksc, vsc=vsc),
+                          (q, kp, vp, tables, lengths, k_new, v_new)),
             }
             for layout, (fn, args) in calls.items():
                 case = f"{layout}/q{q_len}/{kv}"

@@ -1,10 +1,13 @@
 """The chip's compiler, asked without the chip.
 
 The TPU compiler is installed in the CPU sandbox and compiles for a
-DESCRIBED v5e device. Each case lowers one kernel of the main path at the
-GPT-small serving/training widths and compiles it: what Mosaic refuses
-(block shapes off the (8, 128) tiling, too much VMEM) fails here at no
-chip time. Interpret-mode tests cannot see any of this.
+DESCRIBED v5e device. Each kernel case lowers one kernel of the main path
+at the GPT-small serving/training widths and compiles it: what Mosaic
+refuses (block shapes off the (8, 128) tiling, too much VMEM) fails here
+at no chip time. Interpret-mode tests cannot see any of this. The paged
+STEP cases compile the serve cell's whole programs at gpt2-large widths
+and read the compiler's memory plan: the pool is updated where it lies,
+or the case fails.
 
 The topology is described only inside the module-scoped fixture below —
 never at import, in a ``skipif`` or in ``parametrize`` arguments: one
@@ -13,6 +16,7 @@ test file. Keep all such compiles in THIS file (a second file could land
 on another worker, where the fixture would skip).
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +29,7 @@ fa = importlib.import_module("apex_tpu.ops.flash_attention")
 # GPT-small: 12 heads x head dim 64, seq/max_len 1024, 8 serving slots;
 # paged pool = BENCH_DECODE_CONFIGS["gpt_decode_paged"] (bench.py)
 B, H, S, D = 4, 12, 1024, 64
-SLOTS, MAX_LEN, BLOCK, NUM_BLOCKS = 8, 1024, 128, 65
+SLOTS, MAX_LEN, BLOCK, NUM_BLOCKS, LAYERS = 8, 1024, 128, 65, 12
 VERIFY_Q = 5                      # speculate_k=4 drafts + the bonus row
 
 
@@ -99,19 +103,21 @@ def _dense(q_len, quantized):
 
 
 def _paged(q_len, quantized):
+    # the pool as PagedKVCache stores it, all layers stacked; the kernel
+    # is aimed at one of them by a traced index, as in the layer scan
     qs = (SLOTS, H, D) if q_len == 1 else (SLOTS, H, q_len, D)
-    pool = (NUM_BLOCKS, H, BLOCK, D)
+    pool = (LAYERS, NUM_BLOCKS, BLOCK, H * D)
     shapes = [(qs, BF16), (pool, I8 if quantized else BF16),
-              (pool, I8 if quantized else BF16),
+              (pool, I8 if quantized else BF16), ((), I32),
               ((SLOTS, MAX_LEN // BLOCK), I32), ((SLOTS,), I32),
               (qs, BF16), (qs, BF16)]
     if quantized:
-        shapes += [((NUM_BLOCKS, H, BLOCK), F32)] * 2
+        shapes += [((LAYERS, NUM_BLOCKS, H, BLOCK), F32)] * 2
 
-    def f(q, kp, vp, tables, lengths, k_new, v_new, k_scale=None,
+    def f(q, kp, vp, layer, tables, lengths, k_new, v_new, k_scale=None,
           v_scale=None):
         return fa.paged_decode_attention(
-            q, kp, vp, tables, lengths, k_new=k_new, v_new=v_new,
+            q, kp, vp, layer, tables, lengths, k_new=k_new, v_new=v_new,
             k_scale=k_scale, v_scale=v_scale, mean_context=160.0,
             use_pallas=True)
     return f, shapes
@@ -135,3 +141,130 @@ def test_kernel_compiles_for_v5e(case, one_chip, for_tpu):
     fn, shapes = CASES[case]()
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+# ---------------------------------------------------------------------------
+# the paged serving programs, whole, at the serve cell's size
+# ---------------------------------------------------------------------------
+
+# gpt2-large.chat-r80 (benchmark/workloads): 36 layers, 20 heads x 64, a
+# pool of 257 blocks of 128 tokens, 32 slots of 1024, prefill bucket 512
+LARGE = dict(layers=36, heads=20, head_dim=64, vocab=50257, positions=1024,
+             blocks=257, block=128, slots=32, prefill=512, verify_q=5)
+HALF_GB = 0.5e9
+
+
+def _paged_step(kind, weights_dtype, sharding):
+    """``(fn, args, donated argument, pool bytes, weight bytes in bf16)``
+    for one program over a donated gpt2-large ``PagedKVCache``."""
+    from apex_tpu.models import GPTConfig, GPTModel
+    from apex_tpu.serving.cache import PagedKVCache
+    g = LARGE
+    model = GPTModel(GPTConfig(
+        vocab_size=g["vocab"], hidden_size=g["heads"] * g["head_dim"],
+        num_layers=g["layers"], num_attention_heads=g["heads"],
+        max_position_embeddings=g["positions"]))
+
+    def described(tree, dtype_of=lambda d: d):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, dtype_of(x.dtype),
+                                           sharding=sharding), tree)
+
+    params = described(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                       lambda d: weights_dtype if d == F32 else d)
+    cache = described(jax.eval_shape(lambda: PagedKVCache.create(
+        g["layers"], g["blocks"], g["heads"], g["block"], g["head_dim"])))
+    n_weights = sum(x.size for x in jax.tree_util.tree_leaves(params))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
+
+    S, per_slot = g["slots"], g["positions"] // g["block"]
+    if kind == "decode":
+        def fn(params, cache, tokens, tables, lengths, ids, offs, src, dst):
+            return model.forward(
+                params, tokens[:, None], kv_cache=cache,
+                block_tables=tables, lengths=lengths, append_block_ids=ids,
+                append_offsets=offs, cow_src=src, cow_dst=dst)
+        args = (params, cache, i32(S), i32(S, per_slot), i32(S), i32(S),
+                i32(S), i32(S), i32(S))
+    elif kind == "verify":
+        Q = g["verify_q"]
+
+        def fn(params, cache, tokens, tables, lengths, ids, offs, src, dst):
+            return model.verify_forward(
+                params, tokens, cache, block_tables=tables, lengths=lengths,
+                append_block_ids=ids, append_offsets=offs, cow_src=src,
+                cow_dst=dst)
+        args = (params, cache, i32(S, Q), i32(S, per_slot), i32(S),
+                i32(S, Q), i32(S, Q), i32(S), i32(S))
+    elif kind == "prefill":
+        def fn(params, cache, tokens, block_row, prompt_len):
+            return model.forward(
+                params, tokens, kv_cache=cache, block_row=block_row,
+                prompt_len=prompt_len, last_logit_only=True)
+        args = (params, cache, i32(1, g["prefill"]),
+                i32(g["prefill"] // g["block"]), i32())
+    else:
+        def fn(cache, src, dst):
+            return cache.cow_copy(src, dst)
+        args = (cache, i32(S), i32(S))
+    return fn, args, args.index(cache), cache.nbytes(), 2 * n_weights
+
+
+_ARRAY_RESULT = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* "
+                           r"([\w\-]+)\(")
+# a result of the pool's size may only be the pool itself, passed on or
+# written where it lies
+_IN_PLACE = {"parameter", "get-tuple-element", "bitcast",
+             "dynamic-update-slice", "scatter"}
+
+
+def _pool_sized_copies(text, sizes):
+    """Instructions of compiled ``text`` whose array result has one of
+    ``sizes`` elements and is neither the pool passed on nor an in-place
+    write of it (a fusion counts as what its root is)."""
+    roots, current, found = {}, None, []
+    for line in text.splitlines():
+        if line.rstrip().endswith("{") and "=" not in line.split("(")[0]:
+            current = line.split("(")[0].replace("ENTRY", "").strip(" %")
+        m = _ARRAY_RESULT.match(line)
+        if not m:
+            continue
+        if m.group(1):
+            roots[current] = m.group(4)
+        count = 1
+        for dim in m.group(3).split(","):
+            count *= int(dim)
+        if count in sizes:
+            found.append((m.group(4), line.strip()))
+    bad = []
+    for op, line in found:
+        if op == "fusion":
+            op = roots.get(re.search(r"calls=%?([\w.\-]+)", line).group(1))
+        if op not in _IN_PLACE:
+            bad.append(line[:200])
+    return bad
+
+
+@pytest.mark.parametrize("kind,weights", [
+    ("decode", "bf16"), ("decode", "f32"), ("verify", "bf16"),
+    ("prefill", "bf16"), ("cow_copy", "bf16")])
+def test_paged_step_updates_the_pool_in_place_on_v5e(kind, weights,
+                                                     one_chip, for_tpu):
+    """The serve cell's programs keep no second image of the pool: no
+    temporary of its size (3 GB an array), none of a layer's slice of it
+    (84 MB), the donated pool aliased to the result. With the cell's
+    float32 weights XLA hoists their bf16 image (1.55 GB) out of the
+    layer loop — ROADMAP Speed item 6's, allowed for here by its size."""
+    fn, args, donated, pool_bytes, weight_image = _paged_step(
+        kind, BF16 if weights == "bf16" else F32, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(donated,)).lower(*args).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert ("tpu_custom_call" in text) == (kind != "cow_copy")
+    assert memory.alias_size_in_bytes >= pool_bytes
+    budget = HALF_GB + (weight_image if weights == "f32" else 0)
+    assert memory.temp_size_in_bytes < budget, memory.temp_size_in_bytes
+    pool_elements = pool_bytes // 2 // 2           # K or V, bf16
+    assert _pool_sized_copies(text, {
+        pool_elements, pool_elements // LARGE["layers"]}) == []

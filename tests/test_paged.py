@@ -34,6 +34,7 @@ def _quantize_ref(x):
 class TestPagedDecodeKernel:
     B, H, BS, NBS, D = 4, 4, 32, 8, 32      # per-slot span 256
     NB = 34                                  # pool blocks (0 = null)
+    L, LAYER = 2, 1                          # the kernel reads ONE layer
     LENGTHS = [0, 1, 100, 256]               # empty, single, partial, full
 
     def _layout(self, rng):
@@ -43,10 +44,16 @@ class TestPagedDecodeKernel:
         tables = perm[: self.B * self.NBS].reshape(self.B, self.NBS)
         return tables.astype(np.int32)
 
+    def _pool(self, rng):
+        """A token-major stacked pool ``(L, NB, BS, H*D)``."""
+        return rng.randn(self.L, self.NB, self.BS,
+                         self.H * self.D).astype(np.float32)
+
     def _dense_of(self, pool, tables):
-        g = np.asarray(pool)[tables]              # (B, NBS, H, BS, D)
-        return g.transpose(0, 2, 1, 3, 4).reshape(
-            self.B, self.H, self.NBS * self.BS, self.D)
+        """Layer ``LAYER``'s table-mapped blocks as ``(B, H, T, D)``."""
+        g = np.asarray(pool, np.float32)[self.LAYER][tables]
+        return g.reshape(self.B, self.NBS * self.BS, self.H,
+                         self.D).transpose(0, 2, 1, 3)
 
     @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
                                            (jnp.bfloat16, 2e-2)])
@@ -55,12 +62,13 @@ class TestPagedDecodeKernel:
         tables = self._layout(rng)
         lengths = jnp.asarray(self.LENGTHS, jnp.int32)
         q = jnp.asarray(rng.randn(self.B, self.H, self.D), dtype)
-        kp = jnp.asarray(rng.randn(self.NB, self.H, self.BS, self.D), dtype)
-        vp = jnp.asarray(rng.randn(self.NB, self.H, self.BS, self.D), dtype)
+        kp = jnp.asarray(self._pool(rng), dtype)
+        vp = jnp.asarray(self._pool(rng), dtype)
         k_new = jnp.asarray(rng.randn(self.B, self.H, self.D), dtype)
         v_new = jnp.asarray(rng.randn(self.B, self.H, self.D), dtype)
-        out = paged_decode_attention(q, kp, vp, jnp.asarray(tables),
-                                     lengths, k_new=k_new, v_new=v_new)
+        out = paged_decode_attention(q, kp, vp, self.LAYER,
+                                     jnp.asarray(tables), lengths,
+                                     k_new=k_new, v_new=v_new)
         # oracle: dense-gather the pool and write the current token at
         # each row's CURSOR (kv_length masks everything past it)
         kd = np.concatenate([self._dense_of(kp, tables),
@@ -78,24 +86,32 @@ class TestPagedDecodeKernel:
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32), atol=tol)
 
+    def _quantized_pool(self, rng):
+        """int8 pool + its ``(L, NB, H, BS)`` scale plane + the
+        dequantized float image, per-(position, head) scales."""
+        f = self._pool(rng).reshape(self.L, self.NB, self.BS, self.H,
+                                    self.D)
+        q, sc = _quantize_ref(f)                 # sc (L, NB, BS, H)
+        deq = (q.astype(np.float32) * sc[..., None]).reshape(
+            self.L, self.NB, self.BS, self.H * self.D)
+        return (q.reshape(deq.shape), sc.transpose(0, 1, 3, 2).copy(),
+                deq)
+
     def test_parity_int8(self):
         rng = np.random.RandomState(1)
         tables = self._layout(rng)
         lengths = jnp.asarray(self.LENGTHS, jnp.int32)
         q = jnp.asarray(rng.randn(self.B, self.H, self.D), jnp.float32)
-        kf = rng.randn(self.NB, self.H, self.BS, self.D).astype(np.float32)
-        vf = rng.randn(self.NB, self.H, self.BS, self.D).astype(np.float32)
-        # pool scales are per-(block-position, head): quantize on the
-        # (NB, H, BS) leading axes
-        kq, ksc = _quantize_ref(kf)
-        vq, vsc = _quantize_ref(vf)
+        kq, ksc, kdeq = self._quantized_pool(rng)
+        vq, vsc, vdeq = self._quantized_pool(rng)
         out = paged_decode_attention(
-            q, jnp.asarray(kq), jnp.asarray(vq), jnp.asarray(tables),
-            lengths, k_scale=jnp.asarray(ksc), v_scale=jnp.asarray(vsc))
-        kd = self._dense_of(kq.astype(np.float32) * ksc[..., None], tables)
-        vd = self._dense_of(vq.astype(np.float32) * vsc[..., None], tables)
-        ref = mha_reference(q[:, :, None], jnp.asarray(kd),
-                            jnp.asarray(vd), kv_length=lengths)[:, :, 0]
+            q, jnp.asarray(kq), jnp.asarray(vq), self.LAYER,
+            jnp.asarray(tables), lengths, k_scale=jnp.asarray(ksc),
+            v_scale=jnp.asarray(vsc))
+        ref = mha_reference(q[:, :, None],
+                            jnp.asarray(self._dense_of(kdeq, tables)),
+                            jnp.asarray(self._dense_of(vdeq, tables)),
+                            kv_length=lengths)[:, :, 0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-2)
 
@@ -104,98 +120,246 @@ class TestPagedDecodeKernel:
         tables = self._layout(rng)
         lengths = jnp.asarray([7, 63, 128, 200], jnp.int32)
         q = jnp.asarray(rng.randn(self.B, self.H, self.D), jnp.float32)
-        kp = jnp.asarray(rng.randn(self.NB, self.H, self.BS, self.D),
-                         jnp.float32)
-        vp = jnp.asarray(rng.randn(self.NB, self.H, self.BS, self.D),
-                         jnp.float32)
-        a = paged_decode_attention(q, kp, vp, jnp.asarray(tables), lengths,
+        kp = jnp.asarray(self._pool(rng))
+        vp = jnp.asarray(self._pool(rng))
+        a = paged_decode_attention(q, kp, vp, self.LAYER,
+                                   jnp.asarray(tables), lengths,
                                    use_pallas=True)
-        b = paged_decode_attention(q, kp, vp, jnp.asarray(tables), lengths,
+        b = paged_decode_attention(q, kp, vp, self.LAYER,
+                                   jnp.asarray(tables), lengths,
                                    use_pallas=False)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)
 
     def test_unmapped_tail_blocks_never_pollute(self):
         """Table entries past ceil(length/block) may be garbage (null or
         stale) — the clamped index map / length mask must keep them out
-        of the math."""
+        of the math, and so must the layer index the other layers."""
         rng = np.random.RandomState(3)
         tables = self._layout(rng)
         lengths = jnp.asarray([40, 40, 40, 40], jnp.int32)  # 2 blocks
         q = jnp.asarray(rng.randn(self.B, self.H, self.D), jnp.float32)
-        kp = rng.randn(self.NB, self.H, self.BS, self.D).astype(np.float32)
-        vp = rng.randn(self.NB, self.H, self.BS, self.D).astype(np.float32)
+        kp, vp = self._pool(rng), self._pool(rng)
         out1 = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
-                                      jnp.asarray(tables), lengths)
-        # poison every block the cursor doesn't cover
+                                      self.LAYER, jnp.asarray(tables),
+                                      lengths)
+        # poison every block the cursor doesn't cover, and layer 0 whole
         used = set(tables[:, :2].ravel().tolist())
         for blk in range(self.NB):
             if blk not in used:
-                kp[blk] = 1e6
-                vp[blk] = 1e6
+                kp[self.LAYER, blk] = 1e6
+                vp[self.LAYER, blk] = 1e6
+        kp[0] = vp[0] = 1e6
         out2 = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
-                                      jnp.asarray(tables), lengths)
+                                      self.LAYER, jnp.asarray(tables),
+                                      lengths)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                    atol=1e-6)
+
+
+class TestPagedDecodeKernelLargeWidths:
+    """The serve cell's head geometry (gpt2-large: 20 heads x 64, block
+    128): every head of a block is scored at once through the
+    block-diagonal query, so parity is checked where 20 heads share one
+    1280-lane row — cursors inside a block, on a block boundary, at 0."""
+    B, H, BS, NBS, D = 3, 20, 128, 2, 64
+    NB, L, LAYER = 8, 2, 1
+    LENGTHS = [0, 128, 200]                  # empty, boundary, inside
+
+    def _case(self, rng, q_len, quantized):
+        tables = rng.permutation(np.arange(1, self.NB))[
+            : self.B * self.NBS].reshape(self.B, self.NBS).astype(np.int32)
+        qs = (self.B, self.H, self.D) if q_len == 1 else \
+            (self.B, self.H, q_len, self.D)
+        q = rng.randn(*qs).astype(np.float32)
+
+        def pool():
+            f = rng.randn(self.L, self.NB, self.BS, self.H,
+                          self.D).astype(np.float32)
+            flat = (self.L, self.NB, self.BS, self.H * self.D)
+            if not quantized:
+                bf = np.asarray(jnp.asarray(f, jnp.bfloat16), np.float32)
+                return jnp.asarray(f.reshape(flat), jnp.bfloat16), None, bf
+            qv, sc = _quantize_ref(f)
+            return (jnp.asarray(qv.reshape(flat)),
+                    jnp.asarray(sc.transpose(0, 1, 3, 2).copy()),
+                    qv.astype(np.float32) * sc[..., None])
+
+        def dense(image):                    # (L,NB,BS,H,D) -> (B,H,T,D)
+            g = image[self.LAYER][tables]    # (B, NBS, BS, H, D)
+            return jnp.asarray(g.reshape(
+                self.B, self.NBS * self.BS, self.H,
+                self.D).transpose(0, 2, 1, 3))
+
+        kp, ksc, kimg = pool()
+        vp, vsc, vimg = pool()
+        return q, tables, (kp, vp, ksc, vsc), dense(kimg), dense(vimg)
+
+    @pytest.mark.parametrize("q_len", [1, 5])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    def test_parity_vs_mha_reference(self, q_len, quantized):
+        rng = np.random.RandomState(10 * q_len + quantized)
+        q, tables, (kp, vp, ksc, vsc), kd, vd = self._case(
+            rng, q_len, quantized)
+        lengths = jnp.asarray(self.LENGTHS, jnp.int32)
+        out = paged_decode_attention(
+            jnp.asarray(q), kp, vp, self.LAYER, jnp.asarray(tables),
+            lengths, k_scale=ksc, v_scale=vsc)
+        q4 = jnp.asarray(q if q_len > 1 else q[:, :, None])
+        ref = mha_reference(q4, kd, vd, kv_length=lengths)
+        if q_len == 1:
+            ref = ref[:, :, 0]
+        assert out.shape == q.shape
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+        # the empty slot reads exactly zero, whatever its table names
+        assert not np.any(np.asarray(out)[0])
 
 
 # ---------------------------------------------------------------------------
 # PagedKVCache pool writes
 # ---------------------------------------------------------------------------
 
+def _append_all(pool, k_new, v_new, block_ids, offsets, method="append"):
+    """Every layer's write, one layer at a time (how the layer scan of
+    the decode program drives the pool)."""
+    for layer in range(pool.num_layers):
+        pool = getattr(pool, method)(layer, k_new[layer], v_new[layer],
+                                     block_ids, offsets)
+    return pool
+
+
+def _heads(pool_array, num_heads):
+    """A token-major pool array ``(L, NB, bs, H*D)`` viewed
+    ``(L, NB, bs, H, D)``."""
+    a = np.asarray(pool_array)
+    return a.reshape(a.shape[:3] + (num_heads, -1))
+
+
 class TestPagedKVCache:
     def test_append_and_null_masking(self):
         pool = PagedKVCache.create(2, 6, 3, 4, 5, dtype=jnp.float32)
+        assert pool.k.shape == (2, 6, 4, 15)
+        assert (pool.num_heads, pool.head_dim, pool.block_size) == (3, 5, 4)
         kn = jnp.arange(2 * 2 * 3 * 5, dtype=jnp.float32).reshape(2, 2, 3, 5)
-        pool = pool.append(kn, kn + 100, jnp.asarray([2, 3]),
+        pool = _append_all(pool, kn, kn + 100, jnp.asarray([2, 3]),
                            jnp.asarray([1, 0]))
-        np.testing.assert_allclose(np.asarray(pool.k)[:, 2, :, 1, :],
+        np.testing.assert_allclose(_heads(pool.k, 3)[:, 2, 1],
                                    np.asarray(kn)[:, 0])
-        np.testing.assert_allclose(np.asarray(pool.v)[:, 3, :, 0, :],
+        np.testing.assert_allclose(_heads(pool.v, 3)[:, 3, 0],
                                    np.asarray(kn)[:, 1] + 100)
         # a null-targeted append (masked slot) lands in block 0 only
-        pool2 = pool.append(kn * 0 - 7, kn * 0 - 7, jnp.asarray([0, 0]),
-                            jnp.asarray([0, 0]))
-        np.testing.assert_allclose(np.asarray(pool2.k)[:, 2, :, 1, :],
+        pool2 = _append_all(pool, kn * 0 - 7, kn * 0 - 7,
+                            jnp.asarray([0, 0]), jnp.asarray([0, 0]))
+        np.testing.assert_allclose(_heads(pool2.k, 3)[:, 2, 1],
                                    np.asarray(kn)[:, 0])
+        np.testing.assert_allclose(np.asarray(pool2.k)[:, 1:],
+                                   np.asarray(pool.k)[:, 1:])
+
+    def test_append_writes_one_layer(self):
+        pool = PagedKVCache.create(3, 4, 2, 4, 3, dtype=jnp.float32)
+        kn = jnp.ones((1, 2, 3))
+        pool = pool.append(jnp.int32(1), kn, 2 * kn, jnp.asarray([2]),
+                           jnp.asarray([3]))
+        k = _heads(pool.k, 2)
+        assert np.all(k[1, 2, 3] == 1) and np.all(
+            _heads(pool.v, 2)[1, 2, 3] == 2)
+        assert not np.any(k[[0, 2]])          # layers 0 and 2 untouched
+        assert np.count_nonzero(k[1]) == k[1, 2, 3].size
 
     def test_write_prompt_blocks_layout(self):
         L, H, P, D, bs = 2, 3, 8, 5, 4
         pool = PagedKVCache.create(L, 6, H, bs, D, dtype=jnp.float32)
         kp = jnp.arange(L * H * P * D, dtype=jnp.float32).reshape(L, H, P, D)
         pool = pool.write_prompt_blocks(kp, kp + 5, jnp.asarray([4, 5]))
-        # block 4 holds positions 0..3, block 5 positions 4..7
-        np.testing.assert_allclose(np.asarray(pool.k)[:, 4],
-                                   np.asarray(kp)[:, :, 0:4, :])
-        np.testing.assert_allclose(np.asarray(pool.v)[:, 5],
-                                   np.asarray(kp)[:, :, 4:8, :] + 5)
+        # block 4 holds positions 0..3, block 5 positions 4..7, each
+        # position's heads side by side
+        tok_major = np.asarray(kp).transpose(0, 2, 1, 3)   # (L, P, H, D)
+        np.testing.assert_allclose(_heads(pool.k, H)[:, 4],
+                                   tok_major[:, 0:4])
+        np.testing.assert_allclose(_heads(pool.v, H)[:, 5],
+                                   tok_major[:, 4:8] + 5)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+    def test_decode_reads_back_the_rows_prefill_wrote(self, dtype):
+        """The prefill's transpose to token-major against the kernel's
+        reading of it: with a one-hot softmax (a huge matching key) the
+        attention output IS one written V row, so every position of
+        every head must come back exactly where the prompt put it."""
+        L, H, P, D, bs = 2, 3, 8, 8, 4
+        rng = np.random.RandomState(0)
+        pool = PagedKVCache.create(L, 6, H, bs, D, dtype=dtype)
+        # keys: position p of head h is the unit vector (p + h) % D, so
+        # no two positions of a head share a direction; values: random
+        k = np.zeros((L, H, P, D), np.float32)
+        for h in range(H):
+            for pos in range(P):
+                k[:, h, pos, (pos + h) % D] = 1.0
+        v = rng.randint(-8, 8, (L, H, P, D)).astype(np.float32)
+        pool = pool.write_prompt_blocks(jnp.asarray(k), jnp.asarray(v),
+                                        jnp.asarray([5, 2]))
+        tables = jnp.asarray([[5, 2]], jnp.int32)
+        for layer in range(L):
+            for pos in range(P):
+                # query head h = its own key at `pos`, scaled up: the
+                # softmax over the cached positions is one-hot at `pos`
+                q = jnp.asarray(k[layer, :, pos] * 1e4)[None]
+                out = paged_decode_attention(
+                    q, pool.k, pool.v, layer, tables,
+                    jnp.asarray([P], jnp.int32), k_scale=pool.k_scale,
+                    v_scale=pool.v_scale)
+                np.testing.assert_allclose(
+                    np.asarray(out)[0], v[layer, :, pos],
+                    atol=0.1 if dtype == jnp.int8 else 1e-6)
 
     def test_cow_copy_and_null_noop(self):
         pool = PagedKVCache.create(1, 4, 2, 4, 3, dtype=jnp.float32)
         kn = jnp.ones((1, 1, 2, 3))
-        pool = pool.append(kn, 2 * kn, jnp.asarray([2]), jnp.asarray([0]))
+        pool = _append_all(pool, kn, 2 * kn, jnp.asarray([2]),
+                           jnp.asarray([0]))
         pool = pool.cow_copy(jnp.asarray([2]), jnp.asarray([3]))
         np.testing.assert_allclose(np.asarray(pool.k)[:, 3],
                                    np.asarray(pool.k)[:, 2])
+        assert np.any(np.asarray(pool.k)[:, 3])
         # the all-null pair is the no-op every COW-free step runs
         pool2 = pool.cow_copy(jnp.asarray([0]), jnp.asarray([0]))
         np.testing.assert_allclose(np.asarray(pool2.k), np.asarray(pool.k))
+
+    def test_cow_copy_pairs_in_slot_order(self):
+        """A later pair may take an earlier pair's released source as
+        its target (``BlockAllocator.prepare_verify``): the earlier copy
+        must have read it by then."""
+        pool = PagedKVCache.create(2, 5, 1, 2, 2, dtype=jnp.float32)
+        k = np.zeros(pool.k.shape, np.float32)
+        for blk in range(5):
+            k[:, blk] = blk
+        pool = PagedKVCache(jnp.asarray(k), jnp.asarray(-k), 1)
+        # slot 0: 1 -> 3; slot 1: 2 -> 1 (block 1 reused); slot 2 null
+        pool = pool.cow_copy(jnp.asarray([1, 2, 0]), jnp.asarray([3, 1, 0]))
+        got = np.asarray(pool.k)[0, :, 0, 0]
+        np.testing.assert_array_equal(got, [0, 2, 2, 1, 4])
+        np.testing.assert_array_equal(np.asarray(pool.v)[1, :, 1, 1],
+                                      [0, -2, -2, -1, -4])
 
     def test_int8_pool_roundtrip_and_pytree(self):
         pool = PagedKVCache.create(1, 3, 2, 4, 8, dtype=jnp.int8)
         assert pool.quantized
         x = jnp.asarray(np.random.RandomState(0).randn(1, 1, 2, 8),
                         jnp.float32)
-        pool = pool.append(x, x, jnp.asarray([1]), jnp.asarray([2]))
-        deq = (pool.k[0, 1, :, 2].astype(jnp.float32)
-               * pool.k_scale[0, 1, :, 2, None])
-        np.testing.assert_allclose(np.asarray(deq), np.asarray(x[0, 0]),
+        pool = _append_all(pool, x, x, jnp.asarray([1]), jnp.asarray([2]))
+        # block 1, position 2: each head's 8 lanes against its own scale
+        deq = (_heads(pool.k, 2)[0, 1, 2].astype(np.float32)
+               * np.asarray(pool.k_scale)[0, 1, :, 2, None])
+        np.testing.assert_allclose(deq, np.asarray(x[0, 0]),
                                    atol=float(jnp.max(jnp.abs(x)) / 127.0)
                                    + 1e-6)
         leaves, treedef = jax.tree_util.tree_flatten(pool)
         assert len(leaves) == 4
-        assert jax.tree_util.tree_unflatten(treedef, leaves).quantized
+        back = jax.tree_util.tree_unflatten(treedef, leaves)
+        assert back.quantized and back.num_heads == 2 and back.head_dim == 8
         fp = PagedKVCache.create(1, 3, 2, 4, 8)
         assert len(jax.tree_util.tree_leaves(fp)) == 2
+        assert jax.tree_util.tree_map(lambda x: x, fp).num_heads == 2
 
     def test_block_bytes(self):
         assert paged_block_bytes(12, 12, 16, 64, jnp.bfloat16) == \
@@ -203,6 +367,9 @@ class TestPagedKVCache:
         pool = PagedKVCache.create(12, 4, 12, 16, 64, dtype=jnp.bfloat16)
         assert pool.nbytes() == 4 * paged_block_bytes(12, 12, 16, 64,
                                                       jnp.bfloat16)
+        q8 = PagedKVCache.create(12, 4, 12, 16, 64, dtype=jnp.int8)
+        assert q8.nbytes() == 4 * paged_block_bytes(12, 12, 16, 64,
+                                                    jnp.int8)
 
 
 # ---------------------------------------------------------------------------
@@ -307,9 +474,10 @@ class TestBlockAllocator:
 # PagedServingEngine contracts
 # ---------------------------------------------------------------------------
 
-def _tiny_model():
+def _tiny_model(max_position_embeddings=64):
     cfg = GPTConfig(vocab_size=97, hidden_size=32, num_layers=2,
-                    num_attention_heads=4, max_position_embeddings=64,
+                    num_attention_heads=4,
+                    max_position_embeddings=max_position_embeddings,
                     compute_dtype=jnp.float32)
     model = GPTModel(cfg)
     return model, model.init(jax.random.PRNGKey(0))
@@ -478,8 +646,10 @@ class TestPagedEngine:
 class TestPagedCostModel:
     def test_paged_decode_prices_mean_context_not_max_len(self):
         from apex_tpu.pyprof.model import model_program
-        model, params = _tiny_model()
-        MAX_LEN, MEAN = 64, 8
+        # contexts long enough that the KV stream outweighs the fixed
+        # per-call traffic (the block-diagonal query and output tiles)
+        MAX_LEN, MEAN = 2048, 256
+        model, params = _tiny_model(max_position_embeddings=MAX_LEN)
         dense = ServingEngine(model, params, max_seqs=2, max_len=MAX_LEN,
                               prefill_len=8, cache_dtype=jnp.float32)
         paged = _paged_engine(model, params, max_len=MAX_LEN,

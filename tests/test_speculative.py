@@ -174,14 +174,16 @@ class TestPagedVerifyWindow:
         for s in range(2):
             for r in range(3):
                 val[0, s, 0, r, :] = 100 * s + r + 1
-        pool = pool.append_k(jnp.asarray(val), jnp.asarray(val),
+        # one layer, one head: the token-major pool (L, NB, bs, H*D)
+        # reads (L, NB, bs, D) here
+        pool = pool.append_k(0, jnp.asarray(val[0]), jnp.asarray(val[0]),
                              jnp.asarray(bids), jnp.asarray(offs))
         k = np.asarray(pool.k)
         # write-all: every row of slot 0's window landed at its target,
         # accepted or not — rejected rows sit ABOVE the cursor, masked
         # from every read and overwritten by the next window
         for r, (b, o) in enumerate(zip(bids[0], offs[0])):
-            np.testing.assert_array_equal(k[0, b, 0, o], [r + 1, r + 1])
+            np.testing.assert_array_equal(k[0, b, o], [r + 1, r + 1])
         # nothing outside the named blocks and the null absorber moved
         untouched = np.ones(alloc.num_blocks, bool)
         untouched[[NULL_BLOCK, b0, b1]] = False
