@@ -1,4 +1,5 @@
-"""Kind ``serve``: drives ``SlotScheduler.submit()/step()`` over an engine.
+"""Kind ``serve``: drives ``SlotScheduler.submit()/step()`` over the engine
+the configuration's family builds (``ctx.family``, ``benchmark/families/``).
 
 Open loop: the requests of ``benchmark/traffic.py`` are submitted when they
 are due, whether or not earlier ones have finished; one thread, as the
@@ -17,30 +18,13 @@ import time
 
 import numpy as np
 
-from benchmark import reference, traffic
+from benchmark import traffic
 
+REQUIRED_LIMITS = ("token_gap_mean", "short_streams", "unfinished")
 TRACE_SECONDS = 3.0      # the traced part of a --trace 1 window
 TRACE_AT = 0.4           # ... which starts at this share of the window
 DRAIN_SECONDS = 60.0     # how long past the close an answer is waited for
 WARM_REQUESTS = 4
-
-
-def to_engine(w):
-    """The reference's tensors as ``GPTModel.init``'s pytree: a tensor
-    axis of size 1 added, no number changed."""
-    def lin(name):
-        return {"weight": w[f"{name}_w"][:, None],
-                "bias": w[f"{name}_b"][:, None]}
-
-    def ln(name):
-        return {"weight": w[f"{name}_w"], "bias": w[f"{name}_b"]}
-
-    return {"embedding": {"word": {"weight": w["wte"][None]},
-                          "position": w["wpe"]},
-            "layers": {"ln1": ln("ln1"), "qkv": lin("qkv"),
-                       "proj": lin("proj"), "ln2": ln("ln2"),
-                       "fc1": lin("fc1"), "fc2": lin("fc2")},
-            "final_ln": {"weight": w["lnf_w"], "bias": w["lnf_b"]}}
 
 
 def percentile(values, q):
@@ -56,57 +40,41 @@ class Server:
     round the calls into each layer."""
 
     def __init__(self, ctx):
-        import jax
-        import jax.numpy as jnp
-
-        from apex_tpu.models import GPTConfig, GPTModel
         from apex_tpu.observability.registry import MetricsRegistry
-        from apex_tpu.serving import PagedServingEngine, SlotScheduler
+        from apex_tpu.serving import SlotScheduler
 
-        cfg, eng = ctx.config, ctx.cell["engine"]
         self.ctx = ctx
-        model = GPTModel(GPTConfig(
-            vocab_size=cfg["vocab_size"], hidden_size=cfg["n_embd"],
-            num_layers=cfg["n_layer"], num_attention_heads=cfg["n_head"],
-            max_position_embeddings=cfg["n_positions"],
-            ffn_hidden_size=cfg.get("n_inner"),
-            layernorm_epsilon=cfg["layer_norm_epsilon"]))
-        lo, hi = reference.seed_key(ctx.seed)
-        params = jax.block_until_ready(jax.jit(
-            lambda lo, hi: to_engine(reference.make_weights(cfg, lo, hi))
-        )(lo, hi))
-        self.engine = PagedServingEngine(
-            model, params, max_seqs=eng["max_seqs"], max_len=eng["max_len"],
-            prefill_len=eng["prefill_len"],
-            cache_dtype=jnp.dtype(eng["cache_dtype"]),
-            speculate_k=eng["speculate_k"], block_size=eng["block_size"],
-            num_blocks=eng["num_blocks"])
-        del params
-        self.sched = SlotScheduler(self.engine, registry=MetricsRegistry(),
+        self.engine = ctx.family.serve_engine(ctx.config, ctx.cell["engine"],
+                                              ctx.seed)
+        self.registry = MetricsRegistry()
+        self.sched = SlotScheduler(self.engine, registry=self.registry,
                                    speculate_k=self.engine.speculate_k)
-        self.decode_calls = []       # (t0, t1, [context of each slot])
-        self.prefill_calls = []      # (t0, t1, prompt tokens)
+        self.decode_calls = []       # (t0, t1, the family's step_facts)
+        self.prefill_calls = []      # (t0, t1, the same + prompt tokens)
         self.first_token_t = {}      # prompt -> clock at its prefill's return
+        self.registry_at = {}        # point of the run -> registry.snapshot()
         self._wrap()
 
     def _wrap(self):
         engine, sched, ctx = self.engine, self.sched, self.ctx
         decode, prefill = engine.decode, engine.prefill
+        step_facts = ctx.family.step_facts
 
         def timed_decode(*args, **kw):
-            contexts = [st.position for st in sched.active.values()]
+            step = step_facts(engine, sched)
             t0 = time.perf_counter()
             with ctx.span("engine.decode"):
                 out = decode(*args, **kw)
-            self.decode_calls.append((t0, time.perf_counter(), contexts))
+            self.decode_calls.append((t0, time.perf_counter(), step))
             return out
 
         def timed_prefill(prompt, *args, **kw):
+            step = dict(step_facts(engine, sched), tokens=len(prompt))
             t0 = time.perf_counter()
             with ctx.span("engine.prefill"):
                 out = prefill(prompt, *args, **kw)
             t1 = time.perf_counter()
-            self.prefill_calls.append((t0, t1, len(prompt)))
+            self.prefill_calls.append((t0, t1, step))
             self.first_token_t.setdefault(tuple(prompt), t1)
             return out
 
@@ -117,6 +85,12 @@ class Server:
         self.decode_calls.clear()
         self.prefill_calls.clear()
         self.first_token_t.clear()
+        self.registry_at.clear()
+
+    def snapshot(self, point):
+        """The scheduler's registry (``serve/*`` and whatever the program
+        counts there) as it stands at ``point`` of the run."""
+        self.registry_at[point] = self.registry.snapshot()
 
     def free(self):
         import jax
@@ -162,6 +136,7 @@ def serve_window(ctx, server, arrivals, seconds):
     def stop_trace():
         tb = time.perf_counter()
         ctx.stop_trace()
+        server.snapshot("trace_to")
         return dict(seconds=tb - ta, t_from=ta - t0, t_to=tb - t0,
                     decode=(n_dec, len(server.decode_calls)),
                     prefill=(n_pre, len(server.prefill_calls)))
@@ -173,6 +148,7 @@ def serve_window(ctx, server, arrivals, seconds):
             break
         if ctx.trace and traced is None and not tracing \
                 and now >= trace_from:
+            server.snapshot("trace_from")
             ctx.start_trace()
             tracing, ta = True, time.perf_counter()
             n_dec, n_pre = len(server.decode_calls), len(server.prefill_calls)
@@ -196,6 +172,7 @@ def serve_window(ctx, server, arrivals, seconds):
     window_s = time.perf_counter() - t0
     if tracing:
         traced = stop_trace()
+    server.snapshot("close")
     # the window is closed: what was due inside it and is still owed is
     # submitted and waited for, and its latency counts the wait
     not_submitted = len(arrivals) - nxt
@@ -210,26 +187,6 @@ def serve_window(ctx, server, arrivals, seconds):
         if first is not None:
             rec["first"] = first - t0
     return records, window_s, tokens, traced, not_submitted
-
-
-def pool_fill(cfg, eng, decode_calls):
-    """How much of the KV pool the traffic fills: the most blocks that
-    held a cached position at any decode step (a slot with ``c`` positions
-    holds ``ceil(c / block_size)``), and the bytes a deployment really
-    holds at that instant: the stored weights and those blocks."""
-    import jax.numpy as jnp
-
-    from benchmark import counts
-    block = eng["block_size"]
-    most = max((sum(-(-c // block) for c in contexts)
-                for _, _, contexts in decode_calls), default=0)
-    block_bytes = (2 * cfg["n_layer"] * cfg["n_embd"] * block
-                   * jnp.dtype(eng["cache_dtype"]).itemsize)
-    weights = 4 * counts.n_params(cfg)        # stored float32
-    return dict(kv_blocks_filled_at_most=most,
-                kv_blocks_in_pool=eng["num_blocks"],
-                kv_pool_bytes=eng["num_blocks"] * block_bytes,
-                filled_bytes_at_most=weights + most * block_bytes)
 
 
 def finished(rec):
@@ -255,7 +212,8 @@ def check_sample(records, seed, size):
 
 
 def run(ctx):
-    cfg, cell = ctx.config, ctx.cell
+    cfg, cell, family = ctx.config, ctx.cell, ctx.family
+    vocab = family.vocab(cfg)
     server = Server(ctx)
     ctx.mark("weights_and_engine")
     paths = server.engine.attention_paths()
@@ -265,7 +223,7 @@ def run(ctx):
     from apex_tpu.serving import Request
     warm = traffic.serve_arrivals(
         dict(cell["traffic_params"], rate_per_s=float(WARM_REQUESTS)),
-        cfg["vocab_size"], ctx.seed + 1, 1.0)
+        vocab, ctx.seed + 1, 1.0)
     for a in warm:
         server.sched.submit(Request(prompt=a.prompt, max_new_tokens=8,
                                     temperature=0.0,
@@ -276,7 +234,8 @@ def run(ctx):
     server.forget()
 
     arrivals = traffic.serve_arrivals(
-        cell["traffic_params"], cfg["vocab_size"], ctx.seed, ctx.seconds)
+        cell["traffic_params"], vocab, ctx.seed, ctx.seconds)
+    server.snapshot("open")
     with ctx.no_compiles() as compiles:
         ctx.open_window()
         records, window_s, tokens, traced, not_submitted = serve_window(
@@ -313,7 +272,7 @@ def run(ctx):
             ttft_p50_ms=percentile(ttft, 50), tpot_p50_ms=percentile(tpot, 50),
             decode_steps=len(server.decode_calls),
             prefills=len(server.prefill_calls),
-            **pool_fill(cfg, cell["engine"], server.decode_calls))
+            **family.held_bytes(cfg, cell["engine"], server.decode_calls))
     end_to_end = {
         "serve_tokens_per_s": tokens / window_s,
         "ttft_p95_ms": percentile(ttft, 95),
@@ -321,7 +280,8 @@ def run(ctx):
     }
     facts = dict(kind="serve", traced=traced, queue_wait_ms=queue_wait,
                  decode_calls=server.decode_calls,
-                 prefill_calls=server.prefill_calls)
+                 prefill_calls=server.prefill_calls,
+                 registry=server.registry_at)
     prompts, streams = check_sample(records, ctx.seed,
                                     cell["check"]["requests"])
     server.free()
@@ -344,15 +304,15 @@ def score(ctx, prompts, streams, control=False):
     gap (``control``: ``"int8"`` or ``"fp8"``, the same for the token that
     forward puts first)."""
     import jax
-    cfg, eng = ctx.config, ctx.cell["engine"]
+    cfg, eng, family = ctx.config, ctx.cell["engine"], ctx.family
     if not prompts:
         return {"token_gap_mean": (float(10 ** 9), "no request finished")}
     t_ref = time.perf_counter()
     width = max(len(p) + len(s) for p, s in zip(prompts, streams))
     width = min(eng["max_len"], -(-width // 128) * 128)
-    ref = reference.ServeReference(cfg, width, control=control)
-    lo, hi = reference.seed_key(ctx.seed)
-    w = jax.jit(lambda lo, hi: reference.make_weights(cfg, lo, hi))(lo, hi)
+    ref = family.serve_reference(cfg, width, control=control)
+    lo, hi = family.seed_key(ctx.seed)
+    w = jax.jit(lambda lo, hi: family.make_weights(cfg, lo, hi))(lo, hi)
     served, ctrl = ref.gaps(w, prompts, streams)
     gaps = np.concatenate(served).astype(np.float64)
     exact = int((gaps == 0).sum())

@@ -1,7 +1,10 @@
-"""Kind ``train``: drives ``GPTHybridTrainer.jit_train_step(donate=True)``.
+"""Kind ``train``: drives the hybrid trainer's
+``jit_train_step(donate=True)``.
 
+The model's sizes, the trainer, the weights' layout and the reference are
+the configuration's family's (``ctx.family``, ``benchmark/families/``).
 Set-up builds ONE object — the AOT-compiled donated step with its state,
-the weights drawn by ``benchmark/reference.py`` from the seed — takes its
+the weights drawn by the family's reference from the seed — takes its
 first steps through the window's own call and feed, and hands the same
 object to the window. After the window has closed, the peak has been read
 and the state is freed, the plain reference follows the first
@@ -15,45 +18,12 @@ import time
 
 import numpy as np
 
-from benchmark import reference, traffic
+from benchmark import traffic
 
+REQUIRED_LIMITS = ("grad_gap", "grad_diff_median", "change_gap")
 CHECK_STEPS = 3          # steps the reference follows
 WARM_STEPS = 1           # further steps before the window opens
 TRACE_SECONDS = 3.0      # the traced part of a --trace 1 window
-
-
-# -- weights: the reference's layout <-> the trainer's state -----------------
-
-def to_trainer(w):
-    """The reference's tensors as the trainer's ``(stage_stack, shared)``:
-    pipeline and tensor axes of size 1 added, no number changed."""
-    def lin(name):
-        return {"weight": w[f"{name}_w"][None, :, None],
-                "bias": w[f"{name}_b"][None, :, None]}
-
-    def ln(name):
-        return {"weight": w[f"{name}_w"][None], "bias": w[f"{name}_b"][None]}
-
-    stage_stack = {"ln1": ln("ln1"), "qkv": lin("qkv"), "proj": lin("proj"),
-                   "ln2": ln("ln2"), "fc1": lin("fc1"), "fc2": lin("fc2")}
-    shared = {"embedding": {"word": {"weight": w["wte"][None]},
-                            "position": w["wpe"]},
-              "final_ln": {"weight": w["lnf_w"], "bias": w["lnf_b"]}}
-    return stage_stack, shared
-
-
-def from_trainer(stage_stack, shared):
-    out = {"wte": shared["embedding"]["word"]["weight"][0],
-           "wpe": shared["embedding"]["position"],
-           "lnf_w": shared["final_ln"]["weight"],
-           "lnf_b": shared["final_ln"]["bias"]}
-    for name in ("ln1", "ln2"):
-        out[f"{name}_w"] = stage_stack[name]["weight"][0]
-        out[f"{name}_b"] = stage_stack[name]["bias"][0]
-    for name in ("qkv", "proj", "fc1", "fc2"):
-        out[f"{name}_w"] = stage_stack[name]["weight"][0, :, 0]
-        out[f"{name}_b"] = stage_stack[name]["bias"][0, :, 0]
-    return out
 
 
 class Job:
@@ -63,36 +33,15 @@ class Job:
         import jax
         from jax.sharding import NamedSharding
 
-        from apex_tpu.config import (BatchConfig, ModelConfig,
-                                     OptimizerConfig, ParallelConfig,
-                                     TrainConfig)
-        from apex_tpu.training import GPTHybridTrainer
-
-        cfg, job = ctx.config, ctx.cell["job"]
-        self.ctx, self.cfg, self.job = ctx, cfg, job
-        o = job["optimizer"]
-        tc = TrainConfig(
-            model=ModelConfig(
-                name="gpt", vocab_size=cfg["vocab_size"],
-                hidden_size=cfg["n_embd"], num_layers=cfg["n_layer"],
-                num_attention_heads=cfg["n_head"],
-                max_position_embeddings=cfg["n_positions"],
-                ffn_hidden_size=cfg.get("n_inner")),
-            parallel=ParallelConfig(tensor_model_parallel_size=1),
-            batch=BatchConfig(
-                global_batch_size=(job["microbatches"] * job["micro_batch"]
-                                   * job["dp"]),
-                micro_batch_size=job["micro_batch"]),
-            optimizer=OptimizerConfig(
-                name=o["name"], lr=o["lr"], weight_decay=o["weight_decay"],
-                betas=tuple(o["betas"]), eps=o["eps"], zero=job["zero"]),
-            opt_level=job["opt_level"], half_dtype=job["half_dtype"])
-        self.mesh = tc.initialize_mesh(devices=ctx.devices)
-        self.trainer = trainer = GPTHybridTrainer(tc, self.mesh)
-        self.lo, self.hi = reference.seed_key(ctx.seed)
+        cfg, job, family = ctx.config, ctx.cell["job"], ctx.family
+        self.ctx, self.cfg, self.job, self.family = ctx, cfg, job, family
+        self.vocab = family.vocab(cfg)
+        trainer, self.mesh = family.trainer(cfg, job, ctx.devices)
+        self.trainer = trainer
+        self.lo, self.hi = family.seed_key(ctx.seed)
 
         def fresh(lo, hi):
-            return to_trainer(reference.make_weights(cfg, lo, hi))
+            return family.to_trainer(family.make_weights(cfg, lo, hi))
 
         shapes = jax.eval_shape(fresh, self.lo, self.hi)
         specs = (trainer.stage_specs(shapes[0]), trainer.shared_specs)
@@ -119,8 +68,7 @@ class Job:
         self.losses = []
 
     def batch(self, step):
-        return traffic.train_batch(self.job, self.cfg["vocab_size"],
-                                   self.ctx.seed, step)
+        return traffic.train_batch(self.job, self.vocab, self.ctx.seed, step)
 
     def step(self, batch):
         """One dispatch of the timed entry: consumes the state, keeps the
@@ -161,11 +109,11 @@ class Job:
         """The norm of each leaf's change since the seed's weights."""
         import jax
         import jax.numpy as jnp
-        cfg = self.cfg
+        cfg, family = self.cfg, self.family
 
         def norms(stage_stack, shared, lo, hi):
-            p0 = reference.make_weights(cfg, lo, hi)
-            p = from_trainer(stage_stack, shared)
+            p0 = family.make_weights(cfg, lo, hi)
+            p = family.from_trainer(stage_stack, shared)
             return {k: jnp.sqrt(jnp.sum(jnp.square(p[k] - p0[k])))
                     for k in p0}
 
@@ -187,16 +135,16 @@ def first_gradient(ctx, moment):
     itself as host arrays. Run once the job's state is freed."""
     import jax
     import jax.numpy as jnp
-    o, cfg = ctx.cell["job"]["optimizer"], ctx.config
+    o, cfg, family = ctx.cell["job"]["optimizer"], ctx.config, ctx.family
     b1, wd = o["betas"][0], o["weight_decay"]
 
     def grads(moment, lo, hi):
-        p0 = reference.make_weights(cfg, lo, hi)
-        m = from_trainer(*moment)
+        p0 = family.make_weights(cfg, lo, hi)
+        m = family.from_trainer(*moment)
         g = {k: m[k] / (1.0 - b1) - wd * p0[k] for k in p0}
         return g, {k: jnp.sqrt(jnp.sum(jnp.square(v))) for k, v in g.items()}
 
-    g, norms = jax.jit(grads)(moment, *reference.seed_key(ctx.seed))
+    g, norms = jax.jit(grads)(moment, *family.seed_key(ctx.seed))
     host = {k: np.asarray(v) for k, v in g.items()}
     del g
     return {k: float(v) for k, v in norms.items()}, host
@@ -249,16 +197,16 @@ def follow_reference(ctx, quant=False, keep_share=1.0, against=None,
     """The reference's (or, with ``quant``, the control's) readings over
     the first ``CHECK_STEPS`` steps of this seed."""
     import jax
-    cfg, job = ctx.config, ctx.cell["job"]
-    ref = reference.TrainReference(
+    cfg, job, family = ctx.config, ctx.cell["job"], ctx.family
+    ref = family.train_reference(
         cfg, job, quant=quant, keep_share=keep_share,
         rows_per_block=job["micro_batch"] * job["dp"], devices=ctx.devices)
-    lo, hi = reference.seed_key(ctx.seed)
-    make = jax.jit(lambda lo, hi: reference.make_weights(cfg, lo, hi),
+    lo, hi = family.seed_key(ctx.seed)
+    make = jax.jit(lambda lo, hi: family.make_weights(cfg, lo, hi),
                    out_shardings=ref.tree_sh)
     batches = []
     for step in range(CHECK_STEPS):
-        tokens, targets = traffic.train_batch(job, cfg["vocab_size"],
+        tokens, targets = traffic.train_batch(job, family.vocab(cfg),
                                               ctx.seed, step)
         batches.append((tokens.reshape(-1, tokens.shape[-1]),
                         targets.reshape(-1, targets.shape[-1])))

@@ -1,10 +1,13 @@
 """Of the device time of the leaf ops inside the runs of the program
 ``params["module"]``, the share spent in ops whose result has one of the
-shapes ``params["shapes"]``, in %. A dimension is a number or a name: a
-key of the configuration (``n_head``), of the cell's engine block
-(``engine.num_blocks``), or a quotient of two configuration keys
-(``n_embd/n_head``). 0.0 where the program ran and no op has such a
-result; None where it did not run."""
+shapes ``params["shapes"]``, in %. A dimension is a number or a name that
+the metric's file gives as data: a key of the configuration, a key of the
+cell's engine block (``engine.num_blocks``), or a quotient of two
+configuration keys (``<key>/<key>``). The serve cell's metric names the
+paged KV pool as the program holds it since PR 27, ``(layers | 1, blocks,
+block_size, hidden)``: the in-place row scatters of a decode step, and any
+whole-pool copy that comes back. 0.0 where the program ran and no op has
+such a result; None where it did not run."""
 
 import re
 
@@ -25,7 +28,7 @@ def dimension(dim, config, cell):
 
 
 def result_dims(text):
-    """``[36, 257, 20, 128, 64]`` out of ``%copy.47 = bf16[36,257,20,128,64]{..}
+    """``[36, 257, 128, 1280]`` out of ``%copy.47 = bf16[36,257,128,1280]{..}
     copy(...)``; None where the text shows no array result."""
     m = RESULT_DIMS.search(text)
     if not m:

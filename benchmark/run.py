@@ -3,13 +3,17 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Finds the cell's files BY NAME (``workloads/<cell>.json`` -> its ``config``
-and ``kind`` -> ``configs/<config>.json`` and ``kinds/<kind>.py``; with
+and ``kind`` -> ``configs/<config>.json`` and ``kinds/<kind>.py``; the
+configuration's ``family`` -> ``families/<family>.py``, which holds all the
+harness knows of the architecture; with
 ``--trace 1`` every per-layer metric of ``BENCHMARK.json`` that lists the
 cell -> ``metrics/<metric>.json`` -> ``readers/<reader>.py``), runs the
 kind, and prints one JSON object as the last line of standard output.
 Refuses to measure without a TPU and the chips the cell asks for.
 ``--rehearse`` drives the same code on whatever JAX finds (the CPU
-sandbox, tiny cells) and prints no number under any metric's name.
+sandbox, tiny cells) and prints no number under any metric's name: with
+``--trace 1`` it lists, under ``readers_with_a_value``, the per-layer
+metrics whose reader found something to read.
 """
 
 import time
@@ -44,12 +48,20 @@ class CompileCounter:
     count = 0
 
 
+def load_family(config):
+    """The module that knows the configuration's architecture, found by
+    the name the configuration gives."""
+    return importlib.import_module("benchmark.families." + config["family"])
+
+
 class Context:
-    """What a kind gets: the cell, its configuration, the run's arguments
-    and the harness's instruments."""
+    """What a kind gets: the cell, its configuration and the family that
+    configuration names, the run's arguments and the harness's
+    instruments."""
 
     def __init__(self, args, cell, config, devices):
         self.cell, self.config, self.devices = cell, config, devices
+        self.family = load_family(config)
         self.seed, self.seconds = args.seed, args.seconds
         self.trace, self.rehearse = bool(args.trace), args.rehearse
         self.trace_dir = os.path.join(ROOT, ".bench_trace")
@@ -177,7 +189,8 @@ def read_trace(ctx, result, metric_entries, data_root):
     ops = trace_reduce.device_ops(trace)
     span = trace_reduce.span_of(trace)
     facts = dict(result["facts"], trace=trace, device_ops=ops,
-                 config=ctx.config, cell=ctx.cell, chips=len(ctx.devices),
+                 config=ctx.config, family=ctx.family, cell=ctx.cell,
+                 chips=len(ctx.devices),
                  device_kind=ctx.devices[0].device_kind,
                  memory_peak_bytes=result["memory_peak_bytes"],
                  memory_stats=ctx.memory_stats)
@@ -217,9 +230,13 @@ def main(argv=None):
     ap.add_argument("--data", default=HERE,
                     help="directory holding configs/, workloads/, metrics/ "
                          "(default: benchmark/)")
+    ap.add_argument("--manifest", default=os.path.join(ROOT,
+                                                       "BENCHMARK.json"),
+                    help="the manifest that says which per-layer metrics "
+                         "the cell reports (default: BENCHMARK.json)")
     args = ap.parse_args(argv)
 
-    manifest = load_json(ROOT, "BENCHMARK.json")
+    manifest = load_json(args.manifest)
     if args.seconds is None:
         args.seconds = float(manifest["run_seconds"])
     cell, config = load_cell(args.data, args.workload)
@@ -251,13 +268,16 @@ def main(argv=None):
               "memory_peak_bytes": result["memory_peak_bytes"]}
     line = {"correct": correct, "attempted": result["attempted"],
             "failed": result["failed"]}
-    if args.rehearse:
-        line.update(metrics={}, rehearsal=True, device=device)
-    elif args.trace:
+    if args.trace:
         entries = metrics_of(manifest, "per_layer", args.workload,
                              set(end_to_end))
         metrics, traced_device, breakdown = read_trace(
             ctx, result, entries, args.data)
+    if args.rehearse:
+        line.update(metrics={}, rehearsal=True, device=device)
+        if args.trace:
+            line["readers_with_a_value"] = sorted(metrics)
+    elif args.trace:
         device.update(traced_device)
         line.update(metrics=metrics, device=device)
         if breakdown:

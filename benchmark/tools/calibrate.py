@@ -53,10 +53,10 @@ def train_seed(ctx, controls, program=True):
 
 def serve_seed(ctx, controls, program=True):
     from benchmark.kinds import serve
-    cell, cfg = ctx.cell, ctx.config
+    cell = ctx.cell
     server = serve.Server(ctx)
     arrivals = traffic.serve_arrivals(cell["traffic_params"],
-                                      cfg["vocab_size"], ctx.seed,
+                                      ctx.family.vocab(ctx.config), ctx.seed,
                                       ctx.seconds)
     records, *_ = serve.serve_window(ctx, server, arrivals, ctx.seconds)
     prompts, streams = serve.check_sample(records, ctx.seed,
@@ -73,7 +73,7 @@ def serve_seed(ctx, controls, program=True):
     return out
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
@@ -83,7 +83,7 @@ def main():
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--skip-program", action="store_true",
                     help="train: read only the control and the faults")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     import jax
     cell, config = harness.load_cell(args.data, args.workload)
     devices = jax.devices()

@@ -66,7 +66,7 @@ def window(ctx, server, serve, arrivals, seconds):
             tpot.append((rec["done"] - rec["first"]) * 1e3
                         / (len(c.tokens) - 1))
     calls = [c for c in server.decode_calls if c[1] - c[0] > 0]
-    in_window = [len(c[2]) for c in calls]
+    in_window = [len(c[2]["contexts"]) for c in calls]
     return {
         "requests": len(arrivals), "unfinished_after_drain": unfinished,
         "offered_tokens_per_s": sum(a.max_new_tokens for a in arrivals)
@@ -83,10 +83,10 @@ def window(ctx, server, serve, arrivals, seconds):
         "active_slots_most": max(in_window),
         "decode_roundtrip_median_ms": sorted(
             (b - a) * 1e3 for a, b, _ in calls)[len(calls) // 2],
-        **serve.pool_fill(ctx.config, ctx.cell["engine"], calls)}
+        **ctx.family.held_bytes(ctx.config, ctx.cell["engine"], calls)}
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
@@ -94,7 +94,7 @@ def main():
     ap.add_argument("--long", type=float, default=0.0)
     ap.add_argument("--data", default=harness.HERE)
     ap.add_argument("--rehearse", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     import jax
 
     from benchmark.kinds import serve
@@ -107,7 +107,7 @@ def main():
     ctx = harness.quiet_context(cell, config, devices[:1], seeds[0],
                                 args.seconds, args.rehearse)
     server = serve.Server(ctx)
-    params, vocab = cell["traffic_params"], config["vocab_size"]
+    params, vocab = cell["traffic_params"], ctx.family.vocab(config)
     plan = [("stand_in", seeds[0], args.seconds)] \
         + [("iid", seed, args.seconds) for seed in seeds]
     if args.long:

@@ -22,26 +22,31 @@ from benchmark.kinds import serve  # noqa: E402
 from benchmark.tools import poisson_tails  # noqa: E402
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--rates", required=True)
     ap.add_argument("--seconds", type=float, default=20.0)
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--iid", action="store_true")
-    args = ap.parse_args()
+    ap.add_argument("--data", default=harness.HERE)
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args(argv)
     import jax
-    cell, config = harness.load_cell(harness.HERE, args.workload)
+    cell, config = harness.load_cell(args.data, args.workload)
+    devices = jax.devices()
+    if not args.rehearse and devices[0].platform != "tpu":
+        sys.exit("sweep_rate needs the chip")
     harness.enable_cache()
-    ctx = harness.quiet_context(cell, config, jax.devices()[:1], args.seed,
-                                args.seconds)
+    ctx = harness.quiet_context(cell, config, devices[:1], args.seed,
+                                args.seconds, args.rehearse)
     server = serve.Server(ctx)
+    vocab = ctx.family.vocab(config)
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         mix = dict(cell["traffic_params"], rate_per_s=rate)
         make = poisson_tails.iid_arrivals if args.iid \
             else traffic.serve_arrivals
-        arrivals = make(mix, config["vocab_size"], args.seed + i,
-                        args.seconds)
+        arrivals = make(mix, vocab, args.seed + i, args.seconds)
         server.forget()
         records, window_s, tokens, _, not_submitted = serve.serve_window(
             ctx, server, arrivals, args.seconds)
@@ -82,8 +87,8 @@ def main():
             "decode_roundtrip_median_ms": sorted(
                 (b - a) * 1e3 for a, b, _ in server.decode_calls)[
                     len(server.decode_calls) // 2],
-            "mean_active_slots": sum(len(c) for _, _, c in
-                                     server.decode_calls)
+            "mean_active_slots": sum(len(step["contexts"]) for _, _, step
+                                     in server.decode_calls)
             / max(1, len(server.decode_calls)),
         }), flush=True)
 

@@ -18,8 +18,8 @@ SPAN_METRICS = ["prefill_roundtrip_ms.serve", "decode_host_ms.serve",
                 "scheduler_self_ms.serve", "dispatch_gap_ms.serve",
                 "fetch_gap_ms.serve", "pool_rewrite_share.serve"]
 MS = 1e6          # nanoseconds
-POOL = "bf16[2,9,4,8,16]"
-LAYER = "bf16[1,9,4,8,16]"
+POOL = "bf16[2,9,8,64]"       # (layers, blocks, block_size, hidden): PR 27's
+LAYER = "bf16[1,9,8,64]"
 CONFIG = {"n_layer": 2, "n_head": 4, "n_embd": 64}
 CELL = {"engine": {"num_blocks": 9, "block_size": 8}}
 
@@ -235,14 +235,16 @@ def test_shape_share_zero_and_none():
 def test_dimensions_come_from_the_configuration_and_the_cell():
     dims = spec("pool_rewrite_share.serve")["params"]["shapes"]
     assert [[shape_share.dimension(d, CONFIG, CELL) for d in shape]
-            for shape in dims] == [[2, 9, 4, 8, 16], [1, 9, 4, 8, 16]]
+            for shape in dims] == [[2, 9, 8, 64], [1, 9, 8, 64]]
+    # a quotient of two configuration keys: the head size
+    assert shape_share.dimension("n_embd/n_head", CONFIG, CELL) == 16
     large = harness.load_json(BENCH, "configs", "gpt2-large.json")
     cell = harness.load_json(BENCH, "workloads", "gpt2-large.chat-r80.json")
     assert [shape_share.dimension(d, large, cell) for d in dims[0]] \
-        == [36, 257, 20, 128, 64]
+        == [36, 257, 128, 1280]
     assert shape_share.result_dims(
-        "%copy.47 = bf16[36,257,20,128,64]{4,3,2,1,0} copy(%p)") \
-        == [36, 257, 20, 128, 64]
+        "%copy.47 = bf16[36,257,128,1280]{3,2,1,0} copy(%p)") \
+        == [36, 257, 128, 1280]
     assert shape_share.result_dims(
         "%f = (f32[2]{0}, u32[]) fusion()") == [2]
     assert shape_share.result_dims("%w = () while()") is None
