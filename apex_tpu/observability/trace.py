@@ -63,8 +63,10 @@ SPANS: Dict[str, str] = {
     "engine.prefill": "prefill() of either engine [slot]",
     "prefill.plan": "allocator.lookup/admit (paged), pad_prompt, the "
                     "_host() marshalling, _next_key()",
-    "prefill.dispatch": "the call of prefill_compiled (returns before "
-                        "the device is done)",
+    "prefill.dispatch": "the call of the prefill program (returns before "
+                        "the device is done) [on an engine with several "
+                        "prefill buckets: bucket, its positions; tokens, "
+                        "the prompt's own, the rest padding]",
     "prefill.wait": "int(tok): the host blocks until the device has the "
                     "token",
     "prefill.index": "allocator.register_prefix (paged)",

@@ -165,7 +165,8 @@ def _norm_segment_ids(segment_ids, sq, sk):
 def mha_reference(q, k, v, bias=None, causal=False,
                   softmax_scale: Optional[float] = None,
                   dropout_rate: float = 0.0, dropout_seed=None,
-                  segment_ids=None, kv_length=None):
+                  segment_ids=None, kv_length=None,
+                  window: Optional[int] = None):
     """Plain-XLA attention; the parity reference for the kernel (the role of
     the Python attention in ``reference:apex/contrib/test/fmha/test_fmha.py``).
     With ``dropout_rate > 0`` it applies the *same* counter-based mask as the
@@ -177,9 +178,18 @@ def mha_reference(q, k, v, bias=None, causal=False,
     beyond it are masked out (the ground truth for
     :func:`decode_attention`, whose ``k``/``v`` are preallocated
     ``max_len`` caches carrying garbage past the write cursor). Rows with
-    length 0 produce an exactly-zero output, matching the kernel."""
+    length 0 produce an exactly-zero output, matching the kernel.
+
+    ``window`` (with ``causal``): row ``i`` reads column ``j`` only while
+    ``0 <= i - j < window``, the row's own position counted. ``k``/``v``
+    may carry fewer heads than ``q`` (grouped-query attention: query head
+    ``i`` reads KV head ``i // (h // h_kv)``); the oracle repeats them."""
     if softmax_scale is None:
         softmax_scale = 1.0 / math.sqrt(q.shape[-1])
+    if k.shape[1] != q.shape[1]:
+        group = q.shape[1] // k.shape[1]
+        k = jnp.repeat(k, group, axis=1)
+        v = jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * softmax_scale
     if bias is not None:
@@ -197,6 +207,8 @@ def mha_reference(q, k, v, bias=None, causal=False,
         row = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
         s = jnp.where(col > row + (sk - sq), NEG_INF, s)
+        if window is not None:
+            s = jnp.where(col <= row + (sk - sq) - window, NEG_INF, s)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.max(s, axis=-1, keepdims=True) <= NEG_INF, 0.0, p)
     if dropout_rate > 0.0:
@@ -224,7 +236,7 @@ def _seg_mask(q_seg_ref, kv_seg_ref):
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
                 kv_seg_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k,
-                n_kv, offset, dropout_rate):
+                n_kv, offset, dropout_rate, window=None):
     bh, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -236,6 +248,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
     # causal: skip blocks entirely above the diagonal (with the sk-sq
     # offset so cross-shaped causal matches mha_reference)
     run = (j * block_k <= i * block_q + block_q - 1 + offset) if causal else True
+    if window is not None:
+        # ... and blocks wholly left of the window of the block's first row
+        run = run & (j * block_k + block_k - 1
+                     > i * block_q + offset - window)
 
     @pl.when(run)
     def _():
@@ -250,7 +266,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
                 jnp.int32, (block_q, block_k), 0)
             col = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col > row + offset, NEG_INF, s)
+            dead = col > row + offset
+            if window is not None:
+                dead = dead | (col <= row + offset - window)
+            s = jnp.where(dead, NEG_INF, s)
         if q_seg_ref is not None:
             smask = _seg_mask(q_seg_ref, kv_seg_ref)
             s = jnp.where(smask, s, NEG_INF)
@@ -260,7 +279,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
         if causal:
             # rows fully masked within a running block have m_new == NEG_INF,
             # so exp(s - m_new) == 1 on masked entries — zero them explicitly
-            p = jnp.where(col > row + offset, 0.0, p)
+            p = jnp.where(dead, 0.0, p)
         if q_seg_ref is not None:
             p = jnp.where(smask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
@@ -506,7 +525,7 @@ def _seg_specs(h, block_q, block_k, *, swapped):
 
 
 def _fwd_pallas(q3, k3, v3, bias4, seed, segs, h, *, scale, causal, block_q,
-                block_k, dropout_rate):
+                block_k, dropout_rate, window=None, kv_heads=None):
     bh, sq, d = q3.shape
     sk = k3.shape[1]
     n_q, n_kv = sq // block_q, sk // block_k
@@ -516,7 +535,27 @@ def _fwd_pallas(q3, k3, v3, bias4, seed, segs, h, *, scale, causal, block_q,
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+    if window is None and kv_heads is None:
+        kv_map = lambda b, i, j: (b, j, 0)
+    else:
+        # the forward-only serving form: ``k3``/``v3`` hold ``kv_heads``
+        # heads a sequence and query head ``g`` reads KV head
+        # ``g // (h // kv_heads)`` where it lies (no repeated copy); the
+        # fetch is clamped to the blocks the q block's rows can see, so a
+        # block above the diagonal or left of the window resolves to the
+        # block before it and its DMA is elided, not masked after the read
+        kvh = h if kv_heads is None else kv_heads
+        offset = sk - sq
+
+        def kv_map(b, i, j):
+            hi = (i * block_q + block_q - 1 + offset) // block_k
+            jj = jnp.minimum(j, hi) if causal else j
+            if window is not None:
+                lo = jnp.maximum(i * block_q + offset - window + 1, 0) \
+                    // block_k
+                jj = jnp.maximum(jj, lo)
+            return ((b // h) * kvh + (b % h) // (h // kvh), jj, 0)
+    kv_spec = pl.BlockSpec((1, block_k, d), kv_map,
                            memory_space=pltpu.VMEM)
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [q3, k3, v3]
@@ -547,8 +586,10 @@ def _fwd_pallas(q3, k3, v3, bias4, seed, segs, h, *, scale, causal, block_q,
                     o_ref, lse_ref,
                     acc, m, l, scale=scale, causal=causal, block_q=block_q,
                     block_k=block_k, n_kv=n_kv, offset=sk - sq,
-                    dropout_rate=dropout_rate)
+                    dropout_rate=dropout_rate, window=window)
 
+    named = {} if window is None and kv_heads is None \
+        else {"name": "flash_attention_window"}
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_kv),
@@ -562,6 +603,7 @@ def _fwd_pallas(q3, k3, v3, bias4, seed, segs, h, *, scale, causal, block_q,
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         interpret=_interp(),
+        **named,
     )(*args)
     return out, lse
 
@@ -871,8 +913,18 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
                     dropout_rate: float = 0.0,
                     dropout_seed=None,
                     segment_ids=None,
-                    checkpoint_names: bool = False):
+                    checkpoint_names: bool = False,
+                    window: Optional[int] = None):
     """Fused attention over ``(b, h, s, d)`` tensors.
+
+    ``window`` (needs ``causal``) and grouped KV heads (``k``/``v`` with
+    ``h_kv < h`` heads, ``h % h_kv == 0``) select the FORWARD-ONLY serving
+    form (kernel name ``flash_attention_window``): row ``i`` reads column
+    ``j`` while ``0 <= i - j < window`` (its own position counted), KV
+    blocks wholly outside the window or above the diagonal are neither
+    computed nor fetched, and query head ``g`` reads KV head
+    ``g // (h // h_kv)`` in place. No bias, dropout, segments or gradient
+    there. Without either the call is what it always was.
 
     ``segment_ids``: packed-sequence (varlen) attention — the TPU-native
     form of the reference's ``cu_seqlens`` packing
@@ -920,6 +972,30 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
         block_k = _auto_block(sk) or 128
     if use_pallas is None:
         use_pallas = supports_flash(sq, sk, d, block_q, block_k)
+    kv_heads = k.shape[1]
+    if window is not None or kv_heads != h:
+        if window is not None and (not causal or window < 1):
+            raise ValueError("window needs causal=True and window >= 1, "
+                             f"got causal={causal}, window={window}")
+        if h % kv_heads or v.shape[1] != kv_heads:
+            raise ValueError(f"{h} query heads do not group over "
+                             f"{k.shape[1]}/{v.shape[1]} KV heads")
+        if (bias is not None or dropout_rate > 0.0
+                or segment_ids is not None or checkpoint_names):
+            raise ValueError("the windowed / grouped-KV form is forward-"
+                             "only serving attention: no bias, dropout, "
+                             "segment_ids or checkpoint_names")
+        if not use_pallas:
+            return mha_reference(q, k, v, None, causal, softmax_scale,
+                                 window=window)
+        with jax.named_scope("flash_attention_window"):
+            out, _ = _fwd_pallas(
+                q.reshape(b * h, sq, d), k.reshape(b * kv_heads, sk, d),
+                v.reshape(b * kv_heads, sk, d), None, None, None, h,
+                scale=float(softmax_scale), causal=bool(causal),
+                block_q=block_q, block_k=block_k, dropout_rate=0.0,
+                window=window, kv_heads=kv_heads)
+        return out.reshape(b, h, sq, d)
     if not use_pallas:
         # honor bias_requires_grad here too so gradient semantics do not
         # silently flip with tile alignment
@@ -1400,12 +1476,18 @@ def decode_attention(q, k, v, lengths, k_new=None, v_new=None,
 
 def _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref,
                          ksc_ref, vsc_ref, o_ref, lse_ref, acc_ref, m_ref,
-                         l_ref, *, scale, block_size, n_blocks, q_len):
+                         l_ref, *, scale, block_size, n_blocks, q_len,
+                         window=None):
     del layer_ref, tab_ref                  # the index maps' business
     s, j = pl.program_id(0), pl.program_id(1)
     length = len_ref[s]
+    jgrid = j
+    if window is not None:
+        # the grid walks the window's blocks only: step j is the slot's
+        # logical block first + j (the index map aims the fetch the same)
+        j = jnp.maximum(length - window + 1, 0) // block_size + j
 
-    @pl.when(j == 0)
+    @pl.when(jgrid == 0)
     def _():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -1417,11 +1499,16 @@ def _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref,
     def _():
         k = k_ref[0, 0].astype(jnp.float32)       # (block_size, h*d)
         v = v_ref[0, 0].astype(jnp.float32)
-        live = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1) < length
+        pos = j * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_size), 1)
+        cached = pos < length
         # one (h, h*d) block-diagonal query tile per in-flight row: every
         # array below is (h, ·), the same program at q_len 1 and q_len k
         for i in range(q_len):
+            # row i sits at position length + i and, under a window, reads
+            # back to length + i - window + 1
+            live = cached if window is None \
+                else cached & (pos > length + i - window)
             q = q_ref[0, i].astype(jnp.float32)   # (h, h*d)
             s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32
@@ -1443,7 +1530,7 @@ def _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref,
                                      preferred_element_type=jnp.float32)
             acc_ref[i] = acc_ref[i] * corr + pv   # (h, h*d)
 
-    @pl.when(j == n_blocks - 1)
+    @pl.when(jgrid == n_blocks - 1)
     def _():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -1454,7 +1541,7 @@ def _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref,
 
 
 def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
-                mean_context, q_len=1, q_itemsize=2):
+                mean_context, q_len=1, q_itemsize=2, hkv=None):
     """``pl.CostEstimate`` for one paged decode call: the fetch-elided
     HBM bytes at ``mean_context`` tokens of ACTUAL context per slot (the
     index-map clamp makes repeated blocks free), so the pyprof roofline
@@ -1475,18 +1562,19 @@ def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
     # fetched context rounds up to whole blocks per slot
     ctx = math.ceil(ctx / block_size) * block_size
     itemsize = jnp.dtype(kv_dtype).itemsize
-    kv_bytes = 2.0 * s * h * ctx * d * itemsize
+    hkv = h if hkv is None else hkv      # grouped KV: the pool's heads
+    kv_bytes = 2.0 * s * hkv * ctx * d * itemsize
     if quantized:
-        kv_bytes += 2.0 * s * h * ctx * 4
-    io_bytes = (kv_bytes + 2.0 * s * q_len * h * h * d * q_itemsize
+        kv_bytes += 2.0 * s * hkv * ctx * 4
+    io_bytes = (kv_bytes + 2.0 * s * q_len * h * hkv * d * q_itemsize
                 + s * q_len * h * 4 + (s * (n_blocks_slot + 1) + 1) * 4)
-    flops = 4.0 * s * q_len * h * ctx * h * d  # qk^T + pv, 2 MACs each
+    flops = 4.0 * s * q_len * h * ctx * hkv * d  # qk^T + pv, 2 MACs each
     return pl.CostEstimate(flops=int(flops), bytes_accessed=int(io_bytes),
                            transcendentals=int(s * h * ctx * q_len))
 
 
 def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
-                         scale, mean_context):
+                         scale, mean_context, window=None):
     # q is (S, h, q_len, d): q_len == 1 is the classic decode step,
     # q_len == k + 1 the speculative verify — ONE program shape for
     # both. Every block spans its array's last two dims whole — the
@@ -1498,12 +1586,21 @@ def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
     block_size = kp.shape[2]
     n_blocks = tables.shape[1]
     has_scale = ksc is not None
+    # grouped KV heads: the pool's rows are hkv * d lanes wide and query
+    # head g reads KV head g // (h // hkv) where it lies
+    hkv = kp.shape[3] // d
+    if window is not None:
+        # a window of W positions before a cursor touches at most this
+        # many blocks: the grid is the window's, not the table's
+        n_blocks = min(n_blocks, (max(window, 2) - 2) // block_size + 2)
 
-    # block-diagonal query (see the section comment): (S, q_len, h, h*d)
-    eye = jnp.eye(h, dtype=jnp.bool_)
+    # block-diagonal query (see the section comment): (S, q_len, h, hkv*d)
+    # with row g's own d lanes under its KV head and exact zeros elsewhere
+    eye = jnp.eye(h, dtype=jnp.bool_) if hkv == h else (
+        jnp.arange(h)[:, None] // (h // hkv) == jnp.arange(hkv)[None, :])
     q_bd = jnp.where(eye[None, None, :, :, None],
                      jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None, :],
-                     jnp.zeros((), q.dtype)).reshape(S, q_len, h, h * d)
+                     jnp.zeros((), q.dtype)).reshape(S, q_len, h, hkv * d)
 
     def q_map(s, j, lay, tabs, lens):
         return (s, 0, 0, 0)
@@ -1513,17 +1610,22 @@ def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
         # equal consecutive indices elide the fetch, which is what
         # bounds HBM traffic to the actual context. An empty slot
         # (length 0) clamps to table entry 0 — the allocator's null
-        # block — and its compute is fully masked.
+        # block — and its compute is fully masked. Under a window the
+        # walk starts at the first block the cursor's row still reads:
+        # the blocks before it are never fetched.
         nb_valid = jnp.maximum(
             (lens[s] + block_size - 1) // block_size, 1)
+        if window is not None:
+            j = jnp.maximum(lens[s] - window + 1, 0) // block_size + j
         jj = jnp.minimum(j, nb_valid - 1)
         return (lay[0], tabs[s, jj], 0, 0)
 
-    kv_spec = pl.BlockSpec((1, 1, block_size, h * d), kv_map)
-    in_specs = [pl.BlockSpec((1, q_len, h, h * d), q_map), kv_spec, kv_spec]
+    kv_spec = pl.BlockSpec((1, 1, block_size, hkv * d), kv_map)
+    in_specs = [pl.BlockSpec((1, q_len, h, hkv * d), q_map), kv_spec,
+                kv_spec]
     args = [q_bd, kp, vp]
     if has_scale:
-        sc_spec = pl.BlockSpec((1, 1, h, block_size), kv_map)
+        sc_spec = pl.BlockSpec((1, 1, hkv, block_size), kv_map)
         in_specs += [sc_spec, sc_spec]
         args += [ksc, vsc]
 
@@ -1538,33 +1640,33 @@ def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
         _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref,
                              v_ref, ksc_ref, vsc_ref, o_ref, lse_ref, acc,
                              m, l, scale=scale, block_size=block_size,
-                             n_blocks=n_blocks, q_len=q_len)
+                             n_blocks=n_blocks, q_len=q_len, window=window)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, n_blocks),
         in_specs=in_specs,
-        out_specs=(pl.BlockSpec((1, q_len, h, h * d), q_map),
+        out_specs=(pl.BlockSpec((1, q_len, h, hkv * d), q_map),
                    pl.BlockSpec((1, q_len, h, 1), q_map)),
-        scratch_shapes=[pltpu.VMEM((q_len, h, h * d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((q_len, h, hkv * d), jnp.float32),
                         pltpu.VMEM((q_len, h, 1), jnp.float32),
                         pltpu.VMEM((q_len, h, 1), jnp.float32)])
     out_dtype = q.dtype if q.dtype != jnp.int8 else jnp.float32
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((S, q_len, h, h * d), out_dtype),
+        out_shape=(jax.ShapeDtypeStruct((S, q_len, h, hkv * d), out_dtype),
                    jax.ShapeDtypeStruct((S, q_len, h, 1), jnp.float32)),
         cost_estimate=_paged_cost(S, h, d, kp.dtype, has_scale, n_blocks,
                                   block_size, mean_context, q_len=q_len,
-                                  q_itemsize=q.dtype.itemsize),
+                                  q_itemsize=q.dtype.itemsize, hkv=hkv),
         interpret=_interp(),
         name="paged_decode_attention",
     )(jnp.reshape(layer, (1,)), tables, lengths, *args)
     # keep each head's own d lanes of its (h*d)-wide row: a select, so a
     # cross-head product is dropped before anything could be summed
     out = jnp.sum(jnp.where(eye[None, None, :, :, None],
-                            out.reshape(S, q_len, h, h, d),
+                            out.reshape(S, q_len, h, hkv, d),
                             jnp.zeros((), out_dtype)), axis=3)
     return (jnp.transpose(out, (0, 2, 1, 3)),
             jnp.transpose(lse[..., 0], (0, 2, 1)))
@@ -1576,9 +1678,18 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                            softmax_scale: Optional[float] = None,
                            mean_context: Optional[float] = None,
                            use_pallas: Optional[bool] = None,
-                           k_cast=None, v_cast=None):
+                           k_cast=None, v_cast=None,
+                           window: Optional[int] = None):
     """Single-query attention over a PAGED KV cache (see the section
     comment above) — the v2 serving decode kernel.
+
+    Grouped KV heads: a pool whose rows are ``h_kv * d`` wide with
+    ``h % h_kv == 0`` is read as it lies, query head ``g`` against KV
+    head ``g // (h // h_kv)`` (``k_new``/``v_new`` are then ``(b, h_kv,
+    d)``). ``window``: the row at cursor ``c`` reads cached positions
+    ``p`` with ``c - p < window`` (its own position counts as one of the
+    window's); the kernel's grid and fetches cover the window's blocks
+    only, so table entries left of it may be null.
 
     Speculative verify: pass ``q`` as ``(b, h, q_len, d)`` (with rank-4
     ``k_new``/``v_new`` and optional ``k_cast``/``v_cast`` store+load
@@ -1626,10 +1737,16 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
         b, h, d = q.shape
         q_len = 1
     if k_pool.ndim != 4 or v_pool.shape != k_pool.shape \
-            or k_pool.shape[3] != h * d:
+            or k_pool.shape[3] % d or h % (k_pool.shape[3] // d):
         raise ValueError(f"pool shapes {k_pool.shape}/{v_pool.shape} are "
-                         f"not (layers, num_blocks, block_size, h * d) "
-                         f"for q {q.shape}")
+                         f"not (layers, num_blocks, block_size, h_kv * d) "
+                         f"with h_kv dividing h for q {q.shape}")
+    hkv = k_pool.shape[3] // d
+    if (window is not None or hkv != h) and (
+            multi or k_pool.dtype == jnp.int8):
+        raise ValueError("window / grouped KV heads are served for one "
+                         "query row over an unquantized pool (no "
+                         "speculative verify, no int8 cache)")
     block_size = k_pool.shape[2]
     if block_tables.ndim != 2 or block_tables.shape[0] != b:
         raise ValueError(f"block_tables must be (b, n_blocks_per_slot), "
@@ -1656,7 +1773,8 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                 block_tables, lengths,
                 k_scale if quantized else None,
                 v_scale if quantized else None,
-                scale=float(softmax_scale), mean_context=mean_context)
+                scale=float(softmax_scale), mean_context=mean_context,
+                window=window)
             if not multi:
                 out, lse = out[:, :, 0], lse[:, :, 0]
             if k_new is not None and multi:
@@ -1666,6 +1784,10 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                     v_new if v_cast is None else v_cast,
                     float(softmax_scale), q.dtype)
             elif k_new is not None:
+                if hkv != h:
+                    # one token's (b, h_kv, d) row a query head: tiny
+                    k_new = jnp.repeat(k_new, h // hkv, axis=1)
+                    v_new = jnp.repeat(v_new, h // hkv, axis=1)
                 out = _merge_current(out, lse, q, k_new, v_new,
                                      float(softmax_scale), q.dtype)
             return out.astype(q.dtype)
@@ -1673,6 +1795,23 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
         # dense layout and run the dense fallback (one masked score pass
         # + the same merge) — identical math, O(table span) traffic
         T = block_tables.shape[1] * block_size
+        if window is not None or hkv != h:
+            # the oracle of the windowed / grouped form: the cached
+            # positions the cursor's row reads and the current token as
+            # one more column, one plain softmax
+            def dense(pool, new):
+                g = pool[layer][block_tables].reshape(b, T, hkv, d)
+                g = jnp.concatenate([g, new[:, None].astype(g.dtype)], 1)
+                return jnp.repeat(g.transpose(0, 2, 1, 3), h // hkv, 1)
+            pos = jnp.arange(T + 1)[None, :]
+            cur = lengths[:, None]
+            seen = (pos < cur) | (pos == T)
+            if window is not None:
+                seen = seen & ((pos > cur - window) | (pos == T))
+            bias = jnp.where(seen, 0.0, NEG_INF)[:, None, None, :]
+            return mha_reference(q[:, :, None], dense(k_pool, k_new),
+                                 dense(v_pool, v_new), bias,
+                                 softmax_scale=softmax_scale)[:, :, 0]
 
         def gather(pool):
             g = pool[layer][block_tables]       # (b, nbs, bs, h*d)

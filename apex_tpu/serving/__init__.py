@@ -41,6 +41,7 @@ from apex_tpu.observability.reqtrace import (RequestRecord, RequestTrace,
 from apex_tpu.observability.slo import (SLOTarget, SLOTracker,
                                         SLOViolationError)
 from apex_tpu.serving.cache import (AdmitPlan, BlockAllocator, KVCache,
+                                    KindBlockAllocator, KindPagedKVCache,
                                     PagedKVCache, PoolExhausted, StepPlan,
                                     cache_bytes_per_slot,
                                     paged_block_bytes)
@@ -55,7 +56,8 @@ from apex_tpu.serving.scheduler import (Completion, DraftSource,
                                         SlotScheduler)
 
 __all__ = ["KVCache", "cache_bytes_per_slot", "ServingEngine",
-           "PagedKVCache", "BlockAllocator", "AdmitPlan", "StepPlan",
+           "PagedKVCache", "BlockAllocator", "KindPagedKVCache",
+           "KindBlockAllocator", "AdmitPlan", "StepPlan",
            "PoolExhausted", "paged_block_bytes", "PagedServingEngine",
            "sample_tokens", "verify_tokens", "Completion", "Request",
            "SlotScheduler", "DraftSource", "NGramDraftSource",

@@ -40,6 +40,16 @@ sharing and COW are all zero-recompile by construction. Block index 0
 is the allocator's reserved NULL block: unmapped table entries and
 masked writes land there, keeping every device program total.
 
+**Pools by layer kind**: a model built from a layer pattern
+(:mod:`apex_tpu.models.pattern_decoder`) keeps one pool and one block
+table per layer KIND (:class:`KindPagedKVCache`,
+:class:`KindBlockAllocator`): a window layer reads only the last
+``window`` positions, so its allocator (``BlockAllocator(window=)``) never
+maps a prompt block that lies wholly left of the window and hands a block
+back the moment the cursor has left it for good, while a full layer's
+table keeps every block until ``release``. One cursor a slot, a table a
+kind.
+
 The pool is token-major with the heads fused into its last axis because
 that is the one layout the append, the layer scan and the decode kernel
 all take as it is (measured and compiled for PR 27; the earlier
@@ -74,8 +84,9 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["KVCache", "cache_bytes_per_slot", "PagedKVCache",
-           "BlockAllocator", "AdmitPlan", "StepPlan", "PoolExhausted",
-           "paged_block_bytes", "store_roundtrip"]
+           "KindPagedKVCache", "BlockAllocator", "KindBlockAllocator",
+           "AdmitPlan", "StepPlan", "PoolExhausted", "paged_block_bytes",
+           "store_roundtrip"]
 
 # floor for the absmax quantization scale: keeps an all-zero row (e.g. a
 # never-written slot) from producing 0/0 at dequantization
@@ -543,6 +554,31 @@ class PagedKVCache:
             new["v_scale"] = scatter_sc(self.v_scale, vs)
         return dataclasses.replace(self, **new)
 
+    def write_layer_blocks(self, layer, k_new: jnp.ndarray,
+                           v_new: jnp.ndarray,
+                           block_row: jnp.ndarray) -> "PagedKVCache":
+        """Prefill write of ONE layer (an int32 scalar traced inside a
+        layer scan): ``k_new``/``v_new`` are ``(P, H * D)`` token-major
+        with ``P`` a multiple of ``block_size``, ``block_row`` as in
+        :meth:`write_prompt_blocks`. Whole ``(block_size, H * D)`` blocks
+        land in place; entries that name the null block (padding, blocks
+        left of a window) are absorbed there. Unquantized pools only."""
+        if self.quantized:
+            raise ValueError("write_layer_blocks serves unquantized pools")
+        P = k_new.shape[0]
+        bs = self.block_size
+        if P % bs:
+            raise ValueError(f"prompt window {P} must be a multiple of "
+                             f"block_size {bs}")
+        row = jnp.asarray(block_row, jnp.int32)
+
+        def scatter(pool, x):
+            blocks = x.astype(pool.dtype).reshape(P // bs, bs, -1)
+            return pool.at[layer, row].set(blocks, mode="drop")
+
+        return dataclasses.replace(self, k=scatter(self.k, k_new),
+                                   v=scatter(self.v, v_new))
+
     def cow_copy(self, src: jnp.ndarray, dst: jnp.ndarray) -> "PagedKVCache":
         """Copy-on-write resolution: pool block ``dst[s] <- src[s]`` per
         slot, BEFORE this step's reads and append (the caller sequences
@@ -579,6 +615,49 @@ class PagedKVCache:
             new["k_scale"] = copy(self.k_scale)
             new["v_scale"] = copy(self.v_scale)
         return dataclasses.replace(self, **new)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class KindPagedKVCache:
+    """One :class:`PagedKVCache` pool per layer kind of a pattern model
+    (module docstring, "Pools by layer kind"): ``pools[kind]`` holds the
+    ``(layers of the kind, num_blocks of the kind, block_size, H_kv * D)``
+    pool, block 0 of each its own null block."""
+
+    pools: Dict[str, PagedKVCache]
+
+    def tree_flatten(self):
+        kinds = tuple(sorted(self.pools))    # as jax orders a dict's keys
+        return tuple(self.pools[k] for k in kinds), kinds
+
+    @classmethod
+    def tree_unflatten(cls, kinds, pools):
+        return cls(dict(zip(kinds, pools)))
+
+    @classmethod
+    def create(cls, kinds: Dict[str, Tuple[int, Optional[int]]],
+               num_blocks: Dict[str, int], num_heads: int, block_size: int,
+               head_dim: int, dtype=jnp.bfloat16) -> "KindPagedKVCache":
+        """``kinds``: ``{kind: (layers, window or None)}`` as the model's
+        ``cfg.cache_kinds`` gives it; ``num_blocks[kind]`` includes the
+        kind's null block."""
+        if jnp.dtype(dtype) == jnp.int8:
+            raise ValueError("pools by layer kind are unquantized")
+        return cls({kind: PagedKVCache.create(layers, num_blocks[kind],
+                                              num_heads, block_size,
+                                              head_dim, dtype)
+                    for kind, (layers, _) in kinds.items()})
+
+    def nbytes(self) -> int:
+        return sum(pool.nbytes() for pool in self.pools.values())
+
+    def scrub_null_blocks(self) -> "KindPagedKVCache":
+        return KindPagedKVCache({
+            kind: dataclasses.replace(
+                pool, k=pool.k.at[:, NULL_BLOCK].set(0),
+                v=pool.v.at[:, NULL_BLOCK].set(0))
+            for kind, pool in self.pools.items()})
 
 
 # ---------------------------------------------------------------------------
@@ -651,13 +730,24 @@ class BlockAllocator:
     pending block is the complete COW story."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 blocks_per_slot: int, max_seqs: int):
+                 blocks_per_slot: int, max_seqs: int,
+                 window: Optional[int] = None):
         if num_blocks < 2:
             raise ValueError(f"num_blocks must be >= 2, got {num_blocks}")
+        if window is not None and window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.blocks_per_slot = int(blocks_per_slot)
         self.max_seqs = int(max_seqs)
+        # a window layer's pool: the row at cursor c reads positions
+        # > c - window, so a block wholly at or below c - window is out
+        # for good (the cursor only grows) and goes back to the pool
+        self.window = None if window is None else int(window)
+        self.blocks_given = 0        # monotonic: blocks ever mapped
+        self.blocks_returned = 0     # ... and handed back by the window
+        # per slot, the first table index the window has not handed back
+        self._live_from = np.zeros(max_seqs, np.int32)
         # LIFO free list; block 0 is the reserved null block
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
         self.refcount = np.zeros(num_blocks, np.int32)
@@ -690,7 +780,42 @@ class BlockAllocator:
         return self.blocks_per_slot * self.block_size
 
     def blocks_for(self, n_tokens: int) -> int:
-        return -(-int(n_tokens) // self.block_size)
+        """Blocks a cold admission of ``n_tokens`` maps: all of them, or
+        under a window those the row at the cursor still reads."""
+        return (-(-int(n_tokens) // self.block_size)
+                - self.first_live_block(n_tokens))
+
+    def can_admit(self, n_tokens: int) -> bool:
+        """Whether a cold admission of ``n_tokens`` finds its blocks."""
+        return self.free_blocks >= self.blocks_for(n_tokens)
+
+    def first_live_block(self, cursor: int) -> int:
+        """The first block the row at ``cursor`` (and every later row)
+        can still read: 0 without a window."""
+        if self.window is None:
+            return 0
+        return max(0, int(cursor) - self.window + 1) // self.block_size
+
+    def trim(self, slot: int) -> int:
+        """Hand back ``slot``'s blocks that lie wholly left of its
+        window; how many went. No-op without a window."""
+        if self.window is None:
+            return 0
+        gone = 0
+        live = self.first_live_block(self.lengths[slot])
+        for bidx in range(int(self._live_from[slot]), live):
+            block = int(self.tables[slot, bidx])
+            if block != NULL_BLOCK:
+                self.tables[slot, bidx] = NULL_BLOCK
+                self._release_block(block)
+                gone += 1
+        self._live_from[slot] = max(int(self._live_from[slot]), live)
+        self.blocks_returned += gone
+        return gone
+
+    @property
+    def blocks_in_use(self) -> int:
+        return self.num_blocks - 1 - self.free_blocks
 
     # -- low-level block lifecycle ------------------------------------------
 
@@ -813,6 +938,8 @@ class BlockAllocator:
                                           for t in prompt[shared_tokens:]),
                              cow_pending=covers_all)
         # cold path: real blocks for the prompt, nulls for the padding
+        # (and, under a window, for the blocks already left of it)
+        n_skip = self.first_live_block(P)
         n_real = self.blocks_for(P)
         row: List[int] = []
         try:
@@ -824,17 +951,22 @@ class BlockAllocator:
             raise
         for b in row:
             self.refcount[b] = 1
-        self.tables[slot, :n_real] = row
+        self.blocks_given += n_real
+        self.tables[slot, n_skip:n_skip + n_real] = row
         self.lengths[slot] = P
+        self._live_from[slot] = n_skip
         return AdmitPlan(slot, P, prefill=True,
-                         block_row=row + [NULL_BLOCK] *
-                         (prefill_blocks - n_real))
+                         block_row=[NULL_BLOCK] * n_skip + row
+                         + [NULL_BLOCK] * (prefill_blocks - n_skip
+                                           - n_real))
 
     def register_prefix(self, slot: int, prompt: Sequence[int]) -> None:
         """After a COLD prefill lands: index ``slot``'s full prompt
         blocks under their chain digests so later admissions can share
         them. Existing registrations win (their block is already
         shared-ready); a block never re-registers under a second key."""
+        if self.window is not None:
+            return      # a window hands its early blocks back: no sharing
         for i, (digest, chunk) in enumerate(self._chain(prompt)):
             block = int(self.tables[slot, i])
             if block == NULL_BLOCK or block in self._block_key:
@@ -852,6 +984,7 @@ class BlockAllocator:
             self._release_block(int(b))
         self.tables[slot] = NULL_BLOCK
         self.lengths[slot] = 0
+        self._live_from[slot] = 0
         self._cow_pending.pop(slot, None)
 
     # -- per-step device arguments ------------------------------------------
@@ -953,6 +1086,8 @@ class BlockAllocator:
                 self.refcount[new] = 1
                 self.tables[slot, bidx] = new
                 taken.append(bidx)
+            if not short:
+                self.blocks_given += len(taken)
             if short:
                 # atomic per slot: hand the partial grab back so a
                 # sibling slot (or the next step) can use it
@@ -968,6 +1103,8 @@ class BlockAllocator:
         for slot in slots:
             self.lengths[slot] = min(int(self.lengths[slot]) + 1,
                                      self.capacity_tokens)
+            if self.window is not None:
+                self.trim(slot)
 
     def advance_counts(self, slots: Sequence[int],
                        counts: Sequence[int]) -> None:
@@ -977,3 +1114,110 @@ class BlockAllocator:
         for slot, n in zip(slots, counts):
             self.lengths[slot] = min(int(self.lengths[slot]) + int(n),
                                      self.capacity_tokens)
+
+
+class KindBlockAllocator:
+    """The allocators of a pattern model's pools, one
+    :class:`BlockAllocator` a layer kind, behind the calls the paged
+    engine makes of one (module docstring, "Pools by layer kind"). What
+    is an array there is a dict by kind here (``tables``, the block ids of
+    ``append_targets``, an admission's ``block_row``); the cursor
+    (``lengths``) is one a slot, the same in every kind. An admission or a
+    step either gets its blocks in every kind or in none. No prefix is
+    shared (a window layer has handed its early blocks back), so no
+    copy-on-write is ever pending."""
+
+    def __init__(self, kinds: Dict[str, Tuple[int, Optional[int]]],
+                 num_blocks: Dict[str, int], block_size: int,
+                 blocks_per_slot: int, max_seqs: int):
+        self.kinds = {kind: BlockAllocator(num_blocks[kind], block_size,
+                                           blocks_per_slot, max_seqs,
+                                           window=window)
+                      for kind, (_, window) in kinds.items()}
+        self._first = next(iter(self.kinds.values()))
+        self.block_size = int(block_size)
+        self.blocks_per_slot = int(blocks_per_slot)
+        self.max_seqs = int(max_seqs)
+        self.num_blocks = sum(num_blocks[kind] for kind in self.kinds)
+        self.cow_copies = 0
+
+    # -- what the engine threads into the programs ----------------------------
+
+    @property
+    def tables(self) -> Dict[str, np.ndarray]:
+        return {kind: a.tables for kind, a in self.kinds.items()}
+
+    @property
+    def lengths(self) -> np.ndarray:
+        return self._first.lengths
+
+    @property
+    def capacity_tokens(self) -> int:
+        return self._first.capacity_tokens
+
+    @property
+    def free_blocks(self) -> int:
+        """Allocatable blocks over all kinds (the scheduler's gauge)."""
+        return sum(a.free_blocks for a in self.kinds.values())
+
+    def blocks_for(self, n_tokens: int) -> int:
+        return sum(a.blocks_for(n_tokens) for a in self.kinds.values())
+
+    def can_admit(self, n_tokens: int) -> bool:
+        return all(a.can_admit(n_tokens) for a in self.kinds.values())
+
+    def lookup(self, prompt) -> List[int]:
+        return []
+
+    def register_prefix(self, slot: int, prompt) -> None:
+        """Nothing is indexed: see the class docstring."""
+
+    # -- admission / stepping / release ----------------------------------------
+
+    def admit(self, slot: int, prompt: Sequence[int], prefill_blocks: int,
+              share: bool = False) -> AdmitPlan:
+        rows, done = {}, []
+        try:
+            for kind, a in self.kinds.items():
+                rows[kind] = a.admit(slot, prompt, prefill_blocks,
+                                     share=False).block_row
+                done.append(a)
+        except Exception:
+            for a in done:
+                a.release(slot)
+            raise
+        return AdmitPlan(slot, len(prompt), prefill=True, block_row=rows)
+
+    def prepare_step(self, active_slots: Sequence[int]) -> StepPlan:
+        failed: List[int] = []
+        for a in self.kinds.values():
+            failed += a.prepare_step(active_slots).failed
+        zeros = np.zeros(self.max_seqs, np.int32)
+        return StepPlan(zeros, zeros, sorted(set(failed)))
+
+    def append_targets(self, active: np.ndarray):
+        out = {kind: a.append_targets(active)
+               for kind, a in self.kinds.items()}
+        offsets = next(iter(out.values()))[1]
+        return {kind: ids for kind, (ids, _) in out.items()}, offsets
+
+    def advance(self, slots: Sequence[int]) -> None:
+        for a in self.kinds.values():
+            a.advance(slots)
+
+    def release(self, slot: int) -> None:
+        for a in self.kinds.values():
+            a.release(slot)
+
+    # -- counters ----------------------------------------------------------------
+
+    @property
+    def blocks_in_use(self) -> Dict[str, int]:
+        return {kind: a.blocks_in_use for kind, a in self.kinds.items()}
+
+    def window_blocks(self) -> Tuple[int, int]:
+        """``(given, returned)``: blocks ever mapped by the window kinds
+        and blocks their windows handed back before release."""
+        win = [a for a in self.kinds.values() if a.window is not None]
+        return (sum(a.blocks_given for a in win),
+                sum(a.blocks_returned for a in win))

@@ -44,6 +44,7 @@ import numpy as np
 from apex_tpu.observability.costs import memory_budget
 from apex_tpu.observability.trace import span
 from apex_tpu.serving.cache import (KVCache, PagedKVCache, BlockAllocator,
+                                    KindPagedKVCache, KindBlockAllocator,
                                     AdmitPlan, PoolExhausted,
                                     cache_bytes_per_slot, paged_block_bytes)
 from apex_tpu.serving.sampling import sample_tokens, verify_tokens
@@ -107,6 +108,12 @@ class ServingEngine:
                  quarantine: bool = False, speculate_k: int = 0):
         model._require_cacheable()
         cfg = model.cfg
+        if hasattr(cfg, "cache_kinds"):
+            raise ValueError(
+                "the dense ServingEngine keeps one (L, S, H, max_len, D) "
+                "reservation for one kind of layer; a model built from a "
+                "layer pattern has a pool a kind and is served by "
+                "PagedServingEngine (docs/SERVING.md, Limits)")
         if max_len > cfg.max_position_embeddings:
             raise ValueError(
                 f"max_len {max_len} exceeds max_position_embeddings "
@@ -566,12 +573,27 @@ class PagedServingEngine(ServingEngine):
     the fixed-shape programs as plain array arguments, so per-request
     bookkeeping never retraces anything.
 
+    A model built from a layer pattern (``model.cfg.cache_kinds``:
+    :class:`~apex_tpu.models.pattern_decoder.PatternDecoder`) gets one
+    pool and one block table per layer KIND
+    (:class:`~apex_tpu.serving.cache.KindPagedKVCache`,
+    :class:`~apex_tpu.serving.cache.KindBlockAllocator`): the tables and
+    append targets ride into the same programs as dicts by kind, a
+    window kind's blocks go back to its pool as the cursor leaves them,
+    and what a ``step_stats`` model counts in a step comes back in the
+    token fetch (:attr:`last_stats`). Such a model is served without
+    the prefix index and without speculation (docs/SERVING.md, Limits).
+
     Extra construction knobs vs the dense engine:
 
     Args:
+      prefill_len: one prompt window, or a list of them: one AOT prefill
+        program a bucket, a prompt runs the smallest that holds it and
+        the scheduler admits up to the widest.
       num_blocks: global pool size in blocks (block 0 is the reserved
         null block — allocatable capacity is ``num_blocks - 1``). Size
-        with :meth:`suggest_pool_blocks`.
+        with :meth:`suggest_pool_blocks`. A dict by layer kind for a
+        model with ``cfg.cache_kinds``.
       block_size: tokens per block. The paged Pallas kernel takes any
         size on every backend; ``block_size % 128 == 0`` keeps its
         score rows lane-dense on TPU.
@@ -591,6 +613,12 @@ class PagedServingEngine(ServingEngine):
                  speculate_k: int = 0):
         model._require_cacheable()
         cfg = model.cfg
+        # a few prompt-length buckets, one prefill program each: a prompt
+        # runs the smallest that holds it (an int is the one bucket)
+        buckets = tuple(sorted({int(b) for b in (
+            prefill_len if isinstance(prefill_len, (list, tuple))
+            else (prefill_len,))}))
+        prefill_len = buckets[-1]
         if max_len > cfg.max_position_embeddings:
             raise ValueError(
                 f"max_len {max_len} exceeds max_position_embeddings "
@@ -598,18 +626,32 @@ class PagedServingEngine(ServingEngine):
         if prefill_len > max_len:
             raise ValueError(f"prefill_len {prefill_len} exceeds max_len "
                              f"{max_len}")
-        if prefill_len % block_size != 0:
+        if any(b % block_size for b in buckets):
             raise ValueError(
-                f"prefill_len {prefill_len} must be a multiple of "
+                f"prefill_len {buckets} must be multiples of "
                 f"block_size {block_size} (the prefill program writes "
                 "whole pool blocks)")
+        self.by_kind = hasattr(cfg, "cache_kinds")
+        if self.by_kind and (speculate_k or prefix_suffix_cap is not None):
+            raise ValueError(
+                "a model with pools by layer kind is served without "
+                "speculation (its window kernel takes one query row) and "
+                "without the prefix index (a window layer has handed its "
+                "early blocks back): docs/SERVING.md, Limits")
+        if self.by_kind != isinstance(num_blocks, dict):
+            raise ValueError(
+                "num_blocks is a dict by layer kind for a model with "
+                "cfg.cache_kinds and one number for any other; got "
+                f"{num_blocks!r}")
         self.model = model
         self.params = params
         self.max_seqs = int(max_seqs)
         self.max_len = int(max_len)
         self.prefill_len = int(prefill_len)
+        self.prefill_buckets = buckets
         self.block_size = int(block_size)
-        self.num_blocks = int(num_blocks)
+        self.num_blocks = ({k: int(n) for k, n in num_blocks.items()}
+                           if self.by_kind else int(num_blocks))
         self.top_k = int(top_k)
         self.quarantine = bool(quarantine)
         self.speculate_k = int(speculate_k)
@@ -625,6 +667,9 @@ class PagedServingEngine(ServingEngine):
         self.last_finite: Optional[np.ndarray] = None
         self.last_admit: Optional[AdmitPlan] = None
         self.last_failed: list = []
+        # a model with ``step_stats`` returns small int32 counters beside
+        # its logits; they ride to the host IN the token fetch
+        self.last_stats: Optional[np.ndarray] = None
         self.swaps = 0
         with span("engine.build"):
             self._build(model, params, cache_dtype, rng_seed)
@@ -637,42 +682,63 @@ class PagedServingEngine(ServingEngine):
         block_size = self.block_size
         self.prefill_blocks = self.prefill_len // self.block_size
         blocks_per_slot = -(-self.max_len // self.block_size)
-        self.cache = PagedKVCache.create(
-            cfg.num_layers, num_blocks, cfg.num_attention_heads,
-            block_size, cfg.head_dim, dtype=cache_dtype)
-        self.allocator = BlockAllocator(num_blocks, block_size,
-                                        blocks_per_slot, max_seqs)
+        if self.by_kind:
+            self.cache = KindPagedKVCache.create(
+                cfg.cache_kinds, num_blocks, cfg.num_key_value_heads,
+                block_size, cfg.head_dim, dtype=cache_dtype)
+            self.allocator = KindBlockAllocator(
+                cfg.cache_kinds, num_blocks, block_size, blocks_per_slot,
+                max_seqs)
+        else:
+            self.cache = PagedKVCache.create(
+                cfg.num_layers, num_blocks, cfg.num_attention_heads,
+                block_size, cfg.head_dim, dtype=cache_dtype)
+            self.allocator = BlockAllocator(num_blocks, block_size,
+                                            blocks_per_slot, max_seqs)
+        stats = getattr(model, "step_stats", False)
+        self._stats_shape = model.stats_shape if stats else None
+        #: the counter each column of :attr:`last_stats` adds to
+        self.stats_names = model.stats_names if stats else ()
+
+        def packed(toks, out):
+            """The sampled tokens with the model's counters behind them:
+            one array, one fetch."""
+            if not stats:
+                return toks
+            return jnp.concatenate([toks.reshape(-1).astype(jnp.int32),
+                                    out[2].reshape(-1).astype(jnp.int32)])
 
         def prefill_step(params, cache, tokens, block_row, true_len,
                          temperature, rng):
             with jax.named_scope("serve_prefill"):
-                logits, cache = model.forward(params, tokens,
-                                              kv_cache=cache,
-                                              block_row=block_row,
-                                              prompt_len=true_len,
-                                              last_logit_only=True)
+                out = model.forward(params, tokens, kv_cache=cache,
+                                    block_row=block_row,
+                                    prompt_len=true_len,
+                                    last_logit_only=True)
+                logits, cache = out[0], out[1]
                 tok = sample_tokens(logits[0], rng, temperature[None],
                                     self.top_k)[0]
-            return cache, tok
+            return cache, packed(tok, out)
 
         mc = self.mean_context
 
         def _decode_core(params, cache, tables, lengths, tokens,
                          temperature, block_ids, offsets, cow_src,
                          cow_dst, rng, poison=None):
-            logits, cache = model.forward(
+            out = model.forward(
                 params, tokens[:, None], kv_cache=cache,
                 block_tables=tables, lengths=lengths,
                 append_block_ids=block_ids, append_offsets=offsets,
                 cow_src=cow_src, cow_dst=cow_dst, mean_context=mc)
+            logits, cache = out[0], out[1]
             if poison is not None:
                 logits = logits + poison[:, None]
                 finite = jnp.all(jnp.isfinite(logits), axis=-1)
                 toks = sample_tokens(logits, rng, temperature,
                                      self.top_k)
-                return cache, toks, finite
+                return cache, packed(toks, out), finite
             toks = sample_tokens(logits, rng, temperature, self.top_k)
-            return cache, toks
+            return cache, packed(toks, out)
 
         if self.quarantine:
             def decode_step(params, cache, tables, lengths, tokens,
@@ -694,22 +760,31 @@ class PagedServingEngine(ServingEngine):
 
         self._init_key(rng_seed)
         S = self.max_seqs
-        ex_tokens = jnp.zeros((1, self.prefill_len), jnp.int32)
-        ex_row = jnp.zeros((self.prefill_blocks,), jnp.int32)
+        by_kind = (lambda x: {k: x for k in cfg.cache_kinds}) \
+            if self.by_kind else (lambda x: x)
         ex_scalar = jnp.zeros((), jnp.int32)
         ex_temp = jnp.zeros((), jnp.float32)
+        self._prefill_programs = {}
         with span("compile.prefill"):
-            self.prefill_traced = jax.jit(
-                prefill_step, donate_argnums=(1,)).trace(
-                    params, self.cache, ex_tokens, ex_row, ex_scalar,
-                    ex_temp, self._key)
-            self.prefill_compiled = self.prefill_traced.lower().compile()
+            for bucket in self.prefill_buckets:
+                self.prefill_traced = jax.jit(
+                    prefill_step, donate_argnums=(1,)).trace(
+                        params, self.cache,
+                        jnp.zeros((1, bucket), jnp.int32),
+                        by_kind(jnp.zeros((bucket // block_size,),
+                                          jnp.int32)),
+                        ex_scalar, ex_temp, self._key)
+                self._prefill_programs[bucket] = \
+                    self.prefill_traced.lower().compile()
+            # the widest bucket's program stands for the leg (lint,
+            # attention_paths)
+            self.prefill_compiled = self._prefill_programs[self.prefill_len]
         self._zero_poison = jnp.zeros((S,), jnp.float32)
         zs = jnp.zeros((S,), jnp.int32)
         decode_args = (params, self.cache,
-                       jnp.zeros((S, blocks_per_slot), jnp.int32), zs,
-                       zs, jnp.zeros((S,), jnp.float32), zs, zs, zs, zs,
-                       self._key)
+                       by_kind(jnp.zeros((S, blocks_per_slot), jnp.int32)),
+                       zs, zs, jnp.zeros((S,), jnp.float32), by_kind(zs),
+                       zs, zs, zs, self._key)
         if self.quarantine:
             decode_args += (self._zero_poison,)
         with span("compile.decode"):
@@ -789,6 +864,8 @@ class PagedServingEngine(ServingEngine):
             # back to the "reads as zeros" invariant. Real in-place
             # writes on every donated leaf — the donation lint holds.
             from apex_tpu.serving.cache import NULL_BLOCK, _MIN_SCALE
+            if self.by_kind:
+                return cache.scrub_null_blocks()
             new = {"k": cache.k.at[:, NULL_BLOCK].set(0),
                    "v": cache.v.at[:, NULL_BLOCK].set(0)}
             if cache.quantized:
@@ -814,8 +891,28 @@ class PagedServingEngine(ServingEngine):
     def can_admit(self, prompt: Sequence[int]) -> bool:
         """Whether the pool can take ``prompt`` right now (conservative:
         assumes a cold admission; a prefix hit needs fewer blocks)."""
-        return (self.allocator.free_blocks
-                >= self.allocator.blocks_for(len(prompt)))
+        return self.allocator.can_admit(len(prompt))
+
+    def pad_prompt(self, prompt: Sequence[int]) -> np.ndarray:
+        """``prompt`` right-padded to the smallest bucket that holds it."""
+        if len(self.prefill_buckets) == 1:
+            return super().pad_prompt(prompt)
+        if not 0 < len(prompt) <= self.prefill_len:
+            raise ValueError(
+                f"prompt length {len(prompt)} outside (0, "
+                f"{self.prefill_len}], the widest prefill bucket")
+        bucket = next(b for b in self.prefill_buckets if b >= len(prompt))
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, : len(prompt)] = np.asarray(prompt, np.int32)
+        return padded
+
+    def _unpack(self, fetched: np.ndarray, n: int) -> np.ndarray:
+        """The first ``n`` values of a token fetch; what a ``step_stats``
+        model packed behind them goes to :attr:`last_stats`."""
+        if self._stats_shape is None:
+            return fetched
+        self.last_stats = fetched[n:].reshape(self._stats_shape)
+        return fetched[:n]
 
     def prefill(self, prompt: Sequence[int], slot: int,
                 temperature: float = 0.0) -> int:
@@ -847,26 +944,36 @@ class PagedServingEngine(ServingEngine):
                               len(prompt) - 1)
                 if shared and len(prompt) - covered > self.prefix_suffix_cap:
                     shared = []        # tail too long: cold prefill wins
+                padded = self.pad_prompt(prompt)
+                bucket = padded.shape[1]
                 plan = self.allocator.admit(slot, prompt,
-                                            self.prefill_blocks,
+                                            bucket // self.block_size,
                                             share=bool(shared))
                 self.last_admit = plan
                 if plan.prefill:
-                    args = (self.params, self.cache,
-                            self.pad_prompt(prompt),
-                            _host(plan.block_row, np.int32),
+                    args = (self.params, self.cache, padded,
+                            jax.tree_util.tree_map(
+                                lambda r: _host(r, np.int32),
+                                plan.block_row,
+                                is_leaf=lambda r: isinstance(r, list)),
                             _host(len(prompt), np.int32),
                             _host(temperature, np.float32),
                             self._next_key())
             if plan.prefill:
-                with span("prefill.dispatch"):
-                    self.cache, tok = self.prefill_compiled(*args)
+                # with several buckets the span says which one's program
+                # the prompt ran and how many of its positions are tokens
+                # (the rest is padding)
+                ids = dict(bucket=bucket, tokens=len(prompt)) \
+                    if len(self.prefill_buckets) > 1 else {}
+                with span("prefill.dispatch", **ids):
+                    self.cache, tok = self._prefill_programs[bucket](*args)
                 with span("prefill.index"):
                     # index the freshly written full blocks so LATER
                     # admissions can share them
                     self.allocator.register_prefix(slot, prompt)
                 with span("prefill.wait"):
-                    return int(tok)
+                    return int(self._unpack(np.asarray(tok).reshape(-1),
+                                            1)[0])
             # prefix hit: decode the un-shared tail token by token through
             # the ordinary decode program (same compiled program — zero
             # recompiles), other slots frozen; each is an engine.decode
@@ -907,12 +1014,15 @@ class PagedServingEngine(ServingEngine):
                 ok = active.copy()
                 ok[step.failed] = False
                 block_ids, offsets = self.allocator.append_targets(ok)
+                # a dict by layer kind where the pools are (a leaf is an
+                # array either way)
+                host = lambda x: jax.tree_util.tree_map(_host, x)
                 args = (self.params, self.cache,
-                        _host(self.allocator.tables),
+                        host(self.allocator.tables),
                         _host(self.allocator.lengths),
                         _host(tokens, np.int32),
                         _host(temperatures, np.float32),
-                        _host(block_ids), _host(offsets),
+                        host(block_ids), _host(offsets),
                         _host(step.cow_src), _host(step.cow_dst),
                         self._next_key())
                 args += self._poison_arg(poison)
@@ -923,7 +1033,7 @@ class PagedServingEngine(ServingEngine):
             with span("decode.wait"):
                 if finite:
                     self.last_finite = np.asarray(finite[0])
-                return np.asarray(toks)
+                return self._unpack(np.asarray(toks), self.max_seqs)
 
     def verify(self, tokens: np.ndarray, drafts: np.ndarray,
                temperatures: np.ndarray,
@@ -1000,6 +1110,10 @@ class PagedServingEngine(ServingEngine):
 
     def block_bytes(self) -> int:
         cfg = self.model.cfg
+        if self.by_kind:
+            raise NotImplementedError(
+                "the capacity arithmetic sizes one pool; pools by layer "
+                "kind are sized by the caller (num_blocks by kind)")
         return paged_block_bytes(cfg.num_layers, cfg.num_attention_heads,
                                  self.block_size, cfg.head_dim,
                                  self.cache.k.dtype)

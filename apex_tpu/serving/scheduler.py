@@ -267,6 +267,9 @@ class SlotScheduler:
         # paged engines only: the allocator's monotonic COW counter at
         # the last step, so serve/blocks_cow_copied emits deltas
         self._cow_seen = 0
+        # pools by layer kind only: the window allocators' monotonic
+        # (given, returned) at the last step
+        self._window_seen = (0, 0)
 
     # -- submission ---------------------------------------------------------
 
@@ -585,6 +588,7 @@ class SlotScheduler:
         # honest first-token time (prefill-done == first-token: the
         # admission program samples it)
         rec.prefill_done_t = rec.first_token_t = time.perf_counter()
+        self._count_step_stats()
         st = _Active(req, [], len(req.prompt), rec, deadline_t=deadline)
         self.active[slot] = st
         self._temps[slot] = req.temperature
@@ -670,8 +674,20 @@ class SlotScheduler:
                                      mask, poison=poison)
         self.steps = step_idx
         self._reg.counter("serve/decode_steps").inc()
+        self._count_step_stats()
         with span("sched.harvest"):
             self._harvest(nxt, counts, mask)
+
+    def _count_step_stats(self) -> None:
+        """What a ``step_stats`` model counted in the program that just
+        ran (it came back in the token fetch), summed over its rows and
+        added to the counters the model names (``stats_names``, one a
+        column)."""
+        stats = getattr(self.engine, "last_stats", None)
+        if stats is None:
+            return
+        for name, n in zip(self.engine.stats_names, stats.sum(axis=0)):
+            self._reg.counter(f"serve/{name}").inc(int(n))
 
     def _harvest(self, nxt: np.ndarray, counts: Optional[np.ndarray],
                  mask: np.ndarray) -> None:
@@ -758,6 +774,15 @@ class SlotScheduler:
             self._reg.gauge("serve/pool_blocks_used").set(used)
             self._reg.gauge("serve/pool_utilization").set(
                 used / capacity if capacity else 0.0)
+            if hasattr(alloc, "kinds"):
+                for kind, n in alloc.blocks_in_use.items():
+                    self._reg.gauge(f"serve/blocks_in_use/{kind}").set(n)
+                given, returned = alloc.window_blocks()
+                self._reg.counter("serve/window_blocks_given").inc(
+                    given - self._window_seen[0])
+                self._reg.counter("serve/window_blocks_returned").inc(
+                    returned - self._window_seen[1])
+                self._window_seen = (given, returned)
             if alloc.cow_copies > self._cow_seen:
                 self._reg.counter("serve/blocks_cow_copied").inc(
                     alloc.cow_copies - self._cow_seen)

@@ -268,3 +268,96 @@ def test_paged_step_updates_the_pool_in_place_on_v5e(kind, weights,
     pool_elements = pool_bytes // 2 // 2           # K or V, bf16
     assert _pool_sized_copies(text, {
         pool_elements, pool_elements // LARGE["layers"]}) == []
+
+
+# ---------------------------------------------------------------------------
+# the pattern decoder's programs at command-a-plus-05-2026's published widths
+# ---------------------------------------------------------------------------
+
+# command-a-plus-05-2026.rag-r80 (benchmark/workloads): one chip of eight's
+# share, 4 layers (3 window + 1 full), 16 query heads on 1 KV head of 128,
+# 16 held + 4 shared experts of 4096, pools by kind for 32 slots of 8,704
+COMMAND = dict(slots=32, max_len=8704, block=128, window=4096,
+               blocks={"sliding_attention": 1057, "full_attention": 2177})
+
+
+def _pattern_step(kind, sharding):
+    from apex_tpu.models.pattern_decoder import (PatternDecoder,
+                                                 PatternDecoderConfig)
+    from apex_tpu.serving.cache import KindPagedKVCache
+    c = COMMAND
+    cfg = PatternDecoderConfig(
+        vocab_size=32768, hidden_size=4096,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        num_attention_heads=16, num_key_value_heads=1, head_dim=128,
+        expert_size=4096, num_experts=128, num_experts_per_tok=8,
+        held_experts=tuple(range(16)), num_shared_experts=4,
+        sliding_window=c["window"], rope_theta=50000.0,
+        max_position_embeddings=200000)
+    model = PatternDecoder(cfg)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    params = described(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = described(jax.eval_shape(lambda: KindPagedKVCache.create(
+        cfg.cache_kinds, c["blocks"], 1, c["block"], 128)))
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree_util.tree_leaves(params))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
+
+    by_kind = lambda x: {k: x for k in cfg.cache_kinds}
+    S, per_slot = c["slots"], c["max_len"] // c["block"]
+    if kind == "decode":
+        def fn(params, cache, tokens, tables, lengths, ids, offs):
+            return model.forward(
+                params, tokens[:, None], kv_cache=cache,
+                block_tables=tables, lengths=lengths, append_block_ids=ids,
+                append_offsets=offs)
+        args = (params, cache, i32(S), by_kind(i32(S, per_slot)), i32(S),
+                by_kind(i32(S)), i32(S))
+    else:
+        bucket = int(kind[len("prefill"):])
+
+        def fn(params, cache, tokens, block_row, prompt_len):
+            return model.forward(
+                params, tokens, kv_cache=cache, block_row=block_row,
+                prompt_len=prompt_len, last_logit_only=True)
+        args = (params, cache, i32(1, bucket),
+                by_kind(i32(bucket // c["block"])), i32())
+    return fn, args, cache, weight_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill1024", "prefill8192"])
+def test_pattern_decoder_programs_compile_for_v5e(kind, one_chip, for_tpu,
+                                                  monkeypatch):
+    """The window kernels, the grouped-KV paged kernel and the expert
+    product pass Mosaic at published widths; the pools are updated where
+    they lie; weights + pools + temporaries fit the chip's 16 GB; and the
+    stacked expert weights are read in place, not sliced a layer (a copy
+    of one kind's gate matrices alone would be 2 GB)."""
+    ep = importlib.import_module("apex_tpu.transformer.expert_parallel")
+    monkeypatch.setattr(ep, "_interp", lambda: False)
+    fn, args, cache, weight_bytes = _pattern_step(kind, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in text
+    for name in ("moe_experts_gate_up", "moe_experts_down",
+                 "paged_decode_attention" if kind == "decode"
+                 else "flash_attention_window"):
+        assert name in text, name
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree_util.tree_leaves(cache))
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert 8.4e9 < weight_bytes < 8.6e9
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert total < 15.5e9, (total, memory.temp_size_in_bytes)
+    # a decode step and a short prefill keep their temporaries far under
+    # one layer kind's expert stack
+    if kind != "prefill8192":
+        assert memory.temp_size_in_bytes < 1.0e9, memory.temp_size_in_bytes

@@ -224,6 +224,19 @@ def test_a_speculative_dense_engine_has_the_same_cut(model_params):
         "engine.decode", "decode.plan", "decode.dispatch", "decode.wait"}
 
 
+def test_an_engine_with_buckets_names_the_bucket_and_its_tokens(
+        model_params):
+    model, params = model_params
+    engine = PagedServingEngine(model, params, max_seqs=2, max_len=24,
+                                prefill_len=[4, 8], num_blocks=16,
+                                block_size=4, cache_dtype=jnp.float32)
+    with trace.span_recording():
+        serve(engine)
+        spans = trace.drain_spans()
+    assert sorted((s.ids["bucket"], s.ids["tokens"]) for s in spans
+                  if s.name == "prefill.dispatch") == [(4, 3), (8, 6)]
+
+
 def test_an_unknown_name_is_an_error_only_while_recording():
     with trace.span("no.such.span"):
         pass
