@@ -213,6 +213,55 @@ class GPTModel:
                          "bias": jnp.zeros(cfg.hidden_size, cfg.params_dtype)},
         }
 
+    def serving_params(self, params: dict) -> dict:
+        """The image of ``params`` the KV-cached passes (:meth:`forward`
+        with a cache, :meth:`verify_forward`) are meant to be handed:
+        the four matrices of every layer already in
+        ``cfg.compute_dtype``, so a serving program reads them as they
+        lie and does not round 2 x their size on every run (the MXU
+        takes them in that dtype whatever they are stored in). The tied
+        head, which wants the word table in the compute dtype, gets its
+        own copy of it as ``["head"]["weight"]``; the word and position
+        tables keep their dtype, because the lookup adds their rows in
+        it and rounds the SUM. Both copies of the word table are
+        ``(vocab, hidden)``, the shape the gather and the head's product
+        take: the tensor axis (1 on this path) would cost the lookup a
+        copy of the table into another layout every run.
+
+        The biases and the norms' gains and biases stay as stored. They
+        are a thousandth of the bytes, and the TPU compiler, which may
+        keep excess precision, applies them unrounded where the code
+        says ``.astype(x.dtype)``: stored in the compute dtype they
+        moved every logit of gpt2-large on the chip (by up to 0.012; the
+        matrices alone move no decode logit by a bit).
+
+        ``params`` whose matrices are in the compute dtype already, and
+        an image, come back as they are; a serving engine makes the
+        image at build and at every swap. Not for training: the
+        optimizer's master weights are ``params``.
+        """
+        self._require_cacheable()
+        dtype = self.cfg.compute_dtype
+        layers = {name: dict(leaves)
+                  for name, leaves in params["layers"].items()}
+        for name in ("qkv", "proj", "fc1", "fc2"):
+            layers[name]["weight"] = layers[name]["weight"].astype(dtype)
+        image = dict(params, layers=layers)
+        word = params["embedding"]["word"]["weight"]
+        if "head" not in params and word.dtype != dtype:
+            image["embedding"] = dict(params["embedding"],
+                                      word={"weight": word[0]})
+            image["head"] = {"weight": word[0].astype(dtype)}
+        return image
+
+    def _word_rows(self, params: dict, tokens: jnp.ndarray) -> jnp.ndarray:
+        """Rows of the word table for the cached passes: a serving image
+        stores it ``(vocab, hidden)`` (:meth:`serving_params`)."""
+        word = params["embedding"]["word"]
+        if word["weight"].ndim == 2:
+            return jnp.take(word["weight"], tokens, axis=0)
+        return self.embedding(word, tokens)
+
     def param_specs(self, params: dict):
         """``PartitionSpec`` tree for a :meth:`init` params pytree under
         the standard TP layout (vocab-sharded embedding, per-layer TP
@@ -326,7 +375,7 @@ class GPTModel:
     def embed(self, params: dict, tokens: jnp.ndarray,
               dropout_rng: Optional[jax.Array] = None) -> jnp.ndarray:
         cfg = self.cfg
-        h = self.embedding(params["embedding"]["word"], tokens)
+        h = self._word_rows(params, tokens)
         pos = params["embedding"]["position"][: tokens.shape[1]]
         h = (h + pos).astype(cfg.compute_dtype)
         if cfg.sequence_parallel:
@@ -418,12 +467,18 @@ class GPTModel:
     def logits(self, params: dict, x: jnp.ndarray) -> jnp.ndarray:
         """Tied output embedding (standalone_gpt.py parallel_lm_logits):
         returns vocab-parallel logits (local shard) when tp>1."""
-        w = _local_shard(params["embedding"]["word"]["weight"],
-                         self.cfg.tensor_model_parallel_size)
-        if self.cfg.tensor_model_parallel_size == 1:
-            from apex_tpu.utils.vma import restore_invariant
-            from apex_tpu.transformer.parallel_state import TENSOR_AXIS
-            w = restore_invariant(w, TENSOR_AXIS)
+        if "head" in params:
+            # a serving image brings the head's own (vocab, hidden) copy of
+            # the word table in the compute dtype (serving_params); a
+            # trainer's tree never does
+            w = params["head"]["weight"]
+        else:
+            w = _local_shard(params["embedding"]["word"]["weight"],
+                             self.cfg.tensor_model_parallel_size)
+            if self.cfg.tensor_model_parallel_size == 1:
+                from apex_tpu.utils.vma import restore_invariant
+                from apex_tpu.transformer.parallel_state import TENSOR_AXIS
+                w = restore_invariant(w, TENSOR_AXIS)
         return jax.lax.dot_general(
             x, w.astype(x.dtype), (((x.ndim - 1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -623,7 +678,7 @@ class GPTModel:
         if positions is None:
             positions = cache.lengths
         with jax.named_scope("gpt_embed"):
-            h = self.embedding(params["embedding"]["word"], tokens)
+            h = self._word_rows(params, tokens)
             pos = jnp.take(
                 params["embedding"]["position"],
                 jnp.clip(positions, 0, cfg.max_position_embeddings - 1),
@@ -736,7 +791,7 @@ class GPTModel:
             cache = cache.cow_copy(jnp.asarray(cow_src, jnp.int32),
                                    jnp.asarray(cow_dst, jnp.int32))
         with jax.named_scope("gpt_embed"):
-            h = self.embedding(params["embedding"]["word"], tokens)
+            h = self._word_rows(params, tokens)
             pos = jnp.take(
                 params["embedding"]["position"],
                 jnp.clip(lengths, 0, cfg.max_position_embeddings - 1),
@@ -777,7 +832,7 @@ class GPTModel:
         cfg = self.cfg
         Q = tokens.shape[1]
         with jax.named_scope("gpt_embed"):
-            h = self.embedding(params["embedding"]["word"], tokens)
+            h = self._word_rows(params, tokens)
             positions = lengths[:, None] + jnp.arange(Q)[None, :]
             pos = jnp.take(
                 params["embedding"]["position"],

@@ -90,6 +90,9 @@ SPANS: Dict[str, str] = {
     # construction
     "engine.build": "ServingEngine/PagedServingEngine.__init__ after the "
                     "argument checks",
+    "compile.image": "trace + lower + compile of the cast program that "
+                     "makes the weights' serving image (only where the "
+                     "image is not the handed tree)",
     "compile.prefill": "trace + lower + compile of the prefill program",
     "compile.decode": "trace + lower + compile of the decode program",
     "compile.verify": "trace + lower + compile of the verify program",

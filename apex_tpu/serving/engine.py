@@ -62,12 +62,19 @@ def _host(x, dtype=None) -> np.ndarray:
     return np.array(x, dtype)
 
 
+def _tree_bytes(tree) -> int:
+    return sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
 class ServingEngine:
     """See module docstring.
 
     Args:
       model: a :class:`~apex_tpu.models.gpt.GPTModel` (tp=1, no SP).
-      params: its :meth:`init` pytree.
+      params: its :meth:`init` pytree. The engine keeps the model's
+        serving image of it as :attr:`params` and not the tree itself
+        (:meth:`_hold_weights`).
       max_seqs: concurrent sequence slots (the decode batch width).
       max_len: per-slot cache capacity in tokens (<= the model's
         ``max_position_embeddings``).
@@ -122,7 +129,6 @@ class ServingEngine:
             raise ValueError(f"prefill_len {prefill_len} exceeds max_len "
                              f"{max_len}")
         self.model = model
-        self.params = params
         self.max_seqs = int(max_seqs)
         self.max_len = int(max_len)
         self.prefill_len = int(prefill_len)
@@ -138,7 +144,47 @@ class ServingEngine:
         self.last_finite: Optional[np.ndarray] = None
         self.swaps = 0
         with span("engine.build"):
-            self._build(model, params, cache_dtype, rng_seed)
+            self._build(model, self._hold_weights(params), cache_dtype,
+                        rng_seed)
+
+    def _hold_weights(self, params):
+        """Make and keep what the AOT programs take as their weights,
+        :attr:`params`: the MODEL's image of the handed tree
+        (``model.serving_params``: for a
+        :class:`~apex_tpu.models.gpt.GPTModel` with float32 weights the
+        layers' matrices in the compute dtype, the tables float32 for
+        the lookup and a compute-dtype copy of the word table for the
+        tied head), made by a cast program that is compiled here and run
+        again by every :meth:`swap_params`. The programs then read the
+        image as it lies; handed the float32 tree they would round all
+        of it on every run. Where the model has no such method, or its
+        image of ``params`` is ``params`` (every matrix in the dtype of
+        its use), the engine holds the very arrays it was handed.
+
+        The handed tree itself is NOT kept, only its shapes and dtypes
+        (:attr:`params_spec`: what a swap, or a checkpoint restored for
+        one, has to look like): whoever frees the engine's weights
+        (``del`` of the leaves of :attr:`params`) has freed all of them.
+        Returns :attr:`params`."""
+        self.params_spec = jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), params)
+        self._image_compiled = None
+        to_image = getattr(self.model, "serving_params", None)
+        if to_image is not None and \
+                jax.eval_shape(to_image, self.params_spec) != self.params_spec:
+            with span("compile.image"):
+                self._image_compiled = jax.jit(to_image).lower(
+                    self.params_spec).compile()
+        self.params = self._image(params)
+        self.weights_handed_bytes = _tree_bytes(self.params_spec)
+        self.weights_held_bytes = _tree_bytes(self.params)
+        return self.params
+
+    def _image(self, params):
+        """``params`` (of :attr:`params_spec`) as the programs take them."""
+        if self._image_compiled is None:
+            return params
+        return self._image_compiled(params)
 
     def _build(self, model, params, cache_dtype, rng_seed: int) -> None:
         """The cache and the AOT programs (``__init__`` past its checks)."""
@@ -456,33 +502,38 @@ class ServingEngine:
     def swap_params(self, new_params, *, relint: bool = True) -> None:
         """Swap the serving weights in place with ZERO recompiles.
 
-        The params are a plain (non-donated) array argument of all three
-        AOT programs, so replacing the pytree retargets every subsequent
-        prefill/decode/release dispatch at the new weights — no retrace,
-        no recompile, no cache reallocation (the compile-storm counters
-        stay flat; asserted under ``recompile_guard`` in
-        ``tests/test_resilience.py``). In-flight sequences keep their
-        OLD-weight KV prefix and extend it under the new weights — the
-        standard serve-while-train rollover semantics; drain first
+        The weights are a plain (non-donated) array argument of all the
+        AOT programs, so replacing :attr:`params` retargets every
+        subsequent prefill/decode/release dispatch at the new weights —
+        no retrace, no recompile, no cache reallocation (the
+        compile-storm counters stay flat; asserted under
+        ``recompile_guard`` in ``tests/test_resilience.py``). In-flight
+        sequences keep their OLD-weight KV prefix and extend it under
+        the new weights — the standard serve-while-train rollover
+        semantics; drain first
         (:meth:`~apex_tpu.serving.scheduler.SlotScheduler.drain`) for a
         clean generation boundary.
 
-        ``new_params`` must match the compiled programs' structure
-        exactly (same treedef, same leaf shapes/dtypes) — anything else
-        would retrace on next dispatch, which is exactly the compile
-        storm this method exists to avoid, so it is refused here at the
-        host boundary. ``relint=True`` re-runs the analysis engine's
-        donation/aliasing lint over the three compiled programs after
-        the swap (rule ``jaxpr-donation`` — the construction-time
-        self-check repeated at every rollover).
+        A swap takes what construction took: ``new_params`` must match
+        :attr:`params_spec` exactly (same treedef, same leaf
+        shapes/dtypes — the trainer's tree, not the image the programs
+        read). Anything else is refused here at the host boundary,
+        before a leaf is cast: it would retrace on next dispatch, which
+        is exactly the compile storm this method exists to avoid. The
+        image is then made by the cast program construction compiled
+        (:meth:`_hold_weights`), and ``new_params`` is not kept.
+        ``relint=True`` re-runs the analysis engine's donation/aliasing
+        lint over the compiled programs after the swap (rule
+        ``jaxpr-donation`` — the construction-time self-check repeated
+        at every rollover).
         """
-        old_leaves, old_def = jax.tree_util.tree_flatten(self.params)
+        old_leaves, old_def = jax.tree_util.tree_flatten(self.params_spec)
         new_leaves, new_def = jax.tree_util.tree_flatten(new_params)
         if old_def != new_def:
             raise ValueError(
                 "swap_params: new params tree structure differs from "
-                "the compiled programs' — a swap must never retrace "
-                f"(old {old_def}, new {new_def})")
+                "what the engine was built on — a swap must never "
+                f"retrace (old {old_def}, new {new_def})")
         converted = []
         for i, (o, n) in enumerate(zip(old_leaves, new_leaves)):
             # one device_put per leaf: validate on the converted array
@@ -491,10 +542,11 @@ class ServingEngine:
             if o.shape != n.shape or o.dtype != n.dtype:
                 raise ValueError(
                     f"swap_params: leaf {i} is {n.shape}/{n.dtype}, "
-                    f"compiled for {o.shape}/{o.dtype} — a swap must "
+                    f"built on {o.shape}/{o.dtype} — a swap must "
                     "never retrace")
             converted.append(n)
-        self.params = jax.tree_util.tree_unflatten(new_def, converted)
+        self.params = self._image(
+            jax.tree_util.tree_unflatten(new_def, converted))
         self.swaps += 1
         if relint:
             from apex_tpu.analysis.program import (lint_serving_engine,
@@ -548,9 +600,7 @@ class ServingEngine:
         exposes no memory analysis."""
         overhead = self.overhead_bytes()
         if overhead is None:
-            overhead = sum(
-                leaf.size * leaf.dtype.itemsize
-                for leaf in jax.tree_util.tree_leaves(self.params))
+            overhead = self.weights_held_bytes
         avail = int(hbm_bytes * (1.0 - reserve_fraction)) - overhead
         return max(0, avail // self.bytes_per_slot())
 
@@ -644,7 +694,6 @@ class PagedServingEngine(ServingEngine):
                 "cfg.cache_kinds and one number for any other; got "
                 f"{num_blocks!r}")
         self.model = model
-        self.params = params
         self.max_seqs = int(max_seqs)
         self.max_len = int(max_len)
         self.prefill_len = int(prefill_len)
@@ -672,7 +721,8 @@ class PagedServingEngine(ServingEngine):
         self.last_stats: Optional[np.ndarray] = None
         self.swaps = 0
         with span("engine.build"):
-            self._build(model, params, cache_dtype, rng_seed)
+            self._build(model, self._hold_weights(params), cache_dtype,
+                        rng_seed)
 
     def _build(self, model, params, cache_dtype, rng_seed: int) -> None:
         """The pool, its allocator and the AOT programs (``__init__``
@@ -1133,9 +1183,7 @@ class PagedServingEngine(ServingEngine):
             raise ValueError(f"mean_len must be positive, got {mean_len}")
         overhead = self.overhead_bytes()
         if overhead is None:
-            overhead = sum(
-                leaf.size * leaf.dtype.itemsize
-                for leaf in jax.tree_util.tree_leaves(self.params))
+            overhead = self.weights_held_bytes
         avail = int(hbm_bytes * (1.0 - reserve_fraction)) - overhead
         return max(0, avail // self.block_bytes())
 

@@ -135,8 +135,9 @@ class CheckpointWatcher:
 
     :meth:`poll` is cheap when nothing changed (one ``latest_step``
     directory listing); when a NEW committed step appears it restores
-    onto ``target`` (default: arrays shaped like the engine's params —
-    the params-only checkpoint a serving deployment publishes), applies
+    onto ``target`` (default: ``engine.params_spec``, the shapes and
+    dtypes of the tree the engine was built on — the params-only
+    checkpoint a serving deployment publishes), applies
     ``extract`` (for checkpoints whose state pytree nests the model
     params inside larger trainer state — pass the full-state ``target``
     and ``extract=lambda state: state[...]``), and calls
@@ -172,16 +173,13 @@ class CheckpointWatcher:
         changed (including: no checkpoint exists yet — a serving process
         may outrun its trainer's first save)."""
         from apex_tpu.checkpoint import latest_step, restore_checkpoint
-        import jax
 
         step = latest_step(self.run_dir)
         if step is None or (self.step is not None and step <= self.step):
             return None
         target = self.target
         if target is None:
-            target = jax.tree_util.tree_map(
-                lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype),
-                self.engine.params)
+            target = self.engine.params_spec
         state, _ = restore_checkpoint(self.run_dir, target, step=step)
         params = self.extract(state) if self.extract is not None else state
         self.engine.swap_params(params)

@@ -762,6 +762,12 @@ class SlotScheduler:
         self._reg.counter("serve/generated_tokens").inc(generated)
         self._reg.gauge("serve/queue_depth").set(len(self.queue))
         self._reg.gauge("serve/active_slots").set(len(self.active))
+        # the tree the engine was built on against the image of it that
+        # its programs read (equal where the image is the tree)
+        self._reg.gauge("serve/weights_handed_bytes").set(
+            self.engine.weights_handed_bytes)
+        self._reg.gauge("serve/weights_held_bytes").set(
+            self.engine.weights_held_bytes)
         alloc = getattr(self.engine, "allocator", None)
         if alloc is not None:
             self._reg.gauge("serve/pool_blocks_free").set(

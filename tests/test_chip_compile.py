@@ -154,7 +154,7 @@ LARGE = dict(layers=36, heads=20, head_dim=64, vocab=50257, positions=1024,
 HALF_GB = 0.5e9
 
 
-def _paged_step(kind, weights_dtype, sharding):
+def _paged_step(kind, weights, sharding):
     """``(fn, args, donated argument, pool bytes, weight bytes in bf16)``
     for one program over a donated gpt2-large ``PagedKVCache``."""
     from apex_tpu.models import GPTConfig, GPTModel
@@ -165,13 +165,21 @@ def _paged_step(kind, weights_dtype, sharding):
         num_layers=g["layers"], num_attention_heads=g["heads"],
         max_position_embeddings=g["positions"]))
 
-    def described(tree, dtype_of=lambda d: d):
+    def described(tree):
         return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, dtype_of(x.dtype),
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=sharding), tree)
 
-    params = described(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-                       lambda d: weights_dtype if d == F32 else d)
+    # "f32": the tree as the model stores it; "bf16": every leaf of it
+    # bf16; "image": what the engines hand their programs, the model's
+    # own image of the float32 tree (GPTModel.serving_params)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    if weights == "image":
+        params = jax.eval_shape(model.serving_params, params)
+    elif weights == "bf16":
+        params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+            lambda x: jnp.zeros(x.shape, BF16), params))
+    params = described(params)
     cache = described(jax.eval_shape(lambda: PagedKVCache.create(
         g["layers"], g["blocks"], g["heads"], g["block"], g["head_dim"])))
     n_weights = sum(x.size for x in jax.tree_util.tree_leaves(params))
@@ -220,45 +228,91 @@ _IN_PLACE = {"parameter", "get-tuple-element", "bitcast",
              "dynamic-update-slice", "scatter"}
 
 
-def _pool_sized_copies(text, sizes):
-    """Instructions of compiled ``text`` whose array result has one of
-    ``sizes`` elements and is neither the pool passed on nor an in-place
-    write of it (a fusion counts as what its root is)."""
-    roots, current, found = {}, None, []
+# ... and one of a weight's size only the weight itself, passed on
+_PASSED_ON = {"parameter", "get-tuple-element", "bitcast"}
+_CALLEE = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def _weight_sizes():
+    """Elements of each stacked matrix of gpt2-large's layers and of its
+    word table: what a cast or a copy of a weight would have."""
+    g = LARGE
+    h = g["heads"] * g["head_dim"]
+    return {g["layers"] * h * n for n in (h, 3 * h, 4 * h)} \
+        | {g["vocab"] * h}
+
+
+def _array_results(text):
+    """``(computation, operation, elements, line, is root)`` of every
+    instruction of compiled ``text`` with an array result."""
+    current = None
     for line in text.splitlines():
         if line.rstrip().endswith("{") and "=" not in line.split("(")[0]:
             current = line.split("(")[0].replace("ENTRY", "").strip(" %")
         m = _ARRAY_RESULT.match(line)
-        if not m:
-            continue
-        if m.group(1):
-            roots[current] = m.group(4)
-        count = 1
-        for dim in m.group(3).split(","):
-            count *= int(dim)
-        if count in sizes:
-            found.append((m.group(4), line.strip()))
+        if m:
+            count = 1
+            for dim in m.group(3).split(","):
+                count *= int(dim)
+            yield current, m.group(4), count, line.strip(), bool(m.group(1))
+
+
+def _pool_sized_copies(text, sizes):
+    """Instructions of compiled ``text`` whose array result has one of
+    ``sizes`` elements and is neither the pool passed on nor an in-place
+    write of it (a fusion counts as what its root is)."""
+    results = list(_array_results(text))
+    roots = {comp: op for comp, op, _, _, root in results if root}
     bad = []
-    for op, line in found:
+    for _, op, count, line, _ in results:
+        if count not in sizes:
+            continue
         if op == "fusion":
-            op = roots.get(re.search(r"calls=%?([\w.\-]+)", line).group(1))
+            op = roots.get(_CALLEE.search(line).group(1))
         if op not in _IN_PLACE:
+            bad.append(line[:200])
+    return bad
+
+
+def _weight_sized_results(text, sizes):
+    """Instructions of compiled ``text`` that WRITE an array of one of
+    ``sizes`` elements: not the weight passed on, and not what stands
+    inside a fusion (only the fusion's result reaches memory; it counts
+    as passed on where everything inside it is)."""
+    results = list(_array_results(text))
+    inside = {}
+    for comp, op, _, _, _ in results:
+        inside.setdefault(comp, set()).add(op)
+    fused = {_CALLEE.search(line).group(1)
+             for _, op, _, line, _ in results if op == "fusion"}
+    bad = []
+    for comp, op, count, line, _ in results:
+        if count not in sizes or comp in fused:
+            continue
+        ops = inside[_CALLEE.search(line).group(1)] if op == "fusion" \
+            else {op}
+        if not ops <= _PASSED_ON:
             bad.append(line[:200])
     return bad
 
 
 @pytest.mark.parametrize("kind,weights", [
     ("decode", "bf16"), ("decode", "f32"), ("verify", "bf16"),
-    ("prefill", "bf16"), ("cow_copy", "bf16")])
+    ("prefill", "bf16"), ("cow_copy", "bf16"), ("decode", "image"),
+    ("prefill", "image"), ("verify", "image")])
 def test_paged_step_updates_the_pool_in_place_on_v5e(kind, weights,
                                                      one_chip, for_tpu):
     """The serve cell's programs keep no second image of the pool: no
     temporary of its size (3 GB an array), none of a layer's slice of it
-    (84 MB), the donated pool aliased to the result. With the cell's
-    float32 weights XLA hoists their bf16 image (1.55 GB) out of the
-    layer loop — ROADMAP Speed item 6's, allowed for here by its size."""
+    (84 MB), the donated pool aliased to the result. Handed the MODEL's
+    float32 tree, XLA hoists its bf16 image (1.55 GB) out of the layer
+    loop and makes it anew every run: allowed for here by its size, as
+    what the model alone compiles to. Handed the ``image`` the engines
+    make of that tree once (``GPTModel.serving_params``), a program
+    casts and copies no weight: no result of a stacked matrix's or the
+    word table's size but the weight itself, passed on."""
     fn, args, donated, pool_bytes, weight_image = _paged_step(
-        kind, BF16 if weights == "bf16" else F32, one_chip)
+        kind, weights, one_chip)
     compiled = jax.jit(fn, donate_argnums=(donated,)).lower(*args).compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert ("tpu_custom_call" in text) == (kind != "cow_copy")
@@ -268,6 +322,8 @@ def test_paged_step_updates_the_pool_in_place_on_v5e(kind, weights,
     pool_elements = pool_bytes // 2 // 2           # K or V, bf16
     assert _pool_sized_copies(text, {
         pool_elements, pool_elements // LARGE["layers"]}) == []
+    if weights == "image":
+        assert _weight_sized_results(text, _weight_sizes()) == []
 
 
 # ---------------------------------------------------------------------------

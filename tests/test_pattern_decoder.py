@@ -101,6 +101,21 @@ def test_served_tokens_are_the_references_argmax(served):
     assert max(float(g.max()) for g in gaps) < 1e-4
 
 
+def test_the_engine_holds_the_weights_it_was_handed(served):
+    """Every leaf is stored in the dtype of its use and the model names no
+    serving image of its own: the programs take the handed arrays, and
+    the two weight gauges read the same."""
+    engine = served["engine"]
+    held = jax.tree_util.tree_leaves(engine.params)
+    handed = jax.tree_util.tree_leaves(served["w"])
+    assert len(held) == len(handed)
+    assert all(a is b for a, b in zip(held, handed))
+    assert engine._image_compiled is None
+    c = served["counters"]
+    assert c["serve/weights_held_bytes"] == c["serve/weights_handed_bytes"] \
+        == sum(leaf.nbytes for leaf in handed)
+
+
 def test_full_forward_logits_agree_with_the_reference(served):
     tokens = jnp.asarray(served["prompts"][3] + served["streams"][3])
     got = jax.jit(served["model"].__call__)(served["w"], tokens)

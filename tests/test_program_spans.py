@@ -22,8 +22,9 @@ from benchmark import trace_reduce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PREFIX = trace.SPAN_PREFIX
-BUILD = {"engine.build", "compile.prefill", "compile.decode",
-         "compile.verify", "compile.release", "engine.lint"}
+BUILD = {"engine.build", "compile.image", "compile.prefill",
+         "compile.decode", "compile.verify", "compile.release",
+         "engine.lint"}
 VERIFY = {"engine.verify", "verify.plan", "verify.dispatch", "verify.wait",
           "verify.advance"}
 # what three requests on two paged slots produce once the engine is built
@@ -177,7 +178,8 @@ def test_recording_gives_the_same_names_with_parents_and_ids(profiled,
     assert counters_again["serve/decode_steps"] \
         == counters["serve/decode_steps"]
     names = {s.name for s in spans}
-    assert names == SERVED | BUILD - {"compile.verify"}
+    # float32 stored, float32 compute: the weights' image is the tree
+    assert names == SERVED | BUILD - {"compile.verify", "compile.image"}
     in_profile = [n[len(PREFIX):] for n, _, _ in
                   trace_reduce.host_spans(reduced, PREFIX)]
     for name in SERVED:
@@ -218,9 +220,10 @@ def test_a_speculative_dense_engine_has_the_same_cut(model_params):
                               speculate_k=2)
         sched.run([Request(prompt=[1, 2, 3, 1, 2, 3], max_new_tokens=5)])
         names = {s.name for s in trace.drain_spans()}
-    # no allocator: no index, no advance; no plain decode step either
+    # no allocator: no index, no advance; no plain decode step either;
+    # no cast program (the weights' image is the tree)
     assert names == set(trace.SPANS) - {
-        "prefill.index", "decode.advance", "verify.advance",
+        "compile.image", "prefill.index", "decode.advance", "verify.advance",
         "engine.decode", "decode.plan", "decode.dispatch", "decode.wait"}
 
 
