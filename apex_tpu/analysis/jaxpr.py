@@ -60,12 +60,17 @@ def count_primitives(text: str, *names: str) -> dict:
     return {name: text.count(name) for name in names}
 
 
-def collective_census(text: str) -> dict:
+def collective_census(program) -> dict:
     """The collective census shared by the ring-decomposition and
-    DP-bucketing structural tests."""
-    return {"ppermute": text.count("ppermute"),
-            "all_gather": text.count("all_gather"),
-            "reduce_scatter": text.count("reduce_scatter")}
+    DP-bucketing structural tests. A (closed) jaxpr is counted by its
+    equations' primitives; jaxpr TEXT by substring, which also counts
+    whatever is merely NAMED for a collective (a ``custom_vjp_call`` of
+    ``all_gather_matmul`` prints its function's name)."""
+    names = ("ppermute", "all_gather", "reduce_scatter")
+    if isinstance(program, str):
+        return {name: program.count(name) for name in names}
+    found = [e.primitive.name for e in iter_eqns(jaxpr_of(program))]
+    return {name: found.count(name) for name in names}
 
 
 def iter_eqns(jaxpr):

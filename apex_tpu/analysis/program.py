@@ -474,7 +474,7 @@ def lint_trainer_step(trainer, state, tokens, targets, *,
 def lint_serving_engine(engine) -> List[Finding]:
     """Donation safety over the AOT serving programs (prefill / decode /
     release — plus ``verify`` on a speculative engine — all with the
-    donated cache) plus grad-sync collective placement on the decode
+    donated pool) plus grad-sync collective placement on the decode
     program (a serving step has no business reducing gradients at
     all)."""
     import jax
@@ -486,7 +486,7 @@ def lint_serving_engine(engine) -> List[Finding]:
     programs = [("prefill", engine.prefill_compiled),
                 ("decode", engine.decode_compiled),
                 ("release", engine.release_compiled)]
-    if getattr(engine, "verify_compiled", None) is not None:
+    if engine.verify_compiled is not None:
         programs.append(("verify", engine.verify_compiled))
     for name, compiled in programs:
         findings += check_donation(
@@ -494,7 +494,7 @@ def lint_serving_engine(engine) -> List[Finding]:
             label=f"ServingEngine.{name}")
     findings += check_collective_placement(
         engine.decode_traced, axes=None, label="ServingEngine.decode")
-    if getattr(engine, "verify_traced", None) is not None:
+    if engine.verify_traced is not None:
         findings += check_collective_placement(
             engine.verify_traced, axes=None, label="ServingEngine.verify")
     return findings

@@ -631,26 +631,21 @@ def _check_decode_configs(repo: str, bench_path: str, findings: list,
     """The serving legs: ``BENCH_DECODE_CONFIGS`` keys must be real
     engine-constructor parameters — bench.py builds the engine by
     ``**spec``, so an unknown key would TypeError only at bench runtime
-    (and a renamed engine knob would silently strand the leg). Legs
-    carrying block-pool keys validate against
-    ``PagedServingEngine.__init__``; dense legs (the speculative A/B)
-    against ``ServingEngine.__init__``. A leg that states
-    ``speculate_k`` must state it >= 1 — ``speculate_k=0`` would
-    silently bench the non-speculative path against itself."""
+    (and a renamed engine knob would silently strand the leg). A leg
+    that states ``speculate_k`` must state it >= 1 — ``speculate_k=0``
+    would silently bench the non-speculative path against itself."""
     engine_path = os.path.join(repo, PACKAGE, "serving", "engine.py")
     try:
-        paged_allowed = _class_init_params(engine_path,
-                                           "PagedServingEngine")
-        dense_allowed = _class_init_params(engine_path, "ServingEngine")
+        allowed = _class_init_params(engine_path, "ServingEngine")
         table = _literal_assign(bench_path, "BENCH_DECODE_CONFIGS")
     except (OSError, SyntaxError, ValueError) as e:
         findings.append(Finding("ast-bench-configs", "MISSING",
                                 "bench.py BENCH_DECODE_CONFIGS", str(e)))
         return
-    if paged_allowed is None or dense_allowed is None:
+    if allowed is None:
         findings.append(Finding(
             "ast-bench-configs", "MISSING", "serving/engine.py",
-            "no PagedServingEngine/ServingEngine.__init__ to validate "
+            "no ServingEngine.__init__ to validate "
             "BENCH_DECODE_CONFIGS against"))
         return
     if table is None:
@@ -667,14 +662,11 @@ def _check_decode_configs(repo: str, bench_path: str, findings: list,
                 f"expected a dict of engine kwargs, got "
                 f"{type(spec).__name__}"))
             continue
-        paged = bool(set(spec) - dense_allowed)
-        allowed = paged_allowed if paged else dense_allowed
-        engine = "PagedServingEngine" if paged else "ServingEngine"
         bad = [k for k in spec if k not in allowed]
         if bad:
             findings.append(Finding(
                 "ast-bench-configs", "UNKNOWN", where,
-                f"{bad} are not {engine}.__init__ parameters"))
+                f"{bad} are not ServingEngine.__init__ parameters"))
             continue
         sk = spec.get("speculate_k")
         if sk is not None and (not isinstance(sk, int) or sk < 1):
@@ -684,7 +676,7 @@ def _check_decode_configs(repo: str, bench_path: str, findings: list,
                 "static draft window >= 1 (0 benches the "
                 "non-speculative path against itself)"))
             continue
-        notes.append(f"ok       {where}: {len(spec)} keys ({engine})")
+        notes.append(f"ok       {where}: {len(spec)} keys")
 
 
 def _check_decode_slo(bench_path: str, findings: list, notes: list):

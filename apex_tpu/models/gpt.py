@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from apex_tpu.normalization import fused_layer_norm_affine
 from apex_tpu.ops.dropout import dropout
 from apex_tpu.remat import RematPolicy, tag as _remat_tag
-from apex_tpu.ops.flash_attention import (decode_attention, flash_attention,
+from apex_tpu.ops.flash_attention import (flash_attention,
                                           paged_decode_attention)
 from apex_tpu.ops.xentropy import softmax_cross_entropy_loss
 from apex_tpu.transformer import tensor_parallel as tp_mod
@@ -520,36 +520,10 @@ class GPTModel:
                 f"{cfg.tensor_model_parallel_size}, sequence_parallel="
                 f"{cfg.sequence_parallel}")
 
-    def _decode_layer(self, lp: dict, x: jnp.ndarray, layer_cache,
-                      lengths: jnp.ndarray):
-        """One layer of the decode step: ``x`` is ``(S, 1, hidden)`` (one
-        token per slot), ``layer_cache`` this layer's ``(ck, cv, ksc,
-        vsc)`` cache slices. Returns ``(x, (k_new, v_new))`` — the new
-        token's K/V ``(S, H, D)``, appended to the cache by the caller
-        AFTER the scan (the kernel merges the current token itself, so
-        the cache is read-only inside the layer stack)."""
-        cfg = self.cfg
-        h = self._ln(lp["ln1"], x)
-        with jax.named_scope("gpt_attention"):
-            qkv, _ = self.qkv(lp["qkv"], h)       # (S, 1, 3*hidden)
-            S = qkv.shape[0]
-            qkv = qkv.reshape(S, cfg.num_attention_heads, 3 * cfg.head_dim)
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)   # (S, H, D)
-            ck, cv, ksc, vsc = layer_cache
-            ctx = decode_attention(q, ck, cv, lengths, k_new=k_new,
-                                   v_new=v_new, k_scale=ksc, v_scale=vsc,
-                                   use_pallas=cfg.use_flash)
-            out, _ = self.proj(lp["proj"], ctx.reshape(S, 1, -1))
-        x = x + out
-        x = x + self._mlp(lp, self._ln(lp["ln2"], x))
-        return x, (k_new, v_new)
-
     def forward(self, params: dict, tokens: jnp.ndarray,
                 dropout_rng: Optional[jax.Array] = None,
-                kv_cache=None, positions: Optional[jnp.ndarray] = None,
-                slot=None, prompt_len=None,
+                kv_cache=None, prompt_len=None,
                 last_logit_only: bool = False,
-                active: Optional[jnp.ndarray] = None,
                 block_row: Optional[jnp.ndarray] = None,
                 block_tables: Optional[jnp.ndarray] = None,
                 lengths: Optional[jnp.ndarray] = None,
@@ -561,26 +535,30 @@ class GPTModel:
         """The cache-threading entry point (docs/SERVING.md).
 
         Without ``kv_cache`` this is :meth:`__call__`. With a
-        :class:`~apex_tpu.serving.cache.KVCache` it dispatches on ``slot``:
+        :class:`~apex_tpu.serving.cache.PagedKVCache` it runs one of two
+        legs against the block pool, picked by ``block_row``:
 
-        - **prefill** (``slot`` given): ``tokens`` is ``(1, P)`` — the
-          ordinary causal forward (same flash path, same layer scan as
-          training) that ALSO collects every layer's K/V and writes them
-          into cache slot ``slot``, cursor set to ``prompt_len``
-          (default ``P``; right-pad shorter prompts). Returns
-          ``(logits (1, P, vocab), new_cache)``.
-        - **decode** (no ``slot``): ``tokens`` is ``(max_seqs, 1)`` — one
-          token per slot, every slot stepping together under a fixed
-          shape. Attention runs the decode kernel over each slot's cached
-          prefix, the new K/V are appended at each slot's own cursor, and
-          cursors advance. ``positions`` (default: the cache cursors)
-          indexes the position embedding. Returns
-          ``(logits (max_seqs, vocab), new_cache)``.
-
-        ``active`` (decode only): ``(max_seqs,)`` bool — slots NOT in it
-        keep a frozen cursor (their garbage token lands at the same
-        position each step and the next prefill overwrites it), so free
-        slots never grow an attention prefix. Default: all advance.
+        - **prefill** (``block_row`` given): ``tokens`` is ``(1, P)``,
+          ``P`` a multiple of the pool's block size — the ordinary causal
+          forward (same flash path, same layer scan as training) that
+          ALSO collects every layer's K/V and writes them into the pool
+          blocks named by ``block_row`` (``(P // block_size,)`` int32,
+          null-padded). ``prompt_len`` (default ``P``; right-pad shorter
+          prompts) is the cursor the caller keeps. Returns ``(logits (1,
+          P, vocab), new_cache)``.
+        - **decode** (no ``block_row``): ``tokens`` is ``(max_seqs, 1)`` —
+          one token per slot, every slot stepping together under a fixed
+          shape. It first resolves any copy-on-write pairs
+          (``cow_src``/``cow_dst``, null pairs no-op), reads each slot's
+          context through ``block_tables``/``lengths`` with the bounded
+          paged kernel — HBM per step is O(actual context), not
+          O(max_len) — and appends the new token at
+          ``append_block_ids``/``append_offsets`` (host-computed; null
+          entries drop the write, which is how an inactive slot keeps a
+          frozen cursor). ``lengths`` also indexes the position
+          embedding. ``mean_context`` only prices the kernel's
+          CostEstimate for pyprof. Returns ``(logits (max_seqs, vocab),
+          new_cache)``.
 
         ``last_logit_only`` (prefill only): project the vocab head for
         JUST the position ``prompt_len - 1`` — logits come back
@@ -589,20 +567,6 @@ class GPTModel:
         the serving engine always sets this (parity tests use the
         default full logits).
 
-        With a :class:`~apex_tpu.serving.cache.PagedKVCache` the same
-        two legs run against the global block pool instead
-        (docs/SERVING.md "Paged serving"): **paged prefill** writes the
-        collected K/V into the pool blocks named by ``block_row``
-        (``(P // block_size,)`` int32, null-padded); **paged decode**
-        (``block_row=None``) first resolves any copy-on-write pairs
-        (``cow_src``/``cow_dst``, null pairs no-op), reads each slot's
-        context through ``block_tables``/``lengths`` with the bounded
-        paged kernel — HBM per step is O(actual context), not
-        O(max_len) — and appends the new token at
-        ``append_block_ids``/``append_offsets`` (host-computed; null
-        entries drop the write). ``mean_context`` only prices the
-        kernel's CostEstimate for pyprof.
-
         Both legs are inference-mode (no dropout) and are meant to be
         AOT-compiled with the cache donated — see
         :class:`apex_tpu.serving.engine.ServingEngine`.
@@ -610,109 +574,24 @@ class GPTModel:
         if kv_cache is None:
             return self(params, tokens, dropout_rng)
         self._require_cacheable()
-        # lazy: serving -> engine -> gpt would cycle at import time
-        from apex_tpu.serving.cache import PagedKVCache
-        if isinstance(kv_cache, PagedKVCache):
-            if block_row is not None:
-                return self._paged_prefill_forward(
-                    params, tokens, kv_cache, block_row, prompt_len,
-                    last_logit_only)
-            return self._paged_decode_forward(
-                params, tokens, kv_cache, block_tables, lengths,
-                append_block_ids, append_offsets, cow_src, cow_dst,
-                mean_context)
-        if slot is not None:
-            return self._prefill_forward(params, tokens, kv_cache, slot,
-                                         prompt_len, last_logit_only)
-        return self._decode_forward(params, tokens, kv_cache, positions,
-                                    active)
-
-    def _prefill_forward(self, params, tokens, cache, slot, prompt_len,
-                         last_logit_only=False):
-        cfg = self.cfg
-        b, P = tokens.shape
-        if b != 1:
-            raise ValueError(f"prefill is per-request: tokens must be "
-                             f"(1, P), got {tokens.shape}")
-        if P > cache.max_len:
-            raise ValueError(f"prompt window {P} exceeds cache max_len "
-                             f"{cache.max_len}")
-        if prompt_len is None:
-            prompt_len = P
-        elif isinstance(prompt_len, int):
-            # a cursor past the written window would make every later
-            # decode read stale cache — reject statically when we can
-            if not 0 < prompt_len <= P:
-                raise ValueError(f"prompt_len {prompt_len} outside the "
-                                 f"written window (1, {P}]")
-        else:
-            # traced (the AOT engine path): clamp for the same reason
-            prompt_len = jnp.clip(jnp.asarray(prompt_len, jnp.int32), 1,
-                                  P)
-        x = self.embed(params, tokens)
-
-        def body(x, lp):
-            return self._layer(lp, x, collect_kv=True)
-
-        x, (k_all, v_all) = scan_stable_vma(body, x, params["layers"],
-                                            unroll=cfg.layer_scan_unroll)
-        x = self._ln(params["final_ln"], x)
-        if last_logit_only:
-            # the head is per-position: gathering the hidden row BEFORE
-            # the vocab projection skips (P-1)/P of the prefill's
-            # largest matmul
-            x = jax.lax.dynamic_slice_in_dim(
-                x, jnp.asarray(prompt_len, jnp.int32) - 1, 1, axis=1)
-        logits = self.logits(params, x)
-        # ys stacked (L, 1, H, P, D) -> (L, H, P, D) for the slot write
-        cache = cache.write_prompt(k_all[:, 0], v_all[:, 0], slot,
-                                   prompt_len)
-        return logits, cache
-
-    def _decode_forward(self, params, tokens, cache, positions,
-                        active=None):
-        cfg = self.cfg
-        if tokens.ndim != 2 or tokens.shape[1] != 1:
-            raise ValueError(f"decode tokens must be (max_seqs, 1), got "
-                             f"{tokens.shape}")
-        if positions is None:
-            positions = cache.lengths
-        with jax.named_scope("gpt_embed"):
-            h = self._word_rows(params, tokens)
-            pos = jnp.take(
-                params["embedding"]["position"],
-                jnp.clip(positions, 0, cfg.max_position_embeddings - 1),
-                axis=0)[:, None]
-            x = (h + pos).astype(cfg.compute_dtype)
-
-        xs = (params["layers"], cache.k, cache.v)
-        if cache.quantized:
-            xs = xs + (cache.k_scale, cache.v_scale)
-
-        def body(x, lp_c):
-            lp, ck, cv = lp_c[:3]
-            ksc, vsc = (lp_c[3], lp_c[4]) if cache.quantized else (None,
-                                                                   None)
-            return self._decode_layer(lp, x, (ck, cv, ksc, vsc),
-                                      cache.lengths)
-
-        x, (k_new, v_new) = scan_stable_vma(body, x, xs,
-                                            unroll=cfg.layer_scan_unroll)
-        x = self._ln(params["final_ln"], x)
-        logits = self.logits(params, x)[:, 0]
-        # `active` (``(max_seqs,)`` bool): only those slots advance their
-        # cursor — free slots must not creep one garbage position per
-        # step (see KVCache.append)
-        return logits, cache.append(k_new, v_new, active)
+        if block_row is not None:
+            return self._paged_prefill_forward(
+                params, tokens, kv_cache, block_row, prompt_len,
+                last_logit_only)
+        return self._paged_decode_forward(
+            params, tokens, kv_cache, block_tables, lengths,
+            append_block_ids, append_offsets, cow_src, cow_dst,
+            mean_context)
 
     def _paged_decode_layer(self, lp: dict, x: jnp.ndarray, cache, layer,
                             block_tables: jnp.ndarray,
                             lengths: jnp.ndarray, block_ids: jnp.ndarray,
                             offsets: jnp.ndarray,
                             mean_context: Optional[float]):
-        """One layer of the paged decode step: like :meth:`_decode_layer`
-        but the context comes through each slot's block table, so only
-        ~ceil(cursor/block_size) pool blocks are streamed per slot. The
+        """One layer of the decode step: ``x`` is ``(S, 1, hidden)``, one
+        token per slot. The context comes through each slot's block
+        table, so only ~ceil(cursor/block_size) pool blocks are streamed
+        per slot. The
         kernel reads layer ``layer`` of the stacked pool where it lies;
         the layer then writes its own new K/V row there (after the read:
         the current token reaches attention through the merge)."""
@@ -851,40 +730,18 @@ class GPTModel:
                           3 * cfg.head_dim).transpose(0, 2, 1, 3)
         return jnp.split(qkv, 3, axis=-1), store_roundtrip
 
-    def _verify_layer(self, lp: dict, x: jnp.ndarray, layer_cache,
-                      lengths: jnp.ndarray):
-        """One layer of the dense VERIFY step: like :meth:`_decode_layer`
-        but ``x`` is ``(S, Q, hidden)`` — the last accepted token plus
-        the in-flight drafts — scored against the cached prefix in one
-        kernel pass; causality among the Q rows is the exact LSE merge
-        inside :func:`decode_attention`, fed the cache-dtype store+load
-        images so the numerics match Q sequential steps."""
-        cfg = self.cfg
-        h = self._ln(lp["ln1"], x)
-        with jax.named_scope("gpt_attention"):
-            (q, k_new, v_new), roundtrip = self._verify_qkv(lp, h)
-            ck, cv, ksc, vsc = layer_cache
-            quantized = ksc is not None
-            ctx = decode_attention(
-                q, ck, cv, lengths, k_new=k_new, v_new=v_new,
-                k_scale=ksc, v_scale=vsc, use_pallas=cfg.use_flash,
-                k_cast=roundtrip(k_new, ck.dtype, quantized),
-                v_cast=roundtrip(v_new, ck.dtype, quantized))
-            S, _, Q, _ = ctx.shape
-            out, _ = self.proj(lp["proj"],
-                               ctx.transpose(0, 2, 1, 3).reshape(S, Q, -1))
-        x = x + out
-        x = x + self._mlp(lp, self._ln(lp["ln2"], x))
-        return x, (k_new, v_new)
-
     def _paged_verify_layer(self, lp: dict, x: jnp.ndarray, cache, layer,
                             block_tables: jnp.ndarray,
                             lengths: jnp.ndarray, block_ids: jnp.ndarray,
                             offsets: jnp.ndarray,
                             mean_context: Optional[float]):
-        """One layer of the PAGED verify step: the bounded block-table
-        fetch of :meth:`_paged_decode_layer`, amortized over Q rows, and
-        the layer's write of the whole window (rejected rows land above
+        """One layer of the verify step: ``x`` is ``(S, Q, hidden)`` — the
+        last accepted token plus the in-flight drafts. The bounded
+        block-table fetch of :meth:`_paged_decode_layer` is amortized
+        over the Q rows; causality among them is the exact LSE merge
+        inside :func:`paged_decode_attention`, fed the cache-dtype
+        store+load images so the numerics match Q sequential steps; the
+        layer then writes the whole window (rejected rows land above
         the cursor, see ``PagedKVCache.append_k``)."""
         cfg = self.cfg
         h = self._ln(lp["ln1"], x)
@@ -906,71 +763,40 @@ class GPTModel:
         return x, cache
 
     def verify_forward(self, params: dict, tokens: jnp.ndarray, kv_cache,
-                       block_tables: Optional[jnp.ndarray] = None,
-                       lengths: Optional[jnp.ndarray] = None,
-                       append_block_ids: Optional[jnp.ndarray] = None,
-                       append_offsets: Optional[jnp.ndarray] = None,
+                       block_tables: jnp.ndarray, lengths: jnp.ndarray,
+                       append_block_ids: jnp.ndarray,
+                       append_offsets: jnp.ndarray,
                        cow_src: Optional[jnp.ndarray] = None,
                        cow_dst: Optional[jnp.ndarray] = None,
                        mean_context: Optional[float] = None):
         """Speculative verify: score ``tokens (max_seqs, Q)`` — each
         slot's last accepted token plus its ``Q - 1`` drafts — in ONE
         pass over the cached prefix. Returns ``(logits (S, Q, vocab),
-        new_kv, cache)``.
+        cache)``.
 
-        Dense caches read ``kv_cache.lengths`` and come back WITHOUT the
-        window appended, ``new_kv`` being ``(k_new, v_new) (L, S, H, Q,
-        D)``: the engine decides the accepted counts from the logits
-        first (they move the dense cursor) and then appends via
-        ``append_k``, all inside the same AOT program. The paged pool
-        takes the host table/cursor mirrors like the decode leg, resolves
-        its COW pairs first, and every layer writes its whole window at
-        ``append_block_ids``/``append_offsets`` ``(S, Q)`` as it goes
-        (the accepted counts move only the HOST cursor, so nothing waits
-        for them); ``new_kv`` is ``None``."""
+        The pool takes the host table/cursor mirrors like the decode
+        leg, resolves its COW pairs first, and every layer writes its
+        whole window at ``append_block_ids``/``append_offsets`` ``(S,
+        Q)`` as it goes (the accepted counts move only the HOST cursor,
+        so nothing waits for them)."""
         self._require_cacheable()
-        cfg = self.cfg
         if tokens.ndim != 2:
             raise ValueError(f"verify tokens must be (max_seqs, Q), got "
                              f"{tokens.shape}")
-        from apex_tpu.serving.cache import PagedKVCache
-        if isinstance(kv_cache, PagedKVCache):
-            if block_tables is None or lengths is None \
-                    or append_block_ids is None or append_offsets is None:
-                raise ValueError("paged verify needs block_tables, lengths, "
-                                 "append_block_ids and append_offsets")
-            lengths = jnp.asarray(lengths, jnp.int32)
-            block_ids = jnp.asarray(append_block_ids, jnp.int32)
-            offsets = jnp.asarray(append_offsets, jnp.int32)
-            # copy-on-write FIRST — same sequencing as the decode leg
-            if cow_src is not None:
-                kv_cache = kv_cache.cow_copy(
-                    jnp.asarray(cow_src, jnp.int32),
-                    jnp.asarray(cow_dst, jnp.int32))
-            x, kv_cache = self._scan_paged_layers(
-                self._paged_verify_layer, params,
-                self._verify_embed(params, tokens, lengths), kv_cache,
-                block_tables, lengths, block_ids, offsets, mean_context)
-            new_kv = None
-        else:
-            lengths = kv_cache.lengths
-            x = self._verify_embed(params, tokens, lengths)
-            xs = (params["layers"], kv_cache.k, kv_cache.v)
-            if kv_cache.quantized:
-                xs = xs + (kv_cache.k_scale, kv_cache.v_scale)
-
-            def body(x, lp_c):
-                lp, ck, cv = lp_c[:3]
-                ksc, vsc = (lp_c[3], lp_c[4]) if kv_cache.quantized else \
-                    (None, None)
-                return self._verify_layer(lp, x, (ck, cv, ksc, vsc),
-                                          lengths)
-
-            x, new_kv = scan_stable_vma(body, x, xs,
-                                        unroll=cfg.layer_scan_unroll)
+        lengths = jnp.asarray(lengths, jnp.int32)
+        block_ids = jnp.asarray(append_block_ids, jnp.int32)
+        offsets = jnp.asarray(append_offsets, jnp.int32)
+        # copy-on-write FIRST — same sequencing as the decode leg
+        if cow_src is not None:
+            kv_cache = kv_cache.cow_copy(
+                jnp.asarray(cow_src, jnp.int32),
+                jnp.asarray(cow_dst, jnp.int32))
+        x, kv_cache = self._scan_paged_layers(
+            self._paged_verify_layer, params,
+            self._verify_embed(params, tokens, lengths), kv_cache,
+            block_tables, lengths, block_ids, offsets, mean_context)
         x = self._ln(params["final_ln"], x)
-        logits = self.logits(params, x)            # (S, Q, vocab)
-        return logits, new_kv, kv_cache
+        return self.logits(params, x), kv_cache     # (S, Q, vocab)
 
     def sp_grad_sync(self, grads: dict) -> dict:
         """Megatron-LM allreduces the grads of ``sequence_parallel``-marked

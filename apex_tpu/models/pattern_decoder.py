@@ -17,10 +17,9 @@ KV pool and block table (:class:`~apex_tpu.serving.cache.KindPagedKVCache`)
 because a window layer gives back the blocks that fall out of its window
 and a full layer never does.
 
-The model answers the calls ``PagedServingEngine`` makes of ``GPTModel``
-(``cfg``, ``_require_cacheable``, ``forward`` for paged prefill and paged
-decode) and nothing else: no trainer, no loss, no dense cache, no
-speculative verify. Weights are held bfloat16; norm, router, softmax and
+The model answers the calls ``ServingEngine`` makes of ``GPTModel``
+(``cfg``, ``_require_cacheable``, ``forward`` for prefill and decode)
+and nothing else: no trainer, no loss, no speculative verify. Weights are held bfloat16; norm, router, softmax and
 logits are float32.
 
 The chip may hold a SHARE of every layer (``held_experts`` of the
@@ -290,8 +289,9 @@ class PatternDecoder:
         x, stats = self._finish(lp, big, li, x, h32, ctx, valid)
         return x, cache, stats
 
-    def _decode_layer(self, kind, lp, big, li, x, cache, tables, lengths,
-                      block_ids, offsets, valid, mean_context):
+    def _paged_decode_layer(self, kind, lp, big, li, x, cache, tables,
+                            lengths, block_ids, offsets, valid,
+                            mean_context):
         """One layer of the decode step: ``x`` ``(S, hidden)``, one token
         a slot at position ``lengths``."""
         cfg = self.cfg
@@ -398,7 +398,7 @@ class PatternDecoder:
         if not isinstance(kv_cache, KindPagedKVCache):
             raise ValueError(
                 "PatternDecoder is served from a KindPagedKVCache (one "
-                "pool a layer kind) by PagedServingEngine; got "
+                "pool a layer kind) by ServingEngine; got "
                 f"{type(kv_cache).__name__}")
         pools = dict(kv_cache.pools)
         if block_row is not None:
@@ -426,7 +426,7 @@ class PatternDecoder:
         valid = jnp.asarray(first) != NULL_BLOCK
         x = jnp.take(params["embedding"], tokens[:, 0], axis=0).astype(
             cfg.compute_dtype)
-        fn = lambda kind, lp, big, li, x, cache: self._decode_layer(
+        fn = lambda kind, lp, big, li, x, cache: self._paged_decode_layer(
             kind, lp, big, li, x, cache, block_tables, lengths,
             append_block_ids, append_offsets, valid, mean_context)
         x, pools, stats = self._run_layers(fn, params, x, pools)

@@ -60,8 +60,8 @@ SPANS: Dict[str, str] = {
     "sched.gauges": "the serve/* counters and gauges set at the end of "
                     "a step",
     # inside sched.admit
-    "engine.prefill": "prefill() of either engine [slot]",
-    "prefill.plan": "allocator.lookup/admit (paged), pad_prompt, the "
+    "engine.prefill": "prefill() of the engine [slot]",
+    "prefill.plan": "allocator.lookup/admit, pad_prompt, the "
                     "_host() marshalling, _next_key()",
     "prefill.dispatch": "the call of the prefill program (returns before "
                         "the device is done) [on an engine with several "
@@ -69,26 +69,26 @@ SPANS: Dict[str, str] = {
                         "the prompt's own, the rest padding]",
     "prefill.wait": "int(tok): the host blocks until the device has the "
                     "token",
-    "prefill.index": "allocator.register_prefix (paged)",
+    "prefill.index": "allocator.register_prefix",
     # inside sched.step (and inside engine.prefill on a prefix hit)
-    "engine.decode": "decode() of either engine [active: slots stepped]",
-    "decode.plan": "prepare_step, append_targets (paged), the _host() "
+    "engine.decode": "decode() of the engine [active: slots stepped]",
+    "decode.plan": "prepare_step, append_targets, the _host() "
                    "marshalling, _next_key()",
     "decode.dispatch": "the call of decode_compiled",
-    "decode.advance": "allocator.advance (paged), while the device runs",
+    "decode.advance": "allocator.advance, while the device runs",
     "decode.wait": "np.asarray(toks), and last_finite on a quarantine "
                    "engine: the host blocks until the device is done",
-    "engine.verify": "verify() of either engine [active]",
-    "verify.plan": "prepare_verify, verify_targets (paged), marshalling, "
+    "engine.verify": "verify() of the engine [active]",
+    "verify.plan": "prepare_verify, verify_targets, marshalling, "
                    "_next_key()",
     "verify.dispatch": "the call of verify_compiled",
     "verify.wait": "np.asarray of tokens and counts (and last_finite)",
-    "verify.advance": "allocator.advance_counts (paged)",
+    "verify.advance": "allocator.advance_counts",
     # inside sched.harvest (or sched.step, or a cancel/drain)
-    "engine.release": "release_slot: allocator.release (paged) + the "
+    "engine.release": "release_slot: allocator.release + the "
                       "release_compiled dispatch [slot]",
     # construction
-    "engine.build": "ServingEngine/PagedServingEngine.__init__ after the "
+    "engine.build": "ServingEngine.__init__ after the "
                     "argument checks",
     "compile.image": "trace + lower + compile of the cast program that "
                      "makes the weights' serving image (only where the "

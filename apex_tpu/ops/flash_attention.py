@@ -57,8 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 from apex_tpu.utils.backend import pallas_interpret as _interp
 
 __all__ = ["flash_attention", "mha_reference", "supports_flash",
-           "dropout_keep_mask", "decode_attention",
-           "paged_decode_attention"]
+           "dropout_keep_mask", "paged_decode_attention"]
 
 NEG_INF = -1e30
 
@@ -176,8 +175,8 @@ def mha_reference(q, k, v, bias=None, causal=False,
     ``kv_length``: the KV-cache oracle path — an int array ``(b,)`` giving
     the number of VALID cache entries per batch row; key positions at or
     beyond it are masked out (the ground truth for
-    :func:`decode_attention`, whose ``k``/``v`` are preallocated
-    ``max_len`` caches carrying garbage past the write cursor). Rows with
+    :func:`paged_decode_attention`, whose pool blocks carry garbage past
+    the write cursor). Rows with
     length 0 produce an exactly-zero output, matching the kernel.
 
     ``window`` (with ``causal``): row ``i`` reads column ``j`` only while
@@ -1058,7 +1057,7 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
 
 
 # ---------------------------------------------------------------------------
-# decode kernel — single-query attention over a preallocated KV cache
+# decode — single-query attention over the paged KV pool
 # ---------------------------------------------------------------------------
 #
 # The serving fast path (docs/SERVING.md). The training kernels above are
@@ -1066,143 +1065,13 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
 # regime — ONE query row per sequence against a long cached key stripe, a
 # memory-bound streaming reduction with no backward pass (the reference
 # ships a separate inference attention family, fmhalib /
-# fast_multihead_attn, for exactly this reason). This kernel:
-#
-# - grids ``(b*h, max_len/block_k)`` with the cache blocks innermost and
-#   streams the flash-LSE running ``(m, l, acc)`` in VMEM scratch across
-#   them (the same online-softmax recurrence as ``_fwd_kernel``, one query
-#   row wide — the row rides a padded sublane tile);
-# - masks by a per-sequence integer write cursor (``lengths``) held in
-#   SMEM, and SKIPS the compute of cache blocks entirely past the cursor
-#   (a sequence at position t prices O(t) MXU work). NOTE the grid — and
-#   therefore the pipelined HBM->VMEM block fetches — is still shaped by
-#   max_len HERE: this dense-cache kernel streams the full stripe and
-#   skips only the math, so its memory-bound cost is O(max_len) per slot
-#   per step. The paged kernel below (``paged_decode_attention``) bounds
-#   the fetches too — scalar-prefetched block tables whose index map
-#   clamps past the cursor, so Pallas elides the repeat DMAs and HBM
-#   traffic is O(actual context); dense engines keep this kernel, paged
-#   engines (docs/SERVING.md "Paged serving") take the bounded grid;
-# - optionally dequantizes an int8 cache blockwise in VMEM against
-#   per-(position, head) fp32 scales — the cache stays int8 in HBM, which
-#   is where a decode step's bytes actually go;
-# - returns the per-row logsumexp so the caller can fold in the CURRENT
-#   token's k/v with one exact two-way LSE merge (``_merge_current``) —
-#   the cache is read before the new token is appended, so the kernel
-#   never needs a variable-position write. Empty rows (length 0) return
-#   lse = -inf, the correct identity for that merge (the training
-#   kernel's +inf convention exists only for its backward).
-
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
-                   lse_ref, acc_ref, m_ref, l_ref, *, scale, block_k, n_kv):
-    bh, j = pl.program_id(0), pl.program_id(1)
-    length = len_ref[bh]
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # skip the COMPUTE of cache blocks past the write cursor (the
-    # pipeline still fetches them — see the section comment)
-    @pl.when(j * block_k < length)
-    def _():
-        q = q_ref[0].astype(jnp.float32)          # (q_len, d)
-        k = k_ref[0]                              # (block_k, d)
-        v = v_ref[0]
-        if ksc_ref is not None:
-            # int8 cache: dequantize blockwise in VMEM against the
-            # per-(position, head) scales — HBM only ever holds int8
-            k = k.astype(jnp.float32) * ksc_ref[0].T
-            v = v.astype(jnp.float32) * vsc_ref[0].T
-        s = jax.lax.dot_general(q, k.astype(jnp.float32),
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        col = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        s = jnp.where(col < length, s, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(col < length, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:] = m_new
-        pv = jax.lax.dot_general(p, v.astype(jnp.float32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv
-
-    @pl.when(j == n_kv - 1)
-    def _():
-        l = l_ref[:]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        # -inf (NOT the training kernels' +inf): the empty row must be
-        # the identity of the two-way merge with the current token
-        lse_ref[0] = jnp.where(l == 0.0, -jnp.inf,
-                               m_ref[:] + jnp.log(safe_l))
-
-
-def _decode_pallas(q3, k3, v3, lengths_bh, ksc, vsc, *, scale, block_k):
-    bh, T, d = k3.shape
-    # q3 is (bh, q_len, d): q_len == 1 is the classic decode step; the
-    # speculative verify path rides q_len == k drafts + 1 bonus row
-    # through the SAME kernel body (every reduction in it is already
-    # per-row) — only the block/scratch shapes widen. All q rows share
-    # one prefix mask (the drafts are NOT in the cache; causality among
-    # them is the caller's exact merge, _merge_drafts).
-    q_len = q3.shape[1]
-    n_kv = T // block_k
-    has_scale = ksc is not None
-
-    q_spec = pl.BlockSpec((1, q_len, d), lambda b, j: (b, 0, 0),
-                          memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0),
-                           memory_space=pltpu.VMEM)
-    # scales ride (bh, 1, T): a (1, block_k) block over (bh, T) breaks
-    # Mosaic's rule that a block's last two dims are (8, 128)-multiples
-    # or the array's own; the unit middle dim makes them (1, block_k)
-    # over (1, T)
-    sc_spec = pl.BlockSpec((1, 1, block_k), lambda b, j: (b, 0, j),
-                           memory_space=pltpu.VMEM)
-    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, kv_spec,
-                kv_spec]
-    args = [lengths_bh, q3, k3, v3]
-    if has_scale:
-        in_specs += [sc_spec, sc_spec]
-        args += [ksc[:, None, :], vsc[:, None, :]]
-
-    def kernel(*refs):
-        refs = list(refs)
-        len_ref, q_ref, k_ref, v_ref = refs[:4]
-        nxt = 4
-        ksc_ref = refs[nxt] if has_scale else None
-        vsc_ref = refs[nxt + 1] if has_scale else None
-        nxt += 2 * has_scale
-        o_ref, lse_ref, acc, m, l = refs[nxt:]
-        _decode_kernel(len_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref,
-                       o_ref, lse_ref, acc, m, l, scale=scale,
-                       block_k=block_k, n_kv=n_kv)
-
-    out_dtype = q3.dtype if q3.dtype != jnp.int8 else jnp.float32
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, n_kv),
-        in_specs=in_specs,
-        out_specs=(q_spec,
-                   pl.BlockSpec((1, q_len, 1), lambda b, j: (b, 0, 0),
-                                memory_space=pltpu.VMEM)),
-        out_shape=(jax.ShapeDtypeStruct((bh, q_len, d), out_dtype),
-                   jax.ShapeDtypeStruct((bh, q_len, 1), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((q_len, d), jnp.float32),
-                        pltpu.VMEM((q_len, 1), jnp.float32),
-                        pltpu.VMEM((q_len, 1), jnp.float32)],
-        interpret=_interp(),
-    )(*args)
-    return out, lse
-
+# fast_multihead_attn, for exactly this reason). The cache is read BEFORE
+# the new token is appended: the kernel returns the per-row logsumexp and
+# the caller folds the CURRENT token's k/v in with one exact two-way LSE
+# merge (``_merge_current``; ``_merge_drafts`` for a verify window), so
+# the kernel never needs a variable-position write. Empty rows (length 0)
+# return lse = -inf, the correct identity for that merge (the training
+# kernel's +inf convention exists only for its backward).
 
 def _dequant(x, scale):
     """int8 cache block -> fp32 against per-(position, head) scales
@@ -1265,167 +1134,74 @@ def _merge_drafts(out, lse, q, k_new, v_new, k_cast, v_cast, scale,
     return (merged / denom[..., None]).astype(out_dtype)
 
 
-def decode_attention(q, k, v, lengths, k_new=None, v_new=None,
-                     k_scale=None, v_scale=None,
-                     softmax_scale: Optional[float] = None,
-                     block_k: Optional[int] = None,
-                     use_pallas: Optional[bool] = None,
-                     k_cast=None, v_cast=None):
-    """Single-query attention over a preallocated KV cache — the serving
-    decode kernel (see the section comment above).
-
-    Speculative verify: pass ``q`` as ``(b, h, q_len, d)`` (with matching
-    rank-4 ``k_new``/``v_new``) to score q_len in-flight tokens per slot
-    in ONE cache pass — the kernel prices the cached prefix once for all
-    rows, and causality among the in-flight tokens is an exact LSE merge
-    (``_merge_drafts``). ``k_cast``/``v_cast`` optionally carry the
-    cache-dtype store+load images of ``k_new``/``v_new`` so cross-draft
-    attention reproduces sequential decode's numerics bit-for-bit
-    (default: the fresh values). The return is ``(b, h, q_len, d)``.
-
-    Args:
-      q: ``(b, h, d)`` — one query row per sequence slot — or
-        ``(b, h, q_len, d)`` for the verify path.
-      k, v: ``(b, h, max_len, d)`` preallocated caches (bf16/fp32, or int8
-        with ``k_scale``/``v_scale``). Entries at or past ``lengths`` are
-        never read.
-      lengths: ``(b,)`` int — the per-slot write cursor: number of valid
-        cache positions (the already-written PREFIX; the current token is
-        NOT in the cache — pass it as ``k_new``/``v_new``).
-      k_new, v_new: optional ``(b, h, d)`` — the current token's key/value,
-        folded in by an exact two-way LSE merge. With an empty prefix the
-        result is exactly ``v_new`` (softmax over one position).
-      k_scale, v_scale: ``(b, h, max_len)`` fp32 per-(position, head)
-        dequantization scales, required iff the cache dtype is int8.
-      block_k: cache streaming block (default: largest of 512/256/128
-        dividing ``max_len``).
-
-    Returns ``(b, h, d)`` in ``q.dtype``. Rows whose prefix is empty AND
-    have no ``k_new`` are exactly zero.
-
-    Falls back to the XLA reference (:func:`mha_reference` with its
-    ``kv_length`` oracle path) when the cache isn't tile-aligned.
-    """
+def _gathered_reference(q, k, v, lengths, k_new, v_new, k_scale, v_scale,
+                        softmax_scale, k_cast, v_cast):
+    """The paged kernel's XLA oracle over a slot-major gather of the
+    pool: ``q`` ``(b, h, d)`` or ``(b, h, q_len, d)``, ``k``/``v`` ``(b,
+    h, T, d)`` (int8 with ``k_scale``/``v_scale`` ``(b, h, T)``), one
+    masked score pass over the positions below ``lengths`` and the same
+    merge of the in-flight tokens as the kernel's caller makes (and the
+    same math as :func:`mha_reference`'s ``kv_length`` path — the parity
+    tests pin all three together)."""
     multi = q.ndim == 4
-    if multi:
-        b, h, q_len, d = q.shape
-    else:
-        b, h, d = q.shape
-        q_len = 1
     T = k.shape[2]
-    if k.shape != (b, h, T, d) or v.shape != (b, h, T, d):
-        raise ValueError(f"cache shapes {k.shape}/{v.shape} do not match "
-                         f"q {q.shape} with max_len {T}")
     quantized = k.dtype == jnp.int8
-    if quantized and (k_scale is None or v_scale is None):
-        raise ValueError("int8 caches need k_scale/v_scale")
-    if softmax_scale is None:
-        softmax_scale = 1.0 / math.sqrt(d)
-    if block_k is None:
-        block_k = _auto_block(T) or 128
-    if use_pallas is None:
-        use_pallas = supports_flash(1, T, d, 1, block_k)
-    elif use_pallas and not supports_flash(1, T, d, 1, block_k):
-        # a forced kernel on a misaligned cache would silently drop the
-        # T % block_k tail (or never write the output at T < block_k) —
-        # refuse instead of decoding garbage
-        raise ValueError(
-            f"use_pallas=True but cache max_len {T} / head_dim {d} are "
-            f"not tile-aligned for block_k={block_k}; pass a dividing "
-            "block_k or let use_pallas auto-select the XLA fallback")
-    lengths = jnp.asarray(lengths).astype(jnp.int32)
-
-    with jax.named_scope("decode_attention"):
-        if multi:
-            # verify path: q_len rows per slot, ONE pass over the cached
-            # prefix (the mask is the same for every row — none of the
-            # in-flight tokens are in the cache), then the causal merge
-            if use_pallas:
-                q3 = q.reshape(b * h, q_len, d)
-                k3 = k.reshape(b * h, T, d)
-                v3 = v.reshape(b * h, T, d)
-                lengths_bh = jnp.repeat(lengths, h)
-                ksc = k_scale.reshape(b * h, T) if quantized else None
-                vsc = v_scale.reshape(b * h, T) if quantized else None
-                out3, lse3 = _decode_pallas(q3, k3, v3, lengths_bh, ksc,
-                                            vsc,
-                                            scale=float(softmax_scale),
-                                            block_k=block_k)
-                out = out3.reshape(b, h, q_len, d)
-                lse = lse3.reshape(b, h, q_len)
-            else:
-                kd = _dequant(k, k_scale) if quantized else k
-                vd = _dequant(v, v_scale) if quantized else v
-                s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                               kd.astype(jnp.float32)) * softmax_scale
-                col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, T), 3)
-                valid = col < lengths[:, None, None, None]
-                s = jnp.where(valid, s, NEG_INF)
-                m = jnp.max(s, axis=-1, keepdims=True)
-                p = jnp.where(valid, jnp.exp(s - m), 0.0)
-                l = jnp.sum(p, axis=-1, keepdims=True)
-                safe_l = jnp.where(l == 0.0, 1.0, l)
-                out = jnp.einsum("bhqk,bhkd->bhqd", p / safe_l,
-                                 vd.astype(jnp.float32))
-                lse = jnp.where(lengths[:, None, None] == 0, -jnp.inf,
-                                (m + jnp.log(safe_l))[..., 0])
-            if k_new is not None:
-                out = _merge_drafts(
-                    out, lse, q, k_new, v_new,
-                    k_new if k_cast is None else k_cast,
-                    v_new if v_cast is None else v_cast,
-                    float(softmax_scale), q.dtype)
-            return out.astype(q.dtype)
-        if use_pallas:
-            q3 = q.reshape(b * h, 1, d)
-            k3 = k.reshape(b * h, T, d)
-            v3 = v.reshape(b * h, T, d)
-            # per-slot cursor fanned out per head for the SMEM lookup
-            lengths_bh = jnp.repeat(lengths, h)
-            ksc = k_scale.reshape(b * h, T) if quantized else None
-            vsc = v_scale.reshape(b * h, T) if quantized else None
-            out3, lse3 = _decode_pallas(q3, k3, v3, lengths_bh, ksc, vsc,
-                                        scale=float(softmax_scale),
-                                        block_k=block_k)
-            out = out3.reshape(b, h, d)
-            lse = lse3.reshape(b, h)
-        else:
-            # XLA fallback, same math as the kernel (and as
-            # mha_reference's kv_length oracle — the parity tests pin all
-            # three together): ONE masked score pass feeds both the
-            # output and the lse the merge needs
-            kd = _dequant(k, k_scale) if quantized else k
-            vd = _dequant(v, v_scale) if quantized else v
-            s = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32),
-                           kd.astype(jnp.float32)) * softmax_scale
-            col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
-            valid = col < lengths[:, None, None]
-            s = jnp.where(valid, s, NEG_INF)
-            m = jnp.max(s, axis=-1, keepdims=True)
-            # fully-masked rows have m == NEG_INF and exp(s - m) == 1 on
-            # every entry — zero them explicitly (the kernels' rule)
-            p = jnp.where(valid, jnp.exp(s - m), 0.0)
-            l = jnp.sum(p, axis=-1, keepdims=True)
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            out = jnp.einsum("bhk,bhkd->bhd", p / safe_l,
-                             vd.astype(jnp.float32))
-            lse = jnp.where(lengths[:, None] == 0, -jnp.inf,
-                            (m + jnp.log(safe_l))[..., 0])
+    kd = _dequant(k, k_scale) if quantized else k
+    vd = _dequant(v, v_scale) if quantized else v
+    if multi:
+        # verify path: q_len rows per slot, ONE pass over the cached
+        # prefix (the mask is the same for every row — none of the
+        # in-flight tokens are in the cache), then the causal merge
+        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                       kd.astype(jnp.float32)) * softmax_scale
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, T), 3)
+        valid = col < lengths[:, None, None, None]
+        s = jnp.where(valid, s, NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.where(valid, jnp.exp(s - m), 0.0)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        safe_l = jnp.where(l == 0.0, 1.0, l)
+        out = jnp.einsum("bhqk,bhkd->bhqd", p / safe_l,
+                         vd.astype(jnp.float32))
+        lse = jnp.where(lengths[:, None, None] == 0, -jnp.inf,
+                        (m + jnp.log(safe_l))[..., 0])
         if k_new is not None:
-            out = _merge_current(out, lse, q, k_new, v_new,
-                                 float(softmax_scale), q.dtype)
+            out = _merge_drafts(
+                out, lse, q, k_new, v_new,
+                k_new if k_cast is None else k_cast,
+                v_new if v_cast is None else v_cast,
+                float(softmax_scale), q.dtype)
         return out.astype(q.dtype)
+    s = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32),
+                   kd.astype(jnp.float32)) * softmax_scale
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
+    valid = col < lengths[:, None, None]
+    s = jnp.where(valid, s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    # fully-masked rows have m == NEG_INF and exp(s - m) == 1 on
+    # every entry — zero them explicitly (the kernels' rule)
+    p = jnp.where(valid, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    safe_l = jnp.where(l == 0.0, 1.0, l)
+    out = jnp.einsum("bhk,bhkd->bhd", p / safe_l,
+                     vd.astype(jnp.float32))
+    lse = jnp.where(lengths[:, None] == 0, -jnp.inf,
+                    (m + jnp.log(safe_l))[..., 0])
+    if k_new is not None:
+        out = _merge_current(out, lse, q, k_new, v_new,
+                             float(softmax_scale), q.dtype)
+    return out.astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
 # paged decode kernel — bounded-grid attention over a block-pool KV cache
 # ---------------------------------------------------------------------------
 #
-# The v2 serving kernel (docs/SERVING.md "Paged serving"): vLLM-style
-# PagedAttention (Kwon et al.) brought to Pallas. The dense kernel above
-# streams a per-slot ``(max_len, d)`` stripe and only SKIPS the compute
-# past the cursor — its pipelined HBM fetches stay O(max_len). Here the
-# cache is the engine's whole block pool, taken AS IT IS STORED —
+# The serving kernel (docs/SERVING.md "Paged serving"): vLLM-style
+# PagedAttention (Kwon et al.) brought to Pallas. A kernel that streams a
+# per-slot ``(max_len, d)`` stripe can only SKIP the compute past the
+# cursor — its pipelined HBM fetches stay O(max_len). Here the cache is
+# the engine's whole block pool, taken AS IT IS STORED —
 # ``(layers, num_blocks, block_size, h * d)``, token-major, all layers
 # stacked — and each slot owns an int32 row of pool indices (its block
 # table), so:
@@ -1450,7 +1226,7 @@ def decode_attention(q, k, v, lengths, k_new=None, v_new=None,
 #   steps resolve to the SAME pool block and the Pallas pipeline elides
 #   the re-fetch (equal block index => no new DMA) — HBM traffic per slot
 #   per step is O(actual_context), not O(max_len). Compute past the
-#   cursor is skipped with the same ``@pl.when`` the dense kernel uses;
+#   cursor is skipped under a ``@pl.when``;
 # - all heads of a block are scored at once and no lane is ever sliced:
 #   the query comes in BLOCK-DIAGONAL, ``(h, h * d)`` with head g's row
 #   holding q[g] in lanes [g*d, (g+1)*d) and exact zeros elsewhere, so
@@ -1461,12 +1237,13 @@ def decode_attention(q, k, v, lengths, k_new=None, v_new=None,
 #   over EVERY head's values; the caller keeps the diagonal ``d``-wide
 #   pieces (a select, not a product: the cross-head pieces are masked
 #   before anything is summed);
-# - the online-softmax recurrence, the int8 blockwise dequant (the pooled
+# - the online-softmax recurrence is ``_fwd_kernel``'s, one query row
+#   wide; an int8 pool is dequantized blockwise (the pooled
 #   per-(position, head) scales ride ``(layers, num_blocks, h,
 #   block_size)`` and scale the score tile and the weights, which is the
-#   dequantized product reassociated), the -inf empty-row convention and
-#   the exact two-way ``_merge_current`` with the current token are the
-#   dense kernel's — the parity tests pin all of them to
+#   dequantized product reassociated) — HBM only ever holds int8, which
+#   is where a decode step's bytes go. The parity tests pin it, the
+#   -inf empty-row convention and the merge with the current token to
 #   ``mha_reference(kv_length=)``;
 # - ``mean_context`` (an expected-occupancy hint, tokens) sizes the
 #   ``pl.CostEstimate`` attached to the kernel so the pyprof roofline
@@ -1791,9 +1568,9 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                 out = _merge_current(out, lse, q, k_new, v_new,
                                      float(softmax_scale), q.dtype)
             return out.astype(q.dtype)
-        # XLA fallback: gather the layer's table-mapped blocks into the
-        # dense layout and run the dense fallback (one masked score pass
-        # + the same merge) — identical math, O(table span) traffic
+        # XLA fallback: gather the layer's table-mapped blocks slot-major
+        # and run one masked score pass + the same merge — identical
+        # math, O(table span) traffic
         T = block_tables.shape[1] * block_size
         if window is not None or hkv != h:
             # the oracle of the windowed / grouped form: the cached
@@ -1825,8 +1602,5 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                 return g.transpose(0, 2, 1, 3).reshape(b, h, T)
             ksc = gather_sc(k_scale)
             vsc = gather_sc(v_scale)
-        return decode_attention(q, kd, vd, lengths, k_new=k_new,
-                                v_new=v_new, k_scale=ksc, v_scale=vsc,
-                                softmax_scale=softmax_scale,
-                                use_pallas=False, k_cast=k_cast,
-                                v_cast=v_cast)
+        return _gathered_reference(q, kd, vd, lengths, k_new, v_new, ksc,
+                                   vsc, softmax_scale, k_cast, v_cast)

@@ -1,44 +1,27 @@
-"""KV cache: the fixed-layout pytree the serving fast path decodes from.
+"""The paged KV cache: a block pool on the device, an allocator on the host.
 
-One preallocated buffer pair per layer stack — ``k``/``v`` shaped
-``(num_layers, max_seqs, num_heads, max_len, head_dim)`` — plus a per-slot
-integer write cursor ``lengths``. The layout is chosen so that
+:class:`PagedKVCache` is ONE global ``(num_layers, num_blocks,
+block_size, H * D)`` block pool per K and V; which pool blocks a slot
+owns is host-side state in :class:`BlockAllocator` (per-slot int32 block
+tables + cursors, refcounts, a chained prefix-hash index for
+copy-on-write prompt sharing). The device pytree holds ONLY the pool
+(+ scales) — tables and cursors ride as plain array arguments of the
+AOT serving programs, so every program over it is FIXED SHAPE:
+admission, retirement, block growth, variable sequence lengths, prefix
+sharing and COW are all expressed through those arguments, never through
+array shapes, and nothing ever recompiles. Block index 0 is the
+allocator's reserved NULL block: unmapped table entries and masked
+writes land there, keeping every device program total. A pool of
+``max_seqs * ceil(max_len / block_size) + 1`` blocks holds every slot's
+whole ``max_len`` (the engine's default); a smaller one serves the same
+slots at the traffic's mean length.
 
-- the layer dim scans (``lax.scan`` over the GPT stack feeds each layer
-  its ``(S, H, T, D)`` slice, exactly like the stacked params);
-- each ``(slot, head)``'s positions are contiguous along ``T`` — the
-  stripe the decode kernel streams blockwise
-  (:func:`apex_tpu.ops.flash_attention.decode_attention`);
-- every program over it is FIXED SHAPE: admission, retirement and
-  variable sequence lengths are all expressed through the cursor, never
-  through array shapes, so the AOT-compiled decode step never recompiles.
-
-Writes are in-place-friendly by construction: :meth:`KVCache.append` is
-one batched ``dynamic_update_slice`` (a scatter over slots) appending one
-token to every slot at its own cursor, and :meth:`KVCache.write_prompt`
-is a single slot-indexed ``dynamic_update_slice`` — both alias their
-donated operands under ``jit`` (asserted in ``tests/test_serving.py``),
-so a decode step allocates nothing.
-
-``dtype=jnp.int8`` stores the cache quantized with per-(position, head)
+``dtype=jnp.int8`` stores the pool quantized with per-(position, head)
 fp32 scales (symmetric absmax over the head dim, quantized at write
 time — every token is quantized against its own range, so there is no
 prefill-vs-decode calibration order to get wrong). HBM cost per token
 drops 2x vs bf16 at ~6% scale overhead; the decode kernel dequantizes
 blockwise in VMEM.
-
-**Paged layout (v2, docs/SERVING.md "Paged serving")**: the dense
-``(L, S, H, max_len, D)`` reservation pins max_len HBM per slot for its
-whole lifetime. :class:`PagedKVCache` replaces it with a global
-``(L, num_blocks, block_size, H * D)`` block POOL; which pool blocks a
-slot owns is host-side state in :class:`BlockAllocator` (per-slot int32
-block tables + cursors, refcounts, a chained prefix-hash index for
-copy-on-write prompt sharing). The device pytree holds ONLY the pool
-(+ scales) — tables and cursors ride as plain array arguments of the
-AOT serving programs, so admission, retirement, block growth, prefix
-sharing and COW are all zero-recompile by construction. Block index 0
-is the allocator's reserved NULL block: unmapped table entries and
-masked writes land there, keeping every device program total.
 
 **Pools by layer kind**: a model built from a layer pattern
 (:mod:`apex_tpu.models.pattern_decoder`) keeps one pool and one block
@@ -83,7 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["KVCache", "cache_bytes_per_slot", "PagedKVCache",
+__all__ = ["cache_bytes_per_slot", "PagedKVCache",
            "KindPagedKVCache", "BlockAllocator", "KindBlockAllocator",
            "AdmitPlan", "StepPlan", "PoolExhausted", "paged_block_bytes",
            "store_roundtrip"]
@@ -117,245 +100,11 @@ def store_roundtrip(x: jnp.ndarray, cache_dtype,
     return x.astype(cache_dtype)
 
 
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
-class KVCache:
-    """See module docstring. Leaves: ``k``, ``v``, ``lengths`` (+
-    ``k_scale``/``v_scale`` when quantized)."""
-
-    k: jnp.ndarray                       # (L, S, H, T, D)
-    v: jnp.ndarray                       # (L, S, H, T, D)
-    lengths: jnp.ndarray                 # (S,) int32 write cursor
-    k_scale: Optional[jnp.ndarray] = None  # (L, S, H, T) fp32 iff int8
-    v_scale: Optional[jnp.ndarray] = None
-
-    # -- pytree protocol ----------------------------------------------------
-
-    def tree_flatten(self):
-        if self.quantized:
-            return ((self.k, self.v, self.lengths, self.k_scale,
-                     self.v_scale), True)
-        return ((self.k, self.v, self.lengths), False)
-
-    @classmethod
-    def tree_unflatten(cls, quantized, leaves):
-        if quantized:
-            return cls(*leaves)
-        k, v, lengths = leaves
-        return cls(k, v, lengths)
-
-    # -- shape/bookkeeping --------------------------------------------------
-
-    @property
-    def quantized(self) -> bool:
-        return self.k_scale is not None
-
-    @property
-    def num_layers(self) -> int:
-        return self.k.shape[0]
-
-    @property
-    def max_seqs(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def num_heads(self) -> int:
-        return self.k.shape[2]
-
-    @property
-    def max_len(self) -> int:
-        return self.k.shape[3]
-
-    @property
-    def head_dim(self) -> int:
-        return self.k.shape[4]
-
-    def nbytes(self) -> int:
-        """Total cache bytes (the number capacity planning divides)."""
-        return sum(leaf.size * leaf.dtype.itemsize
-                   for leaf in self.tree_flatten()[0])
-
-    # -- construction -------------------------------------------------------
-
-    @classmethod
-    def create(cls, num_layers: int, max_seqs: int, num_heads: int,
-               max_len: int, head_dim: int,
-               dtype=jnp.bfloat16) -> "KVCache":
-        """Zero-filled cache. ``dtype=jnp.int8`` enables the quantized
-        layout (scales allocated alongside)."""
-        shape = (num_layers, max_seqs, num_heads, max_len, head_dim)
-        k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
-        lengths = jnp.zeros((max_seqs,), jnp.int32)
-        if jnp.dtype(dtype) == jnp.int8:
-            # two DISTINCT buffers: a shared array would be donated twice
-            # by the AOT steps (XLA rejects duplicate donation)
-            return cls(k, v, lengths,
-                       jnp.full(shape[:-1], _MIN_SCALE, jnp.float32),
-                       jnp.full(shape[:-1], _MIN_SCALE, jnp.float32))
-        return cls(k, v, lengths)
-
-    # -- writes -------------------------------------------------------------
-
-    def _store(self, x: jnp.ndarray):
-        """(value-to-store, scale-or-None) in the cache dtype."""
-        if self.quantized:
-            return _quantize(x)
-        return x.astype(self.k.dtype), None
-
-    def append(self, k_new: jnp.ndarray, v_new: jnp.ndarray,
-               active: Optional[jnp.ndarray] = None) -> "KVCache":
-        """Append one token to EVERY slot at its own cursor:
-        ``k_new``/``v_new`` are ``(L, S, H, D)``. Only slots where
-        ``active`` (``(S,)`` bool, default all) advance their cursor —
-        an idle slot writes its garbage at a FROZEN cursor (overwritten
-        by the next prefill) instead of creeping one position per step,
-        which would otherwise grow every free slot's attention prefix
-        without bound. Slots already at ``max_len`` write NOTHING and
-        stay saturated: silently overwriting the last position (the v1
-        behavior) corrupted the newest KV entry of any sequence the
-        scheduler failed to retire in time — saturation is now loud at
-        the scheduler (retire-capacity before the step) and harmless
-        here (regression-tested in ``tests/test_serving.py``). One
-        batched dynamic_update_slice per array — in-place on donated
-        buffers."""
-        pos = jnp.minimum(self.lengths, self.max_len - 1)
-        # saturated slots must NOT overwrite position max_len-1: write
-        # back the value already there (a no-op update keeps the one
-        # batched in-place DUS shape the donation contract relies on)
-        writable = self.lengths < self.max_len
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
-
-        def upd(cache_s, new_s, p, w):
-            # per-slot: (L, H, T, D) <- (L, H, 1, D) at position p
-            old = jax.lax.dynamic_slice(cache_s, (0, 0, p, 0),
-                                        (L, H, 1, D))
-            return jax.lax.dynamic_update_slice(
-                cache_s, jnp.where(w, new_s[:, :, None, :], old),
-                (0, 0, p, 0))
-
-        kq, ks = self._store(k_new)
-        vq, vs = self._store(v_new)
-        k = jax.vmap(upd, in_axes=(1, 1, 0, 0), out_axes=1)(
-            self.k, kq, pos, writable)
-        v = jax.vmap(upd, in_axes=(1, 1, 0, 0), out_axes=1)(
-            self.v, vq, pos, writable)
-        advanced = jnp.minimum(self.lengths + 1, self.max_len)
-        if active is not None:
-            advanced = jnp.where(jnp.asarray(active, jnp.bool_),
-                                 advanced, self.lengths)
-        new = {"k": k, "v": v, "lengths": advanced}
-        if self.quantized:
-            def upd_sc(sc_s, new_s, p, w):
-                # per-slot: (L, H, T) <- (L, H, 1) at position p
-                old = jax.lax.dynamic_slice(sc_s, (0, 0, p), (L, H, 1))
-                return jax.lax.dynamic_update_slice(
-                    sc_s, jnp.where(w, new_s[:, :, None], old),
-                    (0, 0, p))
-
-            new["k_scale"] = jax.vmap(upd_sc, in_axes=(1, 1, 0, 0),
-                                      out_axes=1)(self.k_scale, ks, pos,
-                                                  writable)
-            new["v_scale"] = jax.vmap(upd_sc, in_axes=(1, 1, 0, 0),
-                                      out_axes=1)(self.v_scale, vs, pos,
-                                                  writable)
-        return dataclasses.replace(self, **new)
-
-    def append_k(self, k_new: jnp.ndarray, v_new: jnp.ndarray,
-                 counts: jnp.ndarray) -> "KVCache":
-        """Speculative verify append: write a WINDOW of up to ``K``
-        tokens per slot at its cursor in one batched DUS per array —
-        ``k_new``/``v_new`` are ``(L, S, H, K, D)`` (row i belongs at
-        position ``cursor + i``) and ``counts`` ``(S,)`` int32 is each
-        slot's cursor advance (accepted drafts + 1; 0 for
-        inactive/failed slots). Every row that FITS below ``max_len`` is
-        written — rows past the accepted count hold drafted-but-rejected
-        KV, which lands ABOVE the advanced cursor where no read ever
-        masks it in and the next step's window overwrites it. That is
-        the whole mid-verify rollback story: the cursor only ever moves
-        by the accepted count, so retiring a slot at ANY point (deadline,
-        poison) can never strand rejected entries below it (negative
-        test in ``tests/test_speculative.py``). Near saturation the
-        window clamps: rows that would land at or past ``max_len`` are
-        dropped and positions below the cursor are written back
-        unchanged; a slot AT ``max_len`` writes nothing."""
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
-        T = self.max_len
-        K = k_new.shape[3]
-        if K > T:
-            raise ValueError(f"verify window {K} exceeds max_len {T}")
-        start = jnp.minimum(self.lengths, T - K)
-        # >0 only near saturation: the window slid back so it fits, and
-        # row r of the new KV sits at window offset r + shift
-        shift = self.lengths - start
-        w = jnp.arange(K)
-
-        def upd(cache_s, new_s, st, sh):
-            # per-slot: (L, H, T, D) window <- (L, H, K, D) at st
-            old = jax.lax.dynamic_slice(cache_s, (0, 0, st, 0),
-                                        (L, H, K, D))
-            r = w - sh
-            rows = jnp.take(new_s, jnp.clip(r, 0, K - 1), axis=2)
-            vals = jnp.where((r >= 0)[None, None, :, None], rows, old)
-            return jax.lax.dynamic_update_slice(cache_s, vals,
-                                                (0, 0, st, 0))
-
-        kq, ks = self._store(k_new)
-        vq, vs = self._store(v_new)
-        k = jax.vmap(upd, in_axes=(1, 1, 0, 0), out_axes=1)(
-            self.k, kq, start, shift)
-        v = jax.vmap(upd, in_axes=(1, 1, 0, 0), out_axes=1)(
-            self.v, vq, start, shift)
-        advanced = jnp.minimum(
-            self.lengths + jnp.asarray(counts, jnp.int32), T)
-        new = {"k": k, "v": v, "lengths": advanced}
-        if self.quantized:
-            def upd_sc(sc_s, new_s, st, sh):
-                old = jax.lax.dynamic_slice(sc_s, (0, 0, st), (L, H, K))
-                r = w - sh
-                rows = jnp.take(new_s, jnp.clip(r, 0, K - 1), axis=2)
-                vals = jnp.where((r >= 0)[None, None, :], rows, old)
-                return jax.lax.dynamic_update_slice(sc_s, vals,
-                                                    (0, 0, st))
-
-            new["k_scale"] = jax.vmap(upd_sc, in_axes=(1, 1, 0, 0),
-                                      out_axes=1)(self.k_scale, ks,
-                                                  start, shift)
-            new["v_scale"] = jax.vmap(upd_sc, in_axes=(1, 1, 0, 0),
-                                      out_axes=1)(self.v_scale, vs,
-                                                  start, shift)
-        return dataclasses.replace(self, **new)
-
-    def write_prompt(self, k_new: jnp.ndarray, v_new: jnp.ndarray,
-                     slot, true_len) -> "KVCache":
-        """Prefill write: ``k_new``/``v_new`` are ``(L, H, P, D)`` for ONE
-        slot; positions ``[0, P)`` are overwritten and the slot's cursor
-        is set to ``true_len`` (<= P — right-padded prompts write their
-        padding too, but the cursor masks it from every future read and
-        the next appends overwrite it)."""
-        slot = jnp.asarray(slot, jnp.int32)
-        kq, ks = self._store(k_new)
-        vq, vs = self._store(v_new)
-        k = jax.lax.dynamic_update_slice(
-            self.k, kq[:, None], (0, slot, 0, 0, 0))
-        v = jax.lax.dynamic_update_slice(
-            self.v, vq[:, None], (0, slot, 0, 0, 0))
-        lengths = jax.lax.dynamic_update_slice(
-            self.lengths, jnp.asarray(true_len, jnp.int32)[None], (slot,))
-        new = {"k": k, "v": v, "lengths": lengths}
-        if self.quantized:
-            new["k_scale"] = jax.lax.dynamic_update_slice(
-                self.k_scale, ks[:, None], (0, slot, 0, 0))
-            new["v_scale"] = jax.lax.dynamic_update_slice(
-                self.v_scale, vs[:, None], (0, slot, 0, 0))
-        return dataclasses.replace(self, **new)
-
-
 def cache_bytes_per_slot(num_layers: int, num_heads: int, max_len: int,
                          head_dim: int, dtype=jnp.bfloat16) -> int:
-    """HBM bytes one sequence slot pins for its whole lifetime — the unit
-    of the capacity math in :func:`apex_tpu.serving.engine.suggest_max_seqs`
-    (k + v, plus the fp32 scales when int8)."""
+    """HBM bytes of ``max_len`` cached positions (k + v across all
+    layers, plus the fp32 scales when int8): what one slot's full-length
+    reservation pins, and under :func:`paged_block_bytes` one block."""
     per_pos = 2 * num_layers * num_heads * head_dim * jnp.dtype(dtype).itemsize
     if jnp.dtype(dtype) == jnp.int8:
         per_pos += 2 * num_layers * num_heads * 4
@@ -366,7 +115,7 @@ def paged_block_bytes(num_layers: int, num_heads: int, block_size: int,
                       head_dim: int, dtype=jnp.bfloat16) -> int:
     """HBM bytes of ONE pool block (k + v across all layers, plus the
     fp32 scales when int8) — the unit of the paged capacity math in
-    :meth:`apex_tpu.serving.engine.PagedServingEngine.suggest_pool_blocks`."""
+    :meth:`apex_tpu.serving.engine.ServingEngine.suggest_pool_blocks`."""
     return cache_bytes_per_slot(num_layers, num_heads, block_size,
                                 head_dim, dtype)
 
@@ -457,7 +206,8 @@ class PagedKVCache:
         k = jnp.zeros(shape, dtype)
         v = jnp.zeros(shape, dtype)
         if jnp.dtype(dtype) == jnp.int8:
-            # two DISTINCT scale buffers — see KVCache.create
+            # two DISTINCT buffers: a shared array would be donated twice
+            # by the AOT steps (XLA rejects duplicate donation)
             sc = (num_layers, num_blocks, num_heads, block_size)
             return cls(k, v, num_heads,
                        jnp.full(sc, _MIN_SCALE, jnp.float32),

@@ -1,40 +1,48 @@
-"""AOT-compiled prefill/decode steps with a donated KV cache.
+"""The serving engine: AOT-compiled prefill, decode and verify programs
+over a donated block pool.
 
-The engine owns the cache and the two compiled programs a serving
-process runs forever:
+The engine owns the pool (:class:`~apex_tpu.serving.cache.PagedKVCache`,
+or one pool a layer kind), its host-side allocator and the programs a
+serving process runs forever:
 
-- **prefill**: one request's padded prompt ``(1, prefill_len)`` through
-  the ordinary causal forward (the training flash path), K/V written
-  into one cache slot, the first output token sampled from the logits at
-  the prompt's true last position;
+- **prefill**: one request's padded prompt ``(1, bucket)`` through the
+  ordinary causal forward (the training flash path), K/V written into
+  the pool blocks the allocator mapped for the slot, the first output
+  token sampled from the logits at the prompt's true last position. One
+  program a prompt-length bucket;
 - **decode**: ONE token for EVERY slot ``(max_seqs, 1)`` through the
-  decode attention kernel, K/V appended at each slot's cursor, next
-  tokens sampled.
+  paged decode kernel, each slot's context read through its block
+  table, K/V appended at each slot's cursor, next tokens sampled;
+- **verify** (``speculate_k > 0``): each slot's last token plus ``k``
+  drafts scored in one pass;
+- **release**: scrubs the null block when a slot retires.
 
-Both are ``jax.jit(..., donate_argnums=<cache>)`` and compiled ONCE at
-construction (``.trace().lower().compile()`` — the bench/test AOT
-convention), which buys the two serving-latency properties the tests
-pin down:
+All are ``jax.jit(..., donate_argnums=<pool>)`` and compiled ONCE at
+construction (``.trace().lower().compile()``), which buys the two
+serving-latency properties the tests pin down:
 
-- **zero allocation**: the cache buffers are donated and every write is
-  a fixed-position dynamic_update_slice, so XLA aliases them in place
-  (``input_output_alias`` asserted over every cache leaf in
-  ``tests/test_serving.py``) — a decode step never copies the cache;
+- **zero allocation**: the pool is donated and every write lands in
+  place (``input_output_alias`` over every pool leaf, asserted by
+  ``lint_serving_engine`` at construction and in
+  ``tests/test_chip_compile.py``): a decode step never copies the pool;
 - **zero recompilation**: every per-request quantity is an array
-  argument (tokens, temperatures, cursors-in-cache) and every
-  shape-changing knob is fixed at construction (``max_seqs``,
-  ``prefill_len``, ``top_k``), so admission/retirement never retraces —
-  the compile-storm counters (PR 1) are asserted flat across steps.
+  argument (tokens, temperatures, block tables, cursors, copy-on-write
+  pairs) and every shape-changing knob is fixed at construction
+  (``max_seqs``, the prefill buckets, the pool's size, ``top_k``), so
+  admission, block growth, prefix sharing and retirement never retrace.
 
-Capacity: :meth:`ServingEngine.suggest_max_seqs` turns the compiled
-decode step's static memory plan (``observability/costs.memory_budget``)
-into "how many concurrent sequences fit this chip's HBM" — the
-ROADMAP's cache-capacity accounting.
+Capacity: a pool left to its default holds every slot's full ``max_len``
+(:meth:`ServingEngine.suggest_max_seqs` says how many such slots fit a
+chip); :meth:`ServingEngine.suggest_pool_blocks` sizes a smaller pool
+from the compiled decode step's static memory plan
+(``observability/costs.memory_budget``) — docs/SERVING.md, "How to size
+the pool".
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -43,10 +51,10 @@ import numpy as np
 
 from apex_tpu.observability.costs import memory_budget
 from apex_tpu.observability.trace import span
-from apex_tpu.serving.cache import (KVCache, PagedKVCache, BlockAllocator,
-                                    KindPagedKVCache, KindBlockAllocator,
-                                    AdmitPlan, PoolExhausted,
-                                    cache_bytes_per_slot, paged_block_bytes)
+from apex_tpu.serving.cache import (NULL_BLOCK, _MIN_SCALE, PagedKVCache,
+                                    BlockAllocator, KindPagedKVCache,
+                                    KindBlockAllocator, AdmitPlan,
+                                    paged_block_bytes)
 from apex_tpu.serving.sampling import sample_tokens, verify_tokens
 
 __all__ = ["ServingEngine", "PagedServingEngine"]
@@ -68,21 +76,55 @@ def _tree_bytes(tree) -> int:
 
 
 class ServingEngine:
-    """See module docstring.
+    """See module docstring. The same AOT contract for every model: a
+    slot holds ``ceil(context / block_size)`` pool blocks, the decode
+    step's HBM traffic is O(actual context)
+    (``paged_decode_attention``), and admissions whose prompt prefix is
+    already pooled SHARE those blocks and skip prefill for the shared
+    span (copy-on-write; the TTFT win ``serve/ttft_prefix_ms`` tracks).
+
+    Host state (block tables, cursors, refcounts, the prefix-hash
+    index) lives in :attr:`allocator` — a
+    :class:`~apex_tpu.serving.cache.BlockAllocator` — and rides into
+    the fixed-shape programs as plain array arguments, so per-request
+    bookkeeping never retraces anything.
+
+    A model built from a layer pattern (``model.cfg.cache_kinds``:
+    :class:`~apex_tpu.models.pattern_decoder.PatternDecoder`) gets one
+    pool and one block table per layer KIND
+    (:class:`~apex_tpu.serving.cache.KindPagedKVCache`,
+    :class:`~apex_tpu.serving.cache.KindBlockAllocator`): the tables and
+    append targets ride into the same programs as dicts by kind, a
+    window kind's blocks go back to its pool as the cursor leaves them,
+    and what a ``step_stats`` model counts in a step comes back in the
+    token fetch (:attr:`last_stats`). Such a model is served without
+    the prefix index and without speculation (docs/SERVING.md, Limits).
 
     Args:
-      model: a :class:`~apex_tpu.models.gpt.GPTModel` (tp=1, no SP).
+      model: a :class:`~apex_tpu.models.gpt.GPTModel` (tp=1, no SP) or
+        a :class:`~apex_tpu.models.pattern_decoder.PatternDecoder`.
       params: its :meth:`init` pytree. The engine keeps the model's
         serving image of it as :attr:`params` and not the tree itself
         (:meth:`_hold_weights`).
       max_seqs: concurrent sequence slots (the decode batch width).
-      max_len: per-slot cache capacity in tokens (<= the model's
+      max_len: per-slot capacity in tokens (<= the model's
         ``max_position_embeddings``).
-      prefill_len: the fixed prompt window; prompts are right-padded to
-        it (longer prompts are rejected — one bucket keeps this PR's
-        program count at two).
+      prefill_len: one prompt window, or a list of them: one AOT prefill
+        program a bucket, a prompt runs the smallest that holds it and
+        the scheduler admits up to the widest.
+      num_blocks: pool size in blocks (block 0 is the reserved null
+        block — allocatable capacity is ``num_blocks - 1``). Default:
+        ``max_seqs * ceil(max_len / block_size) + 1``, every slot's
+        full ``max_len`` reserved; a smaller pool serves the same slots
+        at the traffic's mean length (:meth:`suggest_pool_blocks`). A
+        dict by layer kind, and required, for a model with
+        ``cfg.cache_kinds``.
+      block_size: tokens per block; every prefill bucket is a multiple
+        of it. Default: ``gcd(128, *buckets)``. The paged Pallas kernel
+        takes any size on every backend; ``block_size % 128 == 0``
+        keeps its score rows lane-dense on TPU.
       cache_dtype: ``jnp.bfloat16`` (default) or ``jnp.int8`` (quantized
-        cache with per-(position, head) scales).
+        pool with per-(position, head) scales).
       top_k: static top-k sampling cutoff (0 = full vocab).
       quarantine: compile the poison-slot quarantine check into the
         decode program — one per-slot ``isfinite`` reduction over the
@@ -93,34 +135,43 @@ class ServingEngine:
         injection path, zero extra compiles). After each
         :meth:`decode`, :attr:`last_finite` carries the per-slot flags
         the scheduler's quarantine reads. Default off — the decode
-        program is byte-identical to a quarantine-free engine's (the
-        PR 3 zero-cost idiom, asserted in ``tests/test_resilience.py``).
-      speculate_k: when > 0, compile a FOURTH AOT program — ``verify``
-        — that scores each slot's last accepted token plus ``k``
-        drafted tokens in ONE pass over the cached prefix
+        program is byte-identical to a quarantine-free engine's
+        (asserted in ``tests/test_resilience.py``).
+      prefix_suffix_cap: longest un-shared prompt TAIL (tokens) worth
+        serving through per-token decode steps on a prefix hit; a hit
+        whose tail is longer falls back to the cold full prefill
+        (sequential decode would beat one batched prefill only near
+        full coverage). Default: ``block_size``.
+      speculate_k: when > 0, compile the ``verify`` program, which
+        scores each slot's last accepted token plus ``k`` drafted
+        tokens in ONE pass over the cached prefix
         (:meth:`~apex_tpu.models.gpt.GPTModel.verify_forward`), runs
         the acceptance rule
-        (:func:`~apex_tpu.serving.sampling.verify_tokens`) and appends
-        the whole window with a k-token cache write
-        (:meth:`~apex_tpu.serving.cache.KVCache.append_k`). ``k`` is
-        the only static knob; draft tokens, temperatures and the
+        (:func:`~apex_tpu.serving.sampling.verify_tokens`) and writes
+        the whole window
+        (:meth:`~apex_tpu.serving.cache.PagedKVCache.append_k`). ``k``
+        is the only static knob; draft tokens, temperatures and the
         active mask are array arguments, so speculative serving keeps
         the zero-recompile contract. Default 0 — the engine is
-        byte-identical to a pre-speculation one.
+        byte-identical to a speculation-free one.
     """
 
     def __init__(self, model, params, *, max_seqs: int, max_len: int,
-                 prefill_len: int, cache_dtype=jnp.bfloat16,
-                 top_k: int = 0, rng_seed: int = 0,
-                 quarantine: bool = False, speculate_k: int = 0):
+                 prefill_len: int, num_blocks: Optional[int] = None,
+                 block_size: Optional[int] = None,
+                 cache_dtype=jnp.bfloat16, top_k: int = 0,
+                 rng_seed: int = 0, quarantine: bool = False,
+                 prefix_suffix_cap: Optional[int] = None,
+                 mean_context: Optional[float] = None,
+                 speculate_k: int = 0):
         model._require_cacheable()
         cfg = model.cfg
-        if hasattr(cfg, "cache_kinds"):
-            raise ValueError(
-                "the dense ServingEngine keeps one (L, S, H, max_len, D) "
-                "reservation for one kind of layer; a model built from a "
-                "layer pattern has a pool a kind and is served by "
-                "PagedServingEngine (docs/SERVING.md, Limits)")
+        # a few prompt-length buckets, one prefill program each: a prompt
+        # runs the smallest that holds it (an int is the one bucket)
+        buckets = tuple(sorted({int(b) for b in (
+            prefill_len if isinstance(prefill_len, (list, tuple))
+            else (prefill_len,))}))
+        prefill_len = buckets[-1]
         if max_len > cfg.max_position_embeddings:
             raise ValueError(
                 f"max_len {max_len} exceeds max_position_embeddings "
@@ -128,10 +179,36 @@ class ServingEngine:
         if prefill_len > max_len:
             raise ValueError(f"prefill_len {prefill_len} exceeds max_len "
                              f"{max_len}")
+        if block_size is None:
+            block_size = math.gcd(128, *buckets)
+        if any(b % block_size for b in buckets):
+            raise ValueError(
+                f"prefill_len {buckets} must be multiples of "
+                f"block_size {block_size} (the prefill program writes "
+                "whole pool blocks)")
+        self.by_kind = hasattr(cfg, "cache_kinds")
+        if self.by_kind and (speculate_k or prefix_suffix_cap is not None):
+            raise ValueError(
+                "a model with pools by layer kind is served without "
+                "speculation (its window kernel takes one query row) and "
+                "without the prefix index (a window layer has handed its "
+                "early blocks back): docs/SERVING.md, Limits")
+        if num_blocks is None and not self.by_kind:
+            # every slot's whole max_len, and the null block
+            num_blocks = max_seqs * -(-max_len // block_size) + 1
+        if self.by_kind != isinstance(num_blocks, dict):
+            raise ValueError(
+                "num_blocks is a dict by layer kind for a model with "
+                "cfg.cache_kinds and one number for any other; got "
+                f"{num_blocks!r}")
         self.model = model
         self.max_seqs = int(max_seqs)
         self.max_len = int(max_len)
         self.prefill_len = int(prefill_len)
+        self.prefill_buckets = buckets
+        self.block_size = int(block_size)
+        self.num_blocks = ({k: int(n) for k, n in num_blocks.items()}
+                           if self.by_kind else int(num_blocks))
         self.top_k = int(top_k)
         self.quarantine = bool(quarantine)
         self.speculate_k = int(speculate_k)
@@ -141,7 +218,15 @@ class ServingEngine:
             raise ValueError(
                 f"speculate_k {speculate_k} needs a {speculate_k + 1}-token "
                 f"verify window, which exceeds max_len {max_len}")
+        self.prefix_suffix_cap = int(block_size if prefix_suffix_cap
+                                     is None else prefix_suffix_cap)
+        self.mean_context = mean_context
         self.last_finite: Optional[np.ndarray] = None
+        self.last_admit: Optional[AdmitPlan] = None
+        self.last_failed: list = []
+        # a model with ``step_stats`` returns small int32 counters beside
+        # its logits; they ride to the host IN the token fetch
+        self.last_stats: Optional[np.ndarray] = None
         self.swaps = 0
         with span("engine.build"):
             self._build(model, self._hold_weights(params), cache_dtype,
@@ -185,544 +270,6 @@ class ServingEngine:
         if self._image_compiled is None:
             return params
         return self._image_compiled(params)
-
-    def _build(self, model, params, cache_dtype, rng_seed: int) -> None:
-        """The cache and the AOT programs (``__init__`` past its checks)."""
-        cfg = model.cfg
-        max_seqs, max_len = self.max_seqs, self.max_len
-        self.cache = KVCache.create(
-            cfg.num_layers, max_seqs, cfg.num_attention_heads, max_len,
-            cfg.head_dim, dtype=cache_dtype)
-
-        def prefill_step(params, cache, tokens, slot, true_len,
-                         temperature, rng):
-            with jax.named_scope("serve_prefill"):
-                # last_logit_only: the admission samples exactly one row
-                # of the head, so only that row is projected
-                logits, cache = model.forward(params, tokens,
-                                              kv_cache=cache, slot=slot,
-                                              prompt_len=true_len,
-                                              last_logit_only=True)
-                tok = sample_tokens(logits[0], rng, temperature[None],
-                                    self.top_k)[0]
-            return cache, tok
-
-        if self.quarantine:
-            # the quarantine variant: one extra (S,) array argument
-            # (``poison``, normally zeros — adding NaN to a slot's row is
-            # the deterministic fault-injection path) and one extra
-            # per-slot output (``finite``). Both ride the SAME compiled
-            # program forever — injecting or clearing poison never
-            # retraces. The finite reduction runs on the post-injection
-            # sampling-path logits, so a NaN from ANY upstream source
-            # (poisoned cache, bad weights, the injection arg) flags the
-            # slot the very step it first reaches sampling.
-            def decode_step(params, cache, tokens, temperature, active,
-                            rng, poison):
-                with jax.named_scope("serve_decode"):
-                    logits, cache = model.forward(params, tokens[:, None],
-                                                  kv_cache=cache,
-                                                  active=active)
-                    logits = logits + poison[:, None]
-                    finite = jnp.all(jnp.isfinite(logits), axis=-1)
-                    toks = sample_tokens(logits, rng, temperature,
-                                         self.top_k)
-                return cache, toks, finite
-        else:
-            def decode_step(params, cache, tokens, temperature, active,
-                            rng):
-                with jax.named_scope("serve_decode"):
-                    logits, cache = model.forward(params, tokens[:, None],
-                                                  kv_cache=cache,
-                                                  active=active)
-                    toks = sample_tokens(logits, rng, temperature,
-                                         self.top_k)
-                return cache, toks
-
-        self._init_key(rng_seed)
-        S = self.max_seqs
-        ex_tokens = jnp.zeros((1, self.prefill_len), jnp.int32)
-        ex_scalar = jnp.zeros((), jnp.int32)
-        ex_temp = jnp.zeros((), jnp.float32)
-        with span("compile.prefill"):
-            self.prefill_traced = jax.jit(
-                prefill_step, donate_argnums=(1,)).trace(
-                    params, self.cache, ex_tokens, ex_scalar, ex_scalar,
-                    ex_temp, self._key)
-            self.prefill_compiled = self.prefill_traced.lower().compile()
-        self._zero_poison = jnp.zeros((S,), jnp.float32)
-        decode_args = (params, self.cache, jnp.zeros((S,), jnp.int32),
-                       jnp.zeros((S,), jnp.float32),
-                       jnp.ones((S,), jnp.bool_), self._key)
-        if self.quarantine:
-            decode_args += (self._zero_poison,)
-        with span("compile.decode"):
-            self.decode_traced = jax.jit(
-                decode_step, donate_argnums=(1,)).trace(*decode_args)
-            self.decode_compiled = self.decode_traced.lower().compile()
-
-        self.verify_traced = None
-        self.verify_compiled = None
-        if self.speculate_k > 0:
-            K = self.speculate_k
-
-            def _verify_core(params, cache, tokens, drafts, temperature,
-                             active, rng, poison=None):
-                # score the whole window BEFORE appending: the accepted
-                # count decides the cursor advance, and append_k writes
-                # every row that fits — rejected rows land above the
-                # cursor, masked from every read (the rollback story)
-                logits, (k_new, v_new), cache = model.verify_forward(
-                    params, tokens, cache)
-                finite = None
-                if poison is not None:
-                    logits = logits + poison[:, None, None]
-                    finite = jnp.all(jnp.isfinite(logits), axis=(-2, -1))
-                toks, accepted = verify_tokens(logits, drafts, rng,
-                                               temperature, self.top_k)
-                counts = jnp.where(active, accepted + 1, 0)
-                cache = cache.append_k(k_new, v_new, counts)
-                if finite is not None:
-                    return cache, toks, counts, finite
-                return cache, toks, counts
-
-            if self.quarantine:
-                def verify_step(params, cache, tokens, drafts,
-                                temperature, active, rng, poison):
-                    with jax.named_scope("serve_verify"):
-                        return _verify_core(params, cache, tokens, drafts,
-                                            temperature, active, rng,
-                                            poison)
-            else:
-                def verify_step(params, cache, tokens, drafts,
-                                temperature, active, rng):
-                    with jax.named_scope("serve_verify"):
-                        return _verify_core(params, cache, tokens, drafts,
-                                            temperature, active, rng)
-
-            verify_args = (params, self.cache,
-                           jnp.zeros((S, K + 1), jnp.int32),
-                           jnp.zeros((S, K), jnp.int32),
-                           jnp.zeros((S,), jnp.float32),
-                           jnp.ones((S,), jnp.bool_), self._key)
-            if self.quarantine:
-                verify_args += (self._zero_poison,)
-            with span("compile.verify"):
-                self.verify_traced = jax.jit(
-                    verify_step, donate_argnums=(1,)).trace(*verify_args)
-                self.verify_compiled = self.verify_traced.lower().compile()
-
-        def release_step(cache, slot):
-            # zero one slot's cursor so a freed slot stops paying
-            # attention over its dead prefix on every later decode step
-            lengths = jax.lax.dynamic_update_slice(
-                cache.lengths, jnp.zeros((1,), jnp.int32), (slot,))
-            return dataclasses.replace(cache, lengths=lengths)
-
-        with span("compile.release"):
-            self.release_compiled = jax.jit(
-                release_step, donate_argnums=(0,)).trace(
-                    self.cache, ex_scalar).lower().compile()
-
-        # construction-time donation self-check (analysis rule
-        # jaxpr-donation, docs/ANALYSIS.md): every cache leaf must be
-        # input/output-aliased in all three compiled programs, and no
-        # two cache leaves may share one buffer — a KVCache built with a
-        # shared scale plane would donate the SAME buffer twice, the
-        # exact class PR 9's review caught by hand
-        from apex_tpu.analysis.program import (lint_serving_engine,
-                                               verify_findings)
-        with span("engine.lint"):
-            verify_findings(lint_serving_engine(self),
-                            "ServingEngine construction")
-
-    # -- stepping -----------------------------------------------------------
-
-    def _init_key(self, rng_seed: int) -> None:
-        self._key, _ = jax.random.split(jax.random.PRNGKey(rng_seed))
-        # the per-dispatch key split is an AOT program like the steps:
-        # the steady-state loop dispatches ONLY compiled executables and
-        # marshals host values with numpy. An eager jnp op there
-        # (``jax.random.split``, ``jnp.asarray(x, dtype)``) is a jitted
-        # primitive that can fall off jit's C++ fast path and then
-        # reports a trace on every dispatch (docs/SERVING.md
-        # "Zero-recompile contract").
-        self._split_compiled = jax.jit(
-            lambda key: tuple(jax.random.split(key))).lower(
-                self._key).compile()
-
-    def _next_key(self) -> jax.Array:
-        self._key, sub = self._split_compiled(self._key)
-        return sub
-
-    def pad_prompt(self, prompt: Sequence[int]) -> np.ndarray:
-        if len(prompt) == 0:
-            raise ValueError("empty prompt")
-        if len(prompt) > self.prefill_len:
-            raise ValueError(
-                f"prompt length {len(prompt)} exceeds the prefill window "
-                f"{self.prefill_len} (pick a larger prefill_len at "
-                "engine construction)")
-        padded = np.zeros((1, self.prefill_len), np.int32)
-        padded[0, : len(prompt)] = np.asarray(prompt, np.int32)
-        return padded
-
-    def prefill(self, prompt: Sequence[int], slot: int,
-                temperature: float = 0.0) -> int:
-        """Admit ``prompt`` into ``slot`` and return the first sampled
-        token (a host int). Consumes and replaces the donated cache."""
-        if not 0 <= int(slot) < self.max_seqs:
-            # an out-of-range slot would CLAMP inside the compiled
-            # dynamic_update_slice and silently clobber the last valid
-            # slot's in-flight sequence
-            raise ValueError(f"slot {slot} out of range "
-                             f"[0, {self.max_seqs})")
-        with span("engine.prefill", slot=int(slot)):
-            with span("prefill.plan"):
-                args = (self.params, self.cache, self.pad_prompt(prompt),
-                        _host(slot, np.int32),
-                        _host(len(prompt), np.int32),
-                        _host(temperature, np.float32), self._next_key())
-            with span("prefill.dispatch"):
-                self.cache, tok = self.prefill_compiled(*args)
-            with span("prefill.wait"):
-                return int(tok)
-
-    def decode(self, tokens: np.ndarray, temperatures: np.ndarray,
-               active: Optional[np.ndarray] = None,
-               poison: Optional[np.ndarray] = None) -> np.ndarray:
-        """One decode step for every slot: ``tokens (max_seqs,)`` are the
-        last emitted token per slot (anything for free slots), returns
-        the next token per slot. ``active`` (``(max_seqs,)`` bool,
-        default all): slots outside it keep a frozen cursor — free slots
-        never grow an attention prefix. Consumes and replaces the
-        donated cache.
-
-        ``poison`` (quarantine engines only, ``(max_seqs,)`` f32,
-        default zeros) is added to each slot's sampling-path logits —
-        the deterministic fault-injection argument. On a quarantine
-        engine :attr:`last_finite` holds this step's per-slot finite
-        flags afterwards; on a plain engine it stays None (and a poison
-        array is refused — the fault would be silently dropped)."""
-        if active is None:
-            active = np.ones(self.max_seqs, np.bool_)
-        self._refuse_poison_unless_quarantine(poison)
-        with span("engine.decode", active=int(np.count_nonzero(active))):
-            with span("decode.plan"):
-                args = (self.params, self.cache,
-                        _host(tokens, np.int32),
-                        _host(temperatures, np.float32),
-                        _host(active, np.bool_), self._next_key())
-                args += self._poison_arg(poison)
-            with span("decode.dispatch"):
-                # a quarantine engine's program returns ``finite`` too
-                self.cache, toks, *finite = self.decode_compiled(*args)
-            with span("decode.wait"):
-                if finite:
-                    self.last_finite = np.asarray(finite[0])
-                return np.asarray(toks)
-
-    def _refuse_poison_unless_quarantine(self, poison) -> None:
-        if poison is not None and not self.quarantine:
-            raise ValueError(
-                "poison injection requires a quarantine engine "
-                f"({type(self).__name__}(..., quarantine=True)) — on a "
-                "plain engine the fault would be silently dropped")
-
-    def _poison_arg(self, poison) -> tuple:
-        """The quarantine programs' trailing ``poison`` argument."""
-        if not self.quarantine:
-            return ()
-        return (self._zero_poison if poison is None
-                else _host(poison, np.float32),)
-
-    def verify(self, tokens: np.ndarray, drafts: np.ndarray,
-               temperatures: np.ndarray,
-               active: Optional[np.ndarray] = None,
-               poison: Optional[np.ndarray] = None):
-        """One speculative verify step for every slot: ``tokens
-        (max_seqs,)`` are each slot's last emitted token, ``drafts
-        (max_seqs, speculate_k)`` the draft-source proposals after it.
-        Returns ``(out_tokens (max_seqs, speculate_k + 1), counts
-        (max_seqs,))`` — slot ``s`` emits ``out_tokens[s, :counts[s]]``
-        this step (``counts`` is 0 for inactive slots, otherwise
-        ``accepted_drafts + 1``), and its cursor has already advanced by
-        exactly ``counts[s]``: rejected rows sit above the cursor where
-        no read masks them in, so retiring the slot at ANY point leaves
-        no drafted-but-rejected KV visible. Consumes and replaces the
-        donated cache; requires ``speculate_k > 0`` at construction.
-
-        ``poison`` follows the :meth:`decode` quarantine contract — on a
-        quarantine engine :attr:`last_finite` carries the per-slot
-        finite flags of the VERIFY logits afterwards."""
-        if self.verify_compiled is None:
-            raise ValueError(
-                "verify requires a speculative engine "
-                f"({type(self).__name__}(..., speculate_k=k) with k > 0)")
-        if active is None:
-            active = np.ones(self.max_seqs, np.bool_)
-        self._refuse_poison_unless_quarantine(poison)
-        with span("engine.verify", active=int(np.count_nonzero(active))):
-            with span("verify.plan"):
-                drafts = np.asarray(drafts, np.int32).reshape(
-                    self.max_seqs, self.speculate_k)
-                tok_mat = np.concatenate(
-                    [np.asarray(tokens, np.int32).reshape(self.max_seqs, 1),
-                     drafts], axis=1)
-                args = (self.params, self.cache, _host(tok_mat),
-                        _host(drafts),
-                        _host(temperatures, np.float32),
-                        _host(active, np.bool_), self._next_key())
-                args += self._poison_arg(poison)
-            with span("verify.dispatch"):
-                self.cache, toks, counts, *finite = self.verify_compiled(
-                    *args)
-            with span("verify.wait"):
-                if finite:
-                    self.last_finite = np.asarray(finite[0])
-                return np.asarray(toks), np.asarray(counts)
-
-    def release_slot(self, slot: int) -> None:
-        """Zero ``slot``'s write cursor (AOT-compiled, donated like the
-        steps). Call when a sequence retires: the decode kernel skips
-        the compute of blocks past the cursor (and the XLA fallback
-        skips nothing but masks), so an idle slot left at a deep cursor
-        would keep paying prefix attention math on every step until
-        reused — and the cursor is also the capacity/accounting truth
-        the next admission relies on."""
-        if not 0 <= int(slot) < self.max_seqs:
-            raise ValueError(f"slot {slot} out of range "
-                             f"[0, {self.max_seqs})")
-        with span("engine.release", slot=int(slot)):
-            self.cache = self.release_compiled(self.cache,
-                                               _host(slot, np.int32))
-
-    # -- hot weight swap ----------------------------------------------------
-
-    def swap_params(self, new_params, *, relint: bool = True) -> None:
-        """Swap the serving weights in place with ZERO recompiles.
-
-        The weights are a plain (non-donated) array argument of all the
-        AOT programs, so replacing :attr:`params` retargets every
-        subsequent prefill/decode/release dispatch at the new weights —
-        no retrace, no recompile, no cache reallocation (the
-        compile-storm counters stay flat; asserted under
-        ``recompile_guard`` in ``tests/test_resilience.py``). In-flight
-        sequences keep their OLD-weight KV prefix and extend it under
-        the new weights — the standard serve-while-train rollover
-        semantics; drain first
-        (:meth:`~apex_tpu.serving.scheduler.SlotScheduler.drain`) for a
-        clean generation boundary.
-
-        A swap takes what construction took: ``new_params`` must match
-        :attr:`params_spec` exactly (same treedef, same leaf
-        shapes/dtypes — the trainer's tree, not the image the programs
-        read). Anything else is refused here at the host boundary,
-        before a leaf is cast: it would retrace on next dispatch, which
-        is exactly the compile storm this method exists to avoid. The
-        image is then made by the cast program construction compiled
-        (:meth:`_hold_weights`), and ``new_params`` is not kept.
-        ``relint=True`` re-runs the analysis engine's donation/aliasing
-        lint over the compiled programs after the swap (rule
-        ``jaxpr-donation`` — the construction-time self-check repeated
-        at every rollover).
-        """
-        old_leaves, old_def = jax.tree_util.tree_flatten(self.params_spec)
-        new_leaves, new_def = jax.tree_util.tree_flatten(new_params)
-        if old_def != new_def:
-            raise ValueError(
-                "swap_params: new params tree structure differs from "
-                "what the engine was built on — a swap must never "
-                f"retrace (old {old_def}, new {new_def})")
-        converted = []
-        for i, (o, n) in enumerate(zip(old_leaves, new_leaves)):
-            # one device_put per leaf: validate on the converted array
-            # and keep it, rather than transferring the model twice
-            n = jnp.asarray(n)
-            if o.shape != n.shape or o.dtype != n.dtype:
-                raise ValueError(
-                    f"swap_params: leaf {i} is {n.shape}/{n.dtype}, "
-                    f"built on {o.shape}/{o.dtype} — a swap must "
-                    "never retrace")
-            converted.append(n)
-        self.params = self._image(
-            jax.tree_util.tree_unflatten(new_def, converted))
-        self.swaps += 1
-        if relint:
-            from apex_tpu.analysis.program import (lint_serving_engine,
-                                                   verify_findings)
-            verify_findings(lint_serving_engine(self),
-                            "ServingEngine.swap_params")
-
-    # -- what the compiler was handed ----------------------------------------
-
-    def attention_paths(self) -> Dict[str, str]:
-        """Which attention path each AOT program took, read off its
-        compiled text: ``"pallas"`` when the program holds a Mosaic
-        kernel (``tpu_custom_call``), ``"xla"`` when attention lowered
-        to plain XLA ops (``use_flash=False``, or a shape the
-        ``use_pallas=None`` gate sent to the reference path). Keys:
-        ``prefill``, ``decode`` and, on a speculative engine,
-        ``verify``. Meaningful on the TPU backend — on CPU the kernels
-        run interpreted, which inlines them as plain ops, so every
-        program reads ``"xla"`` there."""
-        programs = {"prefill": self.prefill_compiled,
-                    "decode": self.decode_compiled}
-        if self.verify_compiled is not None:
-            programs["verify"] = self.verify_compiled
-        return {name: "pallas" if "tpu_custom_call" in prog.as_text()
-                else "xla" for name, prog in programs.items()}
-
-    # -- capacity -----------------------------------------------------------
-
-    def bytes_per_slot(self) -> int:
-        cfg = self.model.cfg
-        return cache_bytes_per_slot(cfg.num_layers,
-                                    cfg.num_attention_heads, self.max_len,
-                                    cfg.head_dim, self.cache.k.dtype)
-
-    def overhead_bytes(self) -> Optional[int]:
-        """Non-cache HBM the compiled decode step pins (params, logits,
-        temporaries), from the executable's static memory plan — None
-        when the backend reports no analysis."""
-        budget = memory_budget(self.decode_compiled)
-        if budget is None:
-            return None
-        return max(0, int(budget["peak_hbm_bytes"]) - self.cache.nbytes())
-
-    def suggest_max_seqs(self, hbm_bytes: int,
-                         reserve_fraction: float = 0.1) -> int:
-        """Max concurrent sequence slots that fit ``hbm_bytes``: the
-        compiled step's non-cache footprint (measured, not guessed) is
-        subtracted, a ``reserve_fraction`` safety margin held back, and
-        the rest divided by the per-slot cache bytes. Falls back to the
-        raw params size as the overhead estimate when the backend
-        exposes no memory analysis."""
-        overhead = self.overhead_bytes()
-        if overhead is None:
-            overhead = self.weights_held_bytes
-        avail = int(hbm_bytes * (1.0 - reserve_fraction)) - overhead
-        return max(0, avail // self.bytes_per_slot())
-
-
-class PagedServingEngine(ServingEngine):
-    """The v2 paged engine: same three-AOT-program contract as
-    :class:`ServingEngine` (compiled once at construction, cache
-    donated, ``lint_serving_engine`` self-check, zero recompiles across
-    admit/COW/retire), but the cache is a global
-    :class:`~apex_tpu.serving.cache.PagedKVCache` block pool — a slot
-    reserves ``ceil(context/block_size)`` blocks instead of ``max_len``
-    positions, the decode step's HBM traffic is O(actual context)
-    (``paged_decode_attention``), and admissions whose prompt prefix is
-    already pooled SHARE those blocks and skip prefill for the shared
-    span (copy-on-write; the TTFT win ``serve/ttft_prefix_ms`` tracks).
-
-    Host state (block tables, cursors, refcounts, the prefix-hash
-    index) lives in :attr:`allocator` — a
-    :class:`~apex_tpu.serving.cache.BlockAllocator` — and rides into
-    the fixed-shape programs as plain array arguments, so per-request
-    bookkeeping never retraces anything.
-
-    A model built from a layer pattern (``model.cfg.cache_kinds``:
-    :class:`~apex_tpu.models.pattern_decoder.PatternDecoder`) gets one
-    pool and one block table per layer KIND
-    (:class:`~apex_tpu.serving.cache.KindPagedKVCache`,
-    :class:`~apex_tpu.serving.cache.KindBlockAllocator`): the tables and
-    append targets ride into the same programs as dicts by kind, a
-    window kind's blocks go back to its pool as the cursor leaves them,
-    and what a ``step_stats`` model counts in a step comes back in the
-    token fetch (:attr:`last_stats`). Such a model is served without
-    the prefix index and without speculation (docs/SERVING.md, Limits).
-
-    Extra construction knobs vs the dense engine:
-
-    Args:
-      prefill_len: one prompt window, or a list of them: one AOT prefill
-        program a bucket, a prompt runs the smallest that holds it and
-        the scheduler admits up to the widest.
-      num_blocks: global pool size in blocks (block 0 is the reserved
-        null block — allocatable capacity is ``num_blocks - 1``). Size
-        with :meth:`suggest_pool_blocks`. A dict by layer kind for a
-        model with ``cfg.cache_kinds``.
-      block_size: tokens per block. The paged Pallas kernel takes any
-        size on every backend; ``block_size % 128 == 0`` keeps its
-        score rows lane-dense on TPU.
-      prefix_suffix_cap: longest un-shared prompt TAIL (tokens) worth
-        serving through per-token decode steps on a prefix hit; a hit
-        whose tail is longer falls back to the cold full prefill
-        (sequential decode would beat one batched prefill only near
-        full coverage). Default: ``block_size``.
-    """
-
-    def __init__(self, model, params, *, max_seqs: int, max_len: int,
-                 prefill_len: int, num_blocks: int, block_size: int,
-                 cache_dtype=jnp.bfloat16, top_k: int = 0,
-                 rng_seed: int = 0, quarantine: bool = False,
-                 prefix_suffix_cap: Optional[int] = None,
-                 mean_context: Optional[float] = None,
-                 speculate_k: int = 0):
-        model._require_cacheable()
-        cfg = model.cfg
-        # a few prompt-length buckets, one prefill program each: a prompt
-        # runs the smallest that holds it (an int is the one bucket)
-        buckets = tuple(sorted({int(b) for b in (
-            prefill_len if isinstance(prefill_len, (list, tuple))
-            else (prefill_len,))}))
-        prefill_len = buckets[-1]
-        if max_len > cfg.max_position_embeddings:
-            raise ValueError(
-                f"max_len {max_len} exceeds max_position_embeddings "
-                f"{cfg.max_position_embeddings}")
-        if prefill_len > max_len:
-            raise ValueError(f"prefill_len {prefill_len} exceeds max_len "
-                             f"{max_len}")
-        if any(b % block_size for b in buckets):
-            raise ValueError(
-                f"prefill_len {buckets} must be multiples of "
-                f"block_size {block_size} (the prefill program writes "
-                "whole pool blocks)")
-        self.by_kind = hasattr(cfg, "cache_kinds")
-        if self.by_kind and (speculate_k or prefix_suffix_cap is not None):
-            raise ValueError(
-                "a model with pools by layer kind is served without "
-                "speculation (its window kernel takes one query row) and "
-                "without the prefix index (a window layer has handed its "
-                "early blocks back): docs/SERVING.md, Limits")
-        if self.by_kind != isinstance(num_blocks, dict):
-            raise ValueError(
-                "num_blocks is a dict by layer kind for a model with "
-                "cfg.cache_kinds and one number for any other; got "
-                f"{num_blocks!r}")
-        self.model = model
-        self.max_seqs = int(max_seqs)
-        self.max_len = int(max_len)
-        self.prefill_len = int(prefill_len)
-        self.prefill_buckets = buckets
-        self.block_size = int(block_size)
-        self.num_blocks = ({k: int(n) for k, n in num_blocks.items()}
-                           if self.by_kind else int(num_blocks))
-        self.top_k = int(top_k)
-        self.quarantine = bool(quarantine)
-        self.speculate_k = int(speculate_k)
-        if self.speculate_k < 0:
-            raise ValueError(f"speculate_k must be >= 0, got {speculate_k}")
-        if self.speculate_k + 1 > max_len:
-            raise ValueError(
-                f"speculate_k {speculate_k} needs a {speculate_k + 1}-token "
-                f"verify window, which exceeds max_len {max_len}")
-        self.prefix_suffix_cap = int(block_size if prefix_suffix_cap
-                                     is None else prefix_suffix_cap)
-        self.mean_context = mean_context
-        self.last_finite: Optional[np.ndarray] = None
-        self.last_admit: Optional[AdmitPlan] = None
-        self.last_failed: list = []
-        # a model with ``step_stats`` returns small int32 counters beside
-        # its logits; they ride to the host IN the token fetch
-        self.last_stats: Optional[np.ndarray] = None
-        self.swaps = 0
-        with span("engine.build"):
-            self._build(model, self._hold_weights(params), cache_dtype,
-                        rng_seed)
 
     def _build(self, model, params, cache_dtype, rng_seed: int) -> None:
         """The pool, its allocator and the AOT programs (``__init__``
@@ -856,7 +403,7 @@ class PagedServingEngine(ServingEngine):
                 # the window — rejected rows land in blocks above the
                 # host cursor mirror, which only ever advances by the
                 # accepted count
-                logits, _, cache = model.verify_forward(
+                logits, cache = model.verify_forward(
                     params, tokens, cache, block_tables=tables,
                     lengths=lengths, append_block_ids=block_ids,
                     append_offsets=offsets, cow_src=cow_src,
@@ -913,7 +460,6 @@ class PagedServingEngine(ServingEngine):
             # it, so a retire is the natural point to scrub the garbage
             # back to the "reads as zeros" invariant. Real in-place
             # writes on every donated leaf — the donation lint holds.
-            from apex_tpu.serving.cache import NULL_BLOCK, _MIN_SCALE
             if self.by_kind:
                 return cache.scrub_null_blocks()
             new = {"k": cache.k.at[:, NULL_BLOCK].set(0),
@@ -934,7 +480,26 @@ class PagedServingEngine(ServingEngine):
                                                verify_findings)
         with span("engine.lint"):
             verify_findings(lint_serving_engine(self),
-                            "PagedServingEngine construction")
+                            "ServingEngine construction")
+
+    # -- the sampling key ---------------------------------------------------
+
+    def _init_key(self, rng_seed: int) -> None:
+        self._key, _ = jax.random.split(jax.random.PRNGKey(rng_seed))
+        # the per-dispatch key split is an AOT program like the steps:
+        # the steady-state loop dispatches ONLY compiled executables and
+        # marshals host values with numpy. An eager jnp op there
+        # (``jax.random.split``, ``jnp.asarray(x, dtype)``) is a jitted
+        # primitive that can fall off jit's C++ fast path and then
+        # reports a trace on every dispatch (docs/SERVING.md
+        # "Zero-recompile contract").
+        self._split_compiled = jax.jit(
+            lambda key: tuple(jax.random.split(key))).lower(
+                self._key).compile()
+
+    def _next_key(self) -> jax.Array:
+        self._key, sub = self._split_compiled(self._key)
+        return sub
 
     # -- admission ----------------------------------------------------------
 
@@ -945,12 +510,13 @@ class PagedServingEngine(ServingEngine):
 
     def pad_prompt(self, prompt: Sequence[int]) -> np.ndarray:
         """``prompt`` right-padded to the smallest bucket that holds it."""
-        if len(self.prefill_buckets) == 1:
-            return super().pad_prompt(prompt)
-        if not 0 < len(prompt) <= self.prefill_len:
+        if len(prompt) == 0:
+            raise ValueError("empty prompt")
+        if len(prompt) > self.prefill_len:
             raise ValueError(
-                f"prompt length {len(prompt)} outside (0, "
-                f"{self.prefill_len}], the widest prefill bucket")
+                f"prompt length {len(prompt)} exceeds the prefill window "
+                f"{self.prefill_len} (pick a larger prefill_len at "
+                "engine construction)")
         bucket = next(b for b in self.prefill_buckets if b >= len(prompt))
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(prompt)] = np.asarray(prompt, np.int32)
@@ -1045,9 +611,20 @@ class PagedServingEngine(ServingEngine):
     def decode(self, tokens: np.ndarray, temperatures: np.ndarray,
                active: Optional[np.ndarray] = None,
                poison: Optional[np.ndarray] = None) -> np.ndarray:
-        """One decode step for every slot (same call contract as
-        :meth:`ServingEngine.decode`). Per-step block bookkeeping
-        happens HERE: pending copy-on-writes are resolved (the device
+        """One decode step for every slot: ``tokens (max_seqs,)`` are the
+        last emitted token per slot (anything for free slots), returns
+        the next token per slot. ``active`` (``(max_seqs,)`` bool,
+        default all): slots outside it keep a frozen cursor and write
+        nothing. Consumes and replaces the donated pool.
+
+        ``poison`` (quarantine engines only, ``(max_seqs,)`` f32,
+        default zeros) is added to each slot's sampling-path logits —
+        the deterministic fault-injection argument. On a quarantine
+        engine :attr:`last_finite` holds this step's per-slot finite
+        flags afterwards; on a plain engine it stays None (and a poison
+        array is refused — the fault would be silently dropped).
+
+        Per-step block bookkeeping happens HERE: pending copy-on-writes are resolved (the device
         copies the block before writing it), cursors that crossed a
         block boundary get a fresh block, and slots the exhausted pool
         could not serve land in :attr:`last_failed` — their append is
@@ -1085,13 +662,39 @@ class PagedServingEngine(ServingEngine):
                     self.last_finite = np.asarray(finite[0])
                 return self._unpack(np.asarray(toks), self.max_seqs)
 
+    def _refuse_poison_unless_quarantine(self, poison) -> None:
+        if poison is not None and not self.quarantine:
+            raise ValueError(
+                "poison injection requires a quarantine engine "
+                f"({type(self).__name__}(..., quarantine=True)) — on a "
+                "plain engine the fault would be silently dropped")
+
+    def _poison_arg(self, poison) -> tuple:
+        """The quarantine programs' trailing ``poison`` argument."""
+        if not self.quarantine:
+            return ()
+        return (self._zero_poison if poison is None
+                else _host(poison, np.float32),)
+
     def verify(self, tokens: np.ndarray, drafts: np.ndarray,
                temperatures: np.ndarray,
                active: Optional[np.ndarray] = None,
                poison: Optional[np.ndarray] = None):
-        """Paged speculative verify (same call contract as
-        :meth:`ServingEngine.verify`). Per-window block bookkeeping
-        happens HERE: :meth:`~apex_tpu.serving.cache.BlockAllocator.
+        """One speculative verify step for every slot: ``tokens
+        (max_seqs,)`` are each slot's last emitted token, ``drafts
+        (max_seqs, speculate_k)`` the draft-source proposals after it.
+        Returns ``(out_tokens (max_seqs, speculate_k + 1), counts
+        (max_seqs,))`` — slot ``s`` emits ``out_tokens[s, :counts[s]]``
+        this step (``counts`` is 0 for inactive slots, otherwise
+        ``accepted_drafts + 1``), and its cursor has already advanced by
+        exactly ``counts[s]``: rejected rows sit above the cursor where
+        no read masks them in, so retiring the slot at ANY point leaves
+        no drafted-but-rejected KV visible. Consumes and replaces the
+        donated pool; requires ``speculate_k > 0`` at construction.
+        ``poison`` follows the :meth:`decode` quarantine contract (the
+        finite flags are of the VERIFY logits).
+
+        Per-window block bookkeeping happens HERE: :meth:`~apex_tpu.serving.cache.BlockAllocator.
         prepare_verify` makes every block the ``speculate_k + 1``-token
         window touches slot-private and writable (COW resolved, fresh
         blocks mapped, atomic per slot), slots the exhausted pool could
@@ -1156,9 +759,86 @@ class PagedServingEngine(ServingEngine):
             self.allocator.release(slot)
             self.cache = self.release_compiled(self.cache)
 
+    # -- hot weight swap ----------------------------------------------------
+
+    def swap_params(self, new_params, *, relint: bool = True) -> None:
+        """Swap the serving weights in place with ZERO recompiles.
+
+        The weights are a plain (non-donated) array argument of all the
+        AOT programs, so replacing :attr:`params` retargets every
+        subsequent prefill/decode/release dispatch at the new weights —
+        no retrace, no recompile, no cache reallocation (the
+        compile-storm counters stay flat; asserted under
+        ``recompile_guard`` in ``tests/test_resilience.py``). In-flight
+        sequences keep their OLD-weight KV prefix and extend it under
+        the new weights — the standard serve-while-train rollover
+        semantics; drain first
+        (:meth:`~apex_tpu.serving.scheduler.SlotScheduler.drain`) for a
+        clean generation boundary.
+
+        A swap takes what construction took: ``new_params`` must match
+        :attr:`params_spec` exactly (same treedef, same leaf
+        shapes/dtypes — the trainer's tree, not the image the programs
+        read). Anything else is refused here at the host boundary,
+        before a leaf is cast: it would retrace on next dispatch, which
+        is exactly the compile storm this method exists to avoid. The
+        image is then made by the cast program construction compiled
+        (:meth:`_hold_weights`), and ``new_params`` is not kept.
+        ``relint=True`` re-runs the analysis engine's donation/aliasing
+        lint over the compiled programs after the swap (rule
+        ``jaxpr-donation`` — the construction-time self-check repeated
+        at every rollover).
+        """
+        old_leaves, old_def = jax.tree_util.tree_flatten(self.params_spec)
+        new_leaves, new_def = jax.tree_util.tree_flatten(new_params)
+        if old_def != new_def:
+            raise ValueError(
+                "swap_params: new params tree structure differs from "
+                "what the engine was built on — a swap must never "
+                f"retrace (old {old_def}, new {new_def})")
+        converted = []
+        for i, (o, n) in enumerate(zip(old_leaves, new_leaves)):
+            # one device_put per leaf: validate on the converted array
+            # and keep it, rather than transferring the model twice
+            n = jnp.asarray(n)
+            if o.shape != n.shape or o.dtype != n.dtype:
+                raise ValueError(
+                    f"swap_params: leaf {i} is {n.shape}/{n.dtype}, "
+                    f"built on {o.shape}/{o.dtype} — a swap must "
+                    "never retrace")
+            converted.append(n)
+        self.params = self._image(
+            jax.tree_util.tree_unflatten(new_def, converted))
+        self.swaps += 1
+        if relint:
+            from apex_tpu.analysis.program import (lint_serving_engine,
+                                                   verify_findings)
+            verify_findings(lint_serving_engine(self),
+                            "ServingEngine.swap_params")
+
+    # -- what the compiler was handed ----------------------------------------
+
+    def attention_paths(self) -> Dict[str, str]:
+        """Which attention path each AOT program took, read off its
+        compiled text: ``"pallas"`` when the program holds a Mosaic
+        kernel (``tpu_custom_call``), ``"xla"`` when attention lowered
+        to plain XLA ops (``use_flash=False``, or a shape the
+        ``use_pallas=None`` gate sent to the reference path). Keys:
+        ``prefill``, ``decode`` and, on a speculative engine,
+        ``verify``. Meaningful on the TPU backend — on CPU the kernels
+        run interpreted, which inlines them as plain ops, so every
+        program reads ``"xla"`` there."""
+        programs = {"prefill": self.prefill_compiled,
+                    "decode": self.decode_compiled}
+        if self.verify_compiled is not None:
+            programs["verify"] = self.verify_compiled
+        return {name: "pallas" if "tpu_custom_call" in prog.as_text()
+                else "xla" for name, prog in programs.items()}
+
     # -- capacity -----------------------------------------------------------
 
     def block_bytes(self) -> int:
+        """HBM bytes of one pool block (K and V, all layers)."""
         cfg = self.model.cfg
         if self.by_kind:
             raise NotImplementedError(
@@ -1168,24 +848,51 @@ class PagedServingEngine(ServingEngine):
                                  self.block_size, cfg.head_dim,
                                  self.cache.k.dtype)
 
-    def suggest_pool_blocks(self, hbm_bytes: int, mean_len: float,
-                            reserve_fraction: float = 0.1) -> int:
-        """Pool blocks that fit ``hbm_bytes`` — the paged successor of
-        :meth:`ServingEngine.suggest_max_seqs`. The compiled step's
-        non-cache footprint is measured and subtracted (params, logits,
-        temporaries), a ``reserve_fraction`` margin held back, and the
-        rest divided by the per-block bytes. The mean-length capacity
-        math reads off it: a pool of ``B`` blocks sustains about
-        ``B * block_size / mean_len`` concurrent sequences — versus the
-        dense engine's hard ``HBM / (max_len bytes-per-slot)`` ceiling,
-        a ``max_len / mean_len`` capacity win at the same HBM."""
-        if mean_len <= 0:
-            raise ValueError(f"mean_len must be positive, got {mean_len}")
+    def bytes_per_slot(self) -> int:
+        """HBM bytes of one slot's full-length reservation: the
+        ``ceil(max_len / block_size)`` blocks the default pool keeps for
+        every slot."""
+        return self.allocator.blocks_per_slot * self.block_bytes()
+
+    def overhead_bytes(self) -> Optional[int]:
+        """Non-pool HBM the compiled decode step pins (params, logits,
+        temporaries), from the executable's static memory plan — None
+        when the backend reports no analysis."""
+        budget = memory_budget(self.decode_compiled)
+        if budget is None:
+            return None
+        return max(0, int(budget["peak_hbm_bytes"]) - self.cache.nbytes())
+
+    def _pool_budget(self, hbm_bytes: int, reserve_fraction: float) -> int:
+        """What of ``hbm_bytes`` is left for the pool: the compiled
+        step's non-pool footprint (measured; the held weights where the
+        backend exposes no memory analysis) subtracted and a
+        ``reserve_fraction`` margin held back."""
         overhead = self.overhead_bytes()
         if overhead is None:
             overhead = self.weights_held_bytes
-        avail = int(hbm_bytes * (1.0 - reserve_fraction)) - overhead
-        return max(0, avail // self.block_bytes())
+        return int(hbm_bytes * (1.0 - reserve_fraction)) - overhead
+
+    def suggest_max_seqs(self, hbm_bytes: int,
+                         reserve_fraction: float = 0.1) -> int:
+        """Max concurrent slots whose full ``max_len`` reservation fits
+        ``hbm_bytes`` — the ``max_seqs`` to build a default-sized pool
+        with (:meth:`_pool_budget` over :meth:`bytes_per_slot`)."""
+        return max(0, self._pool_budget(hbm_bytes, reserve_fraction)
+                   // self.bytes_per_slot())
+
+    def suggest_pool_blocks(self, hbm_bytes: int, mean_len: float,
+                            reserve_fraction: float = 0.1) -> int:
+        """Pool blocks that fit ``hbm_bytes`` (:meth:`_pool_budget` over
+        :meth:`block_bytes`). The mean-length capacity math reads off
+        it: a pool of ``B`` blocks sustains about ``B * block_size /
+        mean_len`` concurrent sequences — against the ``HBM / (max_len
+        bytes-per-slot)`` of :meth:`suggest_max_seqs`, a ``max_len /
+        mean_len`` capacity win at the same HBM."""
+        if mean_len <= 0:
+            raise ValueError(f"mean_len must be positive, got {mean_len}")
+        return max(0, self._pool_budget(hbm_bytes, reserve_fraction)
+                   // self.block_bytes())
 
     def suggest_max_seqs_for_pool(self, num_blocks: int,
                                   mean_len: float) -> int:
@@ -1193,3 +900,7 @@ class PagedServingEngine(ServingEngine):
         observed ``mean_len`` (the second half of the capacity math)."""
         per_seq = max(1, -(-int(mean_len) // self.block_size))
         return max(0, (num_blocks - 1) // per_seq)
+
+
+# the name benchmark/ imports, which only a benchmark PR may change
+PagedServingEngine = ServingEngine

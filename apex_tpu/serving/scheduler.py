@@ -215,10 +215,10 @@ class SlotScheduler:
         if max_queue is not None and max_queue < 1:
             raise ValueError(f"max_queue must be >= 1, got {max_queue}")
         if speculate_k:
-            if getattr(engine, "speculate_k", 0) != speculate_k:
+            if engine.speculate_k != speculate_k:
                 raise ValueError(
                     f"speculate_k={speculate_k} but the engine compiled "
-                    f"speculate_k={getattr(engine, 'speculate_k', 0)} — "
+                    f"speculate_k={engine.speculate_k} — "
                     "the verify program's window is static, so the "
                     "scheduler and engine must agree at construction")
         elif draft_source is not None:
@@ -264,8 +264,8 @@ class SlotScheduler:
         self._any_deadlines = default_deadline_ms is not None
         self._tok_count = 0
         self._tok_t0: Optional[float] = None
-        # paged engines only: the allocator's monotonic COW counter at
-        # the last step, so serve/blocks_cow_copied emits deltas
+        # the allocator's monotonic COW counter at the last step, so
+        # serve/blocks_cow_copied emits deltas
         self._cow_seen = 0
         # pools by layer kind only: the window allocators' monotonic
         # (given, returned) at the last step
@@ -321,20 +321,19 @@ class SlotScheduler:
             self._reg.counter("serve/rejected").inc()
             return Rejection("queue_full", request.request_id,
                              f"queue at max_queue={self.max_queue}")
-        alloc = getattr(self.engine, "allocator", None)
-        if alloc is not None:
-            # paged admission control: a prompt that could never fit
-            # the WHOLE pool is refused up front (queueing it would
-            # deadlock the queue head forever); transient pressure —
-            # blocks held by in-flight sequences — queues instead and
-            # _admit waits for retirements to free blocks
-            need = alloc.blocks_for(len(request.prompt))
-            if need > alloc.num_blocks - 1:
-                self._reg.counter("serve/rejected").inc()
-                return Rejection(
-                    "pool_exhausted", request.request_id,
-                    f"prompt needs {need} blocks but the pool only has "
-                    f"{alloc.num_blocks - 1} allocatable")
+        # admission control: a prompt that could never fit the WHOLE
+        # pool is refused up front (queueing it would deadlock the queue
+        # head forever); transient pressure — blocks held by in-flight
+        # sequences — queues instead and _admit waits for retirements to
+        # free blocks
+        alloc = self.engine.allocator
+        need = alloc.blocks_for(len(request.prompt))
+        if need > alloc.num_blocks - 1:
+            self._reg.counter("serve/rejected").inc()
+            return Rejection(
+                "pool_exhausted", request.request_id,
+                f"prompt needs {need} blocks but the pool only has "
+                f"{alloc.num_blocks - 1} allocatable")
         if self.brownout is not None:
             engaged = self.brownout.engaged()
             self._reg.gauge("serve/brownout").set(1.0 if engaged else 0.0)
@@ -544,9 +543,8 @@ class SlotScheduler:
                 # expired while waiting: never spend a prefill on it
                 self._retire_queued(req, rec, "expired", now)
                 continue
-            if (hasattr(self.engine, "can_admit")
-                    and not self.engine.can_admit(req.prompt)):
-                # paged block-pool pressure: the blocks exist (submit
+            if not self.engine.can_admit(req.prompt):
+                # block-pool pressure: the blocks exist (submit
                 # bounds the prompt to the pool) but in-flight
                 # sequences hold them — requeue at the head and wait
                 # for retirements to free blocks
@@ -594,8 +592,8 @@ class SlotScheduler:
         self._temps[slot] = req.temperature
         self._reg.counter("serve/admitted").inc()
         self._reg.counter("serve/prefill_tokens").inc(len(req.prompt))
-        plan = getattr(self.engine, "last_admit", None)
-        if plan is not None and not plan.prefill:
+        plan = self.engine.last_admit
+        if not plan.prefill:
             # a prefix-shared admission: the shared span skipped
             # prefill entirely — serve/ttft_prefix_ms is the TTFT
             # histogram the acceptance bar compares against the
@@ -631,12 +629,10 @@ class SlotScheduler:
                 if not self._draining:
                     self._admit()
                 if self.active:
-                    # satellite of the paged PR: a slot AT capacity must
-                    # retire loudly BEFORE the decode dispatch — its
-                    # append would be dropped (KVCache.append writes
-                    # nothing at max_len; the paged pool has no block to
-                    # give), so one more step would sample a token whose
-                    # KV never landed
+                    # a slot AT capacity must retire loudly BEFORE the
+                    # decode dispatch — its append would be dropped (the
+                    # pool has no block to give), so one more step would
+                    # sample a token whose KV never landed
                     now = time.perf_counter()
                     for slot in list(self.active):
                         if self.active[slot].position >= self.engine.max_len:
@@ -683,7 +679,7 @@ class SlotScheduler:
         ran (it came back in the token fetch), summed over its rows and
         added to the counters the model names (``stats_names``, one a
         column)."""
-        stats = getattr(self.engine, "last_stats", None)
+        stats = self.engine.last_stats
         if stats is None:
             return
         for name, n in zip(self.engine.stats_names, stats.sum(axis=0)):
@@ -738,15 +734,15 @@ class SlotScheduler:
             if self._spec_drafted:
                 self._reg.gauge("serve/spec_accept_rate").set(
                     self._spec_accepted / self._spec_drafted)
-        # paged engines: slots the exhausted pool could not
-        # give a write block retire loudly as "capacity" — this
+        # slots the exhausted pool could not give a write block
+        # retire loudly as "capacity" — this
         # step's sampled token is valid (the kernel merges the
         # current token in-flight) but its KV was dropped, so
         # one more step would decode against a hole. On the
         # speculative path a failed slot's window aimed at the
         # null block and its count came back 0, so it emitted
         # nothing this step before retiring
-        for slot in getattr(self.engine, "last_failed", ()):
+        for slot in self.engine.last_failed:
             if slot in self.active:
                 self._retire(slot, "capacity", now)
         # mid-flight deadline enforcement: overdue survivors of
@@ -768,31 +764,30 @@ class SlotScheduler:
             self.engine.weights_handed_bytes)
         self._reg.gauge("serve/weights_held_bytes").set(
             self.engine.weights_held_bytes)
-        alloc = getattr(self.engine, "allocator", None)
-        if alloc is not None:
-            self._reg.gauge("serve/pool_blocks_free").set(
-                alloc.free_blocks)
-            # used + utilization next to free: free blocks alone cannot
-            # separate fragmentation from load (block 0 is the reserved
-            # null block, so allocatable capacity is num_blocks - 1)
-            capacity = alloc.num_blocks - 1
-            used = capacity - alloc.free_blocks
-            self._reg.gauge("serve/pool_blocks_used").set(used)
-            self._reg.gauge("serve/pool_utilization").set(
-                used / capacity if capacity else 0.0)
-            if hasattr(alloc, "kinds"):
-                for kind, n in alloc.blocks_in_use.items():
-                    self._reg.gauge(f"serve/blocks_in_use/{kind}").set(n)
-                given, returned = alloc.window_blocks()
-                self._reg.counter("serve/window_blocks_given").inc(
-                    given - self._window_seen[0])
-                self._reg.counter("serve/window_blocks_returned").inc(
-                    returned - self._window_seen[1])
-                self._window_seen = (given, returned)
-            if alloc.cow_copies > self._cow_seen:
-                self._reg.counter("serve/blocks_cow_copied").inc(
-                    alloc.cow_copies - self._cow_seen)
-                self._cow_seen = alloc.cow_copies
+        alloc = self.engine.allocator
+        self._reg.gauge("serve/pool_blocks_free").set(
+            alloc.free_blocks)
+        # used + utilization next to free: free blocks alone cannot
+        # separate fragmentation from load (block 0 is the reserved
+        # null block, so allocatable capacity is num_blocks - 1)
+        capacity = alloc.num_blocks - 1
+        used = capacity - alloc.free_blocks
+        self._reg.gauge("serve/pool_blocks_used").set(used)
+        self._reg.gauge("serve/pool_utilization").set(
+            used / capacity if capacity else 0.0)
+        if self.engine.by_kind:
+            for kind, n in alloc.blocks_in_use.items():
+                self._reg.gauge(f"serve/blocks_in_use/{kind}").set(n)
+            given, returned = alloc.window_blocks()
+            self._reg.counter("serve/window_blocks_given").inc(
+                given - self._window_seen[0])
+            self._reg.counter("serve/window_blocks_returned").inc(
+                returned - self._window_seen[1])
+            self._window_seen = (given, returned)
+        if alloc.cow_copies > self._cow_seen:
+            self._reg.counter("serve/blocks_cow_copied").inc(
+                alloc.cow_copies - self._cow_seen)
+            self._cow_seen = alloc.cow_copies
         elapsed = time.perf_counter() - self._tok_t0
         if elapsed > 0:
             self._reg.gauge("serve/tokens_per_sec").set(

@@ -56,20 +56,11 @@ Other configs:
              baseline (``gpt_sp_overlap_tokens_per_sec``; needs >= 2
              devices, emits a skip line otherwise — docs/PERF.md
              "Dependent-collective overlap");
-  decode   — serving fast path: KV-cached autoregressive decode through
-             the AOT ``ServingEngine`` (Pallas decode kernel, donated
-             cache, fixed-shape sampling). Two legs:
-             ``gpt_decode_tok_per_sec_b1`` (one active slot in a
-             max_seqs=1 program — per-token latency) and
-             ``gpt_decode_tok_per_sec_sat`` (every slot of the
-             saturating grid active — per-chip throughput), each with
-             HBM accounting and a prefill-vs-decode pyprof split;
-             ``vs_baseline`` is measured over the HBM roofline
-             (docs/SERVING.md "Reading bench_gpt_decode");
-  paged    — the paged twin: ``gpt_decode_tok_per_sec_paged`` (the
-             saturating grid through ``PagedServingEngine`` — block-pool
-             cache, bounded-grid kernel; carries ``modeled_hbm_ratio``,
-             the pyprof-modeled paged/dense attention-HBM gap) and
+  paged    — serving fast path: ``gpt_decode_tok_per_sec_paged`` (the
+             saturating grid through the AOT ``ServingEngine`` —
+             block-pool cache, bounded-grid Pallas decode kernel,
+             fixed-shape sampling; ``vs_baseline`` is measured over the
+             HBM roofline) and
              ``gpt_decode_ttft_prefix_ms`` (shared-prefix admission vs
              the cold prefill it skips); engine config is the
              declarative ``BENCH_DECODE_CONFIGS`` table
@@ -921,13 +912,13 @@ def bench_dp_accumulate_overlap(iters=10, warmup=2, K=4, layers=8,
 # off a TPU round (BASELINE.md round 13).
 DECODE_SLO = (("ttft_ms", 95.0, 2000.0), ("tpot_ms", 99.0, 500.0))
 
-# Declarative paged-decode leg config: keys are REAL
-# ``PagedServingEngine.__init__`` keyword parameters — statically
+# Declarative decode leg config: keys are REAL
+# ``ServingEngine.__init__`` keyword parameters — statically
 # validated by scripts/check_bench_configs.py (rule ast-bench-configs),
 # so a renamed engine knob breaks the check instead of TypeError-ing
 # only at bench runtime. num_blocks = max_seqs * (max_len/block_size)
-# + 1 (the reserved null block): full dense-equivalent worst-case
-# capacity, so the throughput delta isolates the bounded-grid kernel,
+# + 1 (the reserved null block): every slot's whole max_len, the
+# engine's own default, so the throughput reads the bounded-grid kernel,
 # not admission pressure. mean_context prices the kernel's CostEstimate
 # at the fleet's expected live context (docs/SERVING.md "Paged
 # serving").
@@ -936,9 +927,8 @@ BENCH_DECODE_CONFIGS = {
         "max_seqs": 8, "max_len": 1024, "prefill_len": 128,
         "block_size": 128, "num_blocks": 65, "mean_context": 160.0,
     },
-    # the speculative A/B leg: a DENSE engine (no block keys — the
-    # static check validates it against ServingEngine.__init__), small
-    # batch where decode is deepest into the memory-bound regime and
+    # the speculative A/B leg: the pool left to the engine's default,
+    # small batch where decode is deepest into the memory-bound regime and
     # speculation's k-tokens-per-step amortization reads clearest;
     # speculate_k >= 1 is enforced statically (k=0 would silently bench
     # the non-speculative path against itself)
@@ -949,208 +939,18 @@ BENCH_DECODE_CONFIGS = {
 }
 
 
-def bench_gpt_decode(iters=40, warmup=5, prefill_iters=5, max_len=1024,
-                     prefill_len=128, sat_slots=8, hidden=768, layers=12,
-                     heads=12, vocab=32768):
-    """Serving decode family (docs/SERVING.md): the GPT-small shape
-    through the AOT ``ServingEngine``, timing the compiled decode step
-    with its donated cache threaded call-to-call (the autoregressive
-    loop itself: sampled tokens feed back as the next step's input).
-
-    - ``gpt_decode_tok_per_sec_b1``: a ``max_seqs=1`` program, one
-      active sequence — the latency leg; 1/value is the per-token
-      interval a single user sees.
-    - ``gpt_decode_tok_per_sec_sat``: a ``max_seqs=sat_slots`` program
-      with every slot active — the throughput leg continuous batching
-      sustains at saturation.
-
-    ``vs_baseline`` is measured/roofline against the HBM-bound bound
-    (params read once per step + each active slot's LIVE cache stripe at
-    the measured mean length, over the chip's ``DeviceSpec`` bandwidth)
-    — necessarily < 1; the gap is the decode overhead. Known v1
-    contributor: the kernel's pipelined block fetches are max_len-shaped
-    (compute past the cursor is skipped, fetches are not), so expect the
-    gap to track mean_len/max_len until the bounded-grid variant lands
-    (docs/SERVING.md). Each line carries
-    ``temp_bytes``/``peak_hbm_bytes`` (decode program), the decode-step
-    pyprof attribution, and a ``prefill_step_ms`` + prefill attribution
-    so the prefill/decode split rides the bench history.
-
-    Each leg also drives a short continuous-batching run through the
-    SAME AOT engine (no extra compiles) and carries the per-request
-    latency percentiles off the ``serve/*`` histograms —
-    ``ttft_p50/p95/p99_ms``, ``tpot_p50/p95/p99_ms`` — plus ``goodput``
-    under the stated ``DECODE_SLO``; the sat leg's goodput is
-    additionally emitted as the ``gpt_decode_goodput`` line
-    (docs/SERVING.md "TTFT, TPOT and the SLO"). CPU numbers are
-    structural; read real latencies off a TPU run."""
-    from apex_tpu.models import GPTConfig, GPTModel
-    from apex_tpu.observability.costs import device_spec
-    from apex_tpu.observability.registry import MetricsRegistry
-    from apex_tpu.observability.reqtrace import (LATENCY_BUCKETS_MS,
-                                                 RequestTrace)
-    from apex_tpu.observability.slo import SLOTarget, SLOTracker
-    from apex_tpu.serving import Request, ServingEngine, SlotScheduler
-
-    cfg = GPTConfig(vocab_size=vocab, hidden_size=hidden,
-                    num_layers=layers, num_attention_heads=heads,
-                    max_position_embeddings=max_len,
-                    compute_dtype=jnp.bfloat16)
-    model = GPTModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    param_bytes = sum(l.size * l.dtype.itemsize
-                      for l in jax.tree_util.tree_leaves(params))
-    prompt = np.random.RandomState(0).randint(
-        1, vocab, size=prefill_len).tolist()
-    slo_targets = tuple(SLOTarget(m, q, t) for m, q, t in DECODE_SLO)
-
-    def serve_leg(eng, slots):
-        """A short continuous-batching run through the already-compiled
-        engine: real request latencies -> serve/* histogram percentiles
-        + goodput under DECODE_SLO. Rides the leg (no new config-budget
-        entry, no extra AOT compiles)."""
-        sreg = MetricsRegistry()
-        tracker = SLOTracker(slo_targets, registry=sreg,
-                             trace=RequestTrace(capacity=64),
-                             on_violation="skip")
-        sched = SlotScheduler(eng, registry=sreg,
-                              trace=tracker.trace, slo=tracker)
-        sched.run([Request(prompt=prompt[: 1 + (3 * i) % prefill_len],
-                           max_new_tokens=8)
-                   for i in range(2 * slots)])
-        extras = {}
-        for short, name in (("ttft", "serve/ttft_ms"),
-                            ("tpot", "serve/tpot_ms")):
-            hist = sreg.histogram(name, LATENCY_BUCKETS_MS)
-            extras.update({f"{short}_p{q}_ms":
-                           round(float(hist.percentile(q)), 3)
-                           for q in (50, 95, 99)})
-        extras["goodput"] = round(tracker.goodput(), 4)
-        extras["slo"] = "; ".join(t.describe() for t in slo_targets)
-        # resilience wiring (docs/SERVING.md "Resilience"): a burst at
-        # 4x the queue bound against the SAME compiled engine — the
-        # admission bound must hold with typed rejections while every
-        # ADMITTED request still completes (rides the leg: no new
-        # config-budget entry, no extra AOT compiles)
-        t2 = SLOTracker(slo_targets, registry=sreg, on_violation="skip")
-        over = SlotScheduler(eng, registry=sreg, slo=t2,
-                             max_queue=slots,
-                             default_deadline_ms=120000.0)
-        burst = [over.submit(Request(prompt=prompt[: 1 + i],
-                                     max_new_tokens=4))
-                 for i in range(4 * slots)]
-        over.run([])
-        snap = sreg.snapshot()
-        extras["rejected"] = int(snap.get("serve/rejected", 0.0))
-        extras["expired"] = int(snap.get("serve/expired", 0.0))
-        extras["overload_admitted_goodput"] = round(t2.goodput(), 4)
-        assert extras["rejected"] == sum(
-            1 for b in burst if not isinstance(b, int))
-        return extras
-
-    def measure(slots):
-        eng = ServingEngine(model, params, max_seqs=slots,
-                            max_len=max_len, prefill_len=prefill_len)
-        key = eng._next_key()
-        temps = jnp.zeros((slots,), jnp.float32)
-
-        # prefill leg: the compiled prefill threaded the _timeit way
-        # (slot 0 overwritten each call — timing, not generation)
-        ptok = eng.pad_prompt(prompt)
-        zero = jnp.asarray(0, jnp.int32)
-        plen = jnp.asarray(prefill_len, jnp.int32)
-
-        def pwrap(cache, tok):
-            cache, tok = eng.prefill_compiled(
-                params, cache, ptok, zero, plen, jnp.float32(0.0), key)
-            return cache, tok
-        ptimes = _timeit(pwrap, (eng.cache, jnp.asarray(0, jnp.int32)),
-                         prefill_iters, 1)
-        prefill_ms = float(np.mean(ptimes) * 1e3)
-        # the timing loop consumed the engine's donated cache outside its
-        # bookkeeping — give it a fresh one, then fill every slot so the
-        # decode leg runs fully active
-        from apex_tpu.serving import KVCache
-        eng.cache = KVCache.create(layers, slots, heads, max_len,
-                                   cfg.head_dim, dtype=jnp.bfloat16)
-        for s in range(slots):
-            eng.prefill(prompt, slot=s)
-
-        all_active = jnp.ones((slots,), jnp.bool_)
-
-        def dwrap(cache, toks):
-            cache, toks = eng.decode_compiled(params, cache, toks, temps,
-                                              all_active, key)
-            return cache, toks
-        times = _timeit(dwrap, (eng.cache, jnp.zeros((slots,),
-                                                     jnp.int32)),
-                        iters, warmup)
-        step_ms = float(np.mean(times) * 1e3)
-        tok_per_sec = slots / float(np.mean(times))
-
-        # HBM roofline: params once per step + each slot's K+V stripe at
-        # the mean decoded length (two dtype-width bytes per element)
-        mean_len = prefill_len + (warmup + iters) / 2.0
-        stripe = (2 * layers * heads * mean_len * cfg.head_dim
-                  * jnp.dtype(jnp.bfloat16).itemsize)
-        spec = device_spec()
-        step_bytes = param_bytes + slots * stripe
-        roofline = slots / (step_bytes / (spec.hbm_gbps * 1e9))
-        extras = dict(_mem_extra(eng.decode_compiled))
-        extras.update(_attrib_extra(eng.decode_traced, step_ms))
-        extras.update({f"prefill_{k}": v for k, v in _attrib_extra(
-            eng.prefill_traced, prefill_ms).items()
-            if k not in ("attribution", "step_time_ms")})
-        # request-lifecycle percentiles: the timing loop consumed the
-        # donated cache again — fresh one, then a real scheduler run on
-        # the same compiled programs
-        eng.cache = KVCache.create(layers, slots, heads, max_len,
-                                   cfg.head_dim, dtype=jnp.bfloat16)
-        extras.update(serve_leg(eng, slots))
-        return (tok_per_sec, roofline, step_ms,
-                float(np.std(times) * 1e3), prefill_ms, extras)
-
-    goodput = None
-    for metric, slots in (("gpt_decode_tok_per_sec_b1", 1),
-                          ("gpt_decode_tok_per_sec_sat", sat_slots)):
-        tps, roof, step_ms, std_ms, prefill_ms, extras = measure(slots)
-        goodput = extras["goodput"]
-        _emit(metric, tps, "tokens/sec", tps / roof,
-              anchor="hbm_roofline_this_chip",
-              roofline_tok_per_sec=round(roof, 2),
-              step_ms=round(step_ms, 3), std_ms=round(std_ms, 3),
-              prefill_step_ms=round(prefill_ms, 3),
-              slots=slots, max_len=max_len, prefill_len=prefill_len,
-              iters=iters, **extras)
-    # the headline goodput row: the saturating grid scored under the
-    # stated SLO (100 = every request met every target; the serving
-    # quality number next to the serving speed numbers above). Emitted
-    # in PERCENT: _emit rounds value to 2 decimals, and against a 1%
-    # p99 error budget a fraction would quantize away sub-0.5%
-    # violation rates (0.996 would read as a perfect 1.0)
-    _emit("gpt_decode_goodput", goodput * 100.0, "percent", None,
-          slo="; ".join(t.describe() for t in slo_targets),
-          slots=sat_slots, max_len=max_len, prefill_len=prefill_len)
-
-
 def bench_gpt_decode_paged(iters=20, warmup=3, prefix_reps=5, hidden=768,
                            layers=12, heads=12, vocab=32768):
     """Paged serving legs (docs/SERVING.md "Paged serving"): the same
-    GPT-small shape through the AOT ``PagedServingEngine`` — block-pool
+    GPT-small shape through the AOT ``ServingEngine`` — block-pool
     KV cache, bounded-grid decode kernel, copy-on-write prefix sharing.
     The engine config is the declarative
     ``BENCH_DECODE_CONFIGS["gpt_decode_paged"]`` entry, statically
     validated by scripts/check_bench_configs.py.
 
-    - ``gpt_decode_tok_per_sec_paged``: every slot of the paged grid
-      active — the throughput twin of ``gpt_decode_tok_per_sec_sat``,
-      same slots/max_len/prefill_len so the delta isolates the paged
-      machinery. ``vs_baseline`` is measured/roofline over the same
-      live-stripe HBM bound as the dense legs; ``modeled_hbm_ratio``
-      carries the pyprof-modeled ``decode_attention`` HBM of this
-      program over the dense engine's — the O(actual_context) vs
-      O(max_len) gap the bounded grid closes (expect it to track
-      ``mean_context / max_len``).
+    - ``gpt_decode_tok_per_sec_paged``: every slot of the grid
+      active. ``vs_baseline`` is measured/roofline over the
+      live-stripe HBM bound at the actual mean context.
     - ``gpt_decode_ttft_prefix_ms``: prefill latency for a prompt whose
       prefix is already registered in the pool (maps the shared blocks,
       decodes only the un-shared tail) vs the same-length cold path.
@@ -1161,9 +961,8 @@ def bench_gpt_decode_paged(iters=20, warmup=3, prefix_reps=5, hidden=768,
     latencies off a TPU run."""
     from apex_tpu.models import GPTConfig, GPTModel
     from apex_tpu.observability.costs import device_spec
-    from apex_tpu.pyprof import model_program
     from apex_tpu.serving import (BlockAllocator, PagedKVCache,
-                                  PagedServingEngine, ServingEngine)
+                                  ServingEngine)
 
     spec = dict(BENCH_DECODE_CONFIGS["gpt_decode_paged"])
     slots, max_len = spec["max_seqs"], spec["max_len"]
@@ -1177,7 +976,7 @@ def bench_gpt_decode_paged(iters=20, warmup=3, prefix_reps=5, hidden=768,
     param_bytes = sum(l.size * l.dtype.itemsize
                       for l in jax.tree_util.tree_leaves(params))
     rs = np.random.RandomState(0)
-    eng = PagedServingEngine(model, params, **spec)
+    eng = ServingEngine(model, params, **spec)
 
     # --- TTFT leg first (the throughput _timeit consumes the donated
     # cache outside the engine's bookkeeping) ---
@@ -1234,8 +1033,8 @@ def bench_gpt_decode_paged(iters=20, warmup=3, prefix_reps=5, hidden=768,
     step_ms = float(np.mean(times) * 1e3)
     tok_per_sec = slots / float(np.mean(times))
 
-    # the same live-stripe roofline as the dense legs, at the ACTUAL
-    # mean context — the paged step's HBM target, not max_len's
+    # the live-stripe roofline at the ACTUAL mean context — the step's
+    # HBM target, not max_len's
     mean_len = float(np.mean(np.asarray(alloc.lengths)))
     stripe = (2 * layers * heads * mean_len * cfg.head_dim
               * jnp.dtype(jnp.bfloat16).itemsize)
@@ -1245,22 +1044,6 @@ def bench_gpt_decode_paged(iters=20, warmup=3, prefix_reps=5, hidden=768,
 
     extras = dict(_mem_extra(eng.decode_compiled))
     extras.update(_attrib_extra(eng.decode_traced, step_ms))
-    # the modeled attention-HBM gap: this program's decode_attention
-    # bytes over the dense engine's at identical shapes — the number
-    # the bounded grid exists to shrink (CostEstimate-priced, so it
-    # reflects the clamped grid, not the dense worst case)
-    try:
-        dense = ServingEngine(model, params, max_seqs=slots,
-                              max_len=max_len, prefill_len=prefill_len)
-        paged_hbm = model_program(
-            eng.decode_traced).regions["decode_attention"].hbm_bytes
-        dense_hbm = model_program(
-            dense.decode_traced).regions["decode_attention"].hbm_bytes
-        if dense_hbm > 0:
-            extras["modeled_hbm_ratio"] = round(paged_hbm / dense_hbm, 4)
-    except Exception:
-        pass
-
     _emit("gpt_decode_tok_per_sec_paged", tok_per_sec, "tokens/sec",
           tok_per_sec / roofline, anchor="hbm_roofline_this_chip",
           roofline_tok_per_sec=round(roofline, 2),
@@ -1279,7 +1062,7 @@ def bench_gpt_decode_spec(new_tokens=48, requests=8, hidden=768,
                           layers=12, heads=12, vocab=32768):
     """Speculative-decoding A/B (docs/SERVING.md "Speculative
     decoding"): the SAME GPT-small weights and request set through a
-    non-speculative dense engine and a ``speculate_k`` one
+    non-speculative engine and a ``speculate_k`` one
     (``BENCH_DECODE_CONFIGS["gpt_decode_spec"]``), both driven by the
     full scheduler loop so the number includes the host drafting cost.
 
@@ -1397,19 +1180,17 @@ def main():
         # the multi-compile configs run LAST, newest first to be starved:
         # sp_ovl (two GPT TP=2 compiles) after the longer-tracked configs
         # above it, remat (FOUR GPT-small train-step compiles) next,
-        # gpt_fast (two full hybrid-trainer compiles) after that, and
-        # gpt_decode (two serving engines = four AOT compiles) next,
-        # and gpt_decode_paged (one paged engine = three AOT compiles
-        # plus a dense twin for the modeled-HBM ratio) next, and
-        # gpt_decode_spec (two dense engines = seven AOT compiles for
+        # gpt_fast (two full hybrid-trainer compiles) after that,
+        # gpt_decode_paged (one engine = three AOT compiles) next, and
+        # gpt_decode_spec (two engines = seven AOT compiles for
         # the speculative A/B, the newest leg) dead last so a tight
         # budget drops the newest metrics, never the established
         # baseline rows
         for fn in (bench_layernorm, bench_optimizer, bench_gpt,
                    bench_flash_long, bench_dp_accumulate_overlap,
                    bench_gpt_sp_overlap, bench_gpt_remat,
-                   bench_gpt_fast, bench_gpt_decode,
-                   bench_gpt_decode_paged, bench_gpt_decode_spec):
+                   bench_gpt_fast, bench_gpt_decode_paged,
+                   bench_gpt_decode_spec):
             if time.perf_counter() - t0 > budget_s:
                 _emit(fn.__name__, -1.0, "skipped", None,
                       error="config budget exhausted; headline protected")

@@ -24,25 +24,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# GPT-small: BENCH_TRAIN_CONFIGS["gpt_base"] / bench_gpt_decode (bench.py)
+# GPT-small: BENCH_TRAIN_CONFIGS["gpt_base"] / BENCH_DECODE_CONFIGS (bench.py)
 MODEL = dict(vocab_size=32768, hidden_size=768, num_layers=12,
              num_attention_heads=12, max_position_embeddings=1024)
 SEQ = 1024
 MICROBATCHES, MICRO_BATCH = 2, 4          # 2 x 4 x 1024 tokens per step
 TRAIN_STEPS, SHARDED_STEPS = 5, 3
 SERVE = dict(max_seqs=8, max_len=1024, prefill_len=128)
-PAGED = dict(block_size=128, num_blocks=65)   # 8 slots x 8 blocks + null
+# the pool an engine sizes for SERVE by itself: blocks of gcd(128, 128)
+# tokens, 8 slots x 8 blocks + the null block
+BLOCK_SIZE, NUM_BLOCKS = 128, 65
 SPECULATE_K = 4
 N_REQUESTS, PROMPT_LEN, NEW_TOKENS = 16, (16, 128), (8, 64)
 REF_TOKENS = 16                # request 0's tokens checked free-running
 # docs/SERVING.md "Tolerances": kernel decode vs one-shot forward agree
 # within 0.05 logit units at bf16 — bitwise identity is not the contract
 LOGIT_TOL = 0.05
-# two engines may part only where the reference's own logits for the two
-# tokens are this close (largest gap seen at a fork: 0.0095, PERF.md PR 22)
+# an engine may part from the reference's greedy stream only where the
+# reference's own logits for the two tokens are this close (largest gap
+# seen at a fork: 0.0095, PERF.md PR 22)
 FORK_TOL = 0.02
-# a bf16 decode kernel vs the same function's XLA path on randn inputs
-# (tests/test_serving.py, tests/test_paged.py: atol 2e-2)
+# the bf16 decode kernel vs the same function's XLA path on randn inputs
+# (tests/test_paged.py: atol 2e-2)
 KERNEL_TOL = 2e-2
 SEED = 0
 
@@ -178,18 +181,17 @@ def sharded_phase():
 
 
 def kernel_phase():
-    """The decode kernels' arithmetic at the serving shape: each Pallas
+    """The decode kernel's arithmetic at the serving shape: the Pallas
     kernel against the same function's XLA path (``use_pallas=False``) on
-    one seeded cache — dense and paged, ``q_len`` 1 (decode) and
-    ``SPECULATE_K + 1`` (verify), bf16 and int8 KV. The serve phase's
-    argmax of a random-weight model barely moves with the attention
-    context; this does."""
-    from apex_tpu.ops.flash_attention import (decode_attention,
-                                              paged_decode_attention)
+    one seeded pool — ``q_len`` 1 (decode) and ``SPECULATE_K + 1``
+    (verify), bf16 and int8 KV. The serve phase's argmax of a
+    random-weight model barely moves with the attention context; this
+    does."""
+    from apex_tpu.ops.flash_attention import paged_decode_attention
     S, T = SERVE["max_seqs"], SERVE["max_len"]
     H = MODEL["num_attention_heads"]
     D = MODEL["hidden_size"] // H
-    bs, nb = PAGED["block_size"], PAGED["num_blocks"]
+    bs, nb = BLOCK_SIZE, NUM_BLOCKS
     rng = np.random.RandomState(SEED + 2)
     # every slot's blocks scattered through the pool, never block 0 (null)
     tables = rng.permutation(np.arange(1, nb)).reshape(S, T // bs)
@@ -199,12 +201,6 @@ def kernel_phase():
     check(len(lengths) == S and max(lengths) < T, "kernel_phase's cursors "
           f"are written for 8 slots of >= 6 blocks, not {S} x {T // bs}")
     lengths = jnp.asarray(lengths, jnp.int32)
-
-    def dense_of(pool):        # (nb, bs, H*D) -> (S, H, T, D)
-        return pool[tables].reshape(S, T, H, D).transpose(0, 2, 1, 3)
-
-    def dense_scale_of(scale):  # (nb, H, bs) -> (S, H, T)
-        return jnp.moveaxis(scale[tables], 2, 1).reshape(S, H, T)
 
     def quantize(x):           # per-(position, head) symmetric int8
         scale = np.maximum(np.abs(x).max(-1) / 127.0, 1e-8)
@@ -230,35 +226,27 @@ def kernel_phase():
 
     diffs = {}
     for kv, (kp, vp, ksc, vsc) in pools.items():
-        dense_scales = (None, None) if ksc is None else (
-            dense_scale_of(ksc), dense_scale_of(vsc))
         for q_len in (1, SPECULATE_K + 1):
             shape = (S, H, D) if q_len == 1 else (S, H, q_len, D)
             q, k_new, v_new = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
                                for _ in "qkv")
-            calls = {
-                "dense": (decode_attention,
-                          (q, dense_of(kp), dense_of(vp), lengths, k_new,
-                           v_new, *dense_scales)),
-                "paged": (functools.partial(paged, ksc=ksc, vsc=vsc),
-                          (q, kp, vp, tables, lengths, k_new, v_new)),
-            }
-            for layout, (fn, args) in calls.items():
-                case = f"{layout}/q{q_len}/{kv}"
-                outs = {}
-                for use in (True, False):
-                    prog = jax.jit(functools.partial(
-                        fn, use_pallas=use)).lower(*args).compile()
-                    check(("tpu_custom_call" in prog.as_text()) == use,
-                          f"{case}: use_pallas={use} compiled the other "
-                          "attention path")
-                    outs[use] = np.asarray(block(prog(*args)), np.float32)
-                check(np.isfinite(outs[True]).all(),
-                      f"{case}: the kernel returned a non-finite value")
-                diffs[case] = float(np.abs(outs[True] - outs[False]).max())
-                check(diffs[case] <= KERNEL_TOL,
-                      f"{case}: kernel and XLA path differ by "
-                      f"{diffs[case]:.4g} (tolerance {KERNEL_TOL})")
+            args = (q, kp, vp, tables, lengths, k_new, v_new)
+            case = f"paged/q{q_len}/{kv}"
+            outs = {}
+            for use in (True, False):
+                prog = jax.jit(functools.partial(
+                    paged, ksc=ksc, vsc=vsc,
+                    use_pallas=use)).lower(*args).compile()
+                check(("tpu_custom_call" in prog.as_text()) == use,
+                      f"{case}: use_pallas={use} compiled the other "
+                      "attention path")
+                outs[use] = np.asarray(block(prog(*args)), np.float32)
+            check(np.isfinite(outs[True]).all(),
+                  f"{case}: the kernel returned a non-finite value")
+            diffs[case] = float(np.abs(outs[True] - outs[False]).max())
+            check(diffs[case] <= KERNEL_TOL,
+                  f"{case}: kernel and XLA path differ by "
+                  f"{diffs[case]:.4g} (tolerance {KERNEL_TOL})")
     say(phase="kernels", slots=S, heads=H, max_len=T, head_dim=D,
         block_size=bs, max_abs_diff_kernel_vs_xla=diffs,
         worst=max(diffs.values()))
@@ -337,31 +325,32 @@ def timed_ms(fn, n):
 def serve_phase():
     from apex_tpu.models import GPTConfig, GPTModel
     from apex_tpu.observability.registry import MetricsRegistry
-    from apex_tpu.serving import (PagedServingEngine, ServingEngine,
-                                  SlotScheduler)
+    from apex_tpu.serving import ServingEngine, SlotScheduler
 
     model = GPTModel(GPTConfig(**MODEL))
     params = block(model.init(jax.random.PRNGKey(SEED)))
     reference = Reference(params)
     requests = seeded_requests()
 
+    # the pool left to the engine: every slot can reach max_len
     engines = {
-        "dense": lambda: ServingEngine(
+        "paged": lambda: ServingEngine(
             model, params, cache_dtype=jnp.bfloat16, **SERVE),
-        "paged": lambda: PagedServingEngine(
-            model, params, cache_dtype=jnp.bfloat16, **SERVE, **PAGED),
-        "dense_spec": lambda: ServingEngine(
+        "paged_spec": lambda: ServingEngine(
             model, params, cache_dtype=jnp.bfloat16,
             speculate_k=SPECULATE_K, **SERVE),
-        "paged_spec": lambda: PagedServingEngine(
-            model, params, cache_dtype=jnp.bfloat16,
-            speculate_k=SPECULATE_K, **SERVE, **PAGED),
     }
-    streams = {}
+    free = reference.greedy(requests[0].prompt, REF_TOKENS)
+    forks = {}
     for name, build in engines.items():
         t0 = time.perf_counter()
         engine = build()
         compile_s = time.perf_counter() - t0
+        check((engine.block_size, engine.num_blocks)
+              == (BLOCK_SIZE, NUM_BLOCKS),
+              f"{name}: the default pool is {engine.num_blocks} blocks of "
+              f"{engine.block_size}, the kernel phase checked "
+              f"{NUM_BLOCKS} of {BLOCK_SIZE}")
         paths = engine.attention_paths()
         check(all(p == "pallas" for p in paths.values()),
               f"{name}: an AOT program fell back to XLA attention: {paths}")
@@ -376,19 +365,45 @@ def serve_phase():
         reasons = {c.finish_reason for c in done.values()}
         check(reasons <= {"length", "eos"},
               f"{name}: finish reasons {reasons}")
-        streams[name] = {i: c.tokens for i, c in done.items()}
-        check(all(len(streams[name][r.request_id]) == r.max_new_tokens
+        streams = {i: c.tokens for i, c in done.items()}
+        check(all(len(streams[r.request_id]) == r.max_new_tokens
                   for r in requests), f"{name}: a stream is short")
-        ref_logits = reference.teacher_forced(requests, streams[name])
-        regret = np.concatenate([regrets(ref_logits[i], streams[name][i])
-                                 for i in sorted(done)])
-        worst, exact, total = (float(regret.max()),
-                               int((regret == 0).sum()), len(regret))
+        ref_logits = reference.teacher_forced(requests, streams)
+        regret = {i: regrets(ref_logits[i], streams[i]) for i in done}
+        every = np.concatenate([regret[i] for i in sorted(done)])
+        worst, exact, total = (float(every.max()),
+                               int((every == 0).sum()), len(every))
         check(worst <= LOGIT_TOL,
               f"{name}: an emitted token sits {worst:.4f} logit units "
               f"below the reference's best (tolerance {LOGIT_TOL})")
-        if name == "dense":
-            dense_logits = ref_logits
+        # The reference's greedy stream — or, where the engine parts from
+        # it, it parts at a position the REFERENCE itself calls a tie (its
+        # logits for the two tokens within FORK_TOL). Up to a request's
+        # first token that is not the reference's argmax the two streams
+        # are one, so that token is where they part and its regret is the
+        # gap. The paths reduce in different orders (one softmax vs KV
+        # blocks of 128, 1 vs k+1 query rows), greedy argmax in bf16 is
+        # tie-sensitive, and after a fork the stream answers to the
+        # reference token by token (checked above).
+        forks[name] = []
+        for i in sorted(done):
+            parted = np.flatnonzero(regret[i] > 0)
+            if not len(parted):
+                continue
+            at, gap = int(parted[0]), float(regret[i][parted[0]])
+            check(gap <= FORK_TOL,
+                  f"{name} and the reference's greedy stream part at token "
+                  f"{at} of request {i}, where the reference is NOT tied "
+                  f"(gap {gap:.4f}, tolerance {FORK_TOL})")
+            forks[name].append(dict(request=i, token=at, reference_gap=gap))
+        # ... and request 0's, run free, is that stream as far as it goes
+        same = next((p for p, (a, b) in enumerate(zip(free, streams[0]))
+                     if a != b), min(len(free), len(streams[0])))
+        first = next((f["token"] for f in forks[name] if f["request"] == 0),
+                     len(streams[0]))
+        check(same == min(first, len(free)),
+              f"{name}: request 0 leaves the free-running reference at "
+              f"token {same}, its teacher-forced logits say {first}")
         counters = {k: v for k, v in registry.snapshot().items()
                     if k.startswith("serve/") and "_bucket_le_" not in k
                     and not k.endswith(("_ms_sum", "_ms_count"))}
@@ -396,12 +411,18 @@ def serve_phase():
                     build_and_compile_s=compile_s, run_s=run_s,
                     tokens=total, reference_argmax_matches=exact,
                     worst_reference_regret=worst, counters=counters)
-        if name == "dense":
+        if name == "paged":
             # the engine is idle again: time the two programs directly
             # (both return host values, so each call is a full round trip)
             prompt = requests[0].prompt
-            line["prefill_ms"] = timed_ms(
-                lambda: engine.prefill(prompt, 0), 5)
+
+            def prefill_once():
+                engine.prefill(prompt, 0)
+                check(engine.last_admit.prefill, "a timed prefill was "
+                      "served from the prefix index")
+                engine.release_slot(0)
+
+            line["prefill_and_release_ms"] = timed_ms(prefill_once, 5)
             toks = np.zeros(SERVE["max_seqs"], np.int32)
             temps = np.zeros(SERVE["max_seqs"], np.float32)
             line["decode_ms"] = timed_ms(
@@ -409,32 +430,8 @@ def serve_phase():
         say(**line)
         del engine, sched
 
-    # The same greedy stream everywhere — or, where two streams part, they
-    # part at a position the REFERENCE itself calls a tie (its logits for
-    # the two tokens within FORK_TOL). The paths reduce in different
-    # orders (one softmax vs KV blocks of 512 vs 128, 1 vs k+1 query rows),
-    # greedy argmax in bf16 is tie-sensitive, and after a fork each stream
-    # answers to the reference on its own (checked above).
-    def fork(i, other, who):
-        base = streams["dense"][i][:len(other)]
-        if other == base:
-            return None
-        at = next(p for p, (a, b) in enumerate(zip(base, other)) if a != b)
-        gap = abs(float(dense_logits[i][at, base[at]]
-                        - dense_logits[i][at, other[at]]))
-        check(gap <= FORK_TOL,
-              f"{who} and the dense engine part at token {at} of request "
-              f"{i}, where the reference is NOT tied (gap {gap:.4f}, "
-              f"tolerance {FORK_TOL})")
-        return dict(request=i, token=at, reference_gap=gap)
-
-    free = reference.greedy(requests[0].prompt, REF_TOKENS)
-    forks = {"reference_greedy_request0": fork(0, free, "reference greedy")}
-    for name in ("paged", "dense_spec", "paged_spec"):
-        forks[name] = [f for f in (fork(i, streams[name][i], name)
-                                   for i in sorted(streams[name])) if f]
     say(phase="serve", request0_reference_greedy=free,
-        forks_from_dense_at_reference_ties=forks)
+        forks_from_reference_greedy=forks)
 
 
 def main(argv=None):

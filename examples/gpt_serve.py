@@ -148,8 +148,8 @@ def main(argv=None):
             and not k.endswith(("_count", "_sum"))}
     print("serve/* summary:", {k: round(v, 1) for k, v in snap.items()})
 
-    # the latency/SLO summary: percentiles off the serve/*_ms histograms
-    # (the same readout bench_gpt_decode ships), goodput off the tracker.
+    # the latency/SLO summary: percentiles off the serve/*_ms histograms,
+    # goodput off the tracker.
     # LATENCY_BUCKETS_MS matters on the get-or-create: a histogram the
     # scheduler never touched (tpot with --max-new-tokens 1) must still
     # land on the documented latency grid, not DEFAULT_BUCKETS

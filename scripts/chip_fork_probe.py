@@ -1,18 +1,15 @@
-"""Why greedy streams part between the serving engines on the chip.
+"""Why greedy streams part between the plain and the speculative engine
+on the chip.
 
-``chip_smoke.py`` finds the dense, paged and speculative engines emitting
-different tokens at positions where the plain reference is nearly tied
-(PERF.md, PR 22). This probe separates the candidate causes. One chip, one
-process, the requests and weights of ``chip_smoke.py``:
+``chip_smoke.py`` finds the engine emitting, with and without
+speculation, different tokens at positions where the plain reference is
+nearly tied (PERF.md, PR 22). This probe separates the candidate causes.
+One chip, one process, the requests and weights of ``chip_smoke.py``:
 
 - ``kernels``:  the engines as ``chip_smoke.py`` builds them.
 - ``xla``:      the same engines on ``GPTConfig(use_flash=False)`` — no
-                kernel anywhere, so no KV-block reduction order; the paged
-                engine gathers its blocks into the dense layout and runs
-                the dense math.
-- ``block128``: the dense kernel streaming 128-wide cache blocks like the
-                paged one (it picks 512 at max_len 1024). Steered from
-                here; the program has no such option.
+                kernel anywhere, so no KV-block reduction order: the
+                blocks are gathered slot-major and scored in one pass.
 - ``f32cache``: kernels, fp32 KV cache — no rounding at the cache store.
 - ``gemm_rows``: one set of 8 rows through the model's GEMM shapes alone
                 and inside 40 rows (decode sees 8 rows, verify 8 x 5).
@@ -20,7 +17,6 @@ process, the requests and weights of ``chip_smoke.py``:
     python scripts/chip_fork_probe.py     # needs a TPU; prints JSON lines
 """
 
-import importlib
 import os
 import sys
 
@@ -47,18 +43,12 @@ def parted(a, b):
             for i in sorted(a) if a[i] != b[i]]
 
 
-def engine_runs(model, params, cache_dtype, names):
-    from apex_tpu.serving import PagedServingEngine, ServingEngine
+def engine_runs(model, params, cache_dtype):
+    from apex_tpu.serving import ServingEngine
     kw = dict(cache_dtype=cache_dtype, **cs.SERVE)
-    build = {
-        "dense": lambda: ServingEngine(model, params, **kw),
-        "paged": lambda: PagedServingEngine(model, params, **kw, **cs.PAGED),
-        "dense_spec": lambda: ServingEngine(
-            model, params, speculate_k=cs.SPECULATE_K, **kw),
-    }
     out = {}
-    for name in names:
-        engine = build[name]()
+    for name, k in (("paged", 0), ("paged_spec", cs.SPECULATE_K)):
+        engine = ServingEngine(model, params, speculate_k=k, **kw)
         out[name] = streams_of(engine), engine.attention_paths()
         del engine
     return out
@@ -107,7 +97,6 @@ def probe():
     model = GPTModel(GPTConfig(**cs.MODEL))
     params = cs.block(model.init(jax.random.PRNGKey(cs.SEED)))
     bf16 = jnp.bfloat16
-    all_three = ("dense", "paged", "dense_spec")
 
     def report(variant, runs, base):
         cs.say(variant=variant,
@@ -115,28 +104,17 @@ def probe():
                requests_parted_from=base[0],
                parted={n: parted(base[1], s) for n, (s, _) in runs.items()})
 
-    kernels = engine_runs(model, params, bf16, all_three)
-    dense = ("kernels/dense", kernels["dense"][0])
-    report("kernels", kernels, dense)
+    kernels = engine_runs(model, params, bf16)
+    paged = ("kernels/paged", kernels["paged"][0])
+    report("kernels", kernels, paged)
 
     plain = GPTModel(GPTConfig(use_flash=False, **cs.MODEL))
-    xla = engine_runs(plain, params, bf16, all_three)
-    report("xla", xla, ("xla/dense", xla["dense"][0]))
-    report("xla_vs_kernels", xla, dense)
+    xla = engine_runs(plain, params, bf16)
+    report("xla", xla, ("xla/paged", xla["paged"][0]))
+    report("xla_vs_kernels", xla, paged)
 
-    fa = importlib.import_module("apex_tpu.ops.flash_attention")
-    auto_block = fa._auto_block
-    fa._auto_block = lambda seq, choices=None: auto_block(seq, (128,))
-    try:
-        block128 = engine_runs(model, params, bf16, ("dense",))
-    finally:
-        fa._auto_block = auto_block
-    report("block128_vs_dense512", block128, dense)
-    report("block128_vs_paged", block128,
-           ("kernels/paged", kernels["paged"][0]))
-
-    f32 = engine_runs(model, params, jnp.float32, all_three)
-    report("f32cache", f32, ("f32cache/dense", f32["dense"][0]))
+    f32 = engine_runs(model, params, jnp.float32)
+    report("f32cache", f32, ("f32cache/paged", f32["paged"][0]))
 
     cs.say(variant="gemm_rows", rows=[8, 40], report=gemm_rows(model, params))
 

@@ -647,11 +647,11 @@ def test_jaxpr_shared_finding_points_at_the_fix():
 
 class TestDonation:
     def test_shared_kvcache_scale_plane_detected(self):
-        """The literal PR 9 bug, rebuilt: an int8 KVCache whose k/v
+        """The literal PR 9 bug, rebuilt: an int8 pool whose k/v
         scale planes are the SAME buffer double-donates it."""
         import dataclasses
-        from apex_tpu.serving.cache import KVCache
-        cache = KVCache.create(1, 2, 2, 8, 4, dtype=jnp.int8)
+        from apex_tpu.serving.cache import PagedKVCache
+        cache = PagedKVCache.create(1, 3, 2, 8, 4, dtype=jnp.int8)
         assert not check_donation(donated_args=cache)  # create() is safe
         broken = dataclasses.replace(cache, v_scale=cache.k_scale)
         findings = check_donation(donated_args=broken)

@@ -86,33 +86,18 @@ def _flash_loss(dropout_rate):
     return jax.grad(loss, argnums=(0, 1, 2))
 
 
-def _dense(q_len, quantized):
-    qs = (SLOTS, H, D) if q_len == 1 else (SLOTS, H, q_len, D)
-    cache = (SLOTS, H, MAX_LEN, D)
-    shapes = [(qs, BF16), (cache, I8 if quantized else BF16),
-              (cache, I8 if quantized else BF16), ((SLOTS,), I32),
-              (qs, BF16), (qs, BF16)]
-    if quantized:
-        shapes += [((SLOTS, H, MAX_LEN), F32)] * 2
-
-    def f(q, k, v, lengths, k_new, v_new, k_scale=None, v_scale=None):
-        return fa.decode_attention(q, k, v, lengths, k_new=k_new,
-                                   v_new=v_new, k_scale=k_scale,
-                                   v_scale=v_scale, use_pallas=True)
-    return f, shapes
-
-
-def _paged(q_len, quantized):
+def _paged(q_len, quantized, heads=H, head_dim=D, block=BLOCK):
     # the pool as PagedKVCache stores it, all layers stacked; the kernel
     # is aimed at one of them by a traced index, as in the layer scan
-    qs = (SLOTS, H, D) if q_len == 1 else (SLOTS, H, q_len, D)
-    pool = (LAYERS, NUM_BLOCKS, BLOCK, H * D)
+    qs = (SLOTS, heads, head_dim) if q_len == 1 \
+        else (SLOTS, heads, q_len, head_dim)
+    pool = (LAYERS, NUM_BLOCKS, block, heads * head_dim)
     shapes = [(qs, BF16), (pool, I8 if quantized else BF16),
               (pool, I8 if quantized else BF16), ((), I32),
-              ((SLOTS, MAX_LEN // BLOCK), I32), ((SLOTS,), I32),
+              ((SLOTS, MAX_LEN // block), I32), ((SLOTS,), I32),
               (qs, BF16), (qs, BF16)]
     if quantized:
-        shapes += [((LAYERS, NUM_BLOCKS, H, BLOCK), F32)] * 2
+        shapes += [((LAYERS, NUM_BLOCKS, heads, block), F32)] * 2
 
     def f(q, kp, vp, layer, tables, lengths, k_new, v_new, k_scale=None,
           v_scale=None):
@@ -127,12 +112,13 @@ CASES = {
     "flash_fwd": lambda: (_flash_fwd, QKV),
     "flash_fwd_bwd": lambda: (_flash_loss(0.0), QKV),
     "flash_fwd_bwd_dropout": lambda: (_flash_loss(0.1), QKV),
-    "dense_decode_q1": lambda: _dense(1, False),
-    "dense_verify_q5": lambda: _dense(VERIFY_Q, False),
-    "dense_decode_int8": lambda: _dense(1, True),
     "paged_decode_q1": lambda: _paged(1, False),
     "paged_verify_q5": lambda: _paged(VERIFY_Q, False),
     "paged_decode_int8": lambda: _paged(1, True),
+    # no shape is refused: 3 heads x 40 = 120 lanes of 128, blocks of 8
+    # tokens (what an engine's default block is under a bucket of 8)
+    "paged_decode_off_lanes": lambda: _paged(1, False, heads=3, head_dim=40,
+                                             block=8),
 }
 
 

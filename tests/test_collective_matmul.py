@@ -223,7 +223,7 @@ def test_jaxpr_ring_decomposition_primitives(mesh_tp):
                          in_specs=(P(None, "tensor", None), P("tensor")),
                          out_specs=P(None, None, "tensor"))(x, w)
 
-    c = _census(str(jax.make_jaxpr(fwd)(x, w)))
+    c = _census(jax.make_jaxpr(fwd)(x, w))
     assert c == {"ppermute": tp - 1, "all_gather": 0, "reduce_scatter": 0}
 
     # fwd+bwd: the backward ring (RS of dX) adds its own tp-1 ppermutes
@@ -235,7 +235,7 @@ def test_jaxpr_ring_decomposition_primitives(mesh_tp):
                          in_specs=(P(None, "tensor", None), P("tensor")),
                          out_specs=P())(x, w)
 
-    c = _census(str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, w)))
+    c = _census(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, w))
     assert c == {"ppermute": 2 * (tp - 1), "all_gather": 0,
                  "reduce_scatter": 0}
 
@@ -267,8 +267,8 @@ def test_jaxpr_ring_decomposition_wired_layers(mesh_tp2):
                                    P(None, "tensor", None)),
                          out_specs=P())(cp, rp, x)
 
-    c = _census(str(jax.make_jaxpr(
-        jax.grad(loss, argnums=(0, 1)))(cp, rp, x)))
+    c = _census(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1)))(cp, rp, x))
     # fwd: col ring + row ring; bwd: col dX ring + row dX ring
     assert c == {"ppermute": 4 * (tp - 1), "all_gather": 0,
                  "reduce_scatter": 0}, c
@@ -359,11 +359,18 @@ def test_gpt_sp_overlap_matches_sp_and_tp(mesh_tp2):
     loss_sp, g_sp = jax.jit(lambda p, t: run(m_sp, p, t))(params, tokens)
     loss_ov, g_ov = jax.jit(lambda p, t: run(m_ov, p, t))(params, tokens)
 
-    # overlap vs fused SP: bit-identical at tp=2 (loss AND every grad leaf)
-    assert float(loss_ov) == float(loss_sp)
+    # overlap vs fused SP at tp=2: the same two partial products summed
+    # (a + b is b + a), so the loss and every grad leaf agree to a few
+    # ULP of the leaf's largest entry. Not to the bit: the ring's chunked
+    # GEMMs have other shapes than the fused one, and a backend's GEMM may
+    # block its K-sum by shape (the layer-level test above: <= 1 ULP)
+    ulp = float(np.finfo(np.float32).eps)
+    np.testing.assert_allclose(float(loss_ov), float(loss_sp), rtol=2 * ulp)
     for a, b in zip(jax.tree_util.tree_leaves(g_ov),
                     jax.tree_util.tree_leaves(g_sp)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=0,
+            atol=4 * ulp * float(np.abs(np.asarray(b)).max()))
     # overlap vs plain TP: the existing SP-vs-TP tolerance contract
     np.testing.assert_allclose(float(loss_ov), float(loss_tp), rtol=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(g_ov),

@@ -100,7 +100,7 @@ def test_gpt_pretrain_elastic_checkpoint_and_resume(tmp_path):
 def test_gpt_serve_runs(tmp_path):
     """The serving demo: every request completes through the continuous
     batcher, the serve/* surface is populated, and the
-    percentile/goodput summary (the bench_gpt_decode vocabulary) plus
+    percentile/goodput summary plus
     the per-slot Chrome request trace come out (docs/SERVING.md)."""
     import gpt_serve
     trace_path = tmp_path / "req_trace.json"

@@ -390,7 +390,13 @@ def test_fastpath_parity_with_plain_trainer():
     l_ref, s_ref = run(_cfg(dp=dp))
     l_fast, s_fast = run(_cfg(dp=dp).fastpath(bucket_bytes=1024))
     np.testing.assert_allclose(l_fast, l_ref, rtol=1e-6, atol=1e-7)
+    # Two Adam steps at lr 1e-2 move an entry by up to 2e-2; the params
+    # are held to a thousandth of that. Tighter is not the schedule's to
+    # give: where an entry's gradient is itself rounding noise (|g| ~ eps
+    # = 1e-8: biases that start at zero) the normalised step
+    # m / (sqrt(v) + eps) turns the last bits of g, which a bucketed
+    # reduce-scatter sums in another order, into any step up to lr.
     for pa, pb in zip(jax.tree_util.tree_leaves((s_ref[0], s_ref[1])),
                       jax.tree_util.tree_leaves((s_fast[0], s_fast[1]))):
         np.testing.assert_allclose(np.asarray(pa), np.asarray(pb),
-                                   rtol=3e-6, atol=3e-6)
+                                   rtol=3e-6, atol=2e-5)

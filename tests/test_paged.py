@@ -1,9 +1,10 @@
 """Paged serving path (docs/SERVING.md "Paged serving"): the bounded
 paged decode kernel vs the cache oracle, PagedKVCache pool writes, the
-block allocator's refcount/COW/prefix-hash lifecycle, and the
-PagedServingEngine contracts — prefill+decode parity vs the one-shot
-forward, prefix-shared stream identity, zero-recompile across
-admit/COW/retire, pool-exhaustion admission control."""
+block allocator's refcount/COW/prefix-hash lifecycle, and the engine's
+contracts over a pool smaller than the default — prefill+decode parity
+vs the one-shot forward, prefix-shared stream identity, zero-recompile
+across admit/COW/retire, pool-exhaustion admission control — and over
+the default one, sized so that every slot can reach ``max_len``."""
 
 import numpy as np
 
@@ -13,12 +14,14 @@ import pytest
 
 from apex_tpu.models import GPTConfig, GPTModel
 from apex_tpu.observability.registry import MetricsRegistry
-from apex_tpu.ops.flash_attention import (decode_attention, mha_reference,
+from apex_tpu.ops.flash_attention import (mha_reference,
                                           paged_decode_attention)
 from apex_tpu.serving import (BlockAllocator, PagedKVCache,
                               PagedServingEngine, PoolExhausted, Rejection,
                               Request, ServingEngine, SlotScheduler,
                               paged_block_bytes)
+
+from _program_text import program_text
 
 
 def _quantize_ref(x):
@@ -129,6 +132,68 @@ class TestPagedDecodeKernel:
                                    jnp.asarray(tables), lengths,
                                    use_pallas=False)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)
+
+    def test_current_token_merge_matches_in_cache_oracle(self):
+        """paged_decode_attention(k_new=...) over an L-length prefix must
+        equal the oracle over an (L+1)-length cache with the token
+        written at the cursor — the exactness the write-after-read decode
+        step relies on — on the kernel and on its XLA fallback; and with
+        an empty prefix it is a softmax over one position: exactly
+        ``v_new``."""
+        rng = np.random.RandomState(4)
+        tables = self._layout(rng)
+        prefix = [0, 1, 100, 255]        # 255: the last position is free
+        q = jnp.asarray(rng.randn(self.B, self.H, self.D), jnp.float32)
+        kp, vp = self._pool(rng), self._pool(rng)
+        kn = rng.randn(self.B, self.H, self.D).astype(np.float32)
+        vn = rng.randn(self.B, self.H, self.D).astype(np.float32)
+        kd, vd = self._dense_of(kp, tables), self._dense_of(vp, tables)
+        for i, ln in enumerate(prefix):
+            kd[i, :, ln], vd[i, :, ln] = kn[i], vn[i]
+        ref = mha_reference(q[:, :, None], jnp.asarray(kd), jnp.asarray(vd),
+                            kv_length=jnp.asarray(prefix) + 1)[:, :, 0]
+        for use_pallas in (True, False):
+            out = paged_decode_attention(
+                q, jnp.asarray(kp), jnp.asarray(vp), self.LAYER,
+                jnp.asarray(tables), jnp.asarray(prefix, jnp.int32),
+                k_new=jnp.asarray(kn), v_new=jnp.asarray(vn),
+                use_pallas=use_pallas)
+            np.testing.assert_allclose(out, ref, atol=2e-6)
+            np.testing.assert_array_equal(np.asarray(out[0]), vn[0])
+
+    def test_int8_requires_scales(self):
+        z8 = jnp.zeros((1, 2, 8, 16), jnp.int8)
+        with pytest.raises(ValueError, match="k_scale"):
+            paged_decode_attention(jnp.zeros((1, 2, 8)), z8, z8, 0,
+                                   jnp.zeros((1, 1), jnp.int32),
+                                   jnp.zeros(1, jnp.int32))
+
+    def test_forced_pallas_on_a_pool_off_the_lanes_is_served(self):
+        """No shape is refused: every block of the kernel spans its
+        array's last two dims whole, so ``use_pallas=True`` on a pool
+        whose ``H * D`` (120) is no multiple of the 128 lanes, in blocks
+        of 8 tokens, reads what the oracle reads (Mosaic takes the same
+        shape: ``tests/test_chip_compile.py``, ``paged_decode_off_lanes``).
+        A cursor short of its last block's end must not read that
+        block's tail."""
+        rng = np.random.RandomState(5)
+        B, H, D, BS, NBS = 2, 3, 40, 8, 3
+        tables = np.asarray([[4, 1, 5], [2, 6, 3]], np.int32)
+        lengths = jnp.asarray([BS * NBS, BS + 3], jnp.int32)
+        q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
+        kp = rng.randn(1, 7, BS, H * D).astype(np.float32)
+        vp = rng.randn(1, 7, BS, H * D).astype(np.float32)
+
+        def dense(pool):
+            return jnp.asarray(pool[0][tables].reshape(
+                B, NBS * BS, H, D).transpose(0, 2, 1, 3))
+
+        out = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                                     0, jnp.asarray(tables), lengths,
+                                     use_pallas=True)
+        ref = mha_reference(q[:, :, None], dense(kp), dense(vp),
+                            kv_length=lengths)[:, :, 0]
+        np.testing.assert_allclose(out, ref, atol=2e-6)
 
     def test_unmapped_tail_blocks_never_pollute(self):
         """Table entries past ceil(length/block) may be garbage (null or
@@ -650,15 +715,15 @@ class TestPagedCostModel:
         # per-call traffic (the block-diagonal query and output tiles)
         MAX_LEN, MEAN = 2048, 256
         model, params = _tiny_model(max_position_embeddings=MAX_LEN)
-        dense = ServingEngine(model, params, max_seqs=2, max_len=MAX_LEN,
-                              prefill_len=8, cache_dtype=jnp.float32)
+        # told no mean context, the estimate prices the whole table span
+        whole = _paged_engine(model, params, max_len=MAX_LEN, num_blocks=40)
         paged = _paged_engine(model, params, max_len=MAX_LEN,
                               num_blocks=40, mean_context=MEAN)
-        da = model_program(dense.decode_traced).regions["decode_attention"]
+        da = model_program(whole.decode_traced).regions["decode_attention"]
         pa = model_program(paged.decode_traced).regions["decode_attention"]
         ratio = pa.hbm_bytes / da.hbm_bytes
-        # the paged program's modeled HBM is ~mean/max of the dense
-        # leg's — the O(max_len) gap, closed
+        # the modeled HBM is ~mean/max of the whole span's — the
+        # O(max_len) gap, closed
         assert ratio <= (MEAN / MAX_LEN) * 1.5, ratio
         # and it scales WITH the context, not the pool span
         paged2 = _paged_engine(model, params, max_len=MAX_LEN,
@@ -666,3 +731,87 @@ class TestPagedCostModel:
         pa2 = model_program(paged2.decode_traced).regions[
             "decode_attention"]
         assert pa2.hbm_bytes > 2 * pa.hbm_bytes
+
+
+# ---------------------------------------------------------------------------
+# the default pool: every slot can reach max_len
+# ---------------------------------------------------------------------------
+
+class TestDefaultPool:
+    def test_paged_serving_engine_is_the_one_engine(self):
+        assert PagedServingEngine is ServingEngine
+
+    @pytest.mark.parametrize("buckets,block", [
+        (8, 8), (12, 4), ([16, 24], 8), (128, 128), ([256, 512], 128),
+        (7, 1)])
+    def test_block_size_defaults_to_the_buckets_common_factor_with_128(
+            self, buckets, block):
+        """``gcd(128, *buckets)``: the largest block that every prefill
+        bucket is whole blocks of and that divides the kernel's 128-token
+        tile. One rule for every caller: a bucket that shares no factor
+        with 128 gets blocks of one token, legal and slow
+        (docs/SERVING.md, "How to size the pool")."""
+        model, params = _tiny_model(max_position_embeddings=512)
+        widest = max(buckets) if isinstance(buckets, list) else buckets
+        eng = ServingEngine(model, params, max_seqs=1, max_len=widest,
+                            prefill_len=buckets)
+        assert eng.block_size == block
+        assert eng.num_blocks == -(-widest // block) + 1
+
+    def test_every_slot_reaches_max_len_and_not_a_token_more(self):
+        """The default pool is the whole reservation: ``max_seqs``
+        sequences of ``max_len`` tokens each never find the pool
+        exhausted, and the token after that has no block to land in."""
+        model, params = _tiny_model()
+        S, MAX_LEN = 3, 16
+        eng = ServingEngine(model, params, max_seqs=S, max_len=MAX_LEN,
+                            prefill_len=8, cache_dtype=jnp.float32)
+        assert eng.num_blocks == S * 2 + 1 and eng.block_size == 8
+        for slot in range(S):
+            eng.prefill([1 + slot, 2, 3, 4, 5], slot)   # distinct prompts
+        toks, temps = np.zeros(S, np.int32), np.zeros(S, np.float32)
+        for _ in range(MAX_LEN - 5):
+            toks = eng.decode(toks, temps)
+            assert eng.last_failed == []
+        assert eng.allocator.lengths.tolist() == [MAX_LEN] * S
+        assert eng.allocator.free_blocks == 0
+        eng.decode(toks, temps)
+        assert eng.last_failed == [0, 1, 2]
+        assert eng.allocator.lengths.tolist() == [MAX_LEN] * S
+        # under the scheduler the same thing is a loud "capacity"
+        for slot in range(S):
+            eng.release_slot(slot)
+        out = SlotScheduler(eng, registry=MetricsRegistry()).run(
+            [Request(prompt=[1 + i, 2, 3, 4, 5], max_new_tokens=50)
+             for i in range(S)])
+        for c in out.values():
+            assert c.finish_reason == "capacity"
+            # the last token sampled is the one whose KV has no room
+            assert len(c.tokens) == MAX_LEN - 5
+
+    @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8])
+    def test_bytes_per_slot_times_slots_is_the_pool_less_its_null_block(
+            self, cache_dtype):
+        model, params = _tiny_model()
+        eng = ServingEngine(model, params, max_seqs=3, max_len=20,
+                            prefill_len=8, cache_dtype=cache_dtype)
+        # 20 tokens in blocks of 8: a slot reserves 3 blocks
+        assert eng.bytes_per_slot() == 3 * eng.block_bytes()
+        assert eng.bytes_per_slot() * eng.max_seqs == \
+            eng.cache.nbytes() - eng.block_bytes()
+        hbm = 1 << 30
+        assert eng.suggest_max_seqs(hbm) == \
+            eng.suggest_pool_blocks(hbm, mean_len=20) // 3
+
+    def test_explicit_defaults_lower_to_the_same_programs(self):
+        model, params = _tiny_model()
+        kw = dict(max_seqs=2, max_len=24, prefill_len=[8, 16],
+                  speculate_k=2)
+        left_out = ServingEngine(model, params, **kw)
+        spelled = ServingEngine(model, params, num_blocks=2 * 3 + 1,
+                                block_size=8, **kw)
+        for name in ("prefill_traced", "decode_traced", "verify_traced"):
+            assert getattr(left_out, name).lower().as_text() == \
+                getattr(spelled, name).lower().as_text(), name
+        assert program_text(left_out.release_compiled) == \
+            program_text(spelled.release_compiled)

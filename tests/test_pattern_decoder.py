@@ -430,7 +430,7 @@ def test_the_engine_counts_by_kind_and_returns_window_blocks(served):
 def test_what_refuses_this_model_says_why():
     model = PatternDecoder(program_config())
     w = weights()
-    with pytest.raises(ValueError, match="pool a kind"):
+    with pytest.raises(ValueError, match="dict by layer kind"):
         ServingEngine(model, w, max_seqs=2, max_len=64, prefill_len=32)
     common = dict(max_seqs=2, max_len=64, prefill_len=32, block_size=8,
                   num_blocks={"sliding_attention": 9, "full_attention": 17})

@@ -18,6 +18,8 @@ from apex_tpu.observability.registry import MetricsRegistry
 from apex_tpu.serving import (PagedServingEngine, Request, ServingEngine,
                               SlotScheduler)
 from apex_tpu.utils.timers import Timer, profile_trace
+
+from _program_text import program_text
 from benchmark import trace_reduce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -211,7 +213,8 @@ def test_with_both_off_nothing_is_recorded(model_params):
     assert trace.drain_spans() == []
 
 
-def test_a_speculative_dense_engine_has_the_same_cut(model_params):
+def test_a_speculative_engine_over_its_default_pool_has_the_same_cut(
+        model_params):
     model, params = model_params
     with trace.span_recording():
         engine = ServingEngine(model, params, max_seqs=2, max_len=24,
@@ -220,11 +223,11 @@ def test_a_speculative_dense_engine_has_the_same_cut(model_params):
                               speculate_k=2)
         sched.run([Request(prompt=[1, 2, 3, 1, 2, 3], max_new_tokens=5)])
         names = {s.name for s in trace.drain_spans()}
-    # no allocator: no index, no advance; no plain decode step either;
-    # no cast program (the weights' image is the tree)
+    # no plain decode step (no prefix was shared, so no tail went through
+    # one); no cast program (the weights' image is the tree)
     assert names == set(trace.SPANS) - {
-        "compile.image", "prefill.index", "decode.advance", "verify.advance",
-        "engine.decode", "decode.plan", "decode.dispatch", "decode.wait"}
+        "compile.image", "engine.decode", "decode.plan", "decode.dispatch",
+        "decode.advance", "decode.wait"}
 
 
 def test_an_engine_with_buckets_names_the_bucket_and_its_tokens(
@@ -282,7 +285,8 @@ def test_programs_are_the_same_with_recording_on(model_params):
             trace.disable_spans()
     off, on = built
     for name in ("prefill_compiled", "decode_compiled", "release_compiled"):
-        assert getattr(off, name).as_text() == getattr(on, name).as_text()
+        assert program_text(getattr(off, name)) == \
+            program_text(getattr(on, name))
 
 
 def test_host_tracer_level_reaches_the_profiler(tmp_path):

@@ -458,17 +458,30 @@ def test_trainer_full_policy_jaxpr_identical_to_legacy_bool():
         args = state + (tokens, targets)
         j_full = jaxpr_str(full.train_step, *args)
         assert jaxpr_str(legacy.train_step, *args) == j_full
-        assert " name[" not in j_full and "remat2" in j_full
+
+        # by structure, not by the printed text (which wraps a name
+        # equation over lines as it pleases): the equations' primitives
+        def eqns(trainer):
+            return list(iter_eqns(
+                jax.make_jaxpr(trainer.train_step)(*args).jaxpr))
+
+        def tags(trainer):
+            return {e.params["name"] for e in eqns(trainer)
+                    if e.primitive.name == "name"}
+
+        def rematerializes(trainer):
+            return any(e.primitive.name in ("remat2", "checkpoint")
+                       for e in eqns(trainer))
+
+        assert tags(full) == set() and rematerializes(full)
 
         sel = GPTHybridTrainer(cfg_sel, mesh)
         assert sel.remat_policy.uses_names
-        j_sel = jaxpr_str(sel.train_step, *args)
-        assert "remat2" in j_sel
+        assert rematerializes(sel)
         # seq=8 takes the XLA attention fallback, which still tags the
         # context; the GEMM/LN tags come from the layer body
-        for name in ("qkv_out", "attn_proj_out", "mlp_fc1_out",
-                     "mlp_fc2_out", "ln_out", "flash_ctx"):
-            assert f"name[name={name}]" in j_sel, name
+        assert tags(sel) >= {"qkv_out", "attn_proj_out", "mlp_fc1_out",
+                             "mlp_fc2_out", "ln_out", "flash_ctx"}
     finally:
         parallel_state.destroy_model_parallel()
 
@@ -491,9 +504,23 @@ def test_trainer_selective_step_runs_and_matches_none():
         loss0, *out0 = jax.jit(t_none.train_step)(*s0, tokens, targets)
         loss1, *out1 = jax.jit(t_sel.train_step)(*s1, tokens, targets)
         np.testing.assert_allclose(float(loss0), float(loss1), rtol=1e-6)
+        # the GRADIENT is what recompute must not change: Adam's first
+        # moment after one step is (1 - beta1) g, held leaf by leaf to
+        # float32 roundoff of the leaf's largest entry
         jax.tree_util.tree_map(
             lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
+                np.asarray(a), np.asarray(b), rtol=0,
+                atol=2e-6 * float(np.abs(np.asarray(a)).max())),
+            out0[2].exp_avg, out1[2].exp_avg)
+        # the updated params agree to a thousandth of the largest step
+        # Adam takes (lr 1e-2): where an entry's gradient is itself
+        # rounding noise (|g| ~ eps = 1e-8) the normalised step
+        # m / (sqrt(v) + eps) turns the last bits of g into any step up
+        # to lr, so those entries (biases that start at zero) are not
+        # bit-comparable between two schedules of the same sums
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5),
             out0[0], out1[0])
     finally:
         parallel_state.destroy_model_parallel()

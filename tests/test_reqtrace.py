@@ -26,6 +26,8 @@ from apex_tpu.observability.slo import (SLOTarget, SLOTracker,
                                         SLOViolationError)
 from apex_tpu.serving import Request, ServingEngine, SlotScheduler
 
+from _program_text import program_text
+
 
 # ---------------------------------------------------------------------------
 # log-spaced buckets + percentile readout
@@ -455,7 +457,7 @@ class TestTracingZeroCost:
         for a, b in ((eng_off.prefill_compiled, eng_on.prefill_compiled),
                      (eng_off.decode_compiled, eng_on.decode_compiled),
                      (eng_off.release_compiled, eng_on.release_compiled)):
-            assert a.as_text() == b.as_text()
+            assert program_text(a) == program_text(b)
         # and identical greedy token streams — tracing observed, never
         # perturbed
         for rid in out_off:

@@ -23,6 +23,8 @@ from apex_tpu.serving import (BrownoutPolicy, CheckpointWatcher,
                               Rejection, Request, ServingEngine,
                               SlotScheduler, watch_checkpoints)
 
+from _program_text import program_text
+
 
 @pytest.fixture(scope="module")
 def model_params():
@@ -182,7 +184,7 @@ class TestDeadlines:
         assert len(out[rid].tokens) >= 1  # partial output delivered
         assert not sched.active and sorted(sched.free) == [0, 1]
         np.testing.assert_array_equal(
-            np.asarray(sched.engine.cache.lengths), [0, 0])
+            sched.engine.allocator.lengths, [0, 0])
         assert reg.snapshot()["serve/expired"] == 1.0
 
     def test_default_deadline_applies_when_request_sets_none(self, engine):
@@ -293,7 +295,7 @@ class TestQuarantine:
         assert reg.snapshot()["serve/poisoned"] == 1.0
         # the slot was released (cursor zeroed) like any retirement
         np.testing.assert_array_equal(
-            np.asarray(qengine.cache.lengths), [0, 0])
+            qengine.allocator.lengths, [0, 0])
 
     def test_poison_writes_strict_json_flight_record(self, qengine,
                                                      tmp_path):
@@ -354,7 +356,7 @@ class TestZeroCostOff:
         for a, b in ((engine.prefill_compiled, fresh.prefill_compiled),
                      (engine.decode_compiled, fresh.decode_compiled),
                      (engine.release_compiled, fresh.release_compiled)):
-            assert a.as_text() == b.as_text()
+            assert program_text(a) == program_text(b)
 
     def test_host_side_knobs_leave_programs_untouched(self, model_params,
                                                       engine):
@@ -379,15 +381,15 @@ class TestZeroCostOff:
                      (engine.decode_compiled, wired_eng.decode_compiled),
                      (engine.release_compiled,
                       wired_eng.release_compiled)):
-            assert a.as_text() == b.as_text()
+            assert program_text(a) == program_text(b)
 
     def test_quarantine_differs_only_in_decode(self, engine, qengine):
-        assert (engine.prefill_compiled.as_text()
-                == qengine.prefill_compiled.as_text())
-        assert (engine.release_compiled.as_text()
-                == qengine.release_compiled.as_text())
-        assert (engine.decode_compiled.as_text()
-                != qengine.decode_compiled.as_text())
+        assert (program_text(engine.prefill_compiled)
+                == program_text(qengine.prefill_compiled))
+        assert (program_text(engine.release_compiled)
+                == program_text(qengine.release_compiled))
+        assert (program_text(engine.decode_compiled)
+                != program_text(qengine.decode_compiled))
 
     def test_poison_injection_never_recompiles(self, qengine):
         """Injecting (and clearing) poison is an array-argument change on

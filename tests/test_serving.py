@@ -1,6 +1,7 @@
-"""Serving fast path: decode kernel parity, KV-cached prefill/decode vs
-the one-shot forward, AOT donation + zero-recompile contracts, and the
-continuous slot batcher (docs/SERVING.md)."""
+"""Serving fast path: KV-cached prefill/decode vs the one-shot forward,
+AOT donation + zero-recompile contracts of an engine over its default
+pool, and the continuous slot batcher (docs/SERVING.md). The decode
+kernel and the pool's own tests are in ``tests/test_paged.py``."""
 
 import numpy as np
 
@@ -9,135 +10,19 @@ import jax.numpy as jnp
 import pytest
 
 from apex_tpu.models import GPTConfig, GPTModel
-from apex_tpu.ops.flash_attention import decode_attention, mha_reference
-from apex_tpu.serving import (KVCache, Request, ServingEngine,
-                              SlotScheduler, cache_bytes_per_slot,
+from apex_tpu.ops.flash_attention import mha_reference
+from apex_tpu.serving import (BlockAllocator, PagedKVCache, Request,
+                              ServingEngine, SlotScheduler,
+                              cache_bytes_per_slot, paged_block_bytes,
                               sample_tokens)
 from apex_tpu.observability.registry import MetricsRegistry
 
 
-def _quantize_ref(x):
-    """Host-side mirror of the cache's symmetric per-(position, head)
-    int8 quantization."""
-    scale = np.maximum(np.abs(x).max(-1) / 127.0, 1e-8)
-    q = np.clip(np.round(x / scale[..., None]), -127, 127).astype(np.int8)
-    return q, scale.astype(np.float32)
-
-
 # ---------------------------------------------------------------------------
-# decode kernel vs the mha_reference cache oracle
+# the cache oracle, and the arithmetic the pool's size rests on
 # ---------------------------------------------------------------------------
 
-class TestDecodeKernel:
-    B, H, T, D = 4, 4, 256, 32
-    LENGTHS = [0, 1, 100, 256]  # empty, single, partial, full
-
-    def _rand(self, rng, shape, dtype):
-        return jnp.asarray(rng.randn(*shape), dtype)
-
-    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
-                                           (jnp.bfloat16, 2e-2)])
-    def test_parity_vs_cache_oracle(self, dtype, tol):
-        rng = np.random.RandomState(0)
-        q = self._rand(rng, (self.B, self.H, self.D), dtype)
-        k = self._rand(rng, (self.B, self.H, self.T, self.D), dtype)
-        v = self._rand(rng, (self.B, self.H, self.T, self.D), dtype)
-        lengths = jnp.asarray(self.LENGTHS, jnp.int32)
-        out = decode_attention(q, k, v, lengths)
-        ref = mha_reference(q[:, :, None], k, v, kv_length=lengths)[:, :, 0]
-        np.testing.assert_allclose(out.astype(np.float32),
-                                   ref.astype(np.float32), atol=tol)
-        # the empty row is exactly zero on both paths
-        assert np.all(np.asarray(out[0]) == 0.0)
-
-    def test_current_token_merge_matches_in_cache_oracle(self):
-        """decode_attention(k_new=...) over an L-length prefix must equal
-        the oracle over an (L+1)-length cache with the token written at
-        the cursor — the exactness the write-after-read decode step
-        relies on."""
-        rng = np.random.RandomState(1)
-        q = self._rand(rng, (self.B, self.H, self.D), jnp.float32)
-        k = self._rand(rng, (self.B, self.H, self.T, self.D), jnp.float32)
-        v = self._rand(rng, (self.B, self.H, self.T, self.D), jnp.float32)
-        kn = self._rand(rng, (self.B, self.H, self.D), jnp.float32)
-        vn = self._rand(rng, (self.B, self.H, self.D), jnp.float32)
-        prefix = [0, 1, 100, 255]
-        k2, v2 = k, v
-        for i, L in enumerate(prefix):
-            k2 = k2.at[i, :, L].set(kn[i])
-            v2 = v2.at[i, :, L].set(vn[i])
-        out = decode_attention(q, k, v, jnp.asarray(prefix), k_new=kn,
-                               v_new=vn)
-        ref = mha_reference(q[:, :, None], k2, v2,
-                            kv_length=jnp.asarray(prefix) + 1)[:, :, 0]
-        np.testing.assert_allclose(out, ref, atol=2e-6)
-        # empty prefix == softmax over one position == exactly v_new
-        np.testing.assert_array_equal(np.asarray(out[0]),
-                                      np.asarray(vn[0]))
-
-    def test_int8_cache_parity(self):
-        rng = np.random.RandomState(2)
-        q = self._rand(rng, (self.B, self.H, self.D), jnp.float32)
-        kf = rng.randn(self.B, self.H, self.T, self.D).astype(np.float32)
-        vf = rng.randn(self.B, self.H, self.T, self.D).astype(np.float32)
-        ki, ks = _quantize_ref(kf)
-        vi, vs = _quantize_ref(vf)
-        lengths = jnp.asarray([3, 50, 200, 256], jnp.int32)
-        out = decode_attention(q, jnp.asarray(ki), jnp.asarray(vi),
-                               lengths, k_scale=jnp.asarray(ks),
-                               v_scale=jnp.asarray(vs))
-        # oracle over the DEQUANTIZED cache: the kernel's only error
-        # budget is fp roundoff, not quantization (same int8 values in)
-        ref = mha_reference(q[:, :, None],
-                            jnp.asarray(ki.astype(np.float32)
-                                        * ks[..., None]),
-                            jnp.asarray(vi.astype(np.float32)
-                                        * vs[..., None]),
-                            kv_length=lengths)[:, :, 0]
-        np.testing.assert_allclose(out, ref, atol=2e-6)
-        # and vs the unquantized truth the int8 error stays bounded
-        full = mha_reference(q[:, :, None], jnp.asarray(kf),
-                             jnp.asarray(vf), kv_length=lengths)[:, :, 0]
-        assert np.max(np.abs(out - full)) < 0.05
-
-    def test_pallas_and_fallback_agree(self):
-        rng = np.random.RandomState(3)
-        q = self._rand(rng, (self.B, self.H, self.D), jnp.float32)
-        k = self._rand(rng, (self.B, self.H, self.T, self.D), jnp.float32)
-        v = self._rand(rng, (self.B, self.H, self.T, self.D), jnp.float32)
-        kn = self._rand(rng, (self.B, self.H, self.D), jnp.float32)
-        vn = self._rand(rng, (self.B, self.H, self.D), jnp.float32)
-        lengths = jnp.asarray(self.LENGTHS, jnp.int32)
-        a = decode_attention(q, k, v, lengths, k_new=kn, v_new=vn,
-                             use_pallas=True)
-        b = decode_attention(q, k, v, lengths, k_new=kn, v_new=vn,
-                             use_pallas=False)
-        np.testing.assert_allclose(a, b, atol=2e-6)
-
-    def test_int8_requires_scales(self):
-        z8 = jnp.zeros((1, 1, 128, 8), jnp.int8)
-        with pytest.raises(ValueError, match="k_scale"):
-            decode_attention(jnp.zeros((1, 1, 8)), z8, z8,
-                             jnp.zeros(1, jnp.int32))
-
-    def test_forced_pallas_on_misaligned_cache_refused(self):
-        """use_pallas=True on a misaligned max_len would silently drop
-        the T % block_k tail (or never write the output at
-        T < block_k) — it must raise, not decode garbage; the auto path
-        falls back and stays correct."""
-        rng = np.random.RandomState(5)
-        for T in (192, 64):  # tail-dropping and empty-grid cases
-            q = jnp.asarray(rng.randn(2, 2, 32), jnp.float32)
-            k = jnp.asarray(rng.randn(2, 2, T, 32), jnp.float32)
-            v = jnp.asarray(rng.randn(2, 2, T, 32), jnp.float32)
-            lengths = jnp.asarray([T, T // 2], jnp.int32)
-            with pytest.raises(ValueError, match="tile-aligned"):
-                decode_attention(q, k, v, lengths, use_pallas=True)
-            auto = decode_attention(q, k, v, lengths)
-            ref = mha_reference(q[:, :, None], k, v,
-                                kv_length=lengths)[:, :, 0]
-            np.testing.assert_allclose(auto, ref, atol=2e-6)
-
+class TestCacheOracle:
     def test_kv_length_oracle_masks_garbage(self):
         """mha_reference's kv_length path must be insensitive to cache
         content past the cursor — the property that makes it a valid
@@ -153,63 +38,17 @@ class TestDecodeKernel:
             v.at[0, :, 5:].set(7.0), kv_length=lengths)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(trash))
 
-
-# ---------------------------------------------------------------------------
-# KV cache pytree
-# ---------------------------------------------------------------------------
-
-class TestKVCache:
-    def test_append_and_write_prompt(self):
-        cache = KVCache.create(2, 3, 2, 8, 4, dtype=jnp.float32)
-        k_p = jnp.ones((2, 2, 5, 4))
-        cache = cache.write_prompt(k_p, 2 * k_p, slot=1, true_len=3)
-        assert int(cache.lengths[1]) == 3 and int(cache.lengths[0]) == 0
-        k_n = jnp.full((2, 3, 2, 4), 9.0)
-        cache = cache.append(k_n, k_n)
-        # slot 1 appended at its cursor (3); slot 0 at 0
-        assert float(cache.k[0, 1, 0, 3, 0]) == 9.0
-        assert float(cache.k[0, 1, 0, 2, 0]) == 1.0   # prompt intact
-        assert float(cache.k[0, 0, 0, 0, 0]) == 9.0
-        assert cache.lengths.tolist() == [1, 4, 1]
-
-    def test_append_saturates_at_max_len(self):
-        cache = KVCache.create(1, 1, 1, 2, 4, dtype=jnp.float32)
-        u = jnp.ones((1, 1, 1, 4))
-        cache = cache.append(u, u)
-        cache = cache.append(2 * u, 2 * u)      # fills max_len
-        for _ in range(2):
-            cache = cache.append(9 * u, 9 * u)  # saturated appends
-        assert int(cache.lengths[0]) == 2  # clamped, no OOB write
-        # a saturated slot writes NOTHING: the last position keeps its
-        # value (the old semantics silently overwrote position
-        # max_len-1 with each newest token's KV — the scheduler now
-        # retires at capacity BEFORE the dispatch, and the cache write
-        # is a no-op even if one slips through)
-        assert float(cache.k[0, 0, 0, 1, 0]) == 2.0
-        assert float(cache.v[0, 0, 0, 1, 0]) == 2.0
-
-    def test_int8_roundtrip(self):
-        cache = KVCache.create(1, 1, 2, 4, 8, dtype=jnp.int8)
-        assert cache.quantized
-        x = jnp.asarray(np.random.RandomState(0).randn(1, 1, 2, 8),
-                        jnp.float32)
-        cache = cache.append(x, x)
-        deq = (cache.k[0, 0, :, 0].astype(jnp.float32)
-               * cache.k_scale[0, 0, :, 0, None])
-        np.testing.assert_allclose(deq, x[0, 0], atol=float(
-            jnp.max(jnp.abs(x)) / 127.0) + 1e-6)
-        # pytree roundtrip preserves the quantized layout
-        leaves, treedef = jax.tree_util.tree_flatten(cache)
-        assert len(leaves) == 5
-        assert jax.tree_util.tree_unflatten(treedef, leaves).quantized
-
     def test_bytes_per_slot(self):
         bf16 = cache_bytes_per_slot(12, 12, 1024, 64, jnp.bfloat16)
         assert bf16 == 2 * 12 * 12 * 64 * 2 * 1024
         i8 = cache_bytes_per_slot(12, 12, 1024, 64, jnp.int8)
         assert i8 == (2 * 12 * 12 * 64 + 2 * 12 * 12 * 4) * 1024
-        cache = KVCache.create(12, 3, 12, 1024, 64, dtype=jnp.int8)
-        assert cache.nbytes() == 3 * i8 + 3 * 4  # + the (S,) cursor
+        # a slot's 1024 positions as 8 blocks of 128: the same bytes,
+        # and a pool's bytes are its blocks'
+        assert 8 * paged_block_bytes(12, 12, 128, 64, jnp.int8) == i8
+        pool = jax.eval_shape(lambda: PagedKVCache.create(
+            12, 3 * 8 + 1, 12, 128, 64, dtype=jnp.int8))
+        assert pool.nbytes() == 3 * i8 + i8 // 8    # + the null block
 
 
 # ---------------------------------------------------------------------------
@@ -222,6 +61,48 @@ def _tiny_model(compute_dtype):
                     compute_dtype=compute_dtype)
     model = GPTModel(cfg)
     return model, model.init(jax.random.PRNGKey(0))
+
+
+class _Pool:
+    """``model.forward``'s two cache legs driven as the engine drives
+    them, without the engine: a pool that holds ``slots`` x ``max_len``
+    and its allocator."""
+
+    def __init__(self, model, slots, max_len=16, block=8,
+                 dtype=jnp.float32):
+        cfg = model.cfg
+        per_slot = -(-max_len // block)
+        blocks = slots * per_slot + 1
+        self.model, self.block = model, block
+        self.cache = PagedKVCache.create(
+            cfg.num_layers, blocks, cfg.num_attention_heads, block,
+            cfg.head_dim, dtype=dtype)
+        self.alloc = BlockAllocator(blocks, block, per_slot, slots)
+
+    def prefill(self, params, tokens, slot, prompt_len=None, **kw):
+        P = tokens.shape[1]
+        plan = self.alloc.admit(slot, [0] * (prompt_len or P),
+                                P // self.block, share=False)
+        logits, self.cache = self.model.forward(
+            params, tokens, kv_cache=self.cache,
+            block_row=np.asarray(plan.block_row, np.int32),
+            prompt_len=prompt_len, **kw)
+        return logits
+
+    def decode(self, params, tokens, active):
+        """One step: ``tokens`` ``(slots,)``, only ``active`` advance."""
+        active = np.asarray(active, bool)
+        step = self.alloc.prepare_step(list(np.flatnonzero(active)))
+        assert not step.failed
+        ids, offs = self.alloc.append_targets(active)
+        logits, self.cache = self.model.forward(
+            params, jnp.asarray(tokens)[:, None], kv_cache=self.cache,
+            block_tables=self.alloc.tables.copy(),
+            lengths=self.alloc.lengths.copy(), append_block_ids=ids,
+            append_offsets=offs, cow_src=step.cow_src,
+            cow_dst=step.cow_dst)
+        self.alloc.advance(list(np.flatnonzero(active)))
+        return logits
 
 
 class TestPrefillDecodeParity:
@@ -242,19 +123,20 @@ class TestPrefillDecodeParity:
         tokens = jnp.asarray(rng.randint(0, 97, (1, n)))
         oneshot = np.asarray(model(params, tokens), np.float32)
 
-        cache = KVCache.create(2, S, 4, 16, 8, dtype=cache_dtype)
-        logits_p, cache = model.forward(params, tokens[:, :P],
-                                        kv_cache=cache, slot=1)
+        pool = _Pool(model, S, dtype=cache_dtype)
+        logits_p = pool.prefill(params, tokens[:, :P], slot=1)
         np.testing.assert_allclose(np.asarray(logits_p[0], np.float32),
                                    oneshot[0, :P], atol=tol)
         # teacher-forced decode of the remaining positions on slot 1 (the
         # other slots stay empty and step along — the fixed-shape grid)
+        only = np.arange(S) == 1
         for t in range(P, n):
-            dt = jnp.zeros((S, 1), tokens.dtype).at[1, 0].set(tokens[0, t])
-            logits_d, cache = model.forward(params, dt, kv_cache=cache)
+            dt = np.zeros(S, np.int32)
+            dt[1] = int(tokens[0, t])
+            logits_d = pool.decode(params, dt, only)
             np.testing.assert_allclose(np.asarray(logits_d[1], np.float32),
                                        oneshot[0, t], atol=tol)
-        assert int(cache.lengths[1]) == n
+        assert pool.alloc.lengths.tolist() == [0, n, 0]
 
     def test_int8_cache_stays_close(self):
         """int8 cache: quantization error bounded, ranking mostly
@@ -265,13 +147,12 @@ class TestPrefillDecodeParity:
         n, P = 10, 6
         tokens = jnp.asarray(rng.randint(0, 97, (1, n)))
         oneshot = np.asarray(model(params, tokens), np.float32)
-        cache = KVCache.create(2, 1, 4, 16, 8, dtype=jnp.int8)
-        _, cache = model.forward(params, tokens[:, :P], kv_cache=cache,
-                                 slot=0)
+        pool = _Pool(model, 1, block=2, dtype=jnp.int8)
+        pool.prefill(params, tokens[:, :P], slot=0)
         agree = 0
         for t in range(P, n):
-            logits_d, cache = model.forward(params, tokens[:, t][:, None],
-                                            kv_cache=cache)
+            logits_d = pool.decode(params, np.asarray(tokens[:, t]),
+                                   [True])
             agree += int(np.argmax(np.asarray(logits_d[0]))
                          == np.argmax(oneshot[0, t]))
         assert agree >= (n - P) - 1
@@ -284,15 +165,12 @@ class TestPrefillDecodeParity:
         toks = [5, 6, 7]
 
         def run(window):
-            cache = KVCache.create(2, 1, 4, 16, 8, dtype=jnp.float32)
+            pool = _Pool(model, 1, block=1)
             padded = np.zeros((1, window), np.int32)
             padded[0, : len(toks)] = toks
-            _, cache = model.forward(params, jnp.asarray(padded),
-                                     kv_cache=cache, slot=0,
-                                     prompt_len=len(toks))
-            out, _ = model.forward(params, jnp.asarray([[9]]),
-                                   kv_cache=cache)
-            return np.asarray(out)
+            pool.prefill(params, jnp.asarray(padded), slot=0,
+                         prompt_len=len(toks))
+            return np.asarray(pool.decode(params, np.asarray([9]), [True]))
 
         np.testing.assert_allclose(run(3), run(8), atol=1e-5)
 
@@ -302,15 +180,19 @@ class TestPrefillDecodeParity:
         traced one (the AOT engine path) is clamped."""
         model, params = _tiny_model(jnp.float32)
         tokens = jnp.asarray([[1, 2, 3, 4]])
-        cache = KVCache.create(2, 1, 4, 16, 8, dtype=jnp.float32)
+        pool = _Pool(model, 1, block=4)
+        row = np.asarray([1], np.int32)
         with pytest.raises(ValueError, match="written window"):
-            model.forward(params, tokens, kv_cache=cache, slot=0,
-                          prompt_len=7)
-        _, out_cache = jax.jit(
-            lambda p, c, pl: model.forward(p, tokens, kv_cache=c,
-                                           slot=0, prompt_len=pl)
-        )(params, cache, jnp.asarray(7, jnp.int32))
-        assert int(out_cache.lengths[0]) == 4  # clamped to the window
+            model.forward(params, tokens, kv_cache=pool.cache,
+                          block_row=row, prompt_len=7)
+        last = jax.jit(
+            lambda p, c, pl: model.forward(
+                p, tokens, kv_cache=c, block_row=row, prompt_len=pl,
+                last_logit_only=True)[0])
+        # clamped to the window: the row it projects is the last written
+        np.testing.assert_array_equal(
+            np.asarray(last(params, pool.cache, jnp.asarray(7, jnp.int32))),
+            np.asarray(last(params, pool.cache, jnp.asarray(4, jnp.int32))))
 
     def test_forward_without_cache_is_call(self):
         model, params = _tiny_model(jnp.float32)
@@ -326,7 +208,8 @@ class TestPrefillDecodeParity:
         model = GPTModel(cfg)
         with pytest.raises(NotImplementedError, match="tp=1"):
             model.forward({}, jnp.zeros((1, 4), jnp.int32),
-                          kv_cache=KVCache.create(1, 1, 2, 8, 8))
+                          kv_cache=PagedKVCache.create(1, 2, 2, 4, 8),
+                          block_row=np.asarray([1], np.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -357,7 +240,11 @@ class TestEngineContracts:
         engine.prefill([1, 2, 3], slot=0)
         assert all(leaf.is_deleted() for leaf in old)
         old = jax.tree_util.tree_leaves(engine.cache)
-        engine.decode(np.zeros(2, np.int32), np.zeros(2, np.float32))
+        engine.decode(np.zeros(2, np.int32), np.zeros(2, np.float32),
+                      active=np.asarray([True, False]))
+        assert all(leaf.is_deleted() for leaf in old)
+        old = jax.tree_util.tree_leaves(engine.cache)
+        engine.release_slot(0)
         assert all(leaf.is_deleted() for leaf in old)
 
     def test_zero_recompiles_across_steps(self, engine):
@@ -368,8 +255,10 @@ class TestEngineContracts:
         reg = MetricsRegistry()
         # warm every host path once (prefill, decode, release, rng
         # split, asarray)
+        only = np.eye(2, dtype=bool)       # a held slot is not re-admitted
         engine.prefill([1, 2], slot=0)
-        engine.decode(np.zeros(2, np.int32), np.zeros(2, np.float32))
+        engine.decode(np.zeros(2, np.int32), np.zeros(2, np.float32),
+                      active=only[0])
         engine.release_slot(0)
         obs.install_compile_listeners(reg)
         try:
@@ -377,7 +266,8 @@ class TestEngineContracts:
             for i in range(4):
                 engine.prefill([1, 2, 3], slot=i % 2)
                 engine.decode(np.asarray([i, i + 1], np.int32),
-                              np.asarray([0.0, 0.7], np.float32))
+                              np.asarray([0.0, 0.7], np.float32),
+                              active=only[i % 2])
                 engine.release_slot(i % 2)
             after = reg.snapshot()
         finally:
@@ -407,12 +297,11 @@ class TestEngineContracts:
         """An out-of-range slot would CLAMP inside the compiled
         dynamic_update_slice and silently clobber the last valid slot's
         in-flight sequence — it must bounce at the host boundary."""
-        before = np.asarray(engine.cache.lengths)
+        before = engine.allocator.lengths.copy()
         for slot in (engine.max_seqs, -1):
             with pytest.raises(ValueError, match="out of range"):
                 engine.prefill([1, 2], slot=slot)
-        np.testing.assert_array_equal(np.asarray(engine.cache.lengths),
-                                      before)
+        np.testing.assert_array_equal(engine.allocator.lengths, before)
 
     def test_prefill_last_logit_only_matches_full_head(self):
         """The engine's single-row head projection equals the full-head
@@ -422,10 +311,9 @@ class TestEngineContracts:
         tokens = jnp.asarray([[3, 1, 4, 1, 5, 0, 0, 0]])
 
         def run(last_only):
-            cache = KVCache.create(2, 1, 4, 16, 8, dtype=jnp.float32)
-            lg, _ = model.forward(params, tokens, kv_cache=cache, slot=0,
-                                  prompt_len=5, last_logit_only=last_only)
-            return np.asarray(lg)
+            return np.asarray(_Pool(model, 1).prefill(
+                params, tokens, slot=0, prompt_len=5,
+                last_logit_only=last_only))
 
         full, last = run(False), run(True)
         assert last.shape == (1, 1, full.shape[-1])
@@ -586,8 +474,9 @@ class TestSlotScheduler:
         sched, _ = _sched(max_seqs=2)
         sched.run([Request(prompt=[1, 2, 3], max_new_tokens=10)])
         # slot 0 ran 10 tokens then released; slot 1 idled 9 steps
-        np.testing.assert_array_equal(
-            np.asarray(sched.engine.cache.lengths), [0, 0])
+        np.testing.assert_array_equal(sched.engine.allocator.lengths,
+                                      [0, 0])
+        assert sched.engine.allocator.blocks_in_use == 0
 
     def test_submit_rejects_nonpositive_max_new_tokens(self):
         sched, _ = _sched()

@@ -17,7 +17,7 @@ from apex_tpu.observability import trace
 from apex_tpu.observability.registry import MetricsRegistry
 from apex_tpu.serving import (CheckpointWatcher, PagedServingEngine,
                               Request, ServingEngine, SlotScheduler)
-from apex_tpu.serving.cache import KVCache
+from apex_tpu.serving.cache import PagedKVCache
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 
@@ -32,7 +32,7 @@ def model_params():
     return model, model.init(jax.random.PRNGKey(0))
 
 
-def dense(model, params, **kw):
+def default_pool(model, params, **kw):
     return ServingEngine(model, params, max_seqs=2, max_len=32,
                          prefill_len=8, **kw)
 
@@ -43,7 +43,7 @@ def paged(model, params, **kw):
                               **kw)
 
 
-ENGINES = pytest.mark.parametrize("build", [dense, paged])
+ENGINES = pytest.mark.parametrize("build", [default_pool, paged])
 
 
 MATRICES = ("qkv", "proj", "fc1", "fc2")
@@ -120,16 +120,26 @@ def test_forward_over_the_image_is_forward_over_the_hand_cast_tree(
     def run(p):
         if leg == "plain":
             return model.forward(p, tokens)
-        cache = KVCache.create(2, 2, 4, 16, 8)
-        logits, cache = model.forward(p, tokens, kv_cache=cache, slot=1,
-                                      prompt_len=6)
+        # two slots of 16 positions in blocks of 2, slot 1 holds the prompt
+        cache = PagedKVCache.create(2, 17, 4, 2, 8)
+        tables = jnp.arange(1, 17, dtype=jnp.int32).reshape(2, 8)
+        logits, cache = model.forward(p, tokens, kv_cache=cache,
+                                      block_row=tables[1, :3], prompt_len=6)
         if leg == "prefill":
             return logits
+        lengths = jnp.asarray([0, 6], jnp.int32)
+        at = dict(kv_cache=cache, block_tables=tables, lengths=lengths)
         if leg == "decode":
-            return model.forward(p, jnp.asarray([[3], [4]], jnp.int32),
-                                 kv_cache=cache)[0]
+            return model.forward(
+                p, jnp.asarray([[3], [4]], jnp.int32),
+                append_block_ids=jnp.asarray([1, 12], jnp.int32),
+                append_offsets=jnp.zeros(2, jnp.int32), **at)[0]
         return model.verify_forward(
-            p, jnp.asarray([[3, 1, 2], [4, 5, 6]], jnp.int32), cache)[0]
+            p, jnp.asarray([[3, 1, 2], [4, 5, 6]], jnp.int32),
+            append_block_ids=jnp.asarray([[1, 1, 2], [12, 12, 13]],
+                                         jnp.int32),
+            append_offsets=jnp.asarray([[0, 1, 0]] * 2, jnp.int32),
+            **at)[0]
 
     got, want = jax.jit(run)(image), jax.jit(run)(by_hand)
     assert got.dtype == want.dtype == F32

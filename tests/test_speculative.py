@@ -3,8 +3,8 @@ verify_tokens acceptance rule (greedy exact-prefix, rejection sampling
 with the corrected residual), the self-drafting NGramDraftSource, the
 k-token paged verify window at block boundaries (counts 0/1/k-1/k
 across a block edge, pool-exhaustion mid-verify, saturation writing
-nothing), dense append_k saturation, the advance-by-accepted rollback
-invariant on both engines, greedy spec-stream parity under the
+nothing), the advance-by-accepted rollback invariant on an engine over
+its default pool and over a smaller one, greedy spec-stream parity under the
 zero-recompile guard, and mid-verify retirement (poison quarantine)
 leaving no drafted-but-rejected KV visible to a re-admitted slot."""
 
@@ -17,7 +17,7 @@ import pytest
 from apex_tpu.elastic.faults import FaultPlan
 from apex_tpu.models import GPTConfig, GPTModel
 from apex_tpu.observability.registry import MetricsRegistry
-from apex_tpu.serving import (BlockAllocator, DraftSource, KVCache,
+from apex_tpu.serving import (BlockAllocator, DraftSource,
                               NGramDraftSource, PagedKVCache,
                               PagedServingEngine, Rejection, Request,
                               ServingEngine, SlotScheduler, verify_tokens)
@@ -242,39 +242,6 @@ class TestPagedVerifyWindow:
         assert np.all(bids[0] == NULL_BLOCK)
 
 
-class TestDenseAppendKSaturation:
-    def _cache(self, length):
-        cache = KVCache.create(1, 1, 1, 8, 2, dtype=jnp.float32)
-        import dataclasses
-        return dataclasses.replace(
-            cache, lengths=jnp.asarray([length], jnp.int32))
-
-    def _window(self):
-        val = np.zeros((1, 1, 1, 3, 2), np.float32)
-        for r in range(3):
-            val[0, 0, 0, r, :] = r + 1
-        return jnp.asarray(val)
-
-    def test_at_max_len_writes_nothing(self):
-        cache = self._cache(8)
-        out = cache.append_k(self._window(), self._window(),
-                             jnp.asarray([0], jnp.int32))
-        np.testing.assert_array_equal(np.asarray(out.k),
-                                      np.asarray(cache.k))
-        assert int(out.lengths[0]) == 8
-
-    def test_near_saturation_clamps_the_window(self):
-        cache = self._cache(7)
-        out = cache.append_k(self._window(), self._window(),
-                             jnp.asarray([1], jnp.int32))
-        k = np.asarray(out.k)[0, 0, 0]
-        # row 0 landed at position 7; rows 1-2 (past max_len) dropped,
-        # and positions below the cursor came back unchanged
-        np.testing.assert_array_equal(k[7], [1.0, 1.0])
-        assert not np.any(k[:7])
-        assert int(out.lengths[0]) == 8
-
-
 # ---------------------------------------------------------------------------
 # engines: advance-by-accepted + stream parity + retirement
 # ---------------------------------------------------------------------------
@@ -289,14 +256,14 @@ def model_params():
 
 
 @pytest.fixture(scope="module")
-def dense_ref(model_params):
+def default_pool_ref(model_params):
     model, params = model_params
     return ServingEngine(model, params, max_seqs=2, max_len=24,
                          prefill_len=8, cache_dtype=jnp.float32)
 
 
 @pytest.fixture(scope="module")
-def dense_spec(model_params):
+def default_pool_spec(model_params):
     model, params = model_params
     return ServingEngine(model, params, max_seqs=2, max_len=24,
                          prefill_len=8, cache_dtype=jnp.float32,
@@ -343,33 +310,33 @@ class TestEngineVerify:
             ServingEngine(model, params, max_seqs=1, max_len=8,
                           prefill_len=4, speculate_k=8)
 
-    def test_verify_on_plain_engine_raises(self, dense_ref):
-        assert dense_ref.verify_compiled is None
+    def test_verify_on_plain_engine_raises(self, default_pool_ref):
+        assert default_pool_ref.verify_compiled is None
         with pytest.raises(ValueError, match="speculative"):
-            dense_ref.verify(np.zeros(2, np.int32),
+            default_pool_ref.verify(np.zeros(2, np.int32),
                              np.zeros((2, K), np.int32),
                              np.zeros(2, np.float32))
 
-    def test_scheduler_engine_window_mismatch(self, dense_ref,
-                                              dense_spec):
+    def test_scheduler_engine_window_mismatch(self, default_pool_ref,
+                                              default_pool_spec):
         with pytest.raises(ValueError, match="speculate_k"):
-            SlotScheduler(dense_ref, registry=MetricsRegistry(),
+            SlotScheduler(default_pool_ref, registry=MetricsRegistry(),
                           speculate_k=K)
         with pytest.raises(ValueError, match="speculate_k"):
-            SlotScheduler(dense_spec, registry=MetricsRegistry(),
+            SlotScheduler(default_pool_spec, registry=MetricsRegistry(),
                           speculate_k=K + 1)
         with pytest.raises(ValueError, match="draft_source"):
-            SlotScheduler(dense_ref, registry=MetricsRegistry(),
+            SlotScheduler(default_pool_ref, registry=MetricsRegistry(),
                           draft_source=NGramDraftSource())
         # the default draft source rides in with speculate_k
-        sched = SlotScheduler(dense_spec, registry=MetricsRegistry(),
+        sched = SlotScheduler(default_pool_spec, registry=MetricsRegistry(),
                               speculate_k=K)
         assert isinstance(sched.draft_source, NGramDraftSource)
 
-    @pytest.mark.parametrize("kind", ["dense", "paged"])
+    @pytest.mark.parametrize("kind", ["default_pool", "paged"])
     def test_advance_by_accepted_and_rejected_kv_invisible(
             self, kind, request):
-        """The satellite-4 invariant on BOTH engines: the cursor moves
+        """The satellite-4 invariant on both pool sizes: the cursor moves
         by exactly the accepted count, and a stream that suffered
         rejections stays bitwise the non-speculative greedy stream —
         rejected rows land above the cursor where no read masks them
@@ -396,8 +363,7 @@ class TestEngineVerify:
             assert c == (K + 1 if correct else 1)
             assert int(counts[1]) == 0          # inactive slot frozen
             got.extend(int(t) for t in out[0, :c])
-            cursor = (eng.allocator.lengths if kind == "paged"
-                      else np.asarray(eng.cache.lengths))
+            cursor = eng.allocator.lengths
             # advance-by-accepted: prompt KV + every emitted-and-
             # consumed token, never the rejected tail
             assert int(cursor[0]) == len(prompt) + len(got) - 1
@@ -417,10 +383,10 @@ class TestSchedulerSpeculative:
                          for p in self.PROMPTS], no_recompile=True)
         return out, reg
 
-    @pytest.mark.parametrize("kind", ["dense", "paged"])
+    @pytest.mark.parametrize("kind", ["default_pool", "paged"])
     def test_greedy_stream_parity_zero_recompiles(self, kind, request):
         """The tentpole acceptance bar: greedy speculative streams are
-        bitwise-identical to non-speculative greedy on both engines,
+        bitwise-identical to non-speculative greedy on both pool sizes,
         with the whole draft/verify/retire loop running under the live
         recompile guard (run(no_recompile=True))."""
         ref, _ = self._run(request.getfixturevalue(f"{kind}_ref"), 0)
@@ -440,7 +406,7 @@ class TestSchedulerSpeculative:
         assert snap["serve/decode_steps"] < sum(
             7 - 1 for _ in self.PROMPTS)
 
-    @pytest.mark.parametrize("kind", ["dense", "paged"])
+    @pytest.mark.parametrize("kind", ["default_pool", "paged"])
     def test_poison_mid_verify_retires_clean(self, kind, request,
                                              tmp_path):
         """Satellite-4 negative test: a slot poisoned MID-VERIFY is
