@@ -11,12 +11,15 @@ never materialize in HBM, so memory is O(sq·d) instead of O(sq·sk).
 Forward: grid ``(b*h, sq/block_q, sk/block_k)`` with the kv dimension
 innermost; running ``(m, l, acc)`` live in VMEM scratch across kv steps
 (TPU grid execution is sequential per core, the canonical Pallas flash
-pattern). Backward recomputes probabilities from the saved per-row logsumexp
-(same recompute-not-store trade as the CUDA dgrad kernels) in two kernels:
-one gridded over q blocks (dq), one over kv blocks (dk, dv). Rows that are
-fully masked out save ``lse = +inf`` so the backward's
-``p = exp(s - lse)`` underflows to exactly zero instead of producing
-``exp(-inf - -inf) = 1`` garbage (ADVICE r1).
+pattern), ``m`` and ``l`` the same in all 128 lanes of a row. Backward
+recomputes probabilities from the saved per-row logsumexp (same
+recompute-not-store trade as the CUDA dgrad kernels) in ONE kernel gridded
+``(b*h, sk/block_k, sq/block_q)``: dk and dv gather a kv tile at a time, dq
+of the whole head in VMEM. Both kernels walk a grid tile in sub-tiles and
+do for each only what its place under the causal band needs (see "the
+schedule" below). Rows that are fully masked out save ``lse = +inf`` so the
+backward's ``p = exp(s - lse)`` underflows to exactly zero instead of
+producing ``exp(-inf - -inf) = 1`` garbage (ADVICE r1).
 
 ``bias`` is an additive score bias (the general form of the reference's
 padding masks — additive -10000 fills, ``scaled_masked_softmax.h``). It is
@@ -93,17 +96,19 @@ def _unpack_seed(hi_f, lo_f):
     return (jax.lax.shift_left(hi, 16) | lo).astype(jnp.uint32)
 
 
-def _keep_mask(seed2, bh, i, j, block_q, block_k, rate):
-    """Counter-based dropout keep mask for score block (i, j) of batch-head
-    ``bh`` — the ``philox.cuh`` analog. ``seed2`` is the ``(hi, lo)`` fp32
-    pair from :func:`_pack_seed`. Depends only on the *global*
-    (seed, bh, row, col) coordinates, so every kernel (fwd, dq, dkv, dbias)
-    and the host-side test reference regenerate the identical mask."""
+def _keep_mask(seed2, bh, row0, col0, shape, rate):
+    """Counter-based dropout keep mask for the ``shape`` score tile whose
+    first entry is global ``(row0, col0)`` of batch-head ``bh`` — the
+    ``philox.cuh`` analog. ``seed2`` is the ``(hi, lo)`` fp32 pair from
+    :func:`_pack_seed`. Depends only on the *global* (seed, bh, row, col)
+    coordinates, so every kernel (fwd, dq, dkv, dbias), whatever tile or
+    sub-tile it walks, and the host-side test reference regenerate the
+    identical mask."""
     seed = _unpack_seed(seed2[0], seed2[1])
-    row = (i * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)).astype(jnp.uint32)
-    col = (j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)).astype(jnp.uint32)
+    row = (row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+           ).astype(jnp.uint32)
+    col = (col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+           ).astype(jnp.uint32)
     h = _mix32(seed ^ _mix32(jnp.asarray(bh).astype(jnp.uint32)))
     # two finalizer rounds over the combined counter (single-round murmur
     # finalizers show detectable structure; a second round is cheap)
@@ -121,7 +126,7 @@ def dropout_keep_mask(seed, b, h, sq, sk, rate):
     seed2 = _pack_seed(seed)
     bh_ids = jnp.arange(b * h, dtype=jnp.int32)
     masks = jax.vmap(
-        lambda bh: _keep_mask((seed2[0], seed2[1]), bh, 0, 0, sq, sk, rate))(
+        lambda bh: _keep_mask((seed2[0], seed2[1]), bh, 0, 0, (sq, sk), rate))(
             bh_ids)
     return masks.reshape(b, h, sq, sk)
 
@@ -219,24 +224,217 @@ def mha_reference(q, k, v, bias=None, causal=False,
 
 
 # ---------------------------------------------------------------------------
+# the schedule: which sub-tiles of a grid tile run, and which are masked
+# ---------------------------------------------------------------------------
+#
+# A grid tile (block_q x block_k) is walked in sub-tiles (sub_q x sub_k). A
+# causal sub-tile is one of three kinds, told from where it lies: wholly
+# above the diagonal (or left of the window) - never computed, and never
+# fetched where that holds of the whole grid tile; wholly visible -
+# computed with no mask work at all; crossed by an edge - masked, and
+# where it is a square ON the diagonal its rows are walked in strips, each
+# against only the columns it can see. ``_key_spans`` gives the kinds as
+# ranges of sub-tile indices; it takes python ints (``flash_tile_plan``,
+# the tests) and traced scalars (the kernels) alike.
+#
+# What the v5e taught about sizes (my chip runs, PR 33; PERF.md section 6):
+# a sub-tile's chain - product, row max, exp, row sum, product - is one
+# dependent chain, and the compiler overlaps nothing across the steps of a
+# loop, so a 128 x 128 sub-tile costs what its latencies add up to (0.54 us,
+# 51 cycles a score vreg) and a 512 x 512 one is bound by the MXU (head dim
+# 64 half-fills it in every product). Sub-tiles are therefore LARGE, and
+# the diagonal's dead half is cut inside one straight-line body, not by
+# smaller sub-tiles.
+
+def _clamp(x, lo, hi):
+    if isinstance(x, int) and isinstance(lo, int):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+def _key_spans(row0, sub_q, col_base, n_c, sub_k, offset, causal, window):
+    """For the rows ``[row0, row0 + sub_q)`` and the ``n_c`` key sub-tiles
+    from column ``col_base``: ``(lo, a, b, hi)`` - sub-tiles ``[lo, a)`` are
+    crossed by the window's left edge, ``[a, b)`` are wholly visible,
+    ``[b, hi)`` are crossed by the diagonal, the rest never run."""
+    if not causal:
+        return 0, 0, n_c, n_c
+    vis = row0 + offset + 1 - col_base      # the first row sees [.., vis)
+    b = _clamp(vis // sub_k, 0, n_c)
+    hi = _clamp((vis + sub_q - 1 + sub_k - 1) // sub_k, 0, n_c)
+    if window is None:
+        return 0, 0, b, hi
+    lo = _clamp((vis - window) // sub_k, 0, n_c)
+    a = _clamp((vis + sub_q - 1 - window + sub_k - 1) // sub_k, lo, hi)
+    return lo, a, _clamp(b, a, hi), hi
+
+
+def flash_tile_plan(sq, sk, block_q, block_k, sub_q, sub_k, causal=True,
+                    window=None):
+    """What the schedule does for one head: sub-tiles ``skipped``,
+    ``unmasked`` and ``masked``, and ``scores_computed`` against
+    ``scores_needed``. The kernels walk exactly these ranges
+    (:func:`_key_spans`, :func:`_strips`); a pure function of the static
+    shapes."""
+    offset, n_c = sk - sq, block_k // sub_k
+    plan = dict(skipped=0, unmasked=0, masked=0)
+    for row0 in range(0, sq, sub_q):
+        for col_base in range(0, sk, block_k):
+            lo, a, b, hi = _key_spans(row0, sub_q, col_base, n_c, sub_k,
+                                      offset, causal, window)
+            plan["unmasked"] += b - a
+            plan["masked"] += (a - lo) + (hi - b)
+            plan["skipped"] += n_c - (hi - lo)
+    diagonal = _on_diagonal((block_q, block_k, sub_q, sub_k), offset, causal,
+                            window, True)
+    plan["scores_computed"] = plan["unmasked"] * sub_q * sub_k + sum(
+        (rs.stop - rs.start) * ks.stop
+        for rs, ks in _strips(sub_q, sub_k, diagonal)) * plan["masked"]
+    rows = np.arange(sq) + offset + 1       # columns [.., rows) are seen
+    seen = np.clip(rows, 0, sk) if causal else np.full(sq, sk)
+    if window is not None:
+        seen = seen - np.clip(rows - window, 0, sk)
+    plan["scores_needed"] = int(seen.sum())
+    return plan
+
+
+def _span(lo, hi, n, body):
+    """Run ``body(c)`` for the sub-tiles ``c`` in ``[lo, hi)`` of ``n``.
+    Where there is one sub-tile the loop is a branch and ``c`` the python
+    int 0; state lives in refs, so nothing is carried."""
+    if isinstance(lo, int) and isinstance(hi, int) and hi - lo <= 1:
+        if hi > lo:
+            body(lo)
+    elif n == 1:
+        pl.when(hi > lo)(lambda: body(0))
+    else:
+        jax.lax.fori_loop(lo, hi, lambda c, _: body(c) or 0, 0)
+
+
+def _sub(n, idx, size):
+    """``(start, slice)`` of sub-tile ``idx`` of ``n`` along one dim of a
+    block. Static where there is one: lane-dim slices (bias, segment ids)
+    are only ever static."""
+    if n == 1:
+        start = 0
+    elif isinstance(idx, int):
+        start = idx * size
+    else:
+        start = pl.multiple_of(idx * size, size)
+    return start, pl.ds(start, size)
+
+
+def _folds(scale, dtype):
+    """Whether the softmax scale goes into a ``(rows, d)`` operand instead
+    of the score tile: only where that rounds nothing the score-side
+    multiply would not (float32 operands, or a power of two)."""
+    return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
+
+
+def _scores(bias_ref, seed_ref, q_seg_ref, kv_seg_ref, bh, scale, offset,
+            window, dropout_rate, s, rows, cols, row0, col0, masked):
+    """``(scores, dead, keep)`` of the raw product ``s``, the score sub-tile
+    at global ``(row0, col0)`` (block slices ``rows``, ``cols``): scaled
+    (``scale`` None where it is folded into an operand), biased and masked
+    - the one place the kernels build a mask; a kernel binds the arguments
+    before ``s`` once. ``dead`` is None where
+    nothing is masked: the causal / window part is ``col - row`` against
+    one scalar, and only where ``masked`` says an edge crosses the
+    sub-tile. Segment ids are the TPU-native form of the reference's varlen
+    ``cu_seqlens`` packing
+    (``reference:apex/contrib/csrc/fmha/fmha_api.cpp:420``). ``keep``: the
+    dropout mask, None without dropout."""
+    if scale is not None:
+        s = s * scale
+    if bias_ref is not None:   # (1|bq, bk) broadcasts over the block
+        s = s + bias_ref[0, 0, rows if bias_ref.shape[2] > 1 else slice(None),
+                         cols]
+    dead = None
+    if masked:
+        diff = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+        edge = row0 + offset - col0
+        dead = diff > edge
+        if window is not None:
+            dead = dead | (diff <= edge - window)
+    if q_seg_ref is not None:
+        apart = (q_seg_ref[0, 0, rows][:, None]
+                 != kv_seg_ref[0, 0, cols][None, :])
+        dead = apart if dead is None else dead | apart
+    if dead is not None:
+        s = jnp.where(dead, NEG_INF, s)
+    keep = None
+    if dropout_rate > 0.0:
+        keep = _keep_mask((seed_ref[0], seed_ref[1]), bh, row0, col0, s.shape,
+                          dropout_rate)
+    return s, dead, keep
+
+
+# Row strips a masked sub-tile ON the diagonal is walked in (my chip runs,
+# PR 33: 2 beats 1 and 4, forward and backward: four strips are four short
+# chains again)
+_STRIPS = 2
+
+
+def _on_diagonal(tile, offset, causal, window, plain):
+    """Whether every masked sub-tile is a square ON the diagonal (its first
+    row sees exactly its first column): then its rows are walked in strips,
+    each against only the columns it can see (:func:`_strips`). ``plain``:
+    no bias and no segment ids, whose blocks a strip would slice."""
+    _, _, sub_q, sub_k = tile
+    return bool(causal and window is None and plain and sub_q == sub_k
+                and offset % sub_k == 0 and sub_q % (_STRIPS * _LANES) == 0)
+
+
+def _strips(sub_q, sub_k, diagonal):
+    """``(rows, cols)`` slices of a sub-tile to compute: all of it, or on
+    the diagonal ``_STRIPS`` row strips with the columns at or under each."""
+    if not diagonal:
+        return [(slice(0, sub_q), slice(0, sub_k))]
+    h = sub_q // _STRIPS
+    return [(slice(t * h, (t + 1) * h), slice(0, (t + 1) * h))
+            for t in range(_STRIPS)]
+
+
+def _stack(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+_LANES = 128
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T
+_NN = (((1,), (0,)), ((), ()))     # a @ b
+_TN = (((0,), (0,)), ((), ()))     # a.T @ b
+
+
+def _lanes(x, n):
+    """A per-row statistic held the same in all 128 lanes, as ``n`` lanes.
+    The running max and sum live that way: kept as ``(rows, 1)`` columns
+    they cost a lane broadcast at every use and a masked store at every
+    update, which was most of what a tile cost beside its products."""
+    return jnp.tile(x, (1, -(-n // _LANES)))[:, :n]
+
+
+# ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
-def _seg_mask(q_seg_ref, kv_seg_ref):
-    """(block_q, block_k) keep-mask from packed-sequence segment ids — the
-    TPU-native form of the reference's varlen ``cu_seqlens`` packing
-    (``reference:apex/contrib/csrc/fmha/fmha_api.cpp:420``): tokens attend
-    only within their own segment."""
-    q_seg = q_seg_ref[0, 0]        # (block_q,)
-    kv_seg = kv_seg_ref[0, 0]      # (block_k,)
-    return q_seg[:, None] == kv_seg[None, :]
-
-
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
                 kv_seg_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k,
-                n_kv, offset, dropout_rate, window=None):
+                acc_ref, m_ref, l_ref, *, scale, causal, tile, n_kv, offset,
+                dropout_rate, window=None):
     bh, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    block_q, block_k, sub_q, sub_k = tile
+    n_r, n_c = block_q // sub_q, block_k // sub_k
+    fold = _folds(scale, q_ref.dtype)
+    # a row can be wholly masked in a sub-tile before it has met a live
+    # column only with segments, a window or sk < sq: m is still NEG_INF
+    # there and exp(s - m) == 1 on the masked entries, zeroed explicitly
+    rezero = q_seg_ref is not None or window is not None or offset < 0
+    diagonal = _on_diagonal(tile, offset, causal, window,
+                            bias_ref is None and q_seg_ref is None)
+    scores = functools.partial(
+        _scores, bias_ref, seed_ref, q_seg_ref, kv_seg_ref, bh,
+        None if fold else scale, offset, window, dropout_rate)
 
     @pl.when(j == 0)
     def _():
@@ -244,56 +442,48 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # causal: skip blocks entirely above the diagonal (with the sk-sq
-    # offset so cross-shaped causal matches mha_reference)
-    run = (j * block_k <= i * block_q + block_q - 1 + offset) if causal else True
-    if window is not None:
-        # ... and blocks wholly left of the window of the block's first row
-        run = run & (j * block_k + block_k - 1
-                     > i * block_q + offset - window)
+    def row_body(r):
+        (_, rows), row0 = _sub(n_r, r, sub_q), i * block_q + r * sub_q
 
-    @pl.when(run)
-    def _():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0]  # (1|bq, bk) broadcasts over the block
-        if causal:
-            row = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            dead = col > row + offset
-            if window is not None:
-                dead = dead | (col <= row + offset - window)
-            s = jnp.where(dead, NEG_INF, s)
-        if q_seg_ref is not None:
-            smask = _seg_mask(q_seg_ref, kv_seg_ref)
-            s = jnp.where(smask, s, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if causal:
-            # rows fully masked within a running block have m_new == NEG_INF,
-            # so exp(s - m_new) == 1 on masked entries — zero them explicitly
-            p = jnp.where(dead, 0.0, p)
-        if q_seg_ref is not None:
-            p = jnp.where(smask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        # softmax normalizer uses the UNdropped probabilities
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:] = m_new
-        if dropout_rate > 0.0:
-            keep = _keep_mask((seed_ref[0], seed_ref[1]), bh, i, j,
-                              block_q, block_k,
-                              dropout_rate)
-            p = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
-        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv
+        def sub_tile(c, masked):
+            (_, cols), col0 = _sub(n_c, c, sub_k), j * block_k + c * sub_k
+            q, k, v = q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :]
+            if fold:
+                q = q * scale
+            m_prev, l_prev, acc = m_ref[rows, :], l_ref[rows, :], \
+                acc_ref[rows, :]
+            ms, ls, accs = [], [], []
+            for rs, ks in _strips(sub_q, sub_k, masked and diagonal):
+                s, dead, keep = scores(
+                    jax.lax.dot_general(q[rs], k[ks], _NT,
+                                        preferred_element_type=jnp.float32),
+                    rows, cols, row0 + rs.start, col0, masked)
+                m_new = jnp.maximum(m_prev[rs],
+                                    jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+                if dead is not None and rezero:
+                    p = jnp.where(dead, 0.0, p)
+                corr = jnp.exp(m_prev[rs] - m_new)
+                # softmax normalizer uses the UNdropped probabilities
+                ls.append(l_prev[rs] * corr
+                          + jnp.sum(p, axis=1, keepdims=True))
+                ms.append(m_new)
+                if keep is not None:
+                    p = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
+                accs.append(acc[rs] * _lanes(corr, v.shape[1])
+                            + jax.lax.dot_general(
+                                p.astype(v.dtype), v[ks], _NN,
+                                preferred_element_type=jnp.float32))
+            m_ref[rows, :], l_ref[rows, :] = _stack(ms), _stack(ls)
+            acc_ref[rows, :] = _stack(accs)
+
+        lo, a, b, hi = _key_spans(row0, sub_q, j * block_k, n_c, sub_k,
+                                  offset, causal, window)
+        _span(lo, a, n_c, lambda c: sub_tile(c, True))
+        _span(a, b, n_c, lambda c: sub_tile(c, False))
+        _span(b, hi, n_c, lambda c: sub_tile(c, True))
+
+    _span(0, n_r, n_r, row_body)
 
     @pl.when(j == n_kv - 1)
     def _():
@@ -301,90 +491,116 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
         # fully-masked rows (l==0): 0 output, and lse=+inf so the backward's
         # exp(s - lse) underflows to 0 for every entry of the row
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / _lanes(safe_l, acc_ref.shape[1])
+                    ).astype(o_ref.dtype)
         lse_ref[0] = jnp.where(l == 0.0, jnp.inf,
-                               m_ref[:] + jnp.log(safe_l))
+                               m_ref[:] + jnp.log(safe_l))[:, :1]
 
 
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
 
-def _recompute_p_ds(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
-                    kv_seg_ref, do_ref, lse_ref,
-                    delta_ref, bh, i, j, *, scale, causal, block_q, block_k,
-                    offset, dropout_rate):
-    """Shared backward recompute: p = exp(s - lse) with causal masking
-    (including the explicit p-zeroing of masked entries — masked rows of a
-    running block have lse = +inf so exp underflows, and causally-masked
-    entries are zeroed directly), plus ds = p * (dp_eff - delta).
+def _p_ds(scores, dropout_rate, q, k, v, do, lse, delta, *where):
+    """Shared backward recompute on one score sub-tile: ``p = exp(s - lse)``
+    and ``ds = p * (dp_eff - delta)``; ``scores`` is :func:`_scores` bound
+    to the kernel's side operands, ``where`` its ``(rows, cols, row0, col0,
+    masked)``. A masked score is NEG_INF, so ``p``
+    is exactly zero there whether the row's lse is finite or +inf (a fully
+    masked row): no second select.
 
     With dropout the identical keep mask is regenerated from the counters:
     ``p_eff`` (for dv) is the dropped-and-rescaled probability, and
     ``dp_eff = keep ⊙ dp/(1-rate)`` feeds ds — the exact transpose of the
     forward's dropout-after-normalizer placement.
     """
-    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if bias_ref is not None:
-        s = s + bias_ref[0, 0]  # (1|bq, bk) broadcasts over the block
-    if causal:
-        row = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        col = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(col > row + offset, NEG_INF, s)
-    if q_seg_ref is not None:
-        # masked s = -1e30 underflows through exp(s - lse) whether lse is
-        # finite (row has valid keys) or +inf (fully masked row)
-        s = jnp.where(_seg_mask(q_seg_ref, kv_seg_ref), s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0])
-    if causal:
-        p = jnp.where(col > row + offset, 0.0, p)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    if dropout_rate > 0.0:
-        keep = _keep_mask((seed_ref[0], seed_ref[1]), bh, i, j,
-                          block_q, block_k,
-                          dropout_rate)
+    s, _, keep = scores(
+        jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32),
+        *where)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+    p_eff = p
+    if keep is not None:
         inv = 1.0 / (1.0 - dropout_rate)
         p_eff = jnp.where(keep, p, 0.0) * inv
-        dp_eff = jnp.where(keep, dp, 0.0) * inv
-    else:
-        p_eff, dp_eff = p, dp
-    ds = p * (dp_eff - delta_ref[0])
-    return p_eff, ds
+        dp = jnp.where(keep, dp, 0.0) * inv
+    return p_eff, p * (dp - delta)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
-                   kv_seg_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_acc, *, scale, causal, block_q,
-                   block_k, n_kv, offset, dropout_rate):
-    bh, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
+                kv_seg_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
+                dv_ref, dq_acc, dk_acc, dv_acc, *, scale, causal, tile, n_q,
+                n_kv, offset, dropout_rate):
+    """dq, dk and dv in ONE pass over the scores: grid ``(bh, n_kv, n_q)``,
+    q tiles innermost. dk and dv of kv tile ``j`` gather over its q tiles;
+    dq of the WHOLE head gathers in ``dq_acc`` (its block index is fixed
+    while the head runs, so it is written back once, after the head's last
+    step). Five products and one exp a sub-tile where a dq kernel and a
+    dk/dv kernel took seven and two."""
+    bh = pl.program_id(0)
+    j, i = pl.program_id(1), pl.program_id(2)
+    block_q, block_k, sub_q, sub_k = tile
+    n_r, n_c = block_q // sub_q, block_k // sub_k
+    fold = _folds(scale, q_ref.dtype)
 
-    @pl.when(j == 0)
+    @pl.when(jnp.logical_and(j == 0, i == 0))
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = (j * block_k <= i * block_q + block_q - 1 + offset) if causal else True
-
-    @pl.when(run)
+    @pl.when(i == 0)
     def _():
-        _, ds = _recompute_p_ds(q_ref, k_ref, v_ref, bias_ref, seed_ref,
-                                q_seg_ref, kv_seg_ref,
-                                do_ref, lse_ref, delta_ref, bh, i, j,
-                                scale=scale, causal=causal, block_q=block_q,
-                                block_k=block_k, offset=offset,
-                                dropout_rate=dropout_rate)
-        k = k_ref[0]
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(j == n_kv - 1)
+    diagonal = _on_diagonal(tile, offset, causal, None,
+                            bias_ref is None and q_seg_ref is None)
+    p_ds = functools.partial(
+        _p_ds, functools.partial(
+            _scores, bias_ref, seed_ref, q_seg_ref, kv_seg_ref, bh,
+            None if fold else scale, offset, None, dropout_rate),
+        dropout_rate)
+
+    def row_body(r):
+        (r0, rows), row0 = _sub(n_r, r, sub_q), i * block_q + r * sub_q
+
+        def sub_tile(c, masked):
+            (c0, cols), col0 = _sub(n_c, c, sub_k), j * block_k + c * sub_k
+            q, do, k = q_ref[0, rows, :], do_ref[0, rows, :], k_ref[0, cols, :]
+            v, lse, delta = v_ref[0, cols, :], lse_ref[0, rows, :], \
+                delta_ref[0, rows, :]
+            qs = q * scale if fold else q
+            for rs, ks in _strips(sub_q, sub_k, masked and diagonal):
+                high = rs.stop - rs.start
+                p, ds = p_ds(qs[rs], k[ks], v[ks], do[rs], lse[rs],
+                             delta[rs], rows, cols, row0 + rs.start, col0,
+                             masked)
+                ds = ds.astype(q.dtype)
+                under = pl.ds(c0, ks.stop)
+                dv_acc[under, :] += jax.lax.dot_general(
+                    p.astype(do.dtype), do[rs], _TN,
+                    preferred_element_type=jnp.float32)
+                dk_acc[under, :] += jax.lax.dot_general(
+                    ds, q[rs], _TN, preferred_element_type=jnp.float32)
+                dq_acc[pl.ds(pl.multiple_of(
+                    i * block_q + r0 + rs.start, high), high), :] += \
+                    jax.lax.dot_general(ds, k[ks], _NN,
+                                        preferred_element_type=jnp.float32)
+
+        _, _, b, hi = _key_spans(row0, sub_q, j * block_k, n_c, sub_k,
+                                 offset, causal, None)
+        _span(0, b, n_c, lambda c: sub_tile(c, False))
+        _span(b, hi, n_c, lambda c: sub_tile(c, True))
+
+    _span(0, n_r, n_r, row_body)
+
+    @pl.when(i == n_q - 1)
     def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(j == n_kv - 1, i == n_q - 1))
+    def _():
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
@@ -397,7 +613,7 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
     innermost (and, when the bias broadcasts over sq, the q-blocks too via
     ``swap``), so the output tile is revisited on consecutive steps and the
     reduction accumulates in VMEM — dbias costs O(|bias|) HBM, never the
-    full (b·h, sq, sk) score matrix."""
+    full (b·h, sq, sk) score matrix. One sub-tile a grid tile."""
     g, a, b_, r = (pl.program_id(n) for n in range(4))
     bh = bh_fn(g, r)  # program_id must be read at kernel top level, not
     # inside a pl.when branch (interpret mode cannot substitute it there)
@@ -416,52 +632,17 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
 
     @pl.when(run)
     def _():
-        _, ds = _recompute_p_ds(q_ref, k_ref, v_ref, bias_ref, seed_ref,
-                                q_seg_ref, kv_seg_ref,
-                                do_ref, lse_ref, delta_ref, bh,
-                                i, j, scale=scale, causal=causal,
-                                block_q=block_q, block_k=block_k,
-                                offset=offset, dropout_rate=dropout_rate)
+        _, ds = _p_ds(
+            functools.partial(_scores, bias_ref, seed_ref, q_seg_ref,
+                              kv_seg_ref, bh, scale, offset, None,
+                              dropout_rate),
+            dropout_rate, q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
+            delta_ref[0], slice(None), slice(None), i * block_q, j * block_k,
+            causal)
         if swap:
             db_ref[0, 0] += jnp.sum(ds, axis=0, keepdims=True)
         else:
             db_ref[0, 0] += ds
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, q_seg_ref,
-                    kv_seg_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                    causal, block_q, block_k, n_q, offset, dropout_rate):
-    bh = pl.program_id(0)
-    j, i = pl.program_id(1), pl.program_id(2)  # kv outer, q inner
-
-    @pl.when(i == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    run = (j * block_k <= i * block_q + block_q - 1 + offset) if causal else True
-
-    @pl.when(run)
-    def _():
-        p, ds = _recompute_p_ds(q_ref, k_ref, v_ref, bias_ref, seed_ref,
-                                q_seg_ref, kv_seg_ref,
-                                do_ref, lse_ref, delta_ref, bh, i, j,
-                                scale=scale, causal=causal, block_q=block_q,
-                                block_k=block_k, offset=offset,
-                                dropout_rate=dropout_rate)
-        q, do = q_ref[0], do_ref[0]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    @pl.when(i == n_q - 1)
-    def _():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -523,69 +704,80 @@ def _seg_specs(h, block_q, block_k, *, swapped):
             pl.BlockSpec((1, 1, block_k), kv_map, memory_space=pltpu.VMEM))
 
 
-def _fwd_pallas(q3, k3, v3, bias4, seed, segs, h, *, scale, causal, block_q,
-                block_k, dropout_rate, window=None, kv_heads=None):
-    bh, sq, d = q3.shape
-    sk = k3.shape[1]
-    n_q, n_kv = sq // block_q, sk // block_k
-    has_bias = bias4 is not None
+def _live_kv(i, j, block_q, block_k, offset, n_kv, causal, window=None):
+    """Index-map clamp of kv grid tile ``j`` to those q tile ``i`` can see:
+    a tile above the diagonal or left of the window resolves to its
+    neighbour, so its DMA is elided, not masked after the read."""
+    if not causal:
+        return j
+    hi = (i * block_q + block_q - 1 + offset) // block_k
+    jj = jnp.minimum(j, jnp.clip(hi, 0, n_kv - 1))
+    if window is not None:
+        lo = jnp.maximum(i * block_q + offset - window + 1, 0) // block_k
+        jj = jnp.maximum(jj, lo)
+    return jj
+
+
+def _operands(q3, k3, v3, bias4, seed, segs, h, block_q, block_k, q_spec,
+              kv_spec, *, dropout_rate, swapped):
+    """``(in_specs, args, split)`` of what the forward and both backward
+    kernels share: q, k, v and whichever of bias, dropout seed and segment
+    ids the call has. ``split(refs)`` gives the seven refs the kernels
+    name (None where absent) and the rest."""
+    has_bias, has_seg = bias4 is not None, segs is not None
     has_drop = dropout_rate > 0.0
-    has_seg = segs is not None
-
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    if window is None and kv_heads is None:
-        kv_map = lambda b, i, j: (b, j, 0)
-    else:
-        # the forward-only serving form: ``k3``/``v3`` hold ``kv_heads``
-        # heads a sequence and query head ``g`` reads KV head
-        # ``g // (h // kv_heads)`` where it lies (no repeated copy); the
-        # fetch is clamped to the blocks the q block's rows can see, so a
-        # block above the diagonal or left of the window resolves to the
-        # block before it and its DMA is elided, not masked after the read
-        kvh = h if kv_heads is None else kv_heads
-        offset = sk - sq
-
-        def kv_map(b, i, j):
-            hi = (i * block_q + block_q - 1 + offset) // block_k
-            jj = jnp.minimum(j, hi) if causal else j
-            if window is not None:
-                lo = jnp.maximum(i * block_q + offset - window + 1, 0) \
-                    // block_k
-                jj = jnp.maximum(jj, lo)
-            return ((b // h) * kvh + (b % h) // (h // kvh), jj, 0)
-    kv_spec = pl.BlockSpec((1, block_k, d), kv_map,
-                           memory_space=pltpu.VMEM)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q3, k3, v3]
+    in_specs, args = [q_spec, kv_spec, kv_spec], [q3, k3, v3]
     if has_bias:
-        in_specs.append(_bias_spec(bias4, h, block_q, block_k, swapped=False))
+        in_specs.append(_bias_spec(bias4, h, block_q, block_k,
+                                   swapped=swapped))
         args.append(bias4)
     if has_drop:
         in_specs.append(_seed_spec())
         args.append(seed)
     if has_seg:
-        sq_spec, sk_spec = _seg_specs(h, block_q, block_k, swapped=False)
-        in_specs += [sq_spec, sk_spec]
+        in_specs += _seg_specs(h, block_q, block_k, swapped=swapped)
         args += list(segs)
 
-    def kernel(*refs):
+    def split(refs):
         refs = list(refs)
-        q_ref, k_ref, v_ref = refs[:3]
-        nxt = 3
-        bias_ref = refs[nxt] if has_bias else None
-        nxt += has_bias
-        seed_ref = refs[nxt] if has_drop else None
-        nxt += has_drop
-        qs_ref = refs[nxt] if has_seg else None
-        ks_ref = refs[nxt + 1] if has_seg else None
-        nxt += 2 * has_seg
-        o_ref, lse_ref, acc, m, l = refs[nxt:]
-        _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, qs_ref, ks_ref,
-                    o_ref, lse_ref,
-                    acc, m, l, scale=scale, causal=causal, block_q=block_q,
-                    block_k=block_k, n_kv=n_kv, offset=sk - sq,
-                    dropout_rate=dropout_rate, window=window)
+        nxt = 3 + has_bias + has_drop
+        return (refs[:3] + [refs[3] if has_bias else None,
+                            refs[3 + has_bias] if has_drop else None,
+                            refs[nxt] if has_seg else None,
+                            refs[nxt + 1] if has_seg else None],
+                refs[nxt + 2 * has_seg:])
+    return in_specs, args, split
+
+
+def _fwd_pallas(q3, k3, v3, bias4, seed, segs, h, *, scale, causal, tile,
+                dropout_rate, window=None, kv_heads=None):
+    bh, sq, d = q3.shape
+    sk = k3.shape[1]
+    block_q, block_k = tile[:2]
+    n_q, n_kv = sq // block_q, sk // block_k
+
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+                          memory_space=pltpu.VMEM)
+    # ``k3``/``v3`` hold ``kv_heads`` heads a sequence (the forward-only
+    # serving form) and query head ``g`` reads KV head
+    # ``g // (h // kv_heads)`` where it lies: no repeated copy
+    kvh = h if kv_heads is None else kv_heads
+
+    def kv_map(b, i, j):
+        return ((b // h) * kvh + (b % h) // (h // kvh),
+                _live_kv(i, j, block_q, block_k, sk - sq, n_kv, causal,
+                         window), 0)
+    kv_spec = pl.BlockSpec((1, block_k, d), kv_map,
+                           memory_space=pltpu.VMEM)
+    in_specs, args, split = _operands(
+        q3, k3, v3, bias4, seed, segs, h, block_q, block_k, q_spec, kv_spec,
+        dropout_rate=dropout_rate, swapped=False)
+
+    def kernel(*refs):
+        shared, rest = split(refs)
+        _fwd_kernel(*shared, *rest, scale=scale, causal=causal, tile=tile,
+                    n_kv=n_kv, offset=sk - sq, dropout_rate=dropout_rate,
+                    window=window)
 
     named = {} if window is None and kv_heads is None \
         else {"name": "flash_attention_window"}
@@ -599,8 +791,8 @@ def _fwd_pallas(q3, k3, v3, bias4, seed, segs, h, *, scale, causal, block_q,
         out_shape=(_sds((bh, sq, d), q3.dtype, q3),
                    _sds((bh, sq, 1), jnp.float32, q3)),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32)],
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
         interpret=_interp(),
         **named,
     )(*args)
@@ -608,118 +800,60 @@ def _fwd_pallas(q3, k3, v3, bias4, seed, segs, h, *, scale, causal, block_q,
 
 
 def _bwd_pallas(q3, k3, v3, bias4, seed, segs, h, do3, lse, delta, *, scale,
-                causal, block_q, block_k, dropout_rate):
+                causal, tile, dropout_rate):
+    """One kernel, grid ``(bh, n_kv, n_q)`` with the q tiles innermost: q
+    tiles above the diagonal are neither computed nor fetched."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
+    offset = sk - sq
+    block_q, block_k = tile[:2]
     n_q, n_kv = sq // block_q, sk // block_k
-    has_bias = bias4 is not None
-    has_drop = dropout_rate > 0.0
-    has_seg = segs is not None
 
-    # --- dq: grid (bh, n_q, n_kv), kv innermost ---
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+    def live_q(j, i):
+        if not causal:
+            return i
+        return jnp.maximum(i, jnp.clip((j * block_k - offset) // block_q,
+                                       0, n_q - 1))
+    q_spec = pl.BlockSpec((1, block_q, d),
+                          lambda b, j, i: (b, live_q(j, i), 0),
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0),
                            memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0),
+    row_spec = pl.BlockSpec((1, block_q, 1),
+                            lambda b, j, i: (b, live_q(j, i), 0),
                             memory_space=pltpu.VMEM)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q3, k3, v3]
-    if has_bias:
-        in_specs.append(_bias_spec(bias4, h, block_q, block_k, swapped=False))
-        args.append(bias4)
-    if has_drop:
-        in_specs.append(_seed_spec())
-        args.append(seed)
-    if has_seg:
-        sq_spec, sk_spec = _seg_specs(h, block_q, block_k, swapped=False)
-        in_specs += [sq_spec, sk_spec]
-        args += list(segs)
-    in_specs += [q_spec, row_spec, row_spec]
-    args += [do3, lse, delta]
-
-    def dq_kernel(*refs):
-        refs = list(refs)
-        q_ref, k_ref, v_ref = refs[:3]
-        nxt = 3
-        bias_ref = refs[nxt] if has_bias else None
-        nxt += has_bias
-        seed_ref = refs[nxt] if has_drop else None
-        nxt += has_drop
-        qs_ref = refs[nxt] if has_seg else None
-        ks_ref = refs[nxt + 1] if has_seg else None
-        nxt += 2 * has_seg
-        do_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs[nxt:]
-        _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, qs_ref,
-                       ks_ref, do_ref,
-                       lse_ref, delta_ref, dq_ref, dq_acc, scale=scale,
-                       causal=causal, block_q=block_q, block_k=block_k,
-                       n_kv=n_kv, offset=sk - sq, dropout_rate=dropout_rate)
-
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, n_q, n_kv),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=_sds((bh, sq, d), q3.dtype, q3),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interp(),
-    )(*args)
-
-    # --- dk/dv: grid (bh, n_kv, n_q), q innermost ---
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0),
-                           memory_space=pltpu.VMEM)
-    kv_spec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0),
-                            memory_space=pltpu.VMEM)
-    row_spec2 = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0),
+    head_spec = pl.BlockSpec((1, sq, d), lambda b, j, i: (b, 0, 0),
                              memory_space=pltpu.VMEM)
-    in_specs2 = [q_spec2, kv_spec2, kv_spec2]
-    args2 = [q3, k3, v3]
-    if has_bias:
-        in_specs2.append(_bias_spec(bias4, h, block_q, block_k, swapped=True))
-        args2.append(bias4)
-    if has_drop:
-        in_specs2.append(_seed_spec())
-        args2.append(seed)
-    if has_seg:
-        sq_spec2, sk_spec2 = _seg_specs(h, block_q, block_k, swapped=True)
-        in_specs2 += [sq_spec2, sk_spec2]
-        args2 += list(segs)
-    in_specs2 += [q_spec2, row_spec2, row_spec2]
-    args2 += [do3, lse, delta]
+    in_specs, args, split = _operands(
+        q3, k3, v3, bias4, seed, segs, h, block_q, block_k, q_spec, kv_spec,
+        dropout_rate=dropout_rate, swapped=True)
 
-    def dkv_kernel(*refs):
-        refs = list(refs)
-        q_ref, k_ref, v_ref = refs[:3]
-        nxt = 3
-        bias_ref = refs[nxt] if has_bias else None
-        nxt += has_bias
-        seed_ref = refs[nxt] if has_drop else None
-        nxt += has_drop
-        qs_ref = refs[nxt] if has_seg else None
-        ks_ref = refs[nxt + 1] if has_seg else None
-        nxt += 2 * has_seg
-        (do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
-         dv_acc) = refs[nxt:]
-        _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, qs_ref,
-                        ks_ref, do_ref,
-                        lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                        scale=scale, causal=causal, block_q=block_q,
-                        block_k=block_k, n_q=n_q, offset=sk - sq,
-                        dropout_rate=dropout_rate)
+    def kernel(*refs):
+        shared, rest = split(refs)
+        _bwd_kernel(*shared, *rest, scale=scale, causal=causal, tile=tile,
+                    n_q=n_q, n_kv=n_kv, offset=offset,
+                    dropout_rate=dropout_rate)
 
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    # dq of the whole head is held in VMEM: its float32 gather and its
+    # double-buffered output block; past a few MiB that needs saying
+    held = sq * d * (4 + 2 * q3.dtype.itemsize)
+    roomy = {} if held <= 4 << 20 else dict(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=(24 << 20) + held))
+    return pl.pallas_call(
+        kernel,
         grid=(bh, n_kv, n_q),
-        in_specs=in_specs2,
-        out_specs=(kv_spec2, kv_spec2),
-        out_shape=(_sds((bh, sk, d), k3.dtype, k3),
+        in_specs=in_specs + [q_spec, row_spec, row_spec],
+        out_specs=(head_spec, kv_spec, kv_spec),
+        out_shape=(_sds((bh, sq, d), q3.dtype, q3),
+                   _sds((bh, sk, d), k3.dtype, k3),
                    _sds((bh, sk, d), v3.dtype, v3)),
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interp(),
-    )(*args2)
-    return dq, dk, dv
+        **roomy,
+    )(*args, do3, lse, delta)
 
 
 def _dbias_pallas(q3, k3, v3, bias4, seed, segs, h, do3, lse, delta, *,
@@ -833,26 +967,26 @@ def _dbias_pallas(q3, k3, v3, bias4, seed, segs, h, do3, lse, delta, *,
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash(scale: float, causal: bool, block_q: int, block_k: int,
+def _make_flash(scale: float, causal: bool, tile: tuple,
                 has_bias: bool, need_dbias: bool, h: int,
                 dropout_rate: float, has_seg: bool,
                 checkpoint_names: bool = False):
+    """``tile``: ``(block_q, block_k, sub_q, sub_k)`` (:func:`_auto_block`)."""
+
     def _segs(qs, ks):
         return (qs, ks) if has_seg else None
 
+    def _fwd(q3, k3, v3, bias4, seed, qseg, kseg):
+        return _fwd_pallas(q3, k3, v3, bias4 if has_bias else None, seed,
+                           _segs(qseg, kseg), h, scale=scale, causal=causal,
+                           tile=tile, dropout_rate=dropout_rate)
+
     @jax.custom_vjp
-    def flash(q3, k3, v3, bias4, seed, qseg, kseg):
-        out, _ = _fwd_pallas(q3, k3, v3, bias4 if has_bias else None, seed,
-                             _segs(qseg, kseg),
-                             h, scale=scale, causal=causal, block_q=block_q,
-                             block_k=block_k, dropout_rate=dropout_rate)
-        return out
+    def flash(*args):
+        return _fwd(*args)[0]
 
     def fwd(q3, k3, v3, bias4, seed, qseg, kseg):
-        out, lse = _fwd_pallas(q3, k3, v3, bias4 if has_bias else None, seed,
-                               _segs(qseg, kseg),
-                               h, scale=scale, causal=causal, block_q=block_q,
-                               block_k=block_k, dropout_rate=dropout_rate)
+        out, lse = _fwd(q3, k3, v3, bias4, seed, qseg, kseg)
         if checkpoint_names:
             # Tag the kernel residuals INSIDE the fwd rule (the trace a
             # name-based jax.checkpoint policy sees under AD). Saving the
@@ -870,17 +1004,14 @@ def _make_flash(scale: float, causal: bool, block_q: int, block_k: int,
         q3, k3, v3, bias4, seed, qseg, kseg, out, lse = res
         delta = jnp.sum(do3.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1, keepdims=True)
+        common = dict(scale=scale, causal=causal, dropout_rate=dropout_rate)
         dq, dk, dv = _bwd_pallas(
             q3, k3, v3, bias4 if has_bias else None, seed,
-            _segs(qseg, kseg), h, do3, lse,
-            delta, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, dropout_rate=dropout_rate)
+            _segs(qseg, kseg), h, do3, lse, delta, tile=tile, **common)
         if has_bias and need_dbias:
             dbias = _dbias_pallas(q3, k3, v3, bias4, seed,
-                                  _segs(qseg, kseg), h, do3, lse,
-                                  delta, scale=scale, causal=causal,
-                                  block_q=block_q, block_k=block_k,
-                                  dropout_rate=dropout_rate)
+                                  _segs(qseg, kseg), h, do3, lse, delta,
+                                  block_q=tile[0], block_k=tile[1], **common)
         else:
             # documented: zero unless opted in (scalar placeholder when
             # there is no bias at all)
@@ -892,15 +1023,48 @@ def _make_flash(scale: float, causal: bool, block_q: int, block_k: int,
     return flash
 
 
-def _auto_block(seq: int, choices=(512, 256, 128)) -> int:
-    """Largest tile from ``choices`` dividing ``seq`` (0 if none divide —
-    the caller then falls back to XLA). 512x512 blocks measured ~4x faster
-    than 128x128 on v5e (fewer grid steps, better MXU occupancy; bench
-    seq=4096: 26.5ms vs 123ms fwd+bwd, XLA 86.5ms)."""
-    for c in choices:
-        if seq % c == 0:
-            return c
-    return 0
+def _largest(seq: int, choices) -> int:
+    """Largest of ``choices`` dividing ``seq`` (0 if none does)."""
+    return next((c for c in choices if seq % c == 0), 0)
+
+
+# The backward holds one head's dq in VMEM (float32 gather + output block:
+# 8 bytes an element, 64 MiB of the v5e's 128 here); longer heads go to XLA
+_HEAD_ELEMENTS = 1 << 23
+
+
+def _auto_block(sq: int, sk: int, d: int, block_q: Optional[int] = None,
+                block_k: Optional[int] = None, *, has_bias: bool = False,
+                has_seg: bool = False):
+    """The ``(block_q, block_k, sub_q, sub_k)`` both kernels walk, or None
+    where no tile divides the sequences or a head is too long for the
+    backward (the caller then falls back to XLA). ``block_q`` / ``block_k``
+    are the caller's explicit grid tile, taken as given.
+
+    Chosen on the v5e at the shapes the benchmark's cells run (my chip
+    runs, PR 33, ``scripts/flash_tile_sweep.py``; ms a call, forward /
+    backward, 64 heads x 1,024 x 64 bf16 causal): sub-tiles of 512 in a
+    grid tile of 1,024 0.392 / 0.644, in one of 512 0.396 / 0.673;
+    sub-tiles of 256 0.518 / 0.813, of 128 (with the running max and sum
+    still kept as columns) 1.25 / 2.2. The window form at 16 heads x 8,192
+    x 128, window 4,096: 2.09 in tiles of 1,024 against 2.68 in tiles of
+    512. So: the largest grid tile up to 1,024 that divides the sequence,
+    walked in sub-tiles of up to 512."""
+    # a bias or segment-id block is sliced along its lanes by a key
+    # sub-tile (and, for q ids, by a row sub-tile): only statically, so
+    # those calls walk one key (and one row) sub-tile a grid tile, no
+    # larger than the 512 they always had
+    if sq * d > _HEAD_ELEMENTS:
+        return None
+    whole_k, whole_q = has_bias or has_seg, has_seg
+    edges = (512, 256, 128)
+    bq = block_q or (1 if sq == 1 else _largest(
+        sq, (1024,) * (not whole_q) + edges + (64, 32, 16, 8)))
+    bk = block_k or _largest(sk, (1024,) * (not whole_k) + edges)
+    if not (bq and bk):
+        return None
+    return (bq, bk, bq if whole_q else _largest(bq, edges) or bq,
+            bk if whole_k else _largest(bk, edges) or bk)
 
 
 def flash_attention(q, k, v, bias=None, causal: bool = False,
@@ -963,15 +1127,14 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
         softmax_scale = 1.0 / math.sqrt(d)
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("dropout_rate > 0 requires dropout_seed")
-    if block_q is None:
-        # decode shape: a lone query row rides one padded sublane tile
-        block_q = (1 if sq == 1 else
-                   _auto_block(sq, (512, 256, 128, 64, 32, 16, 8)) or 128)
-    if block_k is None:
-        block_k = _auto_block(sk) or 128
-    if use_pallas is None:
-        use_pallas = supports_flash(sq, sk, d, block_q, block_k)
     kv_heads = k.shape[1]
+    tile = _auto_block(sq, sk, d, block_q, block_k,
+                       has_bias=bias is not None,
+                       has_seg=segment_ids is not None)
+    if use_pallas is None:
+        use_pallas = tile is not None and supports_flash(sq, sk, d, *tile[:2])
+    elif use_pallas and tile is None:
+        raise ValueError(f"no flash tile divides sequences {sq} x {sk}")
     if window is not None or kv_heads != h:
         if window is not None and (not causal or window < 1):
             raise ValueError("window needs causal=True and window >= 1, "
@@ -992,8 +1155,8 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
                 q.reshape(b * h, sq, d), k.reshape(b * kv_heads, sk, d),
                 v.reshape(b * kv_heads, sk, d), None, None, None, h,
                 scale=float(softmax_scale), causal=bool(causal),
-                block_q=block_q, block_k=block_k, dropout_rate=0.0,
-                window=window, kv_heads=kv_heads)
+                tile=tile, dropout_rate=0.0, window=window,
+                kv_heads=kv_heads)
         return out.reshape(b, h, sq, d)
     if not use_pallas:
         # honor bias_requires_grad here too so gradient semantics do not
@@ -1047,7 +1210,7 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
         kseg = kv_ids.astype(jnp.float32).reshape(b, 1, sk)
     else:
         qseg = kseg = jnp.zeros((), jnp.float32)  # placeholder leaf
-    fn = _make_flash(float(softmax_scale), bool(causal), block_q, block_k,
+    fn = _make_flash(float(softmax_scale), bool(causal), tile,
                      has_bias, bool(bias_requires_grad), h,
                      float(dropout_rate), has_seg,
                      bool(checkpoint_names))

@@ -76,6 +76,15 @@ def _flash_fwd(q, k, v):
     return fa.flash_attention(q, k, v, causal=True, use_pallas=True)
 
 
+# the benchmark cells' own shapes: gpt2-medium's microbatch (4 x 16 heads x
+# 1,024 x 64, forward and backward), gpt2-large's prefill bucket (20 heads
+# x 512 x 64, forward); and a head too long for the default VMEM budget of
+# the backward, which holds one head's dq
+MEDIUM = [((4, 16, 1024, 64), BF16)] * 3
+LARGE_PREFILL = [((1, 20, 512, 64), BF16)] * 3
+LONG_HEAD = [((1, 2, 8192, 128), BF16)] * 3
+
+
 def _flash_loss(dropout_rate):
     def loss(q, k, v):
         out = fa.flash_attention(
@@ -112,6 +121,9 @@ CASES = {
     "flash_fwd": lambda: (_flash_fwd, QKV),
     "flash_fwd_bwd": lambda: (_flash_loss(0.0), QKV),
     "flash_fwd_bwd_dropout": lambda: (_flash_loss(0.1), QKV),
+    "flash_fwd_bwd_medium": lambda: (_flash_loss(0.0), MEDIUM),
+    "flash_fwd_large_prefill": lambda: (_flash_fwd, LARGE_PREFILL),
+    "flash_fwd_bwd_long_head": lambda: (_flash_loss(0.0), LONG_HEAD),
     "paged_decode_q1": lambda: _paged(1, False),
     "paged_verify_q5": lambda: _paged(VERIFY_Q, False),
     "paged_decode_int8": lambda: _paged(1, True),
@@ -127,6 +139,25 @@ def test_kernel_compiles_for_v5e(case, one_chip, for_tpu):
     fn, shapes = CASES[case]()
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_training_flash_kernels_keep_the_name_the_metric_reads(one_chip,
+                                                               for_tpu):
+    """``flash_attention_roofline.train`` finds its events by
+    ``^flash_attention[.\\d]* `` inside the step: the instruction names come
+    from ``jax.named_scope("flash_attention")`` and no ``name=`` on the
+    training ``pallas_call``s. A kernel the pattern missed would put the
+    whole work over the others' time alone and read over 100%."""
+    fn, shapes = CASES["flash_fwd_bwd_medium"]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2, calls      # one forward, ONE backward
+    for line in calls:
+        name = line.split("=")[0].strip(" %")
+        scope = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "flash_attention" in name and "window" not in name, name
+        assert re.search(r"\(flash_attention\)+/pallas_call$", scope), scope
 
 
 # ---------------------------------------------------------------------------

@@ -21,13 +21,41 @@ def _qkv(b=2, h=2, sq=256, sk=256, d=64, seed=0, dtype=jnp.float32):
     return q, k, v
 
 
+# Every static branch of the kernels' schedule (flash_tile_plan): the
+# chooser's own tile, whose one diagonal sub-tile is walked in strips;
+# explicit grid tiles, equal and unequal; sub-tiles smaller than the tile
+# (3 x 3 of 128 in a tile of 384: the dynamic loops); sk > sq (the
+# offset); sk < sq (rows wholly masked in their first tile).
+LAYOUTS = {
+    "auto": dict(sq=256, sk=256),
+    "equal_blocks": dict(sq=256, sk=256, block_q=128, block_k=128),
+    "wide_blocks": dict(sq=256, sk=256, block_q=128, block_k=256),
+    "tall_blocks": dict(sq=256, sk=256, block_q=256, block_k=128),
+    "sub_tiles": dict(sq=768, sk=768, block_q=384, block_k=384),
+    "offset": dict(sq=128, sk=384, block_q=128, block_k=384),
+    "masked_rows": dict(sq=384, sk=128),
+}
+
+
+def _layout(name, dtype, seed, b=1, h=2):
+    """``(q, k, v, block keywords, tolerance scale)`` of one LAYOUTS case."""
+    kw = dict(LAYOUTS[name])
+    q, k, v = _qkv(b=b, h=h, sq=kw.pop("sq"), sk=kw.pop("sk"), seed=seed,
+                   dtype=dtype)
+    return q, k, v, kw, 1.0 if dtype == jnp.float32 else 1e3
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_fwd_matches_reference(causal):
-    q, k, v = _qkv()
-    out = flash_attention(q, k, v, causal=causal, use_pallas=True)
+def test_flash_fwd_matches_reference(causal, layout, dtype):
+    q, k, v, blocks, loose = _layout(layout, dtype, seed=0, b=2)
+    out = flash_attention(q, k, v, causal=causal, use_pallas=True, **blocks)
     ref = mha_reference(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    assert out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=2e-5 * loose, atol=2e-5 * loose)
 
 
 def test_flash_with_bias_mask():
@@ -41,14 +69,18 @@ def test_flash_with_bias_mask():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_bwd_matches_reference(causal):
-    q, k, v = _qkv(b=1, h=2, sq=128, sk=128, seed=3)
+def test_flash_bwd_matches_reference(causal, layout, dtype):
+    """dq, dk and dv of the one backward kernel, whose dq gathers over the
+    whole head while dk and dv gather a kv tile at a time."""
+    q, k, v, blocks, loose = _layout(layout, dtype, seed=3)
     dy = jnp.asarray(np.random.RandomState(4).randn(*q.shape), jnp.float32)
 
     def f_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       use_pallas=True) * dy)
+                                       use_pallas=True, **blocks) * dy)
 
     def f_ref(q, k, v):
         return jnp.sum(mha_reference(q, k, v, causal=causal) * dy)
@@ -56,8 +88,10 @@ def test_flash_bwd_matches_reference(causal):
     g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_flash, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
+        assert a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=2e-4 * loose / 4, atol=2e-4 * loose / 4)
 
 
 def test_flash_bwd_with_bias():
@@ -127,25 +161,36 @@ def test_bwd_fully_masked_rows_block_misaligned():
     assert np.all(np.asarray(out)[:, :, :n_masked] == 0.0)
 
 
-@pytest.mark.parametrize("bias_shape", [
-    (1, 2, 128, 128),   # shared over batch (rel-pos table)
-    (2, 2, 128, 128),   # full (no reduction)
-    (1, 1, 128, 128),   # shared over batch and heads
-    (2, 1, 128, 128),   # shared over heads
-    (1, 2, 1, 128),     # broadcast over sq too (ALiBi-style row)
+@pytest.mark.parametrize("seq,blocks", [
+    (128, {}),                                  # one tile
+    (256, dict(block_q=128, block_k=128)),      # 2 x 2 tiles of one sub-tile
 ])
-def test_dbias_learned_bias(bias_shape):
+@pytest.mark.parametrize("bias_shape", [
+    (1, 2, 0, 0),   # shared over batch (rel-pos table)
+    (2, 2, 0, 0),   # full (no reduction)
+    (1, 1, 0, 0),   # shared over batch and heads
+    (2, 1, 0, 0),   # shared over heads
+    (1, 2, 1, 0),   # broadcast over sq too (ALiBi-style row)
+])
+def test_dbias_learned_bias(bias_shape, seq, blocks):
     """bias_requires_grad=True returns the real dbias (score cotangent summed
-    over broadcast dims), matching the XLA fallback's bias grad."""
-    q, k, v = _qkv(b=2, h=2, sq=128, sk=128, seed=13)
-    bias = jnp.asarray(np.random.RandomState(14).randn(*bias_shape) * 0.1,
-                       jnp.float32)
+    over broadcast dims), matching the XLA fallback's bias grad; and the
+    q, k, v gradients beside it, with the bias in the scores."""
+    q, k, v = _qkv(b=2, h=2, sq=seq, sk=seq, seed=13)
+    bias = jnp.asarray(np.random.RandomState(14).randn(
+        *(n or seq for n in bias_shape)) * 0.1, jnp.float32)
     dy = jnp.asarray(np.random.RandomState(15).randn(*q.shape), jnp.float32)
 
-    def f(bias, use_pallas):
+    def f(bias, use_pallas, q=q, k=k, v=v):
         return jnp.sum(flash_attention(
             q, k, v, bias=bias, causal=True, use_pallas=use_pallas,
-            bias_requires_grad=True) * dy)
+            bias_requires_grad=True, **blocks) * dy)
+
+    for a, b in zip(*(jax.grad(lambda q, k, v: f(bias, flag, q, k, v),
+                               argnums=(0, 1, 2))(q, k, v)
+                      for flag in (True, False))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
 
     db_flash = jax.grad(lambda b: f(b, True))(bias)
     db_ref = jax.grad(lambda b: f(b, False))(bias)
@@ -181,20 +226,24 @@ def test_padding_mask_broadcast_shapes():
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_dropout_matches_reference_mask():
+@pytest.mark.parametrize("layout", ["auto", "equal_blocks", "wide_blocks",
+                                    "sub_tiles", "offset"])
+def test_dropout_matches_reference_mask(layout):
     """In-kernel dropout (philox analog) agrees with the XLA reference using
-    the same counter-derived mask — forward AND all gradients."""
-    q, k, v = _qkv(b=2, h=2, sq=256, sk=256, seed=20)
+    the same counter-derived mask — forward AND all gradients: the mask is
+    a function of the global (row, column), so whatever tile, sub-tile or
+    strip a kernel walks regenerates the bits of ``dropout_keep_mask``."""
+    q, k, v, blocks, _ = _layout(layout, jnp.float32, seed=20, b=2)
     dy = jnp.asarray(np.random.RandomState(21).randn(*q.shape), jnp.float32)
     seed = jnp.asarray(12345, jnp.int32)
 
     def f(q, k, v, use_pallas):
         return jnp.sum(flash_attention(
             q, k, v, causal=True, dropout_rate=0.3, dropout_seed=seed,
-            use_pallas=use_pallas) * dy)
+            use_pallas=use_pallas, **blocks) * dy)
 
     out_fl = flash_attention(q, k, v, causal=True, dropout_rate=0.3,
-                             dropout_seed=seed, use_pallas=True)
+                             dropout_seed=seed, use_pallas=True, **blocks)
     out_ref = flash_attention(q, k, v, causal=True, dropout_rate=0.3,
                               dropout_seed=seed, use_pallas=False)
     np.testing.assert_allclose(np.asarray(out_fl), np.asarray(out_ref),
@@ -301,20 +350,25 @@ def _packed_ids(b, s, boundaries):
     return jnp.asarray(ids)
 
 
+@pytest.mark.parametrize("seq,blocks", [
+    (128, {}),                                  # one tile
+    (256, dict(block_q=128, block_k=128)),      # a segment across tiles
+])
 @pytest.mark.parametrize("causal", [False, True])
-def test_segment_ids_match_reference(causal):
-    """Pallas segment masking == XLA fallback, forward and grads."""
-    q, k, v = _qkv(b=2, h=2, sq=128, sk=128, seed=31)
-    ids = _packed_ids(2, 128, [{40, 90}, {64}])
+def test_segment_ids_match_reference(causal, seq, blocks):
+    """Pallas segment masking == XLA fallback, forward and grads; a row
+    whose segment starts in a later tile is wholly masked in the first."""
+    q, k, v = _qkv(b=2, h=2, sq=seq, sk=seq, seed=31)
+    ids = _packed_ids(2, seq, [{40, 90}, {seq - 64}])
     dy = jnp.asarray(np.random.RandomState(32).randn(*q.shape), jnp.float32)
 
     def f(q, k, v, use_pallas):
         return jnp.sum(flash_attention(
             q, k, v, causal=causal, use_pallas=use_pallas,
-            segment_ids=ids) * dy)
+            segment_ids=ids, **blocks) * dy)
 
     out_p = flash_attention(q, k, v, causal=causal, use_pallas=True,
-                            segment_ids=ids)
+                            segment_ids=ids, **blocks)
     out_r = flash_attention(q, k, v, causal=causal, use_pallas=False,
                             segment_ids=ids)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
@@ -379,6 +433,41 @@ def test_segment_ids_validation():
     ref = flash_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_flash_tile_plan_at_the_cells_shapes():
+    """The schedule as a pure function: what the kernels skip, leave
+    unmasked and mask at the benchmark cells' shapes, with the tiles the
+    chooser picks there."""
+    from apex_tpu.ops.flash_attention import _auto_block, flash_tile_plan
+    # gpt2-medium's train step: one grid tile a head, 2 x 2 sub-tiles of
+    # 512; the two on the diagonal are walked in two strips (3/4 of each)
+    assert _auto_block(1024, 1024, 64) == (1024, 1024, 512, 512)
+    assert flash_tile_plan(1024, 1024, 1024, 1024, 512, 512) == dict(
+        skipped=1, unmasked=1, masked=2, scores_needed=1024 * 1025 // 2,
+        scores_computed=512 * 512 + 2 * (256 * 256 + 256 * 512))
+    # gpt2-large's prefill bucket: the one diagonal sub-tile
+    assert _auto_block(512, 512, 64) == (512, 512, 512, 512)
+    assert flash_tile_plan(512, 512, 512, 512, 512, 512) == dict(
+        skipped=0, unmasked=0, masked=1, scores_needed=512 * 513 // 2,
+        scores_computed=256 * 256 + 256 * 512)
+    # the pattern model's longest bucket under its window, 16 x 16
+    # sub-tiles: every row band has its diagonal sub-tile masked, from the
+    # 9th band on also the one the window's left edge crosses, and at most
+    # 7 whole ones between; no strips under a window
+    assert _auto_block(8192, 8192, 128) == (1024, 1024, 512, 512)
+    plan = flash_tile_plan(8192, 8192, 1024, 1024, 512, 512, window=4096)
+    assert plan["masked"] == 16 + 8
+    assert plan["unmasked"] == sum(min(band, 7) for band in range(16))
+    assert plan["skipped"] == 256 - plan["masked"] - plan["unmasked"]
+    assert plan["scores_computed"] == 512 * 512 * (plan["masked"]
+                                                   + plan["unmasked"])
+    rows = np.arange(8192)
+    assert plan["scores_needed"] == int(np.minimum(rows + 1, 4096).sum())
+    # without causal nothing is skipped and nothing masked
+    assert flash_tile_plan(256, 512, 256, 512, 128, 128, causal=False) == \
+        dict(skipped=0, unmasked=8, masked=0, scores_needed=256 * 512,
+             scores_computed=256 * 512)
 
 
 # -- decode shapes (sq=1 vs a cached sk) — the serving kernel family's

@@ -278,14 +278,21 @@ def test_under_a_mesh_axis_the_chips_parts_are_summed():
 
 # -- kernels ----------------------------------------------------------------------
 
+@pytest.mark.parametrize("seq,blocks", [
+    (256, dict(block_q=64, block_k=128)),
+    # 3 x 3 sub-tiles of 128 in a grid tile: the window's left edge, the
+    # unmasked interior and the diagonal are three loops, and no window
+    # below is a multiple of the sub-tile
+    (768, dict(block_q=384, block_k=384)),
+])
 @pytest.mark.parametrize("window", [None, 40, 128, 300])
-def test_flash_window_and_grouped_heads_match_the_oracle(window):
+def test_flash_window_and_grouped_heads_match_the_oracle(window, seq, blocks):
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(keys[0], (1, 8, 256, 8))
-    k = jax.random.normal(keys[1], (1, 2, 256, 8))
-    v = jax.random.normal(keys[2], (1, 2, 256, 8))
+    q = jax.random.normal(keys[0], (1, 8, seq, 8))
+    k = jax.random.normal(keys[1], (1, 2, seq, 8))
+    v = jax.random.normal(keys[2], (1, 2, seq, 8))
     got = fa.flash_attention(q, k, v, causal=True, window=window,
-                             block_q=64, block_k=128, use_pallas=True)
+                             use_pallas=True, **blocks)
     want = fa.mha_reference(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 

@@ -205,8 +205,8 @@ class TestJaxprStructure:
     def test_selective_census(self):
         """The acceptance census: every registry tag emitted in the
         forward; saved names NOT recomputed inside the remat region; the
-        flash *forward* kernel absent from the recompute (only the two
-        backward kernels remain), while full remat reruns it there."""
+        flash *forward* kernel absent from the recompute (only the one
+        backward kernel remains), while full remat reruns it there."""
         j_sel, *_ = _gpt_grad_jaxpr("selective")
         # every registry name is emitted (the flash pair comes from the
         # kernel's custom_vjp fwd rule)
@@ -224,8 +224,9 @@ class TestJaxprStructure:
         j_full, *_ = _gpt_grad_jaxpr("full")
         full_kernels = sum(_count_in(b, "pallas_call")
                            for b in _remat_bodies(j_full))
-        # full: fwd recompute + dq + dkv kernels; selective: dq + dkv only
-        assert full_kernels == 3 and sel_kernels == 2, \
+        # full: fwd recompute + the backward kernel (dq, dk and dv in one
+        # pass since PR 33); selective: the backward kernel only
+        assert full_kernels == 2 and sel_kernels == 1, \
             (full_kernels, sel_kernels)
         # both programs run the real forward kernel exactly once outside
         assert _count_in(j_sel, "pallas_call") - sel_kernels == 1
@@ -250,7 +251,7 @@ class TestJaxprStructure:
         assert "mlp_fc1_out" not in body_names
         # flash residuals unsaved -> the fwd kernel is BACK in the remat
         # region (the failure mode the default save-list exists to avoid)
-        assert sum(_count_in(b, "pallas_call") for b in bodies) == 3
+        assert sum(_count_in(b, "pallas_call") for b in bodies) == 2
 
     def test_bert_selective_traces_with_tags(self):
         from apex_tpu.models import BertConfig, BertModel
