@@ -1,33 +1,55 @@
 """A decoder assembled from a LAYER PATTERN, served through the paged engine.
 
 ``GPTModel`` is one block repeated. The architectures deployed today are a
-short period of different blocks repeated (``layer_types`` in their
-``config.json``): here three ``sliding_attention`` layers (rotary
-positions, a window) then one ``full_attention`` layer (no positional term
-at all, causal), every layer Cohere's parallel block
+short period of different blocks repeated (``layer_types`` or
+``hybrid_override_pattern`` in their ``config.json``). A layer KIND is a
+(mixer, feed-forward, cache kind) triple (:class:`LayerKind`,
+:data:`LAYER_KINDS`):
 
-    h = n(x);  x' = x + attn(h) + moe(h)
+- the MIXER is grouped-query attention (with or without rotary positions
+  and a window), a Mamba-2 state-space mixer (:mod:`apex_tpu.ops.mamba2`),
+  or none;
+- the FEED-FORWARD is the dropless top-k expert layer
+  (:class:`~apex_tpu.transformer.expert_parallel.HeldExpertsMLP`) or none;
+- the CACHE is a pool of KV blocks a window hands back, a pool that keeps
+  them, a fixed-size per-slot state, or none.
 
-with one bias-free LayerNorm feeding grouped-query attention and a dropless
-top-k expert layer (:class:`~apex_tpu.transformer.expert_parallel.
-HeldExpertsMLP`) side by side. A layer KIND is a (mixer, feed-forward,
-cache kind) triple (:data:`LAYER_KINDS`); the layers of one kind are
-stacked and scanned, the period is repeated, and each kind keeps its own
-KV pool and block table (:class:`~apex_tpu.serving.cache.KindPagedKVCache`)
-because a window layer gives back the blocks that fall out of its window
-and a full layer never does.
+A layer is ``x' = x + sum of its sub-blocks over n(x)``: with ``block =
+"parallel"`` (Cohere) a kind's mixer and feed-forward sit side by side
+behind one norm; with ``block = "prenorm"`` (Nemotron-H) a layer is ONE
+sub-block, a mixer or a feed-forward, under a pre-norm residual. ``norm`` is
+a bias-free LayerNorm or an RMS norm; the head is the tied embedding or
+its own matrix. Two families are written as such patterns:
+
+- Cohere2 sparse: three ``sliding_attention`` layers (rotary, a window)
+  then one ``full_attention`` layer (no positional term), each beside a
+  gated-SiLU expert layer, parallel blocks, LayerNorm, tied head;
+- Nemotron-H: ``mamba`` (``M``), ``moe`` (``E``: squared-ReLU experts in a
+  latent, a selection bias, a shared expert of its own width) and
+  ``attention`` (``*``: no positional term at all, the Mamba layers carry
+  order) layers, pre-norm residual, RMS norm, untied head.
+
+The layers of one kind are stacked; runs of one kind are scanned, the
+period is repeated, and each kind that holds something keeps it in its own
+place of the engine's cache (:class:`~apex_tpu.serving.cache.
+KindPagedKVCache`): a pool and a block table a block kind, because a window
+layer gives back the blocks that fall out of its window and a full layer
+never does; one row a slot a state kind (the conv's tail and the SSM
+state), written by the prefill for its slot and advanced in place by the
+decode step.
 
 The model answers the calls ``ServingEngine`` makes of ``GPTModel``
 (``cfg``, ``_require_cacheable``, ``forward`` for prefill and decode)
-and nothing else: no trainer, no loss, no speculative verify. Weights are held bfloat16; norm, router, softmax and
+and nothing else: no trainer, no loss, no speculative verify. Weights are
+held bfloat16; norm, router, softmax, the state-space decay and state, and
 logits are float32.
 
 The chip may hold a SHARE of every layer (``held_experts`` of the
 ``num_experts`` the router scores, the chip's query heads and their KV
-heads, its rows of the tied embedding): what the absent chips would add is
-left out, the partial ``x'`` goes on to the next layer. With
-``axis_name`` the same code runs inside ``shard_map`` over the chips that
-share the layers and the parts are summed there.
+heads, its Mamba heads and groups, its rows of the vocabulary): what the
+absent chips would add is left out, the partial ``x'`` goes on to the next
+layer. With ``axis_name`` the same code runs inside ``shard_map`` over the
+chips that share the layers and the parts are summed there.
 """
 
 from __future__ import annotations
@@ -40,6 +62,8 @@ import jax.numpy as jnp
 
 from apex_tpu.ops.flash_attention import (flash_attention,
                                           paged_decode_attention)
+from apex_tpu.ops.mamba2 import (causal_conv, causal_conv_update,
+                                 mamba2_chunk_scan, mamba2_decode_update)
 from apex_tpu.transformer.expert_parallel import HeldExpertsMLP
 
 __all__ = ["LayerKind", "LAYER_KINDS", "PatternDecoderConfig",
@@ -48,22 +72,26 @@ __all__ = ["LayerKind", "LAYER_KINDS", "PatternDecoderConfig",
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """What a ``layer_types`` entry stands for: the mixer (attention with
-    or without rotary positions and a window) and with it the cache kind
-    (a pool whose blocks a window returns, or one that keeps them). The
-    feed-forward is the expert layer in every kind."""
+    """What a ``layer_types`` entry stands for (module docstring)."""
 
-    rotary: bool
-    windowed: bool
+    mixer: Optional[str]          # "attention" | "mamba2" | None
+    feed_forward: bool            # the expert layer, or none
+    cache: Optional[str]          # "blocks" | "window_blocks" | "state" | None
+    rotary: bool = False          # attention: rotary positions
 
+    @property
+    def windowed(self) -> bool:
+        return self.cache == "window_blocks"
 
-# the stacked expert matrices: read by layer index where they lie, never
-# sliced a layer (a slice of one kind's gate matrices is a 2 GB copy)
-EXPERTS = ("w_gate", "w_up", "w_down")
 
 LAYER_KINDS: Dict[str, LayerKind] = {
-    "sliding_attention": LayerKind(rotary=True, windowed=True),
-    "full_attention": LayerKind(rotary=False, windowed=False),
+    "sliding_attention": LayerKind("attention", True, "window_blocks",
+                                   rotary=True),
+    "full_attention": LayerKind("attention", True, "blocks"),
+    # the names nemotron_h's hybrid_override_pattern letters map to
+    "mamba": LayerKind("mamba2", False, "state"),           # M
+    "moe": LayerKind(None, True, None),                     # E
+    "attention": LayerKind("attention", False, "blocks"),   # *
 }
 
 
@@ -75,7 +103,7 @@ class PatternDecoderConfig:
     num_attention_heads: int
     num_key_value_heads: int
     head_dim: int
-    expert_size: int                      # every routed and shared expert
+    expert_size: int                      # every routed (riding shared) expert
     num_experts: int                      # the router's width
     num_experts_per_tok: int
     held_experts: Tuple[int, ...]
@@ -91,6 +119,22 @@ class PatternDecoderConfig:
     use_flash: Optional[bool] = None      # None: the kernels' own gates
     use_grouped_experts: bool = True      # False: the experts x tokens oracle
     axis_name: Optional[str] = None
+    block: str = "parallel"               # | "prenorm": one sub-block a layer
+    norm: str = "layernorm"               # | "rmsnorm"
+    tie_embeddings: bool = True           # False: the head is ``lm_head``
+    # the expert layer's form (HeldExpertsMLP)
+    expert_activation: str = "swiglu"     # | "relu2"
+    router_bias: bool = False
+    routed_scaling: float = 1.0
+    latent_size: Optional[int] = None
+    shared_expert_size: Optional[int] = None
+    # the Mamba-2 mixer
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_groups: int = 1
+    mamba_state: int = 128
+    mamba_conv: int = 4
+    mamba_chunk: int = 128
 
     def __post_init__(self):
         unknown = set(self.layer_types) - set(LAYER_KINDS)
@@ -103,6 +147,21 @@ class PatternDecoderConfig:
                 f"{self.num_key_value_heads} KV heads")
         if self.head_dim % 2:
             raise ValueError("rotary pairs need an even head_dim")
+        if self.block not in ("parallel", "prenorm") \
+                or self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"block {self.block!r} / norm {self.norm!r}")
+        for kind in set(self.layer_types):
+            k = LAYER_KINDS[kind]
+            if self.block == "prenorm" and (k.mixer is None) \
+                    != k.feed_forward:
+                raise ValueError(
+                    f"a prenorm layer is one sub-block; {kind!r} is {k}")
+            if k.mixer == "mamba2" and (
+                    self.mamba_heads < 1
+                    or self.mamba_heads % self.mamba_groups):
+                raise ValueError(
+                    f"{self.mamba_heads} Mamba heads do not divide over "
+                    f"{self.mamba_groups} groups")
 
     @property
     def num_layers(self) -> int:
@@ -135,13 +194,40 @@ class PatternDecoderConfig:
         return self.layer_types.count(kind)
 
     @property
-    def cache_kinds(self) -> Dict[str, Tuple[int, Optional[int]]]:
-        """``{kind: (layers, window or None)}`` in the period's order: what
-        the engine builds the pools and block tables from."""
-        return {kind: (self.layers_of(kind),
-                       self.sliding_window if LAYER_KINDS[kind].windowed
-                       else None)
-                for kind in dict.fromkeys(self.period)}
+    def kinds(self) -> Tuple[str, ...]:
+        """The layer kinds in the period's order."""
+        return tuple(dict.fromkeys(self.period))
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """x, B and C pass the conv: ``heads * head_dim + 2 groups *
+        state``."""
+        return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state
+
+    @property
+    def cache_kinds(self) -> dict:
+        """What the engine builds its cache from, in the period's order and
+        for the kinds that hold something only: ``{kind: (layers, window or
+        None)}`` for a kind that keeps KV blocks, a
+        :class:`~apex_tpu.serving.cache.StateSpec` for one that keeps a
+        per-slot state."""
+        from apex_tpu.serving.cache import StateSpec
+        out = {}
+        for kind in self.kinds:
+            holds = LAYER_KINDS[kind].cache
+            if holds == "state":
+                out[kind] = StateSpec(
+                    self.layers_of(kind), self.mamba_conv_channels,
+                    self.mamba_conv, self.mamba_heads, self.mamba_head_dim,
+                    self.mamba_state)
+            elif holds is not None:
+                out[kind] = (self.layers_of(kind), self.sliding_window
+                             if holds == "window_blocks" else None)
+        return out
 
 
 def rotary_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
@@ -163,9 +249,10 @@ class PatternDecoder:
     """See the module docstring."""
 
     #: the decode and prefill programs return ``stats`` beside the logits
-    #: and the cache: per layer, in the order the layers run, the
-    #: assignments that landed on each held expert and the tokens with no
-    #: held pick — ``(num_layers, len(held_experts) + 1)`` int32
+    #: and the cache: per layer that has an expert layer, in the order the
+    #: layers run, the assignments that landed on each held expert and the
+    #: tokens with no held pick — ``(expert layers, len(held_experts) + 1)``
+    #: int32
     step_stats = True
 
     def __init__(self, config: PatternDecoderConfig):
@@ -175,11 +262,16 @@ class PatternDecoder:
             cfg.num_experts_per_tok, cfg.held_experts,
             cfg.num_shared_experts, axis_name=cfg.axis_name,
             params_dtype=cfg.params_dtype, init_std=cfg.init_std,
-            use_pallas=cfg.use_grouped_experts)
+            use_pallas=cfg.use_grouped_experts,
+            activation=cfg.expert_activation, select_bias=cfg.router_bias,
+            scaling=cfg.routed_scaling, latent_size=cfg.latent_size,
+            shared_size=cfg.shared_expert_size)
 
     @property
     def stats_shape(self) -> Tuple[int, int]:
-        return (self.cfg.num_layers, len(self.cfg.held_experts) + 1)
+        cfg = self.cfg
+        return (sum(LAYER_KINDS[k].feed_forward for k in cfg.layer_types),
+                len(cfg.held_experts) + 1)
 
     @property
     def stats_names(self) -> Tuple[str, ...]:
@@ -195,31 +287,56 @@ class PatternDecoder:
         cfg = self.cfg
         H, d = cfg.hidden_size, cfg.head_dim
         q, kv = cfg.num_attention_heads * d, cfg.num_key_value_heads * d
-        n, F = self.experts.num_local, cfg.expert_size
-        per_layer = {"norm": (H,), "wq": (H, q), "wk": (H, kv),
-                     "wv": (H, kv), "wo": (q, H),
-                     "router": (H, cfg.num_experts), "w_gate": (n, H, F),
-                     "w_up": (n, H, F), "w_down": (n, F, H)}
-        return {"embedding": (cfg.vocab_size, H), "final_norm": (H,),
-                "layers": {kind: {name: (cfg.layers_of(kind),) + shape
-                                  for name, shape in per_layer.items()}
-                           for kind in cfg.cache_kinds}}
+        inner, nh = cfg.mamba_inner, cfg.mamba_heads
+        mixers = {
+            "attention": {"wq": (H, q), "wk": (H, kv), "wv": (H, kv),
+                          "wo": (q, H)},
+            "mamba2": {"in_proj": (H, inner + cfg.mamba_conv_channels + nh),
+                       "conv_w": (cfg.mamba_conv_channels, cfg.mamba_conv),
+                       "conv_b": (cfg.mamba_conv_channels,),
+                       "dt_bias": (nh,), "A_log": (nh,), "D": (nh,),
+                       "gate_norm": (inner,), "out_proj": (inner, H)},
+            None: {}}
+        layers = {}
+        for kind in cfg.kinds:
+            k = LAYER_KINDS[kind]
+            per_layer = dict({"norm": (H,)}, **mixers[k.mixer])
+            if k.feed_forward:
+                per_layer.update(self.experts.param_shapes())
+            layers[kind] = {name: (cfg.layers_of(kind),) + shape
+                            for name, shape in per_layer.items()}
+        out = {"embedding": (cfg.vocab_size, H), "final_norm": (H,),
+               "layers": layers}
+        if not cfg.tie_embeddings:
+            out["lm_head"] = (H, cfg.vocab_size)
+        return out
 
     def init(self, key: jax.Array) -> dict:
         """Seeded random parameters (tests): matrices N(0, init_std), the
-        residual projections scaled down by sqrt(2 L), gains 1 + N."""
+        residual projections scaled down by sqrt(2 L), gains 1 + N; the
+        state-space scalars where a decay of a few to a few hundred tokens
+        puts them (``dt`` about 0.01-0.1 after its softplus, ``A`` in
+        -[1, 16])."""
         cfg = self.cfg
         shapes, treedef = jax.tree_util.tree_flatten_with_path(
             self.param_shapes(), is_leaf=lambda s: isinstance(s, tuple))
         out = []
         for i, (path, shape) in enumerate(shapes):
             name = path[-1].key
-            x = cfg.init_std * jax.random.normal(
-                jax.random.fold_in(key, i), shape, jnp.float32)
-            if name in ("wo", "w_down"):
+            k = jax.random.fold_in(key, i)
+            x = cfg.init_std * jax.random.normal(k, shape, jnp.float32)
+            if name in ("wo", "w_down", "out_proj", "shared_down",
+                        "w_latent_up"):
                 x = x / (2.0 * cfg.num_layers) ** 0.5
-            if name in ("norm", "final_norm"):
+            if name in ("norm", "final_norm", "gate_norm", "D"):
                 x = 1.0 + x
+            if name == "conv_w":
+                x = 0.5 * jax.random.normal(k, shape, jnp.float32)
+            if name == "dt_bias":
+                x = jax.random.uniform(k, shape, jnp.float32, -4.5, -2.5)
+            if name == "A_log":
+                x = jnp.log(jax.random.uniform(k, shape, jnp.float32,
+                                               1.0, 16.0))
             out.append(x.astype(cfg.params_dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -227,6 +344,10 @@ class PatternDecoder:
 
     def _norm(self, gain, x):
         x = x.astype(jnp.float32)
+        if self.cfg.norm == "rmsnorm":
+            return x * jax.lax.rsqrt(
+                jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                + self.cfg.layer_norm_eps) * gain.astype(jnp.float32)
         mean = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
         return (x - mean) * jax.lax.rsqrt(var + self.cfg.layer_norm_eps) \
@@ -247,46 +368,140 @@ class PatternDecoder:
             k = rotary_interleaved(k, positions, cfg.rope_theta)
         return q, k, v
 
-    def _finish(self, lp, big, li, x, h32, ctx, valid):
-        """``x + attn + moe`` from the attention context ``(T, heads *
-        d)``; the experts read layer ``li`` of the kind's stacked
-        weights."""
+    def _sum_parts(self, part):
+        if self.cfg.axis_name is not None:
+            part = jax.lax.psum(part, self.cfg.axis_name)
+        return part
+
+    def _finish(self, kind, lp, big, li, x, h32, mixed, valid):
+        """``x + mixer + experts``, whichever of the two the kind has:
+        ``mixed`` is the mixer's part before its output projection joined
+        the chips' (float32, or None); the experts read layer ``li`` of the
+        kind's stacked weights."""
         cfg = self.cfg
-        attn = jnp.dot(ctx.astype(cfg.compute_dtype), lp["wo"],
-                       preferred_element_type=jnp.float32)
-        if cfg.axis_name is not None:
-            attn = jax.lax.psum(attn, cfg.axis_name)
+        acc = x.astype(jnp.float32)
+        if mixed is not None:
+            acc = acc + self._sum_parts(mixed)
+        if not LAYER_KINDS[kind].feed_forward:
+            width = len(cfg.held_experts) + 1
+            return acc.astype(cfg.compute_dtype), \
+                jnp.zeros((0, width), jnp.int32)
+        small = {n: v for n, v in lp.items()
+                 if n in self.experts.param_shapes()}
         moe, stats = self.experts(
-            dict(big, router=lp["router"]),
-            h32.astype(cfg.compute_dtype), valid=valid, layer=li)
-        x = (x.astype(jnp.float32) + attn + moe.astype(jnp.float32)
-             ).astype(cfg.compute_dtype)
+            dict(big, **small), h32.astype(cfg.compute_dtype), valid=valid,
+            layer=li)
+        x = (acc + moe.astype(jnp.float32)).astype(cfg.compute_dtype)
         return x, jnp.concatenate([stats["load"],
-                                   stats["no_held_pick"][None]])
+                                   stats["no_held_pick"][None]])[None]
 
     def _window(self, kind):
         return self.cfg.sliding_window if LAYER_KINDS[kind].windowed \
             else None
 
+    # -- the Mamba-2 mixer ----------------------------------------------------
+
+    def _mamba_in(self, lp, h):
+        """``[z | xBC | dt] = h Win``: the gate ``z`` and ``dt`` (after
+        its bias and softplus) float32, what passes the conv in the compute
+        dtype; and ``A = -exp(A_log)``."""
+        cfg = self.cfg
+        inner, conv = cfg.mamba_inner, cfg.mamba_conv_channels
+        proj = jnp.dot(h, lp["in_proj"], preferred_element_type=jnp.float32)
+        z, xbc, dt = jnp.split(proj, (inner, inner + conv), axis=-1)
+        dt = jax.nn.softplus(dt + lp["dt_bias"].astype(jnp.float32))
+        return z, xbc.astype(cfg.compute_dtype), dt, \
+            -jnp.exp(lp["A_log"].astype(jnp.float32))
+
+    def _mamba_split(self, xbc):
+        """``[x | B | C]`` of the conv's output: ``(T, heads, head_dim)``,
+        ``(T, groups, state)`` twice."""
+        cfg = self.cfg
+        T, gn = xbc.shape[0], cfg.mamba_groups * cfg.mamba_state
+        x, B, C = jnp.split(xbc, (cfg.mamba_inner, cfg.mamba_inner + gn),
+                            axis=-1)
+        return (x.reshape(T, cfg.mamba_heads, cfg.mamba_head_dim),
+                B.reshape(T, cfg.mamba_groups, cfg.mamba_state),
+                C.reshape(T, cfg.mamba_groups, cfg.mamba_state))
+
+    def _mamba_out(self, lp, y, x, z):
+        """The skip ``D x``, the gate THEN the norm (RMS over each group's
+        channels apart), the output projection: float32 ``(T, hidden)``,
+        this chip's part."""
+        cfg = self.cfg
+        T = y.shape[0]
+        y = y + lp["D"].astype(jnp.float32)[None, :, None] \
+            * x.astype(jnp.float32)
+        y = y.reshape(T, cfg.mamba_inner) * jax.nn.silu(z)
+        g = y.reshape(T, cfg.mamba_groups, -1)
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                              + cfg.layer_norm_eps)
+        y = g.reshape(T, cfg.mamba_inner) \
+            * lp["gate_norm"].astype(jnp.float32)
+        return jnp.dot(y.astype(cfg.compute_dtype), lp["out_proj"],
+                       preferred_element_type=jnp.float32)
+
+    def _mamba_prefill(self, kind, lp, li, h, cache, prompt_len, slot):
+        """The mixer over one prompt: the chunked scan from a zero state;
+        with a cache the conv's tail and the state of the LAST REAL token
+        overwrite the slot's rows."""
+        cfg = self.cfg
+        z, xbc, dt, A = self._mamba_in(lp, h)
+        xbc, tail = causal_conv(xbc, lp["conv_w"], lp["conv_b"], prompt_len)
+        x, B, C = self._mamba_split(xbc)
+        y, state = mamba2_chunk_scan(
+            x, dt, A, B, C, chunk=min(cfg.mamba_chunk, h.shape[0]),
+            length=prompt_len, use_pallas=cfg.use_flash)
+        if cache is not None:
+            cache = dict(cache, **{kind: cache[kind].write_slot(
+                li, slot, tail, state)})
+        return self._mamba_out(lp, y, x, z), cache
+
+    def _mamba_decode(self, kind, lp, li, h, cache, valid):
+        """One token a slot: the conv over the slot's tail, the state moved
+        on by one token, both written back where they lie; an idle slot's
+        rows stay as they were."""
+        held = cache[kind]
+        z, xbc, dt, A = self._mamba_in(lp, h)
+        tail = held.conv[li]
+        xbc, moved = causal_conv_update(tail, xbc, lp["conv_w"],
+                                        lp["conv_b"])
+        x, B, C = self._mamba_split(xbc)
+        y, state = mamba2_decode_update(held.ssm[li], x, dt, A, B, C, valid)
+        moved = jnp.where(valid[:, None, None], moved, tail)
+        cache = dict(cache, **{kind: held.write_layer(li, moved, state)})
+        return self._mamba_out(lp, y, x, z), cache
+
+    # -- a layer --------------------------------------------------------------
+
     def _prefill_layer(self, kind, lp, big, li, x, cache, block_row,
-                       valid):
+                       valid, prompt_len=None, slot=None):
         """One layer over one prompt: ``x`` ``(P, hidden)``; with a cache
-        the layer's K/V go into the pool blocks ``block_row[kind]``."""
+        an attention layer's K/V go into the pool blocks
+        ``block_row[kind]``, a Mamba layer's state into row ``slot``."""
         cfg = self.cfg
         P = x.shape[0]
         h32 = self._norm(lp["norm"], x)
-        q, k, v = self._qkv(lp, h32.astype(cfg.compute_dtype),
-                            jnp.arange(P), kind)
-        with jax.named_scope("pattern_attention"):
-            ctx = flash_attention(
-                q.transpose(1, 0, 2)[None], k.transpose(1, 0, 2)[None],
-                v.transpose(1, 0, 2)[None], causal=True,
-                window=self._window(kind), use_pallas=cfg.use_flash)
-        ctx = ctx[0].transpose(1, 0, 2).reshape(P, -1)
-        if cache is not None:
-            cache = dict(cache, **{kind: cache[kind].write_layer_blocks(
-                li, k.reshape(P, -1), v.reshape(P, -1), block_row[kind])})
-        x, stats = self._finish(lp, big, li, x, h32, ctx, valid)
+        h = h32.astype(cfg.compute_dtype)
+        mixer, mixed = LAYER_KINDS[kind].mixer, None
+        if mixer == "attention":
+            q, k, v = self._qkv(lp, h, jnp.arange(P), kind)
+            with jax.named_scope("pattern_attention"):
+                ctx = flash_attention(
+                    q.transpose(1, 0, 2)[None], k.transpose(1, 0, 2)[None],
+                    v.transpose(1, 0, 2)[None], causal=True,
+                    window=self._window(kind), use_pallas=cfg.use_flash)
+            ctx = ctx[0].transpose(1, 0, 2).reshape(P, -1)
+            if cache is not None:
+                cache = dict(cache, **{kind: cache[kind].write_layer_blocks(
+                    li, k.reshape(P, -1), v.reshape(P, -1),
+                    block_row[kind])})
+            mixed = jnp.dot(ctx.astype(cfg.compute_dtype), lp["wo"],
+                            preferred_element_type=jnp.float32)
+        elif mixer == "mamba2":
+            mixed, cache = self._mamba_prefill(kind, lp, li, h, cache,
+                                               prompt_len, slot)
+        x, stats = self._finish(kind, lp, big, li, x, h32, mixed, valid)
         return x, cache, stats
 
     def _paged_decode_layer(self, kind, lp, big, li, x, cache, tables,
@@ -297,31 +512,38 @@ class PatternDecoder:
         cfg = self.cfg
         S = x.shape[0]
         h32 = self._norm(lp["norm"], x)
-        q, k_new, v_new = self._qkv(lp, h32.astype(cfg.compute_dtype),
-                                    lengths, kind)
-        pool = cache[kind]
-        with jax.named_scope("pattern_attention"):
-            ctx = paged_decode_attention(
-                q, pool.k, pool.v, li, tables[kind], lengths, k_new=k_new,
-                v_new=v_new, mean_context=mean_context,
-                use_pallas=cfg.use_flash, window=self._window(kind))
-        cache = dict(cache, **{kind: pool.append(
-            li, k_new, v_new, block_ids[kind], offsets)})
-        x, stats = self._finish(lp, big, li, x, h32, ctx.reshape(S, -1),
-                                valid)
+        h = h32.astype(cfg.compute_dtype)
+        mixer, mixed = LAYER_KINDS[kind].mixer, None
+        if mixer == "attention":
+            q, k_new, v_new = self._qkv(lp, h, lengths, kind)
+            pool = cache[kind]
+            with jax.named_scope("pattern_attention"):
+                ctx = paged_decode_attention(
+                    q, pool.k, pool.v, li, tables[kind], lengths,
+                    k_new=k_new, v_new=v_new, mean_context=mean_context,
+                    use_pallas=cfg.use_flash, window=self._window(kind))
+            cache = dict(cache, **{kind: pool.append(
+                li, k_new, v_new, block_ids[kind], offsets)})
+            mixed = jnp.dot(ctx.reshape(S, -1).astype(cfg.compute_dtype),
+                            lp["wo"], preferred_element_type=jnp.float32)
+        elif mixer == "mamba2":
+            mixed, cache = self._mamba_decode(kind, lp, li, h, cache, valid)
+        x, stats = self._finish(kind, lp, big, li, x, h32, mixed, valid)
         return x, cache, stats
 
     def _run_layers(self, layer_fn, params, x, cache):
         """The period's runs, the period repeated: ``layer_fn(kind, lp,
         big, li, x, cache) -> (x, cache, stats)``. The experts' stacked
         weights are closed over and read by layer index where they lie;
-        the small per-layer tensors are the scans' xs."""
+        the small per-layer tensors are the scans' xs. ``stats``: a row a
+        layer that has an expert layer."""
         cfg = self.cfg
         n_periods = cfg.num_layers // len(cfg.period)
-        in_period = {k: cfg.period.count(k) for k in cfg.cache_kinds}
-        small = {kind: {n: v for n, v in lp.items() if n not in EXPERTS}
+        in_period = {k: cfg.period.count(k) for k in cfg.kinds}
+        stacked = self.experts.stacked
+        small = {kind: {n: v for n, v in lp.items() if n not in stacked}
                  for kind, lp in params["layers"].items()}
-        big = {kind: {n: lp[n] for n in EXPERTS}
+        big = {kind: {n: lp[n] for n in stacked if n in lp}
                for kind, lp in params["layers"].items()}
 
         def one_period(carry, p):
@@ -339,7 +561,7 @@ class PatternDecoder:
 
                 carry, stats = jax.lax.scan(
                     body, carry, jnp.arange(count, dtype=jnp.int32))
-                rows.append(stats)
+                rows.append(stats.reshape(-1, stats.shape[-1]))
             return carry, jnp.concatenate(rows, axis=0)
 
         if n_periods == 1:
@@ -352,11 +574,14 @@ class PatternDecoder:
         return x, cache, stats
 
     def _logits(self, params, x):
-        """``logit_scale * n(x) Wemb^T``, float32."""
+        """``logit_scale * n(x) Whead``, float32; the head is the tied
+        embedding or ``lm_head``."""
         cfg = self.cfg
         h = self._norm(params["final_norm"], x).astype(cfg.compute_dtype)
+        head = params["embedding"].T if cfg.tie_embeddings \
+            else params["lm_head"]
         return cfg.logit_scale * jnp.dot(
-            h, params["embedding"].T, preferred_element_type=jnp.float32)
+            h, head, preferred_element_type=jnp.float32)
 
     # -- entry points -------------------------------------------------------
 
@@ -377,19 +602,23 @@ class PatternDecoder:
                 prompt_len=None, last_logit_only: bool = False,
                 block_row=None, block_tables=None, lengths=None,
                 append_block_ids=None, append_offsets=None, cow_src=None,
-                cow_dst=None, mean_context: Optional[float] = None):
+                cow_dst=None, mean_context: Optional[float] = None,
+                slot=None):
         """The engine's two legs over a :class:`~apex_tpu.serving.cache.
         KindPagedKVCache` (``block_row``, ``block_tables`` and
-        ``append_block_ids`` are dicts by layer kind, as the by-kind
+        ``append_block_ids`` are dicts by BLOCK kind, as the by-kind
         allocator hands them out):
 
         - **paged prefill** (``block_row`` given): ``tokens`` ``(1, P)``,
           one prompt right-padded to a bucket; the padding is masked out
           of the routing (it costs no expert row and counts in no
-          counter) and its K/V land in null blocks.
+          counter), its K/V land in null blocks and it advances no state:
+          row ``slot`` (int32 scalar; a model with a state kind) gets the
+          conv tail and the state of the prompt's last real token.
         - **paged decode**: ``tokens`` ``(S, 1)``; a slot whose append
-          aims at the null block is idle and routed nowhere. The
-          copy-on-write pairs are ignored: this model shares no prefix.
+          aims at the null block is idle: routed nowhere, its state rows
+          left as they were. The copy-on-write pairs are ignored: this
+          model shares no prefix.
 
         Returns ``(logits, cache, stats)``; ``stats`` as
         :attr:`step_stats` says."""
@@ -412,7 +641,8 @@ class PatternDecoder:
             x = jnp.take(params["embedding"], tokens[0], axis=0).astype(
                 cfg.compute_dtype)
             fn = lambda kind, lp, big, li, x, cache: self._prefill_layer(
-                kind, lp, big, li, x, cache, block_row, valid)
+                kind, lp, big, li, x, cache, block_row, valid, prompt_len,
+                slot)
             x, pools, stats = self._run_layers(fn, params, x, pools)
             if last_logit_only:
                 x = jax.lax.dynamic_slice_in_dim(x, prompt_len - 1, 1, 0)

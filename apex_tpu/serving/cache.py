@@ -33,6 +33,17 @@ back the moment the cursor has left it for good, while a full layer's
 table keeps every block until ``release``. One cursor a slot, a table a
 kind.
 
+**A state kind** (PR 35): a recurrent layer (Mamba-2) keeps no blocks: what
+a slot needs of the past is a FIXED-SIZE state whatever its context, the
+conv's last inputs and the SSM state, one row a slot a layer
+(:class:`SlotStateCache`, described by a :class:`StateSpec` among the
+model's ``cache_kinds``). It lives in the same :class:`KindPagedKVCache`
+beside the block pools, donated and updated in place like them; the
+allocator gives it no blocks and no table (the slot IS its address), the
+prefill program is told its slot and overwrites the slot's rows (a slot
+given anew starts from zero whatever it held), the decode step advances the
+rows of the slots it serves and leaves the others as they were.
+
 The pool is token-major with the heads fused into its last axis because
 that is the one layout the append, the layer scan and the decode kernel
 all take as it is (measured and compiled for PR 27; the earlier
@@ -60,13 +71,14 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["cache_bytes_per_slot", "PagedKVCache",
+__all__ = ["cache_bytes_per_slot", "PagedKVCache", "StateSpec",
+           "SlotStateCache",
            "KindPagedKVCache", "BlockAllocator", "KindBlockAllocator",
            "AdmitPlan", "StepPlan", "PoolExhausted", "paged_block_bytes",
            "store_roundtrip"]
@@ -367,15 +379,82 @@ class PagedKVCache:
         return dataclasses.replace(self, **new)
 
 
+class StateSpec(NamedTuple):
+    """What a state kind holds (a model's ``cache_kinds`` entry, in the
+    place of a block kind's ``(layers, window)``): ``layers`` of the kind,
+    each keeping a slot the conv's last ``conv_taps - 1`` inputs over
+    ``conv_channels`` channels and an SSM state ``(heads, head_dim,
+    state_size)`` float32."""
+
+    layers: int
+    conv_channels: int
+    conv_taps: int
+    heads: int
+    head_dim: int
+    state_size: int
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class SlotStateCache:
+    """The per-slot recurrent state of one layer kind (module docstring, "A
+    state kind"). ``conv`` is time-major, ``(taps - 1, channels)`` a slot,
+    so that its last axis fills the lanes (``(channels, 3)`` would pad 3
+    lanes to 128)."""
+
+    conv: jnp.ndarray          # (L, slots, taps - 1, channels)
+    ssm: jnp.ndarray           # (L, slots, heads, head_dim, state) float32
+
+    def tree_flatten(self):
+        return (self.conv, self.ssm), None
+
+    @classmethod
+    def tree_unflatten(cls, _, leaves):
+        return cls(*leaves)
+
+    @classmethod
+    def create(cls, spec: StateSpec, max_seqs: int,
+               dtype=jnp.bfloat16) -> "SlotStateCache":
+        return cls(
+            jnp.zeros((spec.layers, max_seqs, spec.conv_taps - 1,
+                       spec.conv_channels), dtype),
+            jnp.zeros((spec.layers, max_seqs, spec.heads, spec.head_dim,
+                       spec.state_size), jnp.float32))
+
+    def nbytes(self) -> int:
+        return sum(leaf.size * leaf.dtype.itemsize
+                   for leaf in (self.conv, self.ssm))
+
+    @property
+    def bytes_per_slot(self) -> int:
+        return self.nbytes() // self.conv.shape[1]
+
+    def write_slot(self, layer, slot, conv_tail, state) -> "SlotStateCache":
+        """A prefill's result for ``slot`` at ``layer`` (int32 scalars):
+        the rows are OVERWRITTEN, whatever the slot held."""
+        return SlotStateCache(
+            self.conv.at[layer, slot].set(conv_tail.astype(self.conv.dtype)),
+            self.ssm.at[layer, slot].set(state.astype(jnp.float32)))
+
+    def write_layer(self, layer, conv_tails, states) -> "SlotStateCache":
+        """A decode step's rows of every slot at ``layer`` (the caller
+        keeps an idle slot's rows as they were): one in-place slab a
+        leaf."""
+        return SlotStateCache(
+            self.conv.at[layer].set(conv_tails.astype(self.conv.dtype)),
+            self.ssm.at[layer].set(states.astype(jnp.float32)))
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KindPagedKVCache:
-    """One :class:`PagedKVCache` pool per layer kind of a pattern model
-    (module docstring, "Pools by layer kind"): ``pools[kind]`` holds the
-    ``(layers of the kind, num_blocks of the kind, block_size, H_kv * D)``
-    pool, block 0 of each its own null block."""
+    """What a pattern model's layer kinds keep, by kind (module docstring,
+    "Pools by layer kind", "A state kind"): ``pools[kind]`` is a
+    :class:`PagedKVCache` ``(layers of the kind, num_blocks of the kind,
+    block_size, H_kv * D)``, block 0 of each its own null block, or a
+    :class:`SlotStateCache`."""
 
-    pools: Dict[str, PagedKVCache]
+    pools: Dict[str, "PagedKVCache | SlotStateCache"]
 
     def tree_flatten(self):
         kinds = tuple(sorted(self.pools))    # as jax orders a dict's keys
@@ -386,25 +465,41 @@ class KindPagedKVCache:
         return cls(dict(zip(kinds, pools)))
 
     @classmethod
-    def create(cls, kinds: Dict[str, Tuple[int, Optional[int]]],
+    def create(cls, kinds: Dict[str, tuple],
                num_blocks: Dict[str, int], num_heads: int, block_size: int,
-               head_dim: int, dtype=jnp.bfloat16) -> "KindPagedKVCache":
-        """``kinds``: ``{kind: (layers, window or None)}`` as the model's
-        ``cfg.cache_kinds`` gives it; ``num_blocks[kind]`` includes the
-        kind's null block."""
+               head_dim: int, dtype=jnp.bfloat16,
+               max_seqs: Optional[int] = None) -> "KindPagedKVCache":
+        """``kinds``: ``{kind: (layers, window or None)}`` for a block kind
+        or a :class:`StateSpec` for a state kind, as the model's
+        ``cfg.cache_kinds`` gives it; ``num_blocks[kind]`` (block kinds
+        only) includes the kind's null block; ``max_seqs`` sizes the state
+        kinds."""
         if jnp.dtype(dtype) == jnp.int8:
             raise ValueError("pools by layer kind are unquantized")
-        return cls({kind: PagedKVCache.create(layers, num_blocks[kind],
-                                              num_heads, block_size,
-                                              head_dim, dtype)
-                    for kind, (layers, _) in kinds.items()})
+        pools = {}
+        for kind, spec in kinds.items():
+            if isinstance(spec, StateSpec):
+                if max_seqs is None:
+                    raise ValueError(f"state kind {kind!r} needs max_seqs")
+                pools[kind] = SlotStateCache.create(spec, max_seqs, dtype)
+            else:
+                pools[kind] = PagedKVCache.create(
+                    spec[0], num_blocks[kind], num_heads, block_size,
+                    head_dim, dtype)
+        return cls(pools)
 
     def nbytes(self) -> int:
         return sum(pool.nbytes() for pool in self.pools.values())
 
+    @property
+    def state_bytes_per_slot(self) -> int:
+        return sum(pool.bytes_per_slot for pool in self.pools.values()
+                   if isinstance(pool, SlotStateCache))
+
     def scrub_null_blocks(self) -> "KindPagedKVCache":
         return KindPagedKVCache({
-            kind: dataclasses.replace(
+            kind: pool if isinstance(pool, SlotStateCache)
+            else dataclasses.replace(
                 pool, k=pool.k.at[:, NULL_BLOCK].set(0),
                 v=pool.v.at[:, NULL_BLOCK].set(0))
             for kind, pool in self.pools.items()})
@@ -875,15 +970,24 @@ class KindBlockAllocator:
     (``lengths``) is one a slot, the same in every kind. An admission or a
     step either gets its blocks in every kind or in none. No prefix is
     shared (a window layer has handed its early blocks back), so no
-    copy-on-write is ever pending."""
+    copy-on-write is ever pending. A state kind (:class:`StateSpec`) gets
+    no allocator, no blocks and no table: its address is the slot. What is
+    counted of it is how many slots hold one (:attr:`state_slots_in_use`:
+    admitted and not yet released)."""
 
-    def __init__(self, kinds: Dict[str, Tuple[int, Optional[int]]],
+    def __init__(self, kinds: Dict[str, tuple],
                  num_blocks: Dict[str, int], block_size: int,
                  blocks_per_slot: int, max_seqs: int):
         self.kinds = {kind: BlockAllocator(num_blocks[kind], block_size,
                                            blocks_per_slot, max_seqs,
-                                           window=window)
-                      for kind, (_, window) in kinds.items()}
+                                           window=spec[1])
+                      for kind, spec in kinds.items()
+                      if not isinstance(spec, StateSpec)}
+        if not self.kinds:
+            raise ValueError("a model with no block kind has no cursor: "
+                             "the paged engine serves at least one")
+        self.has_state = len(self.kinds) < len(kinds)
+        self._held = np.zeros(max_seqs, bool)
         self._first = next(iter(self.kinds.values()))
         self.block_size = int(block_size)
         self.blocks_per_slot = int(blocks_per_slot)
@@ -936,6 +1040,7 @@ class KindBlockAllocator:
             for a in done:
                 a.release(slot)
             raise
+        self._held[slot] = True
         return AdmitPlan(slot, len(prompt), prefill=True, block_row=rows)
 
     def prepare_step(self, active_slots: Sequence[int]) -> StepPlan:
@@ -958,8 +1063,15 @@ class KindBlockAllocator:
     def release(self, slot: int) -> None:
         for a in self.kinds.values():
             a.release(slot)
+        self._held[slot] = False
 
     # -- counters ----------------------------------------------------------------
+
+    @property
+    def state_slots_in_use(self) -> int:
+        """Slots whose state rows belong to a request (0 where the model
+        has no state kind)."""
+        return int(self._held.sum()) if self.has_state else 0
 
     @property
     def blocks_in_use(self) -> Dict[str, int]:

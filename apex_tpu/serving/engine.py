@@ -97,8 +97,12 @@ class ServingEngine:
     append targets ride into the same programs as dicts by kind, a
     window kind's blocks go back to its pool as the cursor leaves them,
     and what a ``step_stats`` model counts in a step comes back in the
-    token fetch (:attr:`last_stats`). Such a model is served without
-    the prefix index and without speculation (docs/SERVING.md, Limits).
+    token fetch (:attr:`last_stats`). A kind that keeps a per-slot STATE
+    in the place of blocks (a :class:`~apex_tpu.serving.cache.StateSpec`
+    among ``cache_kinds``: Mamba-2 layers) lives in the same cache, one
+    row a slot; the prefill programs are then told the slot they fill.
+    Such a model is served without the prefix index and without
+    speculation (docs/SERVING.md, Limits).
 
     Args:
       model: a :class:`~apex_tpu.models.gpt.GPTModel` (tp=1, no SP) or
@@ -190,9 +194,11 @@ class ServingEngine:
         if self.by_kind and (speculate_k or prefix_suffix_cap is not None):
             raise ValueError(
                 "a model with pools by layer kind is served without "
-                "speculation (its window kernel takes one query row) and "
-                "without the prefix index (a window layer has handed its "
-                "early blocks back): docs/SERVING.md, Limits")
+                "speculation (its window kernel takes one query row; a "
+                "per-slot state cannot be rolled back over rejected "
+                "drafts) and without the prefix index (a window layer has "
+                "handed its early blocks back; a state is no block to "
+                "share): docs/SERVING.md, Limits")
         if num_blocks is None and not self.by_kind:
             # every slot's whole max_len, and the null block
             num_blocks = max_seqs * -(-max_len // block_size) + 1
@@ -282,7 +288,8 @@ class ServingEngine:
         if self.by_kind:
             self.cache = KindPagedKVCache.create(
                 cfg.cache_kinds, num_blocks, cfg.num_key_value_heads,
-                block_size, cfg.head_dim, dtype=cache_dtype)
+                block_size, cfg.head_dim, dtype=cache_dtype,
+                max_seqs=max_seqs)
             self.allocator = KindBlockAllocator(
                 cfg.cache_kinds, num_blocks, block_size, blocks_per_slot,
                 max_seqs)
@@ -292,6 +299,9 @@ class ServingEngine:
                 block_size, cfg.head_dim, dtype=cache_dtype)
             self.allocator = BlockAllocator(num_blocks, block_size,
                                             blocks_per_slot, max_seqs)
+        #: a layer kind keeps a per-slot state (cache.py, "A state kind"):
+        #: the prefill programs take the slot they fill
+        self.has_state = self.by_kind and self.allocator.has_state
         stats = getattr(model, "step_stats", False)
         self._stats_shape = model.stats_shape if stats else None
         #: the counter each column of :attr:`last_stats` adds to
@@ -306,12 +316,13 @@ class ServingEngine:
                                     out[2].reshape(-1).astype(jnp.int32)])
 
         def prefill_step(params, cache, tokens, block_row, true_len,
-                         temperature, rng):
+                         temperature, rng, *slot):
             with jax.named_scope("serve_prefill"):
                 out = model.forward(params, tokens, kv_cache=cache,
                                     block_row=block_row,
                                     prompt_len=true_len,
-                                    last_logit_only=True)
+                                    last_logit_only=True,
+                                    **({"slot": slot[0]} if slot else {}))
                 logits, cache = out[0], out[1]
                 tok = sample_tokens(logits[0], rng, temperature[None],
                                     self.top_k)[0]
@@ -357,7 +368,8 @@ class ServingEngine:
 
         self._init_key(rng_seed)
         S = self.max_seqs
-        by_kind = (lambda x: {k: x for k in cfg.cache_kinds}) \
+        # a dict by BLOCK kind (a state kind has no table and no blocks)
+        by_kind = (lambda x: {k: x for k in self.allocator.kinds}) \
             if self.by_kind else (lambda x: x)
         ex_scalar = jnp.zeros((), jnp.int32)
         ex_temp = jnp.zeros((), jnp.float32)
@@ -370,7 +382,8 @@ class ServingEngine:
                         jnp.zeros((1, bucket), jnp.int32),
                         by_kind(jnp.zeros((bucket // block_size,),
                                           jnp.int32)),
-                        ex_scalar, ex_temp, self._key)
+                        ex_scalar, ex_temp, self._key,
+                        *((ex_scalar,) if self.has_state else ()))
                 self._prefill_programs[bucket] = \
                     self.prefill_traced.lower().compile()
             # the widest bucket's program stands for the leg (lint,
@@ -575,6 +588,8 @@ class ServingEngine:
                             _host(len(prompt), np.int32),
                             _host(temperature, np.float32),
                             self._next_key())
+                    if self.has_state:
+                        args += (_host(slot, np.int32),)
             if plan.prefill:
                 # with several buckets the span says which one's program
                 # the prompt ran and how many of its positions are tokens

@@ -784,6 +784,11 @@ class SlotScheduler:
             self._reg.counter("serve/window_blocks_returned").inc(
                 returned - self._window_seen[1])
             self._window_seen = (given, returned)
+            if self.engine.has_state:
+                held = alloc.state_slots_in_use
+                self._reg.gauge("serve/state_slots_in_use").set(held)
+                self._reg.gauge("serve/state_bytes_held").set(
+                    held * self.engine.cache.state_bytes_per_slot)
         if alloc.cow_copies > self._cow_seen:
             self._reg.counter("serve/blocks_cow_copied").inc(
                 alloc.cow_copies - self._cow_seen)

@@ -23,9 +23,12 @@ Design (single SPMD program, static shapes):
 
 Beside it, for SERVING a sparse model one chip holds a share of:
 :class:`HeldExpertsMLP` (section comment further down) — dropless top-k
-sigmoid routing over the published width, SwiGLU experts, shared experts
-averaged, the chip told which experts it holds; its work follows the
-assignments through a grouped product of two Pallas kernels.
+sigmoid routing over the published width, the chip told which experts it
+holds, its work following the assignments through a grouped product of two
+Pallas kernels. One class; the expert's form (gated SiLU or squared ReLU), a
+selection bias, a scaling factor, a latent round the routed part and the
+shared part (riding in the grouped product or an expert of its own width)
+are its parameters.
 """
 
 from __future__ import annotations
@@ -45,7 +48,7 @@ from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 from apex_tpu.transformer.tensor_parallel.layers import init_method_normal
 from jax.lax import axis_size as _axis_size
 
-__all__ = ["ExpertParallelMLP", "HeldExpertsMLP", "grouped_swiglu"]
+__all__ = ["ExpertParallelMLP", "HeldExpertsMLP", "grouped_experts"]
 
 
 class ExpertParallelMLP:
@@ -167,7 +170,8 @@ class ExpertParallelMLP:
 # it holds, and adds its part; the parts of the chips sum to the layer.
 # Nothing is dropped and no capacity is set, so the work is the
 # assignments': rows are sorted by expert into tiles of ``tile_m`` rows,
-# each tile one expert's, and two Pallas kernels (``moe_experts_gate_up``,
+# each tile one expert's, and two Pallas kernels (``moe_experts_gate_up``
+# for a gated expert or ``moe_experts_up`` for one up matrix, then
 # ``moe_experts_down``) multiply the used tiles with their expert's
 # matrices. The grid is shaped by the worst case (every pick held), but a
 # tile past the used ones costs a grid step and nothing else: its index maps
@@ -177,9 +181,19 @@ class ExpertParallelMLP:
 # matrices are read where they lie, and only those of experts with a row.
 
 _VMEM_LIMIT = 64 * 1024 * 1024
+# the most one block of an expert's matrix may take: double-buffered, and
+# two matrices in the gated kernel, it has to leave the limit room
+_WEIGHT_BLOCK_BYTES = 8 * 1024 * 1024
+
+ACTIVATIONS = ("swiglu", "relu2")
 
 
-def _tile_n(n: int) -> int:
+def _tile_n(n: int, k: int, itemsize: int = 2) -> int:
+    """Columns of an expert's matrix a block: all of them where the whole
+    ``(k, n)`` matrix is small (1024 x 2688 bf16 is one 5.5 MB fetch, not
+    21 of 256 KB), else the widest of 512 / 256 / 128 that divides."""
+    if k * n * itemsize <= _WEIGHT_BLOCK_BYTES:
+        return n
     for t in (512, 256, 128):
         if n % t == 0:
             return t
@@ -217,6 +231,16 @@ def _gate_up_kernel(lay_ref, tile_ref, used_ref, x_ref, wg_ref, wu_ref,
         o_ref[...] = (jax.nn.silu(g) * u).astype(o_ref.dtype)
 
 
+def _up_kernel(lay_ref, tile_ref, used_ref, x_ref, w_ref, o_ref):
+    del lay_ref, tile_ref
+
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _():
+        u = jnp.maximum(jnp.dot(x_ref[...], w_ref[0, 0],
+                                preferred_element_type=jnp.float32), 0.0)
+        o_ref[...] = (u * u).astype(o_ref.dtype)
+
+
 def _down_kernel(lay_ref, tile_ref, used_ref, x_ref, w_ref, o_ref):
     del lay_ref, tile_ref
 
@@ -231,7 +255,7 @@ def _grouped_call(kernel, name, xs, weights, layer, tile_expert, used,
                   tile_m):
     M, K = xs.shape
     N = weights[0].shape[-1]
-    tn = _tile_n(N)
+    tn = _tile_n(N, K * len(weights), weights[0].dtype.itemsize)
     x_spec, w_spec, o_spec = _grouped_specs(tile_m, K, tn)
     return pl.pallas_call(
         kernel,
@@ -248,54 +272,92 @@ def _grouped_call(kernel, name, xs, weights, layer, tile_expert, used,
       *weights)
 
 
-def grouped_swiglu(xs, w_gate, w_up, w_down, layer, tile_expert, used,
-                   tile_m: int):
-    """``(silu(xs Wg[e]) * (xs Wu[e])) Wd[e]`` for rows sorted into tiles
-    of ``tile_m``, tile ``t`` multiplied with expert ``tile_expert[t]`` of
+def grouped_experts(xs, w_in, w_down, layer, tile_expert, used, tile_m: int):
+    """``act(xs W_in[e]) W_down[e]`` for rows sorted into tiles of
+    ``tile_m``, tile ``t`` multiplied with expert ``tile_expert[t]`` of
     layer ``layer`` of the stacked ``(layers, experts, in, out)`` weights;
-    only the first ``used`` tiles are computed or fetched."""
+    only the first ``used`` tiles are computed or fetched. ``w_in`` is
+    ``(w_gate, w_up)`` for a gated-SiLU expert (``silu(x Wg) * (x Wu)``,
+    kernel ``moe_experts_gate_up``) or ``(w_up,)`` for a squared-ReLU one
+    (``relu(x Wu)^2``, kernel ``moe_experts_up``)."""
     with jax.named_scope("moe_experts"):
-        mid = _grouped_call(_gate_up_kernel, "moe_experts_gate_up", xs,
-                            (w_gate, w_up), layer, tile_expert, used, tile_m)
+        if len(w_in) == 2:
+            mid = _grouped_call(_gate_up_kernel, "moe_experts_gate_up", xs,
+                                w_in, layer, tile_expert, used, tile_m)
+        else:
+            mid = _grouped_call(_up_kernel, "moe_experts_up", xs, w_in,
+                                layer, tile_expert, used, tile_m)
         return _grouped_call(_down_kernel, "moe_experts_down", mid,
                              (w_down,), layer, tile_expert, used, tile_m)
 
 
+def _act(activation, w_in, x, hi):
+    """One expert's hidden row, float32: ``w_in`` as in
+    :func:`grouped_experts`."""
+    dot = lambda w: jnp.dot(x, w.astype(jnp.float32), precision=hi)
+    if activation == "swiglu":
+        return jax.nn.silu(dot(w_in[0])) * dot(w_in[1])
+    return jnp.square(jnp.maximum(dot(w_in[0]), 0.0))
+
+
 class HeldExpertsMLP:
-    """Dropless top-``top_k`` sigmoid-routed SwiGLU experts, the part of
-    one chip that holds the experts ``held`` (ids in the published
-    numbering) of ``num_experts``, beside ``num_shared`` shared experts
-    whose mean is added for every token (section comment above).
+    """Dropless top-``top_k`` sigmoid-routed experts, the part of one chip
+    that holds the experts ``held`` (ids in the published numbering) of
+    ``num_experts`` (section comment above). ONE layer whose form is its
+    parameters:
+
+    - ``activation``: ``"swiglu"`` (``(silu(x Wg) * (x Wu)) Wd``, three
+      matrices an expert) or ``"relu2"`` (``relu(x Wu)^2 Wd``, two);
+    - ``select_bias``: a per-expert bias (``router_bias``) added
+      to the scores for the CHOICE of the ``top_k`` and not for their
+      weights (DeepSeek-V3's);
+    - ``scaling``: the routed sum times this factor;
+    - ``latent_size``: the routed experts work in a latent of this width:
+      ``l = x W_latent_down`` goes in, the weighted sum of the held picks
+      comes out and ``W_latent_up`` is applied to that PARTIAL sum (the
+      projections are linear, so the chips' parts still add); router and
+      shared expert read the full ``hidden_size``;
+    - the shared part: ``num_shared`` experts of the routed width and form
+      whose MEAN is added for every token, riding as more tiles of the
+      grouped product; or, with ``shared_size``, one shared expert of that
+      width on the full hidden size as its own two (three, gated) dense
+      products, added whole.
 
     The router keeps its published width: a token's weights are its
-    ``top_k`` largest sigmoid scores over ALL experts, normalised over all
+    ``top_k`` picks' sigmoid scores over ALL experts, normalised over all
     ``top_k`` picks; the picks that are held add ``w_e E_e(x)``, the
     others nothing. Parameters (``init``): ``router`` ``(hidden,
-    num_experts)`` and ``w_gate``/``w_up`` ``(n, hidden, expert_size)``,
-    ``w_down`` ``(n, expert_size, hidden)`` with ``n = len(held) +
-    num_shared``, the held experts in the order of ``held``, then the
-    shared ones.
+    num_experts)``, ``w_up`` (and ``w_gate``) ``(n, d, expert_size)``,
+    ``w_down`` ``(n, expert_size, d)`` with ``d`` the latent or hidden size
+    and ``n = len(held) + num_shared``, the held experts in the order of
+    ``held``, then the riding shared ones; with their options
+    ``router_bias``, ``w_latent_down``, ``w_latent_up``, ``shared_up``
+    (``shared_gate``), ``shared_down``.
 
     ``__call__(params, x, valid=None, layer=None)``: ``x`` ``(tokens,
     hidden)``; ``valid`` ``(tokens,)`` bool masks padding and idle slots
-    out of the routing (no row, no count); with ``layer`` the three expert
-    arrays carry a leading layer axis and ``layer`` (int32 scalar, traced
-    in the layer scan) says which to read — the router is that layer's
-    own; ``held`` as in :meth:`route`. Returns ``(out (tokens, hidden), {"load": (len(held),) int32
-    assignments by held expert, "no_held_pick": int32 valid tokens none of
-    whose picks is held})``.
+    out of the routing (no row, no count); with ``layer`` the stacked expert
+    arrays (:attr:`stacked`) carry a leading layer axis and ``layer`` (int32
+    scalar, traced in the layer scan) says which to read — everything else
+    is that layer's own; ``held`` as in :meth:`route`. Returns ``(out
+    (tokens, hidden), {"load": (len(held),) int32 assignments by held
+    expert, "no_held_pick": int32 valid tokens none of whose picks is
+    held})``.
 
     ``axis_name``: inside ``shard_map`` over the chips that share the
     layer, each passing its own ``held`` weights, the parts are summed
     over the axis (the layer's one exchange, an all-reduce, since every
-    chip holds every token) and the shared experts counted once. On one
-    chip there is no exchange."""
+    chip holds every token) and what every chip computes alike (the shared
+    part) is counted once. On one chip there is no exchange."""
 
     def __init__(self, hidden_size: int, expert_size: int, num_experts: int,
                  top_k: int, held: Sequence[int], num_shared: int = 0,
                  axis_name: Optional[str] = None,
                  params_dtype=jnp.bfloat16, init_std: float = 0.02,
-                 use_pallas: bool = True):
+                 use_pallas: bool = True, activation: str = "swiglu",
+                 select_bias: bool = False, scaling: float = 1.0,
+                 latent_size: Optional[int] = None,
+                 shared_size: Optional[int] = None):
         held = tuple(int(e) for e in held)
         if len(set(held)) != len(held) or not held \
                 or not all(0 <= e < num_experts for e in held):
@@ -303,6 +365,13 @@ class HeldExpertsMLP:
                              f"below {num_experts}")
         if not 0 < top_k <= num_experts:
             raise ValueError(f"top_k {top_k} outside (0, {num_experts}]")
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"activation {activation!r} not among "
+                             f"{ACTIVATIONS}")
+        if shared_size and num_shared:
+            raise ValueError("the shared part either rides in the grouped "
+                             "product (num_shared) or is one expert of its "
+                             "own width (shared_size), not both")
         self.hidden_size, self.expert_size = hidden_size, expert_size
         self.num_experts, self.top_k = num_experts, top_k
         self.held, self.num_shared = held, int(num_shared)
@@ -310,19 +379,50 @@ class HeldExpertsMLP:
         self.params_dtype = params_dtype
         self.init_std = init_std
         self.use_pallas = use_pallas
+        self.activation = activation
+        self.select_bias = bool(select_bias)
+        self.scaling = float(scaling)
+        self.latent_size = latent_size
+        self.shared_size = shared_size
 
     @property
     def num_local(self) -> int:
         return len(self.held) + self.num_shared
 
-    def init(self, key: jax.Array) -> dict:
+    @property
+    def stacked(self) -> Tuple[str, ...]:
+        """The per-expert arrays: read by layer index where they lie."""
+        return ("w_gate", "w_up", "w_down") if self.activation == "swiglu" \
+            else ("w_up", "w_down")
+
+    def param_shapes(self) -> dict:
         n, h, f = self.num_local, self.hidden_size, self.expert_size
-        kr, kg, ku, kd = jax.random.split(key, 4)
-        draw = lambda k, shape: (self.init_std * jax.random.normal(
-            k, shape, jnp.float32)).astype(self.params_dtype)
-        return {"router": draw(kr, (h, self.num_experts)),
-                "w_gate": draw(kg, (n, h, f)), "w_up": draw(ku, (n, h, f)),
-                "w_down": draw(kd, (n, f, h))}
+        d = self.latent_size or h
+        gated = self.activation == "swiglu"
+        out = {"router": (h, self.num_experts), "w_up": (n, d, f),
+               "w_down": (n, f, d)}
+        if gated:
+            out["w_gate"] = (n, d, f)
+        if self.select_bias:
+            out["router_bias"] = (self.num_experts,)
+        if self.latent_size:
+            out["w_latent_down"] = (h, d)
+            out["w_latent_up"] = (d, h)
+        if self.shared_size:
+            out["shared_up"] = (h, self.shared_size)
+            out["shared_down"] = (self.shared_size, h)
+            if gated:
+                out["shared_gate"] = (h, self.shared_size)
+        return out
+
+    def init(self, key: jax.Array) -> dict:
+        out = {}
+        for i, (name, shape) in enumerate(sorted(
+                self.param_shapes().items())):
+            out[name] = (self.init_std * jax.random.normal(
+                jax.random.fold_in(key, i), shape, jnp.float32)
+            ).astype(self.params_dtype)
+        return out
 
     def tile_m(self, tokens: int) -> int:
         """Rows a tile: about the rows an expert gets when the picks are
@@ -331,66 +431,101 @@ class HeldExpertsMLP:
         per = max(1, tokens * self.top_k // self.num_experts)
         return int(min(256, max(32, 1 << (per - 1).bit_length())))
 
-    def route(self, router, x, valid=None, held=None):
+    def route(self, router, x, valid=None, held=None, bias=None):
         """``(local (tokens, picks) int32, weight (tokens, picks) f32)``:
-        per token its routed picks then the shared experts, ``local`` the
-        index into this chip's expert stack or -1 (not held, or the token
-        is not ``valid``). ``held``: the ids as a traced ``(len(held),)``
-        array in the place of the constructor's (a chip that learns its
-        experts from its place on the axis)."""
+        per token its routed picks then the riding shared experts,
+        ``local`` the index into this chip's expert stack or -1 (not held,
+        or the token is not ``valid``). ``held``: the ids as a traced
+        ``(len(held),)`` array in the place of the constructor's (a chip
+        that learns its experts from its place on the axis). ``bias``: the
+        selection bias, where the layer has one."""
         T = x.shape[0]
         ids = np.asarray(self.held, np.int32) if held is None else held
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
-        top_s, top_e = jax.lax.top_k(scores, self.top_k)
-        weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+        if bias is None:
+            top_s, top_e = jax.lax.top_k(scores, self.top_k)
+        else:
+            _, top_e = jax.lax.top_k(
+                scores + bias.astype(jnp.float32)[None, :], self.top_k)
+            top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+        weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True) \
+            * self.scaling
         match = top_e[..., None] == ids[None, None, :]
         local = jnp.where(jnp.any(match, axis=-1),
                           jnp.argmax(match, axis=-1), -1).astype(jnp.int32)
         if self.num_shared:
             ns = self.num_shared
-            share = 1.0 / ns
-            if self.axis_name is not None:
-                share /= jax.lax.axis_size(self.axis_name)
             local = jnp.concatenate([local, jnp.broadcast_to(
                 len(self.held) + jnp.arange(ns, dtype=jnp.int32),
                 (T, ns))], axis=1)
             weight = jnp.concatenate(
-                [weight, jnp.full((T, ns), share, jnp.float32)], axis=1)
+                [weight, jnp.full((T, ns), self._once() / ns, jnp.float32)],
+                axis=1)
         if valid is not None:
             local = jnp.where(valid[:, None], local, -1)
         return local, weight
+
+    def _once(self) -> float:
+        """The share of what every chip of the axis computes alike that
+        this chip adds, so that the sum over the axis counts it once."""
+        if self.axis_name is None:
+            return 1.0
+        return 1.0 / jax.lax.axis_size(self.axis_name)
 
     def __call__(self, params: dict, x: jnp.ndarray,
                  valid: Optional[jnp.ndarray] = None, layer=None,
                  held=None):
         T = x.shape[0]
         nh = len(self.held)
-        local, weight = self.route(params["router"], x, valid, held)
+        local, weight = self.route(params["router"], x, valid, held,
+                                   params.get("router_bias"))
         routed = local[:, :self.top_k]
         onehot_r = (routed[..., None] == jnp.arange(nh)).astype(jnp.int32)
         ok = jnp.ones((T,), bool) if valid is None else valid
         stats = {"load": jnp.sum(onehot_r, axis=(0, 1)),
                  "no_held_pick": jnp.sum(
                      ok & ~jnp.any(routed >= 0, axis=1)).astype(jnp.int32)}
-        w_gate, w_up, w_down = (params[k] for k in
-                                ("w_gate", "w_up", "w_down"))
+        stack = [params[k] for k in self.stacked]
         if layer is None:
-            w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+            stack = [w[None] for w in stack]
             layer = 0
         layer = jnp.asarray(layer, jnp.int32)
+        w_in, w_down = tuple(stack[:-1]), stack[-1]
+        rows = x
+        if self.latent_size:
+            rows = jnp.dot(x, params["w_latent_down"],
+                           preferred_element_type=jnp.float32
+                           ).astype(x.dtype)
         if self.use_pallas:
-            out = self._sorted_product(x, local, weight, w_gate, w_up,
-                                       w_down, layer)
+            out = self._sorted_product(rows, local, weight, w_in, w_down,
+                                       layer)
         else:
-            out = self._dense_product(x, local, weight, w_gate[layer],
-                                      w_up[layer], w_down[layer])
+            out = self._dense_product(rows, local, weight,
+                                      [w[layer] for w in w_in],
+                                      w_down[layer])
+        if self.latent_size:
+            out = jnp.dot(out.astype(x.dtype), params["w_latent_up"],
+                          preferred_element_type=jnp.float32)
+        if self.shared_size:
+            out = out + self._once() * self._shared(params, x)
         if self.axis_name is not None:
             out = jax.lax.psum(out, self.axis_name)
         return out.astype(x.dtype), stats
 
-    def _dense_product(self, x, local, weight, w_gate, w_up, w_down):
+    def _shared(self, params, x):
+        """The shared expert of its own width: dense products over every
+        token, float32 out."""
+        dot = lambda a, w: jnp.dot(a, w, preferred_element_type=jnp.float32)
+        if self.activation == "swiglu":
+            mid = jax.nn.silu(dot(x, params["shared_gate"])) \
+                * dot(x, params["shared_up"])
+        else:
+            mid = jnp.square(jnp.maximum(dot(x, params["shared_up"]), 0.0))
+        return dot(mid.astype(x.dtype), params["shared_down"])
+
+    def _dense_product(self, x, local, weight, w_in, w_down):
         """Every expert over every token, masked: experts x tokens of
         work, the oracle of the sorted product."""
         n = self.num_local
@@ -401,15 +536,12 @@ class HeldExpertsMLP:
         hi = jax.lax.Precision.HIGHEST
         out = jnp.zeros(x.shape, jnp.float32)
         for e in range(n):
-            mid = jax.nn.silu(jnp.dot(x32, w_gate[e].astype(jnp.float32),
-                                      precision=hi)) \
-                * jnp.dot(x32, w_up[e].astype(jnp.float32), precision=hi)
+            mid = _act(self.activation, [w[e] for w in w_in], x32, hi)
             out = out + per[:, e:e + 1] * jnp.dot(
                 mid, w_down[e].astype(jnp.float32), precision=hi)
         return out
 
-    def _sorted_product(self, x, local, weight, w_gate, w_up, w_down,
-                        layer):
+    def _sorted_product(self, x, local, weight, w_in, w_down, layer):
         T, H = x.shape
         n, picks = self.num_local, local.shape[1]
         tm = self.tile_m(T)
@@ -434,8 +566,7 @@ class HeldExpertsMLP:
             n - 1).astype(jnp.int32)
         used = (ends[-1] // tm).astype(jnp.int32)
         xs = jnp.take(x, token_of_row, axis=0)
-        ys = grouped_swiglu(xs, w_gate, w_up, w_down, layer, tile_expert,
-                            used, tm)
+        ys = grouped_experts(xs, w_in, w_down, layer, tile_expert, used, tm)
         # each token gathers its own picks' rows back: a gather, where a
         # scatter-add over the rows would serialise
         held = (flat >= 0).reshape(T, picks)

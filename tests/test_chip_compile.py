@@ -434,3 +434,123 @@ def test_pattern_decoder_programs_compile_for_v5e(kind, one_chip, for_tpu,
     # one layer kind's expert stack
     if kind != "prefill8192":
         assert memory.temp_size_in_bytes < 1.0e9, memory.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# nemotron-3-super-120b-a12b.reason-r80 (benchmark/workloads): one chip of
+# four's share, 11 layers MEMEMEM*EME, 32 Mamba heads of 64 in 2 groups
+# with a state of 128, 8 query heads on 1 KV head of 128, 128 held of 512
+# experts (1024 -> 2688 -> 1024) top-22, a shared expert of 5376; 64 slots
+# of 4,096: a pool for the one attention layer, a state row a slot
+# ---------------------------------------------------------------------------
+
+NEMOTRON = dict(slots=64, max_len=4096, block=128,
+                blocks={"attention": 64 * 32 + 1})
+mamba2 = importlib.import_module("apex_tpu.ops.mamba2")
+
+
+def _nemotron_model():
+    from benchmark import run as harness
+    from benchmark.families import nemotron_h
+    cfg = harness.load_json(harness.HERE, "configs",
+                            "nemotron-3-super-120b-a12b.json")
+    return nemotron_h.model(cfg)
+
+
+def _nemotron_step(kind, sharding):
+    from apex_tpu.serving.cache import KindPagedKVCache
+    c = NEMOTRON
+    model = _nemotron_model()
+    cfg = model.cfg
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    params = described(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = described(jax.eval_shape(lambda: KindPagedKVCache.create(
+        cfg.cache_kinds, c["blocks"], 1, c["block"], 128,
+        max_seqs=c["slots"])))
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree_util.tree_leaves(params))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
+
+    by_kind = lambda x: {"attention": x}
+    S, per_slot = c["slots"], c["max_len"] // c["block"]
+    if kind == "decode":
+        def fn(params, cache, tokens, tables, lengths, ids, offs):
+            return model.forward(
+                params, tokens[:, None], kv_cache=cache,
+                block_tables=tables, lengths=lengths, append_block_ids=ids,
+                append_offsets=offs)
+        args = (params, cache, i32(S), by_kind(i32(S, per_slot)), i32(S),
+                by_kind(i32(S)), i32(S))
+    else:
+        bucket = int(kind[len("prefill"):])
+
+        def fn(params, cache, tokens, block_row, prompt_len, slot):
+            return model.forward(
+                params, tokens, kv_cache=cache, block_row=block_row,
+                prompt_len=prompt_len, last_logit_only=True, slot=slot)
+        args = (params, cache, i32(1, bucket),
+                by_kind(i32(bucket // c["block"])), i32(), i32())
+    return fn, args, cache, weight_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill256", "prefill512",
+                                  "prefill1024", "prefill2048"])
+def test_nemotron_h_programs_compile_for_v5e(kind, one_chip, for_tpu,
+                                             monkeypatch):
+    """The cell's decode program and its four prefill programs at published
+    widths: the scan kernel, the one-up-matrix expert kernels, the
+    grouped-KV kernels pass Mosaic; the pool AND the per-slot state are
+    updated where they lie (a decode step copies no whole state: PR 27's
+    lesson, here 0.34 GB); weights 8.4 GB + caches + temporaries fit the
+    chip."""
+    ep = importlib.import_module("apex_tpu.transformer.expert_parallel")
+    monkeypatch.setattr(ep, "_interp", lambda: False)
+    monkeypatch.setattr(mamba2, "_interp", lambda: False)
+    fn, args, cache, weight_bytes = _nemotron_step(kind, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    names = ["moe_experts_up", "moe_experts_down",
+             "paged_decode_attention" if kind == "decode"
+             else "mamba2_chunk_scan"]
+    for name in names:
+        assert name in text, name
+    assert "moe_experts_gate_up" not in text
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    state = cache.pools["mamba"].ssm
+    assert state.shape == (5, 64, 32, 64, 128) and state.dtype == F32
+    assert 0.45e9 < cache_bytes < 0.50e9
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert 8.3e9 < weight_bytes < 8.5e9, weight_bytes
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert total < 15.5e9, (total, memory.temp_size_in_bytes)
+    # no copy of the state, of a layer's expert stack or of the shared
+    # expert: the temporaries stay far under any of them but in the widest
+    # prefill, whose worst-case expert rows are 0.6 GB
+    limit = 1.2e9 if kind == "prefill2048" else 0.45e9
+    assert memory.temp_size_in_bytes < limit, memory.temp_size_in_bytes
+
+
+def test_the_scan_kernel_compiles_for_v5e_at_published_widths(one_chip,
+                                                              for_tpu,
+                                                              monkeypatch):
+    monkeypatch.setattr(mamba2, "_interp", lambda: False)
+
+    def fn(x, dt, A, B, C, n):
+        return mamba2.mamba2_chunk_scan(x, dt, A, B, C, chunk=128, length=n,
+                                        use_pallas=True)
+
+    shapes = [((2048, 32, 64), BF16), ((2048, 32), F32), ((32,), F32),
+              ((2048, 2, 128), BF16), ((2048, 2, 128), BF16), ((), I32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "mamba2_chunk_scan" in calls[0], calls
