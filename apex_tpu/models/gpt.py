@@ -32,7 +32,8 @@ from apex_tpu.normalization import fused_layer_norm_affine
 from apex_tpu.ops.dropout import dropout
 from apex_tpu.remat import RematPolicy, tag as _remat_tag
 from apex_tpu.ops.flash_attention import (flash_attention,
-                                          paged_decode_attention)
+                                          paged_decode_attention,
+                                          paged_work_list)
 from apex_tpu.ops.xentropy import softmax_cross_entropy_loss
 from apex_tpu.transformer import tensor_parallel as tp_mod
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
@@ -587,11 +588,11 @@ class GPTModel:
                             block_tables: jnp.ndarray,
                             lengths: jnp.ndarray, block_ids: jnp.ndarray,
                             offsets: jnp.ndarray,
-                            mean_context: Optional[float]):
+                            mean_context: Optional[float], work):
         """One layer of the decode step: ``x`` is ``(S, 1, hidden)``, one
         token per slot. The context comes through each slot's block
         table, so only ~ceil(cursor/block_size) pool blocks are streamed
-        per slot. The
+        per slot (``work``, the step's walk of them). The
         kernel reads layer ``layer`` of the stacked pool where it lies;
         the layer then writes its own new K/V row there (after the read:
         the current token reaches attention through the merge)."""
@@ -606,7 +607,7 @@ class GPTModel:
                 q, cache.k, cache.v, layer, block_tables, lengths,
                 k_new=k_new, v_new=v_new, k_scale=cache.k_scale,
                 v_scale=cache.v_scale, mean_context=mean_context,
-                use_pallas=cfg.use_flash)
+                use_pallas=cfg.use_flash, work=work)
             cache = cache.append(layer, k_new, v_new, block_ids, offsets)
             out, _ = self.proj(lp["proj"], ctx.reshape(S, 1, -1))
         x = x + out
@@ -680,9 +681,12 @@ class GPTModel:
         block_ids = jnp.asarray(block_ids, jnp.int32)
         offsets = jnp.asarray(offsets, jnp.int32)
 
+        # the kernel's walk is every layer's: made once, outside the scan
         x, cache = self._scan_paged_layers(
             self._paged_decode_layer, params, x, cache, block_tables,
-            lengths, block_ids, offsets, mean_context)
+            lengths, block_ids, offsets, mean_context,
+            paged_work_list(lengths, cache.block_size,
+                            block_tables.shape[1]))
         x = self._ln(params["final_ln"], x)
         return self.logits(params, x)[:, 0], cache
 
@@ -734,7 +738,7 @@ class GPTModel:
                             block_tables: jnp.ndarray,
                             lengths: jnp.ndarray, block_ids: jnp.ndarray,
                             offsets: jnp.ndarray,
-                            mean_context: Optional[float]):
+                            mean_context: Optional[float], work):
         """One layer of the verify step: ``x`` is ``(S, Q, hidden)`` — the
         last accepted token plus the in-flight drafts. The bounded
         block-table fetch of :meth:`_paged_decode_layer` is amortized
@@ -751,7 +755,7 @@ class GPTModel:
                 q, cache.k, cache.v, layer, block_tables, lengths,
                 k_new=k_new, v_new=v_new, k_scale=cache.k_scale,
                 v_scale=cache.v_scale, mean_context=mean_context,
-                use_pallas=cfg.use_flash,
+                use_pallas=cfg.use_flash, work=work,
                 k_cast=roundtrip(k_new, cache.k.dtype, cache.quantized),
                 v_cast=roundtrip(v_new, cache.k.dtype, cache.quantized))
             cache = cache.append_k(layer, k_new, v_new, block_ids, offsets)
@@ -794,7 +798,9 @@ class GPTModel:
         x, kv_cache = self._scan_paged_layers(
             self._paged_verify_layer, params,
             self._verify_embed(params, tokens, lengths), kv_cache,
-            block_tables, lengths, block_ids, offsets, mean_context)
+            block_tables, lengths, block_ids, offsets, mean_context,
+            paged_work_list(lengths, kv_cache.block_size,
+                            block_tables.shape[1]))
         x = self._ln(params["final_ln"], x)
         return self.logits(params, x), kv_cache     # (S, Q, vocab)
 

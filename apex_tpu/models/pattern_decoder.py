@@ -61,7 +61,8 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops.flash_attention import (flash_attention,
-                                          paged_decode_attention)
+                                          paged_decode_attention,
+                                          paged_work_list)
 from apex_tpu.ops.mamba2 import (causal_conv, causal_conv_update,
                                  mamba2_chunk_scan, mamba2_decode_update)
 from apex_tpu.transformer.expert_parallel import HeldExpertsMLP
@@ -506,9 +507,10 @@ class PatternDecoder:
 
     def _paged_decode_layer(self, kind, lp, big, li, x, cache, tables,
                             lengths, block_ids, offsets, valid,
-                            mean_context):
+                            mean_context, work):
         """One layer of the decode step: ``x`` ``(S, hidden)``, one token
-        a slot at position ``lengths``."""
+        a slot at position ``lengths``; ``work`` the paged kernel's walk
+        of each attention kind's blocks."""
         cfg = self.cfg
         S = x.shape[0]
         h32 = self._norm(lp["norm"], x)
@@ -521,7 +523,8 @@ class PatternDecoder:
                 ctx = paged_decode_attention(
                     q, pool.k, pool.v, li, tables[kind], lengths,
                     k_new=k_new, v_new=v_new, mean_context=mean_context,
-                    use_pallas=cfg.use_flash, window=self._window(kind))
+                    use_pallas=cfg.use_flash, window=self._window(kind),
+                    work=work[kind])
             cache = dict(cache, **{kind: pool.append(
                 li, k_new, v_new, block_ids[kind], offsets)})
             mixed = jnp.dot(ctx.reshape(S, -1).astype(cfg.compute_dtype),
@@ -656,8 +659,12 @@ class PatternDecoder:
         valid = jnp.asarray(first) != NULL_BLOCK
         x = jnp.take(params["embedding"], tokens[:, 0], axis=0).astype(
             cfg.compute_dtype)
+        # a kind's layers share one walk: made here, outside their scans
+        work = {kind: paged_work_list(lengths, pools[kind].block_size,
+                                      table.shape[1], self._window(kind))
+                for kind, table in block_tables.items()}
         fn = lambda kind, lp, big, li, x, cache: self._paged_decode_layer(
             kind, lp, big, li, x, cache, block_tables, lengths,
-            append_block_ids, append_offsets, valid, mean_context)
+            append_block_ids, append_offsets, valid, mean_context, work)
         x, pools, stats = self._run_layers(fn, params, x, pools)
         return self._logits(params, x), KindPagedKVCache(pools), stats

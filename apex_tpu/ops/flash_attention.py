@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +60,8 @@ from jax.experimental.pallas import tpu as pltpu
 from apex_tpu.utils.backend import pallas_interpret as _interp
 
 __all__ = ["flash_attention", "mha_reference", "supports_flash",
-           "dropout_keep_mask", "paged_decode_attention"]
+           "dropout_keep_mask", "paged_decode_attention",
+           "paged_work_list"]
 
 NEG_INF = -1e30
 
@@ -1357,7 +1358,7 @@ def _gathered_reference(q, k, v, lengths, k_new, v_new, k_scale, v_scale,
 
 
 # ---------------------------------------------------------------------------
-# paged decode kernel — bounded-grid attention over a block-pool KV cache
+# paged decode kernel — live-block attention over a block-pool KV cache
 # ---------------------------------------------------------------------------
 #
 # The serving kernel (docs/SERVING.md "Paged serving"): vLLM-style
@@ -1379,17 +1380,29 @@ def _gathered_reference(q, k, v, lengths, k_new, v_new, k_scale, v_scale,
 #   multiple of 128 at every published width) the resident layout IS the
 #   row-major one and nothing in the decode program slices, copies or
 #   relays the pool: the kernel takes the stacked pool and a LAYER INDEX;
-# - the layer index, the per-slot block table and the cursor ride as
-#   SCALAR-PREFETCH arguments (``pltpu.PrefetchScalarGridSpec``): they are
-#   resident before the grid starts, and the K/V BlockSpec index maps read
-#   them to aim each fetch at ``(layer, table[slot, j], 0, 0)`` — one whole
-#   ``(block_size, h * d)`` pool block, all heads, lane-dense;
-# - the fetch sequence is bounded by the cursor: past the slot's last
-#   valid block the index map CLAMPS to that block, so consecutive grid
-#   steps resolve to the SAME pool block and the Pallas pipeline elides
-#   the re-fetch (equal block index => no new DMA) — HBM traffic per slot
-#   per step is O(actual_context), not O(max_len). Compute past the
-#   cursor is skipped under a ``@pl.when``;
+# - the layer index, the per-slot block table, the cursor and the work
+#   list ride as SCALAR-PREFETCH arguments
+#   (``pltpu.PrefetchScalarGridSpec``): they are resident before the grid
+#   starts, and the K/V BlockSpec index maps read them to aim each fetch
+#   at ``(layer, table[slot, j], 0, 0)`` — one whole ``(block_size, h *
+#   d)`` pool block, all heads, lane-dense;
+# - the grid follows the LIVE blocks, not the table's capacity
+#   (:func:`paged_work_list`): the cursors become a list of ``(slot,
+#   logical block)`` items, one for every block that holds a position the
+#   slot's rows still read, slot-major, blocks ascending, and their count
+#   ``n_live``. The list is the same for every layer of a kind, so a
+#   model builds it once a step, outside its layer scan. The grid is ONE
+#   dimension whose bound is ``n_live``, a traced value: the bound is
+#   data, not shape, so one compiled program serves every load, and a
+#   call costs what its contexts hold — grid steps and HBM traffic are
+#   O(actual context), not O(max_seqs x table span). Item ``w`` fetches
+#   pool block ``table[slot[w], block[w]]`` and maps the query and output
+#   tiles to ``slot[w]``, so a slot's consecutive items keep its tiles
+#   resident; the accumulators start on a slot's first item, the division
+#   and the write happen on its last. A slot with no item is never
+#   visited and its output tile never written: the diagonal select after
+#   the call gives it ``out`` 0 and ``lse`` -inf, the identity of the
+#   ``_merge_current`` fold;
 # - all heads of a block are scored at once and no lane is ever sliced:
 #   the query comes in BLOCK-DIAGONAL, ``(h, h * d)`` with head g's row
 #   holding q[g] in lanes [g*d, (g+1)*d) and exact zeros elsewhere, so
@@ -1410,67 +1423,110 @@ def _gathered_reference(q, k, v, lengths, k_new, v_new, k_scale, v_scale,
 #   ``mha_reference(kv_length=)``;
 # - ``mean_context`` (an expected-occupancy hint, tokens) sizes the
 #   ``pl.CostEstimate`` attached to the kernel so the pyprof roofline
-#   prices the fetch-elided traffic instead of the worst-case table span
+#   prices the live blocks' traffic instead of the worst-case table span
 #   (``pyprof/model.py`` reads it off the ``pallas_call`` eqn). It never
 #   changes the math — only the modeled bytes.
 
-def _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref,
-                         ksc_ref, vsc_ref, o_ref, lse_ref, acc_ref, m_ref,
-                         l_ref, *, scale, block_size, n_blocks, q_len,
-                         window=None):
-    del layer_ref, tab_ref                  # the index maps' business
-    s, j = pl.program_id(0), pl.program_id(1)
-    length = len_ref[s]
-    jgrid = j
-    if window is not None:
-        # the grid walks the window's blocks only: step j is the slot's
-        # logical block first + j (the index map aims the fetch the same)
-        j = jnp.maximum(length - window + 1, 0) // block_size + j
+class PagedWork(NamedTuple):
+    """The paged kernel's walk (:func:`paged_work_list`): item ``w <
+    n_live`` is logical block ``block[w]`` of slot ``slot[w]``; the
+    entries past ``n_live`` are zeros (in range, never computed on).
+    ``count`` is the items a slot, 0 where the kernel never visits."""
+    slot: jnp.ndarray       # (S * blocks a slot + 1,) int32
+    block: jnp.ndarray      # the same
+    count: jnp.ndarray      # (S,) int32
+    n_live: jnp.ndarray     # () int32
 
-    @pl.when(jgrid == 0)
+
+def _walk_blocks(n_blocks: int, block_size: int,
+                 window: Optional[int]) -> int:
+    """The most blocks one slot's rows read: the table's width, or under
+    a window of W positions before a cursor the blocks those can span."""
+    if window is None:
+        return n_blocks
+    return min(n_blocks, (max(window, 2) - 2) // block_size + 2)
+
+
+def paged_work_list(lengths, block_size: int, n_blocks: int,
+                    window: Optional[int] = None) -> PagedWork:
+    """The blocks :func:`paged_decode_attention` computes on, from the
+    cursors alone: for slot ``s`` the logical blocks ``j`` with ``j *
+    block_size < lengths[s]``, under a ``window`` from the first block the
+    cursor's row still reads, slot-major, blocks ascending. ``n_blocks``
+    is the block table's width. A cumulative sum and a search over a few
+    hundred int32, on the device; the same for every layer of a kind, so
+    build it once a step, outside the layer scan, and hand it down."""
+    lengths = jnp.asarray(lengths, jnp.int32)
+    S = lengths.shape[0]
+    per_slot = _walk_blocks(n_blocks, block_size, window)
+    with jax.named_scope("paged_work_list"):
+        first = jnp.zeros_like(lengths) if window is None \
+            else jnp.maximum(lengths - window + 1, 0) // block_size
+        count = jnp.clip((lengths + block_size - 1) // block_size - first,
+                         0, per_slot)
+        ends = jnp.cumsum(count)
+        w = jnp.arange(S * per_slot + 1, dtype=jnp.int32)
+        # every item against every slot's end: one small fusion, no loop
+        slot = jnp.searchsorted(ends, w, side="right",
+                                method="compare_all").astype(jnp.int32)
+        live = slot < S
+        slot = jnp.where(live, slot, 0)
+        block = jnp.where(live, first[slot] + w - (ends - count)[slot], 0)
+        return PagedWork(slot, block, count, ends[-1])
+
+
+def _paged_decode_kernel(layer_ref, tab_ref, len_ref, slot_ref, blk_ref,
+                         n_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref,
+                         o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale,
+                         block_size, q_len, window=None):
+    del layer_ref, tab_ref                  # the index maps' business
+    w = pl.program_id(0)
+    s, j = slot_ref[w], blk_ref[w]
+    length = len_ref[s]
+
+    # the slot's first item: its neighbour to the left is another slot's
+    @pl.when((w == 0) | (slot_ref[jnp.maximum(w - 1, 0)] != s))
     def _():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # skip the COMPUTE past the cursor; the FETCH is already bounded by
-    # the clamped index map (see the section comment)
-    @pl.when(j * block_size < length)
-    def _():
-        k = k_ref[0, 0].astype(jnp.float32)       # (block_size, h*d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
-        cached = pos < length
-        # one (h, h*d) block-diagonal query tile per in-flight row: every
-        # array below is (h, ·), the same program at q_len 1 and q_len k
-        for i in range(q_len):
-            # row i sits at position length + i and, under a window, reads
-            # back to length + i - window + 1
-            live = cached if window is None \
-                else cached & (pos > length + i - window)
-            q = q_ref[0, i].astype(jnp.float32)   # (h, h*d)
-            s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32
-                                     ) * scale    # (h, block_size)
-            if ksc_ref is not None:
-                # int8 pool: q . (k_q * scale) == (q . k_q) * scale — HBM
-                # and VMEM only ever hold int8 blocks
-                s_ = s_ * ksc_ref[0, 0]
-            s_ = jnp.where(live, s_, NEG_INF)
-            m_prev = m_ref[i]
-            m_new = jnp.maximum(m_prev, jnp.max(s_, axis=1, keepdims=True))
-            p = jnp.where(live, jnp.exp(s_ - m_new), 0.0)
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[i] = l_ref[i] * corr + jnp.sum(p, axis=1, keepdims=True)
-            m_ref[i] = m_new
-            if vsc_ref is not None:
-                p = p * vsc_ref[0, 0]
-            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_ref[i] = acc_ref[i] * corr + pv   # (h, h*d)
+    k = k_ref[0, 0].astype(jnp.float32)       # (block_size, h*d)
+    v = v_ref[0, 0].astype(jnp.float32)
+    pos = j * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_size), 1)
+    cached = pos < length
+    # one (h, h*d) block-diagonal query tile per in-flight row: every
+    # array below is (h, ·), the same program at q_len 1 and q_len k
+    for i in range(q_len):
+        # row i sits at position length + i and, under a window, reads
+        # back to length + i - window + 1
+        live = cached if window is None \
+            else cached & (pos > length + i - window)
+        q = q_ref[0, i].astype(jnp.float32)   # (h, h*d)
+        s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32
+                                 ) * scale    # (h, block_size)
+        if ksc_ref is not None:
+            # int8 pool: q . (k_q * scale) == (q . k_q) * scale — HBM
+            # and VMEM only ever hold int8 blocks
+            s_ = s_ * ksc_ref[0, 0]
+        s_ = jnp.where(live, s_, NEG_INF)
+        m_prev = m_ref[i]
+        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=1, keepdims=True))
+        p = jnp.where(live, jnp.exp(s_ - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[i] = l_ref[i] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[i] = m_new
+        if vsc_ref is not None:
+            p = p * vsc_ref[0, 0]
+        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[i] = acc_ref[i] * corr + pv   # (h, h*d)
 
-    @pl.when(jgrid == n_blocks - 1)
+    # the slot's last item (the entries past n_live are slot 0's, which
+    # the last live slot may be too: hence the count)
+    @pl.when((w == n_ref[0] - 1) | (slot_ref[w + 1] != s))
     def _():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -1482,10 +1538,10 @@ def _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref,
 
 def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
                 mean_context, q_len=1, q_itemsize=2, hkv=None):
-    """``pl.CostEstimate`` for one paged decode call: the fetch-elided
-    HBM bytes at ``mean_context`` tokens of ACTUAL context per slot (the
-    index-map clamp makes repeated blocks free), so the pyprof roofline
-    prices what the kernel moves, not the worst-case table span. The
+    """``pl.CostEstimate`` for one paged decode call: the HBM bytes at
+    ``mean_context`` tokens of ACTUAL context per slot (the kernel walks
+    the live blocks only), so the pyprof roofline prices what the kernel
+    moves, not the worst-case table span. The
     K/V fetches are dense ``(block_size, h * d)`` blocks; the query and
     the output ride block-diagonal, ``h`` times their useful size, and
     the FLOPs are those the MXU is issued for them (``h`` times the
@@ -1513,26 +1569,20 @@ def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
                            transcendentals=int(s * h * ctx * q_len))
 
 
-def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
-                         scale, mean_context, window=None):
+def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, work,
+                         *, scale, mean_context, window=None):
     # q is (S, h, q_len, d): q_len == 1 is the classic decode step,
     # q_len == k + 1 the speculative verify — ONE program shape for
     # both. Every block spans its array's last two dims whole — the
     # (block_size, h*d) pool blocks, the (h, h*d) query/output tiles,
     # the (h, block_size) scale tiles, the (h, 1) lse columns — which
-    # Mosaic accepts at any size; the KV fetch sequence (and its clamp)
-    # is q_len-independent.
+    # Mosaic accepts at any size; the walk is q_len-independent.
     S, h, q_len, d = q.shape
     block_size = kp.shape[2]
-    n_blocks = tables.shape[1]
     has_scale = ksc is not None
     # grouped KV heads: the pool's rows are hkv * d lanes wide and query
     # head g reads KV head g // (h // hkv) where it lies
     hkv = kp.shape[3] // d
-    if window is not None:
-        # a window of W positions before a cursor touches at most this
-        # many blocks: the grid is the window's, not the table's
-        n_blocks = min(n_blocks, (max(window, 2) - 2) // block_size + 2)
 
     # block-diagonal query (see the section comment): (S, q_len, h, hkv*d)
     # with row g's own d lanes under its KV head and exact zeros elsewhere
@@ -1542,23 +1592,11 @@ def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
                      jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None, :],
                      jnp.zeros((), q.dtype)).reshape(S, q_len, h, hkv * d)
 
-    def q_map(s, j, lay, tabs, lens):
-        return (s, 0, 0, 0)
+    def q_map(w, lay, tabs, lens, slot, blk, n):
+        return (slot[w], 0, 0, 0)
 
-    def kv_map(s, j, lay, tabs, lens):
-        # clamp past-the-cursor steps to the slot's LAST valid block:
-        # equal consecutive indices elide the fetch, which is what
-        # bounds HBM traffic to the actual context. An empty slot
-        # (length 0) clamps to table entry 0 — the allocator's null
-        # block — and its compute is fully masked. Under a window the
-        # walk starts at the first block the cursor's row still reads:
-        # the blocks before it are never fetched.
-        nb_valid = jnp.maximum(
-            (lens[s] + block_size - 1) // block_size, 1)
-        if window is not None:
-            j = jnp.maximum(lens[s] - window + 1, 0) // block_size + j
-        jj = jnp.minimum(j, nb_valid - 1)
-        return (lay[0], tabs[s, jj], 0, 0)
+    def kv_map(w, lay, tabs, lens, slot, blk, n):
+        return (lay[0], tabs[slot[w], blk[w]], 0, 0)
 
     kv_spec = pl.BlockSpec((1, 1, block_size, hkv * d), kv_map)
     in_specs = [pl.BlockSpec((1, q_len, h, hkv * d), q_map), kv_spec,
@@ -1571,20 +1609,20 @@ def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
 
     def kernel(*refs):
         refs = list(refs)
-        layer_ref, tab_ref, len_ref, q_ref, k_ref, v_ref = refs[:6]
-        nxt = 6
+        prefetch, (q_ref, k_ref, v_ref) = refs[:6], refs[6:9]
+        nxt = 9
         ksc_ref = refs[nxt] if has_scale else None
         vsc_ref = refs[nxt + 1] if has_scale else None
         nxt += 2 * has_scale
         o_ref, lse_ref, acc, m, l = refs[nxt:]
-        _paged_decode_kernel(layer_ref, tab_ref, len_ref, q_ref, k_ref,
-                             v_ref, ksc_ref, vsc_ref, o_ref, lse_ref, acc,
-                             m, l, scale=scale, block_size=block_size,
-                             n_blocks=n_blocks, q_len=q_len, window=window)
+        _paged_decode_kernel(*prefetch, q_ref, k_ref, v_ref, ksc_ref,
+                             vsc_ref, o_ref, lse_ref, acc, m, l,
+                             scale=scale, block_size=block_size,
+                             q_len=q_len, window=window)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, n_blocks),
+        num_scalar_prefetch=6,
+        grid=(work.n_live,),
         in_specs=in_specs,
         out_specs=(pl.BlockSpec((1, q_len, h, hkv * d), q_map),
                    pl.BlockSpec((1, q_len, h, 1), q_map)),
@@ -1597,19 +1635,25 @@ def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, *,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((S, q_len, h, hkv * d), out_dtype),
                    jax.ShapeDtypeStruct((S, q_len, h, 1), jnp.float32)),
-        cost_estimate=_paged_cost(S, h, d, kp.dtype, has_scale, n_blocks,
-                                  block_size, mean_context, q_len=q_len,
-                                  q_itemsize=q.dtype.itemsize, hkv=hkv),
+        cost_estimate=_paged_cost(
+            S, h, d, kp.dtype, has_scale,
+            _walk_blocks(tables.shape[1], block_size, window), block_size,
+            mean_context, q_len=q_len, q_itemsize=q.dtype.itemsize,
+            hkv=hkv),
         interpret=_interp(),
         name="paged_decode_attention",
-    )(jnp.reshape(layer, (1,)), tables, lengths, *args)
+    )(jnp.reshape(layer, (1,)), tables, lengths, work.slot, work.block,
+      jnp.reshape(work.n_live, (1,)), *args)
     # keep each head's own d lanes of its (h*d)-wide row: a select, so a
-    # cross-head product is dropped before anything could be summed
-    out = jnp.sum(jnp.where(eye[None, None, :, :, None],
-                            out.reshape(S, q_len, h, hkv, d),
+    # cross-head product is dropped before anything could be summed; a
+    # slot the walk never visited has no tile written, and reads as the
+    # empty prefix it is
+    seen = (work.count > 0)[:, None, None]
+    keep = eye[None, None, :, :, None] & seen[..., None, None]
+    out = jnp.sum(jnp.where(keep, out.reshape(S, q_len, h, hkv, d),
                             jnp.zeros((), out_dtype)), axis=3)
-    return (jnp.transpose(out, (0, 2, 1, 3)),
-            jnp.transpose(lse[..., 0], (0, 2, 1)))
+    lse = jnp.where(seen, lse[..., 0], -jnp.inf)
+    return (jnp.transpose(out, (0, 2, 1, 3)), jnp.transpose(lse, (0, 2, 1)))
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
@@ -1619,7 +1663,8 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                            mean_context: Optional[float] = None,
                            use_pallas: Optional[bool] = None,
                            k_cast=None, v_cast=None,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None,
+                           work: Optional[PagedWork] = None):
     """Single-query attention over a PAGED KV cache (see the section
     comment above) — the v2 serving decode kernel.
 
@@ -1628,15 +1673,15 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
     head ``g // (h // h_kv)`` (``k_new``/``v_new`` are then ``(b, h_kv,
     d)``). ``window``: the row at cursor ``c`` reads cached positions
     ``p`` with ``c - p < window`` (its own position counts as one of the
-    window's); the kernel's grid and fetches cover the window's blocks
+    window's); the kernel's walk and fetches cover the window's blocks
     only, so table entries left of it may be null.
 
     Speculative verify: pass ``q`` as ``(b, h, q_len, d)`` (with rank-4
     ``k_new``/``v_new`` and optional ``k_cast``/``v_cast`` store+load
-    images) to score q_len in-flight tokens per slot against ONE bounded
-    fetch of the cached blocks — the block-table walk and its clamp are
-    q_len-independent, so the per-token HBM cost drops ~q_len× at full
-    acceptance. Returns ``(b, h, q_len, d)``.
+    images) to score q_len in-flight tokens per slot against ONE walk of
+    the cached blocks — the walk is q_len-independent, so the per-token
+    HBM cost drops ~q_len× at full acceptance. Returns ``(b, h, q_len,
+    d)``.
 
     Args:
       q: ``(b, h, d)`` — one query row per sequence slot — or
@@ -1650,9 +1695,9 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
         int) — which layer's blocks to read.
       block_tables: ``(b, n_blocks_per_slot)`` int32 — pool indices of
         each slot's logical blocks, in order. Entries past
-        ``ceil(length/block_size)`` are never read (the index map clamps
-        before them); unmapped entries should name the allocator's null
-        block (0).
+        ``ceil(length/block_size)`` are never read (the walk ends before
+        them); unmapped entries should name the allocator's null block
+        (0).
       lengths: ``(b,)`` int32 per-slot cursor — valid cache positions
         (the current token is NOT in the cache; pass it via ``k_new``).
       k_new, v_new: optional ``(b, h, d)`` current token, folded in with
@@ -1663,6 +1708,9 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
       mean_context: expected ACTUAL context per slot (tokens), used only
         to size the kernel's ``CostEstimate`` for the pyprof roofline —
         never changes the math. Default: the worst-case table span.
+      work: :func:`paged_work_list` of these ``lengths``, this table's
+        width and this ``window``; a caller with a layer scan builds it
+        once, outside. Default: built here.
 
     Returns ``(b, h, d)`` in ``q.dtype``.
 
@@ -1705,6 +1753,9 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
     layer = jnp.asarray(layer, jnp.int32)
     block_tables = jnp.asarray(block_tables).astype(jnp.int32)
     lengths = jnp.asarray(lengths).astype(jnp.int32)
+    if use_pallas and work is None:
+        work = paged_work_list(lengths, block_size, block_tables.shape[1],
+                               window)
 
     with jax.named_scope("decode_attention"):
         if use_pallas:
@@ -1712,7 +1763,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                 q if multi else q[:, :, None, :], k_pool, v_pool, layer,
                 block_tables, lengths,
                 k_scale if quantized else None,
-                v_scale if quantized else None,
+                v_scale if quantized else None, work,
                 scale=float(softmax_scale), mean_context=mean_context,
                 window=window)
             if not multi:

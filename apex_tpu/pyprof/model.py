@@ -437,8 +437,8 @@ def model_program(program, args: Optional[tuple] = None, *,
                 if est is not None:
                     # the kernel author's own CostEstimate beats the
                     # body x grid heuristic — it can price data-bounded
-                    # grids (e.g. paged decode, whose index maps clamp
-                    # past-cursor steps so real traffic is O(actual
+                    # grids (e.g. paged decode, whose grid bound is the
+                    # count of live blocks, so real traffic is O(actual
                     # context), which body x grid cannot see)
                     region = bucket(_region_of(stack, regions))
                     region.flops += mult * float(

@@ -662,6 +662,17 @@ class BlockAllocator:
     def blocks_in_use(self) -> int:
         return self.num_blocks - 1 - self.free_blocks
 
+    def walk_blocks(self) -> Tuple[int, int]:
+        """``(live, table)``: the blocks that hold cached positions some
+        slot's next row reads (what the paged kernel walks, ``ops.
+        flash_attention.paged_work_list``), and ``max_seqs x
+        blocks_per_slot``, the table's capacity."""
+        cur = self.lengths.astype(np.int64)
+        first = 0 if self.window is None else \
+            np.maximum(cur - self.window + 1, 0) // self.block_size
+        return (int(np.sum(-(-cur // self.block_size) - first)),
+                self.max_seqs * self.blocks_per_slot)
+
     # -- low-level block lifecycle ------------------------------------------
 
     def _evict_one(self) -> int:
@@ -1076,6 +1087,11 @@ class KindBlockAllocator:
     @property
     def blocks_in_use(self) -> Dict[str, int]:
         return {kind: a.blocks_in_use for kind, a in self.kinds.items()}
+
+    def walk_blocks(self) -> Tuple[int, int]:
+        """:meth:`BlockAllocator.walk_blocks`, summed over the kinds."""
+        walks = [a.walk_blocks() for a in self.kinds.values()]
+        return sum(w[0] for w in walks), sum(w[1] for w in walks)
 
     def window_blocks(self) -> Tuple[int, int]:
         """``(given, returned)``: blocks ever mapped by the window kinds

@@ -648,7 +648,9 @@ class ServingEngine:
             active = np.ones(self.max_seqs, np.bool_)
         active = np.asarray(active, bool)
         self._refuse_poison_unless_quarantine(poison)
-        with span("engine.decode", active=int(np.count_nonzero(active))):
+        live_blocks, table_blocks = self.allocator.walk_blocks()
+        with span("engine.decode", active=int(np.count_nonzero(active)),
+                  live_blocks=live_blocks, table_blocks=table_blocks):
             with span("decode.plan"):
                 step = self.allocator.prepare_step(
                     list(np.flatnonzero(active)))

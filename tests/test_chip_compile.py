@@ -343,6 +343,33 @@ def test_paged_step_updates_the_pool_in_place_on_v5e(kind, weights,
         assert _weight_sized_results(text, _weight_sizes()) == []
 
 
+def test_the_decode_step_builds_the_walk_once_outside_the_layer_loop(
+        one_chip, for_tpu):
+    """The paged kernel's work list is every layer's: its ops (scope
+    ``paged_work_list``) stand in the step's ENTRY computation, which runs
+    once a step, or in fusions called from there, and nowhere else; the
+    kernel is called from another computation, the layer loop's body."""
+    fn, args, donated, _, _ = _paged_step("decode", "image", one_chip)
+    text = jax.jit(fn, donate_argnums=(donated,)).lower(*args) \
+        .compile().as_text()
+    lines_of, entry, current = {}, None, None
+    for line in text.splitlines():
+        if line.rstrip().endswith("{") and "=" not in line.split("(")[0]:
+            current = line.split("(")[0].replace("ENTRY", "").strip(" %")
+            if line.startswith("ENTRY"):
+                entry = current
+        lines_of.setdefault(current, []).append(line)
+    loop, = [c for c, ls in lines_of.items()
+             if any("paged_decode_attention" in line and "custom-call(" in line
+                    for line in ls)]
+    assert loop != entry
+    walk = {c for c, ls in lines_of.items()
+            if any("/paged_work_list/" in line for line in ls)}
+    fused = {name for line in lines_of[entry]
+             for name in _CALLEE.findall(line)}
+    assert entry in walk and walk <= {entry} | fused, walk - fused
+
+
 # ---------------------------------------------------------------------------
 # the pattern decoder's programs at command-a-plus-05-2026's published widths
 # ---------------------------------------------------------------------------

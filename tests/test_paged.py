@@ -6,6 +6,9 @@ vs the one-shot forward, prefix-shared stream identity, zero-recompile
 across admit/COW/retire, pool-exhaustion admission control — and over
 the default one, sized so that every slot can reach ``max_len``."""
 
+import importlib
+import os
+
 import numpy as np
 
 import jax
@@ -22,6 +25,9 @@ from apex_tpu.serving import (BlockAllocator, PagedKVCache,
                               paged_block_bytes)
 
 from _program_text import program_text
+
+# the package re-exports the FUNCTION under the module's name
+fa = importlib.import_module("apex_tpu.ops.flash_attention")
 
 
 def _quantize_ref(x):
@@ -284,6 +290,126 @@ class TestPagedDecodeKernelLargeWidths:
 # ---------------------------------------------------------------------------
 # PagedKVCache pool writes
 # ---------------------------------------------------------------------------
+
+# ---------------------------------------------------------------------------
+# the walk of the live blocks (PR 36): the kernel's grid is the work list
+# ---------------------------------------------------------------------------
+
+WALK_BS, WALK_NBS, WALK_D, WALK_LAYER = 8, 4, 8, 1   # a slot spans 32
+# name: (query heads, KV heads, q_len, window, int8, cursors)
+WALK_CASES = {
+    # 0, 1, block_size, block_size + 1, the table's full span, mid-block
+    "ragged": (2, 2, 1, None, False, [0, 1, 8, 9, 32, 20]),
+    "all_empty": (2, 2, 1, None, False, [0, 0, 0, 0]),
+    "one_live_of_32": (2, 2, 1, None, False, [0] * 17 + [19] + [0] * 14),
+    "all_full": (2, 2, 1, None, False, [32, 32, 32]),
+    # a cursor inside the window, at its edge, and past it by one and more
+    "window": (2, 2, 1, 12, False, [0, 5, 11, 12, 13, 30, 32]),
+    # a window of one position reads nothing cached: never visited
+    "window_of_one": (2, 2, 1, 1, False, [0, 8, 9, 32]),
+    "grouped_kv": (4, 1, 1, None, False, [0, 7, 16, 31]),
+    "grouped_kv_window": (4, 1, 1, 10, False, [3, 10, 25, 0]),
+    "verify_q5": (2, 2, 5, None, False, [0, 1, 9, 27]),
+    "int8": (2, 2, 1, None, True, [0, 3, 8, 30]),
+}
+PARENT_OUTPUTS = os.path.join(os.path.dirname(__file__), "data",
+                              "paged_parent_outputs.npz")
+
+
+def walk_inputs(name):
+    """Seeded float32 inputs of one case, as ``_paged_decode_pallas``
+    takes them: ``q (S, h, q_len, d)``, the stacked pools, a scattered
+    table, the cursors, the int8 scales or None twice."""
+    h, hkv, q_len, _, int8, lengths = WALK_CASES[name]
+    S = len(lengths)
+    rng = np.random.RandomState(sorted(WALK_CASES).index(name))
+    nb = S * WALK_NBS + 1
+    tables = rng.permutation(np.arange(1, nb)).reshape(S, WALK_NBS)
+    pool = lambda: rng.randn(2, nb, WALK_BS, hkv * WALK_D).astype(
+        np.float32)
+    q = rng.randn(S, h, q_len, WALK_D).astype(np.float32)
+    kp, vp, ksc, vsc = pool(), pool(), None, None
+    if int8:
+        def quantize(x):
+            x = x.reshape(2, nb, WALK_BS, hkv, WALK_D)
+            xq, scale = _quantize_ref(x)       # scale (L, nb, bs, h)
+            return (xq.reshape(2, nb, WALK_BS, hkv * WALK_D),
+                    scale.transpose(0, 1, 3, 2))
+        (kp, ksc), (vp, vsc) = quantize(kp), quantize(vp)
+    return tuple(None if x is None else jnp.asarray(x) for x in (
+        q, kp, vp, tables.astype(np.int32),
+        np.asarray(lengths, np.int32), ksc, vsc))
+
+
+def walk_items(lengths, window):
+    """``paged_work_list`` as a loop: the blocks the parent's grid
+    computed on (``j * block_size < length``, from the window's first)."""
+    return [(s, j) for s, n in enumerate(lengths)
+            for j in range(0 if window is None else
+                           max(n - window + 1, 0) // WALK_BS, WALK_NBS)
+            if j * WALK_BS < n]
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_the_kernel_walks_the_live_blocks_only(case):
+    """The work list is the loop's items; on them the kernel's ``out`` and
+    ``lse`` are the PARENT's (the ``max_seqs x blocks a slot`` grid of
+    before PR 36; ``tests/data/paged_parent_outputs.npz`` holds what its
+    ``_paged_decode_pallas`` returned for ``walk_inputs(case)``, layer 1,
+    scale ``d ** -0.5``: the arithmetic is unchanged, so the two are
+    bit-equal on one machine, and a float32 rounding apart where another
+    CPU's matrix product sums in another order); a slot with no item is
+    never visited and reads 0 / -inf, which merges to ``v_new``; and the
+    whole call agrees with the gathered XLA reference."""
+    h, hkv, q_len, window, int8, lengths = WALK_CASES[case]
+    q, kp, vp, tables, cursors, ksc, vsc = walk_inputs(case)
+    S = len(lengths)
+
+    work = fa.paged_work_list(cursors, WALK_BS, WALK_NBS, window)
+    items = walk_items(lengths, window)
+    n = int(work.n_live)
+    assert n == len(items) == int(work.count.sum())
+    assert list(zip(work.slot[:n].tolist(), work.block[:n].tolist())) \
+        == items
+    assert work.slot.shape == work.block.shape and not work.slot[n:].any()
+    visited = np.asarray(work.count) > 0
+    assert visited.tolist() == [any(s == i for s, _ in items)
+                                for i in range(S)]
+
+    out, lse = fa._paged_decode_pallas(
+        q, kp, vp, jnp.int32(WALK_LAYER), tables, cursors, ksc, vsc, work,
+        scale=WALK_D ** -0.5, mean_context=None, window=window)
+    with np.load(PARENT_OUTPUTS) as parent:
+        np.testing.assert_allclose(np.asarray(out)[visited],
+                                   parent[case + ".out"][visited],
+                                   rtol=2e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(lse)[visited],
+                                   parent[case + ".lse"][visited],
+                                   rtol=2e-6, atol=1e-6)
+    assert not np.asarray(out)[~visited].any()
+    assert np.all(np.asarray(lse)[~visited] == -np.inf)
+
+    rng = np.random.RandomState(99)
+    new = lambda: jnp.asarray(rng.randn(S, hkv, q_len, WALK_D), jnp.float32)
+    k_new, v_new = new(), new()
+    if q_len == 1:
+        q, k_new, v_new = q[:, :, 0], k_new[:, :, 0], v_new[:, :, 0]
+    call = lambda use_pallas: paged_decode_attention(
+        q, kp, vp, WALK_LAYER, tables, cursors, k_new=k_new, v_new=v_new,
+        k_scale=ksc, v_scale=vsc, use_pallas=use_pallas, window=window)
+    got = np.asarray(call(True))
+    np.testing.assert_allclose(got, np.asarray(call(False)), rtol=2e-5,
+                               atol=2e-6)
+    # nothing cached to read: the token attends to itself alone (a verify
+    # row also reads the drafts before it, so row 0 only)
+    alone = np.repeat(np.asarray(v_new), h // hkv, axis=1)[~visited]
+    if q_len == 1:
+        np.testing.assert_allclose(got[~visited], alone, rtol=1e-6)
+    else:
+        np.testing.assert_allclose(got[~visited][:, :, 0], alone[:, :, 0],
+                                   rtol=1e-6)
+    assert np.all(np.isfinite(got))
+
 
 def _append_all(pool, k_new, v_new, block_ids, offsets, method="append"):
     """Every layer's write, one layer at a time (how the layer scan of

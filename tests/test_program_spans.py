@@ -169,6 +169,10 @@ def test_identifiers_arrive_as_stats_of_the_events(profiled):
     assert all("slot" in st for st in stats("engine.prefill"))
     assert all("slot" in st for st in stats("engine.release"))
     assert all(st["active"] in (1, 2) for st in stats("engine.decode"))
+    # the paged kernel's walk beside the table it used to walk: two slots
+    # of 24 / 4 blocks, contexts of 3 to 9 positions
+    assert all(st["table_blocks"] == 12 and 1 <= st["live_blocks"] <= 5
+               for st in stats("engine.decode"))
     steps = [st["step"] for st in stats("sched.step")]
     assert steps == sorted(steps) and steps[0] == 0
 
