@@ -8,18 +8,22 @@ short period of different blocks repeated (``layer_types`` or
 
 - the MIXER is grouped-query attention (with or without rotary positions
   and a window), a Mamba-2 state-space mixer (:mod:`apex_tpu.ops.mamba2`),
-  or none;
+  a Mamba-1 selective-scan mixer (:mod:`apex_tpu.ops.mamba1`), or none;
 - the FEED-FORWARD is the dropless top-k expert layer
-  (:class:`~apex_tpu.transformer.expert_parallel.HeldExpertsMLP`) or none;
+  (:class:`~apex_tpu.transformer.expert_parallel.HeldExpertsMLP`), a dense
+  gated MLP (two matrices up, one down: no router, no gather, nothing
+  counted), or none;
 - the CACHE is a pool of KV blocks a window hands back, a pool that keeps
   them, a fixed-size per-slot state, or none.
 
-A layer is ``x' = x + sum of its sub-blocks over n(x)``: with ``block =
-"parallel"`` (Cohere) a kind's mixer and feed-forward sit side by side
-behind one norm; with ``block = "prenorm"`` (Nemotron-H) a layer is ONE
-sub-block, a mixer or a feed-forward, under a pre-norm residual. ``norm`` is
-a bias-free LayerNorm or an RMS norm; the head is the tied embedding or
-its own matrix. Two families are written as such patterns:
+``block`` says how a layer's sub-blocks meet the residual. With
+``"parallel"`` (Cohere) a kind's mixer and feed-forward sit side by side
+behind one norm, ``x' = x + mixer(n(x)) + ff(n(x))``; with ``"prenorm"``
+(Nemotron-H) a layer is ONE sub-block, a mixer or a feed-forward, under a
+pre-norm residual; with ``"sequential"`` (Jamba) a layer is TWO, each
+behind its own norm: ``x = x + mixer(n1(x)); x' = x + ff(n2(x))``. ``norm``
+is a bias-free LayerNorm or an RMS norm; the head is the tied embedding or
+its own matrix. Three families are written as such patterns:
 
 - Cohere2 sparse: three ``sliding_attention`` layers (rotary, a window)
   then one ``full_attention`` layer (no positional term), each beside a
@@ -27,7 +31,10 @@ its own matrix. Two families are written as such patterns:
 - Nemotron-H: ``mamba`` (``M``), ``moe`` (``E``: squared-ReLU experts in a
   latent, a selection bias, a shared expert of its own width) and
   ``attention`` (``*``: no positional term at all, the Mamba layers carry
-  order) layers, pre-norm residual, RMS norm, untied head.
+  order) layers, pre-norm residual, RMS norm, untied head;
+- Jamba: ``mamba_mlp`` (a Mamba-1 mixer with its own RMS norms on ``dt``,
+  ``B`` and ``C``) and ``attention_mlp`` (no positional term) layers, each
+  followed by a dense gated-SiLU MLP, sequential blocks, RMS norm, tied head.
 
 The layers of one kind are stacked; runs of one kind are scanned, the
 period is repeated, and each kind that holds something keeps it in its own
@@ -35,7 +42,7 @@ place of the engine's cache (:class:`~apex_tpu.serving.cache.
 KindPagedKVCache`): a pool and a block table a block kind, because a window
 layer gives back the blocks that fall out of its window and a full layer
 never does; one row a slot a state kind (the conv's tail and the SSM
-state), written by the prefill for its slot and advanced in place by the
+state, in the layout the mixer's kernels read), written by the prefill for its slot and advanced in place by the
 decode step.
 
 The model answers the calls ``ServingEngine`` makes of ``GPTModel``
@@ -63,6 +70,7 @@ import jax.numpy as jnp
 from apex_tpu.ops.flash_attention import (flash_attention,
                                           paged_decode_attention,
                                           paged_work_list)
+from apex_tpu.ops.mamba1 import mamba1_decode_update, mamba1_selective_scan
 from apex_tpu.ops.mamba2 import (causal_conv, causal_conv_update,
                                  mamba2_chunk_scan, mamba2_decode_update)
 from apex_tpu.transformer.expert_parallel import HeldExpertsMLP
@@ -75,8 +83,8 @@ __all__ = ["LayerKind", "LAYER_KINDS", "PatternDecoderConfig",
 class LayerKind:
     """What a ``layer_types`` entry stands for (module docstring)."""
 
-    mixer: Optional[str]          # "attention" | "mamba2" | None
-    feed_forward: bool            # the expert layer, or none
+    mixer: Optional[str]          # "attention" | "mamba2" | "mamba1" | None
+    feed_forward: Optional[str]   # "experts" | "dense" | None
     cache: Optional[str]          # "blocks" | "window_blocks" | "state" | None
     rotary: bool = False          # attention: rotary positions
 
@@ -86,13 +94,16 @@ class LayerKind:
 
 
 LAYER_KINDS: Dict[str, LayerKind] = {
-    "sliding_attention": LayerKind("attention", True, "window_blocks",
+    "sliding_attention": LayerKind("attention", "experts", "window_blocks",
                                    rotary=True),
-    "full_attention": LayerKind("attention", True, "blocks"),
+    "full_attention": LayerKind("attention", "experts", "blocks"),
     # the names nemotron_h's hybrid_override_pattern letters map to
-    "mamba": LayerKind("mamba2", False, "state"),           # M
-    "moe": LayerKind(None, True, None),                     # E
-    "attention": LayerKind("attention", False, "blocks"),   # *
+    "mamba": LayerKind("mamba2", None, "state"),            # M
+    "moe": LayerKind(None, "experts", None),                # E
+    "attention": LayerKind("attention", None, "blocks"),    # *
+    # jamba's two layers, each a mixer THEN a dense MLP
+    "mamba_mlp": LayerKind("mamba1", "dense", "state"),
+    "attention_mlp": LayerKind("attention", "dense", "blocks"),
 }
 
 
@@ -104,12 +115,13 @@ class PatternDecoderConfig:
     num_attention_heads: int
     num_key_value_heads: int
     head_dim: int
-    expert_size: int                      # every routed (riding shared) expert
-    num_experts: int                      # the router's width
-    num_experts_per_tok: int
-    held_experts: Tuple[int, ...]
-    num_shared_experts: int
-    sliding_window: int                   # positions, the query's own counted
+    # the expert layer's sizes (a model with no "experts" kind leaves them)
+    expert_size: int = 0                  # every routed (riding shared) expert
+    num_experts: int = 0                  # the router's width
+    num_experts_per_tok: int = 0
+    held_experts: Tuple[int, ...] = ()
+    num_shared_experts: int = 0
+    sliding_window: int = 0               # positions, the query's own counted
     rope_theta: float = 10000.0
     layer_norm_eps: float = 1e-5
     logit_scale: float = 1.0
@@ -120,7 +132,8 @@ class PatternDecoderConfig:
     use_flash: Optional[bool] = None      # None: the kernels' own gates
     use_grouped_experts: bool = True      # False: the experts x tokens oracle
     axis_name: Optional[str] = None
-    block: str = "parallel"               # | "prenorm": one sub-block a layer
+    block: str = "parallel"               # | "prenorm": one sub-block a
+    #                                       layer | "sequential": two
     norm: str = "layernorm"               # | "rmsnorm"
     tie_embeddings: bool = True           # False: the head is ``lm_head``
     # the expert layer's form (HeldExpertsMLP)
@@ -136,6 +149,11 @@ class PatternDecoderConfig:
     mamba_state: int = 128
     mamba_conv: int = 4
     mamba_chunk: int = 128
+    # the Mamba-1 mixer (it reads mamba_state, mamba_conv, mamba_chunk too)
+    mamba1_inner: int = 0                 # channels: expand * hidden
+    mamba1_dt_rank: int = 0
+    # the dense gated MLP
+    intermediate_size: int = 0
 
     def __post_init__(self):
         unknown = set(self.layer_types) - set(LAYER_KINDS)
@@ -148,15 +166,28 @@ class PatternDecoderConfig:
                 f"{self.num_key_value_heads} KV heads")
         if self.head_dim % 2:
             raise ValueError("rotary pairs need an even head_dim")
-        if self.block not in ("parallel", "prenorm") \
+        if self.block not in ("parallel", "prenorm", "sequential") \
                 or self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"block {self.block!r} / norm {self.norm!r}")
         for kind in set(self.layer_types):
             k = LAYER_KINDS[kind]
             if self.block == "prenorm" and (k.mixer is None) \
-                    != k.feed_forward:
+                    != (k.feed_forward is not None):
                 raise ValueError(
                     f"a prenorm layer is one sub-block; {kind!r} is {k}")
+            if self.block == "sequential" and (
+                    k.mixer is None or k.feed_forward is None):
+                raise ValueError(
+                    f"a sequential layer is a mixer then a feed-forward; "
+                    f"{kind!r} is {k}")
+            if k.feed_forward == "experts" and self.num_experts < 1:
+                raise ValueError(f"{kind!r} needs the expert layer's sizes")
+            if k.feed_forward == "dense" and self.intermediate_size < 1:
+                raise ValueError(f"{kind!r} needs intermediate_size")
+            if k.mixer == "mamba1" and (self.mamba1_inner < 1
+                                        or self.mamba1_dt_rank < 1):
+                raise ValueError(
+                    f"{kind!r} needs mamba1_inner and mamba1_dt_rank")
             if k.mixer == "mamba2" and (
                     self.mamba_heads < 1
                     or self.mamba_heads % self.mamba_groups):
@@ -220,7 +251,12 @@ class PatternDecoderConfig:
         out = {}
         for kind in self.kinds:
             holds = LAYER_KINDS[kind].cache
-            if holds == "state":
+            if holds == "state" and LAYER_KINDS[kind].mixer == "mamba1":
+                out[kind] = StateSpec(
+                    self.layers_of(kind), self.mamba1_inner, self.mamba_conv,
+                    1, self.mamba1_inner, self.mamba_state,
+                    layout="channels_last")
+            elif holds == "state":
                 out[kind] = StateSpec(
                     self.layers_of(kind), self.mamba_conv_channels,
                     self.mamba_conv, self.mamba_heads, self.mamba_head_dim,
@@ -246,18 +282,21 @@ def rotary_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def _rms(x, gain, eps):
+    """``x * rsqrt(mean(x^2) + eps) * gain`` over the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                             + eps) * gain.astype(jnp.float32)
+
+
 class PatternDecoder:
     """See the module docstring."""
 
-    #: the decode and prefill programs return ``stats`` beside the logits
-    #: and the cache: per layer that has an expert layer, in the order the
-    #: layers run, the assignments that landed on each held expert and the
-    #: tokens with no held pick — ``(expert layers, len(held_experts) + 1)``
-    #: int32
-    step_stats = True
-
     def __init__(self, config: PatternDecoderConfig):
         self.cfg = cfg = config
+        #: the expert layer, or None for a model whose configuration
+        #: describes none (``num_experts`` 0: nothing is built for it, no
+        #: router, no stacked weights)
         self.experts = HeldExpertsMLP(
             cfg.hidden_size, cfg.expert_size, cfg.num_experts,
             cfg.num_experts_per_tok, cfg.held_experts,
@@ -266,12 +305,23 @@ class PatternDecoder:
             use_pallas=cfg.use_grouped_experts,
             activation=cfg.expert_activation, select_bias=cfg.router_bias,
             scaling=cfg.routed_scaling, latent_size=cfg.latent_size,
-            shared_size=cfg.shared_expert_size)
+            shared_size=cfg.shared_expert_size) if cfg.num_experts else None
+
+    @property
+    def step_stats(self) -> bool:
+        """Whether the decode and prefill programs' ``stats`` hold anything
+        (the engine then packs them behind the sampled tokens): per layer
+        that has an EXPERT layer, in the order the layers run, the
+        assignments that landed on each held expert and the tokens with no
+        held pick — ``(expert layers, len(held_experts) + 1)`` int32. A
+        model with none has no rows and its token fetch carries nothing."""
+        return self.stats_shape[0] > 0
 
     @property
     def stats_shape(self) -> Tuple[int, int]:
         cfg = self.cfg
-        return (sum(LAYER_KINDS[k].feed_forward for k in cfg.layer_types),
+        return (sum(LAYER_KINDS[k].feed_forward == "experts"
+                    for k in cfg.layer_types),
                 len(cfg.held_experts) + 1)
 
     @property
@@ -289,6 +339,13 @@ class PatternDecoder:
         H, d = cfg.hidden_size, cfg.head_dim
         q, kv = cfg.num_attention_heads * d, cfg.num_key_value_heads * d
         inner, nh = cfg.mamba_inner, cfg.mamba_heads
+        E, R, N = cfg.mamba1_inner, cfg.mamba1_dt_rank, cfg.mamba_state
+        F = cfg.intermediate_size
+        feed_forwards = {
+            "experts": lambda: self.experts.param_shapes(),
+            "dense": lambda: {"mlp_gate": (H, F), "mlp_up": (H, F),
+                              "mlp_down": (F, H)},
+            None: dict}
         mixers = {
             "attention": {"wq": (H, q), "wk": (H, kv), "wv": (H, kv),
                           "wo": (q, H)},
@@ -297,13 +354,20 @@ class PatternDecoder:
                        "conv_b": (cfg.mamba_conv_channels,),
                        "dt_bias": (nh,), "A_log": (nh,), "D": (nh,),
                        "gate_norm": (inner,), "out_proj": (inner, H)},
+            # A_log is LANE-MAJOR, (state, channels), as the state is held
+            "mamba1": {"in_proj": (H, 2 * E), "conv_w": (E, cfg.mamba_conv),
+                       "conv_b": (E,), "x_proj": (E, R + 2 * N),
+                       "dt_norm": (R,), "b_norm": (N,), "c_norm": (N,),
+                       "dt_proj": (R, E), "dt_bias": (E,), "A_log": (N, E),
+                       "D": (E,), "out_proj": (E, H)},
             None: {}}
         layers = {}
         for kind in cfg.kinds:
             k = LAYER_KINDS[kind]
             per_layer = dict({"norm": (H,)}, **mixers[k.mixer])
-            if k.feed_forward:
-                per_layer.update(self.experts.param_shapes())
+            if cfg.block == "sequential":
+                per_layer["ff_norm"] = (H,)
+            per_layer.update(feed_forwards[k.feed_forward]())
             layers[kind] = {name: (cfg.layers_of(kind),) + shape
                             for name, shape in per_layer.items()}
         out = {"embedding": (cfg.vocab_size, H), "final_norm": (H,),
@@ -327,9 +391,10 @@ class PatternDecoder:
             k = jax.random.fold_in(key, i)
             x = cfg.init_std * jax.random.normal(k, shape, jnp.float32)
             if name in ("wo", "w_down", "out_proj", "shared_down",
-                        "w_latent_up"):
+                        "w_latent_up", "mlp_down"):
                 x = x / (2.0 * cfg.num_layers) ** 0.5
-            if name in ("norm", "final_norm", "gate_norm", "D"):
+            if name in ("norm", "final_norm", "gate_norm", "D", "ff_norm",
+                        "dt_norm", "b_norm", "c_norm"):
                 x = 1.0 + x
             if name == "conv_w":
                 x = 0.5 * jax.random.normal(k, shape, jnp.float32)
@@ -344,11 +409,9 @@ class PatternDecoder:
     # -- pieces -------------------------------------------------------------
 
     def _norm(self, gain, x):
-        x = x.astype(jnp.float32)
         if self.cfg.norm == "rmsnorm":
-            return x * jax.lax.rsqrt(
-                jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-                + self.cfg.layer_norm_eps) * gain.astype(jnp.float32)
+            return _rms(x, gain, self.cfg.layer_norm_eps)
+        x = x.astype(jnp.float32)
         mean = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
         return (x - mean) * jax.lax.rsqrt(var + self.cfg.layer_norm_eps) \
@@ -374,19 +437,39 @@ class PatternDecoder:
             part = jax.lax.psum(part, self.cfg.axis_name)
         return part
 
+    def _dense_mlp(self, lp, h):
+        """``(silu(h Wgate) * (h Wup)) Wdown``: float32 ``(T, hidden)``,
+        this chip's part."""
+        cfg = self.cfg
+        dot = lambda a, w: jnp.dot(a, w, preferred_element_type=jnp.float32)
+        mid = jax.nn.silu(dot(h, lp["mlp_gate"])) * dot(h, lp["mlp_up"])
+        return dot(mid.astype(cfg.compute_dtype), lp["mlp_down"])
+
     def _finish(self, kind, lp, big, li, x, h32, mixed, valid):
-        """``x + mixer + experts``, whichever of the two the kind has:
+        """``x + mixer + feed-forward``, whichever of the two the kind has:
         ``mixed`` is the mixer's part before its output projection joined
         the chips' (float32, or None); the experts read layer ``li`` of the
-        kind's stacked weights."""
+        kind's stacked weights. In a sequential block the feed-forward
+        reads its own norm of ``x + mixer``, else the layer's one norm
+        ``h32``."""
         cfg = self.cfg
         acc = x.astype(jnp.float32)
         if mixed is not None:
             acc = acc + self._sum_parts(mixed)
-        if not LAYER_KINDS[kind].feed_forward:
-            width = len(cfg.held_experts) + 1
-            return acc.astype(cfg.compute_dtype), \
-                jnp.zeros((0, width), jnp.int32)
+        feed_forward = LAYER_KINDS[kind].feed_forward
+        no_stats = jnp.zeros((0, len(cfg.held_experts) + 1), jnp.int32)
+        if feed_forward is None:
+            return acc.astype(cfg.compute_dtype), no_stats
+        if cfg.block == "sequential":
+            # the residual is rounded to the compute dtype between the two
+            # sub-blocks, as between two layers
+            acc = acc.astype(cfg.compute_dtype)
+            h32 = self._norm(lp["ff_norm"], acc)
+            acc = acc.astype(jnp.float32)
+        if feed_forward == "dense":
+            out = self._sum_parts(
+                self._dense_mlp(lp, h32.astype(cfg.compute_dtype)))
+            return (acc + out).astype(cfg.compute_dtype), no_stats
         small = {n: v for n, v in lp.items()
                  if n in self.experts.param_shapes()}
         moe, stats = self.experts(
@@ -464,7 +547,7 @@ class PatternDecoder:
         rows stay as they were."""
         held = cache[kind]
         z, xbc, dt, A = self._mamba_in(lp, h)
-        tail = held.conv[li]
+        tail = held.tails(li)
         xbc, moved = causal_conv_update(tail, xbc, lp["conv_w"],
                                         lp["conv_b"])
         x, B, C = self._mamba_split(xbc)
@@ -472,6 +555,71 @@ class PatternDecoder:
         moved = jnp.where(valid[:, None, None], moved, tail)
         cache = dict(cache, **{kind: held.write_layer(li, moved, state)})
         return self._mamba_out(lp, y, x, z), cache
+
+    # -- the Mamba-1 mixer ----------------------------------------------------
+
+    def _mamba1_in(self, lp, h):
+        """``[u0 | z] = h Win``: what passes the conv in the compute dtype,
+        the gate ``z`` float32."""
+        proj = jnp.dot(h, lp["in_proj"], preferred_element_type=jnp.float32)
+        u0, z = jnp.split(proj, 2, axis=-1)
+        return u0.astype(self.cfg.compute_dtype), z
+
+    def _mamba1_ssm_in(self, lp, u):
+        """What the recurrence reads of the conv's output ``u`` ``(T, E)``:
+        ``[dt | B | C] = u Wx``, each under Jamba's own RMS norm, ``delta =
+        softplus(dt Wdt + b)``, and ``A = -exp(A_log)`` ``(N, E)``. All
+        float32."""
+        cfg = self.cfg
+        R, N = cfg.mamba1_dt_rank, cfg.mamba_state
+        proj = jnp.dot(u, lp["x_proj"], preferred_element_type=jnp.float32)
+        dt, B, C = jnp.split(proj, (R, R + N), axis=-1)
+        rms = lambda x, g: _rms(x, g, cfg.layer_norm_eps)
+        dt = rms(dt, lp["dt_norm"]).astype(cfg.compute_dtype)
+        delta = jax.nn.softplus(
+            jnp.dot(dt, lp["dt_proj"], preferred_element_type=jnp.float32)
+            + lp["dt_bias"].astype(jnp.float32))
+        return delta, rms(B, lp["b_norm"]), rms(C, lp["c_norm"]), \
+            -jnp.exp(lp["A_log"].astype(jnp.float32))
+
+    def _mamba1_out(self, lp, y, u, z):
+        """The skip ``D u``, the gate, the output projection: float32 ``(T,
+        hidden)``, this chip's part."""
+        y = (y + lp["D"].astype(jnp.float32) * u.astype(jnp.float32)) \
+            * jax.nn.silu(z)
+        return jnp.dot(y.astype(self.cfg.compute_dtype), lp["out_proj"],
+                       preferred_element_type=jnp.float32)
+
+    def _mamba1_prefill(self, kind, lp, li, h, cache, prompt_len, slot):
+        """The mixer over one prompt: the selective scan from a zero state;
+        with a cache the conv's tail and the state of the LAST REAL token
+        overwrite the slot's rows."""
+        cfg = self.cfg
+        u0, z = self._mamba1_in(lp, h)
+        u, tail = causal_conv(u0, lp["conv_w"], lp["conv_b"], prompt_len)
+        delta, B, C, A = self._mamba1_ssm_in(lp, u)
+        y, state = mamba1_selective_scan(
+            u, delta, A, B, C, chunk=min(cfg.mamba_chunk, h.shape[0]),
+            length=prompt_len, use_pallas=cfg.use_flash)
+        if cache is not None:
+            cache = dict(cache, **{kind: cache[kind].write_slot(
+                li, slot, tail, state)})
+        return self._mamba1_out(lp, y, u, z), cache
+
+    def _mamba1_decode(self, kind, lp, li, h, cache, valid):
+        """One token a slot, as :meth:`_mamba_decode`: the layer's rows of
+        the stacked state ``(L, S, N, E)`` moved on where they lie."""
+        held = cache[kind]
+        u0, z = self._mamba1_in(lp, h)
+        tail = held.tails(li)
+        u, moved = causal_conv_update(tail, u0, lp["conv_w"], lp["conv_b"])
+        delta, B, C, A = self._mamba1_ssm_in(lp, u)
+        y, ssm = mamba1_decode_update(held.ssm, li, u, delta, A, B, C, valid,
+                                      use_pallas=self.cfg.use_flash)
+        moved = jnp.where(valid[:, None, None], moved, tail)
+        cache = dict(cache, **{kind: dataclasses.replace(
+            held.write_tails(li, moved), ssm=ssm)})
+        return self._mamba1_out(lp, y, u, z), cache
 
     # -- a layer --------------------------------------------------------------
 
@@ -502,6 +650,9 @@ class PatternDecoder:
         elif mixer == "mamba2":
             mixed, cache = self._mamba_prefill(kind, lp, li, h, cache,
                                                prompt_len, slot)
+        elif mixer == "mamba1":
+            mixed, cache = self._mamba1_prefill(kind, lp, li, h, cache,
+                                                prompt_len, slot)
         x, stats = self._finish(kind, lp, big, li, x, h32, mixed, valid)
         return x, cache, stats
 
@@ -531,6 +682,8 @@ class PatternDecoder:
                             lp["wo"], preferred_element_type=jnp.float32)
         elif mixer == "mamba2":
             mixed, cache = self._mamba_decode(kind, lp, li, h, cache, valid)
+        elif mixer == "mamba1":
+            mixed, cache = self._mamba1_decode(kind, lp, li, h, cache, valid)
         x, stats = self._finish(kind, lp, big, li, x, h32, mixed, valid)
         return x, cache, stats
 
@@ -543,7 +696,7 @@ class PatternDecoder:
         cfg = self.cfg
         n_periods = cfg.num_layers // len(cfg.period)
         in_period = {k: cfg.period.count(k) for k in cfg.kinds}
-        stacked = self.experts.stacked
+        stacked = self.experts.stacked if self.experts is not None else ()
         small = {kind: {n: v for n, v in lp.items() if n not in stacked}
                  for kind, lp in params["layers"].items()}
         big = {kind: {n: lp[n] for n in stacked if n in lp}

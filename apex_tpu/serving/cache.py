@@ -33,11 +33,14 @@ back the moment the cursor has left it for good, while a full layer's
 table keeps every block until ``release``. One cursor a slot, a table a
 kind.
 
-**A state kind** (PR 35): a recurrent layer (Mamba-2) keeps no blocks: what
-a slot needs of the past is a FIXED-SIZE state whatever its context, the
-conv's last inputs and the SSM state, one row a slot a layer
+**A state kind** (PR 35): a recurrent layer (Mamba-2, Mamba-1) keeps no
+blocks: what a slot needs of the past is a FIXED-SIZE state whatever its
+context, the conv's last inputs and the SSM state, one row a slot a layer
 (:class:`SlotStateCache`, described by a :class:`StateSpec` among the
-model's ``cache_kinds``). It lives in the same :class:`KindPagedKVCache`
+model's ``cache_kinds``). The state's shape is the MODEL's
+(``StateSpec.layout``): Mamba-2's ``(heads, head_dim, 128)`` ends in its
+state size, Mamba-1's state of 16 is held channels last, ``(16,
+channels)``, so that neither pads its lanes. It lives in the same :class:`KindPagedKVCache`
 beside the block pools, donated and updated in place like them; the
 allocator gives it no blocks and no table (the slot IS its address), the
 prefill program is told its slot and overwrites the slot's rows (a slot
@@ -383,8 +386,15 @@ class StateSpec(NamedTuple):
     """What a state kind holds (a model's ``cache_kinds`` entry, in the
     place of a block kind's ``(layers, window)``): ``layers`` of the kind,
     each keeping a slot the conv's last ``conv_taps - 1`` inputs over
-    ``conv_channels`` channels and an SSM state ``(heads, head_dim,
-    state_size)`` float32."""
+    ``conv_channels`` channels and an SSM state of ``heads * head_dim``
+    channels by ``state_size`` float32. ``layout`` says which of the two is
+    the state's LAST axis, the one that fills the lanes:
+
+    - ``"state_last"``: ``(heads, head_dim, state_size)`` a slot (Mamba-2:
+      a state of 128 is a whole tile's lanes);
+    - ``"channels_last"``: ``(state_size, heads * head_dim)`` a slot
+      (Mamba-1: a state of 16 last would pad 16 lanes to 128, eight times
+      the bytes)."""
 
     layers: int
     conv_channels: int
@@ -392,34 +402,62 @@ class StateSpec(NamedTuple):
     heads: int
     head_dim: int
     state_size: int
+    layout: str = "state_last"
+
+    @property
+    def slot_shape(self) -> Tuple[int, ...]:
+        """One slot's SSM state at one layer."""
+        if self.layout == "channels_last":
+            return (self.state_size, self.heads * self.head_dim)
+        if self.layout != "state_last":
+            raise ValueError(f"state layout {self.layout!r}")
+        return (self.heads, self.head_dim, self.state_size)
 
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class SlotStateCache:
     """The per-slot recurrent state of one layer kind (module docstring, "A
-    state kind"). ``conv`` is time-major, ``(taps - 1, channels)`` a slot,
-    so that its last axis fills the lanes (``(channels, 3)`` would pad 3
-    lanes to 128)."""
+    state kind"), in the layout its :class:`StateSpec` names. Both leaves
+    end in an axis that fills the lanes, and neither pads its sublanes:
 
-    conv: jnp.ndarray          # (L, slots, taps - 1, channels)
-    ssm: jnp.ndarray           # (L, slots, heads, head_dim, state) float32
+    - ``"state_last"``: ``conv`` ``(L, slots, taps - 1, channels)``
+      (time-major a slot: ``(channels, 3)`` would pad 3 lanes to 128),
+      ``ssm`` ``(L, slots, heads, head_dim, state)``;
+    - ``"channels_last"``: ``conv`` ``(L, taps - 1, slots, channels)`` (the
+      slots second to last: at 5,120 channels and 128 slots a ``(3,
+      channels)`` tile a slot pads 3 rows to a tile's 16 and the compiler
+      relays the whole array on its way into and out of every step),
+      ``ssm`` ``(L, slots, state, channels)``.
+
+    The model reads and writes a layer's tails slot-major through
+    :meth:`tails` and :meth:`write_layer` whichever way they lie."""
+
+    conv: jnp.ndarray
+    ssm: jnp.ndarray           # (L, slots, *spec.slot_shape) float32
+    layout: str = "state_last"
 
     def tree_flatten(self):
-        return (self.conv, self.ssm), None
+        return (self.conv, self.ssm), self.layout
 
     @classmethod
-    def tree_unflatten(cls, _, leaves):
-        return cls(*leaves)
+    def tree_unflatten(cls, layout, leaves):
+        return cls(*leaves, layout=layout)
 
     @classmethod
     def create(cls, spec: StateSpec, max_seqs: int,
                dtype=jnp.bfloat16) -> "SlotStateCache":
-        return cls(
-            jnp.zeros((spec.layers, max_seqs, spec.conv_taps - 1,
-                       spec.conv_channels), dtype),
-            jnp.zeros((spec.layers, max_seqs, spec.heads, spec.head_dim,
-                       spec.state_size), jnp.float32))
+        conv = (spec.layers, max_seqs, spec.conv_taps - 1,
+                spec.conv_channels)
+        if spec.layout == "channels_last":
+            conv = (conv[0], conv[2], conv[1], conv[3])
+        return cls(jnp.zeros(conv, dtype),
+                   jnp.zeros((spec.layers, max_seqs) + spec.slot_shape,
+                             jnp.float32), layout=spec.layout)
+
+    @property
+    def _slots_first(self) -> bool:
+        return self.layout != "channels_last"
 
     def nbytes(self) -> int:
         return sum(leaf.size * leaf.dtype.itemsize
@@ -427,22 +465,49 @@ class SlotStateCache:
 
     @property
     def bytes_per_slot(self) -> int:
-        return self.nbytes() // self.conv.shape[1]
+        return self.nbytes() // self.ssm.shape[1]
+
+    def tails(self, layer) -> jnp.ndarray:
+        """The conv tails of every slot at ``layer``: ``(slots, taps - 1,
+        channels)``."""
+        rows = self.conv[layer]
+        return rows if self._slots_first else jnp.swapaxes(rows, 0, 1)
 
     def write_slot(self, layer, slot, conv_tail, state) -> "SlotStateCache":
         """A prefill's result for ``slot`` at ``layer`` (int32 scalars):
-        the rows are OVERWRITTEN, whatever the slot held."""
-        return SlotStateCache(
-            self.conv.at[layer, slot].set(conv_tail.astype(self.conv.dtype)),
-            self.ssm.at[layer, slot].set(state.astype(jnp.float32)))
+        the rows are OVERWRITTEN, whatever the slot held. ``conv_tail``
+        ``(taps - 1, channels)``."""
+        tail = conv_tail.astype(self.conv.dtype)
+        conv = self.conv
+        if self._slots_first:
+            conv = conv.at[layer, slot].set(tail)
+        else:
+            # a row a tap: ONE strided write of (taps - 1, channels) makes
+            # the compiler hold the whole array taps-second-to-last through
+            # the layer loop and relay it on the way in and out
+            for tap in range(tail.shape[0]):
+                conv = conv.at[layer, tap, slot].set(tail[tap])
+        return dataclasses.replace(
+            self, conv=conv,
+            ssm=self.ssm.at[layer, slot].set(state.astype(jnp.float32)))
+
+    def write_tails(self, layer, conv_tails) -> "SlotStateCache":
+        """A decode step's conv tails of every slot at ``layer``,
+        ``(slots, taps - 1, channels)`` (the caller keeps an idle slot's
+        rows as they were): one in-place slab."""
+        tails = conv_tails.astype(self.conv.dtype)
+        if not self._slots_first:
+            tails = jnp.swapaxes(tails, 0, 1)
+        return dataclasses.replace(self,
+                                   conv=self.conv.at[layer].set(tails))
 
     def write_layer(self, layer, conv_tails, states) -> "SlotStateCache":
-        """A decode step's rows of every slot at ``layer`` (the caller
-        keeps an idle slot's rows as they were): one in-place slab a
-        leaf."""
-        return SlotStateCache(
-            self.conv.at[layer].set(conv_tails.astype(self.conv.dtype)),
-            self.ssm.at[layer].set(states.astype(jnp.float32)))
+        """:meth:`write_tails` and the SSM rows of every slot at ``layer``,
+        one in-place slab a leaf (a mixer whose update writes the stacked
+        state itself replaces ``ssm`` instead)."""
+        return dataclasses.replace(
+            self.write_tails(layer, conv_tails),
+            ssm=self.ssm.at[layer].set(states.astype(jnp.float32)))
 
 
 @jax.tree_util.register_pytree_node_class

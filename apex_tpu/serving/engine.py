@@ -99,7 +99,8 @@ class ServingEngine:
     and what a ``step_stats`` model counts in a step comes back in the
     token fetch (:attr:`last_stats`). A kind that keeps a per-slot STATE
     in the place of blocks (a :class:`~apex_tpu.serving.cache.StateSpec`
-    among ``cache_kinds``: Mamba-2 layers) lives in the same cache, one
+    among ``cache_kinds``: Mamba-2 or Mamba-1 layers, each in the layout
+    its spec gives) lives in the same cache, one
     row a slot; the prefill programs are then told the slot they fill.
     Such a model is served without the prefix index and without
     speculation (docs/SERVING.md, Limits).
@@ -521,16 +522,21 @@ class ServingEngine:
         assumes a cold admission; a prefix hit needs fewer blocks)."""
         return self.allocator.can_admit(len(prompt))
 
-    def pad_prompt(self, prompt: Sequence[int]) -> np.ndarray:
-        """``prompt`` right-padded to the smallest bucket that holds it."""
-        if len(prompt) == 0:
+    def bucket_of(self, tokens: int) -> int:
+        """The smallest prefill bucket that holds a prompt of ``tokens``:
+        the rows its prefill program computes, padding included."""
+        if tokens == 0:
             raise ValueError("empty prompt")
-        if len(prompt) > self.prefill_len:
+        if tokens > self.prefill_len:
             raise ValueError(
-                f"prompt length {len(prompt)} exceeds the prefill window "
+                f"prompt length {tokens} exceeds the prefill window "
                 f"{self.prefill_len} (pick a larger prefill_len at "
                 "engine construction)")
-        bucket = next(b for b in self.prefill_buckets if b >= len(prompt))
+        return next(b for b in self.prefill_buckets if b >= tokens)
+
+    def pad_prompt(self, prompt: Sequence[int]) -> np.ndarray:
+        """``prompt`` right-padded to the smallest bucket that holds it."""
+        bucket = self.bucket_of(len(prompt))
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(prompt)] = np.asarray(prompt, np.int32)
         return padded

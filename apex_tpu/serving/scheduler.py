@@ -593,7 +593,11 @@ class SlotScheduler:
         self._reg.counter("serve/admitted").inc()
         self._reg.counter("serve/prefill_tokens").inc(len(req.prompt))
         plan = self.engine.last_admit
-        if not plan.prefill:
+        if plan.prefill:
+            # the rows the prefill program computed, padding included
+            self._reg.counter("serve/prefill_bucket_tokens").inc(
+                self.engine.bucket_of(len(req.prompt)))
+        else:
             # a prefix-shared admission: the shared span skipped
             # prefill entirely — serve/ttft_prefix_ms is the TTFT
             # histogram the acceptance bar compares against the

@@ -581,3 +581,120 @@ def test_the_scan_kernel_compiles_for_v5e_at_published_widths(one_chip,
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 1 and "mamba2_chunk_scan" in calls[0], calls
+
+
+# ---------------------------------------------------------------------------
+# jamba2-3b.chat-r80 (benchmark/workloads): the whole model, 28 layers (26
+# Mamba-1 of 5,120 channels with a state of 16, 2 attention of 20 query
+# heads on 1 KV head of 128), each followed by a dense MLP of 8,192; 128
+# slots of 1,280: a pool for the two attention layers, a state row a slot
+# ---------------------------------------------------------------------------
+
+JAMBA = dict(slots=128, max_len=1280, block=128,
+             blocks={"attention_mlp": 1281})
+mamba1 = importlib.import_module("apex_tpu.ops.mamba1")
+
+
+def _jamba_step(kind, sharding):
+    from apex_tpu.serving.cache import KindPagedKVCache
+    from benchmark import run as harness
+    from benchmark.families import jamba
+    c = JAMBA
+    model = jamba.model(harness.load_json(harness.HERE, "configs",
+                                          "jamba2-3b.json"))
+    cfg = model.cfg
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    params = described(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = described(jax.eval_shape(lambda: KindPagedKVCache.create(
+        cfg.cache_kinds, c["blocks"], 1, c["block"], 128,
+        max_seqs=c["slots"])))
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree_util.tree_leaves(params))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
+
+    by_kind = lambda x: {"attention_mlp": x}
+    S, per_slot = c["slots"], c["max_len"] // c["block"]
+    if kind == "decode":
+        def fn(params, cache, tokens, tables, lengths, ids, offs):
+            return model.forward(
+                params, tokens[:, None], kv_cache=cache,
+                block_tables=tables, lengths=lengths, append_block_ids=ids,
+                append_offsets=offs)
+        args = (params, cache, i32(S), by_kind(i32(S, per_slot)), i32(S),
+                by_kind(i32(S)), i32(S))
+    else:
+        bucket = int(kind[len("prefill"):])
+
+        def fn(params, cache, tokens, block_row, prompt_len, slot):
+            return model.forward(
+                params, tokens, kv_cache=cache, block_row=block_row,
+                prompt_len=prompt_len, last_logit_only=True, slot=slot)
+        args = (params, cache, i32(1, bucket),
+                by_kind(i32(bucket // c["block"])), i32(), i32())
+    return fn, args, cache, weight_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill128", "prefill256",
+                                  "prefill512", "prefill1024"])
+def test_jamba_programs_compile_for_v5e(kind, one_chip, for_tpu,
+                                        monkeypatch):
+    """The cell's decode program and its four prefill programs at published
+    widths: the selective-scan kernel and the grouped-KV kernels (20 query
+    heads on 1 KV head) pass Mosaic; the pool AND the per-slot state, (26,
+    128, 16, 5120) float32 with no padded lanes, are updated where they lie
+    (a decode step copies no whole state, 1.09 GB, and no whole conv tail:
+    the kernel ``mamba1_decode_update`` has the state aliased in and out);
+    no dense MLP's weights
+    are copied out of their stack; a prefill holds no (T, 16, 5120) array;
+    weights 6.06 GB + caches + temporaries fit the chip."""
+    monkeypatch.setattr(mamba1, "_interp", lambda: False)
+    fn, args, cache, weight_bytes = _jamba_step(kind, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    for kernel in (("paged_decode_attention", "mamba1_decode_update")
+                   if kind == "decode" else ("mamba1_selective_scan",)):
+        assert kernel in text, kernel
+    state = cache.pools["mamba_mlp"].ssm
+    assert state.shape == (26, 128, 16, 5120) and state.dtype == F32
+    assert cache.pools["mamba_mlp"].conv.shape == (26, 3, 128, 5120)
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    assert 1.35e9 < cache_bytes < 1.37e9, cache_bytes   # 1.09 + 0.10 + 0.17
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert weight_bytes == 2 * 3029337472
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert total < 15.5e9, (total, memory.temp_size_in_bytes)
+    # no copy of the state (1.09 GB), of the conv tails (0.10 GB) or of a
+    # kind's MLP stack (3.3 GB): a decode step's temporaries are its rows
+    limit = 0.05e9 if kind == "decode" else 0.6e9
+    assert memory.temp_size_in_bytes < limit, memory.temp_size_in_bytes
+    if kind != "decode":
+        entries = int(kind[len("prefill"):]) * 16 * 5120
+        assert not [line[:160] for _, _, count, line, _
+                    in _array_results(text)
+                    if count == entries and re.search(r"= f32\[[\d,]*\b16,",
+                                                      line)]
+
+
+def test_the_selective_scan_kernel_compiles_for_v5e_at_published_widths(
+        one_chip, for_tpu, monkeypatch):
+    monkeypatch.setattr(mamba1, "_interp", lambda: False)
+
+    def fn(u, delta, A, B, C, n):
+        return mamba1.mamba1_selective_scan(u, delta, A, B, C, chunk=128,
+                                            length=n, use_pallas=True)
+
+    shapes = [((1024, 5120), BF16), ((1024, 5120), F32), ((16, 5120), F32),
+              ((1024, 16), F32), ((1024, 16), F32), ((), I32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "mamba1_selective_scan" in calls[0], calls

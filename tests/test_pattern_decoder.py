@@ -447,3 +447,41 @@ def test_what_refuses_this_model_says_why():
         PagedServingEngine(model, w, prefix_suffix_cap=8, **common)
     with pytest.raises(ValueError, match="dict by layer kind"):
         PagedServingEngine(model, w, **dict(common, num_blocks=17))
+
+
+# -- what the three served configurations computed before Jamba came ---------
+
+@pytest.mark.parametrize("cell_name", ["tiny.chat", "tiny-cohere2-moe.rag",
+                                       "tiny-nemotron-h.reason"])
+def test_the_served_configurations_compute_what_they_did(cell_name):
+    """The three configurations the benchmark served before PR 38 (gpt2,
+    Cohere2 sparse, Nemotron-H), at their tiny cells' sizes and seed 11:
+    the engine's parameter names and shapes are what they were, and the
+    program's float32-compute logits over a fixed sequence are the
+    recorded ones of commit 7862800 (``tests/data/serve_parent_logits.npz``,
+    made there by the lines below), to float32 rounding: logits of order
+    2-12, sums in another order on another CPU."""
+    import dataclasses
+    import os
+
+    from benchmark import run as harness
+    here = os.path.dirname(os.path.abspath(__file__))
+    recorded = np.load(os.path.join(here, "data", "serve_parent_logits.npz"))
+    cell, config = harness.load_cell(
+        os.path.join(here, "benchmark", "cells"), cell_name)
+    family = harness.load_family(config)
+    engine = family.serve_engine(config, cell["engine"], 11)
+    names = sorted(
+        "/".join(str(getattr(k, "key", k)) for k in path) + " "
+        + "x".join(map(str, leaf.shape)) for path, leaf in
+        jax.tree_util.tree_flatten_with_path(engine.params_spec)[0])
+    assert names == list(recorded[cell_name + ":names"])
+    model = type(engine.model)(dataclasses.replace(
+        engine.model.cfg, compute_dtype=jnp.float32))
+    tokens = jnp.asarray(np.random.default_rng(3).integers(
+        1, family.vocab(config), 24))
+    logits = model(engine.params, tokens[None])[0] \
+        if config["family"] == "gpt2" else model(engine.params, tokens)
+    np.testing.assert_allclose(np.asarray(logits, np.float32)[-4:],
+                               recorded[cell_name + ":logits"],
+                               atol=2e-4, rtol=1e-5)
