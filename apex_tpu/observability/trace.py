@@ -5,7 +5,8 @@ viewers want *spans*. When span recording is enabled, every ``Timer.stop``
 pushes ``(name, t0, t1)`` here via a hook installed into
 ``apex_tpu.utils.timers`` (a plain module-global check — one ``is None``
 test per stop when disabled, and no import cycle: this module imports
-nothing from the rest of the library). The
+nothing from the rest of the library when it loads; :func:`timing_into`
+takes the latency grid from ``reqtrace`` when called). The
 :class:`~apex_tpu.observability.sinks.ChromeTraceSink` drains the buffer
 each report and writes the standard ``traceEvents`` JSON, which loads in
 ``chrome://tracing`` / Perfetto next to a ``jax.profiler.trace`` capture —
@@ -24,6 +25,15 @@ no clock read, no lock). Under :func:`span_recording` the same call also
 appends to the in-memory buffer, with the enclosing span as ``parent``:
 that view sees set-up (the profiler's window opens later) and needs no
 profiler. :data:`SPANS` is the one table of names.
+
+**Timed spans.** While a registry is attached to the thread
+(:func:`timing_into`, which ``SlotScheduler.step`` and ``submit`` hold
+for their duration), a span named in :data:`TIMED` also observes its
+length in ms into the histogram ``serve/span_ms/<name>`` of that
+registry: the same two ``perf_counter`` stamps feed the histogram and,
+under :func:`span_recording`, the buffer. With no registry attached a span
+costs one attribute read more than the annotation, so an engine driven
+outside a scheduler times nothing.
 """
 
 from __future__ import annotations
@@ -35,7 +45,8 @@ from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["Span", "SPANS", "SPAN_PREFIX", "span", "spans_enabled",
+__all__ = ["Span", "SPANS", "SPAN_PREFIX", "TIMED", "span",
+           "spans_enabled", "spans_seen", "timing_into", "SpanTiming",
            "enable_spans", "disable_spans", "record_span", "drain_spans",
            "span_recording", "chrome_trace_events", "epoch_offset",
            "trace_metadata", "merge_chrome_traces"]
@@ -68,9 +79,15 @@ SPANS: Dict[str, str] = {
                         "the device is done) [on an engine with several "
                         "prefill buckets: bucket, its positions; tokens, "
                         "the prompt's own, the rest padding]",
-    "prefill.wait": "int(tok): the host blocks until the device has the "
-                    "token",
     "prefill.index": "allocator.register_prefix",
+    "prefill.drain": "where a decode step is in flight: block until "
+                     "that step's tokens are computed (no transfer). The "
+                     "prefill program is queued behind it, so this is "
+                     "the prefill's wait for the step; on a prefix hit, "
+                     "before the tail's steps",
+    "prefill.wait": "int(tok): the host blocks until the device has the "
+                    "token: the prefill program and its fetch alone, "
+                    "after prefill.drain",
     # inside sched.step (and inside engine.prefill on a prefix hit)
     "engine.decode": "decode() of the engine: dispatch step n+1, read "
                      "step n [active: slots stepped; inflight: 1 when "
@@ -107,6 +124,13 @@ SPANS: Dict[str, str] = {
     "engine.lint": "lint_serving_engine: the donation/aliasing self-check",
 }
 
+# The spans whose lengths a registry attached by timing_into() keeps as
+# histograms, serve/span_ms/<name>, in ms: three observations a decode step
+# (sched.step, engine.decode, decode.wait) and three an admission
+# (sched.admit, engine.prefill, prefill.drain where a step was in flight).
+TIMED = ("sched.step", "sched.admit", "engine.prefill", "prefill.drain",
+         "engine.decode", "decode.wait")
+
 
 class Span(NamedTuple):
     name: str
@@ -124,35 +148,57 @@ _ENABLED = False
 _OPEN = threading.local()  # .stack: the recorded spans open on this thread
 
 
-class _RecordedSpan:
-    """span() under spans_enabled(): the annotation plus one buffer
-    entry, stamped on ``perf_counter`` like a Timer's."""
+class _Timing(threading.local):
+    # the histograms of the registry attached to this thread, by TIMED
+    # name; a class attribute, so that reading it unset costs no lookup
+    # failure
+    hists: Optional[dict] = None
 
-    __slots__ = ("name", "ids", "start", "parent", "annotation")
+    def __init__(self):
+        self.outer: list = []   # what each open timing_into() replaced
 
-    def __init__(self, name: str, ids: dict):
+
+_TIMING = _Timing()
+
+
+class _StampedSpan:
+    """span() under spans_enabled(), or of a TIMED name while a registry
+    is attached: the annotation plus two ``perf_counter`` stamps (a
+    Timer's clock), which feed the buffer (``record``) and the span's
+    histogram (``hist``, in ms)."""
+
+    __slots__ = ("name", "ids", "start", "parent", "annotation", "hist",
+                 "record")
+
+    def __init__(self, name: str, ids: dict, hist=None):
         if name not in SPANS:
             raise ValueError(
                 f"span {name!r} is not in apex_tpu.observability.trace"
                 ".SPANS: add it there with a line on what it covers")
-        self.name, self.ids = name, ids
+        self.name, self.ids, self.hist = name, ids, hist
+        self.record = _ENABLED
         self.annotation = TraceAnnotation(SPAN_PREFIX + name, **ids)
 
     def __enter__(self):
-        stack = getattr(_OPEN, "stack", None)
-        if stack is None:
-            stack = _OPEN.stack = []
-        self.parent = stack[-1] if stack else None
+        if self.record:
+            stack = getattr(_OPEN, "stack", None)
+            if stack is None:
+                stack = _OPEN.stack = []
+            self.parent = stack[-1] if stack else None
         self.annotation.__enter__()
         self.start = time.perf_counter()
-        stack.append((self.name, self.start))
+        if self.record:
+            stack.append((self.name, self.start))
         return self
 
     def __exit__(self, *exc):
         end = time.perf_counter()
-        _OPEN.stack.pop()
         self.annotation.__exit__(*exc)
-        record_span(self.name, self.start, end, self.parent, self.ids)
+        if self.hist is not None:
+            self.hist.observe((end - self.start) * 1e3)
+        if self.record:
+            _OPEN.stack.pop()
+            record_span(self.name, self.start, end, self.parent, self.ids)
         return False
 
 
@@ -162,13 +208,58 @@ def span(name: str, **ids):
     :data:`SPANS` (checked under :func:`spans_enabled`); ``ids`` are the
     identifiers that tie spans together (``request_id``, ``slot``,
     ``step``). See the module docstring for where the span lands."""
+    hists = _TIMING.hists
+    if hists is not None:
+        hist = hists.get(name)
+        if hist is not None:
+            return _StampedSpan(name, ids, hist)
     if _ENABLED:
-        return _RecordedSpan(name, ids)
+        return _StampedSpan(name, ids)
     return TraceAnnotation(SPAN_PREFIX + name, **ids)
+
+
+class SpanTiming:
+    """A registry's :data:`TIMED` histograms, attached to the calling
+    thread for the length of a ``with`` block (nested blocks restore what
+    they replaced). Built once by :func:`timing_into` and entered as often
+    as wanted."""
+
+    __slots__ = ("hists",)
+
+    def __init__(self, hists: Dict[str, object]):
+        self.hists = hists
+
+    def __enter__(self):
+        _TIMING.outer.append(_TIMING.hists)
+        _TIMING.hists = self.hists
+        return self
+
+    def __exit__(self, *exc):
+        _TIMING.hists = _TIMING.outer.pop()
+        return False
+
+
+def timing_into(registry) -> SpanTiming:
+    """The histograms ``serve/span_ms/<name>`` of ``registry`` (a
+    :class:`~apex_tpu.observability.registry.MetricsRegistry`), one a
+    :data:`TIMED` name, on the request latency grid, made now: ``with
+    timing_into(reg): ...`` times every TIMED span opened inside."""
+    from apex_tpu.observability.reqtrace import LATENCY_BUCKETS_MS
+    return SpanTiming({
+        name: registry.histogram(f"serve/span_ms/{name}",
+                                 LATENCY_BUCKETS_MS)
+        for name in TIMED})
 
 
 def spans_enabled() -> bool:
     return _ENABLED
+
+
+def spans_seen() -> bool:
+    """Whether a span's keywords go anywhere: under
+    :func:`span_recording`, or inside a profiler session that records
+    host events. Work done only to fill them can be skipped otherwise."""
+    return _ENABLED or TraceAnnotation.is_enabled()
 
 
 def record_span(name: str, start: float, end: float,

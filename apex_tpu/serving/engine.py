@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.observability.costs import memory_budget
-from apex_tpu.observability.trace import span
+from apex_tpu.observability.trace import span, spans_seen
 from apex_tpu.serving.cache import (NULL_BLOCK, _MIN_SCALE, PagedKVCache,
                                     BlockAllocator, KindPagedKVCache,
                                     KindBlockAllocator, AdmitPlan,
@@ -629,6 +629,7 @@ class ServingEngine:
                     # index the freshly written full blocks so LATER
                     # admissions can share them
                     self.allocator.register_prefix(slot, prompt)
+                self._drain()
                 with span("prefill.wait"):
                     return int(self._unpack(np.asarray(tok).reshape(-1),
                                             1)[0])
@@ -637,6 +638,7 @@ class ServingEngine:
             # recompiles), other slots frozen, each step read at once and
             # the step in flight left as it is; each is an engine.decode
             # span inside this one
+            self._drain()
             active = np.zeros(self.max_seqs, np.bool_)
             active[slot] = True
             tokens = np.zeros(self.max_seqs, np.int32)
@@ -648,6 +650,16 @@ class ServingEngine:
                 toks = self._decode(tokens, temps, active, None, hold=False)
                 tok = int(toks[slot])
             return tok
+
+    def _drain(self) -> None:
+        """Wait, where a decode step is in flight, until the device has
+        computed it (nothing is fetched: its tokens stay held for the next
+        :meth:`decode` or :meth:`settle`). A prefill's program is queued
+        behind that step, so this is the prefill's wait for it, timed
+        apart from the prefill's own."""
+        if self._held is not None:
+            with span("prefill.drain"):
+                jax.block_until_ready(self._held[0])
 
     # -- stepping -----------------------------------------------------------
 
@@ -700,10 +712,14 @@ class ServingEngine:
         active = np.asarray(active, bool)
         self._refuse_poison_unless_quarantine(poison)
         before = self._held if hold else None
-        live_blocks, table_blocks = self.allocator.walk_blocks()
+        # the walk only fills the span's keywords: skipped where no
+        # recording or profiler session takes them
+        walk = {}
+        if spans_seen():
+            walk["live_blocks"], walk["table_blocks"] = \
+                self.allocator.walk_blocks()
         with span("engine.decode", active=int(np.count_nonzero(active)),
-                  live_blocks=live_blocks, table_blocks=table_blocks,
-                  inflight=int(before is not None)):
+                  inflight=int(before is not None), **walk):
             with span("decode.plan"):
                 step = self.allocator.prepare_step(
                     list(np.flatnonzero(active)))

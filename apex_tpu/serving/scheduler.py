@@ -43,6 +43,17 @@ timestamps) for the Chrome-trace export; an
 retirement for goodput/burn-rate. Both default off and neither adds
 device work (asserted in ``tests/test_reqtrace.py``).
 
+**Phase timing.** :meth:`~SlotScheduler.step` and
+:meth:`~SlotScheduler.submit` attach the registry to the thread
+(:func:`~apex_tpu.observability.trace.timing_into`), so the spans of
+``trace.TIMED`` observe their lengths into the histograms
+``serve/span_ms/<name>``: ``sched.step``, ``engine.decode`` and
+``decode.wait`` once a decode step; ``sched.admit``, ``engine.prefill``
+and, where a decode step is in flight, ``prefill.drain`` (the prefill's
+wait for that step) once an admission. Sums over counts of these give
+the loop's phases without a profiler (docs/OBSERVABILITY.md "Program
+spans").
+
 **Resilience** (docs/SERVING.md "Resilience"; the policy objects live in
 :mod:`apex_tpu.serving.resilience`): ``max_queue=`` bounds admission —
 an over-limit ``submit`` returns a typed
@@ -83,7 +94,7 @@ import numpy as np
 from apex_tpu.observability import get_registry
 from apex_tpu.observability.reqtrace import (LATENCY_BUCKETS_MS,
                                              RequestRecord)
-from apex_tpu.observability.trace import span
+from apex_tpu.observability.trace import span, timing_into
 from apex_tpu.serving.cache import PoolExhausted
 from apex_tpu.serving.resilience import Rejection
 
@@ -297,6 +308,9 @@ class SlotScheduler:
         for name in ("serve/decode_steps_overlapped",
                      "serve/decode_rows_discarded"):
             self._reg.counter(name)
+        # the serve/span_ms/* histograms: step() and submit() attach them
+        # to the thread, so the loop's TIMED spans observe their lengths
+        self._timing = timing_into(self._reg)
 
     # -- submission ---------------------------------------------------------
 
@@ -308,8 +322,9 @@ class SlotScheduler:
         RAISES: a load condition is the server's problem, a bad request
         is the caller's."""
         # an id the caller left out is the next one _submit will give
-        with span("sched.submit", request_id=self._next_id
-                  if request.request_id is None else request.request_id):
+        with self._timing, span("sched.submit", request_id=self._next_id
+                                if request.request_id is None
+                                else request.request_id):
             return self._submit(request)
 
     def _submit(self, request: Request) -> Union[int, Rejection]:
@@ -651,7 +666,7 @@ class SlotScheduler:
         request ``finish_reason="error"`` (slots released, records
         stamped, completions visible) before re-raising — a dead decode
         never strands ``active`` state."""
-        with span("sched.step", step=self.steps):
+        with self._timing, span("sched.step", step=self.steps):
             if self._tok_t0 is None:
                 self._tok_t0 = time.perf_counter()
             before = self._tok_count

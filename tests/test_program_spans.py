@@ -237,10 +237,11 @@ def test_a_speculative_engine_over_its_default_pool_has_the_same_cut(
         sched.run([Request(prompt=[1, 2, 3, 1, 2, 3], max_new_tokens=5)])
         names = {s.name for s in trace.drain_spans()}
     # no plain decode step (no prefix was shared, so no tail went through
-    # one); no cast program (the weights' image is the tree)
+    # one), so none in flight for a prefill to drain; no cast program (the
+    # weights' image is the tree)
     assert names == set(trace.SPANS) - {
         "compile.image", "engine.decode", "decode.plan", "decode.dispatch",
-        "decode.advance", "decode.wait"}
+        "decode.advance", "decode.wait", "prefill.drain"}
 
 
 def test_an_engine_with_buckets_names_the_bucket_and_its_tokens(
