@@ -1400,19 +1400,31 @@ def _gathered_reference(q, k, v, lengths, k_new, v_new, k_scale, v_scale,
 #   tiles to ``slot[w]``, so a slot's consecutive items keep its tiles
 #   resident; the accumulators start on a slot's first item, the division
 #   and the write happen on its last. A slot with no item is never
-#   visited and its output tile never written: the diagonal select after
-#   the call gives it ``out`` 0 and ``lse`` -inf, the identity of the
-#   ``_merge_current`` fold;
+#   visited and its output never written: a select after the call gives
+#   it the empty prefix (``out`` 0 and ``lse`` -inf, the identity of the
+#   ``_merge_current`` fold, or where the kernel merges, ``v_new``);
 # - all heads of a block are scored at once and no lane is ever sliced:
-#   the query comes in BLOCK-DIAGONAL, ``(h, h * d)`` with head g's row
+#   the query tile is BLOCK-DIAGONAL, ``(h, h * d)`` with head g's row
 #   holding q[g] in lanes [g*d, (g+1)*d) and exact zeros elsewhere, so
 #   ``q_bd @ K^T`` is the ``(h, block_size)`` per-head score tile. The
 #   other heads' keys are multiplied by exact zeros, so each score — and
 #   with it m, l and the per-head ``lse`` — holds its own head's products
 #   only. ``p @ V`` is ``(h, h * d)``: row g carries head g's weights
-#   over EVERY head's values; the caller keeps the diagonal ``d``-wide
-#   pieces (a select, not a product: the cross-head pieces are masked
-#   before anything is summed);
+#   over EVERY head's values, of which only the diagonal ``d``-wide
+#   pieces are kept (a select, not a product: the cross-head pieces are
+#   masked before anything is summed);
+# - where a KV head is a query head and there is one row (the decode
+#   step of a model without grouped heads: ``hkv == h``, ``q_len == 1``)
+#   that tile lives in VMEM only: the query comes in as the QKV product
+#   gives it, one ``(1, h * d)`` row a slot, laid over h sublanes and
+#   masked to the diagonal in the kernel; on a slot's last item the
+#   kernel folds in the current token's ``k_new``/``v_new`` rows (the
+#   ``_merge_current`` fold, float32) and sums the kept diagonal over the
+#   sublanes into the finished ``(1, h * d)`` row, which is what the
+#   output projection reads. No ``(S, h, h * d)`` array reaches HBM.
+#   Grouped heads (``hkv < h``) and the verify window keep the query
+#   built block-diagonal before the call, ``(h, hkv * d)``, and the
+#   diagonal select and the merge after it;
 # - the online-softmax recurrence is ``_fwd_kernel``'s, one query row
 #   wide; an int8 pool is dequantized blockwise (the pooled
 #   per-(position, head) scales ride ``(layers, num_blocks, h,
@@ -1475,10 +1487,19 @@ def paged_work_list(lengths, block_size: int, n_blocks: int,
         return PagedWork(slot, block, count, ends[-1])
 
 
+def _diagonal(h, d):
+    """``(h, h * d)`` mask of the block diagonal: row g's lanes ``[g * d,
+    (g + 1) * d)``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (h, h * d), 1)
+    start = jax.lax.broadcasted_iota(jnp.int32, (h, h * d), 0) * d
+    return (lane >= start) & (lane < start + d)
+
+
 def _paged_decode_kernel(layer_ref, tab_ref, len_ref, slot_ref, blk_ref,
                          n_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref,
-                         o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale,
-                         block_size, q_len, window=None):
+                         kn_ref, vn_ref, o_ref, lse_ref, acc_ref, m_ref,
+                         l_ref, *, scale, block_size, q_len, window=None,
+                         head_dim=None):
     del layer_ref, tab_ref                  # the index maps' business
     w = pl.program_id(0)
     s, j = slot_ref[w], blk_ref[w]
@@ -1491,6 +1512,14 @@ def _paged_decode_kernel(layer_ref, tab_ref, len_ref, slot_ref, blk_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
+    _, h, hd = acc_ref.shape
+    row = head_dim is not None
+    if row:
+        # the row form: the (1, h*d) query row laid over h sublanes and
+        # cut to the block diagonal here, in VMEM
+        diag = _diagonal(h, head_dim)
+        q_bd = jnp.where(diag, jnp.broadcast_to(
+            q_ref[0].astype(jnp.float32), (h, hd)), 0.0)
     k = k_ref[0, 0].astype(jnp.float32)       # (block_size, h*d)
     v = v_ref[0, 0].astype(jnp.float32)
     pos = j * block_size + jax.lax.broadcasted_iota(
@@ -1503,7 +1532,7 @@ def _paged_decode_kernel(layer_ref, tab_ref, len_ref, slot_ref, blk_ref,
         # back to length + i - window + 1
         live = cached if window is None \
             else cached & (pos > length + i - window)
-        q = q_ref[0, i].astype(jnp.float32)   # (h, h*d)
+        q = q_bd if row else q_ref[0, i].astype(jnp.float32)  # (h, h*d)
         s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32
                                  ) * scale    # (h, block_size)
@@ -1531,21 +1560,46 @@ def _paged_decode_kernel(layer_ref, tab_ref, len_ref, slot_ref, blk_ref,
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         # -inf on empty rows: the identity of the _merge_current fold
-        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(l == 0.0, -jnp.inf,
-                               m_ref[:] + jnp.log(safe_l))
+        lse = jnp.where(l == 0.0, -jnp.inf, m_ref[:] + jnp.log(safe_l))
+        if lse_ref is not None:
+            lse_ref[0] = lse
+        if not row:
+            o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+            return
+        out = acc_ref[0] / safe_l[0]                    # (h, h*d)
+        if kn_ref is not None:
+            # _merge_current's fold of the current token, float32, over
+            # the prefix's context in the output's dtype, as the grouped
+            # form folds it after the call: both forms give the same
+            # numbers. The other heads' lanes of q_bd are zeros, so each
+            # score holds its own head's products only
+            out = out.astype(o_ref.dtype).astype(jnp.float32)
+            s_new = jnp.sum(q_bd * kn_ref[0].astype(jnp.float32), axis=1,
+                            keepdims=True) * scale      # (h, 1)
+            mx = jnp.maximum(lse[0], s_new)
+            a_old = jnp.exp(lse[0] - mx)
+            a_new = jnp.exp(s_new - mx)
+            out = (a_old * out + a_new * vn_ref[0].astype(jnp.float32)) \
+                / (a_old + a_new)
+        # each head's own d lanes, summed over the heads: one exact
+        # value a lane, the finished (1, h*d) row
+        o_ref[0] = jnp.sum(jnp.where(diag, out, 0.0), axis=0,
+                           keepdims=True).astype(o_ref.dtype)
 
 
 def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
-                mean_context, q_len=1, q_itemsize=2, hkv=None):
+                mean_context, q_len=1, q_itemsize=2, hkv=None, merged=False):
     """``pl.CostEstimate`` for one paged decode call: the HBM bytes at
     ``mean_context`` tokens of ACTUAL context per slot (the kernel walks
     the live blocks only), so the pyprof roofline prices what the kernel
     moves, not the worst-case table span. The
-    K/V fetches are dense ``(block_size, h * d)`` blocks; the query and
-    the output ride block-diagonal, ``h`` times their useful size, and
-    the FLOPs are those the MXU is issued for them (``h`` times the
-    algorithm's: the other heads' lanes are multiplied by zeros).
+    K/V fetches are dense ``(block_size, h * d)`` blocks. In the row form
+    (``hkv == h``, one row) the query and the output are ``h * d`` rows,
+    and ``merged`` adds the current token's K and V rows in place of the
+    lse; otherwise the query and the output ride block-diagonal, ``hkv``
+    times their useful size. The FLOPs are those the MXU is issued (``h``
+    times the algorithm's where the tile is block-diagonal: the other
+    heads' lanes are multiplied by zeros).
 
     ``q_len > 1`` is the speculative verify call: the MXU work and the
     q/out traffic scale by q_len, but the dominant KV stream does NOT —
@@ -1562,98 +1616,137 @@ def _paged_cost(s, h, d, kv_dtype, quantized, n_blocks_slot, block_size,
     kv_bytes = 2.0 * s * hkv * ctx * d * itemsize
     if quantized:
         kv_bytes += 2.0 * s * hkv * ctx * 4
-    io_bytes = (kv_bytes + 2.0 * s * q_len * h * hkv * d * q_itemsize
-                + s * q_len * h * 4 + (s * (n_blocks_slot + 1) + 1) * 4)
+    if hkv == h and q_len == 1:
+        rows = 4 if merged else 2
+        qo_bytes = rows * s * h * d * q_itemsize + (0 if merged else s * h * 4)
+    else:
+        qo_bytes = (2.0 * s * q_len * h * hkv * d * q_itemsize
+                    + s * q_len * h * 4)
+    io_bytes = kv_bytes + qo_bytes + (s * (n_blocks_slot + 1) + 1) * 4
     flops = 4.0 * s * q_len * h * ctx * hkv * d  # qk^T + pv, 2 MACs each
     return pl.CostEstimate(flops=int(flops), bytes_accessed=int(io_bytes),
                            transcendentals=int(s * h * ctx * q_len))
 
 
 def _paged_decode_pallas(q, kp, vp, layer, tables, lengths, ksc, vsc, work,
-                         *, scale, mean_context, window=None):
+                         *, scale, mean_context, window=None, k_new=None,
+                         v_new=None):
     # q is (S, h, q_len, d): q_len == 1 is the classic decode step,
     # q_len == k + 1 the speculative verify — ONE program shape for
     # both. Every block spans its array's last two dims whole — the
-    # (block_size, h*d) pool blocks, the (h, h*d) query/output tiles,
-    # the (h, block_size) scale tiles, the (h, 1) lse columns — which
-    # Mosaic accepts at any size; the walk is q_len-independent.
+    # (block_size, h*d) pool blocks, the (h, h*d) query/output tiles or
+    # (1, h*d) rows, the (h, block_size) scale tiles, the (h, 1) lse
+    # columns — which Mosaic accepts at any size; the walk is
+    # q_len-independent. Returns (out (S, h, q_len, d), lse (S, h,
+    # q_len)); where k_new/v_new (S, h, d) are given (the row form only)
+    # out is merged with them and lse is None.
     S, h, q_len, d = q.shape
     block_size = kp.shape[2]
     has_scale = ksc is not None
     # grouped KV heads: the pool's rows are hkv * d lanes wide and query
     # head g reads KV head g // (h // hkv) where it lies
     hkv = kp.shape[3] // d
-
-    # block-diagonal query (see the section comment): (S, q_len, h, hkv*d)
-    # with row g's own d lanes under its KV head and exact zeros elsewhere
-    eye = jnp.eye(h, dtype=jnp.bool_) if hkv == h else (
-        jnp.arange(h)[:, None] // (h // hkv) == jnp.arange(hkv)[None, :])
-    q_bd = jnp.where(eye[None, None, :, :, None],
-                     jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None, :],
-                     jnp.zeros((), q.dtype)).reshape(S, q_len, h, hkv * d)
+    # the row form (see the section comment): each head its own KV head
+    # and one row, so the query and the output are (1, h*d) rows in HBM
+    row = hkv == h and q_len == 1
+    merged = k_new is not None
 
     def q_map(w, lay, tabs, lens, slot, blk, n):
         return (slot[w], 0, 0, 0)
 
+    def row_map(w, lay, tabs, lens, slot, blk, n):
+        return (slot[w], 0, 0)
+
     def kv_map(w, lay, tabs, lens, slot, blk, n):
         return (lay[0], tabs[slot[w], blk[w]], 0, 0)
 
+    if row:
+        row_spec = pl.BlockSpec((1, 1, h * d), row_map)
+        q_in = q.reshape(S, 1, h * d)
+        q_spec = o_spec = row_spec
+    else:
+        # block-diagonal query: (S, q_len, h, hkv*d) with row g's own d
+        # lanes under its KV head and exact zeros elsewhere
+        eye = jnp.eye(h, dtype=jnp.bool_) if hkv == h else (
+            jnp.arange(h)[:, None] // (h // hkv) == jnp.arange(hkv)[None, :])
+        q_in = jnp.where(eye[None, None, :, :, None],
+                         jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None, :],
+                         jnp.zeros((), q.dtype)).reshape(S, q_len, h,
+                                                         hkv * d)
+        q_spec = o_spec = pl.BlockSpec((1, q_len, h, hkv * d), q_map)
     kv_spec = pl.BlockSpec((1, 1, block_size, hkv * d), kv_map)
-    in_specs = [pl.BlockSpec((1, q_len, h, hkv * d), q_map), kv_spec,
-                kv_spec]
-    args = [q_bd, kp, vp]
+    in_specs = [q_spec, kv_spec, kv_spec]
+    args = [q_in, kp, vp]
     if has_scale:
         sc_spec = pl.BlockSpec((1, 1, hkv, block_size), kv_map)
         in_specs += [sc_spec, sc_spec]
         args += [ksc, vsc]
+    if merged:
+        in_specs += [row_spec, row_spec]
+        args += [k_new.reshape(S, 1, h * d), v_new.reshape(S, 1, h * d)]
 
     def kernel(*refs):
         refs = list(refs)
         prefetch, (q_ref, k_ref, v_ref) = refs[:6], refs[6:9]
         nxt = 9
-        ksc_ref = refs[nxt] if has_scale else None
-        vsc_ref = refs[nxt + 1] if has_scale else None
+        ksc_ref, vsc_ref = refs[nxt:nxt + 2] if has_scale else (None, None)
         nxt += 2 * has_scale
-        o_ref, lse_ref, acc, m, l = refs[nxt:]
+        kn_ref, vn_ref = refs[nxt:nxt + 2] if merged else (None, None)
+        nxt += 2 * merged
+        o_ref = refs[nxt]
+        lse_ref = None if merged else refs[nxt + 1]
+        acc, m, l = refs[nxt + 2 - merged:]
         _paged_decode_kernel(*prefetch, q_ref, k_ref, v_ref, ksc_ref,
-                             vsc_ref, o_ref, lse_ref, acc, m, l,
-                             scale=scale, block_size=block_size,
-                             q_len=q_len, window=window)
+                             vsc_ref, kn_ref, vn_ref, o_ref, lse_ref, acc, m,
+                             l, scale=scale, block_size=block_size,
+                             q_len=q_len, window=window,
+                             head_dim=d if row else None)
 
+    out_dtype = q.dtype if q.dtype != jnp.int8 else jnp.float32
+    out_shape = [jax.ShapeDtypeStruct(
+        (S, 1, h * d) if row else (S, q_len, h, hkv * d), out_dtype)]
+    out_specs = [o_spec]
+    if not merged:
+        out_shape.append(jax.ShapeDtypeStruct((S, q_len, h, 1), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, q_len, h, 1), q_map))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(work.n_live,),
         in_specs=in_specs,
-        out_specs=(pl.BlockSpec((1, q_len, h, hkv * d), q_map),
-                   pl.BlockSpec((1, q_len, h, 1), q_map)),
+        out_specs=tuple(out_specs),
         scratch_shapes=[pltpu.VMEM((q_len, h, hkv * d), jnp.float32),
                         pltpu.VMEM((q_len, h, 1), jnp.float32),
                         pltpu.VMEM((q_len, h, 1), jnp.float32)])
-    out_dtype = q.dtype if q.dtype != jnp.int8 else jnp.float32
-    out, lse = pl.pallas_call(
+    res = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((S, q_len, h, hkv * d), out_dtype),
-                   jax.ShapeDtypeStruct((S, q_len, h, 1), jnp.float32)),
+        out_shape=tuple(out_shape),
         cost_estimate=_paged_cost(
             S, h, d, kp.dtype, has_scale,
             _walk_blocks(tables.shape[1], block_size, window), block_size,
             mean_context, q_len=q_len, q_itemsize=q.dtype.itemsize,
-            hkv=hkv),
+            hkv=hkv, merged=merged),
         interpret=_interp(),
         name="paged_decode_attention",
     )(jnp.reshape(layer, (1,)), tables, lengths, work.slot, work.block,
       jnp.reshape(work.n_live, (1,)), *args)
-    # keep each head's own d lanes of its (h*d)-wide row: a select, so a
-    # cross-head product is dropped before anything could be summed; a
-    # slot the walk never visited has no tile written, and reads as the
-    # empty prefix it is
+    out, lse = res if not merged else (res[0], None)
+    # a slot the walk never visited has no tile written, and reads as the
+    # empty prefix it is: out 0 and lse -inf, which merge to v_new
     seen = (work.count > 0)[:, None, None]
+    if lse is not None:
+        lse = jnp.transpose(jnp.where(seen, lse[..., 0], -jnp.inf),
+                            (0, 2, 1))
+    if row:
+        empty = jnp.zeros((), out_dtype) if not merged \
+            else v_new.reshape(S, 1, h * d).astype(out_dtype)
+        return jnp.where(seen, out, empty).reshape(S, h, 1, d), lse
+    # keep each head's own d lanes of its (h*d)-wide row: a select, so a
+    # cross-head product is dropped before anything could be summed
     keep = eye[None, None, :, :, None] & seen[..., None, None]
     out = jnp.sum(jnp.where(keep, out.reshape(S, q_len, h, hkv, d),
                             jnp.zeros((), out_dtype)), axis=3)
-    lse = jnp.where(seen, lse[..., 0], -jnp.inf)
-    return (jnp.transpose(out, (0, 2, 1, 3)), jnp.transpose(lse, (0, 2, 1)))
+    return jnp.transpose(out, (0, 2, 1, 3)), lse
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
@@ -1759,13 +1852,18 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
 
     with jax.named_scope("decode_attention"):
         if use_pallas:
+            # the row form merges the current token in the kernel
+            row = hkv == h and not multi
             out, lse = _paged_decode_pallas(
                 q if multi else q[:, :, None, :], k_pool, v_pool, layer,
                 block_tables, lengths,
                 k_scale if quantized else None,
                 v_scale if quantized else None, work,
                 scale=float(softmax_scale), mean_context=mean_context,
-                window=window)
+                window=window, k_new=k_new if row else None,
+                v_new=v_new if row else None)
+            if row:
+                return out[:, :, 0].astype(q.dtype)
             if not multi:
                 out, lse = out[:, :, 0], lse[:, :, 0]
             if k_new is not None and multi:

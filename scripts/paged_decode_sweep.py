@@ -6,23 +6,24 @@ One process, one chip. At the three serving configurations' shapes
 (gpt2-large: 32 slots x 20 heads x 64 over 8 blocks a slot; command-a's
 full and window kinds: 32 x 16 heads on 1 KV head of 128 over 68 blocks,
 window 4,096; Nemotron's attention layer: 64 x 8 on 1 x 128 over 32) it
-runs the kernel as a layer scan does, 36 calls a program with the walk
-built once outside, for 1, 6, 12 and all slots live at contexts of 1, 2
-and the full span of blocks, and reads the kernel's own device time from a
-profiler trace (the events named ``paged_decode_attention``; the XLA ops
-round it are left out). A least-squares line through a shape's loads gives
-the cost a call whatever the load and the cost a live block. ``--parent
-DIR`` times ``_paged_decode_pallas`` of another checkout's
-``apex_tpu/ops/flash_attention.py`` first (the ``max_seqs x blocks a slot``
-grid, before PR 36), for the before/after table of ``PERF.md``, and says
-load by load whether the two forms' summed outputs are the same bits. JSON
+runs ``paged_decode_attention`` as a decode layer calls it, the current
+token's K and V merged in, 36 calls a program with the walk built once
+outside, for 1, 6, 12 and all slots live at contexts of 1, 2 and the full
+span of blocks. From a profiler trace it reads the kernel's own device
+time (the events named ``paged_decode_attention``) and the whole call's
+(every leaf op of the program, the XLA ops round the kernel included, over
+the calls). A least-squares line through a shape's loads gives the cost a
+call whatever the load and the cost a live block, for the kernel and for
+the whole call. ``--parent DIR`` times another checkout's
+``apex_tpu/ops/flash_attention.py`` first, for the before/after table of
+``PERF.md``, and gives load by load the largest difference of the two
+forms' summed outputs. JSON
 lines on stdout, the table in ``chiprun_out/paged_decode_sweep.jsonl``.
 """
 import argparse
 import glob
 import importlib
 import importlib.util
-import inspect
 import json
 import os
 import sys
@@ -50,29 +51,27 @@ SHAPES = {
 
 def inputs(shape, seed=0):
     S, h, hkv, d, per_slot, _, held = SHAPES[shape]
-    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     pool = (2, S * held + 1, BLOCK, hkv * d)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     mk = lambda k, s: (0.5 * jax.random.normal(k, s)).astype(jnp.bfloat16)
     tables = 1 + np.arange(S)[:, None] * held + np.arange(per_slot) % held
-    return (mk(keys[0], (S, h, 1, d)), mk(keys[1], pool), mk(keys[2], pool),
-            jnp.asarray(tables, jnp.int32))
+    return (mk(keys[0], (S, h, d)), mk(keys[1], pool), mk(keys[2], pool),
+            jnp.asarray(tables, jnp.int32), mk(keys[3], (S, hkv, d)),
+            mk(keys[4], (S, hkv, d)))
 
 
 def layer_scan(mod, shape):
-    """The kernel as a decode step runs it: ``CALLS`` calls, the layer
-    index the scan's, what the calls share made once outside."""
+    """The attention as a decode step runs it: ``CALLS`` calls, the layer
+    index the scan's, the walk made once outside."""
     _, _, _, d, per_slot, window, _ = SHAPES[shape]
-    takes_work = "work" in inspect.signature(
-        mod._paged_decode_pallas).parameters
 
-    def program(q, kp, vp, tables, lengths):
-        rest = (None, None) + ((mod.paged_work_list(
-            lengths, BLOCK, per_slot, window),) if takes_work else ())
+    def program(q, kp, vp, tables, lengths, k_new, v_new):
+        work = mod.paged_work_list(lengths, BLOCK, per_slot, window)
 
         def one(acc, layer):
-            out, lse = mod._paged_decode_pallas(
-                q, kp, vp, layer % kp.shape[0], tables, lengths, *rest,
-                scale=d ** -0.5, mean_context=None, window=window)
+            out = mod.paged_decode_attention(
+                q, kp, vp, layer % kp.shape[0], tables, lengths,
+                k_new=k_new, v_new=v_new, window=window, work=work)
             return acc + out.astype(jnp.float32), None
         return jax.lax.scan(one, jnp.zeros(q.shape, jnp.float32),
                             jnp.arange(CALLS, dtype=jnp.int32))[0]
@@ -93,47 +92,55 @@ def loads(shape, quick):
                    live * (-(-context // BLOCK) - first))
 
 
-def kernel_us(trace_dir):
-    """Device microseconds of each ``paged_decode_attention`` event of the
-    newest trace under ``trace_dir``, in the order they ran."""
+def device_us(trace_dir):
+    """Of the newest trace under ``trace_dir``: device microseconds of each
+    ``paged_decode_attention`` event, in the order they ran, and the
+    summed microseconds of every leaf op."""
     path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                          recursive=True), key=os.path.getmtime)
-    ops = trace_reduce.device_ops(trace_reduce.load_xplane(path))
-    events = sorted((start, dur) for name, start, dur, _ in ops[0]
+    ops = trace_reduce.device_ops(trace_reduce.load_xplane(path))[0]
+    events = sorted((start, dur) for name, start, dur, _ in ops
                     if "paged_decode_attention" in name)
-    return [dur / 1e3 for _, dur in events]
+    return ([dur / 1e3 for _, dur in events],
+            sum(dur for _, _, dur, _ in trace_reduce.leaves(ops)) / 1e3)
+
+
+def fit(rows):
+    x, y = np.array(rows).T
+    per_block, fixed = np.polyfit(x, y, 1)
+    return round(float(fixed), 2), round(float(per_block), 3)
 
 
 def sweep(mod, form, shape, quick, emit):
-    program, (q, kp, vp, tables) = layer_scan(mod, shape), inputs(shape)
+    program = layer_scan(mod, shape)
+    q, kp, vp, tables, k_new, v_new = inputs(shape)
     points = list(loads(shape, quick))
-    jax.block_until_ready(program(q, kp, vp, tables,
-                                  jnp.asarray(points[0][2])))
-    sums = []
-    with tempfile.TemporaryDirectory() as trace_dir:
-        with jax.profiler.trace(trace_dir):
-            for _, _, lengths, _ in points:
+    run = lambda lengths: jax.block_until_ready(program(
+        q, kp, vp, tables, jnp.asarray(lengths), k_new, v_new))
+    run(points[0][2])
+    sums, kernel, whole = [], [], []
+    for _, _, lengths, _ in points:
+        # one trace a load: the whole call is every op the trace holds
+        with tempfile.TemporaryDirectory() as trace_dir:
+            with jax.profiler.trace(trace_dir):
                 for _ in range(REPEATS):
-                    out = jax.block_until_ready(
-                        program(q, kp, vp, tables, jnp.asarray(lengths)))
-                sums.append(np.asarray(out))
-        us = kernel_us(trace_dir)
-    per_point = CALLS * REPEATS
-    if len(us) != per_point * len(points):
-        raise RuntimeError(f"{len(us)} kernel events in the trace, "
-                           f"{per_point * len(points)} calls made")
-    rows = []
+                    out = run(lengths)
+            us, total = device_us(trace_dir)
+        sums.append(np.asarray(out))
+        if len(us) != CALLS * REPEATS:
+            raise RuntimeError(f"{len(us)} kernel events in the trace, "
+                               f"{CALLS * REPEATS} calls made")
+        kernel.append(float(np.median(us)))
+        whole.append(total / (CALLS * REPEATS))
     for i, (live, context, _, blocks) in enumerate(points):
-        mine = us[i * per_point:(i + 1) * per_point]
-        rows.append((blocks, float(np.median(mine))))
         emit(form=form, shape=shape, live_slots=live, context=context,
-             live_blocks=blocks, us_a_call=round(rows[-1][1], 2),
-             us_a_call_max=round(max(mine), 2))
-    x, y = np.array(rows).T
-    per_block, fixed = np.polyfit(x, y, 1)
-    emit(form=form, shape=shape, fit="us_a_call = fixed + per_block * "
-         "live_blocks", fixed_us=round(float(fixed), 2),
-         per_block_us=round(float(per_block), 3))
+             live_blocks=blocks, us_a_call=round(kernel[i], 2),
+             whole_us_a_call=round(whole[i], 2))
+    for name, ys in (("kernel", kernel), ("whole", whole)):
+        fixed, per_block = fit([(p[3], y) for p, y in zip(points, ys)])
+        emit(form=form, shape=shape, of=name,
+             fit="us_a_call = fixed + per_block * live_blocks",
+             fixed_us=fixed, per_block_us=per_block)
     return sums
 
 
@@ -168,9 +175,8 @@ def main():
             sums = [sweep(mod, form, shape, args.quick, emit)
                     for form, mod in forms]
             if args.parent:
-                # the arithmetic is the parent's: the same bits, load by load
-                emit(shape=shape, loads_equal_to_parent=[
-                    bool(np.array_equal(a, b)) for a, b in zip(*sums)])
+                emit(shape=shape, max_abs_diff_to_parent=[
+                    float(np.abs(a - b).max()) for a, b in zip(*sums)])
 
 
 if __name__ == "__main__":

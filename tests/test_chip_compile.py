@@ -370,6 +370,34 @@ def test_the_decode_step_builds_the_walk_once_outside_the_layer_loop(
     assert entry in walk and walk <= {entry} | fused, walk - fused
 
 
+def test_the_decode_step_keeps_the_query_and_the_context_as_rows(
+        one_chip, for_tpu):
+    """At gpt2-large's 20 query heads on 20 KV heads the paged kernel
+    takes the query as the QKV product gives it, one ``(1, h * d)`` row a
+    slot, builds the block-diagonal ``(h, h * d)`` tile in VMEM only, and
+    returns the finished context (the current token merged in) as a row.
+    So no instruction of the step has a result of ``S * h * h * d``
+    elements (32 x 20 x 1,280), neither the block-diagonal query nor the
+    kernel's output, outside a fusion's body (only a fusion's result
+    reaches memory). The kernel keeps the name that
+    ``paged_decode_roofline.serve`` finds it by."""
+    fn, args, donated, _, _ = _paged_step("decode", "image", one_chip)
+    text = jax.jit(fn, donate_argnums=(donated,)).lower(*args) \
+        .compile().as_text()
+    g = LARGE
+    tile = g["slots"] * g["heads"] * g["heads"] * g["head_dim"]
+    results = list(_array_results(text))
+    fused = {_CALLEE.search(line).group(1)
+             for _, op, _, line, _ in results if op == "fusion"}
+    assert [line for comp, _, count, line, _ in results
+            if count == tile and comp not in fused] == []
+    kernels = [line.split("=")[0].strip(" %") for line in text.splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line]
+    assert kernels and all(
+        re.fullmatch(r"paged_decode_attention[.\d]*", name)
+        for name in kernels), kernels
+
+
 # ---------------------------------------------------------------------------
 # the pattern decoder's programs at command-a-plus-05-2026's published widths
 # ---------------------------------------------------------------------------

@@ -287,6 +287,42 @@ class TestPagedDecodeKernelLargeWidths:
         # the empty slot reads exactly zero, whatever its table names
         assert not np.any(np.asarray(out)[0])
 
+    @pytest.mark.parametrize("lengths", [[0, 128, 200], [1, 255, 0],
+                                         [129, 0, 256]],
+                             ids=["boundary", "past_cursor", "full"])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    def test_the_row_form_merges_the_current_token(self, lengths,
+                                                   quantized):
+        """A KV head a query head and one row: the kernel takes the query
+        and the current token's K and V as ``(1, h * d)`` rows, merges
+        the token in and writes the finished row. Held to
+        ``mha_reference(kv_length=)`` over the cache with the token
+        written at each cursor, on ragged loads whose last blocks lie
+        partly past the cursor; a slot the walk never visits (cursor 0)
+        reads ``v_new`` exactly."""
+        rng = np.random.RandomState(20 + quantized)
+        q, tables, (kp, vp, ksc, vsc), kd, vd = self._case(rng, 1,
+                                                            quantized)
+        kn, vn = (rng.randn(self.B, self.H, self.D).astype(np.float32)
+                  for _ in "kv")
+        cursors = jnp.asarray(lengths, jnp.int32)
+        out = paged_decode_attention(
+            jnp.asarray(q), kp, vp, self.LAYER, jnp.asarray(tables),
+            cursors, k_new=jnp.asarray(kn), v_new=jnp.asarray(vn),
+            k_scale=ksc, v_scale=vsc)
+        gap = np.zeros((self.B, self.H, 1, self.D), np.float32)
+        kd = np.concatenate([np.asarray(kd), gap], axis=2)
+        vd = np.concatenate([np.asarray(vd), gap], axis=2)
+        for i, n in enumerate(lengths):
+            kd[i, :, n], vd[i, :, n] = kn[i], vn[i]
+        ref = mha_reference(jnp.asarray(q)[:, :, None], jnp.asarray(kd),
+                            jnp.asarray(vd), kv_length=cursors + 1)[:, :, 0]
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+        empty = np.asarray(lengths) == 0
+        np.testing.assert_array_equal(np.asarray(out)[empty], vn[empty])
+
 
 # ---------------------------------------------------------------------------
 # PagedKVCache pool writes
@@ -410,6 +446,55 @@ def test_the_kernel_walks_the_live_blocks_only(case):
         np.testing.assert_allclose(got[~visited][:, :, 0], alone[:, :, 0],
                                    rtol=1e-6)
     assert np.all(np.isfinite(got))
+
+
+GROUPED_OUTPUTS = os.path.join(os.path.dirname(__file__), "data",
+                               "paged_grouped_outputs.npz")
+GROUPED_CASES = ["grouped_kv", "grouped_kv_window"]
+
+
+def grouped_outputs(case):
+    """The whole call on the grouped path (query heads over fewer KV
+    heads, the current token merged), through the kernel and through the
+    XLA oracle: ``(kernel, oracle)``."""
+    _, hkv, _, window, _, lengths = WALK_CASES[case]
+    q, kp, vp, tables, cursors, _, _ = walk_inputs(case)
+    rng = np.random.RandomState(99)
+    k_new, v_new = (jnp.asarray(rng.randn(len(lengths), hkv, WALK_D),
+                                jnp.float32) for _ in "kv")
+    return tuple(np.asarray(paged_decode_attention(
+        q[:, :, 0], kp, vp, WALK_LAYER, tables, cursors, k_new=k_new,
+        v_new=v_new, use_pallas=use_pallas, window=window))
+        for use_pallas in (True, False))
+
+
+def record_grouped_outputs():
+    """Writes ``GROUPED_OUTPUTS``. Run in a checkout of the commit to hold
+    the kernel to: ``python -c "import sys; sys.path[:0] = ['tests',
+    '.']; import test_paged; test_paged.record_grouped_outputs()"``."""
+    np.savez(GROUPED_OUTPUTS, **{
+        f"{case}.{path}": out for case in GROUPED_CASES
+        for path, out in zip(("kernel", "oracle"), grouped_outputs(case))})
+
+
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_the_grouped_path_computes_what_it_did(case):
+    """The grouped path (``hkv < h``) keeps its block-diagonal tiles and
+    the merge after the call: its output is what commit d72cbbd's
+    computed (``tests/data/paged_grouped_outputs.npz``,
+    :func:`record_grouped_outputs` run there). The XLA oracle, which
+    neither the row form nor anything since touches, is the fixed point:
+    where it reproduces its recorded bits this machine sums as the
+    recording one did, and the kernel's output must be the recorded bits;
+    elsewhere both are held to float32 rounding."""
+    kernel, oracle = grouped_outputs(case)
+    with np.load(GROUPED_OUTPUTS) as rec:
+        want, fixed = rec[case + ".kernel"], rec[case + ".oracle"]
+    if np.array_equal(oracle, fixed):
+        np.testing.assert_array_equal(kernel, want)
+    else:
+        np.testing.assert_allclose(oracle, fixed, rtol=2e-6, atol=1e-6)
+        np.testing.assert_allclose(kernel, want, rtol=2e-6, atol=1e-6)
 
 
 def _append_all(pool, k_new, v_new, block_ids, offsets, method="append"):
@@ -840,7 +925,7 @@ class TestPagedCostModel:
     def test_paged_decode_prices_mean_context_not_max_len(self):
         from apex_tpu.pyprof.model import model_program
         # contexts long enough that the KV stream outweighs the fixed
-        # per-call traffic (the block-diagonal query and output tiles)
+        # per-call traffic (the query, current-token and output rows)
         MAX_LEN, MEAN = 2048, 256
         model, params = _tiny_model(max_position_embeddings=MAX_LEN)
         # told no mean context, the estimate prices the whole table span
