@@ -8,13 +8,15 @@ short period of different blocks repeated (``layer_types`` or
 
 - the MIXER is grouped-query attention (with or without rotary positions
   and a window), a Mamba-2 state-space mixer (:mod:`apex_tpu.ops.mamba2`),
-  a Mamba-1 selective-scan mixer (:mod:`apex_tpu.ops.mamba1`), or none;
+  a Mamba-1 selective-scan mixer (:mod:`apex_tpu.ops.mamba1`), latent
+  attention (MLA, :mod:`apex_tpu.ops.mla`), or none;
 - the FEED-FORWARD is the dropless top-k expert layer
   (:class:`~apex_tpu.transformer.expert_parallel.HeldExpertsMLP`), a dense
   gated MLP (two matrices up, one down: no router, no gather, nothing
   counted), or none;
 - the CACHE is a pool of KV blocks a window hands back, a pool that keeps
-  them, a fixed-size per-slot state, or none.
+  them, a pool of latent rows (one a position for all heads), a
+  fixed-size per-slot state, or none.
 
 ``block`` says how a layer's sub-blocks meet the residual. With
 ``"parallel"`` (Cohere) a kind's mixer and feed-forward sit side by side
@@ -23,7 +25,7 @@ behind one norm, ``x' = x + mixer(n(x)) + ff(n(x))``; with ``"prenorm"``
 pre-norm residual; with ``"sequential"`` (Jamba) a layer is TWO, each
 behind its own norm: ``x = x + mixer(n1(x)); x' = x + ff(n2(x))``. ``norm``
 is a bias-free LayerNorm or an RMS norm; the head is the tied embedding or
-its own matrix. Three families are written as such patterns:
+its own matrix. Four families are written as such patterns:
 
 - Cohere2 sparse: three ``sliding_attention`` layers (rotary, a window)
   then one ``full_attention`` layer (no positional term), each beside a
@@ -34,7 +36,22 @@ its own matrix. Three families are written as such patterns:
   order) layers, pre-norm residual, RMS norm, untied head;
 - Jamba: ``mamba_mlp`` (a Mamba-1 mixer with its own RMS norms on ``dt``,
   ``B`` and ``C``) and ``attention_mlp`` (no positional term) layers, each
-  followed by a dense gated-SiLU MLP, sequential blocks, RMS norm, tied head.
+  followed by a dense gated-SiLU MLP, sequential blocks, RMS norm, tied head;
+- Mistral 4 (DeepSeek-V3's layer): ``mla_moe`` layers only, latent
+  attention then the expert layer with a selection bias and one shared
+  expert, sequential blocks, RMS norm, untied head.
+
+The latent mixer (``mla``): the query through its own latent,
+``q = rms(x Wq_a) Wq_b`` split a head into a part with no position and a
+part rotated by YaRN's table, pairs interleaved; ONE cached row a
+position, ``[c | k_pe] = x Wkv_a`` with ``c`` under its own RMS norm and
+``k_pe`` rotated, shared by every head; with ``llama4_scaling_beta`` the
+query times ``1 + beta ln(1 + floor(p / rope_original))``. A prefill
+expands the row into every head's key ``[c W_uk | k_pe]`` and value ``c
+W_uv`` for the flash forward and caches the ROWS; a decode step appends
+its row and runs the absorbed kernel over the rows
+(:func:`~apex_tpu.ops.mla.mla_decode_attention`), ``W_uk`` folded into
+the query and ``W_uv`` applied to the latent context after it.
 
 The layers of one kind are stacked; runs of one kind are scanned, the
 period is repeated, and each kind that holds something keeps it in its own
@@ -73,6 +90,7 @@ from apex_tpu.ops.flash_attention import (flash_attention,
 from apex_tpu.ops.mamba1 import mamba1_decode_update, mamba1_selective_scan
 from apex_tpu.ops.mamba2 import (causal_conv, causal_conv_update,
                                  mamba2_chunk_scan, mamba2_decode_update)
+from apex_tpu.ops.mla import mla_decode_attention, yarn_inv_freq, yarn_mscale
 from apex_tpu.transformer.expert_parallel import HeldExpertsMLP
 
 __all__ = ["LayerKind", "LAYER_KINDS", "PatternDecoderConfig",
@@ -83,9 +101,10 @@ __all__ = ["LayerKind", "LAYER_KINDS", "PatternDecoderConfig",
 class LayerKind:
     """What a ``layer_types`` entry stands for (module docstring)."""
 
-    mixer: Optional[str]          # "attention" | "mamba2" | "mamba1" | None
+    mixer: Optional[str]   # "attention" | "mamba2" | "mamba1" | "mla" | None
     feed_forward: Optional[str]   # "experts" | "dense" | None
-    cache: Optional[str]          # "blocks" | "window_blocks" | "state" | None
+    cache: Optional[str]   # "blocks" | "window_blocks" | "latent" | "state"
+    #                        | None
     rotary: bool = False          # attention: rotary positions
 
     @property
@@ -104,6 +123,8 @@ LAYER_KINDS: Dict[str, LayerKind] = {
     # jamba's two layers, each a mixer THEN a dense MLP
     "mamba_mlp": LayerKind("mamba1", "dense", "state"),
     "attention_mlp": LayerKind("attention", "dense", "blocks"),
+    # mistral 4's one layer: latent attention THEN the expert layer
+    "mla_moe": LayerKind("mla", "experts", "latent"),
 }
 
 
@@ -154,6 +175,20 @@ class PatternDecoderConfig:
     mamba1_dt_rank: int = 0
     # the dense gated MLP
     intermediate_size: int = 0
+    # the latent-attention mixer (module docstring): head_dim is the
+    # query-key head, qk_nope_head_dim + qk_rope_head_dim
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_factor: float = 1.0              # YaRN's stretch (1: none)
+    rope_original: int = 0                # the positions it stretches
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0      # > 0: YaRN's factor squared in
+    #                                       the softmax scale
+    llama4_scaling_beta: float = 0.0      # 0: no query scaling
 
     def __post_init__(self):
         unknown = set(self.layer_types) - set(LAYER_KINDS)
@@ -188,6 +223,18 @@ class PatternDecoderConfig:
                                         or self.mamba1_dt_rank < 1):
                 raise ValueError(
                     f"{kind!r} needs mamba1_inner and mamba1_dt_rank")
+            if k.mixer == "mla" and not (
+                    self.q_lora_rank > 0 and self.kv_lora_rank > 0
+                    and self.qk_nope_head_dim > 0
+                    and self.qk_rope_head_dim % 2 == 0 < self.qk_rope_head_dim
+                    and self.head_dim == self.qk_nope_head_dim
+                    + self.qk_rope_head_dim == self.v_head_dim):
+                # the prefill's flash forward takes one head width for the
+                # query-key product and the values
+                raise ValueError(
+                    f"{kind!r} needs the latent ranks and head_dim = "
+                    "qk_nope_head_dim + qk_rope_head_dim (even) = "
+                    "v_head_dim")
             if k.mixer == "mamba2" and (
                     self.mamba_heads < 1
                     or self.mamba_heads % self.mamba_groups):
@@ -246,12 +293,17 @@ class PatternDecoderConfig:
         for the kinds that hold something only: ``{kind: (layers, window or
         None)}`` for a kind that keeps KV blocks, a
         :class:`~apex_tpu.serving.cache.StateSpec` for one that keeps a
-        per-slot state."""
-        from apex_tpu.serving.cache import StateSpec
+        per-slot state, a :class:`~apex_tpu.serving.cache.LatentSpec` for
+        one that keeps latent rows."""
+        from apex_tpu.serving.cache import LatentSpec, StateSpec
         out = {}
         for kind in self.kinds:
             holds = LAYER_KINDS[kind].cache
-            if holds == "state" and LAYER_KINDS[kind].mixer == "mamba1":
+            if holds == "latent":
+                out[kind] = LatentSpec(self.layers_of(kind),
+                                       self.kv_lora_rank,
+                                       self.qk_rope_head_dim)
+            elif holds == "state" and LAYER_KINDS[kind].mixer == "mamba1":
                 out[kind] = StateSpec(
                     self.layers_of(kind), self.mamba1_inner, self.mamba_conv,
                     1, self.mamba1_inner, self.mamba_state,
@@ -268,12 +320,14 @@ class PatternDecoderConfig:
 
 
 def rotary_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
-                       theta: float) -> jnp.ndarray:
+                       theta: float, inv=None) -> jnp.ndarray:
     """Rotary positions over the whole head, pairs ``(2j, 2j + 1)``
     (GPT-J's layout): ``x`` ``(..., tokens, heads, d)``, ``positions``
-    ``(..., tokens)``. Float32 inside."""
+    ``(..., tokens)``; ``inv`` the ``d // 2`` frequencies where they are
+    not ``theta``'s own (YaRN's table). Float32 inside."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = positions.astype(jnp.float32)[..., None, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     pair = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
@@ -360,6 +414,7 @@ class PatternDecoder:
                        "dt_norm": (R,), "b_norm": (N,), "c_norm": (N,),
                        "dt_proj": (R, E), "dt_bias": (E,), "A_log": (N, E),
                        "D": (E,), "out_proj": (E, H)},
+            "mla": self._mla_shapes(),
             None: {}}
         layers = {}
         for kind in cfg.kinds:
@@ -394,7 +449,7 @@ class PatternDecoder:
                         "w_latent_up", "mlp_down"):
                 x = x / (2.0 * cfg.num_layers) ** 0.5
             if name in ("norm", "final_norm", "gate_norm", "D", "ff_norm",
-                        "dt_norm", "b_norm", "c_norm"):
+                        "dt_norm", "b_norm", "c_norm", "q_norm", "kv_norm"):
                 x = 1.0 + x
             if name == "conv_w":
                 x = 0.5 * jax.random.normal(k, shape, jnp.float32)
@@ -621,6 +676,112 @@ class PatternDecoder:
             held.write_tails(li, moved), ssm=ssm)})
         return self._mamba1_out(lp, y, u, z), cache
 
+    # -- the latent-attention (MLA) mixer -------------------------------------
+
+    def _mla_shapes(self) -> dict:
+        cfg = self.cfg
+        H, nh = cfg.hidden_size, cfg.num_attention_heads
+        dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+        return {"wq_a": (H, qr), "q_norm": (qr,), "wq_b": (qr, nh * (dn + dr)),
+                "wkv_a": (H, kr + dr), "kv_norm": (kr,),
+                "w_uk": (kr, nh * dn), "w_uv": (kr, nh * cfg.v_head_dim),
+                "wo": (nh * cfg.v_head_dim, H)}
+
+    @property
+    def mla_scale(self) -> float:
+        """The softmax scale: ``1 / sqrt(head_dim)``, times YaRN's attention
+        factor squared where ``rope_mscale_all_dim`` is set (DeepSeek-V3's
+        attention, whose rotation is then scaled by ``mscale /
+        mscale_all_dim``: 1 in every configuration served, so not at all)."""
+        cfg = self.cfg
+        m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) \
+            if cfg.rope_mscale_all_dim else 1.0
+        return m * m / cfg.head_dim ** 0.5
+
+    def _mla_in(self, lp, h, positions):
+        """``h`` ``(T, hidden)`` at ``positions`` ``(T,)`` -> the query
+        ``(T, heads, nope + rope)`` float32, its positional part rotated and
+        the whole of it scaled by llama 4's rule, and the row the layer
+        caches, ``[rms(c) | rotated k_pe]`` ``(T, latent + rope)`` in the
+        compute dtype."""
+        cfg = self.cfg
+        T, nh = h.shape[0], cfg.num_attention_heads
+        dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        dot = lambda a, w: jnp.dot(a, w, preferred_element_type=jnp.float32)
+        cq = _rms(dot(h, lp["wq_a"]), lp["q_norm"], cfg.layer_norm_eps)
+        q = dot(cq.astype(cfg.compute_dtype), lp["wq_b"]).reshape(T, nh, -1)
+        ckv = dot(h, lp["wkv_a"])
+        c = _rms(ckv[:, :kr], lp["kv_norm"], cfg.layer_norm_eps)
+        inv = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta,
+                            cfg.rope_factor, cfg.rope_original,
+                            cfg.rope_beta_fast, cfg.rope_beta_slow)
+        rot = lambda x: rotary_interleaved(x, positions, cfg.rope_theta, inv)
+        q = jnp.concatenate([q[..., :dn], rot(q[..., dn:])], axis=-1)
+        if cfg.llama4_scaling_beta:
+            steps = jnp.floor(positions.astype(jnp.float32)
+                              / cfg.rope_original)
+            q = q * (1.0 + cfg.llama4_scaling_beta
+                     * jnp.log1p(steps))[:, None, None]
+        return q, jnp.concatenate([c, rot(ckv[:, None, kr:])[:, 0]],
+                                  axis=-1).astype(cfg.compute_dtype)
+
+    def _mla_prefill(self, kind, lp, li, h, cache, block_row):
+        """The mixer over one prompt: every head's key and value expanded
+        from the rows for the flash forward; with a cache the ROWS go into
+        the kind's blocks ``block_row[kind]``. Float32 ``(P, hidden)``,
+        this chip's part."""
+        cfg = self.cfg
+        P, nh = h.shape[0], cfg.num_attention_heads
+        kr = cfg.kv_lora_rank
+        q, row = self._mla_in(lp, h, jnp.arange(P))
+        up = lambda w: jnp.dot(row[:, :kr], w,
+                               preferred_element_type=jnp.float32).astype(
+                                   cfg.compute_dtype).reshape(P, nh, -1)
+        k = jnp.concatenate([up(lp["w_uk"]), jnp.broadcast_to(
+            row[:, None, kr:], (P, nh, cfg.qk_rope_head_dim))], axis=-1)
+        heads_first = lambda x: x.transpose(1, 0, 2)[None]
+        with jax.named_scope("pattern_attention"):
+            ctx = flash_attention(
+                heads_first(q.astype(cfg.compute_dtype)), heads_first(k),
+                heads_first(up(lp["w_uv"])), causal=True,
+                softmax_scale=self.mla_scale, use_pallas=cfg.use_flash)
+        if cache is not None:
+            cache = dict(cache, **{kind: cache[kind].write_layer_blocks(
+                li, row, block_row[kind])})
+        ctx = ctx[0].transpose(1, 0, 2).reshape(P, -1)
+        return jnp.dot(ctx.astype(cfg.compute_dtype), lp["wo"],
+                       preferred_element_type=jnp.float32), cache
+
+    def _mla_decode(self, kind, lp, li, h, cache, tables, lengths, block_ids,
+                    offsets, reads, work, mean_context):
+        """One token a slot: its row appended first, then the absorbed
+        kernel over the slot's ``reads`` rows (its own included), ``W_uk``
+        folded into the query and ``W_uv`` applied to the latent
+        context."""
+        cfg = self.cfg
+        S, nh = h.shape[0], cfg.num_attention_heads
+        dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        q, row = self._mla_in(lp, h, lengths)
+        pool = cache[kind].append(li, row, block_ids[kind], offsets)
+        q_lat = jnp.einsum("shd,chd->shc",
+                           q[..., :dn].astype(cfg.compute_dtype),
+                           lp["w_uk"].reshape(kr, nh, dn),
+                           preferred_element_type=jnp.float32)
+        with jax.named_scope("pattern_attention"):
+            ctx = mla_decode_attention(
+                q_lat.astype(cfg.compute_dtype),
+                q[..., dn:].astype(cfg.compute_dtype), pool.latent, pool.rope,
+                li, tables[kind], reads, softmax_scale=self.mla_scale,
+                work=work[kind], use_pallas=cfg.use_flash,
+                mean_context=mean_context)
+        out = jnp.einsum("shc,chd->shd", ctx,
+                         lp["w_uv"].reshape(kr, nh, cfg.v_head_dim),
+                         preferred_element_type=jnp.float32)
+        return jnp.dot(out.reshape(S, -1).astype(cfg.compute_dtype),
+                       lp["wo"], preferred_element_type=jnp.float32), \
+            dict(cache, **{kind: pool})
+
     # -- a layer --------------------------------------------------------------
 
     def _prefill_layer(self, kind, lp, big, li, x, cache, block_row,
@@ -653,15 +814,20 @@ class PatternDecoder:
         elif mixer == "mamba1":
             mixed, cache = self._mamba1_prefill(kind, lp, li, h, cache,
                                                 prompt_len, slot)
+        elif mixer == "mla":
+            mixed, cache = self._mla_prefill(kind, lp, li, h, cache,
+                                             block_row)
         x, stats = self._finish(kind, lp, big, li, x, h32, mixed, valid)
         return x, cache, stats
 
     def _paged_decode_layer(self, kind, lp, big, li, x, cache, tables,
                             lengths, block_ids, offsets, valid,
-                            mean_context, work):
+                            mean_context, work, reads=None):
         """One layer of the decode step: ``x`` ``(S, hidden)``, one token
         a slot at position ``lengths``; ``work`` the paged kernel's walk
-        of each attention kind's blocks."""
+        of each attention kind's blocks; ``reads`` the positions a latent
+        kind's kernel reads a slot (its own row included, 0 where the slot
+        is idle)."""
         cfg = self.cfg
         S = x.shape[0]
         h32 = self._norm(lp["norm"], x)
@@ -684,6 +850,10 @@ class PatternDecoder:
             mixed, cache = self._mamba_decode(kind, lp, li, h, cache, valid)
         elif mixer == "mamba1":
             mixed, cache = self._mamba1_decode(kind, lp, li, h, cache, valid)
+        elif mixer == "mla":
+            mixed, cache = self._mla_decode(
+                kind, lp, li, h, cache, tables, lengths, block_ids, offsets,
+                reads, work, mean_context)
         x, stats = self._finish(kind, lp, big, li, x, h32, mixed, valid)
         return x, cache, stats
 
@@ -812,12 +982,18 @@ class PatternDecoder:
         valid = jnp.asarray(first) != NULL_BLOCK
         x = jnp.take(params["embedding"], tokens[:, 0], axis=0).astype(
             cfg.compute_dtype)
+        # a latent kind appends its row before its kernel reads: a served
+        # slot reads its own row too, an idle one nothing
+        latent = [k for k in block_tables if LAYER_KINDS[k].cache == "latent"]
+        reads = jnp.where(valid, lengths + 1, 0) if latent else None
         # a kind's layers share one walk: made here, outside their scans
-        work = {kind: paged_work_list(lengths, pools[kind].block_size,
-                                      table.shape[1], self._window(kind))
+        work = {kind: paged_work_list(
+            reads if kind in latent else lengths, pools[kind].block_size,
+            table.shape[1], self._window(kind))
                 for kind, table in block_tables.items()}
         fn = lambda kind, lp, big, li, x, cache: self._paged_decode_layer(
             kind, lp, big, li, x, cache, block_tables, lengths,
-            append_block_ids, append_offsets, valid, mean_context, work)
+            append_block_ids, append_offsets, valid, mean_context, work,
+            reads)
         x, pools, stats = self._run_layers(fn, params, x, pools)
         return self._logits(params, x), KindPagedKVCache(pools), stats

@@ -47,6 +47,16 @@ prefill program is told its slot and overwrites the slot's rows (a slot
 given anew starts from zero whatever it held), the decode step advances the
 rows of the slots it serves and leaves the others as they were.
 
+**A latent kind**: a latent-attention layer (MLA) caches ONE row a
+position for all its heads, the compressed key-value latent and the
+rotated positional key side by side (:class:`LatentPagedCache`, described
+by a :class:`LatentSpec`), in blocks like a KV kind: its allocator, table
+and cursor are a block kind's, without a window. Expanding the row into
+per-head K and V would cache ``heads * (qk + v) / width`` times the bytes
+(25.6 at Mistral Small 4's 32 heads and 320-wide row); the decode kernel
+(:func:`apex_tpu.ops.mla.mla_decode_attention`) reads the row once for all
+heads.
+
 The pool is token-major with the heads fused into its last axis because
 that is the one layout the append, the layer scan and the decode kernel
 all take as it is (measured and compiled for PR 27; the earlier
@@ -81,7 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["cache_bytes_per_slot", "PagedKVCache", "StateSpec",
-           "SlotStateCache",
+           "SlotStateCache", "LatentSpec", "LatentPagedCache",
            "KindPagedKVCache", "BlockAllocator", "KindBlockAllocator",
            "AdmitPlan", "StepPlan", "PoolExhausted", "paged_block_bytes",
            "store_roundtrip"]
@@ -510,16 +520,105 @@ class SlotStateCache:
             ssm=self.ssm.at[layer].set(states.astype(jnp.float32)))
 
 
+class LatentSpec(NamedTuple):
+    """What a latent kind holds (a model's ``cache_kinds`` entry, module
+    docstring "A latent kind"): ``layers`` of the kind, each caching a
+    position's ``latent``-wide key-value latent and ``rope``-wide
+    positional key."""
+
+    layers: int
+    latent: int
+    rope: int
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class LatentPagedCache:
+    """The block pool of a latent kind, a position's row in two leaves that
+    each fill whole lane tiles, so that the compiler keeps both as the
+    decode kernel reads them and nothing is relaid or padded:
+
+    - ``latent`` ``(L, NB, block_size, latent)``, token-major like
+      :class:`PagedKVCache`'s K and V (256 lanes at Mistral Small 4);
+    - ``rope`` ``(L, NB, rope, block_size)``, the positions of a block on
+      the lanes: a 64-wide last axis would half-fill a tile, and the
+      compiler would then lay the whole pool with the block axis minor and
+      relay it for the kernel on every step (module docstring).
+
+    Both are carried through the layer scan as the KV pools are (a layer's
+    rows written in place, the decode kernel taking the stacked pools and a
+    layer index). Block 0 is the null block."""
+
+    latent: jnp.ndarray
+    rope: jnp.ndarray
+
+    def tree_flatten(self):
+        return (self.latent, self.rope), None
+
+    @classmethod
+    def tree_unflatten(cls, _, leaves):
+        return cls(*leaves)
+
+    @classmethod
+    def create(cls, spec: LatentSpec, num_blocks: int, block_size: int,
+               dtype=jnp.bfloat16) -> "LatentPagedCache":
+        if num_blocks < 2:
+            raise ValueError(f"num_blocks must be >= 2 (block 0 is the "
+                             f"reserved null block), got {num_blocks}")
+        blocks = (spec.layers, num_blocks)
+        return cls(jnp.zeros(blocks + (block_size, spec.latent), dtype),
+                   jnp.zeros(blocks + (spec.rope, block_size), dtype))
+
+    @property
+    def block_size(self) -> int:
+        return self.latent.shape[2]
+
+    def nbytes(self) -> int:
+        return sum(x.size * x.dtype.itemsize for x in (self.latent, self.rope))
+
+    def append(self, layer, rows: jnp.ndarray, block_ids: jnp.ndarray,
+               offsets: jnp.ndarray) -> "LatentPagedCache":
+        """One row a slot at ``layer``, ``rows`` ``(S, latent + rope)``,
+        where :meth:`PagedKVCache.append` would put it."""
+        kr = self.latent.shape[-1]
+        rows = rows.astype(self.latent.dtype)
+        return LatentPagedCache(
+            self.latent.at[layer, block_ids, offsets, :].set(
+                rows[:, :kr], mode="drop"),
+            self.rope.at[layer, block_ids, :, offsets].set(
+                rows[:, kr:], mode="drop"))
+
+    def write_layer_blocks(self, layer, rows: jnp.ndarray,
+                           block_row: jnp.ndarray) -> "LatentPagedCache":
+        """A prompt's rows ``(P, latent + rope)`` at ``layer``, whole
+        blocks as :meth:`PagedKVCache.write_layer_blocks` lands them."""
+        P, bs = rows.shape[0], self.block_size
+        if P % bs:
+            raise ValueError(f"prompt window {P} must be a multiple of "
+                             f"block_size {bs}")
+        kr = self.latent.shape[-1]
+        blocks = rows.astype(self.latent.dtype).reshape(P // bs, bs, -1)
+        row = jnp.asarray(block_row, jnp.int32)
+        return LatentPagedCache(
+            self.latent.at[layer, row].set(blocks[..., :kr], mode="drop"),
+            self.rope.at[layer, row].set(
+                blocks[..., kr:].transpose(0, 2, 1), mode="drop"))
+
+    def scrub_null_block(self) -> "LatentPagedCache":
+        return LatentPagedCache(self.latent.at[:, NULL_BLOCK].set(0),
+                                self.rope.at[:, NULL_BLOCK].set(0))
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KindPagedKVCache:
     """What a pattern model's layer kinds keep, by kind (module docstring,
     "Pools by layer kind", "A state kind"): ``pools[kind]`` is a
     :class:`PagedKVCache` ``(layers of the kind, num_blocks of the kind,
-    block_size, H_kv * D)``, block 0 of each its own null block, or a
-    :class:`SlotStateCache`."""
+    block_size, H_kv * D)``, block 0 of each its own null block, a
+    :class:`LatentPagedCache` or a :class:`SlotStateCache`."""
 
-    pools: Dict[str, "PagedKVCache | SlotStateCache"]
+    pools: Dict[str, "PagedKVCache | LatentPagedCache | SlotStateCache"]
 
     def tree_flatten(self):
         kinds = tuple(sorted(self.pools))    # as jax orders a dict's keys
@@ -534,11 +633,11 @@ class KindPagedKVCache:
                num_blocks: Dict[str, int], num_heads: int, block_size: int,
                head_dim: int, dtype=jnp.bfloat16,
                max_seqs: Optional[int] = None) -> "KindPagedKVCache":
-        """``kinds``: ``{kind: (layers, window or None)}`` for a block kind
-        or a :class:`StateSpec` for a state kind, as the model's
-        ``cfg.cache_kinds`` gives it; ``num_blocks[kind]`` (block kinds
-        only) includes the kind's null block; ``max_seqs`` sizes the state
-        kinds."""
+        """``kinds``: ``{kind: (layers, window or None)}`` for a block kind,
+        a :class:`LatentSpec` for a latent kind or a :class:`StateSpec` for
+        a state kind, as the model's ``cfg.cache_kinds`` gives it;
+        ``num_blocks[kind]`` (block and latent kinds) includes the kind's
+        null block; ``max_seqs`` sizes the state kinds."""
         if jnp.dtype(dtype) == jnp.int8:
             raise ValueError("pools by layer kind are unquantized")
         pools = {}
@@ -547,6 +646,9 @@ class KindPagedKVCache:
                 if max_seqs is None:
                     raise ValueError(f"state kind {kind!r} needs max_seqs")
                 pools[kind] = SlotStateCache.create(spec, max_seqs, dtype)
+            elif isinstance(spec, LatentSpec):
+                pools[kind] = LatentPagedCache.create(
+                    spec, num_blocks[kind], block_size, dtype)
             else:
                 pools[kind] = PagedKVCache.create(
                     spec[0], num_blocks[kind], num_heads, block_size,
@@ -562,12 +664,16 @@ class KindPagedKVCache:
                    if isinstance(pool, SlotStateCache))
 
     def scrub_null_blocks(self) -> "KindPagedKVCache":
-        return KindPagedKVCache({
-            kind: pool if isinstance(pool, SlotStateCache)
-            else dataclasses.replace(
-                pool, k=pool.k.at[:, NULL_BLOCK].set(0),
-                v=pool.v.at[:, NULL_BLOCK].set(0))
-            for kind, pool in self.pools.items()})
+        def scrub(pool):
+            if isinstance(pool, SlotStateCache):
+                return pool
+            if isinstance(pool, LatentPagedCache):
+                return pool.scrub_null_block()
+            return dataclasses.replace(pool, k=pool.k.at[:, NULL_BLOCK].set(0),
+                                       v=pool.v.at[:, NULL_BLOCK].set(0))
+
+        return KindPagedKVCache({kind: scrub(pool)
+                                 for kind, pool in self.pools.items()})
 
 
 # ---------------------------------------------------------------------------
@@ -1046,7 +1152,8 @@ class KindBlockAllocator:
     (``lengths``) is one a slot, the same in every kind. An admission or a
     step either gets its blocks in every kind or in none. No prefix is
     shared (a window layer has handed its early blocks back), so no
-    copy-on-write is ever pending. A state kind (:class:`StateSpec`) gets
+    copy-on-write is ever pending. A latent kind (:class:`LatentSpec`) is a
+    block kind without a window. A state kind (:class:`StateSpec`) gets
     no allocator, no blocks and no table: its address is the slot. What is
     counted of it is how many slots hold one (:attr:`state_slots_in_use`:
     admitted and not yet released)."""
@@ -1054,11 +1161,11 @@ class KindBlockAllocator:
     def __init__(self, kinds: Dict[str, tuple],
                  num_blocks: Dict[str, int], block_size: int,
                  blocks_per_slot: int, max_seqs: int):
-        self.kinds = {kind: BlockAllocator(num_blocks[kind], block_size,
-                                           blocks_per_slot, max_seqs,
-                                           window=spec[1])
-                      for kind, spec in kinds.items()
-                      if not isinstance(spec, StateSpec)}
+        self.kinds = {kind: BlockAllocator(
+            num_blocks[kind], block_size, blocks_per_slot, max_seqs,
+            window=None if isinstance(spec, LatentSpec) else spec[1])
+            for kind, spec in kinds.items()
+            if not isinstance(spec, StateSpec)}
         if not self.kinds:
             raise ValueError("a model with no block kind has no cursor: "
                              "the paged engine serves at least one")

@@ -54,7 +54,7 @@ from apex_tpu.observability.trace import span, spans_seen
 from apex_tpu.serving.cache import (NULL_BLOCK, _MIN_SCALE, PagedKVCache,
                                     BlockAllocator, KindPagedKVCache,
                                     KindBlockAllocator, AdmitPlan,
-                                    paged_block_bytes)
+                                    LatentSpec, paged_block_bytes)
 from apex_tpu.serving.sampling import sample_tokens, verify_tokens
 
 __all__ = ["ServingEngine", "PagedServingEngine", "NO_TOKEN"]
@@ -105,9 +105,11 @@ class ServingEngine:
     in the place of blocks (a :class:`~apex_tpu.serving.cache.StateSpec`
     among ``cache_kinds``: Mamba-2 or Mamba-1 layers, each in the layout
     its spec gives) lives in the same cache, one
-    row a slot; the prefill programs are then told the slot they fill.
-    Such a model is served without the prefix index and without
-    speculation (docs/SERVING.md, Limits).
+    row a slot; the prefill programs are then told the slot they fill. A
+    kind that keeps latent rows (a :class:`~apex_tpu.serving.cache.
+    LatentSpec`: latent attention) is a block kind whose pool holds one row
+    a position for all heads. Such a model is served without the prefix
+    index and without speculation (docs/SERVING.md, Limits).
 
     Args:
       model: a :class:`~apex_tpu.models.gpt.GPTModel` (tp=1, no SP) or
@@ -311,6 +313,16 @@ class ServingEngine:
         #: a layer kind keeps a per-slot state (cache.py, "A state kind"):
         #: the prefill programs take the slot they fill
         self.has_state = self.by_kind and self.allocator.has_state
+        #: a layer kind keeps latent rows (cache.py, "A latent kind"): the
+        #: decode kernel reads every served slot's rows, its own included;
+        #: :attr:`latent_positions_read` counts them, step by step
+        self.latent = self.by_kind and any(
+            isinstance(spec, LatentSpec) for spec in cfg.cache_kinds.values())
+        self.latent_positions_read = 0
+        #: the positions YaRN's table stretches (0: the model has none): a
+        #: prompt position at or past it is rotated by the stretched table
+        self.rope_original = int(cfg.rope_original) \
+            if getattr(cfg, "rope_factor", 1.0) > 1.0 else 0
         stats = getattr(model, "step_stats", False)
         self._stats_shape = model.stats_shape if stats else None
         #: the counter each column of :attr:`last_stats` adds to
@@ -745,6 +757,12 @@ class ServingEngine:
             with span("decode.dispatch"):
                 self.cache, toks, *finite = self.decode_compiled(*args)
             with span("decode.advance"):
+                if self.latent:
+                    # a slot whose append was given a block reads its
+                    # cursor's rows and its own (the program's ``reads``)
+                    served = next(iter(block_ids.values())) != NULL_BLOCK
+                    self.latent_positions_read += int(np.sum(
+                        self.allocator.lengths[served].astype(np.int64) + 1))
                 self.allocator.advance(list(np.flatnonzero(ok)))
             if not hold:
                 return self._read((toks, finite))

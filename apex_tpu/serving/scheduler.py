@@ -303,6 +303,9 @@ class SlotScheduler:
         # pools by layer kind only: the window allocators' monotonic
         # (given, returned) at the last step
         self._window_seen = (0, 0)
+        # a latent model only: the engine's monotonic count of latent
+        # positions read at the last step
+        self._latent_seen = 0
         # how often the step in flight engages (docs/OBSERVABILITY.md):
         # at 0 from the start, so a run that never overlaps reads 0
         for name in ("serve/decode_steps_overlapped",
@@ -635,6 +638,10 @@ class SlotScheduler:
         self._temps[slot] = req.temperature
         self._reg.counter("serve/admitted").inc()
         self._reg.counter("serve/prefill_tokens").inc(len(req.prompt))
+        if self.engine.rope_original:
+            # the prompt's positions that YaRN's stretched table rotates
+            self._reg.counter("serve/positions_past_rope_original").inc(
+                max(0, len(req.prompt) - self.engine.rope_original))
         plan = self.engine.last_admit
         if plan.prefill:
             # the rows the prefill program computed, padding included
@@ -886,6 +893,12 @@ class SlotScheduler:
                 self._reg.gauge("serve/state_slots_in_use").set(held)
                 self._reg.gauge("serve/state_bytes_held").set(
                     held * self.engine.cache.state_bytes_per_slot)
+        if self.engine.latent:
+            # the live positions the latent decode kernel read, summed over
+            # the steps (the engine's count is monotonic)
+            self._reg.counter("serve/latent_positions_read").inc(
+                self.engine.latent_positions_read - self._latent_seen)
+            self._latent_seen = self.engine.latent_positions_read
         if alloc.cow_copies > self._cow_seen:
             self._reg.counter("serve/blocks_cow_copied").inc(
                 alloc.cow_copies - self._cow_seen)

@@ -726,3 +726,120 @@ def test_the_selective_scan_kernel_compiles_for_v5e_at_published_widths(
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 1 and "mamba1_selective_scan" in calls[0], calls
+
+
+# ---------------------------------------------------------------------------
+# mistral-small-4-119b.docqa-r80 (benchmark/workloads): one chip of eight's
+# share, 6 layers of latent attention (32 heads, latents of 1,024 and 256, a
+# positional part of 64) then 16 held of 128 experts of 2,048 top-4 and a
+# shared expert; 32 slots of 16,896: ONE pool of latent rows, 320 wide
+# ---------------------------------------------------------------------------
+
+MISTRAL = dict(slots=32, max_len=16896, block=128, blocks={"mla_moe": 4225})
+mla_ops = importlib.import_module("apex_tpu.ops.mla")
+
+
+def _mistral_step(kind, sharding):
+    from apex_tpu.serving.cache import KindPagedKVCache
+    from benchmark import run as harness
+    from benchmark.families import mistral4
+    c = MISTRAL
+    model = mistral4.model(harness.load_json(harness.HERE, "configs",
+                                             "mistral-small-4-119b.json"))
+    cfg = model.cfg
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    params = described(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = described(jax.eval_shape(lambda: KindPagedKVCache.create(
+        cfg.cache_kinds, c["blocks"], 32, c["block"], 128)))
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree_util.tree_leaves(params))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
+
+    by_kind = lambda x: {"mla_moe": x}
+    S, per_slot = c["slots"], c["max_len"] // c["block"]
+    if kind == "decode":
+        def fn(params, cache, tokens, tables, lengths, ids, offs):
+            return model.forward(
+                params, tokens[:, None], kv_cache=cache,
+                block_tables=tables, lengths=lengths, append_block_ids=ids,
+                append_offsets=offs)
+        args = (params, cache, i32(S), by_kind(i32(S, per_slot)), i32(S),
+                by_kind(i32(S)), i32(S))
+    else:
+        bucket = int(kind[len("prefill"):])
+
+        def fn(params, cache, tokens, block_row, prompt_len):
+            return model.forward(
+                params, tokens, kv_cache=cache, block_row=block_row,
+                prompt_len=prompt_len, last_logit_only=True)
+        args = (params, cache, i32(1, bucket),
+                by_kind(i32(bucket // c["block"])), i32())
+    return fn, args, cache, weight_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill1024", "prefill16384"])
+def test_mistral4_programs_compile_for_v5e(kind, one_chip, for_tpu,
+                                           monkeypatch):
+    """The cell's decode program and its smallest and widest prefill at
+    published widths: the absorbed kernel ``mla_decode_attention`` (decode)
+    and the flash forward at head dimension 128 (prefill) pass Mosaic beside
+    the expert product; the latent pool, 640 B a position a layer in two
+    lane-dense leaves, is updated where it lies and never relaid (a 320-wide
+    leaf was laid with the block axis minor and copied twice a step, 2.5
+    GB of temporaries); weights 5.75 GB + pool + temporaries fit the chip,
+    the 16,384-token prefill included; a decode step expands no cached row
+    into per-head keys or values."""
+    ep = importlib.import_module("apex_tpu.transformer.expert_parallel")
+    monkeypatch.setattr(ep, "_interp", lambda: False)
+    monkeypatch.setattr(mla_ops, "_interp", lambda: False)
+    fn, args, cache, weight_bytes = _mistral_step(kind, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    for name in ("moe_experts_gate_up", "moe_experts_down",
+                 "mla_decode_attention" if kind == "decode"
+                 else "flash_attention"):
+        assert name in text, name
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    if kind == "decode":
+        assert not [c for c in calls if "flash_attention" in c]
+    pool = cache.pools["mla_moe"]
+    assert pool.latent.shape == (6, 4225, 128, 256) \
+        and pool.rope.shape == (6, 4225, 64, 128) and pool.rope.dtype == BF16
+    pool_bytes = pool.latent.size * 2 + pool.rope.size * 2
+    assert pool_bytes == 2_076_672_000                 # 2.08 GB at 640 B
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert weight_bytes == 2 * 2_872_635_648
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(kind, "argument", memory.argument_size_in_bytes, "temp",
+          memory.temp_size_in_bytes, "total", total)
+    assert total < 15.5e9, (total, memory.temp_size_in_bytes)
+    if kind == "decode":
+        # no (slots, heads, positions, 128) key or value: the rows are read
+        # by the kernel as they lie; the temporaries are a step's rows
+        assert memory.temp_size_in_bytes < 0.3e9, memory.temp_size_in_bytes
+
+
+def test_the_absorbed_kernel_compiles_for_v5e_at_published_widths(
+        one_chip, for_tpu, monkeypatch):
+    monkeypatch.setattr(mla_ops, "_interp", lambda: False)
+
+    def fn(q_lat, q_pe, latent, rope, tables, lengths):
+        return mla_ops.mla_decode_attention(
+            q_lat, q_pe, latent, rope, jnp.int32(3), tables, lengths,
+            softmax_scale=0.19, use_pallas=True)
+
+    shapes = [((32, 32, 256), BF16), ((32, 32, 64), BF16),
+              ((6, 4225, 128, 256), BF16), ((6, 4225, 64, 128), BF16),
+              ((32, 132), I32), ((32,), I32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "mla_decode_attention" in calls[0], calls
