@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from apex_tpu.normalization import fused_layer_norm_affine
 from apex_tpu.ops.dropout import dropout
+from apex_tpu.ops.mlp import gelu_tanh
 from apex_tpu.remat import RematPolicy, tag as _remat_tag
 from apex_tpu.ops.flash_attention import (flash_attention,
                                           paged_decode_attention,
@@ -325,9 +326,10 @@ class GPTModel:
     def _mlp(self, lp: dict, x: jnp.ndarray) -> jnp.ndarray:
         h, _ = self.fc1(lp["fc1"], x)
         # tagged PRE-gelu: saving the GEMM output costs the same bytes and
-        # leaves only the elementwise gelu to recompute for fc2's dW
+        # leaves only the elementwise gelu to recompute for fc2's dW; the
+        # gelu's own backward needs that tensor alone (ops.mlp.gelu_tanh)
         h = self._tag(h, "mlp_fc1_out")
-        h = jax.nn.gelu(h, approximate=True)
+        h = gelu_tanh(h)
         out, _ = self.fc2(lp["fc2"], h)
         return self._tag(out, "mlp_fc2_out")
 

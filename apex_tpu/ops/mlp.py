@@ -22,13 +22,46 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["MLP", "FusedDense", "FusedDenseGeluDense", "mlp_forward",
-           "fused_dense", "fused_dense_gelu_dense"]
+           "fused_dense", "fused_dense_gelu_dense", "gelu_tanh"]
 
 _ACTIVATIONS = {
     "none": lambda x: x,
     "relu": jax.nn.relu,
     "sigmoid": jax.nn.sigmoid,
 }
+
+
+@jax.custom_vjp
+def gelu_tanh(x):
+    """``jax.nn.gelu(x, approximate=True)``, the same ops and so the same
+    bits, whose backward keeps ``x`` ALONE. AD of the plain expression
+    saves ``x``, ``tanh(u)``, ``1 - tanh(u)``, ``0.5 (1 + tanh(u))`` and
+    ``3 x^2`` beside the output; in a scanned layer stack each is a stacked
+    activation written by the forward and read back by the backward. The
+    backward here recomputes them from ``x`` in float32 (a few VPU/EUP
+    operations a value, inside a fusion bound by the bytes it reads) and
+    rounds the gradient to ``x.dtype`` once."""
+    return jax.nn.gelu(x, approximate=True)
+
+
+def _gelu_tanh_fwd(x):
+    return jax.nn.gelu(x, approximate=True), x
+
+
+def _gelu_tanh_bwd(x, g):
+    # the derivative of the function the forward computes: its two
+    # constants rounded to x's dtype, as jax.nn.gelu rounds them
+    f32 = jnp.float32
+    c = jnp.asarray(math.sqrt(2.0 / math.pi), x.dtype).astype(f32)
+    a = jnp.asarray(0.044715, x.dtype).astype(f32)
+    xf = x.astype(f32)
+    t = jnp.tanh(c * (xf + a * xf ** 3))
+    d = 0.5 * (1.0 + t) + 0.5 * xf * (1.0 + t) * (1.0 - t) * c * (
+        1.0 + 3.0 * a * xf * xf)
+    return ((g.astype(f32) * d).astype(x.dtype),)
+
+
+gelu_tanh.defvjp(_gelu_tanh_fwd, _gelu_tanh_bwd)
 
 
 def _dense(x, w, b):
@@ -94,7 +127,7 @@ def fused_dense(x, weight, bias):
 def fused_dense_gelu_dense(x, w1, b1, w2, b2):
     """``fused_dense_cuda.linear_gelu_linear_forward``: GEMM+bias+GELU+GEMM+bias
     in one fusion (tanh GELU, matching cuBLASLt's CUBLASLT_EPILOGUE_GELU)."""
-    h = jax.nn.gelu(_dense(x, w1, b1), approximate=True)
+    h = gelu_tanh(_dense(x, w1, b1))
     return _dense(h.astype(x.dtype), w2, b2).astype(x.dtype)
 
 

@@ -111,7 +111,9 @@ class RematPolicy:
 
     ``mode``:
 
-    - ``"none"`` — no checkpointing (AD saves every residual);
+    - ``"none"`` — no checkpointing (AD saves every residual; the GPT
+      MLP's tanh-GELU, ``ops.mlp.gelu_tanh``, saves its input alone and
+      recomputes the rest of its derivative in the backward);
     - ``"full"`` — plain ``jax.checkpoint`` with the default save-nothing
       policy: the pre-policy ``remat=True`` program, jaxpr-identical;
     - ``"selective"`` — ``save_only_these_names(*save_names)``: registry-

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from _program_text import program_digest
+
 # the package re-exports the FUNCTION under the module's name
 fa = importlib.import_module("apex_tpu.ops.flash_attention")
 
@@ -158,6 +160,51 @@ def test_training_flash_kernels_keep_the_name_the_metric_reads(one_chip,
         scope = re.search(r'op_name="([^"]*)"', line).group(1)
         assert "flash_attention" in name and "window" not in name, name
         assert re.search(r"\(flash_attention\)+/pallas_call$", scope), scope
+
+
+def test_the_train_step_stacks_two_mlp_activations_a_layer(topo, for_tpu):
+    """``gpt2-medium.pretrain-16k``'s own step (the family's trainer at the
+    cell's job, donated, published widths) over 2 layers: the layer scans
+    carry two stacked ``bf16[L, 4, 1024, 4096]`` MLP activations, the
+    tanh-GELU's input and its output, which fc2's weight gradient reads.
+    AD of the plain ``jax.nn.gelu`` saved four more (tanh, 1 - tanh,
+    0.5 (1 + tanh), 3 x^2): 4 x 805 MB written and read back a step at
+    24 layers (``ops.mlp.gelu_tanh``)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from apex_tpu.transformer import parallel_state
+    from benchmark import run as harness
+    from benchmark.families import gpt2
+    cfg = dict(harness.load_json(harness.HERE, "configs", "gpt2-medium.json"),
+               n_layer=2)
+    job = harness.load_json(harness.HERE, "workloads",
+                            "gpt2-medium.pretrain-16k.json")["job"]
+    trainer, mesh = gpt2.trainer(cfg, job, topo.devices[:1])
+    try:
+        stage, shared = jax.eval_shape(
+            lambda: gpt2.to_trainer(gpt2.make_weights(cfg, *gpt2.seed_key(1))))
+
+        def described(tree, specs=None):
+            specs = specs or jax.tree_util.tree_map(lambda _: P(), tree)
+            return jax.tree_util.tree_map(
+                lambda x, s: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+                tree, specs, is_leaf=lambda x: isinstance(x, P))
+
+        tokens = described(jax.ShapeDtypeStruct(
+            (job["microbatches"], job["micro_batch"], job["seq"]), I32))
+        args = (described(stage, trainer.stage_specs(stage)),
+                described(shared, trainer.shared_specs),
+                described(jax.eval_shape(trainer.opt.init, (stage, shared))),
+                described(jax.eval_shape(trainer.scaler.init)),
+                tokens, tokens)
+        text = trainer.jit_train_step(donate=True).lower(*args) \
+            .compile().as_text()
+    finally:
+        parallel_state.destroy_model_parallel()
+    stack = "bf16[2,4,1024,4096]"
+    carried = [line.split(" while(")[0].count(stack)
+               for line in text.splitlines() if ") while(" in line]
+    assert max(carried) == 2, carried
 
 
 # ---------------------------------------------------------------------------
@@ -396,6 +443,28 @@ def test_the_decode_step_keeps_the_query_and_the_context_as_rows(
     assert kernels and all(
         re.fullmatch(r"paged_decode_attention[.\d]*", name)
         for name in kernels), kernels
+
+
+# ``program_digest`` of each program as commit 83dd08e compiled it: a
+# change to code these programs share with the train step (the GPT MLP's
+# GELU, ops.mlp.gelu_tanh) leaves them as they were. A change that means
+# to alter them records its own digests here, with its commit.
+SERVE_DIGESTS_AT_83DD08E = {
+    "prefill": "90d1b813084dae9c513d4db59acb7fdb"
+               "69c340839fa3e9037cd81e17b2d97047",
+    "decode": "85d93fbba38c6e59995f8259e1cbbce0"
+              "b1a42213409e243814166e8fd1ce7c8d",
+}
+
+
+@pytest.mark.parametrize("kind", sorted(SERVE_DIGESTS_AT_83DD08E))
+def test_the_serve_programs_are_the_recorded_ones(kind, one_chip, for_tpu):
+    """gpt2-large.chat-r80's prefill and decode programs, over the
+    engines' weight image: their compiled text without its call-site
+    records (kernel bodies included) is the one recorded above."""
+    fn, args, donated, _, _ = _paged_step(kind, "image", one_chip)
+    compiled = jax.jit(fn, donate_argnums=(donated,)).lower(*args).compile()
+    assert program_digest(compiled) == SERVE_DIGESTS_AT_83DD08E[kind]
 
 
 # ---------------------------------------------------------------------------
