@@ -246,6 +246,7 @@ def run(ctx):
     # a request that failed or never finished counts as the worst there is
     worst = (ctx.seconds + DRAIN_SECONDS) * 1e3
     ttft, tpot, lateness, queue_wait = [], [], [], []
+    latencies = []           # (done, ttft, tpot or None) of the finished
     failed = short = 0
     for rec in records.values():
         if not finished(rec):
@@ -258,9 +259,12 @@ def run(ctx):
         lateness.append(late_ms)
         ttft.append((rec["first"] - rec["due"]) * 1e3)
         queue_wait.append((rec["due"], late_ms + c.queue_wait_ms))
+        per_token = None
         if len(c.tokens) > 1:
-            tpot.append((rec["done"] - rec["first"]) * 1e3
-                        / (len(c.tokens) - 1))
+            per_token = ((rec["done"] - rec["first"]) * 1e3
+                         / (len(c.tokens) - 1))
+            tpot.append(per_token)
+        latencies.append((rec["done"], ttft[-1], per_token))
         short += len(c.tokens) != rec["arrival"].max_new_tokens
     ctx.say(window_s=window_s, requests_due=len(arrivals),
             requests_finished=len(arrivals) - failed, requests_failed=failed,
@@ -279,6 +283,7 @@ def run(ctx):
         "tpot_p95_ms": percentile(tpot, 95),
     }
     facts = dict(kind="serve", traced=traced, queue_wait_ms=queue_wait,
+                 latencies_ms=latencies,
                  decode_calls=server.decode_calls,
                  prefill_calls=server.prefill_calls,
                  registry=server.registry_at)

@@ -18,10 +18,10 @@ def read(facts, params):
     matched = everything = 0.0
     modules = trace_reduce.device_ops(trace, trace_reduce.MODULES_LINE)
     for chip, events in facts["device_ops"].items():
-        runs = trace_reduce.union(trace_reduce.intervals(
-            modules.get(chip, ()), params["module"]))
+        in_run = trace_reduce.meets(trace_reduce.union(
+            trace_reduce.intervals(modules.get(chip, ()), params["module"])))
         for name, start, dur, scope in trace_reduce.leaves(events):
-            if not trace_reduce.clip(runs, start, start + max(dur, 1.0)):
+            if not in_run(start, start + max(dur, 1.0)):
                 continue
             everything += dur
             if pattern.search(f"{name} {scope}"):     # as intervals() does

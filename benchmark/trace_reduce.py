@@ -23,6 +23,7 @@ line ``python3``. An event here is ``[op, start_ns, duration_ns, text]``
 with ``op`` that own name, ``flash_attention.22``.
 """
 
+import bisect
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -146,6 +147,21 @@ def clip(ivals, lo, hi):
             if min(e, hi) > max(s, lo)]
 
 
+def meets(ivals):
+    """A test ``(lo, hi) -> bool`` of whether ``clip(ivals, lo, hi)`` is
+    not empty, for ``ivals`` disjoint and sorted (as ``union`` gives them),
+    by bisection: a reader asks it of ~10^6 ops against ~10^3 program
+    runs."""
+    ivals = [(s, e) for s, e in ivals if e > s]
+    ends = [e for _, e in ivals]
+
+    def test(lo, hi):
+        i = bisect.bisect_right(ends, lo)        # the first that ends past lo
+        return i < len(ivals) and ivals[i][0] < hi and lo < hi
+
+    return test
+
+
 def subtract(ivals, holes):
     """The part of ``ivals`` (disjoint, sorted) outside ``holes``
     (disjoint, sorted)."""
@@ -221,12 +237,26 @@ def idle_gaps(events, lo, hi):
 def gaps_by_host_span(gaps, spans, top=10, other="(no benchmark span)"):
     """Idle seconds by the host span that covered them: each gap is split
     over the spans it overlaps, innermost (latest-starting) span first;
-    what no span covers goes to ``other``. ``[[name, seconds], ...]``."""
+    what no span covers goes to ``other``. ``[[name, seconds], ...]``.
+
+    The spans are put in that order once, and a gap looks only at those
+    that start before its end, and no further than the last that can still
+    reach it: a trace holds ~10^5 gaps and ~10^4 spans."""
+    order = sorted(spans, key=lambda s: -s[1])
+    neg_starts = [-ss for _, ss, _ in order]
+    # reach[j]: the latest end of order[j:], the spans that start no later
+    reach = [0.0] * (len(order) + 1)
+    reach[len(order)] = float("-inf")
+    for j in range(len(order) - 1, -1, -1):
+        reach[j] = max(order[j][2], reach[j + 1])
     sums = {}
     for gs, ge in gaps:
         rest = [(gs, ge)]
-        for name, ss, se in sorted(spans, key=lambda s: -s[1]):
-            if se <= gs or ss >= ge or not rest:
+        j = bisect.bisect_right(neg_starts, -ge)     # first start below ge
+        while rest and j < len(order) and reach[j] > gs:
+            name, ss, se = order[j]
+            j += 1
+            if se <= gs:
                 continue
             covered = clip(rest, ss, se)
             if covered:

@@ -1,7 +1,8 @@
 """Family ``cohere2_moe`` in the harness, end to end on the CPU at the tiny
 cell's size (``cells/workloads/tiny-cohere2-moe.rag.json``): a sound run is
 correct, the fp8 control and every planted fault in the engine's place are
-not, and the family's counts follow the routing."""
+not, the family's counts follow the routing, and the manifest's entries for
+the cell are what the cell was accepted with, found by name."""
 
 import json
 import os
@@ -10,6 +11,7 @@ import jax
 import numpy as np
 import pytest
 
+import accepted
 from benchmark import run as harness
 from benchmark.families import cohere2_moe as family
 from benchmark.kinds import serve
@@ -21,6 +23,14 @@ CELLS = os.path.join(HERE, "cells")
 ROOT = os.path.dirname(os.path.dirname(HERE))
 CELL = "tiny-cohere2-moe.rag"
 PUBLISHED = "command-a-plus-05-2026"
+RAG = PUBLISHED + ".rag-r80"
+NEW_METRICS = ("moe_experts_roofline.serve", "moe_share_of_decode.serve",
+               "expert_load_max_over_mean.serve",
+               "window_blocks_returned_share.serve",
+               "prefill_tokens_per_s.serve")
+ACCEPTED = {"configs": [PUBLISHED], "workloads": [RAG],
+            "per_layer": {name: [RAG] for name in NEW_METRICS},
+            "joined": {"serve_tokens_per_s": [RAG]}}
 
 
 def context(seed):
@@ -144,43 +154,37 @@ def test_a_window_bounds_what_a_decode_step_reads():
 # -- the manifest: what the cell is judged on ---------------------------------------
 
 def test_the_cell_reports_what_held_its_bound_and_its_metrics_move_that():
-    manifest = harness.load_json(ROOT, "BENCHMARK.json")
-    cell = PUBLISHED + ".rag-r80"
-    reported = {m["name"] for m in harness.metrics_of(
-        manifest, "end_to_end", cell, ())}
+    manifest = accepted.load()
+    reported = accepted.reported(manifest, RAG)
     # neither tail held half its bound over the driver's two sets of six
     # (PERF.md section 7 row 20), so the cell reports tokens/s and set-up
-    assert reported == {"serve_tokens_per_s", "setup_s"}
-    per_layer = harness.metrics_of(manifest, "per_layer", cell, reported)
-    names = [m["name"] for m in per_layer]
-    assert sorted(names) == sorted(
-        ["moe_experts_roofline.serve", "moe_share_of_decode.serve",
-         "expert_load_max_over_mean.serve",
-         "window_blocks_returned_share.serve",
-         "prefill_tokens_per_s.serve"])
+    assert {"serve_tokens_per_s", "setup_s"} <= reported
+    assert not reported & {"ttft_p95_ms", "tpot_p95_ms"}
+    per_layer = harness.metrics_of(manifest, "per_layer", RAG, reported)
+    assert set(NEW_METRICS) <= {m["name"] for m in per_layer}
     for m in per_layer:
         assert m["moves"] in reported, m
         spec = harness.load_json(ROOT, "benchmark", "metrics",
                                  m["name"] + ".json")
         assert (spec["name"], spec["moves"]) == (m["name"], m["moves"])
     # one definition a quantity and kind of cell: no copy of an accepted
-    # metric under another suffix
-    suffixes = {m["name"].rsplit(".", 1)[1] for m in manifest["per_layer"]}
-    assert suffixes == {"serve", "train"}
+    # metric under a suffix that names no kind of cell
+    for m in manifest["per_layer"]:
+        suffix = m["name"].rsplit(".", 1)[1]
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmark", "kinds", suffix + ".py")), m["name"]
 
 
 def test_what_the_benchmark_had_is_as_it_was():
     """The accepted entries list the cells they listed: a metric that
-    moves a tail cannot list a cell that reports none."""
-    manifest = harness.load_json(ROOT, "BENCHMARK.json")
-    cell = PUBLISHED + ".rag-r80"
+    moves a tail cannot list a cell that reports none, and the cell's
+    five entries are found by name, each listing the cell first."""
+    manifest = accepted.load()
     tails = {"ttft_p95_ms", "tpot_p95_ms"}
     for m in manifest["end_to_end"] + manifest["per_layer"]:
         if m["name"] in tails or m.get("moves") in tails:
-            assert cell not in m.get("workloads", [cell]), m["name"]
-    new = [m["name"] for m in manifest["per_layer"]][-5:]
-    assert all(manifest["per_layer"][-5 + i]["workloads"] == [cell]
-               for i in range(5)), new
+            assert RAG not in m.get("workloads", [RAG]), m["name"]
+    accepted.check(manifest, ACCEPTED)
 
 
 # -- the new readers on hand-made facts ---------------------------------------------

@@ -198,9 +198,31 @@ def test_the_families_directory_holds_the_gpt2_family():
     for name in ("n_params", "train_flops_per_token", "flash_train_work",
                  "decode_step_flops", "prefill_flops", "paged_decode_work"):
         assert callable(getattr(gpt2.counts, name)), name
-    for cfg in glob.glob(os.path.join(BENCH, "configs", "*.json")) \
-            + glob.glob(os.path.join(CELLS, "configs", "*.json")):
-        assert harness.load_family(harness.load_json(cfg)) is gpt2, cfg
+    for name in ("gpt2-medium", "gpt2-large"):
+        cfg = harness.load_json(BENCH, "configs", name + ".json")
+        assert harness.load_family(cfg) is gpt2, name
+    # every configuration names a family that loads and answers what the
+    # kinds of its cells ask of a family (``family.<name>`` in the kind)
+    kinds = {}
+    for root in (BENCH, CELLS):
+        for path in glob.glob(os.path.join(root, "workloads", "*.json")):
+            cell = harness.load_json(path)
+            kinds.setdefault(cell["config"], set()).add(cell["kind"])
+    for root in (BENCH, CELLS):
+        for path in glob.glob(os.path.join(root, "configs", "*.json")):
+            name = os.path.basename(path)[:-len(".json")]
+            cfg = harness.load_json(path)
+            family = harness.load_family(cfg)
+            assert family.__name__ == "benchmark.families." + cfg["family"]
+            assert kinds.get(name), name          # some cell runs it
+            asked = {"counts"}
+            for kind in kinds[name]:
+                with open(os.path.join(BENCH, "kinds", kind + ".py")) as f:
+                    asked |= set(re.findall(r"\bfamily\.(\w+)", f.read()))
+            for attr in sorted(asked):
+                assert hasattr(family, attr), (name, attr)
+                assert attr == "counts" or callable(getattr(family, attr)), \
+                    (name, attr)
 
 
 # -- the tools still start on a tiny cell, through the family -----------------

@@ -18,6 +18,7 @@ import jax
 import numpy as np
 import pytest
 
+import accepted
 from benchmark import run as harness
 from benchmark.families import mistral4 as family
 from benchmark.kinds import serve
@@ -36,6 +37,10 @@ NEW_METRICS = ("mla_decode_roofline.serve", "mla_share_of_decode.serve",
 # accepted metrics whose lists the cell is appended to
 JOINED = ("prefill_tokens_per_s.serve", "moe_experts_roofline.serve",
           "moe_share_of_decode.serve", "expert_load_max_over_mean.serve")
+ACCEPTED = {"configs": [PUBLISHED], "workloads": [CELL],
+            "per_layer": {name: [CELL] for name in NEW_METRICS},
+            "joined": {name: [CELL]
+                       for name in ("serve_tokens_per_s",) + JOINED}}
 MS = 1e6          # nanoseconds
 
 
@@ -332,71 +337,52 @@ def test_the_family_keeps_each_calls_latent_reads_with_it():
 
 # -- the manifest: what the cell is judged on ---------------------------------
 
-def _named(entries, name):
-    found = [e for e in entries if e["name"] == name]
-    assert len(found) == 1, name
-    return found[0]
-
-
 def _metric_file(name):
     return harness.load_json(ROOT, "benchmark", "metrics", name + ".json")
 
 
-def _entry(spec, workloads):
-    """The manifest entry a metric file stands for."""
-    keys = ("name", "unit", "better", "source", "layer", "moves")
-    return dict({k: spec[k] for k in keys}, workloads=workloads)
-
-
 def test_the_cell_reports_what_its_entries_say():
-    manifest = harness.load_json(ROOT, "BENCHMARK.json")
-    reported = {m["name"] for m in harness.metrics_of(
-        manifest, "end_to_end", CELL, ())}
-    assert reported == {"serve_tokens_per_s", "setup_s"}
+    manifest = accepted.load()
+    reported = accepted.reported(manifest, CELL)
+    assert {"serve_tokens_per_s", "setup_s"} <= reported
+    assert not reported & {"ttft_p95_ms", "tpot_p95_ms"}
     per_layer = harness.metrics_of(manifest, "per_layer", CELL, reported)
-    names = [m["name"] for m in per_layer]
-    assert sorted(names) == sorted(JOINED)
+    assert set(JOINED + NEW_METRICS) <= {m["name"] for m in per_layer}
     for m in per_layer:
-        spec = harness.load_json(ROOT, "benchmark", "metrics",
-                                 m["name"] + ".json")
+        spec = _metric_file(m["name"])
         assert (spec["name"], spec["moves"], spec["layer"], spec["unit"],
                 spec["better"], spec["source"]) \
             == (m["name"], m["moves"], m["layer"], m["unit"], m["better"],
                 m["source"])
-        assert m["moves"] == "serve_tokens_per_s"
-        # appended to what the entry listed, which stays as it was
-        assert m["workloads"][-1] == CELL \
-            and CELL not in m["workloads"][:-1]
-    entry = _named(manifest["workloads"], CELL)
+        assert m["moves"] in reported
+    entry = accepted.find(manifest["workloads"], CELL)
     assert (entry["config"], entry["traffic"], entry["chips"]) \
         == (PUBLISHED, "docqa-r80", 1)
-    assert len(entry["why"]) <= 200
-    cfg_entry = _named(manifest["configs"], PUBLISHED)
+    cfg_entry = accepted.find(manifest["configs"], PUBLISHED)
     assert cfg_entry["reduced"] == published()["reduced"]
     assert cfg_entry["source"] == SOURCE
-    assert cfg_entry["file"] == "benchmark/configs/mistral-small-4-119b.json"
-    assert len(cfg_entry["why"]) <= 200
+    accepted.check(manifest, ACCEPTED)
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS)
 def test_each_latent_metric_file_holds_the_entry_to_append(metric):
-    """The three latent attention metrics have their files and readers but
-    no manifest entry yet: the manifest's last four entries are pinned in
-    place by their own test, and an entry may only be appended after them.
-    Each file holds the whole entry but its cell list, and its reader reads
-    nothing where there is nothing to read."""
-    manifest = harness.load_json(ROOT, "BENCHMARK.json")
-    assert metric not in [m["name"] for m in manifest["per_layer"]]
+    """The three latent attention metrics are appended to the manifest,
+    each listing the cell first, and the entry is what its file holds; the
+    file lists no cell, and its reader reads nothing where there is
+    nothing to read."""
+    manifest = accepted.load()
+    accepted.check(manifest, {"per_layer": {metric: [CELL]}})
+    entry = accepted.find(manifest["per_layer"], metric)
     spec = _metric_file(metric)
     assert "workloads" not in spec
     assert (spec["name"], spec["unit"], spec["source"], spec["moves"]) \
         == (metric, "%", "device_trace", "serve_tokens_per_s")
     # the flash forward's layer is the one the manifest names for its file
     if metric == "mla_prefill_roofline.serve":
-        assert spec["layer"] == _named(
+        assert entry["layer"] == accepted.find(
             manifest["per_layer"], "paged_decode_roofline.serve")["layer"]
     else:
-        assert spec["layer"] == "kernels (apex_tpu/ops/mla.py)"
+        assert entry["layer"] == "kernels (apex_tpu/ops/mla.py)"
     reader = importlib.import_module("benchmark.readers." + spec["reader"])
     empty = {"traced": None, "device_ops": {}, "trace": {"planes": []},
              "chips": 1, "memory_peak_bytes": 0, "queue_wait_ms": []}
@@ -420,12 +406,10 @@ def test_the_harness_reads_the_cells_metrics_on_the_tiny_cell(
                                  metric + ".json"),
                     os.path.join(data, "metrics"))
     manifest = str(tmp_path / "BENCHMARK.json")
-    accepted = harness.load_json(ROOT, "BENCHMARK.json")["per_layer"]
     with open(manifest, "w") as f:
         json.dump({"run_seconds": 1, "end_to_end": [], "per_layer": [
-            dict(m, workloads=[TINY]) for m in accepted
-            if m["name"] in JOINED] + [
-            _entry(_metric_file(name), [TINY]) for name in NEW_METRICS]}, f)
+            dict(m, workloads=[TINY]) for m in accepted.load()["per_layer"]
+            if m["name"] in JOINED + NEW_METRICS]}, f)
     capsys.readouterr()
     harness.main(["--workload", TINY, "--data", data, "--manifest", manifest,
                   "--rehearse", "--seed", "3000000019", "--seconds", "1.0",
@@ -457,7 +441,7 @@ def test_the_cell_is_the_engine_and_traffic_it_states():
         == cell["limits"]["xla_attention_programs"] == 0
     # 0.8 of the 5.0/s the program sustains (PERF.md section 4): 204
     # requests a window
-    seconds = harness.load_json(ROOT, "BENCHMARK.json")["run_seconds"]
+    seconds = accepted.load()["run_seconds"]
     assert mix["rate_per_s"] == 0.8 * 5.0
     assert len(serve.traffic.serve_arrivals(
         mix, 16384, 4500000101, seconds)) == 204

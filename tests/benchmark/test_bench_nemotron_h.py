@@ -2,7 +2,8 @@
 cell's size (``cells/workloads/tiny-nemotron-h.reason.json``): a sound run
 is correct, the fp8 control and every planted fault in the engine's place
 are not, the family's counts follow the cut's arithmetic and the routing,
-and the manifest's new entries are what their files say."""
+and the manifest's entries for the cell are what their files say, found by
+name."""
 
 import json
 import os
@@ -11,6 +12,7 @@ import jax
 import numpy as np
 import pytest
 
+import accepted
 from benchmark import run as harness
 from benchmark.families import nemotron_h as family
 from benchmark.kinds import serve
@@ -25,6 +27,12 @@ NEW_METRICS = ("mamba2_scan_roofline.serve",
                "ssm_state_share_of_decode.serve",
                "latent_experts_roofline.serve",
                "latent_experts_share_of_decode.serve")
+REASON = PUBLISHED + ".reason-r80"
+ACCEPTED = {"configs": [PUBLISHED], "workloads": [REASON],
+            "per_layer": {name: [REASON] for name in NEW_METRICS},
+            "joined": {name: [REASON] for name in (
+                "serve_tokens_per_s", "expert_load_max_over_mean.serve",
+                "prefill_tokens_per_s.serve")}}
 
 
 def context(seed):
@@ -195,39 +203,31 @@ def test_the_scans_work_counts_the_prompts_own_tokens():
 # -- the manifest: what the cell is judged on ---------------------------------
 
 def test_the_cell_reports_what_its_entries_say():
-    manifest = harness.load_json(ROOT, "BENCHMARK.json")
-    cell = PUBLISHED + ".reason-r80"
-    reported = {m["name"] for m in harness.metrics_of(
-        manifest, "end_to_end", cell, ())}
+    manifest = accepted.load()
+    reported = accepted.reported(manifest, REASON)
     assert {"serve_tokens_per_s", "setup_s"} <= reported
     assert reported <= {"serve_tokens_per_s", "setup_s", "ttft_p95_ms",
                         "tpot_p95_ms"}
     # both tails or neither
     assert ("ttft_p95_ms" in reported) == ("tpot_p95_ms" in reported)
-    per_layer = harness.metrics_of(manifest, "per_layer", cell, reported)
-    names = [m["name"] for m in per_layer]
-    assert set(NEW_METRICS) <= set(names)
-    assert {"expert_load_max_over_mean.serve",
-            "prefill_tokens_per_s.serve"} <= set(names)
+    per_layer = harness.metrics_of(manifest, "per_layer", REASON, reported)
+    names = {m["name"] for m in per_layer}
+    assert set(NEW_METRICS) <= names
     assert "pool_rewrite_share.serve" not in names     # gpt2's keys
     for m in per_layer:
         assert m["moves"] in reported, m
         spec = harness.load_json(ROOT, "benchmark", "metrics",
                                  m["name"] + ".json")
         assert (spec["name"], spec["moves"]) == (m["name"], m["moves"])
-    new = manifest["per_layer"][-len(NEW_METRICS):]
-    assert tuple(m["name"] for m in new) == NEW_METRICS
-    assert all(m["workloads"] == [cell] and m["unit"] == "%" for m in new)
-    entry = manifest["workloads"][-1]
-    assert entry["name"] == cell and entry["chips"] == 1
-    cfg_entry = manifest["configs"][-1]
-    assert cfg_entry["reduced"] == published()["reduced"]
-    assert cfg_entry["source"] == published()["source"]
+    for name in NEW_METRICS:
+        assert accepted.find(manifest["per_layer"], name)["unit"] == "%"
+    assert accepted.find(manifest["workloads"], REASON)["chips"] == 1
+    accepted.check(manifest, ACCEPTED)
 
 
 def test_the_cell_is_the_issues_engine_and_traffic():
     cell = harness.load_json(ROOT, "benchmark", "workloads",
-                             PUBLISHED + ".reason-r80.json")
+                             REASON + ".json")
     eng, mix = cell["engine"], cell["traffic_params"]
     assert (eng["max_seqs"], eng["max_len"], eng["block_size"],
             eng["prefill_buckets"], eng["speculate_k"]) \

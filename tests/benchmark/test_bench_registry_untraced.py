@@ -10,6 +10,7 @@ import shutil
 
 import pytest
 
+import accepted
 from benchmark import run as harness
 from benchmark.readers import registry_untraced
 from benchmark.tools import serve_phases
@@ -18,15 +19,16 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 CELLS = os.path.join(HERE, "cells")
 ROOT = os.path.dirname(os.path.dirname(HERE))
 BENCH = os.path.join(ROOT, "benchmark")
-MANIFEST = harness.load_json(ROOT, "BENCHMARK.json")
+MANIFEST = accepted.load()
 CHAT, RAG = "gpt2-large.chat-r80", "command-a-plus-05-2026.rag-r80"
 REASON, JAMBA = "nemotron-3-super-120b-a12b.reason-r80", "jamba2-3b.chat-r80"
-NEW = {  # metric -> the cells the manifest lists for it
+NEW = {  # metric -> the cells it was accepted with, first in its list
     "prefill_own_tokens_per_s.serve": [RAG, REASON, JAMBA],
     "prefill_drain_ms.serve": [CHAT],
     "decode_wait_ms.serve": [CHAT],
     "admit_share_of_loop.serve": [CHAT, RAG, REASON, JAMBA],
 }
+ACCEPTED = {"per_layer": NEW}
 P = "serve/span_ms/"
 
 
@@ -86,26 +88,23 @@ def test_the_reader_gives_none_never_0_for_nothing_to_read(metric):
 
 @pytest.mark.parametrize("metric", sorted(NEW))
 def test_the_entries_match_their_files(metric):
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
-    data = spec(metric)
-    assert data["name"] == metric and data["reader"] == "registry_untraced"
-    for key in ("unit", "better", "source", "layer", "moves"):
-        assert data[key] == entry[key], key
+    accepted.check(MANIFEST, {"per_layer": {metric: NEW[metric]}})
+    entry = accepted.find(MANIFEST["per_layer"], metric)
+    assert spec(metric)["reader"] == "registry_untraced"
     assert entry["source"] == "program_counter"
-    assert entry["workloads"] == NEW[metric]
     # a layer the manifest already had, letter for letter
     assert entry["layer"] in {m["layer"] for m in MANIFEST["per_layer"]
                               if m["name"] not in NEW}
     # each listed cell reports what the metric moves
     for cell in entry["workloads"]:
-        e2e = {m["name"] for m in harness.metrics_of(
-            MANIFEST, "end_to_end", cell, ())}
-        assert entry["moves"] in e2e, cell
+        assert entry["moves"] in accepted.reported(MANIFEST, cell), cell
 
 
 def test_the_entries_were_appended():
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-len(NEW):] == list(NEW)
+    """Found by name, each once, its cells first in its list: entries
+    appended after these four, and cells appended to their lists, leave
+    this as it is."""
+    accepted.check(MANIFEST, ACCEPTED)
 
 
 def test_the_harness_reads_all_four_on_the_tiny_cell(tmp_path, capsys):

@@ -1,22 +1,24 @@
 """The readers of the program's own spans, held to exact values on
-hand-made traces; the manifest's new entries against their files."""
+hand-made traces; the manifest's entries for them against their files,
+found by name."""
 
-import json
 import os
 
 import pytest
 
+import accepted
 from benchmark import run as harness
 from benchmark.readers import idle_under_span, program_span, shape_share
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "benchmark")
-with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-    MANIFEST = json.load(f)
+MANIFEST = accepted.load()
 SPAN_METRICS = ["prefill_roundtrip_ms.serve", "decode_host_ms.serve",
                 "scheduler_self_ms.serve", "dispatch_gap_ms.serve",
                 "fetch_gap_ms.serve", "pool_rewrite_share.serve"]
+ACCEPTED = {"per_layer": {name: ["gpt2-large.chat-r80"]
+                          for name in SPAN_METRICS}}
 MS = 1e6          # nanoseconds
 POOL = "bf16[2,9,8,64]"       # (layers, blocks, block_size, hidden): PR 27's
 LAYER = "bf16[1,9,8,64]"
@@ -252,18 +254,15 @@ def test_dimensions_come_from_the_configuration_and_the_cell():
 
 @pytest.mark.parametrize("metric", SPAN_METRICS)
 def test_new_entries_match_their_files_and_list_the_serve_cell(metric):
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
-    assert entry["workloads"] == ["gpt2-large.chat-r80"]
+    accepted.check(MANIFEST, {"per_layer": {
+        metric: ACCEPTED["per_layer"][metric]}})
+    entry = accepted.find(MANIFEST["per_layer"], metric)
     layers = {m["layer"] for m in MANIFEST["per_layer"]
               if m["name"] not in SPAN_METRICS}
     assert entry["layer"] in layers          # a layer the manifest has
     data = spec(metric)
-    assert data["name"] == metric
     assert data["reader"] in ("program_span", "idle_under_span",
                               "shape_share")
-    # appended: nothing that was there moved
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-len(SPAN_METRICS):] == SPAN_METRICS
 
 
 def test_the_readers_give_nothing_for_a_program_without_spans():

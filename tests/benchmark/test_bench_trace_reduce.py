@@ -64,6 +64,60 @@ def test_idle_gaps_and_attribution_to_host_spans():
     assert by["(no benchmark span)"] == pytest.approx(10e-9)
 
 
+
+def _gaps_by_host_span_plainly(gaps, spans, other):
+    """The attribution by its definition: for each gap, every span in the
+    order innermost first, what is still uncovered going to the first."""
+    sums = {}
+    for gs, ge in gaps:
+        rest = [(gs, ge)]
+        for name, ss, se in sorted(spans, key=lambda s: -s[1]):
+            covered = tr.clip(rest, ss, se)
+            if covered:
+                sums[name] = sums.get(name, 0.0) + tr.total(covered) * 1e-9
+                rest = tr.subtract(rest, tr.union(covered))
+        if rest:
+            sums[other] = sums.get(other, 0.0) + tr.total(rest) * 1e-9
+    return sums
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_attribution_to_host_spans_is_its_definition(seed):
+    """Nested, equal-start, disjoint and far-reaching spans, gaps no span
+    covers: the attribution that walks only the spans that can reach a
+    gap gives the sums of the one that walks them all."""
+    import random
+    rnd = random.Random(seed)
+    spans = []
+    for _ in range(60):
+        s = rnd.randint(0, 400)
+        spans.append((rnd.choice("abcdef"), float(s),
+                      float(s + rnd.choice((0, 3, 20, 150)))))
+    spans.sort(key=lambda sp: (sp[1], sp[2]))
+    gaps, t = [], 0
+    while t < 600:
+        a = t + rnd.randint(0, 9)
+        t = a + rnd.randint(1, 12)
+        gaps.append((float(a), float(t)))
+    got = dict(tr.gaps_by_host_span(gaps, spans, top=None, other="o"))
+    assert got == _gaps_by_host_span_plainly(gaps, spans, "o")
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_meets_is_clip_not_empty(seed):
+    """The bisection a share reader asks of every op gives what a scan of
+    ``clip`` gives: zero-length runs, touching ends, empty and reversed
+    queries included."""
+    import random
+    rnd = random.Random(seed)
+    starts = [float(rnd.randint(0, 300)) for _ in range(40)]
+    runs = tr.union([(s, s + rnd.choice((0, 1, 5, 30))) for s in starts])
+    meets = tr.meets(runs)
+    for _ in range(2000):
+        lo = float(rnd.randint(-10, 340))
+        hi = lo + rnd.choice((-1, 0, 0.5, 1, 4, 50))
+        assert meets(lo, hi) == bool(tr.clip(runs, lo, hi)), (lo, hi)
+
 def test_exposed_collective_time_two_tracks():
     # a collective of 100 ns; compute covers 30 ns of it (20-50), under a
     # while op that spans everything and must not hide the exposure
