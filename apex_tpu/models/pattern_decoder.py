@@ -23,9 +23,21 @@ short period of different blocks repeated (``layer_types`` or
 behind one norm, ``x' = x + mixer(n(x)) + ff(n(x))``; with ``"prenorm"``
 (Nemotron-H) a layer is ONE sub-block, a mixer or a feed-forward, under a
 pre-norm residual; with ``"sequential"`` (Jamba) a layer is TWO, each
-behind its own norm: ``x = x + mixer(n1(x)); x' = x + ff(n2(x))``. ``norm``
-is a bias-free LayerNorm or an RMS norm; the head is the tied embedding or
-its own matrix. Four families are written as such patterns:
+behind its own norm: ``x = x + mixer(n1(x)); x' = x + ff(n3(x))``; with
+``"sandwich"`` (Ouro) each of the two sub-blocks' outputs passes a norm of
+its own too: ``x = x + n2(mixer(n1(x))); x' = x + n4(ff(n3(x)))``.
+``norm`` is a bias-free LayerNorm or an RMS norm; the head is the tied
+embedding or its own matrix.
+
+``loops`` runs the whole stack that many times over its own output (a
+looped language model): pass ``t`` reads the SAME layers' weights and
+writes its own K/V, in cache layer ``t * layers + l`` of the kind's pool,
+so a block kind's pool stacks ``loops * layers`` layers; the final norm
+comes after every pass, the next pass reads the normed state, and the head
+reads the last pass's. Positions are the token's own in every pass. With
+``loops`` 1 no pass is built.
+
+Five families are written as such patterns:
 
 - Cohere2 sparse: three ``sliding_attention`` layers (rotary, a window)
   then one ``full_attention`` layer (no positional term), each beside a
@@ -39,7 +51,10 @@ its own matrix. Four families are written as such patterns:
   followed by a dense gated-SiLU MLP, sequential blocks, RMS norm, tied head;
 - Mistral 4 (DeepSeek-V3's layer): ``mla_moe`` layers only, latent
   attention then the expert layer with a selection bias and one shared
-  expert, sequential blocks, RMS norm, untied head.
+  expert, sequential blocks, RMS norm, untied head;
+- Ouro (a looped stack): ``rope_attention_mlp`` layers only, attention with
+  rotary positions paired ``(j, j + d/2)`` then a dense gated-SiLU MLP,
+  sandwich blocks, RMS norm, untied head, the stack run ``loops`` times.
 
 The latent mixer (``mla``): the query through its own latent,
 ``q = rms(x Wq_a) Wq_b`` split a head into a part with no position and a
@@ -105,7 +120,9 @@ class LayerKind:
     feed_forward: Optional[str]   # "experts" | "dense" | None
     cache: Optional[str]   # "blocks" | "window_blocks" | "latent" | "state"
     #                        | None
-    rotary: bool = False          # attention: rotary positions
+    rotary: Optional[str] = None  # attention: rotary positions, pairs
+    #                               "interleaved" (2j, 2j + 1) | "half"
+    #                               (j, j + d/2)
 
     @property
     def windowed(self) -> bool:
@@ -114,7 +131,7 @@ class LayerKind:
 
 LAYER_KINDS: Dict[str, LayerKind] = {
     "sliding_attention": LayerKind("attention", "experts", "window_blocks",
-                                   rotary=True),
+                                   rotary="interleaved"),
     "full_attention": LayerKind("attention", "experts", "blocks"),
     # the names nemotron_h's hybrid_override_pattern letters map to
     "mamba": LayerKind("mamba2", None, "state"),            # M
@@ -125,6 +142,9 @@ LAYER_KINDS: Dict[str, LayerKind] = {
     "attention_mlp": LayerKind("attention", "dense", "blocks"),
     # mistral 4's one layer: latent attention THEN the expert layer
     "mla_moe": LayerKind("mla", "experts", "latent"),
+    # ouro's one layer: rotary attention (rotate-half pairs) THEN a dense MLP
+    "rope_attention_mlp": LayerKind("attention", "dense", "blocks",
+                                    rotary="half"),
 }
 
 
@@ -154,7 +174,10 @@ class PatternDecoderConfig:
     use_grouped_experts: bool = True      # False: the experts x tokens oracle
     axis_name: Optional[str] = None
     block: str = "parallel"               # | "prenorm": one sub-block a
-    #                                       layer | "sequential": two
+    #                                       layer | "sequential": two |
+    #                                       "sandwich": two, each output
+    #                                       normed too
+    loops: int = 1                        # passes of the whole stack
     norm: str = "layernorm"               # | "rmsnorm"
     tie_embeddings: bool = True           # False: the head is ``lm_head``
     # the expert layer's form (HeldExpertsMLP)
@@ -201,19 +224,29 @@ class PatternDecoderConfig:
                 f"{self.num_key_value_heads} KV heads")
         if self.head_dim % 2:
             raise ValueError("rotary pairs need an even head_dim")
-        if self.block not in ("parallel", "prenorm", "sequential") \
+        if self.block not in ("parallel", "prenorm", "sequential",
+                              "sandwich") \
                 or self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"block {self.block!r} / norm {self.norm!r}")
+        if self.loops < 1:
+            raise ValueError(f"loops {self.loops} < 1")
+        unlooped = {LAYER_KINDS[k].cache for k in self.layer_types} \
+            - {None, "blocks", "window_blocks"}
+        if self.loops > 1 and unlooped:
+            # a pass keeps its own K/V blocks; a per-slot state or a latent
+            # pool a pass is not written
+            raise ValueError(f"a looped stack keeps KV blocks only, not "
+                             f"{sorted(unlooped)}")
         for kind in set(self.layer_types):
             k = LAYER_KINDS[kind]
             if self.block == "prenorm" and (k.mixer is None) \
                     != (k.feed_forward is not None):
                 raise ValueError(
                     f"a prenorm layer is one sub-block; {kind!r} is {k}")
-            if self.block == "sequential" and (
+            if self.block in ("sequential", "sandwich") and (
                     k.mixer is None or k.feed_forward is None):
                 raise ValueError(
-                    f"a sequential layer is a mixer then a feed-forward; "
+                    f"a {self.block} layer is a mixer then a feed-forward; "
                     f"{kind!r} is {k}")
             if k.feed_forward == "experts" and self.num_experts < 1:
                 raise ValueError(f"{kind!r} needs the expert layer's sizes")
@@ -290,8 +323,9 @@ class PatternDecoderConfig:
     @property
     def cache_kinds(self) -> dict:
         """What the engine builds its cache from, in the period's order and
-        for the kinds that hold something only: ``{kind: (layers, window or
-        None)}`` for a kind that keeps KV blocks, a
+        for the kinds that hold something only: ``{kind: (cached layers,
+        window or None)}`` for a kind that keeps KV blocks (``loops`` times
+        its layers: a pass keeps its own K/V), a
         :class:`~apex_tpu.serving.cache.StateSpec` for one that keeps a
         per-slot state, a :class:`~apex_tpu.serving.cache.LatentSpec` for
         one that keeps latent rows."""
@@ -314,7 +348,8 @@ class PatternDecoderConfig:
                     self.mamba_conv, self.mamba_heads, self.mamba_head_dim,
                     self.mamba_state)
             elif holds is not None:
-                out[kind] = (self.layers_of(kind), self.sliding_window
+                out[kind] = (self.loops * self.layers_of(kind),
+                             self.sliding_window
                              if holds == "window_blocks" else None)
         return out
 
@@ -334,6 +369,22 @@ def rotary_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
     a, b = pair[..., 0], pair[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rotary_half(x: jnp.ndarray, positions: jnp.ndarray,
+                theta: float) -> jnp.ndarray:
+    """Rotary positions over the whole head, pairs ``(j, j + d/2)``
+    (``transformers``' ``rotate_half``): ``x cos + rotate_half(x) sin``
+    with ``rotate_half([a, b]) = [-b, a]`` over the head's two halves and
+    ``inv_j = theta^(-2j/d)``; shapes as :func:`rotary_interleaved`.
+    Float32 inside."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def _rms(x, gain, eps):
@@ -374,8 +425,8 @@ class PatternDecoder:
     @property
     def stats_shape(self) -> Tuple[int, int]:
         cfg = self.cfg
-        return (sum(LAYER_KINDS[k].feed_forward == "experts"
-                    for k in cfg.layer_types),
+        return (cfg.loops * sum(LAYER_KINDS[k].feed_forward == "experts"
+                                for k in cfg.layer_types),
                 len(cfg.held_experts) + 1)
 
     @property
@@ -420,8 +471,10 @@ class PatternDecoder:
         for kind in cfg.kinds:
             k = LAYER_KINDS[kind]
             per_layer = dict({"norm": (H,)}, **mixers[k.mixer])
-            if cfg.block == "sequential":
+            if cfg.block in ("sequential", "sandwich"):
                 per_layer["ff_norm"] = (H,)
+            if cfg.block == "sandwich":
+                per_layer.update(mixer_out_norm=(H,), ff_out_norm=(H,))
             per_layer.update(feed_forwards[k.feed_forward]())
             layers[kind] = {name: (cfg.layers_of(kind),) + shape
                             for name, shape in per_layer.items()}
@@ -429,6 +482,10 @@ class PatternDecoder:
                "layers": layers}
         if not cfg.tie_embeddings:
             out["lm_head"] = (H, cfg.vocab_size)
+        if cfg.loops > 1:
+            # the exit gate of a looped model, held as published and read
+            # by nothing: every token runs every pass (no per-token depth)
+            out.update(exit_gate=(H, 1), exit_gate_bias=(1,))
         return out
 
     def init(self, key: jax.Array) -> dict:
@@ -449,7 +506,8 @@ class PatternDecoder:
                         "w_latent_up", "mlp_down"):
                 x = x / (2.0 * cfg.num_layers) ** 0.5
             if name in ("norm", "final_norm", "gate_norm", "D", "ff_norm",
-                        "dt_norm", "b_norm", "c_norm", "q_norm", "kv_norm"):
+                        "dt_norm", "b_norm", "c_norm", "q_norm", "kv_norm",
+                        "mixer_out_norm", "ff_out_norm"):
                 x = 1.0 + x
             if name == "conv_w":
                 x = 0.5 * jax.random.normal(k, shape, jnp.float32)
@@ -482,9 +540,11 @@ class PatternDecoder:
         q = dot(lp["wq"]).reshape(T, cfg.num_attention_heads, cfg.head_dim)
         k = dot(lp["wk"]).reshape(T, cfg.num_key_value_heads, cfg.head_dim)
         v = dot(lp["wv"]).reshape(T, cfg.num_key_value_heads, cfg.head_dim)
-        if LAYER_KINDS[kind].rotary:
-            q = rotary_interleaved(q, positions, cfg.rope_theta)
-            k = rotary_interleaved(k, positions, cfg.rope_theta)
+        rotary = LAYER_KINDS[kind].rotary
+        if rotary:
+            rot = rotary_half if rotary == "half" else rotary_interleaved
+            q = rot(q, positions, cfg.rope_theta)
+            k = rot(k, positions, cfg.rope_theta)
         return q, k, v
 
     def _sum_parts(self, part):
@@ -504,33 +564,37 @@ class PatternDecoder:
         """``x + mixer + feed-forward``, whichever of the two the kind has:
         ``mixed`` is the mixer's part before its output projection joined
         the chips' (float32, or None); the experts read layer ``li`` of the
-        kind's stacked weights. In a sequential block the feed-forward
-        reads its own norm of ``x + mixer``, else the layer's one norm
-        ``h32``."""
+        kind's stacked weights. In a sequential or sandwich block the
+        feed-forward reads its own norm of ``x + mixer``, else the layer's
+        one norm ``h32``; in a sandwich block each sub-block's output is
+        normed before it joins the residual."""
         cfg = self.cfg
+        out_norm = (lambda name, y: self._norm(lp[name], y)) \
+            if cfg.block == "sandwich" else (lambda name, y: y)
         acc = x.astype(jnp.float32)
         if mixed is not None:
-            acc = acc + self._sum_parts(mixed)
+            acc = acc + out_norm("mixer_out_norm", self._sum_parts(mixed))
         feed_forward = LAYER_KINDS[kind].feed_forward
         no_stats = jnp.zeros((0, len(cfg.held_experts) + 1), jnp.int32)
         if feed_forward is None:
             return acc.astype(cfg.compute_dtype), no_stats
-        if cfg.block == "sequential":
+        if cfg.block in ("sequential", "sandwich"):
             # the residual is rounded to the compute dtype between the two
             # sub-blocks, as between two layers
             acc = acc.astype(cfg.compute_dtype)
             h32 = self._norm(lp["ff_norm"], acc)
             acc = acc.astype(jnp.float32)
         if feed_forward == "dense":
-            out = self._sum_parts(
-                self._dense_mlp(lp, h32.astype(cfg.compute_dtype)))
+            out = out_norm("ff_out_norm", self._sum_parts(
+                self._dense_mlp(lp, h32.astype(cfg.compute_dtype))))
             return (acc + out).astype(cfg.compute_dtype), no_stats
         small = {n: v for n, v in lp.items()
                  if n in self.experts.param_shapes()}
         moe, stats = self.experts(
             dict(big, **small), h32.astype(cfg.compute_dtype), valid=valid,
             layer=li)
-        x = (acc + moe.astype(jnp.float32)).astype(cfg.compute_dtype)
+        x = (acc + out_norm("ff_out_norm", moe.astype(jnp.float32))).astype(
+            cfg.compute_dtype)
         return x, jnp.concatenate([stats["load"],
                                    stats["no_held_pick"][None]])[None]
 
@@ -785,11 +849,14 @@ class PatternDecoder:
     # -- a layer --------------------------------------------------------------
 
     def _prefill_layer(self, kind, lp, big, li, x, cache, block_row,
-                       valid, prompt_len=None, slot=None):
+                       valid, prompt_len=None, slot=None, ci=None):
         """One layer over one prompt: ``x`` ``(P, hidden)``; with a cache
         an attention layer's K/V go into the pool blocks
-        ``block_row[kind]``, a Mamba layer's state into row ``slot``."""
+        ``block_row[kind]``, a Mamba layer's state into row ``slot``, both
+        of cache layer ``ci`` (weights layer ``li``'s in this pass; ``li``
+        itself where None)."""
         cfg = self.cfg
+        ci = li if ci is None else ci
         P = x.shape[0]
         h32 = self._norm(lp["norm"], x)
         h = h32.astype(cfg.compute_dtype)
@@ -804,31 +871,33 @@ class PatternDecoder:
             ctx = ctx[0].transpose(1, 0, 2).reshape(P, -1)
             if cache is not None:
                 cache = dict(cache, **{kind: cache[kind].write_layer_blocks(
-                    li, k.reshape(P, -1), v.reshape(P, -1),
+                    ci, k.reshape(P, -1), v.reshape(P, -1),
                     block_row[kind])})
             mixed = jnp.dot(ctx.astype(cfg.compute_dtype), lp["wo"],
                             preferred_element_type=jnp.float32)
         elif mixer == "mamba2":
-            mixed, cache = self._mamba_prefill(kind, lp, li, h, cache,
+            mixed, cache = self._mamba_prefill(kind, lp, ci, h, cache,
                                                prompt_len, slot)
         elif mixer == "mamba1":
-            mixed, cache = self._mamba1_prefill(kind, lp, li, h, cache,
+            mixed, cache = self._mamba1_prefill(kind, lp, ci, h, cache,
                                                 prompt_len, slot)
         elif mixer == "mla":
-            mixed, cache = self._mla_prefill(kind, lp, li, h, cache,
+            mixed, cache = self._mla_prefill(kind, lp, ci, h, cache,
                                              block_row)
         x, stats = self._finish(kind, lp, big, li, x, h32, mixed, valid)
         return x, cache, stats
 
     def _paged_decode_layer(self, kind, lp, big, li, x, cache, tables,
                             lengths, block_ids, offsets, valid,
-                            mean_context, work, reads=None):
+                            mean_context, work, reads=None, ci=None):
         """One layer of the decode step: ``x`` ``(S, hidden)``, one token
-        a slot at position ``lengths``; ``work`` the paged kernel's walk
-        of each attention kind's blocks; ``reads`` the positions a latent
-        kind's kernel reads a slot (its own row included, 0 where the slot
-        is idle)."""
+        a slot at position ``lengths``, read and written at cache layer
+        ``ci`` (weights layer ``li``'s in this pass; ``li`` itself where
+        None); ``work`` the paged kernel's walk of each attention kind's
+        blocks; ``reads`` the positions a latent kind's kernel reads a slot
+        (its own row included, 0 where the slot is idle)."""
         cfg = self.cfg
+        ci = li if ci is None else ci
         S = x.shape[0]
         h32 = self._norm(lp["norm"], x)
         h = h32.astype(cfg.compute_dtype)
@@ -838,31 +907,35 @@ class PatternDecoder:
             pool = cache[kind]
             with jax.named_scope("pattern_attention"):
                 ctx = paged_decode_attention(
-                    q, pool.k, pool.v, li, tables[kind], lengths,
+                    q, pool.k, pool.v, ci, tables[kind], lengths,
                     k_new=k_new, v_new=v_new, mean_context=mean_context,
                     use_pallas=cfg.use_flash, window=self._window(kind),
                     work=work[kind])
             cache = dict(cache, **{kind: pool.append(
-                li, k_new, v_new, block_ids[kind], offsets)})
+                ci, k_new, v_new, block_ids[kind], offsets)})
             mixed = jnp.dot(ctx.reshape(S, -1).astype(cfg.compute_dtype),
                             lp["wo"], preferred_element_type=jnp.float32)
         elif mixer == "mamba2":
-            mixed, cache = self._mamba_decode(kind, lp, li, h, cache, valid)
+            mixed, cache = self._mamba_decode(kind, lp, ci, h, cache, valid)
         elif mixer == "mamba1":
-            mixed, cache = self._mamba1_decode(kind, lp, li, h, cache, valid)
+            mixed, cache = self._mamba1_decode(kind, lp, ci, h, cache, valid)
         elif mixer == "mla":
             mixed, cache = self._mla_decode(
-                kind, lp, li, h, cache, tables, lengths, block_ids, offsets,
+                kind, lp, ci, h, cache, tables, lengths, block_ids, offsets,
                 reads, work, mean_context)
         x, stats = self._finish(kind, lp, big, li, x, h32, mixed, valid)
         return x, cache, stats
 
     def _run_layers(self, layer_fn, params, x, cache):
-        """The period's runs, the period repeated: ``layer_fn(kind, lp,
-        big, li, x, cache) -> (x, cache, stats)``. The experts' stacked
-        weights are closed over and read by layer index where they lie;
-        the small per-layer tensors are the scans' xs. ``stats``: a row a
-        layer that has an expert layer."""
+        """The period's runs, the period repeated, the whole ``loops``
+        times: ``layer_fn(kind, lp, big, li, ci, x, cache) -> (x, cache,
+        stats)``, ``li`` the weights' layer and ``ci`` the cache's (the
+        same tracer with ``loops`` 1; ``t * layers + li`` in pass ``t``).
+        The experts' stacked weights are closed over and read by layer
+        index where they lie; the small per-layer tensors are the scans'
+        xs. ``stats``: a row a layer that has an expert layer, a pass. A
+        looped stack ends every pass in the final norm (the module
+        docstring)."""
         cfg = self.cfg
         n_periods = cfg.num_layers // len(cfg.period)
         in_period = {k: cfg.period.count(k) for k in cfg.kinds}
@@ -872,16 +945,17 @@ class PatternDecoder:
         big = {kind: {n: lp[n] for n in stacked if n in lp}
                for kind, lp in params["layers"].items()}
 
-        def one_period(carry, p):
+        def one_period(carry, p, t):
             rows = []
             for kind, count, before in cfg.runs:
                 first = p * in_period[kind] + before
 
                 def body(carry, i, kind=kind, first=first):
                     li = first + i
+                    ci = li if t is None else t * cfg.layers_of(kind) + li
                     lp = jax.tree_util.tree_map(lambda v: v[li],
                                                 small[kind])
-                    x, cache, stats = layer_fn(kind, lp, big[kind], li,
+                    x, cache, stats = layer_fn(kind, lp, big[kind], li, ci,
                                                *carry)
                     return (x, cache), stats
 
@@ -890,20 +964,34 @@ class PatternDecoder:
                 rows.append(stats.reshape(-1, stats.shape[-1]))
             return carry, jnp.concatenate(rows, axis=0)
 
-        if n_periods == 1:
-            (x, cache), stats = one_period((x, cache), jnp.int32(0))
-        else:
-            (x, cache), stats = jax.lax.scan(
-                one_period, (x, cache),
+        def one_pass(carry, t):
+            if n_periods == 1:
+                return one_period(carry, jnp.int32(0), t)
+            carry, stats = jax.lax.scan(
+                lambda carry, p: one_period(carry, p, t), carry,
                 jnp.arange(n_periods, dtype=jnp.int32))
-            stats = stats.reshape(-1, stats.shape[-1])
-        return x, cache, stats
+            return carry, stats.reshape(-1, stats.shape[-1])
+
+        if cfg.loops == 1:
+            (x, cache), stats = one_pass((x, cache), None)
+            return x, cache, stats
+
+        def looped(carry, t):
+            (x, cache), stats = one_pass(carry, t)
+            x = self._norm(params["final_norm"], x).astype(cfg.compute_dtype)
+            return (x, cache), stats
+
+        (x, cache), stats = jax.lax.scan(
+            looped, (x, cache), jnp.arange(cfg.loops, dtype=jnp.int32))
+        return x, cache, stats.reshape(-1, stats.shape[-1])
 
     def _logits(self, params, x):
         """``logit_scale * n(x) Whead``, float32; the head is the tied
-        embedding or ``lm_head``."""
+        embedding or ``lm_head``. A looped stack's ``x`` is normed
+        already: its last pass ended in the final norm."""
         cfg = self.cfg
-        h = self._norm(params["final_norm"], x).astype(cfg.compute_dtype)
+        h = x if cfg.loops > 1 else self._norm(
+            params["final_norm"], x).astype(cfg.compute_dtype)
         head = params["embedding"].T if cfg.tie_embeddings \
             else params["lm_head"]
         return cfg.logit_scale * jnp.dot(
@@ -919,8 +1007,8 @@ class PatternDecoder:
         cache: the prefill's arithmetic at every position."""
         x = jnp.take(params["embedding"], tokens, axis=0).astype(
             self.cfg.compute_dtype)
-        fn = lambda kind, lp, big, li, x, cache: self._prefill_layer(
-            kind, lp, big, li, x, cache, None, None)
+        fn = lambda kind, lp, big, li, ci, x, cache: self._prefill_layer(
+            kind, lp, big, li, x, cache, None, None, ci=ci)
         x, _, _ = self._run_layers(fn, params, x, None)
         return self._logits(params, x)
 
@@ -966,9 +1054,9 @@ class PatternDecoder:
             valid = jnp.arange(P) < prompt_len
             x = jnp.take(params["embedding"], tokens[0], axis=0).astype(
                 cfg.compute_dtype)
-            fn = lambda kind, lp, big, li, x, cache: self._prefill_layer(
+            fn = lambda kind, lp, big, li, ci, x, cache: self._prefill_layer(
                 kind, lp, big, li, x, cache, block_row, valid, prompt_len,
-                slot)
+                slot, ci=ci)
             x, pools, stats = self._run_layers(fn, params, x, pools)
             if last_logit_only:
                 x = jax.lax.dynamic_slice_in_dim(x, prompt_len - 1, 1, 0)
@@ -991,9 +1079,9 @@ class PatternDecoder:
             reads if kind in latent else lengths, pools[kind].block_size,
             table.shape[1], self._window(kind))
                 for kind, table in block_tables.items()}
-        fn = lambda kind, lp, big, li, x, cache: self._paged_decode_layer(
+        fn = lambda kind, lp, big, li, ci, x, cache: self._paged_decode_layer(
             kind, lp, big, li, x, cache, block_tables, lengths,
             append_block_ids, append_offsets, valid, mean_context, work,
-            reads)
+            reads, ci=ci)
         x, pools, stats = self._run_layers(fn, params, x, pools)
         return self._logits(params, x), KindPagedKVCache(pools), stats

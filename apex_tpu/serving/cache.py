@@ -31,7 +31,10 @@ table per layer KIND (:class:`KindPagedKVCache`,
 maps a prompt block that lies wholly left of the window and hands a block
 back the moment the cursor has left it for good, while a full layer's
 table keeps every block until ``release``. One cursor a slot, a table a
-kind.
+kind. A looped stack (the same layers run ``loops`` times over their own
+output) keeps a K/V layer a pass: its kind's pool stacks ``loops *
+layers`` layers (the model's ``cache_kinds`` says so), and every count of
+the pool's bytes follows from that shape.
 
 **A state kind** (PR 35): a recurrent layer (Mamba-2, Mamba-1) keeps no
 blocks: what a slot needs of the past is a FIXED-SIZE state whatever its

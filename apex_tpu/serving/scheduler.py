@@ -306,6 +306,8 @@ class SlotScheduler:
         # a latent model only: the engine's monotonic count of latent
         # positions read at the last step
         self._latent_seen = 0
+        # the most of the pool in use at the end of any step so far
+        self._pool_peak = 0.0
         # how often the step in flight engages (docs/OBSERVABILITY.md):
         # at 0 from the start, so a run that never overlaps reads 0
         for name in ("serve/decode_steps_overlapped",
@@ -877,8 +879,11 @@ class SlotScheduler:
         capacity = alloc.num_blocks - 1
         used = capacity - alloc.free_blocks
         self._reg.gauge("serve/pool_blocks_used").set(used)
-        self._reg.gauge("serve/pool_utilization").set(
-            used / capacity if capacity else 0.0)
+        utilization = used / capacity if capacity else 0.0
+        self._reg.gauge("serve/pool_utilization").set(utilization)
+        # a high-water mark: how much of the pool the traffic ever filled
+        self._pool_peak = max(self._pool_peak, utilization)
+        self._reg.gauge("serve/pool_utilization_max").set(self._pool_peak)
         if self.engine.by_kind:
             for kind, n in alloc.blocks_in_use.items():
                 self._reg.gauge(f"serve/blocks_in_use/{kind}").set(n)

@@ -912,3 +912,82 @@ def test_the_absorbed_kernel_compiles_for_v5e_at_published_widths(
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 1 and "mla_decode_attention" in calls[0], calls
+
+
+OURO = dict(slots=16, max_len=384, block=128,
+            blocks={"rope_attention_mlp": 49})
+
+
+def _ouro_step(kind, sharding):
+    from apex_tpu.serving.cache import KindPagedKVCache
+    from benchmark import run as harness
+    from benchmark.families import ouro
+    c = OURO
+    model = ouro.model(harness.load_json(harness.HERE, "configs",
+                                         "ouro-2.6b.json"))
+    cfg = model.cfg
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    params = described(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = described(jax.eval_shape(lambda: KindPagedKVCache.create(
+        cfg.cache_kinds, c["blocks"], 16, c["block"], 128,
+        max_seqs=c["slots"])))
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree_util.tree_leaves(params))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
+
+    by_kind = lambda x: {"rope_attention_mlp": x}
+    S, per_slot = c["slots"], c["max_len"] // c["block"]
+    if kind == "decode":
+        def fn(params, cache, tokens, tables, lengths, ids, offs):
+            return model.forward(
+                params, tokens[:, None], kv_cache=cache,
+                block_tables=tables, lengths=lengths, append_block_ids=ids,
+                append_offsets=offs)
+        args = (params, cache, i32(S), by_kind(i32(S, per_slot)), i32(S),
+                by_kind(i32(S)), i32(S))
+    else:
+        bucket = int(kind[len("prefill"):])
+
+        def fn(params, cache, tokens, block_row, prompt_len):
+            return model.forward(
+                params, tokens, kv_cache=cache, block_row=block_row,
+                prompt_len=prompt_len, last_logit_only=True)
+        args = (params, cache, i32(1, bucket),
+                by_kind(i32(bucket // c["block"])), i32())
+    return fn, args, cache, weight_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill128", "prefill256"])
+def test_ouro_programs_compile_for_v5e(kind, one_chip, for_tpu):
+    """The cell's decode program and its two prefill programs at published
+    widths, the looped stack whole: the paged kernel on its ungrouped row
+    path (16 heads of 128, as many KV heads) and the flash forward pass
+    Mosaic, ONE call site of each inside the scans over passes and layers;
+    the pool of 192 cached layers (4 passes x 48), 9.87 GB, is updated
+    where it lies; weights 5.34 GB + pool + temporaries fit the chip."""
+    fn, args, cache, weight_bytes = _ouro_step(kind, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    kernel = "paged_decode_attention" if kind == "decode" \
+        else "flash_attention"
+    assert len(calls) == 1 and kernel in calls[0], calls
+    pool = cache.pools["rope_attention_mlp"]
+    assert pool.k.shape == (192, 49, 128, 16 * 128) and pool.k.dtype == BF16
+    pool_bytes = pool.k.size * 2 + pool.v.size * 2
+    assert pool_bytes == 9_865_003_008                  # 1.5 MiB a position
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert weight_bytes == 2 * 2_667_974_657
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert total < 15.5e9, (total, memory.temp_size_in_bytes)
+    # a step's temporaries are its rows: no copy of a layer's weights or of
+    # the pool
+    assert memory.temp_size_in_bytes < 0.05e9, memory.temp_size_in_bytes
