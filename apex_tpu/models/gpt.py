@@ -33,8 +33,9 @@ from apex_tpu.ops.dropout import dropout
 from apex_tpu.ops.mlp import gelu_tanh
 from apex_tpu.remat import RematPolicy, tag as _remat_tag
 from apex_tpu.ops.flash_attention import (flash_attention,
+                                          flash_attention_qkv, merge_heads,
                                           paged_decode_attention,
-                                          paged_work_list)
+                                          paged_work_list, split_heads)
 from apex_tpu.ops.xentropy import softmax_cross_entropy_loss
 from apex_tpu.transformer import tensor_parallel as tp_mod
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
@@ -298,23 +299,21 @@ class GPTModel:
     def _attention(self, lp: dict, x: jnp.ndarray,
                    attn_seed=None, collect_kv: bool = False):
         cfg = self.cfg
-        b = x.shape[0]
         local_heads = cfg.num_attention_heads // cfg.tensor_model_parallel_size
         qkv, _ = self.qkv(lp["qkv"], x)  # (b, s_full, 3*h/tp) — under SP
         # the ColumnParallel input gather restores the full sequence here
         qkv = self._tag(qkv, "qkv_out")
-        s = qkv.shape[1]
-        qkv = qkv.reshape(b, s, local_heads, 3 * cfg.head_dim)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = jnp.transpose(q, (0, 2, 1, 3))  # (b, nh, s, d)
-        k = jnp.transpose(k, (0, 2, 1, 3))
-        v = jnp.transpose(v, (0, 2, 1, 3))
         rate = cfg.attention_dropout if attn_seed is not None else 0.0
-        ctx = flash_attention(q, k, v, causal=True,
-                              use_pallas=cfg.use_flash,
-                              dropout_rate=rate, dropout_seed=attn_seed,
-                              checkpoint_names=self.remat_policy.uses_names)
-        ctx = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(b, s, -1)
+        kw = dict(causal=True, use_pallas=cfg.use_flash, dropout_rate=rate,
+                  dropout_seed=attn_seed,
+                  checkpoint_names=self.remat_policy.uses_names)
+        if collect_kv:
+            q, k, v = split_heads(qkv, local_heads)   # (b, nh, s, d)
+            ctx = merge_heads(flash_attention(q, k, v, **kw))
+        else:
+            # the QKV activation as it lies: heads of 64 in pairs take the
+            # packed kernels, any other shape the split heads
+            ctx = flash_attention_qkv(qkv, local_heads, **kw)
         out, _ = self.proj(lp["proj"], ctx)
         out = self._tag(out, "attn_proj_out")
         if collect_kv:

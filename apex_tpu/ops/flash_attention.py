@@ -59,9 +59,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.utils.backend import pallas_interpret as _interp
 
-__all__ = ["flash_attention", "mha_reference", "supports_flash",
-           "dropout_keep_mask", "paged_decode_attention",
-           "paged_work_list"]
+__all__ = ["flash_attention", "flash_attention_qkv", "mha_reference",
+           "supports_flash", "dropout_keep_mask", "paged_decode_attention",
+           "paged_work_list", "split_heads", "merge_heads"]
 
 NEG_INF = -1e30
 
@@ -836,11 +836,8 @@ def _bwd_pallas(q3, k3, v3, bias4, seed, segs, h, do3, lse, delta, *, scale,
                     dropout_rate=dropout_rate)
 
     # dq of the whole head is held in VMEM: its float32 gather and its
-    # double-buffered output block; past a few MiB that needs saying
-    held = sq * d * (4 + 2 * q3.dtype.itemsize)
-    roomy = {} if held <= 4 << 20 else dict(
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=(24 << 20) + held))
+    # double-buffered output block
+    roomy = _holding(sq * d * (4 + 2 * q3.dtype.itemsize))
     return pl.pallas_call(
         kernel,
         grid=(bh, n_kv, n_q),
@@ -1022,6 +1019,15 @@ def _make_flash(scale: float, causal: bool, tile: tuple,
 
     flash.defvjp(fwd, bwd)
     return flash
+
+
+def _holding(held: int) -> dict:
+    """The ``pallas_call`` arguments for a kernel that holds ``held``
+    bytes in VMEM across its grid: past a few MiB that needs saying."""
+    if held <= 4 << 20:
+        return {}
+    return dict(compiler_params=pltpu.CompilerParams(
+        vmem_limit_bytes=(24 << 20) + held))
 
 
 def _largest(seq: int, choices) -> int:
@@ -1218,6 +1224,416 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
     with jax.named_scope("flash_attention"):
         out = fn(q3, k3, v3, bias4, seed, qseg, kseg)
     return out.reshape(b, h, sq, d)
+
+
+# ---------------------------------------------------------------------------
+# the packed form: the fused QKV activation where it lies
+# ---------------------------------------------------------------------------
+#
+# A QKV product laid out ``(b, s, h * 3d)``, each head's ``[q | k | v]``
+# side by side (GPTModel's), holds a PAIR of 64-wide heads ``a``, ``b`` in
+# three 128-lane blocks: ``[q_a | k_a]``, ``[v_a | q_b]``, ``[k_b | v_b]``.
+# The kernels below read those blocks where they lie, write the context as
+# the ``(b, s, h * d)`` rows the output projection reads (the pair's block
+# ``[ctx_a | ctx_b]``) and the gradient in the QKV layout, so nothing
+# splits, transposes or pads the heads round them. In VMEM a head's
+# operand is its 64 lanes with the other 64 zeroed, moved by a 64-lane
+# roll to where the product needs it: every product has contraction 128
+# or 128 output columns, half of them zeros, the MXU passes it makes for
+# 64 anyway. The logsumexp leaves as rows, ``(b * h / 2, 2, s)``.
+
+_HALF = _LANES // 2
+_PAIR = 3 * _LANES            # a pair of heads' lanes of the QKV product
+
+
+def _low(shape):
+    """The low 64 of the 128 lanes, as a mask of ``shape``."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1) < _HALF
+
+
+def _moved(x, to_low):
+    """The 64-lane half of ``x`` that a roll by 64 brings to the low lanes
+    (``to_low``) or to the high ones, the other half zero."""
+    lo = _low(x.shape)
+    rolled = pltpu.roll(x.astype(jnp.float32), _HALF, 1)
+    return jnp.where(lo if to_low else ~lo, rolled, 0.0).astype(x.dtype)
+
+
+def _kept(x, low):
+    """``x`` with only its low (``low``) or high 64 lanes, the rest zero."""
+    lo = _low(x.shape)
+    return jnp.where(lo if low else ~lo, x.astype(jnp.float32),
+                     0.0).astype(x.dtype)
+
+
+def _packed_fwd_kernel(qa_ref, qb_ref, k0_ref, k1_ref, k2_ref, o_ref,
+                       lse_ref, acc_ref, m_ref, l_ref, q_ref, *, scale,
+                       causal, tile, n_kv):
+    """One pair of heads, grid ``(b * h / 2, sq / block_q, sk / block_k)``.
+    ``qa_ref``/``qb_ref``: the q rows' blocks ``[q_a | k_a]`` and ``[v_a |
+    q_b]``; ``k0_ref``..``k2_ref``: the kv rows' three blocks. Head ``a``'s
+    scores are ``q_a``, moved to the high lanes, against block 0 (``k_a``
+    high); ``b``'s are ``q_b``, moved low, against block 2 (``k_b`` low).
+    ``P_a V_a`` is the low half of ``P_a . block 1`` and ``P_b V_b`` the
+    high half of ``P_b . block 2``: the accumulator is the context block.
+    ``m``/``l`` per head, in all 128 lanes; ``q_ref`` the moved q of the
+    q tile, made at its first kv step."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    block_q, block_k, sub_q, sub_k = tile
+    n_r, n_c = block_q // sub_q, block_k // sub_k
+    fold = _folds(scale, q_ref.dtype)
+    diagonal = _on_diagonal(tile, 0, causal, None, True)
+    scores = functools.partial(_scores, None, None, None, None, 0,
+                               None if fold else scale, 0, None, 0.0)
+    heads = ((k0_ref, k1_ref), (k2_ref, k2_ref))   # scores, values
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        for t, (ref, to_low) in enumerate(((qa_ref, False), (qb_ref, True))):
+            q = _moved(ref[0], to_low)
+            q_ref[t] = q * scale if fold else q
+
+    def row_body(r):
+        (_, rows), row0 = _sub(n_r, r, sub_q), i * block_q + r * sub_q
+
+        def sub_tile(c, masked):
+            (_, cols), col0 = _sub(n_c, c, sub_k), j * block_k + c * sub_k
+            parts = []
+            for t, (k_ref, v_ref) in enumerate(heads):
+                q, k, v = q_ref[t, rows, :], k_ref[0, cols, :], \
+                    v_ref[0, cols, :]
+                m_prev, l_prev = m_ref[t, rows, :], l_ref[t, rows, :]
+                ms, ls, corrs, pvs = [], [], [], []
+                for rs, ks in _strips(sub_q, sub_k, masked and diagonal):
+                    s, _, _ = scores(
+                        jax.lax.dot_general(q[rs], k[ks], _NT,
+                                            preferred_element_type=jnp.float32),
+                        rows, cols, row0 + rs.start, col0, masked)
+                    m_new = jnp.maximum(m_prev[rs],
+                                        jnp.max(s, axis=1, keepdims=True))
+                    p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+                    corr = jnp.exp(m_prev[rs] - m_new)
+                    ls.append(l_prev[rs] * corr
+                              + jnp.sum(p, axis=1, keepdims=True))
+                    ms.append(m_new)
+                    corrs.append(corr)
+                    pvs.append(jax.lax.dot_general(
+                        p.astype(v.dtype), v[ks], _NN,
+                        preferred_element_type=jnp.float32))
+                m_ref[t, rows, :], l_ref[t, rows, :] = _stack(ms), _stack(ls)
+                parts.append((_stack(corrs), _stack(pvs)))
+            (corr_a, pv_a), (corr_b, pv_b) = parts
+            lo = _low(pv_a.shape)
+            acc_ref[rows, :] = (acc_ref[rows, :] * jnp.where(lo, corr_a, corr_b)
+                                + jnp.where(lo, pv_a, pv_b))
+
+        _, a, b, hi = _key_spans(row0, sub_q, j * block_k, n_c, sub_k, 0,
+                                   causal, None)
+        _span(a, b, n_c, lambda c: sub_tile(c, False))
+        _span(b, hi, n_c, lambda c: sub_tile(c, True))
+
+    _span(0, n_r, n_r, row_body)
+
+    @pl.when(j == n_kv - 1)
+    def _():
+        lo = _low(acc_ref.shape)
+        l = jnp.where(lo, l_ref[0], l_ref[1])
+        safe_l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+        lse = jnp.where(l == 0.0, jnp.inf,
+                        jnp.where(lo, m_ref[0], m_ref[1]) + jnp.log(safe_l))
+        # one transpose makes both heads' rows: lane 0 is a's, 64 b's
+        rows = lse.T
+        lse_ref[0, 0:1, :] = rows[0:1]
+        lse_ref[0, 1:2, :] = rows[_HALF:_HALF + 1]
+
+
+def _packed_bwd_kernel(qa_ref, qb_ref, k0_ref, k1_ref, k2_ref, do_ref,
+                       o_ref, lse_ref, dqkv_ref, acc_ref, kq_ref, q_ref,
+                       do2_ref, st_ref, *, scale, causal, tile, n_q, n_kv):
+    """dq, dk and dv of one pair of heads in ONE pass over the scores, grid
+    ``(b * h / 2, sk / block_k, sq / block_q)``, q tiles innermost (as
+    :func:`_bwd_kernel`). The pair's whole gradient, ``[dq_a | dk_a]``,
+    ``[dv_a | dq_b]``, ``[dk_b | dv_b]``, gathers in ``acc_ref`` (float32,
+    the whole sequence) and is written once, after the pair's last step.
+    Each product lands in its head's half: ``dV = P^T dO`` with ``dO``
+    kept to the head's half, ``dK = dS^T Q`` with Q moved as the forward
+    moves it, ``dQ = dS K`` with K moved to where ``dq`` lies (``kq_ref``,
+    made at a kv tile's first step). delta = rowsum(dO * O) of each head
+    and the lse rows as columns (``st_ref``) are made at every step."""
+    j, i = pl.program_id(1), pl.program_id(2)
+    block_q, block_k, sub_q, sub_k = tile
+    n_r, n_c = block_q // sub_q, block_k // sub_k
+    fold = _folds(scale, q_ref.dtype)
+    diagonal = _on_diagonal(tile, 0, causal, None, True)
+    p_ds = functools.partial(
+        _p_ds, functools.partial(_scores, None, None, None, None, 0,
+                                 None if fold else scale, 0, None, 0.0), 0.0)
+    # per head: scores against, values from, and the blocks dk, dv, dq
+    # gather in
+    heads = ((k0_ref, k1_ref, 0, 1, 0), (k2_ref, k2_ref, 2, 2, 1))
+
+    @pl.when(jnp.logical_and(j == 0, i == 0))
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i == 0)
+    def _():
+        kq_ref[0] = _moved(k0_ref[0], True)
+        kq_ref[1] = _moved(k2_ref[0], False)
+
+    lo = _low((block_q, _LANES))
+    q_ref[0] = _moved(qa_ref[0], False)
+    q_ref[1] = _moved(qb_ref[0], True)
+    do = do_ref[0]
+    do_o = do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    for t, half in enumerate((lo, ~lo)):
+        do2_ref[t] = _kept(do, t == 0)
+        st_ref[t] = jnp.broadcast_to(lse_ref[0, t:t + 1, :],
+                                     (_LANES, block_q)).T
+        st_ref[2 + t] = jnp.broadcast_to(
+            jnp.sum(jnp.where(half, do_o, 0.0), axis=1, keepdims=True),
+            (block_q, _LANES))
+
+    def row_body(r):
+        (r0, rows), row0 = _sub(n_r, r, sub_q), i * block_q + r * sub_q
+
+        def sub_tile(c, masked):
+            (c0, cols), col0 = _sub(n_c, c, sub_k), j * block_k + c * sub_k
+            for t, (k_ref, v_ref, dk_at, dv_at, dq_at) in enumerate(heads):
+                q, do, lse = q_ref[t, rows, :], do2_ref[t, rows, :], \
+                    st_ref[t, rows, :]
+                k, v, kq = k_ref[0, cols, :], v_ref[0, cols, :], \
+                    kq_ref[t, cols, :]
+                delta = st_ref[2 + t, rows, :]
+                qs = q * scale if fold else q
+                for rs, ks in _strips(sub_q, sub_k, masked and diagonal):
+                    high, wide = rs.stop - rs.start, ks.stop
+                    p, ds = p_ds(qs[rs], k[ks], v[ks], do[rs],
+                                 _lanes(lse[rs], wide),
+                                 _lanes(delta[rs], wide), rows, cols,
+                                 row0 + rs.start, col0, masked)
+                    ds = ds.astype(q.dtype)
+                    under = pl.ds(pl.multiple_of(j * block_k + c0, sub_k),
+                                  wide)
+                    acc_ref[dv_at, under, :] += jax.lax.dot_general(
+                        p.astype(do.dtype), do[rs], _TN,
+                        preferred_element_type=jnp.float32)
+                    acc_ref[dk_at, under, :] += jax.lax.dot_general(
+                        ds, q[rs], _TN, preferred_element_type=jnp.float32)
+                    acc_ref[dq_at, pl.ds(pl.multiple_of(
+                        i * block_q + r0 + rs.start, high), high), :] += \
+                        jax.lax.dot_general(ds, kq[ks], _NN,
+                                            preferred_element_type=jnp.float32)
+
+        _, _, b, hi = _key_spans(row0, sub_q, j * block_k, n_c, sub_k, 0,
+                                 causal, None)
+        _span(0, b, n_c, lambda c: sub_tile(c, False))
+        _span(b, hi, n_c, lambda c: sub_tile(c, True))
+
+    _span(0, n_r, n_r, row_body)
+
+    @pl.when(jnp.logical_and(j == n_kv - 1, i == n_q - 1))
+    def _():
+        # dq and dk carry the softmax scale, dv does not
+        low = _low(acc_ref.shape[1:])
+        for t, (s_low, s_high) in enumerate(((scale, scale), (1.0, scale),
+                                             (scale, 1.0))):
+            dqkv_ref[0, :, t * _LANES:(t + 1) * _LANES] = (
+                acc_ref[t] * jnp.where(low, s_low, s_high)
+            ).astype(dqkv_ref.dtype)
+
+
+def _packed_fwd_pallas(qkv, h, *, scale, causal, tile):
+    b, s, _ = qkv.shape
+    pairs = h // 2
+    block_q, block_k = tile[:2]
+    n_q, n_kv = s // block_q, s // block_k
+
+    def q_block(t):
+        return pl.BlockSpec(
+            (1, block_q, _LANES), lambda g, i, j: (g // pairs, i,
+                                                   3 * (g % pairs) + t),
+            memory_space=pltpu.VMEM)
+
+    def kv_block(t):
+        return pl.BlockSpec(
+            (1, block_k, _LANES),
+            lambda g, i, j: (g // pairs,
+                             _live_kv(i, j, block_q, block_k, 0, n_kv, causal),
+                             3 * (g % pairs) + t),
+            memory_space=pltpu.VMEM)
+
+    def kernel(*refs):
+        _packed_fwd_kernel(*refs, scale=scale, causal=causal, tile=tile,
+                           n_kv=n_kv)
+
+    return pl.pallas_call(
+        kernel,
+        grid=(b * pairs, n_q, n_kv),
+        in_specs=[q_block(0), q_block(1)] + [kv_block(t) for t in range(3)],
+        out_specs=(pl.BlockSpec((1, block_q, _LANES),
+                                lambda g, i, j: (g // pairs, i, g % pairs),
+                                memory_space=pltpu.VMEM),
+                   pl.BlockSpec((1, 2, block_q), lambda g, i, j: (g, 0, i),
+                                memory_space=pltpu.VMEM)),
+        out_shape=(_sds((b, s, pairs * _LANES), qkv.dtype, qkv),
+                   _sds((b * pairs, 2, s), jnp.float32, qkv)),
+        scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((2, block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((2, block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((2, block_q, _LANES), qkv.dtype)],
+        interpret=_interp(),
+    )(*[qkv] * 5)
+
+
+def _packed_bwd_pallas(qkv, out, do, lse, h, *, scale, causal, tile):
+    b, s, _ = qkv.shape
+    pairs = h // 2
+    block_q, block_k = tile[:2]
+    n_q, n_kv = s // block_q, s // block_k
+
+    def live_q(j, i):
+        if not causal:
+            return i
+        return jnp.maximum(i, jnp.clip(j * block_k // block_q, 0, n_q - 1))
+
+    def q_block(t):
+        return pl.BlockSpec(
+            (1, block_q, _LANES),
+            lambda g, j, i: (g // pairs, live_q(j, i), 3 * (g % pairs) + t),
+            memory_space=pltpu.VMEM)
+
+    def kv_block(t):
+        return pl.BlockSpec(
+            (1, block_k, _LANES),
+            lambda g, j, i: (g // pairs, j, 3 * (g % pairs) + t),
+            memory_space=pltpu.VMEM)
+
+    row_block = pl.BlockSpec(
+        (1, block_q, _LANES),
+        lambda g, j, i: (g // pairs, live_q(j, i), g % pairs),
+        memory_space=pltpu.VMEM)
+    lse_block = pl.BlockSpec((1, 2, block_q),
+                             lambda g, j, i: (g, 0, live_q(j, i)),
+                             memory_space=pltpu.VMEM)
+
+    def kernel(*refs):
+        _packed_bwd_kernel(*refs, scale=scale, causal=causal, tile=tile,
+                           n_q=n_q, n_kv=n_kv)
+
+    # the pair's whole gradient is held in VMEM: its float32 gather and
+    # its double-buffered output block
+    roomy = _holding(s * _PAIR * (4 + 2 * qkv.dtype.itemsize))
+    return pl.pallas_call(
+        kernel,
+        grid=(b * pairs, n_kv, n_q),
+        in_specs=[q_block(0), q_block(1)] + [kv_block(t) for t in range(3)]
+        + [row_block, row_block, lse_block],
+        out_specs=pl.BlockSpec((1, s, _PAIR),
+                               lambda g, j, i: (g // pairs, 0, g % pairs),
+                               memory_space=pltpu.VMEM),
+        out_shape=_sds(qkv.shape, qkv.dtype, qkv),
+        scratch_shapes=[pltpu.VMEM((3, s, _LANES), jnp.float32),
+                        pltpu.VMEM((2, block_k, _LANES), qkv.dtype),
+                        pltpu.VMEM((2, block_q, _LANES), qkv.dtype),
+                        pltpu.VMEM((2, block_q, _LANES), qkv.dtype),
+                        pltpu.VMEM((4, block_q, _LANES), jnp.float32)],
+        interpret=_interp(),
+        **roomy,
+    )(*[qkv] * 5, do, out, lse)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_packed(scale: float, causal: bool, tile: tuple, h: int,
+                 checkpoint_names: bool = False):
+    common = dict(scale=scale, causal=causal, tile=tile)
+
+    @jax.custom_vjp
+    def flash(qkv):
+        return _packed_fwd_pallas(qkv, h, **common)[0]
+
+    def fwd(qkv):
+        out, lse = _packed_fwd_pallas(qkv, h, **common)
+        if checkpoint_names:
+            # as in _make_flash: both residuals tagged keep the forward
+            # kernel out of a name policy's recomputed set
+            from apex_tpu.remat import tag as _remat_tag
+            out = _remat_tag(out, "flash_ctx")
+            lse = _remat_tag(lse, "flash_lse")
+        return out, (qkv, out, lse)
+
+    def bwd(res, do):
+        qkv, out, lse = res
+        return (_packed_bwd_pallas(qkv, out, do, lse, h, **common),)
+
+    flash.defvjp(fwd, bwd)
+    return flash
+
+
+def _packed_tile(s: int, d: int, h: int, block_q: Optional[int] = None,
+                 block_k: Optional[int] = None):
+    """The tile the packed kernels walk, or None where the packed form
+    does not apply: heads of 64 in pairs, grid tiles of whole 128-row
+    multiples (the lse rows' blocks), and a pair's whole-sequence gradient
+    (``s x 384``, float32 and its output block) within the backward's
+    VMEM budget, as :func:`_auto_block` gates a head's dq."""
+    if d != _HALF or h % 2 or s * _PAIR > _HEAD_ELEMENTS:
+        return None
+    tile = _auto_block(s, s, d, block_q, block_k)
+    if tile is None or tile[0] % _LANES or tile[1] % _LANES:
+        return None
+    return tile
+
+
+def split_heads(qkv, heads: int):
+    """``(b, s, heads * 3d)``, each head's ``[q | k | v]`` side by side, as
+    q, k and v ``(b, heads, s, d)``."""
+    b, s = qkv.shape[:2]
+    q, k, v = jnp.split(qkv.reshape(b, s, heads, -1), 3, axis=-1)
+    return tuple(jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
+
+
+def merge_heads(ctx):
+    """``(b, h, s, d)`` as the ``(b, s, h * d)`` rows a projection reads."""
+    b, _, s, _ = ctx.shape
+    return jnp.transpose(ctx, (0, 2, 1, 3)).reshape(b, s, -1)
+
+
+def flash_attention_qkv(qkv, heads: int, causal: bool = False,
+                        block_q: Optional[int] = None,
+                        block_k: Optional[int] = None,
+                        use_pallas: Optional[bool] = None,
+                        dropout_rate: float = 0.0, dropout_seed=None,
+                        checkpoint_names: bool = False):
+    """Self-attention over a fused QKV activation ``(b, s, heads * 3d)``,
+    each head's ``[q | k | v]`` side by side, returning the context as
+    ``(b, s, heads * d)``, its gradient in the QKV layout.
+
+    Heads of 64 in pairs, no dropout and a tile of whole 128-row blocks
+    take the packed kernels (see "the packed form" above), which read the
+    activation where it lies. Any other call is :func:`flash_attention`
+    over the split, transposed heads, as it always was (the arguments mean
+    what they mean there)."""
+    b, s, width = qkv.shape
+    d = width // (3 * heads)
+    tile = None
+    if dropout_rate == 0.0 and use_pallas is not False:
+        tile = _packed_tile(s, d, heads, block_q, block_k)
+    if tile is None:
+        q, k, v = split_heads(qkv, heads)
+        return merge_heads(flash_attention(
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+            use_pallas=use_pallas,
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            checkpoint_names=checkpoint_names))
+    fn = _make_packed(1.0 / math.sqrt(d), bool(causal), tile, heads,
+                      bool(checkpoint_names))
+    with jax.named_scope("flash_attention"):
+        return fn(qkv)
 
 
 # ---------------------------------------------------------------------------

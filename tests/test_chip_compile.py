@@ -15,6 +15,7 @@ process at a time may load libtpu, and every xdist worker imports every
 test file. Keep all such compiles in THIS file (a second file could land
 on another worker, where the fixture would skip).
 """
+import contextlib
 import importlib
 import re
 
@@ -54,20 +55,29 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def for_tpu(monkeypatch):
+@contextlib.contextmanager
+def _compiling_for_tpu():
     """Steer the kernels' backend probe to "tpu" (the process's default
     backend is the CPU, which would pick interpret mode) and keep the
     persistent compile cache out of these compiles: an executable for a
     described device is written to it but can never be read back."""
     from jax.experimental.compilation_cache import compilation_cache as cc
-    monkeypatch.setattr(fa, "_interp", lambda: False)
-    prev = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, "_interp", lambda: False)
+        prev = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_compilation_cache", prev)
+            cc.reset_cache()
+
+
+@pytest.fixture
+def for_tpu():
+    with _compiling_for_tpu():
+        yield
 
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
@@ -95,6 +105,16 @@ def _flash_loss(dropout_rate):
             dropout_seed=7 if dropout_rate else None)
         return jnp.sum(out.astype(jnp.float32))
     return jax.grad(loss, argnums=(0, 1, 2))
+
+
+# the packed form at gpt2-medium's widths: the layer's QKV product, 4 x
+# 1,024 x 16 heads x [q | k | v] of 64, forward and backward
+MEDIUM_QKV = [((4, 1024, 16 * 3 * 64), BF16)]
+
+
+def _flash_qkv_loss(qkv):
+    return jnp.sum(fa.flash_attention_qkv(qkv, 16, causal=True)
+                   .astype(jnp.float32))
 
 
 def _paged(q_len, quantized, heads=H, head_dim=D, block=BLOCK):
@@ -126,6 +146,8 @@ CASES = {
     "flash_fwd_bwd_medium": lambda: (_flash_loss(0.0), MEDIUM),
     "flash_fwd_large_prefill": lambda: (_flash_fwd, LARGE_PREFILL),
     "flash_fwd_bwd_long_head": lambda: (_flash_loss(0.0), LONG_HEAD),
+    "flash_qkv_fwd_bwd_medium": lambda: (jax.grad(_flash_qkv_loss),
+                                         MEDIUM_QKV),
     "paged_decode_q1": lambda: _paged(1, False),
     "paged_verify_q5": lambda: _paged(VERIFY_Q, False),
     "paged_decode_int8": lambda: _paged(1, True),
@@ -149,27 +171,30 @@ def test_training_flash_kernels_keep_the_name_the_metric_reads(one_chip,
     ``^flash_attention[.\\d]* `` inside the step: the instruction names come
     from ``jax.named_scope("flash_attention")`` and no ``name=`` on the
     training ``pallas_call``s. A kernel the pattern missed would put the
-    whole work over the others' time alone and read over 100%."""
-    fn, shapes = CASES["flash_fwd_bwd_medium"]()
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
-    assert len(calls) == 2, calls      # one forward, ONE backward
-    for line in calls:
-        name = line.split("=")[0].strip(" %")
-        scope = re.search(r'op_name="([^"]*)"', line).group(1)
-        assert "flash_attention" in name and "window" not in name, name
-        assert re.search(r"\(flash_attention\)+/pallas_call$", scope), scope
+    whole work over the others' time alone and read over 100%. Both
+    training forms: the split heads' kernels and the packed ones that
+    read the QKV activation where it lies."""
+    for case in ("flash_fwd_bwd_medium", "flash_qkv_fwd_bwd_medium"):
+        fn, shapes = CASES[case]()
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line]
+        assert len(calls) == 2, calls      # one forward, ONE backward
+        for line in calls:
+            name = line.split("=")[0].strip(" %")
+            scope = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "flash_attention" in name and "window" not in name, name
+            assert re.search(r"\(flash_attention\)+/pallas_call$", scope), \
+                scope
 
 
-def test_the_train_step_stacks_two_mlp_activations_a_layer(topo, for_tpu):
+@pytest.fixture(scope="module")
+def pretrain_step(topo):
     """``gpt2-medium.pretrain-16k``'s own step (the family's trainer at the
-    cell's job, donated, published widths) over 2 layers: the layer scans
-    carry two stacked ``bf16[L, 4, 1024, 4096]`` MLP activations, the
-    tanh-GELU's input and its output, which fc2's weight gradient reads.
-    AD of the plain ``jax.nn.gelu`` saved four more (tanh, 1 - tanh,
-    0.5 (1 + tanh), 3 x^2): 4 x 805 MB written and read back a step at
-    24 layers (``ops.mlp.gelu_tanh``)."""
+    cell's job, donated, published widths) over 2 layers, compiled: its
+    text. The instruction names are those the cell's traces give."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from apex_tpu.transformer import parallel_state
     from benchmark import run as harness
@@ -178,33 +203,78 @@ def test_the_train_step_stacks_two_mlp_activations_a_layer(topo, for_tpu):
                n_layer=2)
     job = harness.load_json(harness.HERE, "workloads",
                             "gpt2-medium.pretrain-16k.json")["job"]
-    trainer, mesh = gpt2.trainer(cfg, job, topo.devices[:1])
-    try:
-        stage, shared = jax.eval_shape(
-            lambda: gpt2.to_trainer(gpt2.make_weights(cfg, *gpt2.seed_key(1))))
+    with _compiling_for_tpu():
+        trainer, mesh = gpt2.trainer(cfg, job, topo.devices[:1])
+        try:
+            stage, shared = jax.eval_shape(
+                lambda: gpt2.to_trainer(
+                    gpt2.make_weights(cfg, *gpt2.seed_key(1))))
 
-        def described(tree, specs=None):
-            specs = specs or jax.tree_util.tree_map(lambda _: P(), tree)
-            return jax.tree_util.tree_map(
-                lambda x, s: jax.ShapeDtypeStruct(
-                    x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
-                tree, specs, is_leaf=lambda x: isinstance(x, P))
+            def described(tree, specs=None):
+                specs = specs or jax.tree_util.tree_map(lambda _: P(), tree)
+                return jax.tree_util.tree_map(
+                    lambda x, s: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+                    tree, specs, is_leaf=lambda x: isinstance(x, P))
 
-        tokens = described(jax.ShapeDtypeStruct(
-            (job["microbatches"], job["micro_batch"], job["seq"]), I32))
-        args = (described(stage, trainer.stage_specs(stage)),
-                described(shared, trainer.shared_specs),
-                described(jax.eval_shape(trainer.opt.init, (stage, shared))),
-                described(jax.eval_shape(trainer.scaler.init)),
-                tokens, tokens)
-        text = trainer.jit_train_step(donate=True).lower(*args) \
-            .compile().as_text()
-    finally:
-        parallel_state.destroy_model_parallel()
+            tokens = described(jax.ShapeDtypeStruct(
+                (job["microbatches"], job["micro_batch"], job["seq"]), I32))
+            args = (described(stage, trainer.stage_specs(stage)),
+                    described(shared, trainer.shared_specs),
+                    described(jax.eval_shape(trainer.opt.init,
+                                             (stage, shared))),
+                    described(jax.eval_shape(trainer.scaler.init)),
+                    tokens, tokens)
+            return trainer.jit_train_step(donate=True).lower(*args) \
+                .compile().as_text()
+        finally:
+            parallel_state.destroy_model_parallel()
+
+
+def test_the_train_step_stacks_two_mlp_activations_a_layer(pretrain_step):
+    """The layer scans carry two stacked ``bf16[L, 4, 1024, 4096]`` MLP
+    activations, the tanh-GELU's input and its output, which fc2's weight
+    gradient reads. AD of the plain ``jax.nn.gelu`` saved four more (tanh,
+    1 - tanh, 0.5 (1 + tanh), 3 x^2): 4 x 805 MB written and read back a
+    step at 24 layers (``ops.mlp.gelu_tanh``)."""
     stack = "bf16[2,4,1024,4096]"
     carried = [line.split(" while(")[0].count(stack)
-               for line in text.splitlines() if ") while(" in line]
+               for line in pretrain_step.splitlines() if ") while(" in line]
     assert max(carried) == 2, carried
+
+
+# an array of split heads (16 of 64 lanes each, padded to 128 in HBM), or
+# the split kernels' logsumexp column (one float32 a (8, 128) tile row)
+_SPLIT_HEADS = re.compile(r"\[(?:\d+,)*(?:64,1024,64|16,1024,64|1024,16,64)\]"
+                          r"|f32\[(?:\d+,)*64,1024,1\]")
+
+
+def test_the_layer_loops_take_the_qkv_activation_as_it_lies(pretrain_step):
+    """In the step's forward and backward layer loops the flash kernels
+    read the QKV product where it lies and give the context and the QKV
+    gradient in the model's own layout: no instruction of a loop makes an
+    array of split heads (``[..,64,1024,64]``, ``[4,16,1024,64]``,
+    ``[4,1024,16,64]``) or an lse column (``f32[64,1024,1]``), ~790 MB of
+    relayouts a layer call with the split kernels; and each loop calls
+    exactly one kernel, named as ``flash_attention_roofline.train``
+    finds it."""
+    lines_of, current = {}, None
+    for line in pretrain_step.splitlines():
+        if line.rstrip().endswith("{") and "=" not in line.split("(")[0]:
+            current = line.split("(")[0].replace("ENTRY", "").strip(" %")
+        lines_of.setdefault(current, []).append(line)
+    loops = {c: ls for c, ls in lines_of.items()
+             if any("tpu_custom_call" in line for line in ls)}
+    assert len(loops) == 2, sorted(loops)       # forward, backward
+    for comp, lines in loops.items():
+        kernels = [line.split("=")[0].strip(" %") for line in lines
+                   if "tpu_custom_call" in line and "custom-call(" in line]
+        assert len(kernels) == 1 and re.fullmatch(
+            r"flash_attention[.\d]*", kernels[0]), (comp, kernels)
+        made = [line.split(" = ")[1][:120] for line in lines
+                if " = " in line and _SPLIT_HEADS.search(
+                    line.split(" = ")[1].split(" ")[0])]
+        assert made == [], (comp, made)
 
 
 # ---------------------------------------------------------------------------
